@@ -541,9 +541,8 @@ def prometheus_rules():
             "annotations": {
                 "summary": "XLA recompile landed while requests were live",
                 "description": (
-                    "A compiled-shape-bucket miss hit a serving engine "
-                    "(BENCH_r05's 120 s p99 was one of these). The victim "
-                    "request's timeline carries a `compile` span event; "
+                    "A compiled-shape-bucket miss hit a serving engine. "
+                    "The victim request's timeline carries a `compile` span event; "
                     "widen --min-decode-bucket or pre-warm the offending "
                     "bucket (kind/shape_bucket labels name it)."
                 ),

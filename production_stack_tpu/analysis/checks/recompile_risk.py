@@ -282,8 +282,8 @@ def run(project: Project) -> List[Finding]:
                     CHECK_ID, src.rel, call.lineno, call.col_offset,
                     "dispatch family %r (from %s) is not enumerated by "
                     "enumerate_lattice in %s — live traffic on this path "
-                    "compiles AFTER /ready flips (the BENCH_r05 120 s p99 "
-                    "class of bug)" % (family, how, precompile.rel),
+                    "compiles AFTER /ready flips"
+                    % (family, how, precompile.rel),
                 ))
 
         # Jit registration (rule 4).

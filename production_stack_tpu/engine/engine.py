@@ -70,17 +70,17 @@ class LLMEngine:
         t_init = time.perf_counter()
         self.cfg = cfg
         self.model_cfg = get_model_config(cfg.model)
-        if cfg.compile_cache_dir:
-            # Before the runner wires any jit: executables compiled earlier
-            # are never written back to the persistent cache.
-            from .precompile import configure_compile_cache
+        # Before the runner wires any jit: executables compiled earlier
+        # are never written back to the persistent cache.
+        from .precompile import configure_compile_cache
 
-            configure_compile_cache(cfg, self.model_cfg)
+        compile_cache_path = configure_compile_cache(cfg, self.model_cfg)
         tok_spec = cfg.tokenizer or (cfg.model if os.path.isdir(cfg.model) else None)
         self.tokenizer = get_tokenizer(tok_spec, self.model_cfg.vocab_size)
         t_runner = time.perf_counter()
         self.runner = ModelRunner(cfg, self.model_cfg, mesh)
         t_runner_s = time.perf_counter() - t_runner
+        self.runner.device_info["compile_cache_dir"] = compile_cache_path
         if cfg.cpu_offload_blocks > 0 or cfg.remote_kv_url:
             from .cache_tiering import TieredAllocator, create_remote_client
 

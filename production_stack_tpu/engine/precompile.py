@@ -8,13 +8,14 @@ decode rows, decode bursts, spec-verify, encode — and drives every jitted
 dispatch in :mod:`runner` through it with all-padding dummy batches at
 warmup, before the server's ``/ready`` flips. The result is the
 prevention half of PR 5's detection machinery: after a ``full`` warmup a
-live-traffic XLA recompile (the BENCH_r05 120 s p99) is impossible for
-any shape the lattice covers, and ``pst_engine_compile_total`` staying
+live-traffic XLA recompile is impossible for any shape the lattice
+covers, and ``pst_engine_compile_total`` staying
 flat under traffic proves it.
 
-Underneath sits a **persistent JAX compilation cache**: executables are
-serialized to ``compile_cache_dir/<key>`` where ``<key>`` hashes model +
-mesh + dtypes + code version, so a warm restart (or a rolling-deploy
+Underneath sits a **persistent JAX compilation cache**, always on and
+placeable from outside (``JAX_COMPILATION_CACHE_DIR``, else
+``--compile-cache-dir``, else a fixed path in the checkout — see
+:func:`configure_compile_cache`), so a warm restart (or a rolling-deploy
 replacement pod on the same PVC/hostPath mount) deserializes instead of
 rebuilding — ``pst_engine_compile_cache_{hits,misses}_total`` count the
 outcomes via jax's monitoring events, and
@@ -299,6 +300,16 @@ def compile_cache_key(cfg: EngineConfig, model_cfg) -> str:
 
 _cache_listener_installed = False
 
+# Where the cache lives when nothing outside places it: one fixed path in
+# the checkout (listed in .gitignore). The path is part of what makes a
+# later process find the entries again, so it is never built from a
+# temporary name, a pid or the time.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
 
 def _install_cache_listener() -> None:
     """Feed jax's compilation-cache monitoring events into the telemetry
@@ -306,12 +317,7 @@ def _install_cache_listener() -> None:
     global _cache_listener_installed
     if _cache_listener_installed:
         return
-    try:
-        from jax._src import monitoring
-    except ImportError:  # pragma: no cover — future jax relayout
-        logger.warning("jax monitoring unavailable; cache hit/miss "
-                       "counters will stay at 0")
-        return
+    from jax._src import monitoring
 
     def _on_event(name: str, **kwargs) -> None:
         if name.endswith("/compilation_cache/cache_hits"):
@@ -324,35 +330,45 @@ def _install_cache_listener() -> None:
 
 
 def configure_compile_cache(cfg: EngineConfig, model_cfg) -> Optional[str]:
-    """Point jax's persistent compilation cache at the keyed directory.
+    """Turn on jax's persistent compilation cache and return its directory.
+
+    Placement, in order: ``JAX_COMPILATION_CACHE_DIR`` — jax reads it
+    itself, and this code then sets no directory at all, so whoever runs
+    the process (a bench driver, a node with a warm disk) decides where
+    executables live; else ``cfg.compile_cache_dir/<key>`` — the
+    deployment flag (helm mounts a PVC there), keyed so engines of
+    different shape sharing one volume stay apart; else, on the chip, the
+    fixed in-checkout default. On the CPU test platform an unplaced cache
+    stays off (returns None): XLA:CPU logs two multi-KB machine-feature
+    errors per cache hit, and a test engine's compiles are sub-second.
 
     Must run before the runner wires its jits (compiles that happen
-    earlier are never written back). Returns the resolved directory, or
-    None when persistence is off."""
-    if not cfg.compile_cache_dir:
-        return None
+    earlier are never written back)."""
     import jax
+    from jax._src import compilation_cache
 
-    path = os.path.join(
-        cfg.compile_cache_dir, compile_cache_key(cfg, model_cfg)
-    )
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    # Persist everything: the lattice is full of sub-second debug-model
-    # compiles that the default 1 s / 4 KiB thresholds would silently skip
-    # — and a skipped entry is a fresh compile on every restart.
+    path = os.environ.get(CACHE_DIR_ENV)
+    if not path:
+        if cfg.compile_cache_dir:
+            path = os.path.join(
+                cfg.compile_cache_dir, compile_cache_key(cfg, model_cfg)
+            )
+        elif jax.default_backend() == "tpu":
+            path = DEFAULT_COMPILE_CACHE_DIR
+        else:
+            return None
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    # Persist everything: the lattice is full of sub-second compiles that
+    # the default 1 s / 4 KiB thresholds would silently skip — and a
+    # skipped entry is a fresh compile on every restart.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # jax initializes its cache object AT MOST ONCE per process, latching
     # "disabled" if any compile ran before the dir was configured (e.g. a
     # previous engine in this process, or an import-time jit). Reset to
-    # pristine so the next compile initializes against the new directory.
-    try:
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    except Exception:  # pragma: no cover — private API moved; the config
-        pass  # settings above still work for fresh processes
+    # pristine so the next compile initializes against the directory.
+    compilation_cache.reset_cache()
     _install_cache_listener()
     logger.info("persistent compilation cache: %s", path)
     return path

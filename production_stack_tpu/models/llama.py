@@ -93,8 +93,9 @@ def quantize_leaf_int4(w: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Symmetric group-wise int4 over the contraction axis (-2), the
     AWQ/GPTQ-family layout (group size 128). Returns (packed int8
     [..., in/2, out] — even contraction rows in the low nibble, odd in the
-    high — and fp32 scales [..., in/G, out]). Packed int8 (not jnp.int4):
-    s4 arrays cannot cross jit boundaries on remote-attached backends."""
+    high — and fp32 scales [..., in/G, out]). Packed int8, not jnp.int4:
+    the Pallas kernel reads the packed bytes and splits the nibble planes
+    itself (ops/int4_matmul.py)."""
     wf = w.astype(jnp.float32)
     *lead, din, dout = wf.shape
     g = _q4_group(din)
@@ -587,7 +588,9 @@ class Llama:
 
         With ``pp_size > 1`` the stacked layer axis (params and cache) is
         sharded over the ``pp`` mesh axis and composed via
-        :func:`pp_compose`; ``mesh`` must be the engine mesh.
+        :func:`pp_compose`. ``mesh`` is the engine mesh whenever it spans
+        more than one device: the Pallas attention kernels then run per
+        shard (``ops/attention.py``).
         """
         cfg = self.cfg
         B, T = tokens.shape
@@ -657,6 +660,13 @@ class Llama:
                     pallas_paged_attention_decode_write,
                 )
 
+                if mesh is not None:
+                    raise ValueError(
+                        "PST_FUSED_KV_WRITE is a single-device path: the "
+                        "fused write kernel has no per-shard wrapper, and "
+                        "a Mosaic kernel cannot be partitioned by GSPMD"
+                    )
+
                 attn, kv_all = pallas_paged_attention_decode_write(
                     q[:, 0], kv_all, block_tables, kv_lens, li,
                     k.reshape(B, cfg.kv_size), v.reshape(B, cfg.kv_size),
@@ -704,6 +714,7 @@ class Llama:
                     # pp, li is the stage-local cache index).
                     window=_layer_window(cfg, li_global),
                     softcap=cfg.attn_logit_softcap,
+                    mesh=mesh,
                 )
             attn = attn.reshape(B, T, cfg.q_size).astype(x.dtype)
             o, wo_s = _qdot(attn, lp, "wo")
@@ -942,13 +953,9 @@ def _decode_write_fused(attn_impl: str) -> bool:
     for revisiting on hardware where row-granular HBM writes are legal."""
     if os.environ.get("PST_FUSED_KV_WRITE") != "1":
         return False
-    if attn_impl == "pallas":
-        return True
-    if attn_impl == "gather":
-        return False
-    from ..ops.attention import _use_pallas
+    from ..ops.attention import resolve_attn_impl
 
-    return _use_pallas()
+    return resolve_attn_impl(attn_impl) == "pallas"
 
 
 def _rms_norm(

@@ -3,9 +3,7 @@
 The metrics PR 3 could not give the engine: everything here is fed from
 the *device-dispatch* layer (``engine/runner.py``) and the scheduler, so a
 mid-run XLA recompile, a padding-wasteful batch, or a slow startup phase
-becomes a Prometheus series instead of a mystery p99 outlier (BENCH_r05's
-120 s TTFT was exactly such a recompile, invisible to every existing
-metric).
+becomes a Prometheus series instead of a mystery p99 outlier.
 
 Compile detection is the first-call-per-bucket heuristic the static-shape
 design makes sound: the runner pads every step into a small set of bucket
@@ -36,6 +34,8 @@ from prometheus_client import (
     Histogram,
     generate_latest,
 )
+
+from ..device import device_spec
 
 ENGINE_TELEMETRY_REGISTRY = CollectorRegistry()
 
@@ -201,17 +201,6 @@ device_busy_seconds = Counter(
     registry=ENGINE_TELEMETRY_REGISTRY,
 )
 
-# Peak FLOPs per chip for the MFU denominator (public specs, bf16 MXU).
-_PEAK_FLOPS_BY_DEVICE_KIND = {
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v4": 275e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
-}
-_DEFAULT_PEAK_FLOPS = 197e12
-
 # Fresh runners must re-count compiles even when an earlier runner in the
 # same process already compiled identical bucket shapes (jit caches are
 # per-runner): each ModelRunner takes a distinct scope id into its keys.
@@ -260,7 +249,9 @@ class EngineTelemetry:
         # attribution audit (bench `cost` phase) sums request costs against.
         self._device_busy_s = 0.0
         self.param_count = 0
-        self.peak_flops = _DEFAULT_PEAK_FLOPS
+        # None until a known device_kind supplies one: the MFU gauge stays
+        # unset rather than measuring against another chip's peak.
+        self.peak_flops: Optional[float] = None
         # --no-startup-phases: the gauges stay at 0 (helm
         # servingEngineSpec.observability.startupPhases).
         self.startup_enabled = True
@@ -272,9 +263,8 @@ class EngineTelemetry:
         peak_flops: Optional[float] = None,
     ) -> None:
         self.param_count = int(param_count)
-        self.peak_flops = peak_flops or _PEAK_FLOPS_BY_DEVICE_KIND.get(
-            device_kind or "", _DEFAULT_PEAK_FLOPS
-        )
+        spec = device_spec(device_kind)
+        self.peak_flops = peak_flops or (spec.peak_bf16_flops if spec else None)
         start_time_seconds.set(time.time())
 
     def record_startup_phase(self, phase: str, seconds: float) -> None:

@@ -2,8 +2,8 @@
 
 The *write side* of deep performance introspection (docs/observability.md
 "Flight recorder"). PR 3's ``/debug/requests`` answers "what happened to
-THIS request"; the flight recorder answers the question the BENCH_r05
-120 s tail left open — "what exactly was the engine doing when that p99
+THIS request"; the flight recorder answers the question a lone tail
+outlier leaves open — "what exactly was the engine doing when that p99
 outlier happened?" Every jitted dispatch appends one fixed-size record:
 step kind, padded batch bucket, device step wall, the host gap that
 preceded it, queue depths, KV occupancy, preemption count, tenant tier

@@ -1,7 +1,7 @@
 """Tail-outlier forensics: turn a bad measured point into evidence.
 
-The missing layer ROADMAP item 5 names: BENCH_r05 carries a 120 s p99 at
-qps 0.5 and *nothing that explains it* — the flight recorder retained
+A measured point can carry a p99 orders of magnitude over its median
+and *nothing that explains it* (ROADMAP S2) — the flight recorder retains
 the snapshot naming the stalled step's bucket and queue state, but no
 path connected the measured outlier back to it. This module closes the
 loop: whenever a measured bench point (or an e2e leg) crosses its tail

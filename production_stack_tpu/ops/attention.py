@@ -33,10 +33,11 @@ Shapes:
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ..parallel.mesh import AXIS_DATA, AXIS_TENSOR
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -51,13 +52,15 @@ def window_eff(window) -> jax.Array:
     return jnp.where(win > 0, win, jnp.int32(1 << 30))
 
 
-def _use_pallas() -> bool:
-    if os.environ.get("PST_DISABLE_PALLAS"):
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+def resolve_attn_impl(impl: str) -> str:
+    """``auto`` by platform: the kernels on ``tpu``, the gather reference
+    on the CPU. Nothing else selects between them — a kernel that cannot
+    compile is an error at the call, not a detour to the reference."""
+    if impl == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "gather"
+    if impl not in ("pallas", "gather"):
+        raise ValueError(f"unknown attn_impl {impl!r} (auto|gather|pallas)")
+    return impl
 
 
 def paged_attention(
@@ -72,6 +75,7 @@ def paged_attention(
     impl: str = "auto",
     window=0,
     softcap: float = 0.0,
+    mesh=None,
 ) -> jax.Array:
     """Causal attention of ``q`` against paged KV. Returns [B, T, H, hd].
 
@@ -79,10 +83,15 @@ def paged_attention(
     index for Gemma-2's alternating local/global layers) limits each query
     to the last ``window`` positions; 0 = unlimited. ``softcap`` applies
     Gemma-style attention-logit soft-capping ``tanh(s/c)*c`` (static; 0 =
-    off)."""
-    if impl == "auto":
-        impl = "pallas" if _use_pallas() else "gather"
+    off). ``mesh``: the engine mesh when it spans more than one device —
+    the kernel then runs once per shard (see :func:`_pallas_per_shard`)."""
+    impl = resolve_attn_impl(impl)
     if impl == "pallas":
+        if mesh is not None and mesh.size > 1:
+            return _pallas_per_shard(
+                q, kv_pages, block_tables, kv_lens, q_positions, layer,
+                mesh=mesh, scale=scale, window=window, softcap=softcap,
+            )
         from .paged_attention_pallas import pallas_paged_attention
 
         return pallas_paged_attention(
@@ -92,6 +101,57 @@ def paged_attention(
     return gather_paged_attention(
         q, kv_pages, block_tables, kv_lens, q_positions, layer, scale=scale,
         window=window, softcap=softcap,
+    )
+
+
+def _pallas_per_shard(
+    q, kv_pages, block_tables, kv_lens, q_positions, layer,
+    *, mesh, scale, window, softcap,
+) -> jax.Array:
+    """The Pallas kernels on a multi-device mesh. A Mosaic kernel cannot be
+    partitioned by GSPMD at all — on the chip, lowering one under ``jit``
+    over more than one device is an error unless EVERY mesh axis is manual
+    around it — so the call is wrapped in a ``shard_map`` over all axes
+    (all that are not manual already, inside a ``pp`` stage). Attention is
+    independent per kv head and per sequence: the query heads (``H`` of
+    ``q``) and the page lanes (``KH*hd`` of ``kv_pages``) are sharded over
+    ``tp`` on the same head boundaries, rows over ``dp`` when the batch
+    divides (decode buckets do; prefill chunks arrive replicated), so each
+    shard runs the kernel on its own heads, rows and pages and nothing —
+    in particular not the cache — crosses devices."""
+    from .paged_attention_pallas import pallas_paged_attention
+
+    ctx_mesh = jax.sharding.get_abstract_mesh()  # empty under plain jit
+    axes = set(mesh.axis_names) - set(ctx_mesh.manual_axes)
+    dp = mesh.shape.get(AXIS_DATA, 1)
+    rows = (
+        AXIS_DATA
+        if AXIS_DATA in axes and dp > 1 and q.shape[0] % dp == 0
+        else None
+    )
+    heads = P(rows, None, AXIS_TENSOR, None)
+
+    def per_shard(q, kv_pages, block_tables, kv_lens, q_positions, layer,
+                  window):
+        return pallas_paged_attention(
+            q, kv_pages, block_tables, kv_lens, q_positions, layer,
+            scale=scale, window=window, softcap=softcap,
+        )
+
+    return jax.shard_map(
+        per_shard,
+        # Nested in a pp stage: its context mesh, where pp is manual already.
+        mesh=mesh if ctx_mesh.empty else None,
+        in_specs=(
+            heads, P(None, None, None, None, AXIS_TENSOR), P(rows, None),
+            P(rows), P(rows, None), P(), P(),
+        ),
+        out_specs=heads,
+        axis_names=axes,
+        check_vma=False,
+    )(
+        q, kv_pages, block_tables, kv_lens, q_positions,
+        jnp.asarray(layer, jnp.int32), jnp.asarray(window, jnp.int32),
     )
 
 

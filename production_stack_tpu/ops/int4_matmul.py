@@ -24,7 +24,14 @@ Returns [N, dout] f32.
 
 Constraints: group size 128, din % 1024 == 0, dout % 128 == 0 — all real
 checkpoint shapes (8B: 4096/14336/1024 contractions) qualify; tiny debug
-shapes fall back to the XLA path in the caller.
+shapes use the XLA dequant in the caller.
+
+Selection (:func:`use_int4_kernel`) is by platform and shape only: on
+``tpu`` every supported shape goes through the compiled kernel; on the CPU
+the XLA dequant serves, unless ``PST_FORCE_PALLAS_INTERPRET`` asks for the
+kernel interpreted (the tests do). A Mosaic kernel cannot be partitioned
+by GSPMD and this one has no per-shard wrapper, so the runner refuses
+``int4`` on a mesh of more than one device on ``tpu``.
 """
 
 from __future__ import annotations
@@ -36,6 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..device import INTERPRET_ENV, pallas_interpret
+
 GROUP = 128
 # Groups folded into one grid step: 8 groups = 512 packed rows per DMA
 # (256 KB at dout-tile 512) — deep enough to amortize per-cell overhead,
@@ -44,30 +53,22 @@ GROUPS_PER_TILE = 8
 IN_TILE = GROUP * GROUPS_PER_TILE  # original rows per grid step
 
 
-def _interpret() -> bool:
-    return bool(os.environ.get("PST_FORCE_PALLAS_INTERPRET"))
-
-
 def kernel_supports(din: int, dout: int, group: int) -> bool:
     return group == GROUP and din % IN_TILE == 0 and dout % 128 == 0
 
 
 def use_int4_kernel(packed: jax.Array, scales: jax.Array) -> bool:
-    """True when this (packed, scales) pair should go through the kernel:
-    serving-scale shapes on a TPU backend (or forced interpret). Tiny/odd
-    shapes and non-TPU backends use the XLA dequant fallback."""
-    if packed.ndim != 2 or os.environ.get("PST_DISABLE_PALLAS"):
+    """True when this (packed, scales) pair goes through the kernel: a
+    supported 2-D shape, on ``tpu`` (compiled) or on the CPU with the
+    interpret variable set. Tiny/odd shapes and MoE banks use the XLA
+    dequant."""
+    if packed.ndim != 2:
         return False
     din, dout = packed.shape[-2] * 2, packed.shape[-1]
     group = din // scales.shape[-2]
     if not kernel_supports(din, dout, group):
         return False
-    if _interpret():
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return not pallas_interpret() or bool(os.environ.get(INTERPRET_ENV))
 
 
 def _kernel(xe_ref, xo_ref, p_ref, s_ref, o_ref, *, groups: int):
@@ -158,6 +159,6 @@ def int4_matmul(
         ],
         out_specs=pl.BlockSpec((tn, tj), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N + pad, dout), jnp.float32),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(xe, xo, packed, scales)
     return out[:N] if pad else out

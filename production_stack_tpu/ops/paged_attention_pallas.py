@@ -47,13 +47,13 @@ Shapes:
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..device import pallas_interpret
 from .attention import window_eff
 
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -88,10 +88,6 @@ def _pv_dot(p, v):
         preferred_element_type=jnp.float32,
     )
     return main + fix * 0.0625
-
-
-def _interpret() -> bool:
-    return bool(os.environ.get("PST_FORCE_PALLAS_INTERPRET"))
 
 
 def _chunk_pages(bs: int, target_tokens: int) -> int:
@@ -418,7 +414,7 @@ def pallas_paged_attention_decode_write(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(tables, lens, layer_arr, win_arr, wf,
       q3,
       k_new.astype(kv_pages.dtype)[:, None],
@@ -564,7 +560,7 @@ def _decode_call(q3, kv_pages, block_tables, kv_lens, layer, window,
             dimension_semantics=("parallel",),
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(block_tables, kv_lens, layer, window, q3, kv_pages)
 
 
@@ -613,7 +609,7 @@ def _prefill_call(q, kv_pages, block_tables, kv_lens, starts, layer, window,
             # 16 MiB scoped-vmem budget; the chip has far more.
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(block_tables, kv_lens, starts, layer, window, q, kv_pages)
 
 
@@ -648,12 +644,11 @@ def pallas_paged_attention(
     # 256-row q tiles: every tile re-streams the sequence's earlier KV, so
     # at long context halving the tile count halves attention HBM traffic.
     q_tile = min(T, 256)
-    if T % q_tile:  # odd shapes: runner buckets are powers of two
-        from .attention import gather_paged_attention
-
-        return gather_paged_attention(
-            q, kv_pages, block_tables, kv_lens, q_positions, layer,
-            scale=scale, window=window, softcap=softcap,
+    if T % q_tile:
+        raise ValueError(
+            f"pallas prefill needs a chunk length divisible by its q tile "
+            f"({q_tile}), got T={T}; the runner only emits power-of-two "
+            "chunk buckets"
         )
     starts = q_positions[:, 0].astype(jnp.int32)
     return _prefill_call(

@@ -80,8 +80,7 @@ def sample_tokens_packed(
     no-logprobs/logprobs step variants, like its penalties gating)
     ``[token, chosen_logprob, top_lps(K), top_ids(K)]``.
 
-    Packing matters on remote-attached chips: one array = one host fetch.
-    Token ids ride as f32 — exact for any vocab < 2^24. Logprobs are raw
+    One packed array = one host fetch per step. Token ids ride as f32 — exact for any vocab < 2^24. Logprobs are raw
     ``log_softmax(logits)`` (pre-temperature, the OpenAI/vLLM convention);
     gating them keeps the full-vocab log_softmax + top-k out of the
     latency-critical decode path when nobody asked."""

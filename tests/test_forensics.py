@@ -7,13 +7,13 @@ evidence bundles", docs/observability.md "Forensics bundles").
   and the post-mortem path (a SIGKILLed engine's persisted snapshots).
 - Flight snapshot persistence: naming contract, bounded oldest-first
   disk eviction, restart load-back via ``?snapshots=1``.
-- Verdicts: the pass/fail claim matrix over synthetic rounds, plus the
-  real BENCH_r05 capture — its qps-0.5 120 s tail must be flagged and
+- Verdicts: the pass/fail claim matrix over synthetic rounds, plus a
+  capture of a run killed mid-sweep — its tail point must be flagged and
   its missing phases surfaced as unevaluable, never silently passed.
 - Driver mode: the budget gate admits exactly one engine bring-up when
   the wall is nearly spent, the watchdog force-emits a verdict-bearing
   partial at T−lead, and the final stdout line is parseable JSON even
-  when a SIGALRM lands mid-run (the r05 rc:124 hole).
+  when a SIGALRM lands mid-run.
 """
 
 import json
@@ -434,12 +434,28 @@ def test_verdicts_missing_phases_are_unevaluable_not_passed():
     assert v2["ok"] is False and v2["n_unevaluable"] == len(V.CLAIMS)
 
 
+def _killed_capture(n: int) -> dict:
+    """A synthetic driver capture of a run killed mid-sweep (rc 124,
+    nothing parsed): only the per-point lines in the stderr tail survive,
+    one of them with a tail two orders of magnitude over its median."""
+    points = [(0.1, 250.0, 380.0), (0.3, 260.0, 400.0),
+              (0.5, 300.0, 90_000.0), (0.7, 310.0, 450.0)]
+    tail = "".join(
+        f"[bench] llama-3-8b: qps {q}: {{'qps': {q}, 'n_requests': 24, "
+        f"'p50_ttft_ms': {p50}, 'p99_ttft_ms': {p99}}}\n"
+        for q, p50, p99 in points
+    )
+    return {"n": n, "rc": 124, "parsed": None, "tail": tail}
+
+
 @pytest.mark.fast
-def test_verdicts_flag_r05_qps_half_outlier():
-    """The real wreck: r05's capture (rc 124, parsed null) must recover
-    its sweep from the tail's dict-literal lines and flag the qps-0.5
-    120 s p99 as the tail_shape failure."""
-    parsed, meta = V.load_round(os.path.join(REPO_ROOT, "BENCH_r05.json"))
+def test_verdicts_flag_killed_run_tail_outlier(tmp_path):
+    """A capture of a killed run (rc 124, parsed null) must recover its
+    sweep from the tail's dict-literal lines and flag the qps-0.5 point's
+    p99 as the tail_shape failure."""
+    path = tmp_path / "BENCH_r05.json"
+    path.write_text(json.dumps(_killed_capture(5)))
+    parsed, meta = V.load_round(str(path))
     assert parsed is not None
     assert meta["rc"] == 124
     assert meta["recovered_from"] == "tail_sweep_lines"
@@ -448,9 +464,8 @@ def test_verdicts_flag_r05_qps_half_outlier():
     tail = next(c for c in v["claims"] if c["claim"] == "tail_shape")
     assert tail["status"] == "fail"
     outlier_qps = [o["qps"] for o in tail["observed"]]
-    assert 0.5 in outlier_qps
-    worst = next(o for o in tail["observed"] if o["qps"] == 0.5)
-    assert worst["p99_ttft_ms"] > 100_000  # the 120 s point, by name
+    assert outlier_qps == [0.5]
+    assert tail["observed"][0]["p99_ttft_ms"] == 90_000.0
     # The phases the truncation ate are surfaced, not silently passed.
     assert v["n_unevaluable"] > 0
 
@@ -473,17 +488,22 @@ def test_recover_from_tail_prefers_emitted_json():
 
 
 @pytest.mark.fast
-def test_verdicts_trajectory_across_rounds():
-    paths = V.round_files(REPO_ROOT)
+def test_verdicts_trajectory_across_rounds(tmp_path):
+    complete = {"backend": "tpu", "flagship": {
+        "p50_ttft_ms": 200.0, "p99_ttft_ms": 300.0, "sweep": []}}
+    (tmp_path / "BENCH_r01.json").write_text(
+        json.dumps({"n": 1, "rc": 0, "parsed": complete, "tail": ""}))
+    (tmp_path / "BENCH_r02.json").write_text(json.dumps(_killed_capture(2)))
+    (tmp_path / "BENCH_notes.json").write_text("{}")  # not a round file
+    paths = V.round_files(str(tmp_path))
     assert [os.path.basename(p) for p in paths] == [
-        f"BENCH_r{i:02d}.json" for i in range(1, 6)
+        "BENCH_r01.json", "BENCH_r02.json"
     ]
     rows = V.trajectory(paths)
-    assert [r["round"] for r in rows] == [
-        f"BENCH_r{i:02d}.json" for i in range(1, 6)
-    ]
-    r05 = rows[-1]
-    assert r05["rc"] == 124 and r05["recovered_from"] == "tail_sweep_lines"
+    assert [r["round"] for r in rows] == ["BENCH_r01.json", "BENCH_r02.json"]
+    assert rows[0]["rc"] == 0 and rows[0]["parsed"] is True
+    assert rows[1]["rc"] == 124
+    assert rows[1]["recovered_from"] == "tail_sweep_lines"
 
 
 # ---------------------------------------------------------------------------
@@ -498,8 +518,8 @@ def test_phase_estimate_prices_next_bringup(monkeypatch):
     monkeypatch.setattr(
         bench_engine, "_PHASE_WALLS", {"flagship": 148.7}
     )
-    # 0.6 x the observed 148.7 s bring-up: the r05 second bring-up
-    # (started with less than that left) would never begin.
+    # 0.6 x the observed bring-up: a second bring-up started with less
+    # than that left never begins.
     assert bench_engine.phase_estimate("warm_restart", 30.0) == \
         pytest.approx(89.22)
     monkeypatch.setattr(bench_engine, "_PHASE_WALLS", {"flagship": 10.0})
