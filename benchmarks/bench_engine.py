@@ -340,9 +340,6 @@ def run_model_phase(
             })
     measure_wall = time.time() - t_meas
 
-    # Per-phase isolation: ENGINE_TELEMETRY is process-global and earlier
-    # phases may have landed samples in the same batch buckets.
-    ENGINE_TELEMETRY.reset_host_gap()
     if budget_exhausted():
         log(f"{model}: skipping decode probe "
             f"({budget_remaining():.0f}s budget left)")
@@ -352,23 +349,10 @@ def run_model_phase(
             max_tokens=decode_probe_tokens, pipelined=pipelined_probe
         )
     # Roofline verdict for the saturated probe: theoretical vs achieved
-    # HBM GB/s and tok/s/chip at the probe's batch/context shape. The
-    # host-gap summary beside it is the direct measure of the serial host
-    # time the overlapped pipeline removed (acceptance: p50 under 10% of
-    # the decode-step p50 at the probe batch).
+    # HBM GB/s and tok/s/chip at the probe's batch/context shape.
     roofline = roofline_table(
         engine, decode_rate, batch=n_users, ctx_tokens=sys_len + hist_len
     )
-    host_gap = {
-        bucket: {
-            "count": int(s["count"]),
-            "p50_ms": round(s["p50"] * 1e3, 3),
-            "mean_ms": round(s["mean"] * 1e3, 3),
-        }
-        for bucket, s in ENGINE_TELEMETRY.host_gap_summary().items()
-    }
-    if host_gap:
-        log(f"{model}: host gap per decode dispatch: {host_gap}")
     floor_end = env_probe()
     n_params = engine.runner.param_count
     # A fully budget-truncated sweep has no measured points; the phase
@@ -406,7 +390,6 @@ def run_model_phase(
         "decode_tok_per_s_chip": round(decode_rate, 1) if decode_rate else None,
         "decode_mfu": mfu(n_params, decode_rate),
         "roofline": roofline,
-        "host_gap_ms": host_gap,
         "prefix_cache_hit_rate": round(engine.allocator.hit_rate, 3),
     }
     stats = engine.stats()

@@ -430,6 +430,14 @@ def fleet_dashboard():
         ('sum(rate({__name__="pst:kv_transfer_fallbacks_total"}[5m]))',
          "engine fallbacks/s"),
     ], 16, 132))
+    # Row 19 — the engine's step loop by phase (docs/observability.md
+    # "Profiling"): where the host's share of a step goes, on the same
+    # names a profile capture shows as pst.* spans.
+    p.append(panel("Step loop: mean wall per step, by phase", [
+        ('sum(rate(pst_engine_step_phase_seconds_sum[2m])) by (phase, kind) '
+         '/ clamp_min(sum(rate(pst_engine_step_phase_seconds_count[2m])) '
+         'by (phase, kind), 1e-9)', "{{phase}} {{kind}}"),
+    ], 0, 139, w=16, unit="s"))
     return dashboard("pst-fleet", "production-stack-tpu / Fleet", p)
 
 

@@ -20,6 +20,7 @@ import uuid
 from typing import AsyncIterator, Dict, List, Optional, Sequence as Seq
 
 from ..logging_utils import init_logger
+from ..obs.engine_telemetry import ENGINE_TELEMETRY
 from .config import EngineConfig
 from .engine import LLMEngine, RequestOutput
 from .sequence import SamplingParams
@@ -274,16 +275,28 @@ class AsyncLLMEngine:
                 self.warmup_error = str(e)
             self._warming = False
             self._work.set()
+        # Every moment of this loop lies in one phase (obs/engine_telemetry
+        # ``phase``): intake, no_work, or the engine's own step.
+        outputs: List[RequestOutput] = []
         while not self._stop:
-            self._drain_mailboxes()
-            if self._sleeping or not self.engine.has_work():
-                self._work.wait(timeout=0.05)
-                self._work.clear()
+            with ENGINE_TELEMETRY.phase("intake"):
+                self._publish(outputs)
+                outputs = []
+                self._drain_mailboxes()
+                stepping = not self._sleeping and self.engine.has_work()
+                if stepping:
+                    self._lock.acquire()
+            if not stepping:
+                with ENGINE_TELEMETRY.phase("no_work"):
+                    self._work.wait(timeout=0.05)
+                    self._work.clear()
                 self.last_step_time = time.time()
                 continue
             try:
-                with self._lock:
+                try:
                     outputs = self.engine.step()
+                finally:
+                    self._lock.release()
                 self.last_step_time = time.time()
             except Exception as e:  # noqa: BLE001 — surface via /health
                 logger.exception("engine step failed")
@@ -309,9 +322,11 @@ class AsyncLLMEngine:
                     # truncated streams) and new submissions are refused.
                     self.engine.abort_all_requests()
                 self._sentinel_all()
-                continue
-            if outputs and self._loop is not None:
-                self._loop.call_soon_threadsafe(self._dispatch, outputs)
+        self._publish(outputs)
+
+    def _publish(self, outputs: List[RequestOutput]) -> None:
+        if outputs and self._loop is not None:
+            self._loop.call_soon_threadsafe(self._dispatch, outputs)
 
     def _dispatch(self, outputs: List[RequestOutput]) -> None:
         for out in outputs:

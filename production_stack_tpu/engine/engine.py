@@ -16,6 +16,7 @@ import time
 from typing import Dict, List, Optional, Sequence as Seq, Union
 
 import numpy as np
+import psutil
 
 from ..kvcache.hashing import CHUNK_TOKENS
 from ..logging_utils import init_logger
@@ -68,6 +69,11 @@ class RequestOutput:
 class LLMEngine:
     def __init__(self, cfg: EngineConfig, mesh=None):
         t_init = time.perf_counter()
+        # Startup decomposition, phase 0: process start to this line, which
+        # is the interpreter and the imports (jax, the server's own).
+        ENGINE_TELEMETRY.record_startup_phase(
+            "imports", time.time() - psutil.Process().create_time()
+        )
         self.cfg = cfg
         self.model_cfg = get_model_config(cfg.model)
         # Before the runner wires any jit: executables compiled earlier
@@ -76,8 +82,10 @@ class LLMEngine:
 
         compile_cache_path = configure_compile_cache(cfg, self.model_cfg)
         tok_spec = cfg.tokenizer or (cfg.model if os.path.isdir(cfg.model) else None)
+        t_tok = time.perf_counter()
         self.tokenizer = get_tokenizer(tok_spec, self.model_cfg.vocab_size)
         t_runner = time.perf_counter()
+        ENGINE_TELEMETRY.record_startup_phase("tokenizer", t_runner - t_tok)
         self.runner = ModelRunner(cfg, self.model_cfg, mesh)
         t_runner_s = time.perf_counter() - t_runner
         self.runner.device_info["compile_cache_dir"] = compile_cache_path
@@ -239,13 +247,17 @@ class LLMEngine:
         self.num_preempted_total = 0
         self.prompt_tokens_total = 0
         self.generation_tokens_total = 0
-        # Startup decomposition, phase 3: everything around the runner —
-        # tokenizer, allocator, swapper, scheduler, LoRA manager
-        # (pst_engine_startup_seconds{phase="warmup"}; the runner records
-        # load and shard itself).
+        # Startup decomposition, phase 3: everything around the runner and
+        # the tokenizer — compile cache, allocator, swapper, scheduler, LoRA
+        # manager (pst_engine_startup_seconds{phase="warmup"}; the runner
+        # records load and shard itself).
+        self._constructed = time.perf_counter()
         ENGINE_TELEMETRY.record_startup_phase(
-            "warmup", time.perf_counter() - t_init - t_runner_s
+            "warmup",
+            self._constructed - t_init - t_runner_s - (t_runner - t_tok),
         )
+        self._precompile_s = 0.0
+        self._serving = False
 
     @property
     def model_name(self) -> str:
@@ -305,11 +317,23 @@ class LLMEngine:
         summary = Precompiler(
             self.runner, self.cfg, mode=mode, bucket_budget=bucket_budget
         ).run()
-        ENGINE_TELEMETRY.record_startup_phase(
-            "precompile", time.perf_counter() - t0
-        )
+        self._precompile_s = time.perf_counter() - t0
+        ENGINE_TELEMETRY.record_startup_phase("precompile", self._precompile_s)
         self.warmup_summary = summary
         return summary
+
+    def note_ready(self) -> None:
+        """``/ready`` answered 200: the first time, close the startup
+        decomposition with ``phase="serve"``, constructor done to here less
+        the precompile pass (the HTTP server coming up, the step thread
+        starting, the prober's interval). The phases then sum to process
+        start to first ready."""
+        if not self._serving:
+            self._serving = True
+            ENGINE_TELEMETRY.record_startup_phase(
+                "serve",
+                time.perf_counter() - self._constructed - self._precompile_s,
+            )
 
     # ------------------------------------------------------------------
     # Requests
@@ -504,38 +528,59 @@ class LLMEngine:
         return cap
 
     def step(self) -> List[RequestOutput]:
-        outputs = self._step_impl()
-        # A compile that landed inside this step delayed every request the
-        # step served: attach the events so the HTTP layer can surface them
-        # on the victim requests' traces. Compiles in output-less steps
-        # (intermediate prefill chunks dispatch without emitting) are held
-        # for the next emitting step — the same requests were waiting on
-        # them.
-        events = self._pending_compile_events + ENGINE_TELEMETRY.drain_compile_events()
-        if outputs:
-            if events:
-                for out in outputs:
-                    out.compile_events = list(events)
-            self._pending_compile_events = []
-        else:
-            self._pending_compile_events = events[-8:]  # bounded
-        return outputs
+        # The whole step is one phase of the loop (obs/engine_telemetry
+        # ``phase``); schedule, batch_build, launch, wait and postprocess
+        # are opened inside it, here and in the runner.
+        with ENGINE_TELEMETRY.phase("step"):
+            outputs = self._step_impl()
+            if self._retiring_slots:
+                with ENGINE_TELEMETRY.phase("schedule"):
+                    self._sweep_retiring_slots()
+            # A compile that landed inside this step delayed every request
+            # the step served: attach the events so the HTTP layer can
+            # surface them on the victim requests' traces. Compiles in
+            # output-less steps (intermediate prefill chunks dispatch
+            # without emitting) are held for the next emitting step — the
+            # same requests were waiting on them.
+            events = (
+                self._pending_compile_events
+                + ENGINE_TELEMETRY.drain_compile_events()
+            )
+            if outputs:
+                if events:
+                    for out in outputs:
+                        out.compile_events = list(events)
+                self._pending_compile_events = []
+            else:
+                self._pending_compile_events = events[-8:]  # bounded
+            return outputs
+
+    def _schedule(
+        self, hint: Optional[int], outputs: List[RequestOutput],
+        locked: frozenset = frozenset(),
+    ):
+        """One scheduling pass and its bookkeeping, as the ``schedule``
+        phase; deadline sheds are appended to ``outputs``."""
+        with ENGINE_TELEMETRY.phase("schedule"):
+            sched = self.scheduler.schedule(locked=locked, n_decode=hint)
+            self.num_preempted_total += len(sched.preempted)
+            outputs += self._finish_expired(sched.expired)
+        return sched
 
     def _step_impl(self) -> List[RequestOutput]:
+        phase = ENGINE_TELEMETRY.phase
         outputs: List[RequestOutput] = []
         hint = self._decode_depth_hint()
         if self.runner.burst_in_flight:
             locked = frozenset(s.request_id for s in self._burst_seqs)
-            sched = self.scheduler.schedule(locked=locked, n_decode=hint)
-            self.num_preempted_total += len(sched.preempted)
-            outputs += self._finish_expired(sched.expired)
+            sched = self._schedule(hint, outputs, locked)
             if self._can_continue_burst(sched):
                 self.pipelined_bursts_total += 1
                 if self._burst_n > self.cfg.num_decode_steps:
                     self.adaptive_deep_bursts_total += 1
                 rows = self.runner.burst_continue(self._burst_seqs)
-                outputs += self._process_burst_rows(rows)
-                self._sweep_retiring_slots()
+                with phase("postprocess", "decode"):
+                    outputs += self._process_burst_rows(rows)
                 return outputs
             # A new arrival's prefill can slip in BEHIND the in-flight
             # burst: dispatch it first (the device serializes the two), then
@@ -547,22 +592,18 @@ class LLMEngine:
             if sched.prefills and not sched.blocked_on_locked:
                 prefill_handle = self.runner.prefill_dispatch(sched.prefills)
             rows = self.runner.burst_drain()
-            outputs += self._process_burst_rows(rows)
-            self._release_burst_deferred()
+            with phase("postprocess", "decode"):
+                outputs += self._process_burst_rows(rows)
+                self._release_burst_deferred()
             if prefill_handle is not None:
                 prows = self.runner.prefill_fetch(
                     prefill_handle, len(sched.prefills)
                 )
-                outputs += self._process_prefill_rows(sched.prefills, prows)
-                self._sweep_retiring_slots()
+                with phase("postprocess", "prefill"):
+                    outputs += self._process_prefill_rows(sched.prefills, prows)
                 return outputs
-            sched = self.scheduler.schedule(n_decode=hint)
-        else:
-            sched = self.scheduler.schedule(n_decode=hint)
-        self.num_preempted_total += len(sched.preempted)
-        outputs += self._finish_expired(sched.expired)
+        sched = self._schedule(hint, outputs)
         if sched.is_empty:
-            self._sweep_retiring_slots()
             return outputs
         if sched.prefills:
             # Intermediate chunks sample nothing anyone reads: dispatch
@@ -576,10 +617,11 @@ class LLMEngine:
             )
             if any_completes:
                 rows = self.runner.execute_prefill_batch(sched.prefills)
-                outputs += self._process_prefill_rows(sched.prefills, rows)
             else:
                 self.runner.execute_prefill_batch_nofetch(sched.prefills)
-                outputs += self._process_prefill_rows(sched.prefills, None)
+                rows = None
+            with phase("postprocess", "prefill"):
+                outputs += self._process_prefill_rows(sched.prefills, rows)
         elif (
             drafts := self._spec_drafts(sched.decodes, sched.n_decode_steps)
         ) is not None:
@@ -606,16 +648,16 @@ class LLMEngine:
             bursts = self.runner.execute_decode_multi(
                 sched.decodes, sched.n_decode_steps
             )
-            for seq, rows in zip(sched.decodes, bursts):
-                for row in rows:
-                    seq.num_computed_tokens += 1
-                    self._commit(seq)
-                    out = self._append_token(seq, int(row[0]), lp_row=row)
-                    if out is not None:
-                        outputs.append(out)
-                    if seq.is_finished:
-                        break  # trim speculative tail of the burst
-        self._sweep_retiring_slots()
+            with phase("postprocess", "decode"):
+                for seq, rows in zip(sched.decodes, bursts):
+                    for row in rows:
+                        seq.num_computed_tokens += 1
+                        self._commit(seq)
+                        out = self._append_token(seq, int(row[0]), lp_row=row)
+                        if out is not None:
+                            outputs.append(out)
+                        if seq.is_finished:
+                            break  # trim speculative tail of the burst
         return outputs
 
     # -- speculative decoding (n-gram prompt lookup; engine/spec.py) ----
@@ -636,37 +678,38 @@ class LLMEngine:
         K = self.cfg.speculative_ngram
         if not K or self.cfg.async_decode or not decodes:
             return None
-        from .spec import propose_ngram
+        with ENGINE_TELEMETRY.phase("batch_build", "spec_verify"):
+            from .spec import propose_ngram
 
-        for s in decodes:
-            if s.sampling.has_penalties or s.sampling.logprobs is not None:
+            for s in decodes:
+                if s.sampling.has_penalties or s.sampling.logprobs is not None:
+                    return None
+            drafts = np.zeros((len(decodes), K), np.int32)
+            lens = np.zeros(len(decodes), np.int32)
+            for i, s in enumerate(decodes):
+                if not s.sampling.greedy or s.sampling.guided_choice:
+                    continue  # rides along; sampled/masked at position 0 only
+                if s.num_tokens + K > self.cfg.max_model_len:
+                    continue  # verify writes would run past the last page
+                d = propose_ngram(
+                    self._spec_token_arr(s), K,
+                    self.cfg.ngram_min, self.cfg.ngram_max,
+                    lookback=self.cfg.ngram_lookback,
+                )
+                if d:
+                    drafts[i, : len(d)] = d
+                    lens[i] = len(d)
+            # A verify pass costs ~one device round trip; worth it only when
+            # enough rows carry drafts — AND when its best case (K+1 tokens per
+            # draft row, 1 per other row) beats the n-step burst it replaces
+            # (num_decode_steps>1 exists for dispatch-latency-bound setups; a
+            # verify pass that yields fewer tokens per round trip would regress
+            # exactly there).
+            B = len(decodes)
+            hits = int(np.count_nonzero(lens))
+            if hits * 2 < B or hits * (K + 1) + (B - hits) < n_burst * B:
                 return None
-        drafts = np.zeros((len(decodes), K), np.int32)
-        lens = np.zeros(len(decodes), np.int32)
-        for i, s in enumerate(decodes):
-            if not s.sampling.greedy or s.sampling.guided_choice:
-                continue  # rides along; sampled/masked at position 0 only
-            if s.num_tokens + K > self.cfg.max_model_len:
-                continue  # verify writes would run past the last page
-            d = propose_ngram(
-                self._spec_token_arr(s), K,
-                self.cfg.ngram_min, self.cfg.ngram_max,
-                lookback=self.cfg.ngram_lookback,
-            )
-            if d:
-                drafts[i, : len(d)] = d
-                lens[i] = len(d)
-        # A verify pass costs ~one device round trip; worth it only when
-        # enough rows carry drafts — AND when its best case (K+1 tokens per
-        # draft row, 1 per other row) beats the n-step burst it replaces
-        # (num_decode_steps>1 exists for dispatch-latency-bound setups; a
-        # verify pass that yields fewer tokens per round trip would regress
-        # exactly there).
-        B = len(decodes)
-        hits = int(np.count_nonzero(lens))
-        if hits * 2 < B or hits * (K + 1) + (B - hits) < n_burst * B:
-            return None
-        return drafts, lens
+            return drafts, lens
 
     @staticmethod
     def _spec_token_arr(s) -> "np.ndarray":
@@ -697,29 +740,30 @@ class LLMEngine:
 
         drafts, lens = spec
         rows, sampled0 = self.runner.execute_spec_verify(decodes, drafts)
-        outputs: List[RequestOutput] = []
-        for i, seq in enumerate(decodes):
-            if lens[i] == 0:
-                # Draftless (or sampled) row: position 0 went through the
-                # full sampling pipeline — exactly one plain decode step.
-                emitted = [int(sampled0[i])]
-            else:
-                draft = [int(t) for t in drafts[i][: lens[i]]]
-                a = count_accepted(draft, rows[i])
-                # Clamp: never emit past max_model_len.
-                a = min(a, self.cfg.max_model_len - seq.num_tokens - 1)
-                self.spec_proposed_total += len(draft)
-                self.spec_accepted_total += a
-                emitted = draft[:a] + [int(rows[i][a])]
-            for tok in emitted:
-                seq.num_computed_tokens += 1
-                self._commit(seq)
-                out = self._append_token(seq, tok)
-                if out is not None:
-                    outputs.append(out)
-                if seq.is_finished:
-                    break
-        return outputs
+        with ENGINE_TELEMETRY.phase("postprocess", "spec_verify"):
+            outputs: List[RequestOutput] = []
+            for i, seq in enumerate(decodes):
+                if lens[i] == 0:
+                    # Draftless (or sampled) row: position 0 went through the
+                    # full sampling pipeline — exactly one plain decode step.
+                    emitted = [int(sampled0[i])]
+                else:
+                    draft = [int(t) for t in drafts[i][: lens[i]]]
+                    a = count_accepted(draft, rows[i])
+                    # Clamp: never emit past max_model_len.
+                    a = min(a, self.cfg.max_model_len - seq.num_tokens - 1)
+                    self.spec_proposed_total += len(draft)
+                    self.spec_accepted_total += a
+                    emitted = draft[:a] + [int(rows[i][a])]
+                for tok in emitted:
+                    seq.num_computed_tokens += 1
+                    self._commit(seq)
+                    out = self._append_token(seq, tok)
+                    if out is not None:
+                        outputs.append(out)
+                    if seq.is_finished:
+                        break
+            return outputs
 
     def _finish_expired(self, expired) -> List[RequestOutput]:
         """Surface scheduler deadline sheds to their waiting clients: the

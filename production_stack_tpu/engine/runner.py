@@ -73,18 +73,20 @@ def _pow2(n: int, cap: Optional[int] = None) -> int:
 _MIN_TABLE_BUCKET = 64
 
 
-def _fetch(arr) -> np.ndarray:
+def _fetch(arr, kind: str = "") -> np.ndarray:
     """Device→host fetch: start the async copy, poll readiness, then read
     through ``jax.device_get`` (which returns the landed copy). The poll
     keeps the engine's step thread off a blocking transfer call so other
     Python threads run while the device finishes; the 0.3 ms interval was
     chosen on an attachment that no longer exists and has not been timed
-    on a directly attached chip (ROADMAP D3)."""
-    arr.copy_to_host_async()
-    while not arr.is_ready():
-        # pstlint: disable=async-blocking(0.3 ms device-readiness poll on the engine's dedicated step thread, never on an event loop)
-        time.sleep(0.0003)
-    return np.asarray(jax.device_get(arr))
+    on a directly attached chip (ROADMAP D3). The whole of it is the
+    ``wait`` phase of the step of ``kind`` that asked."""
+    with ENGINE_TELEMETRY.phase("wait", kind):
+        arr.copy_to_host_async()
+        while not arr.is_ready():
+            # pstlint: disable=async-blocking(0.3 ms device-readiness poll on the engine's dedicated step thread, never on an event loop)
+            time.sleep(0.0003)
+        return np.asarray(jax.device_get(arr))
 
 
 def _seed_for(seq: Sequence) -> int:
@@ -310,20 +312,32 @@ class ModelRunner:
         # Sampled tokens come back replicated: on a multi-host mesh the
         # primary must be able to device_get them (only addressable shards
         # are fetchable), and an all-gather of [B] int32 is free.
-        # pstlint: jit-family=decode,prefill
-        self._step = jax.jit(
-            step,
+        # One body, jitted under two names: the device trace tells a decode
+        # program (``jit_pst_decode_step``) from a prefill program by its
+        # module name alone. Warm-up and live traffic share these objects.
+        def pst_decode_step(params, kv_cache, batch, want_lp, greedy):
+            return step(params, kv_cache, batch, want_lp, greedy)
+
+        def pst_prefill_step(params, kv_cache, batch, want_lp, greedy):
+            return step(params, kv_cache, batch, want_lp, greedy)
+
+        step_jit = dict(
             static_argnums=(3, 4),
             donate_argnums=(1,),
             out_shardings=(self._repl, cache_sh),
         )
+        # pstlint: jit-family=decode
+        decode_step = jax.jit(pst_decode_step, **step_jit)
+        # pstlint: jit-family=prefill
+        prefill_step = jax.jit(pst_prefill_step, **step_jit)
+        self._step = {"decode": decode_step, "prefill": prefill_step}
 
         bs = cfg.block_size
         drop_slot = self.num_blocks * bs
 
-        def multi_step(params, kv_cache, batch, tokens, positions, seed_off,
-                       pen_counts, n_steps: int, want_lp: bool, greedy: bool,
-                       with_pen: bool):
+        def pst_decode_burst(params, kv_cache, batch, tokens, positions,
+                             seed_off, pen_counts, n_steps: int,
+                             want_lp: bool, greedy: bool, with_pen: bool):
             """Decode ``n_steps`` tokens per sequence in one compiled call.
 
             The inter-token dependency (sampled token feeds the next forward)
@@ -410,7 +424,7 @@ class ModelRunner:
 
         # pstlint: jit-family=decode_burst
         self._multi_step = jax.jit(
-            multi_step,
+            pst_decode_burst,
             static_argnums=(7, 8, 9, 10),
             donate_argnums=(1,),
             out_shardings=(
@@ -836,6 +850,21 @@ class ModelRunner:
         shapes = tuple(sorted((k, np.shape(v)) for k, v in batch.items()))
         return (self._tel_scope, kind, shapes, extras)
 
+    def _step_info(
+        self, kind: str, bucket: str, seqs: List[Sequence],
+        batch: Dict[str, np.ndarray], new_tokens: int, kv_ahead: int = 0,
+    ) -> None:
+        """Tell the trace and the open step phase what this step is:
+        ``kv_tokens`` sums ``kv_lens`` over the real rows after this step's
+        tokens (a burst writes ``kv_ahead`` more per row than its batch
+        says), ``kv_pages`` the pages those rows hold."""
+        n = len(seqs)
+        ENGINE_TELEMETRY.step_info(
+            kind, bucket=bucket, rows=n, new_tokens=new_tokens,
+            kv_tokens=int(batch["kv_lens"][:n].sum()) + n * kv_ahead,
+            kv_pages=sum(len(s.block_ids) for s in seqs),
+        )
+
     # -- per-request cost attribution ------------------------------------
 
     def _charge_decode(self, seqs: List[Sequence], seconds: float) -> None:
@@ -900,21 +929,24 @@ class ModelRunner:
     def execute_decode(self, seqs: List[Sequence]) -> np.ndarray:
         """One decode step per sequence. Returns packed sample rows
         [len(seqs), 1 or PACKED_WIDTH] (token [+ logprobs]; ops/sampling.py)."""
-        batch = self._decode_batch(seqs)
-        want_lp, greedy = self._want_lp(seqs), self._all_greedy(seqs)
-        key = self._tel_key("decode", batch, (want_lp, greedy))
-        Bb = batch["kv_lens"].shape[0]
+        with ENGINE_TELEMETRY.phase("batch_build", "decode"):
+            batch = self._decode_batch(seqs)
+            want_lp, greedy = self._want_lp(seqs), self._all_greedy(seqs)
+            key = self._tel_key("decode", batch, (want_lp, greedy))
+            Bb = batch["kv_lens"].shape[0]
+            self._step_info("decode", f"b{Bb}", seqs, batch, len(seqs))
         t0 = time.perf_counter()
         self._host_gap_mark(f"b{Bb}", t0, seqs)
-        rows = self._run(batch, want_lp, greedy)
+        rows = self._run(batch, want_lp, greedy, "decode")
         self._host_gap_arm()
         dt = time.perf_counter() - t0
-        self._charge_decode(seqs, dt)
-        ENGINE_TELEMETRY.record_dispatch(
-            "decode", key, dt,
-            batch_bucket=f"b{Bb}", tokens=len(seqs),
-            fill_ratio=len(seqs) / Bb,
-        )
+        with ENGINE_TELEMETRY.phase("postprocess", "decode"):
+            self._charge_decode(seqs, dt)
+            ENGINE_TELEMETRY.record_dispatch(
+                "decode", key, dt,
+                batch_bucket=f"b{Bb}", tokens=len(seqs),
+                fill_ratio=len(seqs) / Bb,
+            )
         return rows[: len(seqs)]
 
     def execute_decode_multi(self, seqs: List[Sequence], n_steps: int) -> np.ndarray:
@@ -923,20 +955,26 @@ class ModelRunner:
         at stops)."""
         if n_steps == 1:
             return self.execute_decode(seqs)[:, None]
-        batch = self._decode_batch(seqs, multi=True)
-        # Guided-choice masks are rebuilt per token host-side; the scan body
-        # cannot apply them. The scheduler forces n=1 for guided rows — fail
-        # loudly if that invariant ever breaks instead of dropping the mask
-        # (RuntimeError, not assert: must survive `python -O`).
-        if "allowed_ids" in batch:
-            raise RuntimeError(
-                "guided-choice rows reached a multi-step decode burst"
+        with ENGINE_TELEMETRY.phase("batch_build", "decode"):
+            batch = self._decode_batch(seqs, multi=True)
+            # Guided-choice masks are rebuilt per token host-side; the scan
+            # body cannot apply them. The scheduler forces n=1 for guided
+            # rows — fail loudly if that invariant ever breaks instead of
+            # dropping the mask (RuntimeError, not assert: must survive
+            # `python -O`).
+            if "allowed_ids" in batch:
+                raise RuntimeError(
+                    "guided-choice rows reached a multi-step decode burst"
+                )
+            counts = self._penalty_counts_for(seqs, batch)
+            want_lp = self._want_lp(seqs)
+            greedy = self._all_greedy(seqs)
+            key = self._tel_key("decode", batch, (n_steps, want_lp, greedy))
+            Bb = batch["kv_lens"].shape[0]
+            self._step_info(
+                "decode", f"b{Bb}xn{n_steps}", seqs, batch,
+                len(seqs) * n_steps, n_steps - 1,
             )
-        counts = self._penalty_counts_for(seqs, batch)
-        want_lp = self._want_lp(seqs)
-        greedy = self._all_greedy(seqs)
-        key = self._tel_key("decode", batch, (n_steps, want_lp, greedy))
-        Bb = batch["kv_lens"].shape[0]
         t0 = time.perf_counter()
         self._host_gap_mark(f"b{Bb}xn{n_steps}", t0, seqs)
         with self._device_lock:
@@ -949,12 +987,13 @@ class ModelRunner:
             )
         self._host_gap_arm()
         dt = time.perf_counter() - t0
-        self._charge_decode(seqs, dt)
-        ENGINE_TELEMETRY.record_dispatch(
-            "decode", key, dt,
-            batch_bucket=f"b{Bb}xn{n_steps}", tokens=len(seqs) * n_steps,
-            fill_ratio=len(seqs) / Bb,
-        )
+        with ENGINE_TELEMETRY.phase("postprocess", "decode"):
+            self._charge_decode(seqs, dt)
+            ENGINE_TELEMETRY.record_dispatch(
+                "decode", key, dt,
+                batch_bucket=f"b{Bb}xn{n_steps}", tokens=len(seqs) * n_steps,
+                fill_ratio=len(seqs) / Bb,
+            )
         return rows[: len(seqs)]
 
     def _penalty_counts_for(
@@ -1007,17 +1046,18 @@ class ModelRunner:
         want_lp: bool = False,
         greedy: bool = False,
     ) -> np.ndarray:
-        dev = self._put_batch(batch)
-        seed0 = jax.device_put(np.zeros((), np.uint32), self._repl)
-        cdev = jax.device_put(counts, self._repl)
-        tokens = dev.pop("tokens")
-        positions = dev.pop("positions")
-        with_pen = "penalty_seen" in batch
-        toks, _, _, _, _, self.kv_cache = self._multi_step(
-            self.params, self.kv_cache, dev, tokens, positions, seed0,
-            cdev, n_steps, want_lp, greedy, with_pen,
-        )
-        return _fetch(toks)
+        with ENGINE_TELEMETRY.phase("launch", "decode"):
+            dev = self._put_batch(batch)
+            seed0 = jax.device_put(np.zeros((), np.uint32), self._repl)
+            cdev = jax.device_put(counts, self._repl)
+            tokens = dev.pop("tokens")
+            positions = dev.pop("positions")
+            with_pen = "penalty_seen" in batch
+            toks, _, _, _, _, self.kv_cache = self._multi_step(
+                self.params, self.kv_cache, dev, tokens, positions, seed0,
+                cdev, n_steps, want_lp, greedy, with_pen,
+            )
+        return _fetch(toks, "decode")
 
     # ------------------------------------------------------------------
     # Pipelined decode bursts: one burst always in flight; its token fetch
@@ -1033,17 +1073,21 @@ class ModelRunner:
         """Dispatch the first burst of a pipeline (async; nothing fetched)."""
         if self._burst is not None:
             raise RuntimeError("burst already in flight (drain first)")
-        batch = self._decode_batch(seqs, multi=True)
-        if "allowed_ids" in batch:
-            raise RuntimeError(
-                "guided-choice rows reached a pipelined decode burst"
+        with ENGINE_TELEMETRY.phase("batch_build", "decode"):
+            batch = self._decode_batch(seqs, multi=True)
+            if "allowed_ids" in batch:
+                raise RuntimeError(
+                    "guided-choice rows reached a pipelined decode burst"
+                )
+            counts = self._penalty_counts_for(seqs, batch)
+            want_lp = self._want_lp(seqs)
+            greedy = self._all_greedy(seqs)
+            key = self._tel_key("decode", batch, (n_steps, want_lp, greedy))
+            Bb = batch["kv_lens"].shape[0]
+            bucket = f"b{Bb}xn{n_steps}"
+            self._step_info(
+                "decode", bucket, seqs, batch, len(seqs) * n_steps, n_steps - 1
             )
-        counts = self._penalty_counts_for(seqs, batch)
-        want_lp = self._want_lp(seqs)
-        greedy = self._all_greedy(seqs)
-        key = self._tel_key("decode", batch, (n_steps, want_lp, greedy))
-        Bb = batch["kv_lens"].shape[0]
-        bucket = f"b{Bb}xn{n_steps}"
         t0 = time.perf_counter()
         self._host_gap_mark(bucket, t0, seqs)
         with self._device_lock:
@@ -1053,12 +1097,13 @@ class ModelRunner:
                 )
             self._dispatch_burst_start(batch, counts, n_steps, want_lp, greedy)
         dt = time.perf_counter() - t0
-        self._charge_decode(seqs, dt)
-        ENGINE_TELEMETRY.record_dispatch(
-            "decode", key, dt,
-            batch_bucket=bucket, tokens=len(seqs) * n_steps,
-            fill_ratio=len(seqs) / Bb,
-        )
+        with ENGINE_TELEMETRY.phase("postprocess", "decode"):
+            self._charge_decode(seqs, dt)
+            ENGINE_TELEMETRY.record_dispatch(
+                "decode", key, dt,
+                batch_bucket=bucket, tokens=len(seqs) * n_steps,
+                fill_ratio=len(seqs) / Bb,
+            )
         # Continuations re-dispatch the same executable: keep the signature
         # so their step timings land in the same bucket without re-counting
         # a compile.
@@ -1072,18 +1117,22 @@ class ModelRunner:
         want_lp: bool = False,
         greedy: bool = False,
     ) -> None:
-        dev = self._put_batch(batch)
-        seed = jax.device_put(np.zeros((), np.uint32), self._repl)
-        cdev = jax.device_put(counts, self._repl)
-        tokens = dev.pop("tokens")
-        positions = dev.pop("positions")
-        with_pen = "penalty_seen" in batch
-        toks, tokens, positions, seed, cdev, self.kv_cache = self._multi_step(
-            self.params, self.kv_cache, dev, tokens, positions, seed,
-            cdev, n_steps, want_lp, greedy, with_pen,
-        )
-        # Start the host copy NOW; the eventual fetch finds it resident.
-        toks.copy_to_host_async()
+        # pipelined: a later step fetches what this launch computes
+        with ENGINE_TELEMETRY.phase("launch", "decode", pipelined=1):
+            dev = self._put_batch(batch)
+            seed = jax.device_put(np.zeros((), np.uint32), self._repl)
+            cdev = jax.device_put(counts, self._repl)
+            tokens = dev.pop("tokens")
+            positions = dev.pop("positions")
+            with_pen = "penalty_seen" in batch
+            toks, tokens, positions, seed, cdev, self.kv_cache = (
+                self._multi_step(
+                    self.params, self.kv_cache, dev, tokens, positions, seed,
+                    cdev, n_steps, want_lp, greedy, with_pen,
+                )
+            )
+            # Start the host copy NOW; the eventual fetch finds it resident.
+            toks.copy_to_host_async()
         self._burst = {
             "batch": dev, "tokens": tokens, "positions": positions,
             "seed": seed, "counts": cdev, "with_pen": with_pen,
@@ -1108,20 +1157,28 @@ class ModelRunner:
         members that finished host-side get kv_len 0 so their speculative
         rows stop writing KV."""
         assert self._burst is not None
-        Wb = self._burst["batch"]["block_tables"].shape[1]
-        Bb = self._burst["batch"]["kv_lens"].shape[0]
-        tables = np.zeros((Bb, Wb), np.int32)
-        kv_lens = np.zeros(Bb, np.int32)
-        for i, s in enumerate(members):
-            tables[i] = self._table_row(s, Wb)
-            kv_lens[i] = 0 if s.is_finished else max(s.num_tokens, 1)
-        alive = sum(1 for s in members if not s.is_finished)
+        tel = getattr(self, "_burst_tel", None)
+        with ENGINE_TELEMETRY.phase("batch_build", "decode"):
+            Wb = self._burst["batch"]["block_tables"].shape[1]
+            Bb = self._burst["batch"]["kv_lens"].shape[0]
+            tables = np.zeros((Bb, Wb), np.int32)
+            kv_lens = np.zeros(Bb, np.int32)
+            for i, s in enumerate(members):
+                tables[i] = self._table_row(s, Wb)
+                kv_lens[i] = 0 if s.is_finished else max(s.num_tokens, 1)
+            alive = sum(1 for s in members if not s.is_finished)
+            if tel is not None:
+                # The host's view lags the device by the burst in flight,
+                # so kv_tokens is low by up to 2n a row here.
+                self._step_info(
+                    "decode", tel[1], members, {"kv_lens": kv_lens},
+                    alive * tel[3],
+                )
         t0 = time.perf_counter()
         with self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce("burst_cont", (tables, kv_lens))
             rows = self._dispatch_burst_continue(tables, kv_lens)
-        tel = getattr(self, "_burst_tel", None)
         if tel is not None:
             # The continuation was dispatched BEFORE the previous burst's
             # tokens were even read: the device runs the two back-to-back,
@@ -1132,16 +1189,18 @@ class ModelRunner:
             ENGINE_TELEMETRY.record_host_gap(tel[1], 0.0)
             key, bucket, rows_b, n = tel
             dt = time.perf_counter() - t0
-            # The continuation wall (dispatch next + overlapped fetch of
-            # the previous burst) is charged ONCE across the members still
-            # alive — the share of the just-fetched burst's device time.
-            self._charge_decode(members, dt)
-            # pstlint: disable=recompile-risk(key and bucket are carried verbatim from burst_start's registered _tel_key via _burst_tel — a continuation re-dispatches the same executable, so the shape identity cannot drift)
-            ENGINE_TELEMETRY.record_dispatch(
-                "decode", key, dt,
-                batch_bucket=bucket, tokens=alive * n,
-                fill_ratio=alive / max(rows_b, 1),
-            )
+            with ENGINE_TELEMETRY.phase("postprocess", "decode"):
+                # The continuation wall (dispatch next + overlapped fetch
+                # of the previous burst) is charged ONCE across the members
+                # still alive — the share of the just-fetched burst's
+                # device time.
+                self._charge_decode(members, dt)
+                # pstlint: disable=recompile-risk(key and bucket are carried verbatim from burst_start's registered _tel_key via _burst_tel — a continuation re-dispatches the same executable, so the shape identity cannot drift)
+                ENGINE_TELEMETRY.record_dispatch(
+                    "decode", key, dt,
+                    batch_bucket=bucket, tokens=alive * n,
+                    fill_ratio=alive / max(rows_b, 1),
+                )
         return rows
 
     def _dispatch_burst_continue(
@@ -1149,21 +1208,25 @@ class ModelRunner:
     ) -> np.ndarray:
         st = self._burst
         prev = st["toks"]
-        st["batch"].update(
-            self._put_batch({"block_tables": tables, "kv_lens": kv_lens})
-        )
-        toks, tokens, positions, seed, counts, self.kv_cache = self._multi_step(
-            self.params, self.kv_cache, st["batch"], st["tokens"],
-            st["positions"], st["seed"], st["counts"], st["n"],
-            st["want_lp"], st.get("greedy", False), st.get("with_pen", False),
-        )
-        # Start the host copy NOW; the eventual fetch finds it resident.
-        toks.copy_to_host_async()
-        st.update(
-            tokens=tokens, positions=positions, seed=seed, counts=counts,
-            toks=toks,
-        )
-        return _fetch(prev)
+        with ENGINE_TELEMETRY.phase("launch", "decode", pipelined=1):
+            st["batch"].update(
+                self._put_batch({"block_tables": tables, "kv_lens": kv_lens})
+            )
+            toks, tokens, positions, seed, counts, self.kv_cache = (
+                self._multi_step(
+                    self.params, self.kv_cache, st["batch"], st["tokens"],
+                    st["positions"], st["seed"], st["counts"], st["n"],
+                    st["want_lp"], st.get("greedy", False),
+                    st.get("with_pen", False),
+                )
+            )
+            # Start the host copy NOW; the eventual fetch finds it resident.
+            toks.copy_to_host_async()
+            st.update(
+                tokens=tokens, positions=positions, seed=seed, counts=counts,
+                toks=toks,
+            )
+        return _fetch(prev, "decode")
 
     def burst_drain(self) -> np.ndarray:
         """Fetch the in-flight burst's tokens and end the pipeline."""
@@ -1172,7 +1235,7 @@ class ModelRunner:
         # No device op, so no multihost announce: followers hold no pending
         # fetch (they never read tokens) and their next announced dispatch
         # keeps program order identical.
-        rows = _fetch(st["toks"])
+        rows = _fetch(st["toks"], "decode")
         # Drains are transitions (an arrival or shape change broke the
         # pipeline) and a prefill may already be queued behind this fetch —
         # the wall from here to the next decode dispatch is not steady-state
@@ -1197,9 +1260,13 @@ class ModelRunner:
         sit past the committed kv_len and are overwritten on real decode.
         """
         B, K = drafts.shape
-        batch = self._spec_batch(seqs, drafts)
-        key = self._tel_key("spec_verify", batch, (K,))
-        Bb = batch["kv_lens"].shape[0]
+        with ENGINE_TELEMETRY.phase("batch_build", "spec_verify"):
+            batch = self._spec_batch(seqs, drafts)
+            key = self._tel_key("spec_verify", batch, (K,))
+            Bb = batch["kv_lens"].shape[0]
+            self._step_info(
+                "spec_verify", f"b{Bb}xk{K}", seqs, batch, len(seqs) * (K + 1)
+            )
         t0 = time.perf_counter()
         self._host_gap_cancel()
         with self._device_lock:
@@ -1207,12 +1274,13 @@ class ModelRunner:
                 self.publisher.announce("spec_verify", batch)
             ids, sampled0 = self._dispatch_spec_verify(batch)
         dt = time.perf_counter() - t0
-        self._charge_decode(seqs, dt)
-        ENGINE_TELEMETRY.record_dispatch(
-            "spec_verify", key, dt,
-            batch_bucket=f"b{Bb}xk{K}", tokens=len(seqs) * (K + 1),
-            fill_ratio=len(seqs) / Bb,
-        )
+        with ENGINE_TELEMETRY.phase("postprocess", "spec_verify"):
+            self._charge_decode(seqs, dt)
+            ENGINE_TELEMETRY.record_dispatch(
+                "spec_verify", key, dt,
+                batch_bucket=f"b{Bb}xk{K}", tokens=len(seqs) * (K + 1),
+                fill_ratio=len(seqs) / Bb,
+            )
         return ids[: len(seqs)], sampled0[: len(seqs)]
 
     def _spec_batch(
@@ -1274,7 +1342,7 @@ class ModelRunner:
             model_mesh = self._model_mesh
             moe_impl = self._moe_impl
 
-            def spec_step(params, kv_cache, batch):
+            def pst_spec_verify(params, kv_cache, batch):
                 logits, kv_cache = model.forward(
                     params,
                     batch["tokens"],
@@ -1324,14 +1392,15 @@ class ModelRunner:
             )
             # pstlint: jit-family=spec_verify
             self._spec_step = jax.jit(
-                spec_step,
+                pst_spec_verify,
                 donate_argnums=(1,),
                 out_shardings=(self._repl, cache_sh),
             )
-        packed, self.kv_cache = self._spec_step(
-            self.params, self.kv_cache, self._put_batch(batch)
-        )
-        packed = _fetch(packed)
+        with ENGINE_TELEMETRY.phase("launch", "spec_verify"):
+            packed, self.kv_cache = self._spec_step(
+                self.params, self.kv_cache, self._put_batch(batch)
+            )
+        packed = _fetch(packed, "spec_verify")
         return packed[:, :-1], packed[:, -1]
 
     def _prefill_tel(
@@ -1342,9 +1411,11 @@ class ModelRunner:
         prefill step's telemetry."""
         Bb, Tb = batch["tokens"].shape
         real = sum(it.end - it.start for it in items)
+        bucket = f"b{Bb}xt{Tb}"
+        self._step_info("prefill", bucket, [it.seq for it in items], batch, real)
         return (
             self._tel_key("prefill", batch, extras),
-            f"b{Bb}xt{Tb}",
+            bucket,
             real,
             real / max(Bb * Tb, 1),
         )
@@ -1359,20 +1430,22 @@ class ModelRunner:
         common chunk bucket). Returns packed sample rows
         [len(items), 1 or PACKED_WIDTH] (token [+ logprobs])."""
         seqs = [i.seq for i in items]
-        batch = self._prefill_batch(items)
-        want_lp, greedy = self._want_lp(seqs), self._all_greedy(seqs)
-        key, bucket, real, fill = self._prefill_tel(
-            items, batch, (want_lp, greedy)
-        )
+        with ENGINE_TELEMETRY.phase("batch_build", "prefill"):
+            batch = self._prefill_batch(items)
+            want_lp, greedy = self._want_lp(seqs), self._all_greedy(seqs)
+            key, bucket, real, fill = self._prefill_tel(
+                items, batch, (want_lp, greedy)
+            )
         t0 = time.perf_counter()
         self._host_gap_cancel()
-        rows = self._run(batch, want_lp, greedy)
+        rows = self._run(batch, want_lp, greedy, "prefill")
         dt = time.perf_counter() - t0
-        self._charge_prefill(items, dt)
-        ENGINE_TELEMETRY.record_dispatch(
-            "prefill", key, dt,
-            batch_bucket=bucket, tokens=real, fill_ratio=fill,
-        )
+        with ENGINE_TELEMETRY.phase("postprocess", "prefill"):
+            self._charge_prefill(items, dt)
+            ENGINE_TELEMETRY.record_dispatch(
+                "prefill", key, dt,
+                batch_bucket=bucket, tokens=real, fill_ratio=fill,
+            )
         return rows[: len(items)]
 
     def execute_prefill_batch_nofetch(self, items: List[PrefillItem]) -> None:
@@ -1384,10 +1457,13 @@ class ModelRunner:
         20k-token prompt. The KV writes chain on-device through the
         donated cache, so correctness is unaffected; the next fetching step
         transitively waits for all queued work."""
-        batch = self._prefill_batch(items)
-        # nofetch steps compile as (want_lp=False, greedy=True) — the same
-        # executable a fetching greedy step uses.
-        key, bucket, real, fill = self._prefill_tel(items, batch, (False, True))
+        with ENGINE_TELEMETRY.phase("batch_build", "prefill"):
+            batch = self._prefill_batch(items)
+            # nofetch steps compile as (want_lp=False, greedy=True) — the
+            # same executable a fetching greedy step uses.
+            key, bucket, real, fill = self._prefill_tel(
+                items, batch, (False, True)
+            )
         t0 = time.perf_counter()
         self._host_gap_cancel()
         with self._device_lock:
@@ -1395,18 +1471,20 @@ class ModelRunner:
                 self.publisher.announce("step_nofetch", batch)
             self._dispatch_step_nofetch(batch)
         dt = time.perf_counter() - t0
-        self._charge_prefill(items, dt)
-        ENGINE_TELEMETRY.record_dispatch(
-            "prefill", key, dt,
-            batch_bucket=bucket, tokens=real, fill_ratio=fill,
-        )
+        with ENGINE_TELEMETRY.phase("postprocess", "prefill"):
+            self._charge_prefill(items, dt)
+            ENGINE_TELEMETRY.record_dispatch(
+                "prefill", key, dt,
+                batch_bucket=bucket, tokens=real, fill_ratio=fill,
+            )
 
     def _dispatch_step_nofetch(self, batch: Dict[str, np.ndarray]) -> None:
         # greedy=True: nobody reads an intermediate chunk's sample, so the
         # cheapest sampling variant (plain argmax) is always correct here.
-        _, self.kv_cache = self._step(
-            self.params, self.kv_cache, self._put_batch(batch), False, True
-        )
+        with ENGINE_TELEMETRY.phase("launch", "prefill"):
+            _, self.kv_cache = self._step["prefill"](
+                self.params, self.kv_cache, self._put_batch(batch), False, True
+            )
 
     def prefill_dispatch(self, items: List[PrefillItem]):  # noqa: D401
         """Async half of a prefill step: dispatch and return the device
@@ -1414,54 +1492,65 @@ class ModelRunner:
         BEHIND an in-flight decode burst (the device serializes them; the
         burst drain then overlaps the prefill's execution), cutting one full
         host<->device round trip out of TTFT."""
-        batch = self._prefill_batch(items)
-        want_lp = self._want_lp([i.seq for i in items])
-        greedy = self._all_greedy([i.seq for i in items])
-        key, bucket, real, fill = self._prefill_tel(
-            items, batch, (want_lp, greedy)
-        )
+        with ENGINE_TELEMETRY.phase("batch_build", "prefill"):
+            batch = self._prefill_batch(items)
+            want_lp = self._want_lp([i.seq for i in items])
+            greedy = self._all_greedy([i.seq for i in items])
+            key, bucket, real, fill = self._prefill_tel(
+                items, batch, (want_lp, greedy)
+            )
         t0 = time.perf_counter()
         self._host_gap_cancel()
         with self._device_lock:
             if self.publisher is not None:
-                self.publisher.announce("step", (batch, want_lp, greedy))
-            dev = self._put_batch(batch)
-            toks, self.kv_cache = self._step(
-                self.params, self.kv_cache, dev, want_lp, greedy
-            )
+                self.publisher.announce(
+                    "step", (batch, want_lp, greedy, "prefill")
+                )
+            with ENGINE_TELEMETRY.phase("launch", "prefill"):
+                toks, self.kv_cache = self._step["prefill"](
+                    self.params, self.kv_cache, self._put_batch(batch),
+                    want_lp, greedy,
+                )
+                toks.copy_to_host_async()
         dt = time.perf_counter() - t0
-        self._charge_prefill(items, dt)
-        ENGINE_TELEMETRY.record_dispatch(
-            "prefill", key, dt,
-            batch_bucket=bucket, tokens=real, fill_ratio=fill,
-        )
-        toks.copy_to_host_async()
+        with ENGINE_TELEMETRY.phase("postprocess", "prefill"):
+            self._charge_prefill(items, dt)
+            ENGINE_TELEMETRY.record_dispatch(
+                "prefill", key, dt,
+                batch_bucket=bucket, tokens=real, fill_ratio=fill,
+            )
         return toks
 
     def prefill_fetch(self, handle, n_items: int) -> np.ndarray:
-        return _fetch(handle)[:n_items]
+        return _fetch(handle, "prefill")[:n_items]
 
     def _run(
         self,
         batch: Dict[str, np.ndarray],
-        want_lp: bool = False,
-        greedy: bool = False,
+        want_lp: bool,
+        greedy: bool,
+        kind: str,
     ) -> np.ndarray:
         with self._device_lock:
             if self.publisher is not None:
-                self.publisher.announce("step", (batch, want_lp, greedy))
-            return self._dispatch_step(batch, want_lp, greedy)
+                self.publisher.announce("step", (batch, want_lp, greedy, kind))
+            return self._dispatch_step(batch, want_lp, greedy, kind)
 
     def _dispatch_step(
         self,
         batch: Dict[str, np.ndarray],
-        want_lp: bool = False,
-        greedy: bool = False,
+        want_lp: bool,
+        greedy: bool,
+        kind: str,
     ) -> np.ndarray:
-        toks, self.kv_cache = self._step(
-            self.params, self.kv_cache, self._put_batch(batch), want_lp, greedy
-        )
-        return _fetch(toks)
+        """Launch the ``kind`` ("decode" | "prefill") program on ``batch``
+        and fetch its packed rows."""
+        with ENGINE_TELEMETRY.phase("launch", kind):
+            toks, self.kv_cache = self._step[kind](
+                self.params, self.kv_cache, self._put_batch(batch),
+                want_lp, greedy,
+            )
+        return _fetch(toks, kind)
 
     # ------------------------------------------------------------------
     # Warmup precompilation (engine/precompile.py drives this)
@@ -1531,7 +1620,7 @@ class ModelRunner:
         batch.update(self._warmup_sampling_arrays(Bb))
         key = self._tel_key("decode", batch, (bucket.want_lp, bucket.greedy))
         t0 = time.perf_counter()
-        self._run(batch, bucket.want_lp, bucket.greedy)
+        self._run(batch, bucket.want_lp, bucket.greedy, "decode")
         self._record_warmup(
             "decode", key, time.perf_counter() - t0, bucket.label
         )
@@ -1586,7 +1675,7 @@ class ModelRunner:
         batch.update(self._warmup_sampling_arrays(Bb))
         key = self._tel_key("prefill", batch, (bucket.want_lp, bucket.greedy))
         t0 = time.perf_counter()
-        self._run(batch, bucket.want_lp, bucket.greedy)
+        self._run(batch, bucket.want_lp, bucket.greedy, "prefill")
         self._record_warmup(
             "prefill", key, time.perf_counter() - t0, bucket.label
         )

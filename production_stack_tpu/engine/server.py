@@ -22,7 +22,9 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import functools
 import json
+import os
 import time
 from typing import List, Optional
 
@@ -1381,6 +1383,7 @@ def create_engine_app(
         if engine.warmup_error:
             warmup["error"] = engine.warmup_error
         if engine.ready:
+            engine.engine.note_ready()
             return web.json_response({"ready": True, "warmup": warmup})
         # Reason mirrors AsyncLLMEngine.ready's conjuncts, in severity
         # order.
@@ -1425,7 +1428,8 @@ def create_engine_app(
         ``--profiling`` flag must be on, and when an API key is configured
         the endpoint requires it like the work endpoints. On CPU backends
         this is a graceful no-op — there is no device timeline worth the
-        capture overhead."""
+        capture overhead. The response gives ``start_s`` and ``stop_s``:
+        how long the profiler took to start and to stop and write."""
         if not profiling:
             return _error(
                 "profiling is disabled (start the engine with --profiling)",
@@ -1461,17 +1465,39 @@ def create_engine_app(
             return _error("a profile capture is already running", 409,
                           "conflict_error")
         async with profile_lock:
-            import os
-
             os.makedirs(out_dir, exist_ok=True)
-            jax.profiler.start_trace(out_dir)
+            # The pst.* spans of the step loop (obs/engine_telemetry
+            # ``phase``) say what the Python tracer was there to say, and
+            # that tracer slows the very host code whose gaps the trace is
+            # read for: off. Starting and stopping a capture takes the
+            # profiler seconds, so both run in the executor and the loop
+            # keeps serving streams meanwhile.
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 2
+            loop = asyncio.get_running_loop()
+            t0 = time.perf_counter()
+            await loop.run_in_executor(
+                None,
+                functools.partial(
+                    jax.profiler.start_trace, out_dir,
+                    profiler_options=options,
+                ),
+            )
+            start_s = time.perf_counter() - t0
             try:
                 await asyncio.sleep(duration_ms / 1000.0)
             finally:
-                jax.profiler.stop_trace()
-        logger.info("profile captured: %.0f ms -> %s", duration_ms, out_dir)
+                t0 = time.perf_counter()
+                await loop.run_in_executor(None, jax.profiler.stop_trace)
+                stop_s = time.perf_counter() - t0
+        logger.info(
+            "profile captured: %.0f ms -> %s (start %.2f s, stop %.2f s)",
+            duration_ms, out_dir, start_s, stop_s,
+        )
         return web.json_response({
             "status": "ok", "dir": out_dir, "duration_ms": duration_ms,
+            "start_s": start_s, "stop_s": stop_s,
         })
 
     async def debug_requests(request: web.Request) -> web.Response:

@@ -1068,17 +1068,19 @@ def _moe_mlp(cfg: "LlamaConfig", lp: Params, x: jax.Array, impl: str) -> jax.Arr
         tok = order // K  # originating token of each sorted slot
         xs = x[tok]  # [N*K, D]
         group_sizes = jnp.bincount(flat_ids, length=E).astype(jnp.int32)
-        g = jax.lax.ragged_dot(
-            xs, deq("w_gate"), group_sizes,
-            preferred_element_type=jnp.float32,
-        )
-        u = jax.lax.ragged_dot(
-            xs, deq("w_up"), group_sizes, preferred_element_type=jnp.float32
-        )
-        hh = (_act(cfg)(g) * u).astype(x.dtype)
-        y = jax.lax.ragged_dot(
-            hh, deq("w_down"), group_sizes, preferred_element_type=jnp.float32
-        )  # [N*K, D]
+        # A stable name in the device trace for the expert matmuls.
+        with jax.named_scope("moe_experts"):
+            g = jax.lax.ragged_dot(
+                xs, deq("w_gate"), group_sizes,
+                preferred_element_type=jnp.float32,
+            )
+            u = jax.lax.ragged_dot(
+                xs, deq("w_up"), group_sizes, preferred_element_type=jnp.float32
+            )
+            hh = (_act(cfg)(g) * u).astype(x.dtype)
+            y = jax.lax.ragged_dot(
+                hh, deq("w_down"), group_sizes, preferred_element_type=jnp.float32
+            )  # [N*K, D]
         wsort = weights.reshape(-1)[order]  # [N*K]
         return (
             jnp.zeros((N, D), jnp.float32).at[tok].add(y * wsort[:, None])

@@ -84,6 +84,22 @@ host_gap_seconds = Histogram(
     registry=ENGINE_TELEMETRY_REGISTRY,
     buckets=_HOST_GAP_BUCKETS,
 )
+# Step-loop phases are mostly under 10 ms (a launch, a poll, a batch
+# build), so the buckets are fine there; a compile-bearing launch or a long
+# idle wait lands in the coarse tail.
+_PHASE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                  0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 10.0)
+step_phase_seconds = Histogram(
+    "pst_engine_step_phase_seconds",
+    "Wall time of one phase of the engine's step loop (no_work, intake, "
+    "step, schedule, batch_build, launch, wait, postprocess), by phase and "
+    "step kind; phases inside a step are summed over the step and observed "
+    "once when it ends. The same phases are written into a running "
+    "jax.profiler trace as pst.<phase> spans",
+    ["phase", "kind"],
+    registry=ENGINE_TELEMETRY_REGISTRY,
+    buckets=_PHASE_BUCKETS,
+)
 batch_fill_ratio = Histogram(
     "pst_engine_batch_fill_ratio",
     "Useful fraction of each padded device step (real rows*tokens over "
@@ -137,10 +153,12 @@ start_time_seconds = Gauge(
 )
 startup_seconds = Gauge(
     "pst_engine_startup_seconds",
-    "Engine startup decomposition: load (param materialization), shard "
-    "(device placement + KV alloc + jit wiring), warmup (tokenizer, "
-    "allocator, scheduler), precompile (ahead-of-time shape-bucket "
-    "lattice compilation)",
+    "Engine startup decomposition, process start to the first 200 of "
+    "/ready: imports (process start to the engine's constructor), "
+    "tokenizer, load (param materialization), shard (device placement + "
+    "KV alloc + jit wiring), warmup (compile cache, allocator, scheduler), "
+    "precompile (ahead-of-time shape-bucket lattice compilation), serve "
+    "(constructor done to first ready, precompile excluded)",
     ["phase"],
     registry=ENGINE_TELEMETRY_REGISTRY,
 )
@@ -201,6 +219,50 @@ device_busy_seconds = Counter(
     registry=ENGINE_TELEMETRY_REGISTRY,
 )
 
+_trace_annotation = None
+
+
+def _annotation(name: str, **meta):
+    """``jax.profiler.TraceAnnotation``, imported on first use: the router
+    and the fake engine import ``obs`` without jax."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(name, **meta)
+
+
+class _Phase:
+    """One open phase of the step loop: a ``pst.<name>`` span in the
+    profiler's trace (host and device then share a clock) and, on exit,
+    its wall time in ``pst_engine_step_phase_seconds``. ``kind`` may be set
+    until the phase closes (a step learns its kind while it runs); the
+    trace's copy of it is fixed at entry."""
+
+    __slots__ = ("_tel", "name", "kind", "_ann", "_t0")
+
+    def __init__(self, tel: "EngineTelemetry", name: str, kind: str, meta: dict):
+        self._tel = tel
+        self.name = name
+        self.kind = kind
+        if kind:
+            meta["kind"] = kind
+        self._ann = _annotation("pst." + name, **meta)
+
+    def __enter__(self) -> "_Phase":
+        if self.name == "step":
+            self._tel._step, self._tel._step_tid = self, threading.get_ident()
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        self._tel._phase_done(self, dt)
+
+
 # Fresh runners must re-count compiles even when an earlier runner in the
 # same process already compiled identical bucket shapes (jit caches are
 # per-runner): each ModelRunner takes a distinct scope id into its keys.
@@ -236,10 +298,12 @@ class EngineTelemetry:
         # monitoring listener precompile.configure_compile_cache installs).
         self._cache_hits = 0
         self._cache_misses = 0
-        # Bounded raw host-gap samples per batch bucket: Prometheus
-        # histograms cannot answer "p50 at batch 8" locally, but the bench
-        # and scripts/tpu_decode_profile.py --host-gap must.
-        self._host_gap: Dict[str, "deque[float]"] = {}
+        # The step phase open on the step thread, and the wall its inner
+        # phases have summed so far: {(phase, kind): seconds}.
+        self._step: Optional[_Phase] = None
+        self._step_tid = 0
+        self._step_acc: Dict[Tuple[str, str], float] = {}
+        self._phase_children: Dict[Tuple[str, str], object] = {}
         # Flight-recorder sink (obs/flight.py): every live dispatch
         # forwards one ring record; the null recorder makes this free.
         from .flight import NULL_FLIGHT_RECORDER
@@ -393,8 +457,6 @@ class EngineTelemetry:
             )
         return compiled
 
-    _HOST_GAP_SAMPLE_CAP = 1024  # per bucket; enough for a stable p50
-
     def record_host_gap(
         self, batch_bucket: str, seconds: float,
         request_id: "Optional[str]" = None,
@@ -409,13 +471,6 @@ class EngineTelemetry:
         as an OpenMetrics exemplar: a slow host-gap bucket links to the
         ``/debug/requests?request_id=`` timeline that absorbed it."""
         seconds = max(seconds, 0.0)
-        with self._lock:
-            dq = self._host_gap.get(batch_bucket)
-            if dq is None:
-                dq = self._host_gap[batch_bucket] = deque(
-                    maxlen=self._HOST_GAP_SAMPLE_CAP
-                )
-            dq.append(seconds)
         # The gap closes AT the next decode dispatch: hand it to the
         # flight ring so that dispatch's record carries it.
         self._flight.note_host_gap(seconds)
@@ -425,31 +480,46 @@ class EngineTelemetry:
         else:
             child.observe(seconds)
 
-    def reset_host_gap(self) -> None:
-        """Drop retained host-gap samples (NOT the Prometheus histogram —
-        that stays cumulative). The bench calls this per phase so one
-        model's summary never mixes a previous engine's samples that
-        landed in the same batch bucket."""
-        with self._lock:
-            self._host_gap.clear()
+    # -- step-loop phases (pst.* spans + pst_engine_step_phase_seconds) --
 
-    def host_gap_summary(self) -> Dict[str, Dict[str, float]]:
-        """Per-bucket {count, p50, mean} over the retained sample window —
-        what the bench's roofline block and the --host-gap profiling mode
-        report (the acceptance bar: p50 < 10% of the decode-step p50)."""
-        out: Dict[str, Dict[str, float]] = {}
-        with self._lock:
-            buckets = {k: list(v) for k, v in self._host_gap.items()}
-        for bucket, samples in sorted(buckets.items()):
-            if not samples:
-                continue
-            ordered = sorted(samples)
-            out[bucket] = {
-                "count": float(len(ordered)),
-                "p50": float(ordered[len(ordered) // 2]),
-                "mean": float(sum(ordered) / len(ordered)),
-            }
-        return out
+    def phase(self, name: str, kind: str = "", **meta) -> _Phase:
+        """Context manager around one phase of the step loop; see
+        :class:`_Phase`. ``phase("step")`` is the whole of one engine step:
+        the phases opened inside it on the same thread are summed per
+        (phase, kind) and observed once when the step closes, so the
+        histogram counts one observation per phase per step however many
+        spans the trace shows."""
+        return _Phase(self, name, kind, meta)
+
+    def step_info(self, kind: str, **meta) -> None:
+        """What the open step turned out to be, known only once it is
+        scheduled and its batch is built: a zero-length ``pst.step_info``
+        span carrying the metadata (a ``TraceAnnotation`` takes its own at
+        entry, so ``pst.step`` cannot), and the step's ``kind`` label."""
+        with _annotation("pst.step_info", kind=kind, **meta):
+            pass
+        step = self._step
+        if step is not None and threading.get_ident() == self._step_tid:
+            step.kind = kind
+
+    def _observe_phase(self, name: str, kind: str, seconds: float) -> None:
+        child = self._phase_children.get((name, kind))
+        if child is None:
+            child = self._phase_children[(name, kind)] = (
+                step_phase_seconds.labels(phase=name, kind=kind)
+            )
+        child.observe(seconds)
+
+    def _phase_done(self, phase: _Phase, seconds: float) -> None:
+        if phase.name == "step":
+            acc, self._step_acc, self._step = self._step_acc, {}, None
+            for (name, kind), total in acc.items():
+                self._observe_phase(name, kind, total)
+        elif self._step is not None and threading.get_ident() == self._step_tid:
+            key = (phase.name, phase.kind)
+            self._step_acc[key] = self._step_acc.get(key, 0.0) + seconds
+            return
+        self._observe_phase(phase.name, phase.kind, seconds)
 
     def _refresh_throughput_locked(self, now: float) -> None:
         cutoff = now - self._TOKEN_WINDOW_S
@@ -541,7 +611,8 @@ class EngineTelemetry:
             self._kv_hwm = 0.0
             self._cache_hits = 0
             self._cache_misses = 0
-            self._host_gap.clear()
+            self._step = None
+            self._step_acc = {}
             self._device_busy_s = 0.0
             self.startup_enabled = True
         from .flight import NULL_FLIGHT_RECORDER

@@ -67,6 +67,7 @@ REGISTRY: Tuple[MetricSpec, ...] = (
     MetricSpec("pst_engine_compile_seconds", HISTOGRAM, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_step_duration_seconds", HISTOGRAM, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_host_gap_seconds", HISTOGRAM, "obs/engine_telemetry.py"),
+    MetricSpec("pst_engine_step_phase_seconds", HISTOGRAM, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_batch_fill_ratio", HISTOGRAM, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_tokens_per_second", GAUGE, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_mfu", GAUGE, "obs/engine_telemetry.py"),

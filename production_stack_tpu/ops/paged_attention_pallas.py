@@ -415,6 +415,7 @@ def pallas_paged_attention_decode_write(
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=pallas_interpret(),
+        name="paged_attn_decode_write",
     )(tables, lens, layer_arr, win_arr, wf,
       q3,
       k_new.astype(kv_pages.dtype)[:, None],
@@ -561,6 +562,7 @@ def _decode_call(q3, kv_pages, block_tables, kv_lens, layer, window,
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=pallas_interpret(),
+        name="paged_attn_decode",
     )(block_tables, kv_lens, layer, window, q3, kv_pages)
 
 
@@ -610,6 +612,7 @@ def _prefill_call(q, kv_pages, block_tables, kv_lens, starts, layer, window,
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=pallas_interpret(),
+        name="paged_attn_prefill",
     )(block_tables, kv_lens, starts, layer, window, q, kv_pages)
 
 

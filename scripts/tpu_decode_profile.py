@@ -5,14 +5,11 @@ per-call dispatch cost amortizes away):
   1. attention kernel alone, one layer
   2. attention across all 16 layers (scan, no MLP)
   3. KV scatter alone across 16 layers
-  4. the full model decode step (runner._step shape)
+  4. the full model decode step (the runner's decode program's shape)
 
-``--host-gap`` instead measures the serial host time between decode
-bursts (pst_engine_host_gap_seconds) through the real engine loop,
-pre/post pipeline: one leg with pipelining forced OFF (the synchronous
-loop — every burst pays the full host bookkeeping gap) and one leg
-pipelined (burst N+1 dispatched before burst N's bookkeeping runs), so
-the overlapped-decode win is reproducible outside the bench harness.
+The host's side of a decode step is read from the engine itself: the
+``pst.*`` phases (``pst_engine_step_phase_seconds``, and the spans of a
+``POST /debug/profile`` capture; docs/observability.md "Profiling").
 """
 
 import time
@@ -47,9 +44,6 @@ def main():
     import sys
     model_only = "--model-only" in sys.argv
     rng = np.random.default_rng(0)
-    if "--host-gap" in sys.argv:
-        host_gap_leg()
-        return
     if model_only:
         model_leg(rng)
         return
@@ -107,79 +101,6 @@ def main():
     jax.block_until_ready(kv2)
     ts = (time.perf_counter() - t0) / 6 / INNER
     print(f"scatter x16  : {ts*1e3:7.3f} ms per 16-layer sweep")
-
-
-def host_gap_leg():
-    """--host-gap: serial host time between decode bursts, pre/post
-    pipeline (reports pst_engine_host_gap_seconds p50/mean per bucket and
-    the ratio against the mean decode-step wall)."""
-    import sys
-
-    import jax
-
-    from production_stack_tpu.engine.config import EngineConfig
-    from production_stack_tpu.engine.engine import LLMEngine
-    from production_stack_tpu.engine.sequence import SamplingParams
-    from production_stack_tpu.obs import ENGINE_TELEMETRY
-
-    on_tpu = jax.default_backend() == "tpu" and "--tiny" not in sys.argv
-    if on_tpu:
-        kw = dict(
-            model="llama-1b", max_model_len=8192, block_size=bs,
-            num_kv_blocks=nb, max_num_seqs=16, max_prefill_tokens=1024,
-            attn_impl="pallas", num_decode_steps=2, min_decode_bucket=8,
-        )
-        n_seqs, prompt_len, max_tokens = 8, 512, 96
-    else:
-        kw = dict(
-            model="tiny-llama-debug", max_model_len=512, block_size=8,
-            num_kv_blocks=512, max_num_seqs=8, max_prefill_tokens=128,
-            attn_impl="gather", num_decode_steps=2,
-        )
-        n_seqs, prompt_len, max_tokens = 4, 48, 48
-
-    def run(pipelined: bool) -> tuple:
-        ENGINE_TELEMETRY.reset_for_tests()
-        eng = LLMEngine(EngineConfig(
-            **kw,
-            overlap_decode=False,  # isolate: pipeline ONLY when forced
-            async_decode=pipelined,
-            adaptive_decode_steps=0,
-        ))
-        r = np.random.default_rng(0)
-        for i in range(n_seqs):
-            eng.add_request(
-                f"g{i}",
-                prompt_token_ids=r.integers(
-                    1, eng.model_cfg.vocab_size - 1, prompt_len
-                ).tolist(),
-                sampling=SamplingParams(
-                    max_tokens=max_tokens, temperature=0.0, ignore_eos=True
-                ),
-            )
-        steps, wall = 0, 0.0
-        while eng.has_work():
-            t0 = time.perf_counter()
-            eng.step()
-            wall += time.perf_counter() - t0
-            steps += 1
-        summary = ENGINE_TELEMETRY.host_gap_summary()
-        return summary, wall / max(steps, 1)
-
-    for pipelined in (False, True):
-        summary, step_mean = run(pipelined)
-        tag = "pipelined " if pipelined else "synchronous"
-        if not summary:
-            print(f"{tag}: no decode host-gap samples recorded")
-            continue
-        for bucket, s in summary.items():
-            ratio = s["p50"] / step_mean if step_mean else float("inf")
-            print(
-                f"{tag} {bucket:>8}: host gap p50 {s['p50']*1e3:7.3f} ms  "
-                f"mean {s['mean']*1e3:7.3f} ms  n={int(s['count'])}  "
-                f"(engine step mean {step_mean*1e3:.3f} ms, "
-                f"p50/step {ratio:.2%})"
-            )
 
 
 def model_leg(rng):

@@ -1,0 +1,190 @@
+"""``perf/host_trace.py``'s reduction: on a hand-made trace whose every
+number a person can follow, and on a slice recorded on the chip
+(``data/trace_v5e_host_slice.json``; its ``_note`` says how it was cut from a
+``--trace 1`` run of the cell)."""
+
+import json
+import os
+
+import pytest
+
+from perf import host_trace
+
+DATA = os.path.join(os.path.dirname(__file__), "data")
+ATTN = "%paged_attn_decode.3 = bf16[2,32,128]{2,1,0} custom-call(s32[2,64]{1,0} %t)"
+
+
+def _span(name, start, end, **stats):
+    return ["pst." + name, float(start), float(end - start), stats]
+
+
+def _step(start, end, kind, info_at, parts, **info):
+    """A pst.step with its phases: parts = [(phase, start, end), ...]."""
+    out = [_span("step", start, end)]
+    for phase, s, e in parts:
+        out.append(_span(phase, s, e, **({} if phase == "schedule" else {"kind": kind})))
+    out.append(_span("step_info", info_at, info_at + 1, kind=kind, **info))
+    return out
+
+
+def hand_made() -> dict:
+    """Times in ns over a traced interval [0, 2000]; see the arithmetic in
+    ``test_idle_is_attributed_to_the_innermost_phase``."""
+    ops = [["%fusion.1 = f32[2]{0} fusion()", 60.0, 40.0], [ATTN, 100.0, 100.0],
+           ["%fusion.1 = f32[2]{0} fusion()", 400.0, 100.0], [ATTN, 500.0, 200.0],
+           ["%int4_matmul.7 = f32[2,8]{1,0} custom-call()", 700.0, 100.0],
+           ["%fusion.1 = f32[2]{0} fusion()", 1100.0, 100.0], [ATTN, 1200.0, 150.0],
+           ["%paged_attn_prefill.5 = bf16[1,64,32,128]{3,2,1,0} custom-call()",
+            1600.0, 200.0]]
+    modules = [["jit_pst_decode_step(11)", 60.0, 140.0],
+               ["jit_pst_decode_step(11)", 400.0, 400.0],
+               ["jit_pst_decode_step(11)", 1100.0, 250.0],
+               ["jit_pst_prefill_step(12)", 1600.0, 200.0]]
+    thread = (
+        # cut by the left edge of the traced interval: dropped
+        _step(-200, 250, "decode", -160,
+              [("launch", -150, -100), ("wait", -100, 230), ("postprocess", 230, 250)],
+              bucket="b2", rows=2, new_tokens=2, kv_tokens=998, kv_pages=9)
+        + [_span("intake", 250, 300)]
+        + _step(300, 900, "decode", 350,
+                [("schedule", 300, 320), ("batch_build", 320, 360),
+                 ("launch", 360, 390), ("wait", 390, 850), ("postprocess", 850, 900)],
+                bucket="b2", rows=2, new_tokens=2, kv_tokens=1000, kv_pages=9)
+        + [_span("intake", 900, 950)]
+        # its wait closes at 1300, its program ends at 1350: a clock violation;
+        # 1480-1500 lies in the step but in no phase
+        + _step(950, 1500, "decode", 990,
+                [("schedule", 950, 960), ("batch_build", 960, 1000),
+                 ("launch", 1000, 1050), ("wait", 1050, 1300),
+                 ("postprocess", 1300, 1480)],
+                bucket="b2", rows=2, new_tokens=2, kv_tokens=1002, kv_pages=9)
+        + [_span("no_work", 1500, 1550), _span("intake", 1550, 1560)]
+        + _step(1560, 1900, "prefill", 1580,
+                [("schedule", 1560, 1570), ("batch_build", 1570, 1590),
+                 ("launch", 1590, 1610), ("wait", 1610, 1850),
+                 ("postprocess", 1850, 1900)],
+                bucket="b1xt64", rows=1, new_tokens=40, kv_tokens=540, kv_pages=5)
+        + [_span("intake", 1900, 1950)])  # and nothing from 1950 to 2000
+    other = [_span("wait", 0, 2000)]  # an embedding request's fetch, elsewhere
+    return {"planes": [
+        {"name": "/host:CPU", "lines": [{"name": "python", "events": other},
+                                        {"name": "python", "events": thread}]},
+        {"name": "/device:TPU:0", "interval": [0.0, 2000.0], "lines": [
+            {"name": "XLA Modules", "events": modules},
+            {"name": "XLA Ops", "events": ops}]},
+    ]}
+
+
+def test_idle_is_attributed_to_the_innermost_phase():
+    r = host_trace.reduce(hand_made())
+    # busy [60,200] [400,800] [1100,1350] [1600,1800]; idle is the rest of
+    # [0,2000]: 60 + 200 + 300 + 250 + 200 ns
+    assert r["window_s"] == pytest.approx(2000e-9)
+    assert r["idle_s"] == pytest.approx(1010e-9)
+    want = {
+        "wait": 60 + 30 + 10 + 50 + 50 + 50,       # the cut step's wait counts
+        "postprocess": 20 + 50 + 130 + 50,
+        "intake": 50 + 50 + 10 + 50,
+        "schedule": 20 + 10 + 10,
+        "batch_build": 40 + 40 + 20,
+        "launch": 30 + 50 + 10,
+        "no_work": 50,
+        "unattributed": 20 + 50,  # in a step but in no phase; under no span
+    }
+    assert {k: round(v * 1e9, 6) for k, v in r["idle_by_phase"].items()} == want
+    assert sum(r["idle_by_phase"].values()) == pytest.approx(r["idle_s"])
+    assert r["spans"] == len(hand_made()["planes"][0]["lines"][1]["events"])
+
+
+def test_steps_cut_by_an_edge_are_dropped_and_the_rest_joined_in_order():
+    r = host_trace.reduce(hand_made())
+    assert r["steps_kept"] == 3  # the first pst.step began before the interval
+    assert r["modules"] == {"jit_pst_decode_step": [3, pytest.approx(790e-9)],
+                            "jit_pst_prefill_step": [1, pytest.approx(200e-9)]}
+    # the program of the cut step is set aside, so the second program belongs
+    # to the first whole step: its attention ran 200 ns of the program's 400
+    first, second = r["decode_steps"]
+    assert (first["kv_tokens"], first["rows"], first["bucket"]) == (1000, 2, "b2")
+    assert first["module"] == "jit_pst_decode_step"
+    assert first["module_s"] == pytest.approx(400e-9)
+    assert first["attn_s"] == pytest.approx(200e-9)
+    assert (second["kv_tokens"], second["attn_s"]) == (1002, pytest.approx(150e-9))
+
+
+def test_a_program_outside_its_steps_launch_and_wait_is_a_clock_violation():
+    assert host_trace.reduce(hand_made())["clock_violations"] == 1
+    early = hand_made()
+    early["planes"][1]["lines"][0]["events"][3][1] = 1585.0  # before its launch
+    assert host_trace.reduce(early)["clock_violations"] == 2
+    # a pipelined launch is fetched by a later step: the wait after it in its
+    # own step is for the program before, and says nothing of this one
+    piped = hand_made()
+    for ev in piped["planes"][0]["lines"][1]["events"]:
+        if ev[0] == "pst.launch" and ev[1] == 1000.0:
+            ev[3]["pipelined"] = 1
+    assert host_trace.reduce(piped)["clock_violations"] == 0
+
+
+def test_a_trace_without_spans_reduces_to_empty_tables():
+    """The parent of the PR that brought the spans: device planes only."""
+    bare = {"planes": [p for p in hand_made()["planes"] if "interval" in p]}
+    bare["planes"][0]["lines"][0]["events"] = [["jit_step(7)", 60.0, 140.0]]
+    r = host_trace.reduce(bare)
+    assert r["idle_by_phase"] == {} and r["decode_steps"] == [] and r["spans"] == 0
+    assert r["modules"] == {"jit_step": [1, pytest.approx(140e-9)]}
+    assert r["idle_s"] == pytest.approx(1010e-9)
+    assert host_trace.reduce({"planes": []})["window_s"] == 0.0
+
+
+def test_leaf_segments_name_every_moment_by_the_innermost_span():
+    segs = host_trace.leaf_segments([(0, 100, "a"), (10, 40, "b"), (20, 30, "c"),
+                                     (60, 90, "d"), (200, 300, "e")])
+    assert segs == [(0, 10, "a"), (10, 20, "b"), (20, 30, "c"), (30, 40, "b"),
+                    (40, 60, "a"), (60, 90, "d"), (90, 100, "a"), (200, 300, "e")]
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    with open(os.path.join(DATA, "trace_v5e_host_slice.json")) as f:
+        data = json.load(f)
+    for plane in data["planes"]:  # the operations' texts are stored once
+        for line in plane["lines"]:
+            names = line.pop("names", None)
+            if names:
+                line["events"] = [[names[i], s, d] for i, s, d in line["events"]]
+    return data
+
+
+def test_recorded_slice_phase_idle_sums_to_total_idle(recorded):
+    r = host_trace.reduce(recorded)
+    assert r["window_s"] > 0 and 0 < r["idle_s"] < r["window_s"]
+    assert sum(r["idle_by_phase"].values()) == pytest.approx(r["idle_s"])
+    # as trace.py bounds busy and idle on the same events
+    from perf import trace
+
+    t = trace.reduce(recorded)
+    assert r["window_s"] == pytest.approx(t["window_s"])
+    assert r["idle_s"] == pytest.approx(t["window_s"] - t["busy_s"])
+    # on the chip the gaps lie under the phases, almost all of them
+    assert r["idle_by_phase"]["unattributed"] < 0.05 * r["idle_s"]
+    assert r["clock_violations"] == 0
+
+
+def test_recorded_slice_steps_and_programs(recorded):
+    r = host_trace.reduce(recorded)
+    note = recorded["_note"]
+    assert r["steps_kept"] == note["steps_whole"] < note["steps_in_slice"]
+    assert set(r["modules"]) >= {"jit_pst_decode_step", "jit_pst_prefill_step"}
+    assert len(r["decode_steps"]) == note["decode_steps_whole"]
+    for step in r["decode_steps"]:
+        assert step["rows"] in (15, 16) and step["bucket"] == "b16"
+        assert 0 < step["attn_s"] < step["module_s"]
+        assert step["kv_pages"] * 128 >= step["kv_tokens"] > 15 * 3000
+    # the attention share of one step, by hand: its least bytes over the
+    # chip's bandwidth against the kernel's measured time
+    step = r["decode_steps"][0]
+    least = (step["kv_tokens"] * 2 * 8 * 128 * 32
+             + step["rows"] * 32 * 128 * 4 * 32) / 819e9
+    assert least / step["attn_s"] == pytest.approx(note["first_step_attn_share"],
+                                                    rel=1e-6)
+    assert 0 < least / step["attn_s"] <= 1.0

@@ -359,7 +359,6 @@ def _passing_round() -> dict:
     return {
         "backend": "tpu",
         "compile_polluted": False,
-        "host_gap_ms": 2.0,
         "roofline": {"achieved_fraction": 0.93},
         "sweep": [{"qps": 0.5, "p50_ttft_ms": 100.0, "p99_ttft_ms": 180.0}],
         "warm_restart": {"restart_to_ready_seconds": 12.0},
