@@ -6,8 +6,10 @@
 
 With the engine up once, sends the cell's check set for ``--seeds`` seeds,
 then hands every sequence to one reference child (``none`` and the negative
-control's variant) and prints, per seed, the share of clear positions and
-the largest clear / unclear error, for a sweep of ``delta``. The thresholds
+control's variant, one of the ``VARIANTS`` of the configuration's own
+reference module: the child refuses any other and names them) and prints,
+per seed, the share of clear positions and the largest clear / unclear
+error, for a sweep of ``delta``. The thresholds
 in the configuration file are set from this table; the parsed responses and
 the reference's numbers stay in ``--out`` for a closer look.
 """
@@ -20,12 +22,11 @@ import json
 import os
 import sys
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-ROOT = os.path.dirname(HERE)
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from perf import check, client, config as configs, harness, manifest  # noqa: E402
+from perf import check, client, config as configs, manifest  # noqa: E402
 from perf import run as runmod  # noqa: E402
 from perf.harness import log  # noqa: E402
 
@@ -79,19 +80,9 @@ def main(argv=None) -> int:
     with open(os.path.join(out_dir, "calib_parsed.json"), "w") as f:
         json.dump({str(k): v for k, v in per_seed.items()}, f)
     variants = ["none"] + ([args.negative] if args.negative else [])
-    request = {"config_file": cfg.path, "variants": variants,
-               "sequences": [{"id": p["id"], "tokens": p["tokens"],
-                              "n_prompt": p["n_prompt"], "want": p["want"]}
-                             for ps in per_seed.values() for p in ps if p["complete"]]}
-    req_path = os.path.join(out_dir, "calib_request.json")
-    res_path = os.path.join(out_dir, "calib_result.json")
-    with open(req_path, "w") as f:
-        json.dump(request, f)
-    harness.run_python_child(
-        "reference", [os.path.join(HERE, "reference", "run.py"), req_path, res_path],
-        harness.child_env(), out_dir, 3000)
-    with open(res_path) as f:
-        reference = json.load(f)
+    reference = runmod.reference_of(
+        cfg, [p for ps in per_seed.values() for p in ps], variants, out_dir,
+        3000)
     log(f"reference took {reference['seconds']:.1f}s on {reference['platform']}")
     tau = float(cfg.check["tau"])
     table = []

@@ -1,5 +1,6 @@
 """A configuration file: the published ``config.json`` keys at the top
-level, beside the benchmark's own keys (listed in ``OWN_KEYS``)."""
+level, beside the benchmark's own keys (``OWN_KEYS``, which every file has,
+and ``OPTIONAL_KEYS``)."""
 
 from __future__ import annotations
 
@@ -13,6 +14,10 @@ OWN_KEYS = (
     "source", "reduced", "published", "assumed", "deployment", "weights_seed",
     "engine_flags", "check",
 )
+# ``reference``: the module under ``perf/reference/`` that computes this
+# architecture's plain reference (``perf/reference/__init__.py``).
+OPTIONAL_KEYS = ("reference",)
+DEFAULT_REFERENCE = "mistral"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -23,6 +28,7 @@ class Config:
     engine_flags: tuple  # what defines the deployment, as server flags
     weights_seed: int
     check: dict  # delta / tau / tau_loose, with their reason
+    reference: str  # the plain reference's module, by name
     raw: dict
 
     def flag(self, name: str):
@@ -42,10 +48,11 @@ def load(path: str) -> Config:
     return Config(
         name=os.path.splitext(os.path.basename(path))[0],
         path=path,
-        hf={k: v for k, v in raw.items() if k not in OWN_KEYS},
+        hf={k: v for k, v in raw.items() if k not in OWN_KEYS + OPTIONAL_KEYS},
         engine_flags=tuple(str(x) for x in raw["engine_flags"]),
         weights_seed=int(raw["weights_seed"]),
         check=dict(raw["check"]),
+        reference=str(raw.get("reference", DEFAULT_REFERENCE)),
         raw=raw,
     )
 

@@ -1,11 +1,12 @@
 #!/usr/bin/env python3
 """The step loop's phases on the device trace's clock: what the host was
-doing while the device idled, the time of each step program, and each decode
-step's context lengths beside the time its attention kernels took.
+doing while the device idled (in sum, and for each of the longest idle
+stretches), the time of each step program, and each decode step's context
+lengths beside the time its attention kernels took.
 
     JAX_PLATFORMS=cpu python perf/host_trace.py <trace.xplane.pb> <out.json>
 
-Beside ``trace.py``, which it leaves as it is, and in the same two steps:
+Beside ``trace.py`` and in the same two steps:
 :func:`extract` reads the ``.xplane.pb`` into plain lists, :func:`reduce` is
 pure Python over those lists and is tested on a recorded slice.
 
@@ -26,6 +27,7 @@ the readers then leave their metrics out.
 
 from __future__ import annotations
 
+import bisect
 import glob
 import json
 import os
@@ -37,12 +39,14 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from perf import harness  # noqa: E402
-from perf.trace import DEVICE_PLANE, OPS_LINE, _union  # noqa: E402
+from perf.trace import DEVICE_PLANE, OPS_LINE, _union, short_name  # noqa: E402
 
 MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "pst."
 STEP, INFO, LAUNCH, WAIT = "pst.step", "pst.step_info", "pst.launch", "pst.wait"
+UNATTRIBUTED = "unattributed"
 ATTN_DECODE = re.compile(r"^%paged_attn_decode")
+GAPS_KEPT = 40  # kinds of idle stretch the reduction keeps, longest first
 # which step kind launches which program
 MODULE_KIND = (("jit_pst_decode", "decode"), ("jit_pst_prefill", "prefill"),
                ("jit_pst_spec", "spec_verify"))
@@ -116,18 +120,35 @@ def leaf_segments(spans: list) -> list:
     return segs
 
 
-def _overlap_by_name(intervals: list, segs: list) -> dict:
-    """Seconds of ``intervals`` under each segment name; both sorted."""
-    out, j = {}, 0
-    for s, e in intervals:
+def _idle_pieces(ops: list, idle: list, segs: list) -> list:
+    """[(span, around, seconds)] for every idle stretch of one device, cut
+    where the step thread's innermost span changes: that span
+    (``pst.wait``; ``unattributed`` under no span, or inside ``pst.step``
+    but under none of its phases), and the device operations on either side
+    of the whole stretch. ``idle`` and ``segs`` are sorted."""
+    ran = [(text, s, s + d) for text, s, d in ops if d > 0]
+    by_start = sorted(ran, key=lambda op: op[1])
+    by_end = sorted(ran, key=lambda op: op[2])
+    starts, ends = [op[1] for op in by_start], [op[2] for op in by_end]
+    out, j = [], 0
+    for s, e in idle:
+        i = bisect.bisect_right(ends, s + 1) - 1
+        n = bisect.bisect_left(starts, e - 1)
+        before = short_name(by_end[i][0]) if i >= 0 else "start of trace"
+        after = short_name(by_start[n][0]) if n < len(starts) else "end of trace"
+        around = f"after {before} / before {after}"[:180]
         while j < len(segs) and segs[j][1] <= s:
             j += 1
-        k = j
+        at, k = s, j
         while k < len(segs) and segs[k][0] < e:
             a, b = max(s, segs[k][0]), min(e, segs[k][1])
-            if b > a:
-                out[segs[k][2]] = out.get(segs[k][2], 0.0) + (b - a) / 1e9
-            k += 1
+            if a > at:
+                out.append((UNATTRIBUTED, around, (a - at) / 1e9))
+            span = UNATTRIBUTED if segs[k][2] == STEP else segs[k][2]
+            out.append((span, around, (b - a) / 1e9))
+            at, k = b, k + 1
+        if e > at:
+            out.append((UNATTRIBUTED, around, (e - at) / 1e9))
     return out
 
 
@@ -175,7 +196,10 @@ def reduce(extracted: dict) -> dict:
     thread wrote), ``idle_by_phase`` {phase: seconds of device idle while
     that was the step thread's innermost span; idle inside ``pst.step`` but
     under none of its phases, or under no span at all, is
-    ``"unattributed"``}, ``modules`` {program name without its id: [count,
+    ``"unattributed"``}, ``gaps`` (the idle stretches cut at the step
+    thread's span boundaries, the same kind many times over summed into one
+    entry ``{"name", "seconds", "count"}`` over all device planes, longest
+    first: see :func:`_idle_pieces`), ``modules`` {program name without its id: [count,
     seconds]}, ``decode_steps`` (per decode step wholly inside the traced
     interval and joined to the module it launched: its stats, ``module_s``,
     ``attn_s`` = time of the ``%paged_attn_decode*`` operations inside that
@@ -189,25 +213,21 @@ def reduce(extracted: dict) -> dict:
     segs = leaf_segments(spans)
     lo = min((p["interval"][0] for p in devices), default=0.0)
     hi = max((p["interval"][1] for p in devices), default=0.0)
-    idle_by_phase, idle_s, modules = {}, 0.0, {}
+    idle_by_phase, idle_s, modules, gaps = {UNATTRIBUTED: 0.0}, 0.0, {}, {}
     first_modules, first_ops = [], []
     for plane in devices:
         by_line = {ln["name"]: ln["events"] for ln in plane["lines"]}
         ops = by_line.get(OPS_LINE, [])
         idle = _complement(
             _union([[s, s + d] for _, s, d in ops if d > 0]), lo, hi)
-        total = sum(e - s for s, e in idle) / 1e9
-        idle_s += total / len(devices)
-        under = _overlap_by_name(idle, segs)
-        named = 0.0
-        for name, sec in under.items():
-            if name == STEP:
-                continue
-            phase = name[len(SPAN_PREFIX):]
+        idle_s += sum(e - s for s, e in idle) / 1e9 / len(devices)
+        for span, around, sec in _idle_pieces(ops, idle, segs):
+            phase = span[len(SPAN_PREFIX):] if span != UNATTRIBUTED else span
             idle_by_phase[phase] = idle_by_phase.get(phase, 0.0) + sec / len(devices)
-            named += sec
-        idle_by_phase["unattributed"] = (
-            idle_by_phase.get("unattributed", 0.0) + (total - named) / len(devices))
+            g = gaps.setdefault((span, around), {
+                "name": f"{span}: {around}", "seconds": 0.0, "count": 0})
+            g["seconds"] += sec
+            g["count"] += 1
         for name, s, d in by_line.get(MODULES_LINE, []):
             m = modules.setdefault(name.split("(")[0], [0, 0.0])
             m[0] += 1
@@ -247,6 +267,7 @@ def reduce(extracted: dict) -> dict:
         "idle_s": idle_s,
         "spans": len(thread),
         "idle_by_phase": idle_by_phase if thread else {},
+        "gaps": sorted(gaps.values(), key=lambda g: -g["seconds"])[:GAPS_KEPT],
         "modules": modules,
         "steps_kept": len(steps),
         "decode_steps": decode_steps,

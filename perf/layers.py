@@ -45,10 +45,14 @@ def reduce_trace(profile_dir: str, out_dir: str, extra_env: dict = None) -> dict
         return json.load(f)
 
 
-def breakdown(trace: dict) -> dict:
+def breakdown(trace: dict, host: dict = None) -> dict:
     """The ten device operations that took most time, and the ten longest
-    idle gaps. The program writes no host spans into the trace yet, so a
-    gap is named only by the device operations on either side of it."""
+    idle gaps by what the host was doing: each named by the step thread's
+    innermost ``pst.*`` span over it (``unattributed`` under none), then by
+    the device operations on either side (``host``: the reduction of
+    ``perf/host_trace.py``; without it no gap can be named and none is
+    given)."""
     ops = sorted(trace["ops"].items(), key=lambda kv: -kv[1])[:10]
+    gaps = (host or {}).get("gaps", [])[:10]
     return {"device_ops": [[k, v] for k, v in ops],
-            "idle_gaps": [[g["name"], g["seconds"]] for g in trace["gaps"][:10]]}
+            "idle_gaps": [[g["name"], g["seconds"]] for g in gaps]}

@@ -6,7 +6,7 @@ No kernels, no cache, no batching, and no import from
 convention, grouped-query causal attention computed in query blocks, SwiGLU,
 and for Mixtral the router (softmax over all experts, top-k, renormalise).
 One layer's weights are handed in at a time by the caller
-(:mod:`perf.reference.run`), which owns where they come from.
+(:mod:`perf.reference.mistral`), which owns where they come from.
 
 ``variant`` deliberately breaks one piece of mathematics, for the negative
 controls that show the check has power: ``no_renorm`` (top-k weights not

@@ -6,11 +6,17 @@ computes the plain reference for the check set.
 
 ``request.json``: ``{"config_file", "variants": [...], "sequences":
 [{"id", "tokens": [...], "n_prompt", "want": [[ids reported at generated
-position 0], ...]}]}``. Every sequence is teacher-forced on the system's
-own tokens (prompt + what it generated); for each generated position the
-result holds the reference's log-probability of every id in ``want``, its
-own arg-max, and the smallest top-k/next router-logit gap over the layers
-(``inf`` for a dense model).
+position 0], ...]}], "reference_dirs": [...]}`` (the last optional: where
+to look for the module before ``perf/reference/``). Every sequence is
+teacher-forced on the system's own tokens (prompt + what it generated); for
+each generated position the result holds the reference's log-probability of
+every id in ``want``, its own arg-max, and the smallest top-k/next
+router-logit gap over the layers (``inf`` for a model without a router).
+
+What is common to every architecture is here: the request, the compile
+cache, the configuration, the loop over variants, the result's shape. The
+equations are the module's that the configuration file names under
+``reference`` (``perf/reference/__init__.py`` has the interface).
 """
 
 from __future__ import annotations
@@ -41,59 +47,29 @@ def _place_compile_cache() -> None:
 
 def compute(request: dict, log=print) -> dict:
     import jax
-    import jax.numpy as jnp
-    import numpy as np
 
     from perf import config as configs
-    from perf.reference import model as ref
-    from perf.reference import weights
+    from perf import reference
 
     t0 = time.monotonic()
     cfg = configs.load(request["config_file"])
-    hf = cfg.hf
-    model_cfg = configs.program_model_config(cfg)
-    params = weights.engine_params(
-        model_cfg, cfg.weights_seed, cfg.flag("--quantization"))
+    module = reference.load(cfg.reference, request.get("reference_dirs"))
+    variants = request.get("variants") or ["none"]
+    unknown = [v for v in variants if v not in module.VARIANTS]
+    if unknown:
+        raise ValueError(f"unknown variant {unknown}: {module.__file__} has "
+                         f"{list(module.VARIANTS)}")
+    params = module.weights(cfg)
     jax.block_until_ready(params)
-    log(f"[reference] weights ready +{time.monotonic() - t0:.1f}s on "
-        f"{jax.devices()[0].platform}")
-    n_layers = hf["num_hidden_layers"]
-    n_heads = hf["num_attention_heads"]
-    n_kv = hf.get("num_key_value_heads", n_heads)
-    head_dim = hf.get("head_dim") or hf["hidden_size"] // n_heads
-    eps = float(hf.get("rms_norm_eps", 1e-5))
-    top_k = int(hf.get("num_experts_per_tok", 2))
+    log(f"[reference] {cfg.reference}: weights ready "
+        f"+{time.monotonic() - t0:.1f}s on {jax.devices()[0].platform}")
     seqs = request["sequences"]
     out = {}
-    for variant in request.get("variants") or ["none"]:
-        if variant not in ref.VARIANTS:
-            raise ValueError(f"unknown variant {variant!r}")
-        theta = 1e4 if variant == "rope_1e4" else float(hf["rope_theta"])
-        xs, tabs, gaps = [], [], []
-        for s in seqs:
-            padded = ref.pad_len(len(s["tokens"]))
-            ids = np.zeros(padded, np.int32)
-            ids[: len(s["tokens"])] = s["tokens"]
-            xs.append(weights.embed_rows(params, jnp.asarray(ids)))
-            cos, sin = ref.rope_tables(padded, head_dim, theta)
-            tabs.append((jnp.asarray(cos), jnp.asarray(sin)))
-            gaps.append(np.full(padded, np.inf, np.float32))
-        for li in range(n_layers):
-            lw = weights.layer_weights(params, li)
-            for i in range(len(seqs)):
-                xs[i], gap = ref.layer(
-                    xs[i], tabs[i][0], tabs[i][1], lw, n_heads=n_heads,
-                    n_kv=n_kv, top_k=top_k, eps=eps,
-                    renorm=variant != "no_renorm")
-                gaps[i] = np.minimum(gaps[i], np.asarray(gap))
-            del lw
-        final_norm, lm_head = weights.head_weights(params)
+    for variant in variants:
         results = []
-        for i, s in enumerate(seqs):
-            n_prompt, n_gen = s["n_prompt"], len(s["want"])
-            rows = jnp.arange(n_prompt - 1, n_prompt - 1 + n_gen)
-            lps = np.asarray(ref.head_logprobs(
-                xs[i][rows], final_norm, lm_head, eps=eps))
+        for s, (lps, gap) in zip(
+                seqs, module.teacher_force(cfg, params, seqs, variant)):
+            n_gen = len(s["want"])
             results.append({
                 "id": s["id"],
                 "logprobs": [
@@ -101,11 +77,10 @@ def compute(request: dict, log=print) -> dict:
                     for p in range(n_gen)
                 ],
                 "argmax": [int(a) for a in lps.argmax(-1)],
-                "gap": [float(g) for g in
-                        gaps[i][n_prompt - 1: n_prompt - 1 + n_gen]],
+                "gap": [float("inf")] * n_gen if gap is None
+                       else [float(g) for g in gap],
             })
         out[variant] = results
-        del lm_head
         log(f"[reference] variant {variant}: {len(seqs)} sequences, "
             f"{sum(len(s['tokens']) for s in seqs)} tokens "
             f"+{time.monotonic() - t0:.1f}s")

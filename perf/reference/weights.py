@@ -1,10 +1,13 @@
 """Weights are data: the reference holds the same arrays as the engine.
+What every reference module shares: the recipe, the dequantisation of the
+documented layouts, the embedding rows and the head.
 
 The engine makes a preset's weights from its ``--seed`` by a recipe of its
-own; this module follows that recipe with the program's *initialisers* (so
-the numbers are identical) and nothing of its forward pass:
+own; :func:`engine_params` follows that recipe with the *initialisers* of
+the program's model object, which the reference module hands in (so the
+numbers are identical), and nothing of its forward pass:
 
-- unquantised: ``Llama.init_params(PRNGKey(seed))`` under one ``jit``
+- unquantised: ``model.init_params(PRNGKey(seed))`` under one ``jit``
   (``engine/runner.py::_init_params_sharded``);
 - int4 / int8: leaf by leaf, key ``fold_in(PRNGKey(seed), xxh32(name))``,
   ``init_leaf`` then ``quantize_leaf_int4`` (layer matmuls, int4 mode) or
@@ -22,14 +25,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-LAYER_MATMULS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 
-
-def engine_params(model_cfg, seed: int, quantization):
-    """The parameter tree the engine serves for ``--seed seed``."""
+def engine_params(model, seed: int, quantization):
+    """The parameter tree the engine serves for ``--seed seed``; ``model``
+    is the program's model object for the configuration."""
     from production_stack_tpu.models import llama as prog
 
-    model = prog.Llama(model_cfg)
     rng = jax.random.PRNGKey(seed)
     if not quantization:
         return jax.jit(model.init_params)(rng)
@@ -80,40 +81,14 @@ def _dequant_int4(packed, scales):
 
 
 @jax.jit
-def _matmul_leaf(w, q4s, qs):
+def matmul_leaf(w, q4s, qs):
+    """One stored matmul leaf in float32: int4 with its group scales
+    ``q4s``, int8 with its column scales ``qs``, or neither."""
     if q4s is not None:
         return _dequant_int4(w, q4s)
     if qs is not None:
         return w.astype(jnp.float32) * qs[..., None, :]
     return w.astype(jnp.float32)
-
-
-def layer_weights(params, li: int):
-    """Layer ``li``'s weights for :func:`perf.reference.model.layer`, in
-    float32. A MoE layer's unquantised expert banks are the exception: a
-    Mixtral layer's experts are 5.6 GB in float32 and even one layer's
-    slice of the stored bank is a copy the chip has no room for beside the
-    tree, so the whole stacked bank is handed on untouched with ``li``
-    beside it, and the layer widens one expert at a time."""
-    layers = params["layers"]
-    moe = "w_router" in layers
-    out = {}
-    for name, leaf in layers.items():
-        if name.endswith(("_qs", "_q4s")) or name.startswith("lora_"):
-            continue
-        if name in LAYER_MATMULS:
-            q4s = layers.get(name + "_q4s")
-            qs = layers.get(name + "_qs")
-            if moe and name in ("w_gate", "w_up", "w_down") and q4s is None and qs is None:
-                out[name] = leaf
-                out["li"] = jnp.int32(li)
-            else:
-                out[name] = _matmul_leaf(
-                    leaf[li], None if q4s is None else q4s[li],
-                    None if qs is None else qs[li])
-        else:
-            out[name] = leaf[li].astype(jnp.float32)
-    return out
 
 
 @jax.jit

@@ -125,6 +125,33 @@ def prefill_contexts(engine: Engine, prompts: list) -> None:
             raise BenchError(f"set-up prefill failed: {e}") from e
 
 
+def reference_of(cfg, parsed: list, variants: list, out_dir: str,
+                 timeout: float, extra_env: dict = None,
+                 reference_dirs: list = None) -> dict:
+    """Child 2 (``reference/run.py``) over the complete responses: the
+    plain reference of the configuration's own module, teacher-forced on
+    the system's tokens, for each of ``variants``. The request and the
+    result stay in ``out_dir`` as ``reference_request.json`` / ``_result.json``."""
+    request = {"config_file": cfg.path, "variants": variants,
+               "reference_dirs": list(reference_dirs or []),
+               "sequences": [{"id": p["id"], "tokens": p["tokens"],
+                              "n_prompt": p["n_prompt"], "want": p["want"]}
+                             for p in parsed if p["complete"]]}
+    req_path = os.path.join(out_dir, "reference_request.json")
+    res_path = os.path.join(out_dir, "reference_result.json")
+    with open(req_path, "w") as f:
+        json.dump(request, f)
+    if not request["sequences"]:
+        return {"variants": {v: [] for v in variants}, "seconds": 0.0,
+                "platform": None}
+    harness.run_python_child(
+        "reference", [os.path.join(HERE, "reference", "run.py"), req_path,
+                      res_path],
+        harness.child_env(extra_env), out_dir, timeout)
+    with open(res_path) as f:
+        return json.load(f)
+
+
 def _keep(out_dir: str, name: str, obj) -> None:
     """Intermediate files for a look by hand; nothing reads them back."""
     with open(os.path.join(out_dir, name), "w") as f:
@@ -226,25 +253,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         engine.stop()
 
     t0 = time.monotonic()
-    request = {"config_file": cfg.path, "variants": ["none"],
-               "sequences": [{"id": p["id"], "tokens": p["tokens"],
-                              "n_prompt": p["n_prompt"], "want": p["want"]}
-                             for p in parsed if p["complete"]]}
-    req_path = os.path.join(out_dir, "reference_request.json")
-    res_path = os.path.join(out_dir, "reference_result.json")
-    with open(req_path, "w") as f:
-        json.dump(request, f)
-    reference = {"variants": {"none": []}}
-    if request["sequences"]:
-        harness.run_python_child(
-            "reference", [os.path.join(HERE, "reference", "run.py"), req_path,
-                          res_path],
-            harness.child_env(extra_env), out_dir, REFERENCE_TIMEOUT_S)
-        with open(res_path) as f:
-            reference = json.load(f)
+    reference = reference_of(cfg, parsed, ["none"], out_dir,
+                             REFERENCE_TIMEOUT_S, extra_env,
+                             data_dirs.get("reference"))
     timings["reference_s"] = time.monotonic() - t0
     verdict = check.compare(parsed, reference["variants"]["none"], cfg.check)
-    log("check: " + json.dumps({k: v for k, v in verdict.items() if k != "detail"}))
+    compared = "check: " + json.dumps(
+        {k: v for k, v in verdict.items() if k != "detail"})
+    log(compared)
+    # each number compared beside its limit, on standard error too: what a
+    # record keeps of a run that is not correct
+    print(compared, file=sys.stderr, flush=True)
     log("set-up split: " + json.dumps(timings))
 
     peak = max((m.get("peak_bytes_in_use") or m.get("bytes_in_use") or 0)
@@ -263,7 +282,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     if not trace:
         result["metrics"] = end_to_end.metrics(bench, cell, ctx)
         return result
-    from perf import layers
+    from perf import host_trace, layers
 
     if "error" in profile or (profile.get("response") or {}).get("status") != "ok":
         if require_chip:
@@ -273,7 +292,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         ctx["trace"] = layers.reduce_trace(engine.profile_dir, out_dir, extra_env)
         device["busy_s"] = ctx["trace"]["busy_s"]
         device["window_s"] = ctx["trace"]["window_s"]
-        result["breakdown"] = layers.breakdown(ctx["trace"])
+        result["breakdown"] = layers.breakdown(
+            ctx["trace"], host_trace.of_run(ctx))
     result["metrics"] = layers.metrics(
         bench, cell, ctx, data_dirs.get("layer_metrics"))
     return result

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """From a profiler trace to numbers: device busy and idle time, time per
-device operation, the longest idle gaps, and the calls a roofline reader
-needs.
+device operation, and the calls a roofline reader needs. (The idle
+stretches, each under what the host was doing, are ``host_trace.py``'s.)
 
     JAX_PLATFORMS=cpu python perf/trace.py <trace.xplane.pb> <out.json>
 
@@ -89,17 +89,16 @@ def reduce(extracted: dict) -> dict:
     """-> ``busy_s`` and ``window_s`` (busy averaged over the device
     planes; the window is the traced interval as the devices saw it, first
     to last event of any device plane: the host's lines run on for a few
-    tenths of a second after the device tracer has stopped), ``ops`` {name: self seconds, summed over chips}, ``gaps`` (idle
-    stretches, longest first, named by the operations around them) and
-    ``calls`` (one entry per distinct instruction text, with its short
-    name, count and self seconds)."""
+    tenths of a second after the device tracer has stopped), ``ops`` {name:
+    self seconds, summed over chips} and ``calls`` (one entry per distinct
+    instruction text, with its short name, count and self seconds)."""
     devices = [p for p in extracted["planes"] if DEVICE_PLANE.match(p["name"])]
     lo, hi = float("inf"), float("-inf")
     for plane in devices:
         for line in plane["lines"]:
             for _, s, d in line["events"]:
                 lo, hi = min(lo, s), max(hi, s + d)
-    busy, ops, calls, gaps = [], {}, {}, []
+    busy, ops, calls = [], {}, {}
     for plane in devices:
         lines = [ln for ln in plane["lines"] if ln["name"] == OPS_LINE] or [
             ln for ln in plane["lines"] if ln["name"] not in ("Steps", "XLA Modules")]
@@ -114,32 +113,11 @@ def reduce(extracted: dict) -> dict:
                                             "count": 0, "seconds": 0.0})
                 c["count"] += 1
                 c["seconds"] += self_ns / 1e9
-        by_end = sorted(events, key=lambda e: e[1] + e[2])
-        by_start = sorted(events, key=lambda e: e[1])
-        edges = [[lo, lo]] + merged + [[hi, hi]]
-        for (_, a_end), (b_start, _) in zip(edges, edges[1:]):
-            if b_start - a_end <= 0:
-                continue
-            before = next((short_name(e[0]) for e in reversed(by_end)
-                           if e[1] + e[2] <= a_end + 1), "start of trace")
-            after = next((short_name(e[0]) for e in by_start
-                          if e[1] >= b_start - 1), "end of trace")
-            gaps.append({"name": f"after {before} / before {after}"[:180],
-                         "seconds": (b_start - a_end) / 1e9,
-                         "plane": plane["name"]})
-    gaps.sort(key=lambda g: -g["seconds"])
-    grouped: dict = {}
-    for g in gaps:  # the same kind of gap many times over: one entry, summed
-        e = grouped.setdefault(g["name"], {"name": g["name"], "seconds": 0.0, "count": 0})
-        e["seconds"] += g["seconds"]
-        e["count"] += 1
     return {
         "window_s": (hi - lo) / 1e9 if devices and hi > lo else 0.0,
         "busy_s": sum(busy) / len(busy) if busy else 0.0,
         "device_planes": [p["name"] for p in devices],
         "ops": ops,
-        "gaps": sorted(grouped.values(), key=lambda g: -g["seconds"]),
-        "longest_gap_s": gaps[0]["seconds"] if gaps else 0.0,
         "calls": sorted(calls.values(), key=lambda c: -c["seconds"]),
     }
 

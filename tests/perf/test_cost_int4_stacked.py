@@ -129,7 +129,9 @@ def test_benchmark_lists_the_metric_for_the_int4_cell():
     assert entry == {
         "name": entry["name"], "unit": "%",
         "better": "higher", "source": "device_trace", "layer": "kernels",
-        "moves": "itl_p50_ms"}  # no cell list: the benchmark's metrics have none
+        "moves": "itl_p50_ms",
+        # its cost function counts int4 leaves: it lists the int4 cell
+        "workloads": ["mistral-7b-int4.sessions-closed"]}
     names = [m["name"] for m in manifest.metrics_of(
         bench, "per_layer", "mistral-7b-int4.sessions-closed")]
     assert "kernel.int4_matmul_stacked_roofline" in names

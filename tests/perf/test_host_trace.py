@@ -8,7 +8,7 @@ import os
 
 import pytest
 
-from perf import host_trace
+from perf import host_trace, layers
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 ATTN = "%paged_attn_decode.3 = bf16[2,32,128]{2,1,0} custom-call(s32[2,64]{1,0} %t)"
@@ -96,6 +96,34 @@ def test_idle_is_attributed_to_the_innermost_phase():
     assert r["spans"] == len(hand_made()["planes"][0]["lines"][1]["events"])
 
 
+def test_idle_stretches_are_cut_at_span_boundaries_and_named_by_span_and_neighbours():
+    r = host_trace.reduce(hand_made())
+    gaps = {g["name"]: g for g in r["gaps"]}
+    decode = "%paged_attn_decode.3 bf16[2,32,128]"
+    # the stretch [1350, 1600] between the third decode program and the
+    # prefill: postprocess to 1480, nothing to 1500, no_work, intake, ...
+    between = f"after {decode} / before %paged_attn_prefill.5 bf16[1,64,32,128]"
+    assert r["gaps"][0] == {"name": f"pst.postprocess: {between}",
+                            "seconds": pytest.approx(130e-9), "count": 1}
+    assert gaps[f"unattributed: {between}"]["seconds"] == pytest.approx(20e-9)
+    assert gaps[f"pst.no_work: {between}"]["seconds"] == pytest.approx(50e-9)
+    # the same kind of stretch twice, [200, 230] and [390, 400]: one entry
+    twice = gaps[f"pst.wait: after {decode} / before %fusion.1 f32[2]"]
+    assert (twice["count"], twice["seconds"]) == (2, pytest.approx(40e-9))
+    assert gaps["pst.wait: after start of trace / before %fusion.1 f32[2]"][
+        "seconds"] == pytest.approx(60e-9)
+    assert ("unattributed: after %paged_attn_prefill.5 bf16[1,64,32,128] / "
+            "before end of trace") in gaps  # 1950-2000, under no span
+    # the stretches are the idle time, and each span's are its share of it
+    assert sum(g["seconds"] for g in r["gaps"]) == pytest.approx(r["idle_s"])
+    for phase, seconds in r["idle_by_phase"].items():
+        span = phase if phase == "unattributed" else f"pst.{phase}"
+        assert sum(g["seconds"] for g in r["gaps"]
+                   if g["name"].startswith(span + ": ")) == pytest.approx(seconds)
+    seconds = [g["seconds"] for g in r["gaps"]]
+    assert seconds == sorted(seconds, reverse=True)
+
+
 def test_steps_cut_by_an_edge_are_dropped_and_the_rest_joined_in_order():
     r = host_trace.reduce(hand_made())
     assert r["steps_kept"] == 3  # the first pst.step began before the interval
@@ -131,6 +159,8 @@ def test_a_trace_without_spans_reduces_to_empty_tables():
     bare["planes"][0]["lines"][0]["events"] = [["jit_step(7)", 60.0, 140.0]]
     r = host_trace.reduce(bare)
     assert r["idle_by_phase"] == {} and r["decode_steps"] == [] and r["spans"] == 0
+    assert r["gaps"] and all(g["name"].startswith("unattributed: after ")
+                             for g in r["gaps"])
     assert r["modules"] == {"jit_step": [1, pytest.approx(140e-9)]}
     assert r["idle_s"] == pytest.approx(1010e-9)
     assert host_trace.reduce({"planes": []})["window_s"] == 0.0
@@ -188,3 +218,29 @@ def test_recorded_slice_steps_and_programs(recorded):
     assert least / step["attn_s"] == pytest.approx(note["first_step_attn_share"],
                                                     rel=1e-6)
     assert 0 < least / step["attn_s"] <= 1.0
+
+
+def test_breakdown_names_the_recorded_gaps_by_what_the_host_was_doing(recorded):
+    """On the chip the device idles between one step's last copy to the host
+    and the next step's first copy in; which part of the step loop that time
+    is under is what the breakdown's names now say."""
+    from perf import trace
+
+    r = host_trace.reduce(recorded)
+    b = layers.breakdown(trace.reduce(recorded), r)
+    assert 1 <= len(b["idle_gaps"]) <= 10
+    around = "after %copy-done.1 f32[32000] / before %copy-start f32[32000]"
+    names = [name for name, _ in b["idle_gaps"]]
+    assert names[:3] == [f"pst.wait: {around}", f"pst.launch: {around}",
+                         f"pst.postprocess: {around}"]
+    for name, seconds in b["idle_gaps"]:
+        span, _, rest = name.partition(": ")
+        assert span == "unattributed" or span.startswith("pst.")
+        assert rest.startswith("after ") and " / before " in rest
+        assert isinstance(seconds, float) and seconds > 0
+    assert "pst.step" not in {n.split(": ")[0] for n in names}
+    # five whole stretches between steps, each cut at the phases' edges
+    by_span = {n.split(": ")[0]: s for n, s in b["idle_gaps"] if n.endswith(around)}
+    assert by_span["pst.wait"] == pytest.approx(0.011827138, rel=1e-6)
+    assert by_span["pst.launch"] == pytest.approx(0.01101854, rel=1e-6)
+    assert sum(s for _, s in b["idle_gaps"]) == pytest.approx(r["idle_s"], rel=1e-3)

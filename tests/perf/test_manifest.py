@@ -161,26 +161,60 @@ def test_peaks_table_names_its_source():
                    "hbm_bytes": 16e9, "hbm_bytes_per_s": 819e9}
 
 
+KERNEL_METRICS = ("kernel.int4_matmul_roofline",
+                  "kernel.int4_matmul_stacked_roofline",
+                  "kernel.paged_attn_decode_roofline")
+INT4_CELL = "mistral-7b-int4.sessions-closed"
+
+
+def _later(bench):
+    """The benchmark as a later PR leaves it: one more configuration (not
+    int4, other kernels) and a cell of it, as entries and files of its own."""
+    return dict(bench,
+                configs=bench["configs"] + [{
+                    "name": "tiny-moe", "source": "test only", "reduced": [],
+                    "file": "tests/perf/data/configs/tiny-moe.json", "why": "test"}],
+                workloads=bench["workloads"] + [{
+                    "name": "tiny-moe.tiny-closed", "config": "tiny-moe",
+                    "traffic": "tiny-closed", "chips": 1, "why": "test"}])
+
+
 def test_a_new_cell_is_one_entry_and_touches_no_file_that_is_there(bench):
-    """Every metric of the benchmark applies to every cell (none has a
-    ``workloads`` list), so a later PR's cell is an entry of ``workloads``
-    (plus, for a new configuration or mix, files of their own)."""
-    assert not any("workloads" in m for m in bench["end_to_end"] + bench["per_layer"])
+    """Every end-to-end metric and every per-layer metric that reads the
+    program's generic spans, counters and step programs applies to every
+    cell, so a later PR's cell is an entry of ``workloads`` (plus, for a new
+    configuration or mix, files of their own)."""
+    assert not any("workloads" in m for m in bench["end_to_end"])
+    listed = {m["name"] for m in bench["per_layer"] if "workloads" in m}
+    assert listed == set(KERNEL_METRICS)
     data = os.path.join(os.path.dirname(__file__), "data")
-    later = dict(bench,
-                 configs=bench["configs"] + [{
-                     "name": "tiny-moe", "source": "test only", "reduced": [],
-                     "file": "tests/perf/data/configs/tiny-moe.json", "why": "test"}],
-                 workloads=bench["workloads"] + [{
-                     "name": "tiny-moe.tiny-closed", "config": "tiny-moe",
-                     "traffic": "tiny-closed", "chips": 1, "why": "test"}])
+    later = _later(bench)
     cell = manifest.cell(later, "tiny-moe.tiny-closed")
     assert configs.load(cell["config_file"]).hf["num_local_experts"] == 4
     assert manifest.load_mix(cell["traffic"], [os.path.join(data, "traffic")])
-    for group in ("end_to_end", "per_layer"):
-        assert manifest.metrics_of(later, group, cell["name"]) == bench[group]
-    for m in bench["per_layer"]:  # and every reader is found for it as it is
+    assert manifest.metrics_of(later, "end_to_end", cell["name"]) == bench["end_to_end"]
+    generic = [m for m in bench["per_layer"] if m["name"] not in KERNEL_METRICS]
+    assert manifest.metrics_of(later, "per_layer", cell["name"]) == generic
+    for m in generic:  # and every reader is found for it as it is
         assert manifest.load_layer_metric(m["name"])["reader"]
+
+
+@pytest.mark.parametrize("metric", KERNEL_METRICS)
+def test_a_kernel_metric_names_the_cells_whose_kernels_it_reads(bench, metric):
+    """Its cost function counts one architecture's and one quantisation's
+    work (int4 leaves; keys and values of every layer, read whole): it lists
+    the int4 cell, a cell that does not list it owes no such line, and
+    nothing else about the entry or its file changed."""
+    entry = next(m for m in bench["per_layer"] if m["name"] == metric)
+    assert entry == {"name": metric, "unit": "%", "better": "higher",
+                     "source": "device_trace", "layer": "kernels",
+                     "moves": "itl_p50_ms", "workloads": [INT4_CELL]}
+    later = _later(bench)
+    assert entry in manifest.metrics_of(later, "per_layer", INT4_CELL)
+    assert entry not in manifest.metrics_of(later, "per_layer", "tiny-moe.tiny-closed")
+    spec = manifest.load_layer_metric(metric)
+    assert "workloads" not in spec and INT4_CELL not in json.dumps(spec)
+    assert len(manifest.metrics_of(bench, "per_layer", INT4_CELL)) == 38
 
 
 def test_a_new_mix_and_metric_are_found_without_editing_perf():

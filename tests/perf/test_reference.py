@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from perf import check, config as configs
+from perf.reference import mistral
 from perf.reference import model as ref
 from perf.reference import weights
 from tests import numpy_reference
@@ -32,7 +33,7 @@ def _reference_logprobs(cfg, params, tokens, variant="none"):
     gaps = np.full(padded, np.inf, np.float32)
     for li in range(hf["num_hidden_layers"]):
         x, gap = ref.layer(
-            x, jnp.asarray(cos), jnp.asarray(sin), weights.layer_weights(params, li),
+            x, jnp.asarray(cos), jnp.asarray(sin), mistral.layer_weights(params, li),
             n_heads=n_heads, n_kv=hf["num_key_value_heads"],
             top_k=hf.get("num_experts_per_tok", 2), eps=hf["rms_norm_eps"],
             renorm=variant != "no_renorm")
@@ -54,9 +55,7 @@ def _oracle_logprobs(cfg, params, tokens):
 @pytest.fixture(scope="module", params=["tiny-dense-int4", "tiny-moe"])
 def built(request):
     cfg = configs.load(os.path.join(DATA, "configs", f"{request.param}.json"))
-    params = weights.engine_params(
-        configs.program_model_config(cfg), cfg.weights_seed, cfg.flag("--quantization"))
-    return cfg, params
+    return cfg, mistral.weights(cfg)
 
 
 def test_reference_agrees_with_the_numpy_oracle(built):

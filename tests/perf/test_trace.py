@@ -1,12 +1,14 @@
 """The reduction from a device trace to busy/idle time, per-operation time
-and idle gaps, on small recorded traces with known answers."""
+and idle gaps, on small recorded traces with known answers. These traces
+carry no ``pst.*`` span, so every gap is ``unattributed`` and named by its
+neighbours alone (``test_host_trace.py`` has the spans)."""
 
 import json
 import os
 
 import pytest
 
-from perf import layers, trace
+from perf import host_trace, layers, trace
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 
@@ -25,6 +27,17 @@ SMALL = {"planes": [
             ["%int4_matmul.3 = f32[8,512]{1,0} custom-call(bf16[8,64]{1,0} %a)", 1.5e9, 0.5e9],
             ["%fusion.2 = f32[8,128]{1,0} fusion(f32[8,128]{1,0} %p)", 2.5e9, 0.5e9]]}]},
 ]}
+
+
+def _gaps(extracted: dict) -> list:
+    """``host_trace.reduce``'s gaps of a trace in ``trace.extract``'s form
+    (``host_trace.extract`` gives each device plane its ``interval``)."""
+    planes = []
+    for plane in extracted["planes"]:
+        spans = [(s, s + d) for ln in plane["lines"] for _, s, d in ln["events"]]
+        planes.append(dict(plane, interval=[min(s for s, _ in spans),
+                                            max(e for _, e in spans)]))
+    return host_trace.reduce({"planes": planes})["gaps"]
 
 
 def test_busy_idle_and_window():
@@ -46,12 +59,14 @@ def test_per_operation_time_is_self_time():
 
 
 def test_idle_gaps_longest_first_and_named_by_their_neighbours():
-    r = trace.reduce(SMALL)
-    assert [g["seconds"] for g in r["gaps"]] == pytest.approx([0.5, 0.5, 0.5])
-    names = {g["name"] for g in r["gaps"]}
-    assert "after %int4_matmul.3 f32[8,512] / before %fusion.2 f32[8,128]" in names
-    assert r["longest_gap_s"] == pytest.approx(0.5)
-    assert sum(g["seconds"] for g in r["gaps"]) + r["busy_s"] == pytest.approx(3.0)
+    r, gaps = trace.reduce(SMALL), _gaps(SMALL)
+    assert [g["seconds"] for g in gaps] == pytest.approx([0.5, 0.5, 0.5])
+    names = {g["name"] for g in gaps}
+    assert ("unattributed: after %int4_matmul.3 f32[8,512] / "
+            "before %fusion.2 f32[8,128]") in names
+    assert "unattributed: after start of trace / before %while.1 s32[]" in names
+    assert "unattributed: after %fusion.2 f32[8,128] / before end of trace" in names
+    assert sum(g["seconds"] for g in gaps) + r["busy_s"] == pytest.approx(3.0)
 
 
 def test_two_chips_average_busy_and_sum_operations():
@@ -72,9 +87,15 @@ def test_a_trace_without_device_operations_has_no_busy_time():
 
 def test_breakdown_has_at_most_ten_entries_each():
     r = trace.reduce(SMALL)
-    b = layers.breakdown(r)
+    many = [{"name": f"pst.wait: after a / before b{i}", "seconds": 1.0 / (i + 1)}
+            for i in range(25)]
+    b = layers.breakdown(r, {"gaps": many})
     assert b["device_ops"][0] == ["%fusion.2 f32[8,128]", pytest.approx(0.9)]
-    assert len(b["device_ops"]) <= 10 and len(b["idle_gaps"]) <= 10
+    assert len(b["device_ops"]) <= 10 and len(b["idle_gaps"]) == 10
+    assert b["idle_gaps"][0] == ["pst.wait: after a / before b0", 1.0]
+    assert layers.breakdown(r, {"gaps": _gaps(SMALL)})["idle_gaps"][0][1] == 0.5
+    # the host trace could not be reduced: no gap is named, none is given
+    assert layers.breakdown(r)["idle_gaps"] == []
     assert all(isinstance(s, float) for _, s in b["device_ops"] + b["idle_gaps"])
 
 
@@ -93,9 +114,10 @@ def test_recorded_slice_busy_idle_and_gap(recorded):
     assert r["busy_s"] == pytest.approx(0.005743167, rel=1e-6)
     assert sum(r["ops"].values()) == pytest.approx(r["busy_s"], rel=1e-6)
     # the gap between two decode steps, while the host prepares the next
-    assert r["longest_gap_s"] == pytest.approx(0.008647525, rel=1e-6)
-    assert r["gaps"][0]["name"] == (
-        "after %copy-done.1 f32[32000] / before %copy-start f32[32000]")
+    longest = _gaps(recorded)[0]
+    assert longest["seconds"] == pytest.approx(0.008647525, rel=1e-6)
+    assert longest["name"] == ("unattributed: after %copy-done.1 f32[32000] / "
+                               "before %copy-start f32[32000]")
 
 
 def test_recorded_slice_per_operation_times_and_roofline(recorded):
