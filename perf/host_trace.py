@@ -2,9 +2,10 @@
 """The step loop's phases on the device trace's clock: what the host was
 doing while the device idled (in sum, and for each of the longest idle
 stretches), the time of each step program, and each decode step's context
-lengths beside the time its attention kernels took.
+lengths beside the time that the device operations a per-layer metric names
+took inside it.
 
-    JAX_PLATFORMS=cpu python perf/host_trace.py <trace.xplane.pb> <out.json>
+    JAX_PLATFORMS=cpu python perf/host_trace.py <trace.xplane.pb> <out.json> [<ops pattern> ...]
 
 Beside ``trace.py`` and in the same two steps:
 :func:`extract` reads the ``.xplane.pb`` into plain lists, :func:`reduce` is
@@ -45,7 +46,6 @@ MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "pst."
 STEP, INFO, LAUNCH, WAIT = "pst.step", "pst.step_info", "pst.launch", "pst.wait"
 UNATTRIBUTED = "unattributed"
-ATTN_DECODE = re.compile(r"^%paged_attn_decode")
 GAPS_KEPT = 40  # kinds of idle stretch the reduction keeps, longest first
 # which step kind launches which program
 MODULE_KIND = (("jit_pst_decode", "decode"), ("jit_pst_prefill", "prefill"),
@@ -190,8 +190,10 @@ def _steps(events: list, lo: float, hi: float) -> list:
     return [st for st in steps if st["start"] >= lo and st["end"] <= hi]
 
 
-def reduce(extracted: dict) -> dict:
-    """-> ``window_s`` and ``idle_s`` (as ``trace.py`` has them, averaged
+def reduce(extracted: dict, step_ops: tuple = ()) -> dict:
+    """``step_ops``: patterns over a device operation's text, each asked for by a
+    per-layer metric (``params.ops`` of a ``trace_step_roofline`` metric).
+    -> ``window_s`` and ``idle_s`` (as ``trace.py`` has them, averaged
     over the device planes), ``spans`` (how many ``pst.*`` events the step
     thread wrote), ``idle_by_phase`` {phase: seconds of device idle while
     that was the step thread's innermost span; idle inside ``pst.step`` but
@@ -202,11 +204,12 @@ def reduce(extracted: dict) -> dict:
     first: see :func:`_idle_pieces`), ``modules`` {program name without its id: [count,
     seconds]}, ``decode_steps`` (per decode step wholly inside the traced
     interval and joined to the module it launched: its stats, ``module_s``,
-    ``attn_s`` = time of the ``%paged_attn_decode*`` operations inside that
-    module), ``steps_kept`` and ``clock_violations`` (programs that start
-    before their ``pst.launch`` opens or, where the step fetches what it
-    launched, end after its ``pst.wait`` closes: host and device clocks that
-    disagree, or a join that went wrong)."""
+    ``ops_s`` = {pattern: time of the operations inside that module whose
+    text the pattern finds} for each of ``step_ops``), ``steps_kept`` and
+    ``clock_violations`` (programs that start before their ``pst.launch``
+    opens or, where the step fetches what it launched, end after its
+    ``pst.wait`` closes: host and device clocks that disagree, or a join
+    that went wrong)."""
     devices = [p for p in extracted["planes"] if DEVICE_PLANE.match(p["name"])]
     thread = _step_thread(extracted)
     spans = [(ev[1], ev[1] + ev[2], ev[0]) for ev in thread if ev[0] != INFO]
@@ -241,6 +244,7 @@ def reduce(extracted: dict) -> dict:
     # kind the n-th launch is the n-th module, once the modules launched
     # before the first whole step are set aside.
     steps = _steps(thread, lo, hi)
+    patterns = [(p, re.compile(p)) for p in dict.fromkeys(step_ops)]
     decode_steps, violations = [], 0
     for kind in {k for _, k in MODULE_KIND}:
         launches = [(st, ln) for st in steps for ln in st["launch"] if ln[2] == kind]
@@ -258,10 +262,12 @@ def reduce(extracted: dict) -> dict:
             info = next((i for i in st["info"] if i.get("kind") == kind), None)
             if kind != "decode" or info is None or len(st["launch"]) != 1:
                 continue
-            attn = sum(d for text, s, d in first_ops
-                       if ms <= s and s + d <= ms + md and ATTN_DECODE.match(text))
-            decode_steps.append(dict(info, module=name.split("(")[0],
-                                     module_s=md / 1e9, attn_s=attn / 1e9))
+            inside = [(text, d) for text, s, d in first_ops
+                      if ms <= s and s + d <= ms + md]
+            decode_steps.append(dict(
+                info, module=name.split("(")[0], module_s=md / 1e9,
+                ops_s={p: sum(d for text, d in inside if pat.search(text)) / 1e9
+                       for p, pat in patterns}))
     return {
         "window_s": (hi - lo) / 1e9 if devices and hi > lo else 0.0,
         "idle_s": idle_s,
@@ -278,8 +284,10 @@ def reduce(extracted: dict) -> dict:
 def of_run(ctx: dict):
     """The reduction of this run's trace, made once and kept in ``ctx``:
     a child of its own off the chip, as ``layers.reduce_trace`` runs
-    ``trace.py``. None where the run has no trace or the child fails (the
-    readers then leave their metrics out, and the log says why)."""
+    ``trace.py``, told the patterns of ``ctx["step_ops"]``
+    (``layers.step_ops``: what the cell's metrics ask to have timed inside
+    each decode step). None where the run has no trace or the child fails
+    (the readers then leave their metrics out, and the log says why)."""
     if "host_trace" not in ctx:
         ctx["host_trace"] = _reduce_in_child(ctx) if ctx.get("trace") else None
     return ctx["host_trace"]
@@ -295,7 +303,8 @@ def _reduce_in_child(ctx: dict):
             raise harness.BenchError("no .xplane.pb under the run's profile/")
         harness.run_python_child(
             "host_trace_reduce",
-            [os.path.abspath(__file__), traces[-1], out_path],
+            [os.path.abspath(__file__), traces[-1], out_path,
+             *ctx.get("step_ops", [])],
             harness.child_env({"JAX_PLATFORMS": "cpu"}), ctx["out_dir"], 300)
     except harness.BenchError as e:
         harness.log(f"host trace not reduced: {e}")
@@ -310,12 +319,12 @@ def _reduce_in_child(ctx: dict):
 
 
 def main(argv) -> int:
-    if len(argv) != 3:
+    if len(argv) < 3:
         print(__doc__, file=sys.stderr)
         return 2
     extracted = extract(argv[1])
     with open(argv[2], "w") as f:
-        json.dump(reduce(extracted), f)
+        json.dump(reduce(extracted, tuple(argv[3:])), f)
     return 0
 
 

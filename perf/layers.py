@@ -29,6 +29,18 @@ def metrics(bench: dict, cell: dict, ctx: dict, extra_dirs=None) -> dict:
     return out
 
 
+def step_ops(bench: dict, cell: dict, extra_dirs=None) -> list:
+    """The patterns of device-operation names that the cell's per-layer
+    metrics ask to have timed inside each decode step (``params.ops``; a
+    ``trace_step_roofline`` metric names its kernel so): what
+    ``host_trace.reduce`` is told, so that a metric with a kernel of its
+    own is a file and no edit there."""
+    found = (manifest.load_layer_metric(m["name"], extra_dirs)
+             .get("params", {}).get("ops")
+             for m in manifest.metrics_of(bench, "per_layer", cell["name"]))
+    return list(dict.fromkeys(p for p in found if p))
+
+
 def reduce_trace(profile_dir: str, out_dir: str, extra_env: dict = None) -> dict:
     """Reduce the newest ``.xplane.pb`` under ``profile_dir`` in a child of
     its own that is kept off the chip (``JAX_PLATFORMS=cpu``)."""

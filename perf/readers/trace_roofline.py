@@ -8,15 +8,16 @@ operations / peak and bytes / bandwidth; the share is the sum of those
 least times over the sum of the measured durations. A call whose shapes
 the trace does not carry makes the metric absent, not guessed."""
 
-import importlib
 import re
+
+from perf import cost as costs
 
 
 def read(params: dict, ctx: dict):
     t, peaks = ctx.get("trace"), ctx.get("peaks")
     if not t or not peaks:
         return None
-    cost = importlib.import_module(f"perf.cost.{params['cost']}")
+    cost = costs.load(params["cost"], ctx.get("cost_dirs"))
     pat = re.compile(params["pattern"])
     least = measured = 0.0
     for call in t["calls"]:

@@ -6,11 +6,16 @@ operations and bytes the algorithm needs from the step's own metadata
 operations / peak and bytes / bandwidth, and the measured time is the
 kernel's time inside the program that step launched
 (``perf/host_trace.py``). The share is the sum of the least times over the
-sum of the measured. params: ``cost`` (module under ``perf/cost``). A step
-the cost function cannot cost makes the metric absent, not guessed."""
+sum of the measured. params: ``ops`` (regular expression over a device
+operation's text: the operations whose time inside each step is the
+kernel's, such as ``^%paged_attn_decode``; ``host_trace.reduce`` times
+them for every metric of the cell that names some), ``cost`` (module under
+``perf/cost``; it is handed the step's ``pst.step_info`` fields whole, with
+``module_s`` and ``ops_s``). A step the cost function cannot cost makes the
+metric absent, not guessed, and so does a reduction that did not time
+``ops``."""
 
-import importlib
-
+from perf import cost as costs
 from perf import host_trace
 
 
@@ -18,17 +23,20 @@ def read(params: dict, ctx: dict):
     t, peaks = host_trace.of_run(ctx), ctx.get("peaks")
     if not t or not peaks:
         return None
-    cost = importlib.import_module(f"perf.cost.{params['cost']}")
+    cost = costs.load(params["cost"], ctx.get("cost_dirs"))
     least = measured = 0.0
     for step in t["decode_steps"]:
-        if step["attn_s"] <= 0:
+        seconds = step.get("ops_s", {}).get(params["ops"])
+        if seconds is None:
+            return None
+        if seconds <= 0:
             continue
         c = cost.cost(step, ctx["cfg"].hf, ctx["cfg"])
         if c is None:
             return None
         least += max(c["flops"] / peaks[c.get("peak", "bf16_flops_per_s")],
                      c["bytes"] / peaks["hbm_bytes_per_s"])
-        measured += step["attn_s"]
+        measured += seconds
     if measured <= 0:
         return None
     return least / measured * 100.0

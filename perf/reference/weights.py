@@ -13,6 +13,13 @@ numbers are identical), and nothing of its forward pass:
   ``init_leaf`` then ``quantize_leaf_int4`` (layer matmuls, int4 mode) or
   ``quantize_leaf`` (``_init_params_streamed``).
 
+Which leaves are quantised, and by what, is the model object's to say: an
+object that carries ``RECIPE``'s names (same names and signatures as
+``models/llama.py``'s) is followed in all five, one that carries none gets
+``models/llama.py``'s, and one that carries some but not all is refused. A
+model class with other quantised leaves brings them on the object and
+touches no file here.
+
 Dequantisation is this module's own copy of the documented layouts:
 int4 is nibble-packed along the contraction axis (even rows in the low
 nibble, odd in the high) with one float32 scale per group of contraction
@@ -22,38 +29,56 @@ column (``<name>_qs``), and for ``embed`` / ``lm_head`` one per row.
 
 from __future__ import annotations
 
+import types
+
 import jax
 import jax.numpy as jnp
+
+RECIPE = ("QUANT_LAYER_KEYS", "QUANT_TOP_KEYS", "init_leaf", "quantize_leaf",
+          "quantize_leaf_int4")
+
+
+def recipe_of(model):
+    """``RECIPE``'s names, all from ``model`` if it carries any of them (a
+    partial set is an error: the rest would silently be another model's),
+    else all from ``models/llama.py``."""
+    own = [n for n in RECIPE if hasattr(model, n)]
+    if own and len(own) < len(RECIPE):
+        raise ValueError(
+            f"{type(model).__name__} carries {own} of the weights recipe but "
+            f"not {[n for n in RECIPE if n not in own]}: all or none")
+    if not own:
+        from production_stack_tpu.models import llama as model
+    return types.SimpleNamespace(**{n: getattr(model, n) for n in RECIPE})
 
 
 def engine_params(model, seed: int, quantization):
     """The parameter tree the engine serves for ``--seed seed``; ``model``
     is the program's model object for the configuration."""
-    from production_stack_tpu.models import llama as prog
-
     rng = jax.random.PRNGKey(seed)
     if not quantization:
         return jax.jit(model.init_params)(rng)
     import xxhash
 
+    recipe = recipe_of(model)
     shapes = jax.eval_shape(model.init_params, rng)
 
     def build(name, sds, into):
         key = jax.random.fold_in(
             rng, xxhash.xxh32(name.encode()).intdigest() & 0x7FFF_FFFF
         )
-        int4 = quantization == "int4" and name in prog.QUANT_LAYER_KEYS
-        axis = (-2 if name in prog.QUANT_LAYER_KEYS
-                else -1 if name in prog.QUANT_TOP_KEYS else None)
+        int4 = quantization == "int4" and name in recipe.QUANT_LAYER_KEYS
+        axis = (-2 if name in recipe.QUANT_LAYER_KEYS
+                else -1 if name in recipe.QUANT_TOP_KEYS else None)
         if axis is None:
             into[name] = jax.jit(
-                lambda k: prog.init_leaf(name, sds.shape, sds.dtype, k))(key)
+                lambda k: recipe.init_leaf(name, sds.shape, sds.dtype, k))(key)
             return
 
         def init_q(k):
-            w = prog.init_leaf(name, sds.shape, sds.dtype, k)
-            return (prog.quantize_leaf_int4(w) if int4
-                    else prog.quantize_leaf(w, axis=axis))
+            w = recipe.init_leaf(name, sds.shape, sds.dtype, k)
+            return (recipe.quantize_leaf_int4(w) if int4
+                    else recipe.quantize_leaf(w, axis=axis))
 
         q, s = jax.jit(init_q)(key)
         into[name] = q

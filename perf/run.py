@@ -278,6 +278,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         "prom_after": prom_after, "spans": spans, "cfg": cfg, "cell": cell,
         "mix": mix, "peaks": peaks.get(dev.get("device_kind")),
         "out_dir": out_dir, "records": records, "window_wall": window_wall,
+        "cost_dirs": data_dirs.get("cost"),
     }
     if not trace:
         result["metrics"] = end_to_end.metrics(bench, cell, ctx)
@@ -290,6 +291,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         ctx["trace"] = None
     else:
         ctx["trace"] = layers.reduce_trace(engine.profile_dir, out_dir, extra_env)
+        ctx["step_ops"] = layers.step_ops(
+            bench, cell, data_dirs.get("layer_metrics"))
         device["busy_s"] = ctx["trace"]["busy_s"]
         device["window_s"] = ctx["trace"]["window_s"]
         result["breakdown"] = layers.breakdown(

@@ -137,3 +137,48 @@ def test_warm_up_probes_are_fixed_work_from_the_mix_file():
         dict(spec, contexts=[0.0]),
         {"sessions": [], "shared_prefix": list(range(3, 40))}, VOCAB)
     assert all(q[:32] == list(range(3, 35)) for _, g in flat for q in g)
+
+
+def _pow2(n):
+    return 1 << max(n - 1, 0).bit_length()
+
+
+def test_an_unshared_closed_loop_mix_and_its_probes_behind_the_empty_prefix():
+    """A saturated mix of unrelated single prompts as the generator plans it
+    (``data/traffic/tiny-chat-saturated.json``: a client for every row, no
+    shared prefix): prompts over the whole of the range and outputs too, the
+    same multiset for every seed in an order and with contents drawn from
+    the seed. Its probes stand behind the empty prefix and need no
+    ``contexts``: each group is one packed prefill step inside the budget,
+    as long as the file says, and between them they touch every program a
+    packed step of this traffic can be padded to (the program pads to a
+    power of two of rows and of the longest chunk)."""
+    from perf import warmup
+
+    mix = _mix("tiny-chat-saturated")
+    p = closed_loop.plan(mix, BIG_SEED, 50.0, VOCAB)
+    q = closed_loop.plan(mix, 7, 50.0, VOCAB)
+    assert (p["mode"], p["clients"], len(p["requests"])) == ("closed", 8, 96)
+    assert p["shared_prefix"] == [] and p["setup_prompts"] == [] == p["sessions"]
+    lens = [len(r["prompt"]) for r in p["requests"]]
+    outs = [r["max_tokens"] for r in p["requests"]]
+    # the (i + 0.5)/n quantiles: the ends of the ranges to within a token
+    assert (min(lens), max(lens), min(outs), max(outs)) == (8, 63, 4, 12)
+    assert sorted(lens) == sorted(len(r["prompt"]) for r in q["requests"])
+    assert sorted(outs) == sorted(r["max_tokens"] for r in q["requests"])
+    # the seed draws the order as well as the contents: a window consumes
+    # only a prefix of the pool, so a cell on such a mix samples the orders
+    assert lens != [len(r["prompt"]) for r in q["requests"]]
+    assert outs != [r["max_tokens"] for r in q["requests"]]
+    assert len({tuple(r["prompt"][:8]) for r in p["requests"]}) == 96  # unshared
+
+    spec = mix["warmup"]["probes"]
+    assert "contexts" not in spec
+    groups = warmup.probe_groups(spec, p, VOCAB)
+    assert all(len(b) == spec["blocker_tokens"] for b, _ in groups)
+    assert [[len(x) for x in g] for _, g in groups] == spec["groups"]  # no prefix
+    assert all(sum(g) <= spec["blocker_tokens"] and len(g) <= mix["clients"]
+               for g in spec["groups"])
+    touched = {(_pow2(len(g)), _pow2(max(g))) for g in spec["groups"]}
+    assert touched == {(1, 64), (1, 8), (1, 1), (2, 32), (4, 32), (4, 16),
+                       (8, 32), (8, 8)}

@@ -11,6 +11,7 @@ import pytest
 from perf import host_trace, layers
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
+ATTN_OPS, INT4_OPS = "^%paged_attn_decode", "^%int4_matmul"  # two metrics' params.ops
 ATTN = "%paged_attn_decode.3 = bf16[2,32,128]{2,1,0} custom-call(s32[2,64]{1,0} %t)"
 
 
@@ -125,7 +126,7 @@ def test_idle_stretches_are_cut_at_span_boundaries_and_named_by_span_and_neighbo
 
 
 def test_steps_cut_by_an_edge_are_dropped_and_the_rest_joined_in_order():
-    r = host_trace.reduce(hand_made())
+    r = host_trace.reduce(hand_made(), (ATTN_OPS,))
     assert r["steps_kept"] == 3  # the first pst.step began before the interval
     assert r["modules"] == {"jit_pst_decode_step": [3, pytest.approx(790e-9)],
                             "jit_pst_prefill_step": [1, pytest.approx(200e-9)]}
@@ -135,8 +136,35 @@ def test_steps_cut_by_an_edge_are_dropped_and_the_rest_joined_in_order():
     assert (first["kv_tokens"], first["rows"], first["bucket"]) == (1000, 2, "b2")
     assert first["module"] == "jit_pst_decode_step"
     assert first["module_s"] == pytest.approx(400e-9)
-    assert first["attn_s"] == pytest.approx(200e-9)
-    assert (second["kv_tokens"], second["attn_s"]) == (1002, pytest.approx(150e-9))
+    assert first["ops_s"] == {ATTN_OPS: pytest.approx(200e-9)}
+    assert (second["kv_tokens"], second["ops_s"][ATTN_OPS]) == (
+        1002, pytest.approx(150e-9))
+
+
+def test_each_metrics_operations_are_timed_inside_the_same_steps():
+    """Two kernels of different names in one step's program, each named by
+    a metric of its own (``params.ops``): each pattern reads its own
+    operations' time, a step in which none ran reads 0, a pattern asked for
+    twice is timed once, and no pattern asked for means no time kept."""
+    r = host_trace.reduce(hand_made(), (ATTN_OPS, INT4_OPS, ATTN_OPS))
+    first, second = r["decode_steps"]
+    # the first whole step's program [400, 800]: fusion 100, attention 200,
+    # the int4 matmul 100; the second's [1100, 1350]: fusion 100, attention 150
+    assert first["ops_s"] == {ATTN_OPS: pytest.approx(200e-9),
+                              INT4_OPS: pytest.approx(100e-9)}
+    assert second["ops_s"] == {ATTN_OPS: pytest.approx(150e-9), INT4_OPS: 0.0}
+    both = host_trace.reduce(hand_made(), ("^%(paged_attn_decode|int4_matmul)",))
+    assert [list(s["ops_s"].values()) for s in both["decode_steps"]] == [
+        [pytest.approx(300e-9)], [pytest.approx(150e-9)]]
+    # the prefill kernel ran in no decode step's program
+    other = host_trace.reduce(hand_made(), ("^%paged_attn_prefill",))
+    assert [s["ops_s"] for s in other["decode_steps"]] == [
+        {"^%paged_attn_prefill": 0.0}] * 2
+    bare = host_trace.reduce(hand_made())
+    assert [s["ops_s"] for s in bare["decode_steps"]] == [{}, {}]
+    # and nothing else of the reduction depends on what was asked for
+    for key in set(r) - {"decode_steps"}:
+        assert r[key] == bare[key]
 
 
 def test_a_program_outside_its_steps_launch_and_wait_is_a_clock_violation():
@@ -173,8 +201,7 @@ def test_leaf_segments_name_every_moment_by_the_innermost_span():
                     (40, 60, "a"), (60, 90, "d"), (90, 100, "a"), (200, 300, "e")]
 
 
-@pytest.fixture(scope="module")
-def recorded():
+def recorded_slice() -> dict:
     with open(os.path.join(DATA, "trace_v5e_host_slice.json")) as f:
         data = json.load(f)
     for plane in data["planes"]:  # the operations' texts are stored once
@@ -183,6 +210,11 @@ def recorded():
             if names:
                 line["events"] = [[names[i], s, d] for i, s, d in line["events"]]
     return data
+
+
+@pytest.fixture(scope="module")
+def recorded():
+    return recorded_slice()
 
 
 def test_recorded_slice_phase_idle_sums_to_total_idle(recorded):
@@ -201,23 +233,31 @@ def test_recorded_slice_phase_idle_sums_to_total_idle(recorded):
 
 
 def test_recorded_slice_steps_and_programs(recorded):
-    r = host_trace.reduce(recorded)
+    r = host_trace.reduce(recorded, (ATTN_OPS, INT4_OPS))
     note = recorded["_note"]
     assert r["steps_kept"] == note["steps_whole"] < note["steps_in_slice"]
     assert set(r["modules"]) >= {"jit_pst_decode_step", "jit_pst_prefill_step"}
     assert len(r["decode_steps"]) == note["decode_steps_whole"]
     for step in r["decode_steps"]:
         assert step["rows"] in (15, 16) and step["bucket"] == "b16"
-        assert 0 < step["attn_s"] < step["module_s"]
+        assert 0 < step["ops_s"][ATTN_OPS] < step["module_s"]
+        # the second metric's kernel in the same steps: 7 leaves x 32 layers
+        # of int4 matmuls, a third to a half of the program
+        assert 0.3 < step["ops_s"][INT4_OPS] / step["module_s"] < 0.5
+        assert step["ops_s"][ATTN_OPS] + step["ops_s"][INT4_OPS] < step["module_s"]
         assert step["kv_pages"] * 128 >= step["kv_tokens"] > 15 * 3000
+    # the kernel's time as the reduction had it before it was told what to
+    # time (``attn_s``, PR 24-26), to the last digit
+    assert [s["ops_s"][ATTN_OPS] for s in r["decode_steps"]] == [
+        0.01066787, 0.0112277, 0.01122639]
     # the attention share of one step, by hand: its least bytes over the
     # chip's bandwidth against the kernel's measured time
     step = r["decode_steps"][0]
     least = (step["kv_tokens"] * 2 * 8 * 128 * 32
              + step["rows"] * 32 * 128 * 4 * 32) / 819e9
-    assert least / step["attn_s"] == pytest.approx(note["first_step_attn_share"],
-                                                    rel=1e-6)
-    assert 0 < least / step["attn_s"] <= 1.0
+    attn_s = step["ops_s"][ATTN_OPS]
+    assert least / attn_s == pytest.approx(note["first_step_attn_share"], rel=1e-6)
+    assert 0 < least / attn_s <= 1.0
 
 
 def test_breakdown_names_the_recorded_gaps_by_what_the_host_was_doing(recorded):

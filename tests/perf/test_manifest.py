@@ -1,6 +1,14 @@
 """``BENCHMARK.json`` against the contract, and against the files it names:
 every configuration, traffic mix and per-layer metric is a file of its own
-that the harness finds by name."""
+that the harness finds by name.
+
+Every test here states a property and runs twice: on the accepted
+benchmark, and on the benchmark as the next PR will leave it (``_later``:
+one more configuration and cell, one more kernel metric that lists that
+cell and has a cost module of its own, one more generic metric; entries of
+``data/BENCHMARK.later.json``, files under ``data/``). A test that fails
+on the second alone pins today's contents, and stops a PR that may add
+entries and files but may not edit this directory."""
 
 import importlib
 import json
@@ -10,9 +18,9 @@ import re
 import pytest
 
 from perf import config as configs
-from perf import end_to_end, manifest, warmup
+from perf import cost as costs
+from perf import end_to_end, layers, manifest, warmup
 
-ROOT = manifest.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -20,19 +28,50 @@ WIDTH_KEY = re.compile(r"(_dim|_rank)$|hidden_size|intermediate_size|head_dim|"
                        r"num_experts_per_tok|expansion")
 
 
+DATA = os.path.join(os.path.dirname(__file__), "data")
+# where the later PR's files are found: beside the tests' data, as the
+# harness takes them (``run_cell(..., data_dirs=...)``)
+LATER_DIRS = {kind: [os.path.join(DATA, kind)]
+              for kind in ("traffic", "layer_metrics", "cost", "reference")}
+
+
+def _later(bench):
+    """The benchmark as a later PR leaves it: ``data/BENCHMARK.later.json``'s
+    entries appended to the lists of the same name."""
+    with open(os.path.join(DATA, "BENCHMARK.later.json")) as f:
+        more = json.load(f)
+    return dict(bench, **{group: bench[group] + entries
+                          for group, entries in more.items()
+                          if not group.startswith("_")})
+
+
+@pytest.fixture(scope="module", params=["accepted", "later"])
+def stage(request):
+    return request.param
+
+
 @pytest.fixture(scope="module")
-def bench():
-    return manifest.load()
+def bench(stage):
+    accepted = manifest.load()
+    return _later(accepted) if stage == "later" else accepted
+
+
+@pytest.fixture(scope="module")
+def dirs(stage):
+    return LATER_DIRS if stage == "later" else {}
 
 
 def _line(s, n=200):
     return isinstance(s, str) and 1 <= len(s) <= n and "\n" not in s and "\t" not in s
 
 
-def test_top_level_keys_and_limits(bench):
+def test_top_level_keys_and_limits(bench, stage):
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
+    if stage == "accepted":  # the committed file itself
+        assert os.path.getsize(os.path.join(manifest.ROOT, "BENCHMARK.json")) <= 64 * 1024
+    else:
+        assert len(json.dumps(bench, indent=1).encode()) <= 64 * 1024
     assert 1 <= len(bench["paths"]) <= 16 and len(bench["command"]) <= 32
     assert all(_line(w) for w in bench["command"])
     assert isinstance(bench["run_seconds"], int) and 1 <= bench["run_seconds"] <= 51
@@ -73,7 +112,7 @@ def test_names_units_and_keys(bench):
     assert four <= max(1, len(bench["workloads"]) // 4)
 
 
-def test_every_cell_finds_its_files_and_reports_enough(bench):
+def test_every_cell_finds_its_files_and_reports_enough(bench, dirs):
     cell_names = {w["name"] for w in bench["workloads"]}
     used = {w["config"] for w in bench["workloads"]}
     assert used == {c["name"] for c in bench["configs"]}
@@ -84,7 +123,7 @@ def test_every_cell_finds_its_files_and_reports_enough(bench):
         assert cell["config_file"].startswith(tuple(p + "/" for p in bench["paths"]))
         cfg = configs.load(cell["config_file"])
         assert cfg.name == w["config"]
-        mix = manifest.load_mix(w["traffic"])
+        mix = manifest.load_mix(w["traffic"], dirs.get("traffic"))
         gen = importlib.import_module(f"perf.generators.{mix['generator']}")
         plan = gen.plan(mix, 2**31 + 7, float(bench["run_seconds"]),
                         cfg.hf["vocab_size"])
@@ -107,28 +146,34 @@ def test_every_cell_finds_its_files_and_reports_enough(bench):
         for m in layer:
             assert m["moves"] in e2e, (m["name"], w["name"])
     for m in bench["end_to_end"] + bench["per_layer"]:
-        assert set(m.get("workloads", [])) <= cell_names
+        if "workloads" in m:  # a list names accepted cells, each once, and some
+            assert m["workloads"] and set(m["workloads"]) <= cell_names, m["name"]
+            assert len(set(m["workloads"])) == len(m["workloads"]), m["name"]
     setup = next(m for m in bench["end_to_end"] if m["name"] == "setup_s")
     assert "workloads" not in setup and setup["bound"] <= 0.1
 
 
-def test_every_per_layer_metric_is_a_file_with_a_reader(bench):
-    layers_by_name = {}
+def test_every_per_layer_metric_is_a_file_with_a_reader(bench, dirs):
     for m in bench["per_layer"]:
-        spec = manifest.load_layer_metric(m["name"])
+        spec = manifest.load_layer_metric(m["name"], dirs.get("layer_metrics"))
         # the file says how the number is read and nothing that
         # BENCHMARK.json says already: no cell list, no second copy of a field
         assert set(spec) == {"what", "reader", "params"}, m["name"]
         reader = importlib.import_module(f"perf.readers.{spec['reader']}")
         assert callable(reader.read)
-        if spec["reader"] == "trace_roofline":
+        if spec["reader"] in ("trace_roofline", "trace_step_roofline"):
             assert m["name"].endswith("_roofline") or "_roofline." in m["name"]
             assert m["unit"] == "%"
-            cost = importlib.import_module(f"perf.cost.{spec['params']['cost']}")
+            # it says which operations are the kernel, and how to cost them
+            assert spec["params"]["pattern" if spec["reader"] == "trace_roofline"
+                                  else "ops"]
+            cost = costs.load(spec["params"]["cost"], dirs.get("cost"))
             assert callable(cost.cost)
-        layers_by_name.setdefault(m["layer"], []).append(m["name"])
+    # no file of the benchmark's own is left without an entry, and no entry
+    # without a file (the later PR's files lie beside the tests' data)
     on_disk = {f[:-5] for f in os.listdir(os.path.join(manifest.HERE, "layer_metrics"))}
-    assert on_disk == {m["name"] for m in bench["per_layer"]}
+    beside = {f[:-5] for d in dirs.get("layer_metrics", []) for f in os.listdir(d)}
+    assert on_disk == {m["name"] for m in bench["per_layer"]} - beside
 
 
 def test_configurations_state_their_cut(bench):
@@ -167,36 +212,27 @@ KERNEL_METRICS = ("kernel.int4_matmul_roofline",
 INT4_CELL = "mistral-7b-int4.sessions-closed"
 
 
-def _later(bench):
-    """The benchmark as a later PR leaves it: one more configuration (not
-    int4, other kernels) and a cell of it, as entries and files of its own."""
-    return dict(bench,
-                configs=bench["configs"] + [{
-                    "name": "tiny-moe", "source": "test only", "reduced": [],
-                    "file": "tests/perf/data/configs/tiny-moe.json", "why": "test"}],
-                workloads=bench["workloads"] + [{
-                    "name": "tiny-moe.tiny-closed", "config": "tiny-moe",
-                    "traffic": "tiny-closed", "chips": 1, "why": "test"}])
-
-
-def test_a_new_cell_is_one_entry_and_touches_no_file_that_is_there(bench):
+def test_a_cell_reports_the_generic_metrics_and_those_that_list_it(bench, dirs):
     """Every end-to-end metric and every per-layer metric that reads the
-    program's generic spans, counters and step programs applies to every
-    cell, so a later PR's cell is an entry of ``workloads`` (plus, for a new
-    configuration or mix, files of their own)."""
+    program's generic spans, counters and step programs carries no list and
+    applies to every cell, so a later PR's cell is an entry of ``workloads``
+    (plus, for a new configuration or mix, files of their own); a metric
+    with a list is owed by the cells it names and by no other. Which
+    metrics those are, and how many, is the benchmark's to say and no
+    test's."""
     assert not any("workloads" in m for m in bench["end_to_end"])
-    listed = {m["name"] for m in bench["per_layer"] if "workloads" in m}
-    assert listed == set(KERNEL_METRICS)
-    data = os.path.join(os.path.dirname(__file__), "data")
-    later = _later(bench)
-    cell = manifest.cell(later, "tiny-moe.tiny-closed")
-    assert configs.load(cell["config_file"]).hf["num_local_experts"] == 4
-    assert manifest.load_mix(cell["traffic"], [os.path.join(data, "traffic")])
-    assert manifest.metrics_of(later, "end_to_end", cell["name"]) == bench["end_to_end"]
-    generic = [m for m in bench["per_layer"] if m["name"] not in KERNEL_METRICS]
-    assert manifest.metrics_of(later, "per_layer", cell["name"]) == generic
-    for m in generic:  # and every reader is found for it as it is
-        assert manifest.load_layer_metric(m["name"])["reader"]
+    generic = [m for m in bench["per_layer"] if "workloads" not in m]
+    assert generic
+    for w in bench["workloads"]:
+        name = w["name"]
+        assert manifest.metrics_of(bench, "end_to_end", name) == bench["end_to_end"]
+        mine = manifest.metrics_of(bench, "per_layer", name)
+        assert [m for m in mine if "workloads" not in m] == generic
+        assert mine == [m for m in bench["per_layer"]
+                        if "workloads" not in m or name in m["workloads"]]
+        for m in mine:  # and every reader is found for it as it is
+            assert manifest.load_layer_metric(
+                m["name"], dirs.get("layer_metrics"))["reader"]
 
 
 @pytest.mark.parametrize("metric", KERNEL_METRICS)
@@ -204,25 +240,75 @@ def test_a_kernel_metric_names_the_cells_whose_kernels_it_reads(bench, metric):
     """Its cost function counts one architecture's and one quantisation's
     work (int4 leaves; keys and values of every layer, read whole): it lists
     the int4 cell, a cell that does not list it owes no such line, and
-    nothing else about the entry or its file changed."""
+    nothing else about the entry changed."""
     entry = next(m for m in bench["per_layer"] if m["name"] == metric)
     assert entry == {"name": metric, "unit": "%", "better": "higher",
                      "source": "device_trace", "layer": "kernels",
                      "moves": "itl_p50_ms", "workloads": [INT4_CELL]}
-    later = _later(bench)
-    assert entry in manifest.metrics_of(later, "per_layer", INT4_CELL)
-    assert entry not in manifest.metrics_of(later, "per_layer", "tiny-moe.tiny-closed")
+    assert entry in manifest.metrics_of(bench, "per_layer", INT4_CELL)
+    for w in bench["workloads"]:
+        if w["name"] != INT4_CELL:
+            assert entry not in manifest.metrics_of(bench, "per_layer", w["name"])
     spec = manifest.load_layer_metric(metric)
     assert "workloads" not in spec and INT4_CELL not in json.dumps(spec)
-    assert len(manifest.metrics_of(bench, "per_layer", INT4_CELL)) == 38
+
+
+def test_what_the_next_pr_brings_is_found_by_name_and_read():
+    """The rehearsal itself: on the accepted benchmark plus
+    ``data/BENCHMARK.later.json`` the new cell owes the generic metrics (the
+    new one among them) and its own kernel metric, not the int4 cell's; the
+    int4 cell owes the new generic metric and not the new kernel's; what
+    ``host_trace.reduce`` is told to time inside a decode step is what each
+    cell's own metrics name; and the harness reads the new kernel metric
+    through its own file and its own cost module on a reduction that timed
+    its operations, beside the accepted one on the same steps."""
+    accepted = manifest.load()
+    later, metrics_dir = _later(accepted), LATER_DIRS["layer_metrics"]
+    new_cell = manifest.cell(later, "later-moe.later-closed")
+    old_cell = manifest.cell(later, INT4_CELL)
+    names = {c["name"]: {m["name"] for m in manifest.metrics_of(
+        later, "per_layer", c["name"])} for c in (new_cell, old_cell)}
+    before = {m["name"] for m in manifest.metrics_of(accepted, "per_layer", INT4_CELL)}
+    generic = {m["name"] for m in accepted["per_layer"] if "workloads" not in m}
+    assert names[INT4_CELL] == before | {"test.requests_served"}
+    assert names[new_cell["name"]] == generic | {
+        "test.requests_served", "kernel.later_scan_decode_roofline"}
+    assert layers.step_ops(later, new_cell, metrics_dir) == ["^%later_scan_decode"]
+    theirs = layers.step_ops(later, old_cell, metrics_dir)
+    assert "^%paged_attn_decode" in theirs and "^%later_scan_decode" not in theirs
+
+    cfg = configs.load(new_cell["config_file"])
+    step = {"kind": "decode", "bucket": "b8", "rows": 6, "state_slots": 8,
+            "new_tokens": 6, "kv_tokens": 600, "kv_pages": 40,
+            "module": "jit_pst_decode_step", "module_s": 1e-4,
+            "ops_s": {"^%later_scan_decode": 2e-6, "^%paged_attn_decode": 1e-5}}
+    host = {"window_s": 1.0, "idle_s": 0.5, "spans": 9, "idle_by_phase": {},
+            "modules": {}, "gaps": [], "steps_kept": 1, "clock_violations": 0,
+            "decode_steps": [step]}
+    ctx = {"host_trace": host, "trace": None, "cfg": cfg, "cell": new_cell,
+           "peaks": manifest.load_peaks()["TPU v5 lite"],
+           "cost_dirs": LATER_DIRS["cost"], "prom_before": {}, "prom_after": {},
+           "spans": {}, "timings": {}, "summary": {}, "window_wall": (0.0, 1.0)}
+    only = {"per_layer": [m for m in later["per_layer"]
+                          if m["name"].startswith("kernel.")]}
+    got = layers.metrics(dict(later, **only), new_cell, ctx, metrics_dir)
+    # 8 state slots x 8 heads x 16 x 16 x 2 layers float32 states, read and
+    # written once: 262,144 B over 819 GB/s against 2 us measured
+    assert set(got) == {"kernel.later_scan_decode_roofline"}
+    assert got["kernel.later_scan_decode_roofline"] == {
+        "value": pytest.approx(262144 / 819e9 / 2e-6 * 100), "unit": "%"}
+    got = layers.metrics(dict(later, **only), old_cell,
+                         dict(ctx, cell=old_cell), metrics_dir)
+    # the accepted one from the same step; no calls traced for the others
+    assert "kernel.paged_attn_decode_roofline" in got
+    assert "kernel.later_scan_decode_roofline" not in got
 
 
 def test_a_new_mix_and_metric_are_found_without_editing_perf():
-    data = os.path.join(os.path.dirname(__file__), "data")
-    mix = manifest.load_mix("tiny-closed", [os.path.join(data, "traffic")])
+    mix = manifest.load_mix("tiny-closed", LATER_DIRS["traffic"])
     assert mix["generator"] == "closed_loop"
     spec = manifest.load_layer_metric(
-        "test.requests_served", [os.path.join(data, "layer_metrics")])
+        "test.requests_served", LATER_DIRS["layer_metrics"])
     assert spec["reader"] == "prom_delta"
     with pytest.raises(FileNotFoundError):
         manifest.load_mix("tiny-closed")  # not among the benchmark's own

@@ -6,10 +6,12 @@ kinds.)"""
 import pytest
 
 from perf import config as configs
-from perf import manifest
+from perf import host_trace, manifest
 from perf.cost import paged_attn
 from perf.readers import (trace_idle_by_span, trace_module_time,
                           trace_step_roofline)
+
+from .test_host_trace import recorded_slice
 
 REDUCED = {
     "window_s": 2.0, "idle_s": 0.5, "spans": 900,
@@ -22,13 +24,16 @@ REDUCED = {
     "decode_steps": [
         {"kind": "decode", "bucket": "b16", "rows": 16, "new_tokens": 16,
          "kv_tokens": 91000, "kv_pages": 720, "module": "jit_pst_decode_step",
-         "module_s": 0.029, "attn_s": 0.0091},
+         "module_s": 0.029,
+         "ops_s": {"^%paged_attn_decode": 0.0091, "^%int4_matmul": 0.0118}},
         {"kind": "decode", "bucket": "b16", "rows": 16, "new_tokens": 16,
          "kv_tokens": 91016, "kv_pages": 720, "module": "jit_pst_decode_step",
-         "module_s": 0.029, "attn_s": 0.0093},
+         "module_s": 0.029,
+         "ops_s": {"^%paged_attn_decode": 0.0093, "^%int4_matmul": 0.0}},
     ],
     "steps_kept": 50, "clock_violations": 0,
 }
+ATTN = {"ops": "^%paged_attn_decode", "cost": "paged_attn"}  # the metric's params
 NOTHING = dict(REDUCED, spans=0, idle_by_phase={}, modules={}, decode_steps=[])
 
 
@@ -87,15 +92,43 @@ def test_step_roofline_sums_least_over_measured(cfg):
     least = sum(paged_attn.cost(s, cfg.hf, cfg)["bytes"] / 819e9
                 for s in REDUCED["decode_steps"])  # memory bound by far
     assert least == pytest.approx(2 * 0.007292, rel=1e-3)
-    share = trace_step_roofline.read({"cost": "paged_attn"}, ctx)
+    share = trace_step_roofline.read(ATTN, ctx)
     assert share == pytest.approx(least / (0.0091 + 0.0093) * 100)
     assert 75 < share < 85
+
+
+def test_step_roofline_divides_by_the_time_of_the_operations_it_names(cfg):
+    """``params.ops`` says which device operations are the kernel: another
+    pattern over the same steps reads its own time (a step in which none of
+    its operations ran adds nothing to either sum), and a pattern the
+    reduction was not told to time leaves the metric out."""
+    peaks = manifest.load_peaks()["TPU v5 lite"]
+    ctx = {"host_trace": REDUCED, "peaks": peaks, "cfg": cfg}
+    first = paged_attn.cost(REDUCED["decode_steps"][0], cfg.hf, cfg)["bytes"] / 819e9
+    other = trace_step_roofline.read(dict(ATTN, ops="^%int4_matmul"), ctx)
+    assert other == pytest.approx(first / 0.0118 * 100)
+    assert trace_step_roofline.read(dict(ATTN, ops="^%linear_attn"), ctx) is None
+    with pytest.raises(KeyError):  # a metric file without the parameter
+        trace_step_roofline.read({"cost": "paged_attn"}, ctx)
+
+
+def test_the_accepted_metric_reads_the_recorded_slice_as_it_did(cfg):
+    """``kernel.paged_attn_decode_roofline`` through its own file, on the
+    slice recorded on the chip: the number the reader gave when the kernel's
+    name was fixed in ``host_trace.py`` (PR 24-26), to the last digit."""
+    spec = manifest.load_layer_metric("kernel.paged_attn_decode_roofline")
+    assert spec["reader"] == "trace_step_roofline"
+    assert spec["params"] == {"ops": "^%paged_attn_decode", "cost": "paged_attn"}
+    peaks = manifest.load_peaks()["TPU v5 lite"]
+    reduced = host_trace.reduce(recorded_slice(), (spec["params"]["ops"],))
+    ctx = {"host_trace": reduced, "peaks": peaks, "cfg": cfg}
+    assert trace_step_roofline.read(spec["params"], ctx) == 71.48665329038714
 
 
 @pytest.mark.parametrize("reader,params", [
     (trace_idle_by_span, {"span": "wait"}),
     (trace_module_time, {"pattern": "^jit_pst_decode_step"}),
-    (trace_step_roofline, {"cost": "paged_attn"}),
+    (trace_step_roofline, ATTN),
 ])
 def test_nothing_to_read_gives_none(reader, params, cfg):
     """A program that writes no pst.* spans and names no program (the

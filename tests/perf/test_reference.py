@@ -97,6 +97,57 @@ def test_weights_follow_the_engines_recipe(built):
                                       np.asarray(theirs[name], np.float32))
 
 
+def test_the_recipe_asks_the_model_object_for_its_keys_and_initialisers():
+    """A model class with other quantised leaves carries its own key sets
+    and initialisers (same names as ``models/llama.py``'s) on the object it
+    hands in, all five, and they are followed; an object that carries none,
+    as the program's ``Llama``, gets ``models/llama.py``'s; one that carries
+    some and forgets others is refused, not silently given another model's."""
+    import jax.numpy as jnp
+
+    from production_stack_tpu.models import llama
+
+    cfg = configs.load(os.path.join(DATA, "configs", "tiny-dense-int4.json"))
+    inner = llama.Llama(configs.program_model_config(cfg))
+    plain = weights.recipe_of(inner)
+    assert all(getattr(plain, n) is getattr(llama, n) for n in weights.RECIPE)
+
+    class Other:
+        QUANT_LAYER_KEYS = ("wq", "w_down")  # wk, wv, wo, w_gate, w_up stay plain
+        QUANT_TOP_KEYS = ("embed",)          # and so does lm_head
+        init_params = inner.init_params
+
+        @staticmethod
+        def init_leaf(name, shape, dtype, key):
+            if name == "wk":
+                return jnp.full(shape, 0.5, dtype)
+            return llama.init_leaf(name, shape, dtype, key)
+
+    with pytest.raises(ValueError, match="quantize_leaf.*all or none"):
+        weights.recipe_of(Other())  # three of the five
+    Other.quantize_leaf = staticmethod(llama.quantize_leaf)
+    Other.quantize_leaf_int4 = staticmethod(llama.quantize_leaf_int4)
+    recipe = weights.recipe_of(Other())
+    assert recipe.QUANT_LAYER_KEYS == ("wq", "w_down")
+    assert recipe.init_leaf is Other.init_leaf
+    assert recipe.quantize_leaf_int4 is llama.quantize_leaf_int4
+    theirs = weights.engine_params(Other(), cfg.weights_seed, "int4")
+    ours = weights.engine_params(inner, cfg.weights_seed, "int4")
+    scales = {n for n in theirs["layers"] if n.endswith(("_q4s", "_qs"))}
+    assert scales == {"wq_q4s", "w_down_q4s"}
+    assert {n for n in ours["layers"] if n.endswith("_q4s")} == {
+        n + "_q4s" for n in llama.QUANT_LAYER_KEYS}
+    assert "embed_qs" in theirs and "lm_head_qs" not in theirs
+    assert "lm_head_qs" in ours
+    # its own initialiser made wk; the leaves both quantise are the same arrays
+    assert np.all(np.asarray(theirs["layers"]["wk"], np.float32) == 0.5)
+    assert theirs["layers"]["wk"].dtype == theirs["layers"]["wv"].dtype != np.int8
+    for name in ("wq", "wq_q4s", "w_down", "w_down_q4s"):
+        np.testing.assert_array_equal(np.asarray(theirs["layers"][name]),
+                                      np.asarray(ours["layers"][name]))
+    np.testing.assert_array_equal(np.asarray(theirs["embed"]), np.asarray(ours["embed"]))
+
+
 def test_negative_controls_move_the_logprobs(built):
     cfg, params = built
     variant = "no_renorm" if "num_local_experts" in cfg.hf else "rope_1e4"
