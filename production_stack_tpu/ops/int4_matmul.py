@@ -7,20 +7,74 @@ bandwidth than serving int8 and transiently allocates a full layer of bf16
 weights (the OOM/latency cliff the 8B int4 smoke hit). int8 survives in
 XLA because its dequant is a bare convert, which does fuse.
 
-The kernel keeps the stream at the true 0.5 byte/weight: packed tiles DMA
-from HBM once; the two nibble planes are derived in VMEM (arithmetic
-shifts — no interleave/relayout, which Mosaic would hate); each group's
-contribution is TWO MXU dots (even rows against the low plane, odd rows
-against the high plane — the caller pre-splits x, so no reshuffle
-anywhere), scaled per group POST-dot (a group's scale only varies along
-the output axis, so it commutes with the contraction).
+The kernel keeps the stream at the true 0.5 byte/weight, and at decode
+widths it runs at the speed of its weight DMAs (PERF.md §6, PR 28):
+
+* **Row-contiguous weight tiles.** A weight block is ``(tk packed rows,
+  tc columns)`` with ``tc`` the whole output width while the row tile is
+  small (``tn`` ≤ 64: the ``[tn, dout]`` f32 output block stays resident
+  over the contraction, the grid's inner axis), so one block is ONE
+  contiguous run of HBM; ``tk`` is the largest of 512/256/128 packed rows
+  that keeps the block within 1.75 MiB (128 x 14336, 256 x 4096, 512 x
+  1024 at the 7B/8B widths: 16-56 grid steps a call). Wider row tiles
+  (prefill) take column tiles of at most 2048 so the output block stays
+  2 MiB. The scales ride as ``(8 groups, tc)`` blocks whose index moves
+  every ``8 // groups-per-step`` steps.
+* **A grid step walks its 2-8 groups, unrolled, each at the tile's whole
+  width**: unpack → one dot → scale, accumulated into the step's ``[tn,
+  tc]`` f32 partial, which is added to the output block once. Straight-line
+  code that Mosaic cuts into vregs and schedules itself; the MXU's latency
+  overlaps with the next group's unpack. Walking column chunks inside a
+  group bought nothing on the chip (the same times from 128 columns to the
+  whole width, 16 to 1,024 rows) and cost dearly off it: a rolled chunk
+  loop waited out the MXU's latency every iteration (80 against 44 us for
+  4096 -> 14336 at 16 rows; 30 % slower at 256 rows), and an unrolled one
+  made every step program's kernels 10x the equations to trace and lower,
+  1-2 s more of every step shape's first use in a process, which the
+  compile cache does not save (PERF.md §6, PR 28).
+* **Nibbles become bf16 by integer ops on the packed words, no convert.**
+  Four packed bytes of one column (packed rows 4i..4i+3) are one 32-bit
+  word holding eight nibbles, group rows 8i..8i+7. For a in 0..3,
+  ``((w >> (4a - 3)) & 0x00780078) ^ 0x41C041C0`` is a pair of bf16 bit
+  patterns: exponent 2^4, the nibble in the top four mantissa bits with its
+  sign bit flipped, i.e. the value ``24 + s`` for the signed nibble ``s``
+  (exact), rows 8i + a (low half) and 8i + a + 4 (high half). Three integer
+  ops per 16 x 128 weights instead of widen, two shifts, two converts and
+  a multiply per 8 x 128.
+* **Scale (and the +24) after the dot.** A group's scale varies along the
+  output axis only, so it commutes with the contraction: each group is one
+  128-deep MXU dot of the unscaled planes, and the ``[tn, tc]`` f32 partial
+  is corrected and scaled on the way into the accumulator,
+  ``(dot - 24 * rowsum(x_g)) * scale_g``, in f32 (the scale is no longer
+  rounded to the activation dtype). That costs ``tn x tc`` per group where
+  scaling the weights costs ``128 x tc``; at 256 rows the MXU's own time
+  hides either, so one rule served every row count measured (1 to 1,024
+  rows, faster than the scaled-weights body at each: PERF.md §6) and there
+  is no second body.
+* **The activations arrive as before, split even/odd.** The caller-side
+  split is still ``xe = x[:, 0::2]``, ``xo = x[:, 1::2]`` (two XLA strided
+  slices of the small activation). Plane ``a`` holds a group's rows ``a, a +
+  4, a + 8, ...``, so the kernel joins a group's two 64-column halves into
+  ``[tn, 128]`` and reorders its lanes to the planes' K order with one
+  128 x 128 0/1 matrix on the MXU (exact: every output is one input times
+  1.0), once per group and grid step — against the 8-112 weight tiles the
+  group's own dot pushes. (A split in the planes' order made by XLA, a
+  reshape to ``[..., 32, 4]``, cost a prefill step more than the kernel
+  saved: minor dimensions of 4 and 32 pad to 128 lanes.)
+
+VMEM: the blocks are double-buffered by the pipeline — 2 x (weights ≤ 1.75
+MiB + scales 8 x tc x 4 + two x blocks) + 2 x the f32 output block (≤ 3.5
+MiB) — at most 13 MiB at the widths served. ``vmem_limit_bytes`` is 112 of
+the 128 MiB all the same, to leave XLA no room to stage a layer stack of
+scales on chip around every call (see ``VMEM_LIMIT_BYTES``).
 
 Layout contract (matches models/llama.py quantize_leaf_int4):
   x       [N, din]        activations (bf16/f32)
   packed  [din/2, dout]   int8, original row 2i in the low nibble of
                           packed row i, row 2i+1 in the high nibble
   scales  [G, dout]       f32, G = din/128 groups along the contraction
-Returns [N, dout] f32.
+Returns [N, dout] f32. f32 activations (the CPU tests) take the same walk
+with the planes widened to f32, the 24 taken off there, and a HIGHEST dot.
 
 The weights are never sliced: the model keeps every layer's matrix stacked
 on a leading axis, and :func:`int4_matmul_stacked` takes (packed
@@ -58,15 +112,30 @@ from jax.experimental.pallas import tpu as pltpu
 from ..device import INTERPRET_ENV, pallas_interpret
 
 GROUP = 128
-# Groups folded into one grid step: 8 groups = 512 packed rows per DMA
-# (256 KB at dout-tile 512) — deep enough to amortize per-cell overhead,
-# small enough to double-buffer comfortably in VMEM.
-GROUPS_PER_TILE = 8
-IN_TILE = GROUP * GROUPS_PER_TILE  # original rows per grid step
+HALF = GROUP // 2  # packed rows per group
+# din is a multiple of this, so that every packed-row tile (128/256/512)
+# divides din/2 and the groups come in whole (8, tc) scale blocks.
+IN_ALIGN = 1024
+WEIGHT_BLOCK_BYTES = 128 * 14336  # 1.75 MiB: the largest weight block
+WIDE_ROWS = 64  # row tiles up to this keep the whole output width resident
+WIDE_TILE = 2048  # the column tile of wider row tiles
+# The call's VMEM scope, of the v5e's 128 MiB. The double-buffered blocks need
+# at most 13 MiB (module docstring); the scope is set far above that because
+# what it leaves is what XLA's memory-space assignment may keep on chip
+# during the call, and with more it prefetched a whole [L, G, dout] f32 stack
+# of scales (58 MB for 14336 -> 4096) before every layer's call, which reads
+# 1/L of it: 0.95 ms of every decode step (PERF.md §6, PR 28). Pinning the
+# operand to HBM does not stop a prefetch; leaving it no room does.
+VMEM_LIMIT_BYTES = 112 << 20
+# A plane's bf16 pair: the nibble sits in mantissa bits 3..6 of each half,
+# exponent 2**4, sign bit of the nibble flipped -> the value NIBBLE_BIAS + s.
+PLANE_MASK = 0x00780078
+PLANE_MAGIC = 0x41C041C0
+NIBBLE_BIAS = 24.0
 
 
 def kernel_supports(din: int, dout: int, group: int) -> bool:
-    return group == GROUP and din % IN_TILE == 0 and dout % 128 == 0
+    return group == GROUP and din % IN_ALIGN == 0 and dout % 128 == 0
 
 
 def use_int4_kernel(packed: jax.Array, scales: jax.Array) -> bool:
@@ -85,58 +154,89 @@ def use_int4_kernel(packed: jax.Array, scales: jax.Array) -> bool:
     return not pallas_interpret() or bool(os.environ.get(INTERPRET_ENV))
 
 
-def _kernel(*refs):
+def _planes(w8: jax.Array) -> jax.Array:
+    """One group's packed ``[64, tc]`` int8 -> ``[128, tc]`` bf16 holding
+    ``NIBBLE_BIAS + s``: four planes of 32 rows, row ``2i + h`` of plane
+    ``a`` the group's row ``8i + a + 4h`` = ``4 (2i + h) + a``."""
+    w32 = pltpu.bitcast(w8, jnp.int32)  # [16, tc], eight nibbles a word
+    planes = []
+    for a in range(4):
+        sh = 4 * a - 3
+        t = jnp.left_shift(w32, -sh) if sh < 0 else jnp.right_shift(w32, sh)
+        t = jnp.bitwise_xor(jnp.bitwise_and(t, PLANE_MASK), PLANE_MAGIC)
+        planes.append(pltpu.bitcast(t, jnp.bfloat16))  # [32, tc]
+    return jnp.concatenate(planes, axis=0)
+
+
+def _kernel(*refs, gps: int, spb: int):
     # A stacked call has the layer in front (scalar-prefetched); only the
     # index maps read it: p_ref / s_ref are already that layer's tile, the
     # layer axis squeezed.
     xe_ref, xo_ref, p_ref, s_ref, o_ref = refs[-5:]
     k = pl.program_id(2)
-    acc = jnp.zeros(o_ref.shape, jnp.float32)
-    half = GROUP // 2  # packed rows per group
-    p = p_ref[...]  # [groups*half, tj] int8
-    # Mosaic has no i8 vector shifts (arith.shli on vector<i8> fails to
-    # legalize) — widen to i32, extract nibbles there. lo sign-extends the
-    # low 4 bits via a 28-bit round trip; hi is a plain arithmetic shift
-    # (p is already sign-extended by the i8→i32 convert).
-    p32 = p.astype(jnp.int32)
-    lo = jnp.right_shift(jnp.left_shift(p32, 28), 28)
-    hi = jnp.right_shift(p32, 4)
-    xe = xe_ref[...]
-    dt = xe.dtype
-    # Packed row i holds original rows 2i/2i+1, both in group i // half —
-    # ONE scale expansion (broadcast over the half rows of each group)
-    # serves both planes, and each plane contracts in a single big MXU dot
-    # (per-group dots were issue-latency-bound: 16 tiny [tn,64] dots per
-    # cell cost ~20 µs of fixed overhead).
-    s = s_ref[...].astype(dt)  # [groups, tj]
-    s_exp = jnp.broadcast_to(
-        s[:, None, :], (s.shape[0], half, s.shape[1])
-    ).reshape(s.shape[0] * half, s.shape[1])
-    # f32 activations ask for HIGHEST (exact) contraction — the op is
-    # HBM-bound, so the extra MXU passes are free. bf16 must use the
-    # native path (Mosaic rejects fp32 contract precision on bf16
-    # operands: "Bad lhs type").
-    prec = jax.lax.Precision.HIGHEST if dt == jnp.float32 else None
-    ge = jax.lax.dot_general(
-        xe, lo.astype(dt) * s_exp, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=prec,
-    )
-    go = jax.lax.dot_general(
-        xo_ref[...], hi.astype(dt) * s_exp, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=prec,
-    )
-    acc = acc + ge + go
 
     @pl.when(k == 0)
     def _init():
-        o_ref[...] = acc
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(k > 0)
-    def _accum():
-        o_ref[...] += acc
+    dt = xe_ref.dtype
+    # f32 activations ask for HIGHEST (exact) contraction, on f32 planes
+    # with the bias already off. bf16 must use the native path (Mosaic
+    # rejects fp32 contract precision on bf16 operands: "Bad lhs type").
+    exact = dt == jnp.float32
+    prec = jax.lax.Precision.HIGHEST if exact else None
+    # Lane c = 32 a + j of a group's operand is the group's column 4 j + a,
+    # which sits at lane 2 j + a // 2 of xe_g (a even) or of xo_g (a odd).
+    lane = jax.lax.broadcasted_iota(jnp.int32, (GROUP, GROUP), 1)
+    a, j = lane >> 5, lane & 31
+    src = ((a & 1) << 6) + (j << 1) + (a >> 1)
+    perm = (jax.lax.broadcasted_iota(jnp.int32, (GROUP, GROUP), 0)
+            == src).astype(dt)
+    xs, biases = [], []
+    for g in range(gps):
+        cols = slice(g * HALF, (g + 1) * HALF)
+        xg = jax.lax.dot_general(
+            jnp.concatenate([xe_ref[:, cols], xo_ref[:, cols]], axis=1), perm,
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=prec,
+        )
+        xs.append(xg.astype(dt))
+        biases.append(None if exact else NIBBLE_BIAS * jnp.sum(
+            xg, axis=1, keepdims=True))
+    # The scales block holds 8 groups; this step's are gps rows of it.
+    row0 = (k % spb) * gps
+
+    acc = None
+    for g in range(gps):
+        w = _planes(p_ref[g * HALF:(g + 1) * HALF, :])
+        if exact:
+            d = jax.lax.dot_general(
+                xs[g], w.astype(jnp.float32) - NIBBLE_BIAS,
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec,
+            )
+        else:
+            d = jax.lax.dot_general(
+                xs[g], w, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) - biases[g]
+        d = d * s_ref[pl.ds(row0 + g, 1), :]
+        acc = d if acc is None else acc + d
+    o_ref[...] += acc
 
 
-def _call(x, packed, scales, li, out_tile):
+def _tiles(n: int, dout: int) -> tuple[int, int, int]:
+    """(tn, tc, tk) from the call's shape alone."""
+    # Row tile: pad N up to a sublane-friendly size.
+    tn = 256 if n > 256 else max(8, 1 << (n - 1).bit_length())
+    tc = dout
+    if tn > WIDE_ROWS:
+        tc = max(c for c in range(128, WIDE_TILE + 1, 128) if dout % c == 0)
+    tk = next((t for t in (512, 256) if t * tc <= WEIGHT_BLOCK_BYTES), 128)
+    return tn, tc, tk
+
+
+def _call(x, packed, scales, li):
     """The one ``pallas_call``: ``li`` None for a 2-D ``packed [din/2,
     dout]``, else the layer of a stacked ``packed [L, din/2, dout]``. Same
     tiles, same order either way; the layer only offsets the weight DMAs."""
@@ -145,78 +245,83 @@ def _call(x, packed, scales, li, out_tile):
     dout = packed.shape[-1]
     assert packed.shape[-2] * 2 == din, (packed.shape, din)
     assert scales.shape == packed.shape[:-2] + (din // GROUP, dout), scales.shape
-    # Split even/odd contraction rows once (cheap XLA strided slices of the
-    # small activation) so the kernel never reshuffles anything.
+    # Split even/odd contraction columns once (cheap XLA strided slices of the
+    # small activation); the kernel reorders a group's lanes itself.
     xe = x[:, 0::2]
     xo = x[:, 1::2]
-    tj = out_tile
-    while dout % tj:
-        tj //= 2
-    # Row tile: pad N up to a sublane-friendly size.
-    tn = 256 if N > 256 else max(8, 1 << (N - 1).bit_length())
+    # The weights stream from HBM, which is what the roofline is counted
+    # against. Left to itself XLA stages the scan-sliced 2-D matrix (the one
+    # leaf of models/llama.py SLICED_KERNEL_INT4) in its faster on-chip space,
+    # and this kernel, bound by its DMAs, then reads it 2x faster than HBM
+    # can be read (2.5 against 4.9 us for 4096 x 1024: 118 % of the HBM
+    # roofline; PERF.md §6, PR 28). (The interpreter knows no memory spaces.)
+    interpret = pallas_interpret()
+    if not interpret:
+        packed = pltpu.with_memory_space_constraint(packed, pltpu.HBM)
+    tn, tc, tk = _tiles(N, dout)
     pad = -N % tn
     if pad:
         xe = jnp.pad(xe, ((0, pad), (0, 0)))
         xo = jnp.pad(xo, ((0, pad), (0, 0)))
-    ni = (N + pad) // tn
-    nj = dout // tj
-    nk = din // IN_TILE
-    half_tile = IN_TILE // 2  # packed rows per grid step
+    gps = tk // HALF  # groups per grid step
+    spb = 8 // gps  # grid steps per (8, tc) scales block
+    grid = ((N + pad) // tn, dout // tc, din // 2 // tk)
 
-    # Index maps get the grid position, then the prefetched scalars.
-    if stacked:
-        lead = (None,)
-        scalars = (jnp.asarray(li, jnp.int32).reshape(1),)
-        w_map = lambda i, j, k, li: (li[0], k, j)
-    else:
-        lead = scalars = ()
-        w_map = lambda i, j, k: (k, j)
+    # Index maps get the grid position, then the prefetched scalars (the
+    # layer, when stacked), which lead the weight and scale block indices.
+    lead = (None,) if stacked else ()
+    scalars = (jnp.asarray(li, jnp.int32).reshape(1),) if stacked else ()
+    layer = lambda refs: tuple(r[0] for r in refs)
+    w_map = lambda i, j, k, *refs: layer(refs) + (k, j)
+    s_map = lambda i, j, k, *refs: layer(refs) + (k // spb, j)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(ni, nj, nk),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((tn, half_tile), lambda i, j, k, *_: (i, k)),
-            pl.BlockSpec((tn, half_tile), lambda i, j, k, *_: (i, k)),
-            pl.BlockSpec(lead + (half_tile, tj), w_map),
-            pl.BlockSpec(lead + (GROUPS_PER_TILE, tj), w_map),
+            pl.BlockSpec((tn, tk), lambda i, j, k, *_: (i, k)),
+            pl.BlockSpec((tn, tk), lambda i, j, k, *_: (i, k)),
+            pl.BlockSpec(lead + (tk, tc), w_map),
+            pl.BlockSpec(lead + (8, tc), s_map),
         ],
-        out_specs=pl.BlockSpec((tn, tj), lambda i, j, k, *_: (i, j)),
+        out_specs=pl.BlockSpec((tn, tc), lambda i, j, k, *_: (i, j)),
     )
     out = pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, gps=gps, spb=spb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N + pad, dout), jnp.float32),
-        interpret=pallas_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES,
+        ),
+        interpret=interpret,
         # The device trace names the custom call by this.
         name="int4_matmul_stacked" if stacked else "int4_matmul",
     )(*scalars, xe, xo, packed, scales)
     return out[:N] if pad else out
 
 
-@functools.partial(jax.jit, static_argnames=("out_tile",))
+@jax.jit
 def int4_matmul_stacked(
     x: jax.Array,
     packed: jax.Array,
     scales: jax.Array,
     li,
-    out_tile: int = 512,
 ) -> jax.Array:
     """``x @ dequant(packed[li], scales[li])`` in fp32, streaming 0.5
     B/weight, with ``packed [L, din/2, dout]`` and ``scales [L, G, dout]``
     read in place: ``li`` (int32 scalar, may be traced — the model's layer
     scan) only offsets the tiles' DMAs."""
     assert packed.ndim == 3, packed.shape
-    return _call(x, packed, scales, li, out_tile)
+    return _call(x, packed, scales, li)
 
 
-@functools.partial(jax.jit, static_argnames=("out_tile",))
+@jax.jit
 def int4_matmul(
     x: jax.Array,
     packed: jax.Array,
     scales: jax.Array,
-    out_tile: int = 512,
 ) -> jax.Array:
     """``x @ dequant(packed, scales)`` in fp32 for a single ``[din/2, dout]``
     matrix, streaming 0.5 B/weight."""
     assert packed.ndim == 2, packed.shape
-    return _call(x, packed, scales, None, out_tile)
+    return _call(x, packed, scales, None)

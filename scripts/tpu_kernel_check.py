@@ -13,8 +13,11 @@ At the Llama-3-8B layer shapes the serving path uses (32 Q / 8 KV heads x
   - ``int4_matmul`` at decode-width (8) and prefill-width (1024) rows for
     4096->14336 and 14336->4096 — reference: ``dequant_int4`` + fp32 dot;
   - ``int4_matmul_stacked`` (the call the model makes: three layers' weights
-    stacked, layer 2 asked for) at 16 and 256 rows for the same two shapes —
-    same reference on that layer's weights, and bit for bit the 2-D entry's
+    stacked, layer 2 asked for) at 16 and 256 rows for the same two shapes,
+    at 64 and 128 rows for both (one row count on each side of the kernel's
+    tile rule: whole output width up to 64 rows, column tiles above), and at
+    16 rows for the attention projections 4096->4096 and 4096->1024 — same
+    reference on that layer's weights, and bit for bit the 2-D entry's
     result on ``packed[2]``, ``scales[2]``.
 
 Prints one line per case with the max abs difference, then one JSON object
@@ -63,9 +66,9 @@ SCALE = 1.0 / np.sqrt(HD)
 # bf16 output rounding alone is ~4e-3 relative. fp8 pages carry e4m3
 # rounding of P on the kernel side only (split-precision PV dot).
 ATTN_BOUND = 2e-2
-# fp32 accumulation on both sides; the kernel multiplies bf16 x by
-# bf16-rounded (nibble * scale), the reference by fp32 dequant — outputs
-# are O(1) sums of din terms.
+# fp32 accumulation on both sides; the kernel contracts bf16 x with the exact
+# nibbles and scales the fp32 partial by the fp32 scale, the reference
+# multiplies by the fp32 dequant — outputs are O(1) sums of din terms.
 INT4_BOUND = 5e-2
 
 _Q_SLICE = 256
@@ -227,10 +230,12 @@ def cases():
         for din, dout in ((4096, 14336), (14336, 4096)):
             yield f"int4_matmul_n{rows}_{din}x{dout}", int4_case, dict(
                 rows=rows, din=din, dout=dout)
-    for rows in (16, 256):
-        for din, dout in ((4096, 14336), (14336, 4096)):
-            yield f"int4_matmul_stacked_n{rows}_{din}x{dout}", int4_case, dict(
-                rows=rows, din=din, dout=dout, layers=3, li=2)
+    stacked = [(rows, din, dout) for rows in (16, 256, 64, 128)
+               for din, dout in ((4096, 14336), (14336, 4096))]
+    stacked += [(16, 4096, 4096), (16, 4096, 1024)]
+    for rows, din, dout in stacked:
+        yield f"int4_matmul_stacked_n{rows}_{din}x{dout}", int4_case, dict(
+            rows=rows, din=din, dout=dout, layers=3, li=2)
 
 
 def main() -> int:

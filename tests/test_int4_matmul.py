@@ -46,24 +46,78 @@ def _truth(x, packed, scales):
     return np.asarray(x, np.float64) @ w
 
 
+# (din, dout, N, layer): layer None is the 2-D entry, else the stacked entry
+# on three layers' weights. Each new case crosses a boundary of the kernel's
+# tile rule (ops/int4_matmul.py ``_tiles``): grid steps along the contraction
+# (din/2 over tk = 512/256/128 packed rows, by the tile's width), scale
+# blocks shared by 2 or 4 steps, widths that are no multiple of 256 or 512,
+# the whole output width up to 64 rows and column tiles above, padded row
+# tiles.
+TRUTH_CASES = [
+    (1024, 256, 5, None),
+    (2048, 512, 64, None),
+    (1024, 128, 1, None),
+    (2048, 384, 16, None),  # two steps of 512 packed rows; 384 columns
+    (1024, 640, 17, None),  # one step; 640 columns; 17 rows pad to 32
+    (1024, 4096, 16, None),  # tk 256: a scales block serves two steps
+    (1024, 7296, 1, None),  # tk 128: four steps a scales block
+    (1024, 4096, 65, None),  # over 64 rows: two column tiles of 2048
+    (2048, 2560, 300, None),  # two row tiles of 256, column tiles of 1280
+    (2048, 512, 16, 2),  # stacked, layer 2 of 3
+    (1024, 4096, 65, 1),  # stacked, column tiles, layer 1 of 3
+]
+
+
 @pytest.mark.parametrize(
-    "din,dout,N", [(1024, 256, 5), (2048, 512, 64), (1024, 128, 1)]
+    "din,dout,N,layer", TRUTH_CASES,
+    ids=[f"{d}x{o}-n{n}" + ("" if li is None else f"-layer{li}")
+         for d, o, n, li in TRUTH_CASES],
 )
-def test_kernel_matches_f64_truth(din, dout, N):
+def test_kernel_matches_f64_truth(din, dout, N, layer):
     rng = np.random.default_rng(din + N)
-    w = jnp.asarray(rng.normal(size=(din, dout)).astype(np.float32) * 0.02)
+    lead = () if layer is None else (3,)
+    w = jnp.asarray(
+        rng.normal(size=lead + (din, dout)).astype(np.float32) * 0.02
+    )
     packed, scales = quantize_leaf_int4(w)
     x = jnp.asarray(rng.normal(size=(N, din)).astype(np.float32))
-    got = np.asarray(int4_matmul(x, packed, scales))
+    if layer is None:
+        got = np.asarray(int4_matmul(x, packed, scales))
+    else:
+        got = np.asarray(
+            int4_matmul_stacked(x, packed, scales, jnp.int32(layer))
+        )
+        packed, scales = packed[layer], scales[layer]
     ref = _truth(x, packed, scales)
+    assert got.shape == ref.shape
     err = np.abs(got - ref).max() / np.abs(ref).max()
     assert err < 1e-5, err
+
+
+@pytest.mark.parametrize("N", [16, 65], ids=["decode16", "prefill65"])
+def test_bf16_kernel_matches_dequant_f32_dot(N):
+    """bf16 activations, the path the chip serves: exact nibbles (carried as
+    bf16 ``24 + s``, the 24 taken off the f32 partial), the group's lanes
+    reordered by a 0/1 matrix, the scale applied in f32, against the f32
+    dequant and an exact dot."""
+    from production_stack_tpu.models.llama import dequant_int4
+
+    rng = np.random.default_rng(N)
+    w = jnp.asarray(rng.normal(size=(2048, 640)).astype(np.float32) * 0.02)
+    packed, scales = quantize_leaf_int4(w)
+    x = jnp.asarray(rng.normal(size=(N, 2048)), jnp.bfloat16)
+    got = np.asarray(int4_matmul(x, packed, scales))
+    ref = np.asarray(x.astype(jnp.float32), np.float64) @ np.asarray(
+        dequant_int4(packed, scales, jnp.float32), np.float64
+    )
+    err = np.abs(got - ref).max() / np.abs(ref).max()
+    assert err < 1e-4, err
 
 
 def test_kernel_support_gate():
     assert kernel_supports(4096, 14336, 128)
     assert kernel_supports(1024, 128, 128)
-    assert not kernel_supports(512, 128, 128)  # din below one tile
+    assert not kernel_supports(512, 128, 128)  # din below 1024
     assert not kernel_supports(4096, 100, 128)  # ragged dout
     assert not kernel_supports(128, 128, 64)  # tiny-model fallback group
 
