@@ -238,7 +238,7 @@ def fleet_dashboard():
          'by (stage), 1e-9)', "{{stage}}"),
     ], 16, 61, unit="s"))
     # Row 10 — TPU engine telemetry (docs/observability.md "Engine
-    # telemetry"): compiles, step durations, throughput/MFU, KV pressure,
+    # telemetry"): compiles, step durations, throughput, KV pressure,
     # padding waste, startup decomposition.
     p.append(panel("XLA compiles per second (by step kind)", [
         ('sum(rate(pst_engine_compile_total[5m])) by (kind)', "{{kind}}"),
@@ -252,9 +252,8 @@ def fleet_dashboard():
          'pst_engine_step_duration_seconds_bucket[2m])) by (le, kind))',
          "{{kind}}"),
     ], 16, 68, unit="s"))
-    p.append(panel("Engine tokens/s (device view) + MFU", [
+    p.append(panel("Engine tokens/s (by step kind)", [
         ('sum(pst_engine_tokens_per_second) by (kind)', "{{kind}} tok/s"),
-        ('pst_engine_mfu * 100', "MFU %"),
     ], 0, 75))
     p.append(panel("Batch fill ratio (padding waste; 1.0 = none)", [
         ('sum(rate(pst_engine_batch_fill_ratio_sum[2m])) by (kind) / '
@@ -276,7 +275,6 @@ def fleet_dashboard():
     p.append(stat("Compiles (1h)",
                   'sum(increase(pst_engine_compile_total[1h])) or vector(0)',
                   16, 82))
-    p.append(stat("MFU", 'pst_engine_mfu', 20, 82, unit="percentunit"))
     # Row 11 — SLO (docs/observability.md "SLOs & alerting"): attainment
     # ratios, multi-window burn rates, canary probes. The recorded series
     # come from observability/prometheus-rules.yaml (same generator).
@@ -396,12 +394,7 @@ def fleet_dashboard():
                   'clamp_min(sum(rate('
                   'pst_engine_device_busy_seconds_total[5m])), 1e-9), 2)',
                   0, 128, unit="percentunit"))
-    # The evidence plane (docs/observability.md "Forensics bundles"): a
-    # non-zero bundle rate means measured points are crossing their tail
-    # bars — every count here has a JSON bundle on disk explaining it.
-    p.append(panel("Forensics: evidence bundles + persisted snapshots", [
-        ('sum(increase(pst_forensics_bundles_total[1h])) by (trigger)',
-         "{{trigger}} bundles/h"),
+    p.append(panel("Flight recorder: persisted snapshots", [
         ('sum(increase(pst_engine_flight_snapshots_persisted_total[1h]))',
          "snapshots persisted/h"),
     ], 4, 128))

@@ -360,14 +360,9 @@ ENGINE_FIELDS: Tuple[EngineFieldSpec, ...] = (
                     note="companion of --speculative-ngram"),
     EngineFieldSpec("ngram_lookback", "--ngram-lookback",
                     note="companion of --speculative-ngram"),
-    EngineFieldSpec("async_decode", None,
-                    note="embedded-only experiment, superseded by "
-                    "overlap_decode"),
     EngineFieldSpec("overlap_decode", "--overlap-decode",
                     note="default-on; --no-overlap-decode is the CLI "
                     "escape hatch"),
-    EngineFieldSpec("enforce_eager", None,
-                    note="reserved; XLA always compiles"),
     EngineFieldSpec("seed", "--seed", note="debug determinism; extraArgs"),
     EngineFieldSpec("cpu_offload_blocks", "--cpu-offload-blocks",
                     _ms("kvCache.cpuOffloadBlocks"),

@@ -12,8 +12,8 @@ JAX itself drops to the CPU with a warning when TPU initialisation fails;
 a serving engine that carried on there would report healthy and serve at
 interpreter speed. :func:`resolve_platform` turns that into a start-up
 error, and everything that used to assume a device when it could not
-identify one (HBM size, peak FLOP/s, bandwidth) reads :data:`DEVICE_TABLE`
-or fails.
+identify one (the HBM size that KV sizing starts from) reads
+:data:`DEVICE_TABLE` or fails.
 """
 
 from __future__ import annotations
@@ -29,36 +29,28 @@ INTERPRET_ENV = "PST_FORCE_PALLAS_INTERPRET"
 @dataclasses.dataclass(frozen=True)
 class DeviceSpec:
     hbm_bytes: int
-    peak_bf16_flops: float
-    hbm_gbps: float
 
 
-# Published per-chip peaks, keyed by the exact ``device_kind`` string the
-# backend reports. Source: Google Cloud documentation, "TPU v5e" system
-# architecture — 197 TFLOP/s bf16, 16 GB HBM2e, 819 GB/s. A kind that is
-# not here has no peak: the MFU gauge stays unset, the bench roofline
-# refuses to run, and KV sizing needs the backend's own ``bytes_limit``.
-# Add a row (with its source) when the program meets another chip; do not
-# add a default.
+# What the engine needs to know of a chip, keyed by the exact
+# ``device_kind`` string the backend reports. Source: Google Cloud
+# documentation, "TPU v5e" system architecture — 16 GB HBM2e. A kind that
+# is not here has no size: KV sizing then needs the backend's own
+# ``bytes_limit``. The peaks a roofline divides by belong to the benchmark
+# (``perf/peaks.json``). Add a row (with its source) when the program meets
+# another chip; do not add a default.
 DEVICE_TABLE: Dict[str, DeviceSpec] = {
-    "TPU v5 lite": DeviceSpec(
-        hbm_bytes=16 * 1024**3, peak_bf16_flops=197e12, hbm_gbps=819.0
-    ),
+    "TPU v5 lite": DeviceSpec(hbm_bytes=16 * 1024**3),
 }
 
 
-def device_spec(device_kind: Optional[str]) -> Optional[DeviceSpec]:
-    return DEVICE_TABLE.get(device_kind or "")
-
-
 def require_device_spec(device_kind: Optional[str]) -> DeviceSpec:
-    spec = device_spec(device_kind)
+    spec = DEVICE_TABLE.get(device_kind or "")
     if spec is None:
         raise RuntimeError(
             f"device_kind {device_kind!r} is not in DEVICE_TABLE "
             f"(production_stack_tpu/device.py; known: "
             f"{sorted(DEVICE_TABLE)}): refusing to assume another chip's "
-            "peaks — add a row with its source"
+            "memory — add a row with its source"
         )
     return spec
 

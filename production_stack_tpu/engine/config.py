@@ -101,26 +101,18 @@ class EngineConfig:
     # Cap the prompt-lookup scan to the last N tokens (0 = whole history).
     # Bounds the per-step host-side draft cost at long context.
     ngram_lookback: int = 8192
-    # Pipelined decode: keep one burst in flight and overlap its token fetch
-    # with the next burst's execution (hides the host<->device round trip).
-    # Raises decode throughput on dispatch-latency-bound setups but ADDS up
-    # to one extra in-flight burst of queueing delay before a new arrival's
-    # prefill can run — measured on the 20k-context protocol bench it trades
-    # ~35% decode throughput for ~60% worse p50 TTFT, so it is off by
-    # default and meant for throughput-oriented (batch) serving.
-    async_decode: bool = False
     # Overlapped decode pipeline (docs/engine.md "Overlapped decode
-    # pipeline"): the arrival-gated form of pipelining. As soon as burst
-    # N's token ids are fetched, burst N+1 is dispatched and burst N's host
-    # bookkeeping (detokenization, stop scans, stream frames, stats,
-    # scheduler accounting) runs WHILE N+1 executes — but a pipeline only
-    # STARTS when the same three arrival-safety rules as adaptive
-    # deepening hold (waiting queue empty, min-running floor met, arrival
-    # stream quiet), so live-traffic TTFT never queues behind an in-flight
-    # burst it didn't already have. Saturated decode gets async_decode's
-    # throughput; paced traffic keeps the synchronous loop's latency.
+    # pipeline"): keep one burst in flight. As soon as burst N's token ids
+    # are fetched, burst N+1 is dispatched and burst N's host bookkeeping
+    # (detokenization, stop scans, stream frames, stats, scheduler
+    # accounting) runs WHILE N+1 executes, which hides the host<->device
+    # round trip. A pipeline only STARTS when the same three
+    # arrival-safety rules as adaptive deepening hold (waiting queue
+    # empty, min-running floor met, arrival stream quiet), so live-traffic
+    # TTFT never queues behind an in-flight burst it didn't already have:
+    # saturated decode gets the pipeline's throughput, paced traffic keeps
+    # the synchronous loop's latency.
     overlap_decode: bool = True
-    enforce_eager: bool = False  # reserved; XLA always compiles
     seed: int = 0
     # KV tiering (LMCache-analogue knobs; SURVEY.md §2.4).
     cpu_offload_blocks: int = 0
@@ -210,9 +202,9 @@ class EngineConfig:
     # recorder"): every retained snapshot (tail outlier, live compile,
     # SIGTERM/fatal) is also written as one JSON file under this
     # directory, bounded with oldest-first eviction, and loaded back into
-    # GET /debug/flight?snapshots=1 after a restart — so a forensics
-    # collector can harvest the post-mortem even when the engine died
-    # before anyone scraped it. None = in-memory retention only.
+    # GET /debug/flight?snapshots=1 after a restart — so the post-mortem
+    # can be read even when the engine died before anyone scraped it.
+    # None = in-memory retention only.
     flight_snapshot_dir: Optional[str] = None
     # Per-request cost attribution (docs/observability.md "Cost
     # attribution"): accumulate each request's prefill device-seconds,

@@ -160,20 +160,15 @@ class LLMEngine:
                 max_model_len=cfg.max_model_len,
                 num_decode_steps=cfg.num_decode_steps,
                 # The in-flight continuation writes one burst past the host
-                # view, so its pages must already exist at dispatch time —
-                # for unconditional pipelining (async_decode) AND for the
-                # arrival-gated overlap (which can engage on any pass).
+                # view, so its pages must already exist at dispatch time
+                # (the arrival-gated overlap can engage on any pass).
                 # Spec engines never pipeline (_pipeline_ok defers to
                 # speculation), so they keep the tighter reservation.
                 decode_lookahead=(
-                    2
-                    if (
-                        cfg.async_decode
-                        or (cfg.overlap_decode and not cfg.speculative_ngram)
-                    )
+                    2 if cfg.overlap_decode and not cfg.speculative_ngram
                     else 1
                 ),
-                spec_tokens=0 if cfg.async_decode else cfg.speculative_ngram,
+                spec_tokens=cfg.speculative_ngram,
                 swap_quantum=cfg.swap_quantum_tokens,
                 deadline_shedding=cfg.deadline_shedding,
                 tenant_fairness=cfg.tenant_fairness,
@@ -181,14 +176,6 @@ class LLMEngine:
             self.allocator,
             swapper=self.swapper,
         )
-        if cfg.async_decode and cfg.speculative_ngram:
-            # Pipelined bursts win every decode step, so the spec branch
-            # would never run — surface the conflict instead of silently
-            # reserving pages for it.
-            logger.warning(
-                "speculative_ngram is disabled while async_decode is on "
-                "(pipelined bursts preempt the speculation path)"
-            )
         # Speculative-decoding counters (engine.stats / observability).
         self.spec_proposed_total = 0
         self.spec_accepted_total = 0
@@ -676,7 +663,7 @@ class LLMEngine:
         logprobs (verify returns no packed logprob rows), plus too few
         draft-carrying rows to beat a plain burst."""
         K = self.cfg.speculative_ngram
-        if not K or self.cfg.async_decode or not decodes:
+        if not K or not decodes:
             return None
         with ENGINE_TELEMETRY.phase("batch_build", "spec_verify"):
             from .spec import propose_ngram
@@ -851,19 +838,16 @@ class LLMEngine:
     # -- pipelined decode internals ------------------------------------
 
     def _pipeline_ok(self, sched) -> bool:
-        """May this pass start a pipelined burst? ``async_decode`` pipelines
-        unconditionally (batch serving); ``overlap_decode`` — the default —
-        engages only when the three arrival-safety rules certify that no
-        arrival can be delayed (`_arrival_safe`), so live-traffic TTFT
-        never pays for the overlap. Guided rows are excluded (their
+        """May this pass start a pipelined burst? ``overlap_decode`` — the
+        default — engages only when the three arrival-safety rules certify
+        that no arrival can be delayed (`_arrival_safe`), so live-traffic
+        TTFT never pays for the overlap. Guided rows are excluded (their
         allowed-token mask is rebuilt per token host-side); penalty rows
         ride — their state lives in multi_step's scan carry."""
         if not sched.decodes:
             return False
         if any(s.sampling.guided_choice for s in sched.decodes):
             return False
-        if self.cfg.async_decode:
-            return True
         # Speculation and overlap are alternative round-trip amortizers;
         # when n-gram speculation is configured it wins outright (more
         # tokens per trip for greedy rows) and overlap stays out of its
@@ -1159,7 +1143,7 @@ class LLMEngine:
             out["adaptive_deep_bursts_total"] = float(
                 self.adaptive_deep_bursts_total
             )
-        if self.cfg.async_decode or self.cfg.overlap_decode:
+        if self.cfg.overlap_decode:
             out["pipelined_bursts_total"] = float(self.pipelined_bursts_total)
         # Tiering KPIs (present when the LMCache-analogue layer is on).
         for attr in ("host_hit_blocks", "remote_hit_blocks", "spilled_blocks"):

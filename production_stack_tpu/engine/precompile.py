@@ -152,9 +152,9 @@ def encode_buckets(cfg: EngineConfig) -> List[int]:
 
 def burst_depths(cfg: EngineConfig) -> List[int]:
     """Burst depths the engine dispatches at steady state: the configured
-    depth and the adaptive deep depth — plus, when a pipelining mode is on
-    (``async_decode`` or the default arrival-gated ``overlap_decode``),
-    the configured depth even at 1: the pipeline runs the multi-step
+    depth and the adaptive deep depth — plus, when the pipeline is on
+    (the default arrival-gated ``overlap_decode``), the configured depth
+    even at 1: the pipeline runs the multi-step
     executable (``b{B}xn{n}``) at whatever depth the scheduler emits, so
     a depth-1 engine overlaps through ``b{B}xn1`` shapes. (The
     per-sequence clamp near max_model_len can shrink n through arbitrary
@@ -168,7 +168,7 @@ def burst_depths(cfg: EngineConfig) -> List[int]:
     }
     # Mirrors LLMEngine._pipeline_ok: overlap defers to configured n-gram
     # speculation, so spec engines never dispatch the depth-1 variant.
-    if cfg.async_decode or (cfg.overlap_decode and not cfg.speculative_ngram):
+    if cfg.overlap_decode and not cfg.speculative_ngram:
         depths.add(max(cfg.num_decode_steps, 1))
     return sorted(depths)
 

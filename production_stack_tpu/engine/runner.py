@@ -203,12 +203,10 @@ class ModelRunner:
             self.params = self._init_params_streamed(pspecs)
         else:
             self.params = self._init_params_sharded(pspecs)
-        leaves = jax.tree.leaves(self.params)
-        self.param_count = sum(x.size for x in leaves)
-        param_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
-        # Total weight bytes as resident (post-quantization): the decode
-        # roofline's per-step weight-read term (benchmarks/bench_engine.py).
-        self.param_bytes = param_bytes
+        # Weight bytes as resident (post-quantization).
+        param_bytes = sum(
+            x.size * x.dtype.itemsize for x in jax.tree.leaves(self.params)
+        )
         logger.info(
             "params ready: %.2f GiB total, %.1fs", param_bytes / 2**30, time.time() - t0
         )
@@ -216,10 +214,7 @@ class ModelRunner:
         # (pst_engine_startup_seconds{phase="load"}).
         t_load_end = time.perf_counter()
         ENGINE_TELEMETRY.record_startup_phase("load", t_load_end - t_init)
-        ENGINE_TELEMETRY.set_model_info(
-            self.param_count,
-            device_kind=getattr(jax.local_devices()[0], "device_kind", None),
-        )
+        ENGINE_TELEMETRY.record_start_time()
 
         self.num_blocks = resolve_num_kv_blocks(
             cfg, self.model_cfg, param_bytes // (max(tp, 1) * pp)
@@ -1598,7 +1593,7 @@ class ModelRunner:
     def _record_warmup(self, kind: str, key: tuple, seconds: float,
                        label: str) -> None:
         # tokens=0: warmup moves no real tokens, so the throughput window
-        # and MFU stay honest; the compile itself is counted (it is one).
+        # stays honest; the compile itself is counted (it is one).
         # count_busy=False: warmup serves no request, so it stays out of
         # the device-busy denominator and the flight ring (a warmup pass
         # would otherwise flood the ring with compile snapshots).

@@ -1,4 +1,4 @@
-"""TPU-engine telemetry: compiles, step durations, MFU, KV pressure.
+"""TPU-engine telemetry: compiles, step durations, throughput, KV pressure.
 
 The metrics PR 3 could not give the engine: everything here is fed from
 the *device-dispatch* layer (``engine/runner.py``) and the scheduler, so a
@@ -35,7 +35,6 @@ from prometheus_client import (
     generate_latest,
 )
 
-from ..device import device_spec
 
 ENGINE_TELEMETRY_REGISTRY = CollectorRegistry()
 
@@ -112,12 +111,6 @@ tokens_per_second = Gauge(
     "pst_engine_tokens_per_second",
     "Engine token throughput over a short sliding window, by step kind",
     ["kind"],
-    registry=ENGINE_TELEMETRY_REGISTRY,
-)
-mfu_gauge = Gauge(
-    "pst_engine_mfu",
-    "Model-FLOPs utilization estimate: 2 * params * tokens/s over the "
-    "accelerator's peak FLOPs",
     registry=ENGINE_TELEMETRY_REGISTRY,
 )
 kv_page_occupancy = Gauge(
@@ -212,10 +205,11 @@ tenant_device_seconds = Counter(
 )
 device_busy_seconds = Counter(
     "pst_engine_device_busy_seconds",
-    "Cumulative wall the device spent executing live-traffic dispatches "
-    "(warmup precompilation excluded) — the denominator per-request cost "
-    "attribution is audited against (sum of request device-seconds must "
-    "cover >= 90% of this)",
+    "Cumulative host-timed wall of live-traffic step dispatches (warmup "
+    "precompilation excluded): the host's clock around each step, not a "
+    "device counter — the denominator per-request cost attribution is "
+    "audited against (sum of request device-seconds must cover >= 90% of "
+    "this)",
     registry=ENGINE_TELEMETRY_REGISTRY,
 )
 
@@ -309,26 +303,16 @@ class EngineTelemetry:
         from .flight import NULL_FLIGHT_RECORDER
 
         self._flight = NULL_FLIGHT_RECORDER
-        # Live-traffic device-busy accumulator — the denominator the cost
-        # attribution audit (bench `cost` phase) sums request costs against.
+        # Live-traffic step-wall accumulator (host-timed) — the denominator
+        # the cost attribution audit sums request costs against.
         self._device_busy_s = 0.0
-        self.param_count = 0
-        # None until a known device_kind supplies one: the MFU gauge stays
-        # unset rather than measuring against another chip's peak.
-        self.peak_flops: Optional[float] = None
         # --no-startup-phases: the gauges stay at 0 (helm
         # servingEngineSpec.observability.startupPhases).
         self.startup_enabled = True
 
     # -- model / startup ------------------------------------------------
 
-    def set_model_info(
-        self, param_count: int, device_kind: Optional[str] = None,
-        peak_flops: Optional[float] = None,
-    ) -> None:
-        self.param_count = int(param_count)
-        spec = device_spec(device_kind)
-        self.peak_flops = peak_flops or (spec.peak_bf16_flops if spec else None)
+    def record_start_time(self) -> None:
         start_time_seconds.set(time.time())
 
     def record_startup_phase(self, phase: str, seconds: float) -> None:
@@ -526,11 +510,9 @@ class EngineTelemetry:
         while self._tok_samples and self._tok_samples[0][0] < cutoff:
             self._tok_samples.popleft()
         per_kind: Dict[str, int] = {}
-        total = 0
         for _, kind, toks in self._tok_samples:
             self._tok_kinds.add(kind)
             per_kind[kind] = per_kind.get(kind, 0) + toks
-            total += toks
         span = (
             max(now - self._tok_samples[0][0], 0.5)
             if self._tok_samples else 1.0
@@ -540,10 +522,6 @@ class EngineTelemetry:
         for kind in self._tok_kinds:
             tokens_per_second.labels(kind=kind).set(
                 per_kind.get(kind, 0) / span
-            )
-        if self.param_count and self.peak_flops:
-            mfu_gauge.set(
-                2.0 * self.param_count * (total / span) / self.peak_flops
             )
 
     # -- compile events → request traces --------------------------------
@@ -558,8 +536,7 @@ class EngineTelemetry:
         return events
 
     def compile_count(self) -> int:
-        """Total compiles observed since process start (bench.py snapshots
-        this around each qps point to flag recompile-polluted sweeps)."""
+        """Total compiles observed since process start."""
         with self._lock:
             return self._compiles
 

@@ -72,8 +72,8 @@ def load_snapshot_dir(path: str, limit: Optional[int] = None) -> List[dict]:
     lexical sort is chronological. Unparseable files are skipped — a
     snapshot half-written at SIGKILL must not poison the post-mortem.
 
-    Shared by the recorder's restart load-back and the forensics
-    collector's post-mortem path (obs/forensics.py)."""
+    The recorder's restart load-back reads through this, and so can
+    whoever collects a dead engine's snapshot dir."""
     snaps: List[dict] = []
     try:
         names = sorted(
@@ -344,8 +344,8 @@ class FlightRecorder:
     ) -> dict:
         """The ``GET /debug/flight`` response body. ``include_restored``
         (the ``?snapshots=1`` query) adds snapshots persisted by a
-        previous process to this snapshot dir — the post-mortem a
-        forensics collector reads after a restart."""
+        previous process to this snapshot dir — the post-mortem read
+        after a restart."""
         payload = {
             **self.stats(),
             "fields": list(_FIELDS),

@@ -54,11 +54,8 @@ REGISTRY: Tuple[MetricSpec, ...] = (
     # re-pushed to owners that missed them (read-repair).
     MetricSpec("pst_kv_integrity_failures", COUNTER, "obs/metrics.py"),
     MetricSpec("pst_kv_read_repairs", COUNTER, "obs/metrics.py"),
-    # Evidence plane (docs/observability.md "Forensics bundles" /
-    # "Flight recorder"): bundles harvested when a measured point crosses
-    # its tail bar, and flight snapshots persisted to disk so they
-    # survive process death.
-    MetricSpec("pst_forensics_bundles", COUNTER, "obs/metrics.py"),
+    # Flight snapshots persisted to disk so they survive process death
+    # (docs/observability.md "Flight recorder").
     MetricSpec("pst_engine_flight_snapshots_persisted", COUNTER, "obs/metrics.py"),
     # --- obs/logging.py: structured-logging hot-path sampler ------------
     MetricSpec("pst_log_dropped", COUNTER, "obs/logging.py"),
@@ -70,7 +67,6 @@ REGISTRY: Tuple[MetricSpec, ...] = (
     MetricSpec("pst_engine_step_phase_seconds", HISTOGRAM, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_batch_fill_ratio", HISTOGRAM, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_tokens_per_second", GAUGE, "obs/engine_telemetry.py"),
-    MetricSpec("pst_engine_mfu", GAUGE, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_kv_page_occupancy", GAUGE, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_kv_page_high_watermark", GAUGE, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_preemptions", COUNTER, "obs/engine_telemetry.py"),

@@ -68,18 +68,6 @@ kv_read_repairs = Counter(
     registry=OBS_REGISTRY,
 )
 
-forensics_bundles = Counter(
-    "pst_forensics_bundles",
-    "Evidence bundles harvested by the tail-outlier forensics collector "
-    "(obs/forensics.py), by trigger (tail_outlier = p99 > 3x p50, "
-    "slo_bar = an absolute latency bar, postmortem = collected from a "
-    "dead engine's persisted snapshot dir). Each bundle is one JSON file "
-    "beside the bench output naming the stalled step's bucket and queue "
-    "state (docs/observability.md \"Forensics bundles\")",
-    ["trigger"],
-    registry=OBS_REGISTRY,
-)
-
 flight_snapshots_persisted = Counter(
     "pst_engine_flight_snapshots_persisted",
     "Flight-recorder snapshots written to --flight-snapshot-dir (bounded,"
@@ -87,12 +75,6 @@ flight_snapshots_persisted = Counter(
     " death and restart (docs/observability.md \"Flight recorder\")",
     registry=OBS_REGISTRY,
 )
-
-
-def note_forensics_bundle(trigger: str, n: int = 1) -> None:
-    """Count ``n`` harvested evidence bundles for ``trigger``."""
-    if n > 0:
-        forensics_bundles.labels(trigger=trigger).inc(n)
 
 
 def note_flight_snapshot_persisted(n: int = 1) -> None:

@@ -42,7 +42,6 @@ _METRIC_FIELDS = {
     "vllm:gpu_cache_usage_perc": "gpu_cache_usage_perc",
     # Engine telemetry (docs/observability.md "Engine telemetry").
     "pst_engine_compile_total": "engine_compiles_total",
-    "pst_engine_mfu": "engine_mfu",
     "pst_engine_kv_page_occupancy": "engine_kv_page_occupancy",
     "pst_engine_kv_page_high_watermark": "engine_kv_page_high_watermark",
     "pst_engine_warmup_coverage": "engine_warmup_coverage",
@@ -98,7 +97,6 @@ class EngineStats:
     gpu_prefix_cache_queries_total: int = 0
     gpu_cache_usage_perc: float = 0.0
     engine_compiles_total: int = 0
-    engine_mfu: float = 0.0
     engine_kv_page_occupancy: float = 0.0
     engine_kv_page_high_watermark: float = 0.0
     engine_warmup_coverage: float = 0.0

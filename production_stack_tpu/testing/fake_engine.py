@@ -202,8 +202,8 @@ class FakeEngineState:
         # Retained flight snapshots (the real recorder's snapshot_log
         # contract): the `stall` fault appends a deterministic
         # tail_outlier snapshot naming the stalled step's bucket and
-        # queue depths, so forensics tests induce the BENCH_r05
-        # signature on CPU. With a flight_snapshot_dir set, each
+        # queue depths, so flight-snapshot tests induce a stalled
+        # step on CPU. With a flight_snapshot_dir set, each
         # snapshot is also persisted (same file naming as
         # obs/flight.py) so post-mortem collection works after SIGKILL.
         self.flight_snapshots: List[dict] = []
@@ -1219,8 +1219,6 @@ def create_fake_engine_app(
                 'pst_engine_batch_fill_ratio_count{kind="decode"} 10',
                 "# TYPE pst_engine_tokens_per_second gauge",
                 'pst_engine_tokens_per_second{kind="decode"} 1234.0',
-                "# TYPE pst_engine_mfu gauge",
-                "pst_engine_mfu 0.31",
                 "# TYPE pst_engine_kv_page_occupancy gauge",
                 f"pst_engine_kv_page_occupancy {state.kv_occupancy:.4f}",
                 "# TYPE pst_engine_kv_page_high_watermark gauge",
@@ -1696,8 +1694,8 @@ def main(argv: Optional[list] = None) -> None:
                    help="persist flight snapshots (stall outliers) as "
                         "JSON files here, same naming contract as the "
                         "real engine's --flight-snapshot-dir — the "
-                        "post-mortem forensics path: bundles survive "
-                        "SIGKILL; any snapshots already in the dir are "
+                        "post-mortem path: snapshots survive "
+                        "SIGKILL; any already in the dir are "
                         "loaded back and served via "
                         "/debug/flight?snapshots=1")
     p.add_argument("--log-format", choices=["text", "json"], default="text",

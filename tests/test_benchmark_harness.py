@@ -153,149 +153,3 @@ async def test_multi_round_qa_sharegpt_workload(tmp_path):
         assert all(r.status == 200 for r in records)
     finally:
         await runner.cleanup()
-
-
-def test_bench_partial_results_survive_timeouts(tmp_path, monkeypatch):
-    """BENCH_r05 fix: a harness timeout (rc=124) must still yield a
-    parseable partial JSON — the engine child checkpoints per qps point,
-    and bench.py falls back to the partial file."""
-    import json
-    import subprocess
-    import sys
-
-    sys.path.insert(0, ".")
-    import bench
-    from benchmarks import bench_engine
-
-    # 1) The child's atomic checkpoint writer.
-    out = tmp_path / "partial.json"
-    monkeypatch.setenv("PST_BENCH_ENGINE_OUT", str(out))
-    bench_engine.write_partial({"backend": "cpu", "flagship": {
-        "partial": True, "sweep": [{"qps": 0.1, "compiles": 0}],
-    }})
-    data = json.loads(out.read_text())
-    assert data["flagship"]["partial"] is True
-    assert not out.with_suffix(".json.tmp").exists()
-
-    # 2) bench.py's fallback read.
-    assert bench.read_partial(str(out))["backend"] == "cpu"
-    assert bench.read_partial(str(tmp_path / "missing.json")) == {}
-    bad = tmp_path / "bad.json"
-    bad.write_text("{not json")
-    assert bench.read_partial(str(bad)) == {}
-
-    # 3) run_engine_phase degrades to the partial on child timeout. The
-    # fake child writes its checkpoint then "hangs" — run_engine_phase
-    # clears stale partials BEFORE launching, so the write must happen
-    # inside the (mocked) child run.
-    def fake_run(*args, **kwargs):
-        bench_engine.write_partial({"backend": "cpu", "flagship": {
-            "partial": True, "sweep": [{"qps": 0.1}],
-        }})
-        raise subprocess.TimeoutExpired(cmd="bench_engine", timeout=1)
-
-    monkeypatch.setattr(bench.subprocess, "run", fake_run)
-    res = bench.run_engine_phase()
-    assert res["partial"] is True
-    assert res["flagship"]["sweep"] == [{"qps": 0.1}]
-    assert "timed out" in res["error"]
-
-    # 4) emit() keeps the last stdout line a complete JSON object and
-    # mirrors to $PST_BENCH_OUT.
-    final = tmp_path / "final.json"
-    monkeypatch.setenv("PST_BENCH_OUT", str(final))
-    bench.emit(bench.assemble(res, None, None))
-    assert json.loads(final.read_text())["backend"] == "cpu"
-
-
-def test_bench_time_budget_carving_and_traps(monkeypatch):
-    """ROADMAP 5a bench hardening: --time-budget carves per-phase walls,
-    SIGTERM/SIGALRM raise BenchInterrupted (so phases unwind through
-    their cleanup and main() still flushes the final JSON), and the
-    engine child's budget gate trips once its wall is spent."""
-    import os
-    import signal
-    import sys
-    import time
-
-    sys.path.insert(0, ".")
-    import bench
-    from benchmarks import bench_engine
-
-    # Flag / env parsing.
-    assert bench.parse_time_budget(["--time-budget", "30"]) == 30.0
-    assert bench.parse_time_budget(["--time-budget=45"]) == 45.0
-    monkeypatch.setenv("PST_BENCH_TIME_BUDGET", "12")
-    assert bench.parse_time_budget([]) == 12.0
-    monkeypatch.delenv("PST_BENCH_TIME_BUDGET")
-    assert bench.parse_time_budget([]) == 0.0
-
-    # Carving: a phase gets its weight share of the REMAINING budget,
-    # and an unbudgeted run never reports exhaustion.
-    b = bench.TimeBudget(100.0)
-    assert b.enabled
-    assert abs(b.phase_wall(6.0, 10.0) - 60.0) < 1.0
-    assert abs(b.phase_wall(10.0, 10.0) - 100.0) < 1.0
-    assert not b.exhausted()
-    spent = bench.TimeBudget(0.001)
-    time.sleep(0.01)
-    assert spent.exhausted(floor=1.0)
-    assert not bench.TimeBudget(0.0).enabled
-    assert not bench.TimeBudget(0.0).exhausted()
-
-    # SIGTERM -> BenchInterrupted through the trap (restored afterwards).
-    old_term = signal.getsignal(signal.SIGTERM)
-    old_alrm = signal.getsignal(signal.SIGALRM)
-    try:
-        bench.install_term_trap()
-        import pytest
-
-        with pytest.raises(bench.BenchInterrupted):
-            os.kill(os.getpid(), signal.SIGTERM)
-        # The per-phase wall rides SIGALRM through the same trap.
-        with pytest.raises(bench.BenchInterrupted):
-            bench.phase_alarm(0.05)
-            time.sleep(0.5)
-    finally:
-        bench.phase_alarm(0.0)
-        signal.signal(signal.SIGTERM, old_term)
-        signal.signal(signal.SIGALRM, old_alrm)
-
-    # BenchInterrupted must NOT be an Exception: the per-phase
-    # `except Exception` guards would otherwise swallow the shutdown.
-    assert not issubclass(bench.BenchInterrupted, Exception)
-    assert not issubclass(bench_engine.BenchInterrupted, Exception)
-
-    # Engine child's budget gate (PST_BENCH_ENGINE_BUDGET).
-    monkeypatch.setenv("PST_BENCH_ENGINE_BUDGET", "10000")
-    assert not bench_engine.budget_exhausted()
-    monkeypatch.setenv("PST_BENCH_ENGINE_BUDGET", "0.001")
-    assert bench_engine.budget_exhausted(floor=1.0)
-    monkeypatch.delenv("PST_BENCH_ENGINE_BUDGET")
-    assert not bench_engine.budget_exhausted()
-
-
-def test_bench_assemble_flags_compile_polluted_sweeps():
-    """The sweep's compile accounting surfaces in the assembled output."""
-    import sys
-
-    sys.path.insert(0, ".")
-    import bench
-
-    engine_res = {
-        "backend": "tpu",
-        "rpc_floor_ms": 50.0,
-        "flagship": {
-            "p50_ttft_ms": 180.0,
-            "warmup_compiles": 9,
-            "sweep_compiles": 1,
-            "sweep": [
-                {"qps": 0.5, "p99_ttft_ms": 120312.0, "compiles": 1,
-                 "compile_polluted": True},
-            ],
-        },
-    }
-    out = bench.assemble(engine_res, None, None)
-    assert out["value"] == 180.0
-    assert out["warmup_compiles"] == 9
-    assert out["sweep"][0]["compile_polluted"] is True

@@ -3,7 +3,7 @@
 No chip is needed: the backend is monkeypatched. Covers the refusals PR 21
 put where fallbacks used to hide the device — an engine that finds no
 accelerator, interpret mode inherited on a chip, a device kind with no
-peak, an int4 engine on a multi-device mesh on tpu, a prefill chunk the
+known memory, an int4 engine on a multi-device mesh on tpu, a prefill chunk the
 q tile does not divide — the compile-cache placement rule, the per-shard
 attention wrapper, and ``chip_smoke.py`` failing at once on the CPU.
 """
@@ -24,7 +24,6 @@ from production_stack_tpu.engine import runner as runner_mod
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.engine import LLMEngine
 from production_stack_tpu.models.registry import get_model_config
-from production_stack_tpu.obs.engine_telemetry import EngineTelemetry
 
 pytestmark = pytest.mark.fast
 
@@ -109,49 +108,11 @@ def test_int4_on_a_multi_device_mesh_is_refused_on_tpu(monkeypatch, parallel):
 # ---------------------------------------------------------------------------
 
 
-def test_unknown_device_kind_has_no_peak():
-    assert device.device_spec("cpu") is None
-    assert device.device_spec(None) is None
-    with pytest.raises(RuntimeError, match="DEVICE_TABLE"):
-        device.require_device_spec("TPU v9000")
-    v5e = device.require_device_spec("TPU v5 lite")
-    assert (v5e.hbm_bytes, v5e.peak_bf16_flops, v5e.hbm_gbps) == (
-        16 * 1024**3, 197e12, 819.0
-    )
-
-
-def test_unknown_device_kind_leaves_mfu_unset():
-    from production_stack_tpu.obs import engine_telemetry as et
-
-    tel = EngineTelemetry()
-    tel.set_model_info(1_000_000, device_kind="cpu")
-    assert tel.peak_flops is None
-    before = et.mfu_gauge._value.get()
-    tel.record_dispatch("decode", ("a",), 0.1, batch_bucket="b8", tokens=100)
-    tel.record_dispatch("decode", ("a",), 0.1, batch_bucket="b8", tokens=100)
-    assert et.mfu_gauge._value.get() == before  # never set against a guess
-    tel.set_model_info(1_000_000, device_kind="TPU v5 lite")
-    assert tel.peak_flops == 197e12
-
-
-def test_bench_roofline_needs_a_known_chip(monkeypatch):
-    from benchmarks import bench_engine
-
-    class Dev:
-        platform = "tpu"
-        device_kind = "TPU v9000"
-
-    class Eng:
-        cfg = _tiny()
-        model_cfg = get_model_config("tiny-llama-debug")
-
-    # The CPU smoke profile has no device to put a roof on ...
-    assert bench_engine.roofline_table(Eng, 100.0, batch=4, ctx_tokens=64) is None
-    # ... and a chip that is not in the table is an error, not a v5e.
-    monkeypatch.setattr(jax, "local_devices", lambda: [Dev()])
-    with pytest.raises(RuntimeError, match="DEVICE_TABLE"):
-        bench_engine.roofline_table(Eng, 100.0, batch=4, ctx_tokens=64)
-    assert bench_engine.mfu(10**9, 100.0) is None
+def test_unknown_device_kind_has_no_size():
+    for kind in ("cpu", None, "TPU v9000"):
+        with pytest.raises(RuntimeError, match="DEVICE_TABLE"):
+            device.require_device_spec(kind)
+    assert device.require_device_spec("TPU v5 lite").hbm_bytes == 16 * 1024**3
 
 
 # ---------------------------------------------------------------------------
@@ -219,9 +180,9 @@ def test_compile_cache_unset_uses_flag_then_fixed_checkout_path(
 
 
 def test_no_temporary_cache_directories_in_the_tree():
-    """Nothing on the serving or bench path builds a compile-cache
-    directory from a temporary name."""
-    for rel in ("benchmarks/bench_engine.py", "bench.py", "chip_smoke.py",
+    """Nothing on the serving path builds a compile-cache directory from
+    a temporary name."""
+    for rel in ("chip_smoke.py",
                 "production_stack_tpu/engine/precompile.py",
                 "production_stack_tpu/engine/engine.py"):
         with open(os.path.join(REPO, rel)) as f:
@@ -325,25 +286,8 @@ def test_engine_states_its_device_path():
 
 
 # ---------------------------------------------------------------------------
-# bench.py / chip_smoke.py: no quiet answers
+# chip_smoke.py: no quiet answers
 # ---------------------------------------------------------------------------
-
-
-def test_bench_backend_probe_fails_when_the_probe_says_nothing(monkeypatch):
-    sys.path.insert(0, REPO)
-    import bench
-
-    def fake_run(*a, **k):
-        return subprocess.CompletedProcess(a, 1, stdout="", stderr="boom")
-
-    monkeypatch.setattr(bench.subprocess, "run", fake_run)
-    with pytest.raises(RuntimeError, match="backend probe failed"):
-        bench.probe_backend()
-    monkeypatch.setattr(
-        bench.subprocess, "run",
-        lambda *a, **k: subprocess.CompletedProcess(a, 0, stdout="tpu\n"),
-    )
-    assert bench.probe_backend() == "tpu"
 
 
 def test_chip_smoke_fails_at_once_on_the_cpu():

@@ -1,7 +1,7 @@
 """Engine telemetry ring (docs/observability.md "Engine telemetry").
 
 Ring 1: the EngineTelemetry sink — first-call-per-bucket compile
-detection, step-duration routing, throughput/MFU, stats refresh.
+detection, step-duration routing, throughput, stats refresh.
 Ring 2: a real tiny CPU engine — a forced recompile (new prefill shape
 bucket) increments pst_engine_compile_total, records
 pst_engine_compile_seconds, and rides RequestOutput.compile_events.
@@ -74,16 +74,14 @@ def test_compile_events_drain_once():
     assert tel.drain_compile_events() == []
 
 
-def test_throughput_and_mfu_update():
+def test_throughput_update():
     tel = EngineTelemetry()
-    tel.set_model_info(1_000_000, peak_flops=1e9)
     tel.record_dispatch("decode", ("a",), 0.1, batch_bucket="b8", tokens=100)
     tel.record_dispatch("decode", ("a",), 0.1, batch_bucket="b8", tokens=100)
     # Gauges live in the shared registry; the values themselves are
     # asserted through exposition text (the public contract).
     text = render_engine_telemetry().decode()
     assert 'pst_engine_tokens_per_second{kind="decode"}' in text
-    assert "pst_engine_mfu" in text
 
 
 def test_refresh_from_stats_tracks_high_watermark():

@@ -4,7 +4,9 @@
 
 - Ring bounds under sustained load (no growth), outlier auto-snapshot
   firing with the stalled step's bucket + queue state, compile
-  snapshots, disabled/null behavior.
+  snapshots, disabled/null behavior; snapshot persistence (naming
+  contract, bounded oldest-first disk eviction, restart load-back via
+  ``?snapshots=1``) and the fake engine's deterministic stall snapshot.
 - Cost attribution parity: request device-seconds sum to the
   device-busy wall in BOTH pipeline modes (overlap shares must not
   double-count), the X-PST-Cost header / usage extension, and the
@@ -17,6 +19,7 @@
 import asyncio
 import importlib.util
 import json
+import os
 import socket
 import time
 
@@ -36,6 +39,7 @@ from production_stack_tpu.obs.engine_telemetry import (
 from production_stack_tpu.obs.flight import (
     NULL_FLIGHT_RECORDER,
     FlightRecorder,
+    load_snapshot_dir,
 )
 from production_stack_tpu.obs.top import render_frame
 from production_stack_tpu.router.services import capacity as capacity_mod
@@ -153,6 +157,67 @@ def test_probe_failure_never_kills_the_step():
     rec.set_probe(bad_probe)
     rec.record_step("decode", "b2", 0.001)
     assert rec.records()[-1]["waiting"] == 0
+
+
+# ---------------------------------------------------------------------------
+# Flight snapshot persistence (the engine-side half of the post-mortem)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.fast
+def test_flight_snapshots_persist_and_restore(tmp_path):
+    d = str(tmp_path / "snaps")
+    rec = FlightRecorder(capacity=16, snapshot_dir=d)
+    rec.record_step("decode", "b4xn8", 0.002, tokens=8)
+    snap = rec.snapshot("tail_outlier", {"bucket": "b4xn8", "waiting": 3})
+    assert snap["detail"]["bucket"] == "b4xn8"
+    names = sorted(os.listdir(d))
+    assert len(names) == 1
+    # Naming contract: flight_<time_ns>_<seq>_<reason>.json, no .tmp left.
+    assert names[0].startswith("flight_") and names[0].endswith(
+        "_tail_outlier.json"
+    )
+    # A NEW recorder on the same dir (the restarted engine) restores it.
+    rec2 = FlightRecorder(capacity=16, snapshot_dir=d)
+    restored = rec2.restored_snapshots()
+    assert len(restored) == 1
+    assert restored[0]["detail"]["bucket"] == "b4xn8"
+    payload = rec2.to_payload(include_restored=True)
+    assert payload["snapshot_dir"] == d
+    assert payload["restored_snapshots"][0]["detail"]["waiting"] == 3
+    # Without the ?snapshots=1 flag the payload stays lean.
+    assert "restored_snapshots" not in rec2.to_payload()
+
+
+@pytest.mark.fast
+def test_flight_snapshot_disk_eviction_oldest_first(tmp_path):
+    d = str(tmp_path / "snaps")
+    rec = FlightRecorder(capacity=8, snapshot_dir=d, snapshot_disk_keep=3)
+    for i in range(5):
+        rec.snapshot("tail_outlier", {"seq": i})
+    names = sorted(os.listdir(d))
+    assert len(names) == 3
+    kept = [s["detail"]["seq"] for s in load_snapshot_dir(d)]
+    assert kept == [2, 3, 4]  # oldest evicted, chronological order kept
+
+
+@pytest.mark.fast
+def test_load_snapshot_dir_skips_corrupt_files(tmp_path):
+    d = tmp_path / "snaps"
+    d.mkdir()
+    (d / "flight_00000000000000000001_000001_tail_outlier.json").write_text(
+        json.dumps({"reason": "tail_outlier", "detail": {"ok": True}})
+    )
+    # Half-written at SIGKILL: must not poison the post-mortem.
+    (d / "flight_00000000000000000002_000002_tail_outlier.json").write_text(
+        '{"reason": "tail_ou'
+    )
+    (d / "unrelated.txt").write_text("ignored")
+    snaps = load_snapshot_dir(str(d))
+    assert len(snaps) == 1
+    assert snaps[0]["detail"]["ok"] is True
+    assert snaps[0]["persisted_as"].endswith("_000001_tail_outlier.json")
+    assert load_snapshot_dir(str(tmp_path / "missing")) == []
 
 
 # ---------------------------------------------------------------------------
@@ -448,6 +513,57 @@ async def test_fake_engine_flight_and_cost_deterministic():
             ) as r:
                 assert "X-PST-Cost" in r.headers
                 await r.read()
+    finally:
+        await runner.cleanup()
+
+
+async def test_fake_engine_stall_leaves_deterministic_snapshot(tmp_path):
+    app = create_fake_engine_app(model=MODEL, speed=5000)
+    app["state"].flight_snapshot_dir = str(tmp_path / "snaps")
+    runner, url = await _start_site(app)
+    try:
+        async with aiohttp.ClientSession() as sess:
+            async with sess.post(f"{url}/admin/fail", json={
+                "mode": "nope"
+            }) as r:
+                assert r.status == 400
+            async with sess.post(f"{url}/admin/fail", json={
+                "mode": "stall", "delay": 0.05,
+            }) as r:
+                assert r.status == 200
+            t0 = time.monotonic()
+            async with sess.post(f"{url}/v1/completions", json={
+                "model": MODEL, "prompt": "one two", "max_tokens": 4,
+            }) as r:
+                assert r.status == 200  # serves normally, just late
+                await r.read()
+            assert time.monotonic() - t0 >= 0.05
+            async with sess.get(f"{url}/debug/flight?snapshots=1") as r:
+                flight = await r.json()
+            snaps = flight["snapshot_log"]
+            assert len(snaps) == 1
+            det = snaps[0]["detail"]
+            assert snaps[0]["reason"] == "tail_outlier"
+            assert det["injected"] == "stall"
+            assert det["kind"] == "decode"
+            assert det["bucket"].startswith("b")  # names the padded bucket
+            assert det["device_s"] == pytest.approx(0.05)
+            for key in ("waiting", "running", "swapped", "kv_occupancy"):
+                assert key in det  # queue state rides the snapshot
+            # Persisted too (same naming contract as the real recorder).
+            assert flight["snapshot_dir"] == str(tmp_path / "snaps")
+            on_disk = load_snapshot_dir(str(tmp_path / "snaps"))
+            assert len(on_disk) == 1
+            assert on_disk[0]["detail"]["bucket"] == det["bucket"]
+            # One-shot: the default count=1 disarms after one stall.
+            async with sess.post(f"{url}/v1/completions", json={
+                "model": MODEL, "prompt": "three", "max_tokens": 4,
+            }) as r:
+                assert r.status == 200
+                await r.read()
+            async with sess.get(f"{url}/debug/flight") as r:
+                flight2 = await r.json()
+            assert len(flight2["snapshot_log"]) == 1
     finally:
         await runner.cleanup()
 

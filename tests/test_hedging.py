@@ -9,6 +9,7 @@ requests are never hedged.
 """
 
 import asyncio
+import time
 
 import aiohttp
 import pytest
@@ -160,12 +161,20 @@ async def test_hedge_cancelled_when_primary_wins():
                     s, c.router_url, prompt=f"c{i}", max_tokens=2
                 )
                 assert status == 200
-            text = await _router_metrics(s, c.router_url)
-            fired = _metric_value(text, "pst_hedge_fired_total") - base_fired
-            cancelled = (
-                _metric_value(text, "pst_hedge_cancelled_total") - base_cancelled
-            )
-            won = _metric_value(text, "pst_hedge_won_total") - base_won
+            # The losing leg's cancellation is counted a moment after the
+            # last response returns: poll (bounded) until the books close.
+            deadline = time.monotonic() + 2.0
+            while True:
+                text = await _router_metrics(s, c.router_url)
+                fired = _metric_value(text, "pst_hedge_fired_total") - base_fired
+                cancelled = (
+                    _metric_value(text, "pst_hedge_cancelled_total")
+                    - base_cancelled
+                )
+                won = _metric_value(text, "pst_hedge_won_total") - base_won
+                if cancelled + won == fired or time.monotonic() >= deadline:
+                    break
+                await asyncio.sleep(0.05)
             assert fired >= 1
             # Every fired hedge either won or was cancelled — none leaked.
             assert cancelled + won == fired

@@ -37,9 +37,8 @@ def _engine(**over):
         max_prefill_tokens=64,
         attn_impl="gather",
         num_decode_steps=2,
-        # Baseline: every pipeline mode off. Tests opt in explicitly.
+        # Baseline: the pipeline off. Tests opt in explicitly.
         overlap_decode=False,
-        async_decode=False,
     )
     kw.update(over)
     return LLMEngine(EngineConfig(**kw))
@@ -219,6 +218,36 @@ def test_abort_mid_overlap_cancels_cleanly():
             aborted = True
     assert eng.pipelined_bursts_total > 0
     assert kept == ref
+    assert not eng._burst_deferred
+    assert not eng.runner.burst_in_flight
+    assert eng.allocator.num_free == eng.allocator.num_blocks
+
+
+def test_overlap_late_arrival_drains_and_joins():
+    """A request arriving mid-pipeline forces a drain (prefill pending) and
+    then joins the batch; everyone finishes with exact lengths and the
+    allocator balances afterwards."""
+    eng = _overlap_engine(num_decode_steps=4)
+    rng = np.random.default_rng(4)
+    eng.add_request("r0", prompt_token_ids=rng.integers(1, 500, 21).tolist(),
+                    sampling=SamplingParams(max_tokens=24, temperature=0.0,
+                                            ignore_eos=True))
+    toks = {"r0": [], "r1": []}
+    steps = 0
+    while eng.has_work():
+        for out in eng.step():
+            toks[out.request_id].extend(out.new_token_ids)
+        steps += 1
+        if steps == 3:
+            assert eng.runner.burst_in_flight, "arrival must land mid-pipeline"
+            eng.add_request(
+                "r1", prompt_token_ids=rng.integers(1, 500, 15).tolist(),
+                sampling=SamplingParams(max_tokens=10, temperature=0.0,
+                                        ignore_eos=True),
+            )
+        assert steps < 1000
+    assert len(toks["r0"]) == 24
+    assert len(toks["r1"]) == 10
     assert not eng._burst_deferred
     assert not eng.runner.burst_in_flight
     assert eng.allocator.num_free == eng.allocator.num_blocks

@@ -60,8 +60,6 @@ def test_engine_stats_parses_engine_telemetry_names():
             "# TYPE pst_engine_compile counter",
             'pst_engine_compile_total{kind="prefill",shape_bucket="b1xt64"} 3',
             'pst_engine_compile_total{kind="decode",shape_bucket="b8"} 4',
-            "# TYPE pst_engine_mfu gauge",
-            "pst_engine_mfu 0.27",
             "# TYPE pst_engine_kv_page_occupancy gauge",
             "pst_engine_kv_page_occupancy 0.8",
             "# TYPE pst_engine_kv_page_high_watermark gauge",
@@ -71,7 +69,6 @@ def test_engine_stats_parses_engine_telemetry_names():
     )
     stats = EngineStats.from_scrape(text)
     assert stats.engine_compiles_total == 7
-    assert abs(stats.engine_mfu - 0.27) < 1e-9
     assert abs(stats.engine_kv_page_occupancy - 0.8) < 1e-9
     assert abs(stats.engine_kv_page_high_watermark - 0.93) < 1e-9
 
