@@ -77,7 +77,8 @@ class EngineConfig:
     # attached chip — ROADMAP D3). Gated on PAST arrivals only, so a live
     # Poisson stream keeps bursts at num_decode_steps and tail latency is
     # unaffected; saturated decode (batch/offline phases) runs at the deep
-    # setting. 0 = off.
+    # setting. 0 = off. The deepening is all these three fields govern:
+    # an arrival waits for the burst in flight, and a deep one is n steps.
     adaptive_decode_steps: int = 0
     adaptive_decode_quiet_s: float = 0.5
     # Additional deepening gate: require at least this many running
@@ -106,12 +107,14 @@ class EngineConfig:
     # are fetched, burst N+1 is dispatched and burst N's host bookkeeping
     # (detokenization, stop scans, stream frames, stats, scheduler
     # accounting) runs WHILE N+1 executes, which hides the host<->device
-    # round trip. A pipeline only STARTS when the same three
-    # arrival-safety rules as adaptive deepening hold (waiting queue
-    # empty, min-running floor met, arrival stream quiet), so live-traffic
-    # TTFT never queues behind an in-flight burst it didn't already have:
-    # saturated decode gets the pipeline's throughput, paced traffic keeps
-    # the synchronous loop's latency.
+    # round trip. A pipeline starts whenever the decode batch can be
+    # chained (no guided-choice row, no n-gram speculation configured, no
+    # queue left standing), arrivals or not: an arrival waits for the one
+    # burst in flight, as it waits for the running step in the synchronous
+    # loop, and its prefill is launched behind that burst. At
+    # ``num_decode_steps`` = 1 that is at most one decode step, against
+    # the host's share of every step (7 ms of 27 on a v5e: PERF.md §6,
+    # PR 30). False = the synchronous loop.
     overlap_decode: bool = True
     seed: int = 0
     # KV tiering (LMCache-analogue knobs; SURVEY.md §2.4).

@@ -32,6 +32,13 @@ from .tokenizer import get_tokenizer
 
 logger = init_logger(__name__)
 
+# Why a decode chain was drained (`LLMEngine._chain_break_reason`, and
+# `abort_all_requests`): the ``reason`` label of ``pst:pipeline_breaks_total``.
+CHAIN_BREAK_REASONS = (
+    "prefill", "blocked_on_locked", "decode_set", "depth", "table_width",
+    "queue", "not_eligible", "abort_all",
+)
+
 
 @dataclasses.dataclass
 class RequestOutput:
@@ -161,7 +168,7 @@ class LLMEngine:
                 num_decode_steps=cfg.num_decode_steps,
                 # The in-flight continuation writes one burst past the host
                 # view, so its pages must already exist at dispatch time
-                # (the arrival-gated overlap can engage on any pass).
+                # (a chain can start on any pass).
                 # Spec engines never pipeline (_pipeline_ok defers to
                 # speculation), so they keep the tighter reservation.
                 decode_lookahead=(
@@ -195,11 +202,15 @@ class LLMEngine:
             self.lora_manager = None
         # Unloaded-adapter slots awaiting their last in-flight sequence.
         self._retiring_slots: set = set()
-        # Last request arrival (adaptive burst-depth + overlap gates) +
-        # observability counters for deep/pipelined bursts actually executed.
+        # Last request arrival (the adaptive burst-depth gate), and what the
+        # decode loop did: every decode dispatch, those of them that were
+        # chained (a chain's start and its continuations) or deep, and each
+        # drained chain by the reason it could not go on.
         self._last_arrival = 0.0
         self.adaptive_deep_bursts_total = 0
+        self.decode_dispatches_total = 0
         self.pipelined_bursts_total = 0
+        self.pipeline_breaks = {why: 0 for why in CHAIN_BREAK_REASONS}
         # Flight recorder (docs/observability.md "Flight recorder"):
         # always-on bounded ring of per-step records, fed through
         # ENGINE_TELEMETRY's dispatch path; this engine's scheduler/KV
@@ -438,6 +449,7 @@ class LLMEngine:
         """Abort everything queued or running (sleep / fatal-error paths)."""
         if self.runner.burst_in_flight:
             self.runner.burst_drain()  # discard: everything is going away
+            self.pipeline_breaks["abort_all"] += 1
             self._burst_seqs = []
             self._burst_n = 0
             self._release_burst_deferred()
@@ -485,14 +497,16 @@ class LLMEngine:
     # ------------------------------------------------------------------
 
     def _arrival_safe(self) -> bool:
-        """The three arrival-safety rules shared by adaptive deepening and
-        overlap engagement (proposals/adaptive-decode-bursts.md): PAST
-        observations only — (1) the waiting queue is empty, (2) at least
+        """The three arrival-safety rules of adaptive deepening
+        (proposals/adaptive-decode-bursts.md): PAST observations only —
+        (1) the waiting queue is empty, (2) at least
         ``adaptive_decode_min_running`` sequences run (closed-loop traffic:
         a full running set means no client has a request left to send),
         (3) no arrival for ``adaptive_decode_quiet_s``. While arrivals
-        flow, every gate-dependent optimization stays off and each arrival
-        sees a fresh scheduling decision."""
+        flow, bursts stay at the configured depth: an arrival waits for
+        the one burst in flight, and a deep one would make it wait
+        ``adaptive_decode_steps`` steps. (The chained pipeline at the
+        configured depth does not ask: `_pipeline_ok`.)"""
         if self.scheduler.num_waiting:
             return False
         if self.scheduler.num_running < self.cfg.adaptive_decode_min_running:
@@ -561,14 +575,14 @@ class LLMEngine:
         if self.runner.burst_in_flight:
             locked = frozenset(s.request_id for s in self._burst_seqs)
             sched = self._schedule(hint, outputs, locked)
-            if self._can_continue_burst(sched):
-                self.pipelined_bursts_total += 1
-                if self._burst_n > self.cfg.num_decode_steps:
-                    self.adaptive_deep_bursts_total += 1
+            why = self._chain_break_reason(sched)
+            if why is None:
+                self._count_decode(self._burst_n, chained=True)
                 rows = self.runner.burst_continue(self._burst_seqs)
                 with phase("postprocess", "decode"):
                     outputs += self._process_burst_rows(rows)
                 return outputs
+            self.pipeline_breaks[why] += 1
             # A new arrival's prefill can slip in BEHIND the in-flight
             # burst: dispatch it first (the device serializes the two), then
             # drain the burst while the prefill executes — one combined wait
@@ -621,17 +635,10 @@ class LLMEngine:
             # on the NEXT step, overlapped with the following burst.
             self._burst_seqs = list(sched.decodes)
             self._burst_n = sched.n_decode_steps
-            self.pipelined_bursts_total += 1
-            if sched.n_decode_steps > self.cfg.num_decode_steps:
-                self.adaptive_deep_bursts_total += 1
+            self._count_decode(sched.n_decode_steps, chained=True)
             self.runner.burst_start(sched.decodes, sched.n_decode_steps)
         else:
-            if (
-                hint is not None
-                and sched.decodes
-                and sched.n_decode_steps > self.cfg.num_decode_steps
-            ):
-                self.adaptive_deep_bursts_total += 1
+            self._count_decode(sched.n_decode_steps, chained=False)
             bursts = self.runner.execute_decode_multi(
                 sched.decodes, sched.n_decode_steps
             )
@@ -838,43 +845,82 @@ class LLMEngine:
     # -- pipelined decode internals ------------------------------------
 
     def _pipeline_ok(self, sched) -> bool:
-        """May this pass start a pipelined burst? ``overlap_decode`` — the
-        default — engages only when the three arrival-safety rules certify
-        that no arrival can be delayed (`_arrival_safe`), so live-traffic
-        TTFT never pays for the overlap. Guided rows are excluded (their
-        allowed-token mask is rebuilt per token host-side); penalty rows
-        ride — their state lives in multi_step's scan carry."""
-        if not sched.decodes:
-            return False
-        if any(s.sampling.guided_choice for s in sched.decodes):
+        """May this pass start a chain? Whenever its decode batch can be
+        chained: ``overlap_decode`` on (the default), no n-gram speculation
+        configured, every row chainable and no queue left standing
+        (`_queue_stands`). It does not ask whether requests are arriving: an
+        arrival waits for the one burst in flight, as it waits for the
+        running step in the synchronous loop, and its prefill is launched
+        behind that burst (`_step_impl`). Only the deepening past
+        ``num_decode_steps`` waits for quiet (`_decode_depth_hint`)."""
+        if not sched.decodes or not self.cfg.overlap_decode:
             return False
         # Speculation and overlap are alternative round-trip amortizers;
         # when n-gram speculation is configured it wins outright (more
         # tokens per trip for greedy rows) and overlap stays out of its
-        # way — deterministically, not by racing the quiet timer.
+        # way.
         if self.cfg.speculative_ngram:
             return False
-        return self.cfg.overlap_decode and self._arrival_safe()
-
-    def _can_continue_burst(self, sched) -> bool:
-        """The in-flight burst may chain iff nothing about the step shape
-        changed and the NEXT burst's writes are provably covered."""
-        alive = [s for s in self._burst_seqs if not s.is_finished]
-        n = self._burst_n
         return (
-            not sched.prefills
-            and not sched.blocked_on_locked
-            and self.scheduler.num_waiting == 0  # drain so admission can run
-            and alive
-            and sched.decodes == alive
-            and sched.n_decode_steps == n
-            and self.runner.burst_width_stable(self._burst_seqs)
-            # The continuation writes up to num_tokens + 2n (host view lags
-            # one burst); past max_model_len its pages would not exist.
-            and all(
-                s.num_tokens + 2 * n <= self.cfg.max_model_len for s in alive
-            )
+            self._chainable(sched.decodes, sched.n_decode_steps)
+            and not self._queue_stands()
         )
+
+    def _chainable(self, decodes, n: int) -> bool:
+        """Can every row ride a chain of depth ``n``? Not a guided row (its
+        allowed-token mask is rebuilt per token host-side; penalty rows
+        ride, their state lives in the scan carry), not a row within two
+        bursts of ``max_model_len`` (a continuation writes up to
+        num_tokens + 2n, the host's view lagging one burst; past the limit
+        its pages would not exist), not one whose deadline has passed (the
+        scheduler sheds only what no burst in flight writes through)."""
+        now = time.monotonic()
+        return not any(
+            s.sampling.guided_choice
+            or s.num_tokens + 2 * n > self.cfg.max_model_len
+            or s.deadline_expired(now)
+            for s in decodes
+        )
+
+    def _queue_stands(self) -> bool:
+        """Did the pass just made leave requests waiting or parked (every
+        row taken, no pages)? What the scheduler does for a standing queue
+        needs rows no burst writes through: timeslicing rotates only
+        unlocked rows, batch-tier preemption and deadline sheds skip locked
+        ones, and members that finished mid-chain give their pages back at
+        the drain. So the loop stays synchronous while a queue stands, as
+        it was before a chain could start under arrivals; a request the
+        pass admitted is a prefill, not a queue."""
+        return bool(self.scheduler.num_waiting or self.scheduler.num_swapped)
+
+    def _chain_break_reason(self, sched) -> Optional[str]:
+        """None while the burst in flight may chain: nothing about the step
+        shape changed and the NEXT burst's writes are provably covered.
+        Else why not, by what the pass made under the chain's locks
+        produced (the label of ``pst:pipeline_breaks_total``)."""
+        alive = [s for s in self._burst_seqs if not s.is_finished]
+        if sched.prefills:
+            return "prefill"
+        if sched.blocked_on_locked:
+            return "blocked_on_locked"
+        if not alive or sched.decodes != alive:
+            return "decode_set"
+        if sched.n_decode_steps != self._burst_n:
+            return "depth"
+        if not self.runner.burst_width_stable(self._burst_seqs):
+            return "table_width"
+        if self._queue_stands():
+            return "queue"
+        if not self._chainable(alive, self._burst_n):
+            return "not_eligible"
+        return None
+
+    def _count_decode(self, n_steps: int, chained: bool) -> None:
+        self.decode_dispatches_total += 1
+        if chained:
+            self.pipelined_bursts_total += 1
+        if n_steps > self.cfg.num_decode_steps:
+            self.adaptive_deep_bursts_total += 1
 
     def _process_burst_rows(self, rows) -> List[RequestOutput]:
         """Apply one fetched burst's tokens. Rows align with
@@ -1143,8 +1189,10 @@ class LLMEngine:
             out["adaptive_deep_bursts_total"] = float(
                 self.adaptive_deep_bursts_total
             )
+        out["decode_dispatches_total"] = float(self.decode_dispatches_total)
         if self.cfg.overlap_decode:
             out["pipelined_bursts_total"] = float(self.pipelined_bursts_total)
+            out["pipeline_breaks_total"] = dict(self.pipeline_breaks)
         # Tiering KPIs (present when the LMCache-analogue layer is on).
         for attr in ("host_hit_blocks", "remote_hit_blocks", "spilled_blocks"):
             if hasattr(self.allocator, attr):

@@ -153,10 +153,11 @@ def encode_buckets(cfg: EngineConfig) -> List[int]:
 def burst_depths(cfg: EngineConfig) -> List[int]:
     """Burst depths the engine dispatches at steady state: the configured
     depth and the adaptive deep depth — plus, when the pipeline is on
-    (the default arrival-gated ``overlap_decode``), the configured depth
-    even at 1: the pipeline runs the multi-step
+    (``overlap_decode``, the default: every chainable decode batch), the
+    configured depth even at 1: the pipeline runs the multi-step
     executable (``b{B}xn{n}``) at whatever depth the scheduler emits, so
-    a depth-1 engine overlaps through ``b{B}xn1`` shapes. (The
+    a depth-1 engine decodes through ``b{B}xn1`` shapes, whose program
+    is ``jit_pst_decode_step_chained`` (`ModelRunner._burst_fn`). (The
     per-sequence clamp near max_model_len can shrink n through arbitrary
     values on the last few tokens of a context-limit sequence — that long
     tail is deliberately NOT enumerated; it is one compile per engine

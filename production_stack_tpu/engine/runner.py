@@ -417,9 +417,21 @@ class ModelRunner:
                 pen_counts, kv_cache,
             )
 
-        # pstlint: jit-family=decode_burst
-        self._multi_step = jax.jit(
-            pst_decode_burst,
+        # One body, jitted under two names, as the step is above: a chained
+        # step of depth 1 is a decode step, and the device trace finds it
+        # with the synchronous one under ``jit_pst_decode_step``; deeper
+        # bursts are ``jit_pst_decode_burst``. ``_burst_fn`` picks by depth
+        # for live traffic, the warm-up and the multi-host follower alike.
+        def pst_decode_step_chained(params, kv_cache, batch, tokens,
+                                    positions, seed_off, pen_counts,
+                                    n_steps: int, want_lp: bool,
+                                    greedy: bool, with_pen: bool):
+            return pst_decode_burst(
+                params, kv_cache, batch, tokens, positions, seed_off,
+                pen_counts, n_steps, want_lp, greedy, with_pen,
+            )
+
+        burst_jit = dict(
             static_argnums=(7, 8, 9, 10),
             donate_argnums=(1,),
             out_shardings=(
@@ -427,6 +439,10 @@ class ModelRunner:
                 cache_sh,
             ),
         )
+        # pstlint: jit-family=decode_burst
+        self._multi_step = jax.jit(pst_decode_burst, **burst_jit)
+        # pstlint: jit-family=decode_burst
+        self._chained_step = jax.jit(pst_decode_step_chained, **burst_jit)
         # Pipelined-burst state: device handles of the burst in flight.
         self._burst = None
         # Per-request cost attribution (docs/observability.md "Cost
@@ -1033,6 +1049,10 @@ class ModelRunner:
         row_shard = self._dp > 1 and B % self._dp == 0
         return jax.device_put(batch, self._row if row_shard else self._repl)
 
+    def _burst_fn(self, n_steps: int):
+        """The jitted program of a ``b{B}xn{n_steps}`` dispatch."""
+        return self._chained_step if n_steps == 1 else self._multi_step
+
     def _dispatch_multi_step(
         self,
         batch: Dict[str, np.ndarray],
@@ -1048,7 +1068,7 @@ class ModelRunner:
             tokens = dev.pop("tokens")
             positions = dev.pop("positions")
             with_pen = "penalty_seen" in batch
-            toks, _, _, _, _, self.kv_cache = self._multi_step(
+            toks, _, _, _, _, self.kv_cache = self._burst_fn(n_steps)(
                 self.params, self.kv_cache, dev, tokens, positions, seed0,
                 cdev, n_steps, want_lp, greedy, with_pen,
             )
@@ -1121,7 +1141,7 @@ class ModelRunner:
             positions = dev.pop("positions")
             with_pen = "penalty_seen" in batch
             toks, tokens, positions, seed, cdev, self.kv_cache = (
-                self._multi_step(
+                self._burst_fn(n_steps)(
                     self.params, self.kv_cache, dev, tokens, positions, seed,
                     cdev, n_steps, want_lp, greedy, with_pen,
                 )
@@ -1163,11 +1183,14 @@ class ModelRunner:
                 kv_lens[i] = 0 if s.is_finished else max(s.num_tokens, 1)
             alive = sum(1 for s in members if not s.is_finished)
             if tel is not None:
-                # The host's view lags the device by the burst in flight,
-                # so kv_tokens is low by up to 2n a row here.
+                # The host's view lags the device by the burst in flight:
+                # a live row holds 2n - 1 more tokens after this burst
+                # than its kv_len here says.
+                n = tel[3]
                 self._step_info(
-                    "decode", tel[1], members, {"kv_lens": kv_lens},
-                    alive * tel[3],
+                    "decode", tel[1], members,
+                    {"kv_lens": np.where(kv_lens > 0, kv_lens + 2 * n - 1, 0)},
+                    alive * n,
                 )
         t0 = time.perf_counter()
         with self._device_lock:
@@ -1208,7 +1231,7 @@ class ModelRunner:
                 self._put_batch({"block_tables": tables, "kv_lens": kv_lens})
             )
             toks, tokens, positions, seed, counts, self.kv_cache = (
-                self._multi_step(
+                self._burst_fn(st["n"])(
                     self.params, self.kv_cache, st["batch"], st["tokens"],
                     st["positions"], st["seed"], st["counts"], st["n"],
                     st["want_lp"], st.get("greedy", False),

@@ -116,8 +116,14 @@ class EngineMetrics:
             g = Gauge(name, doc, ["model_name"], registry=self.registry)
             return g.labels(**label)
 
-        def counter(name, doc):
-            c = Counter(name, doc, ["model_name"], registry=self.registry)
+        def counter(name, doc, by=()):
+            """The model's child; with further labels ``by``, a function
+            from their values to the child."""
+            c = Counter(
+                name, doc, ["model_name", *by], registry=self.registry
+            )
+            if by:
+                return lambda *values: c.labels(*label.values(), *values)
             return c.labels(**label)
 
         def hist(name, doc, buckets):
@@ -184,6 +190,17 @@ class EngineMetrics:
             "pst:pipelined_bursts",
             "decode bursts dispatched as part of an overlapped pipeline "
             "(one burst in flight, host bookkeeping off the critical path)",
+        )
+        self.decode_dispatches = counter(
+            "pst:decode_dispatches",
+            "decode bursts dispatched, synchronous or pipelined: the "
+            "denominator of pst:pipelined_bursts",
+        )
+        self.pipeline_breaks = counter(
+            "pst:pipeline_breaks",
+            "decode pipelines drained, by why the burst in flight could "
+            "not chain",
+            by=("reason",),
         )
         # Deadline shedding by stage (docs/resilience.md): admission counts
         # at the HTTP layer; queued/running refresh from scheduler stats.
@@ -316,6 +333,14 @@ class EngineMetrics:
             self.pipelined_bursts, "pipelined",
             stats.get("pipelined_bursts_total", 0),
         )
+        self._counter_to(
+            self.decode_dispatches, "decode_dispatches",
+            stats.get("decode_dispatches_total", 0),
+        )
+        for why, total in stats.get("pipeline_breaks_total", {}).items():
+            self._counter_to(
+                self.pipeline_breaks(why), f"pipeline_breaks:{why}", total
+            )
         self._counter_to(
             self.deadline_shed_queued, "dl_queued",
             stats.get("deadline_sheds_queued_total", 0),
@@ -1717,15 +1742,15 @@ def parse_engine_args(argv=None) -> argparse.Namespace:
     p.add_argument("--min-decode-bucket", type=int, default=1)
     # Overlapped decode pipeline (docs/engine.md "Overlapped decode
     # pipeline"): burst N+1 dispatches as soon as burst N's tokens are
-    # fetched, N's host bookkeeping overlaps N+1's execution; engages only
-    # under the adaptive-deepening arrival-safety gates so TTFT is
-    # unaffected.
+    # fetched, N's host bookkeeping overlaps N+1's execution; engages
+    # whenever the decode batch can be chained, and an arrival waits for
+    # the one burst in flight.
     p.add_argument("--overlap-decode", dest="overlap_decode",
                    action="store_true", default=True)
     p.add_argument("--no-overlap-decode", dest="overlap_decode",
                    action="store_false",
-                   help="disable the arrival-gated overlapped decode "
-                        "pipeline (synchronous hot loop)")
+                   help="disable the overlapped decode pipeline "
+                        "(synchronous hot loop)")
     # Speculative decoding (n-gram prompt lookup; 0 = off).
     p.add_argument("--speculative-ngram", type=int, default=0,
                    help="max draft tokens per step via n-gram prompt lookup")

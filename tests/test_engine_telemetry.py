@@ -115,11 +115,10 @@ def _tiny_cfg(**over):
     kw = dict(
         model="tiny-llama-debug", max_model_len=256, block_size=8,
         num_kv_blocks=256, max_num_seqs=8, max_prefill_tokens=64,
-        # These tests count compiles against exact expectations: keep the
-        # arrival-gated overlap pipeline off so a slow CI machine crossing
-        # the quiet window mid-test cannot add the (legitimate) pipelined
-        # multi-step executable to the count. Overlap's own compile story
-        # is covered by the lattice tests in test_precompile.py.
+        # These tests count the synchronous loop's compiles against exact
+        # expectations (the pipeline decodes through its own multi-step
+        # executables). Overlap's own compile story is covered by the
+        # lattice tests in test_precompile.py.
         overlap_decode=False,
     )
     kw.update(over)

@@ -1,13 +1,20 @@
 """Overlapped decode pipeline (docs/engine.md "Overlapped decode pipeline").
 
-The arrival-gated two-stage pipeline: burst N+1 dispatches as soon as
-burst N's tokens are fetched, and burst N's host bookkeeping runs while
-N+1 executes. These tests pin the user-visible contract:
+The chained two-stage pipeline: burst N+1 dispatches as soon as burst N's
+tokens are fetched, and burst N's host bookkeeping runs while N+1
+executes. A chain starts whenever the decode batch can be chained, whether
+or not requests are arriving. These tests pin the user-visible contract:
 
-- the pipeline engages only when the three arrival-safety gates pass, and
-  its outputs (token ids, text deltas, emission order, finish reasons)
-  are IDENTICAL to the unpipelined loop — at most one burst of overshoot,
-  trimmed before emission, never streamed;
+- the pipeline engages at the default configuration under a live arrival
+  stream, and its outputs (token ids, text deltas, emission order, finish
+  reasons) are IDENTICAL to the unpipelined loop — at most one burst of
+  overshoot, trimmed before emission, never streamed;
+- an arrival waits for the one burst in flight and no more; a standing
+  queue keeps the loop synchronous; only the deepening past
+  ``num_decode_steps`` waits for quiet;
+- every decode dispatch is counted, chained or not, and every drained
+  chain by its reason; a chained step of depth 1 is a
+  ``jit_pst_decode_step`` program;
 - stop strings and max_tokens are honored exactly; aborts mid-overlap
   cancel cleanly (no leaked pages);
 - penalty/repetition rows are burst-eligible (multi_step's scan carry —
@@ -18,11 +25,15 @@ N+1 executes. These tests pin the user-visible contract:
 """
 
 import os
+import re
+import time
 
+import jax
 import numpy as np
+import pytest
 
 from production_stack_tpu.engine.config import EngineConfig
-from production_stack_tpu.engine.engine import LLMEngine
+from production_stack_tpu.engine.engine import CHAIN_BREAK_REASONS, LLMEngine
 from production_stack_tpu.engine.sequence import SamplingParams
 from production_stack_tpu.obs import ENGINE_TELEMETRY, ENGINE_TELEMETRY_REGISTRY
 
@@ -45,15 +56,9 @@ def _engine(**over):
 
 
 def _overlap_engine(**over):
-    """Overlap with the arrival gates held open (quiet_s=0, no running
-    floor) so the pipeline engages deterministically on CPU."""
-    kw = dict(
-        overlap_decode=True,
-        adaptive_decode_quiet_s=0.0,
-        adaptive_decode_min_running=0,
-    )
-    kw.update(over)
-    return _engine(**kw)
+    """The pipeline as it is configured by default: ``overlap_decode`` and
+    the three ``adaptive_decode_*`` fields are left to ``EngineConfig``."""
+    return _engine(**over, overlap_decode=EngineConfig.overlap_decode)
 
 
 def _run_stream(engine, requests):
@@ -120,12 +125,246 @@ def test_overlap_engages_and_streams_identically():
         assert all(not e[2] for e in got_events[rid][:-1])
 
 
-def test_overlap_respects_arrival_gates():
-    """A closed gate (live arrival stream / waiting work) must keep the
-    pipeline off: with quiet_s large, overlap never engages."""
-    eng = _overlap_engine(adaptive_decode_quiet_s=3600.0)
-    _run_stream(eng, _reqs((17, 9), (8, 8)))
-    assert eng.pipelined_bursts_total == 0
+def _sp(max_tokens, **kw):
+    return SamplingParams(max_tokens=max_tokens, temperature=0.0,
+                          ignore_eos=True, **kw)
+
+
+def _run_with_arrivals(engine, first, later, every=3):
+    """Drive ``first`` to completion while one request of ``later``
+    arrives every ``every`` engine steps. Returns (token ids by request,
+    engine steps taken)."""
+    for rid, prompt, sp in first:
+        engine.add_request(rid, prompt_token_ids=prompt, sampling=sp)
+    later = list(later)
+    toks = {rid: [] for rid, _, _ in list(first) + later}
+    steps = 0
+    while engine.has_work() or later:
+        for out in engine.step():
+            toks[out.request_id].extend(out.new_token_ids)
+        steps += 1
+        if later and steps % every == 0:
+            rid, prompt, sp = later.pop(0)
+            engine.add_request(rid, prompt_token_ids=prompt, sampling=sp)
+        assert steps < 1000
+    return toks, steps
+
+
+def _arrival_stream():
+    rng = np.random.default_rng(23)
+    mk = lambda rid, n, mt: (  # noqa: E731
+        rid, rng.integers(1, 500, size=n).tolist(), _sp(mt))
+    first = [mk("a0", 17, 40), mk("a1", 9, 40)]
+    later = [mk(f"b{i}", 11 + i, 12) for i in range(5)]
+    return first, later
+
+
+def test_chain_engages_at_default_config_while_requests_arrive():
+    """No ``adaptive_decode_quiet_s`` override, and the quiet gate held
+    shut as a stream of arrivals holds it on a busy server (whatever this
+    machine's clock makes of 0.5 s): most decode dispatches are chained
+    all the same, token for token what the synchronous loop gives."""
+    ref, _ = _run_with_arrivals(_engine(), *_arrival_stream())
+    eng = _overlap_engine()
+    assert eng.cfg.overlap_decode and eng.cfg.adaptive_decode_quiet_s == 0.5
+    eng._arrival_safe = lambda: False
+    got, _ = _run_with_arrivals(eng, *_arrival_stream())
+    assert got == ref
+    assert eng.pipeline_breaks["prefill"] >= 3  # arrivals landed mid-chain
+    assert eng.pipelined_bursts_total * 2 > eng.decode_dispatches_total
+
+
+def test_arrival_mid_chain_prefills_behind_the_burst_in_flight():
+    """An arrival's prefill is launched behind the burst in flight, before
+    that burst is drained, and its first token comes no later than one
+    burst after the synchronous loop gives it."""
+    rng = np.random.default_rng(4)
+    p0 = rng.integers(1, 500, 21).tolist()
+    p1 = rng.integers(1, 500, 15).tolist()
+
+    def run(eng, after=8):
+        """r1 arrives once r0 has ``after`` tokens out: how many r0 had
+        when r1's first token came, and whether its prefill was launched
+        with a burst in flight."""
+        behind = []
+        dispatch = eng.runner.prefill_dispatch
+        eng.runner.prefill_dispatch = lambda items: (
+            behind.append(eng.runner.burst_in_flight), dispatch(items))[1]
+        eng.add_request("r0", prompt_token_ids=p0, sampling=_sp(40))
+        n0, sent = 0, False
+        while eng.has_work():
+            for out in eng.step():
+                if out.request_id == "r1":
+                    return n0, behind
+                n0 += len(out.new_token_ids)
+            if not sent and n0 >= after:
+                eng.add_request("r1", prompt_token_ids=p1, sampling=_sp(6))
+                sent = True
+        raise AssertionError("r1 never answered")
+
+    sync_n0, _ = run(_engine())
+    eng = _overlap_engine()
+    n0, behind = run(eng)
+    assert behind == [True], "the prefill must not wait for a drain"
+    assert n0 <= sync_n0 + eng.cfg.num_decode_steps
+
+
+@pytest.mark.parametrize("swap", [True, False])
+def test_standing_queue_costs_no_more_steps_than_the_synchronous_loop(swap):
+    """More requests than ``max_num_seqs``: while the queue stands the
+    loop is the synchronous one (no start/drain pairs, two engine steps a
+    token), and the chain takes over once it is gone."""
+    reqs = lambda: _reqs((17, 33, 9, 25, 13, 21), (14, 20, 9, 16, 11, 18))  # noqa: E731
+    steps = {}
+    for name, overlap in (("sync", False),
+                          ("chain", EngineConfig.overlap_decode)):
+        eng = _engine(max_num_seqs=2, kv_swap=swap, overlap_decode=overlap)
+        for rid, prompt, sp in reqs():
+            eng.add_request(rid, prompt_token_ids=prompt, sampling=sp)
+        n, toks, chained_under_queue = 0, {}, 0
+        while eng.has_work():
+            queued = eng.scheduler.num_waiting
+            before = eng.pipelined_bursts_total
+            for out in eng.step():
+                toks.setdefault(out.request_id, []).extend(out.new_token_ids)
+            if queued and eng.scheduler.num_waiting:
+                chained_under_queue += eng.pipelined_bursts_total - before
+            n += 1
+            assert n < 1000
+        steps[name] = (n, toks)
+        assert chained_under_queue == 0
+    assert steps["chain"][1] == steps["sync"][1]
+    assert eng.pipelined_bursts_total > 0  # the last two run with no queue
+    # that one chain costs its start, which returns no token, and the
+    # drain of the burst launched before its last member was seen to finish
+    assert sum(eng.pipeline_breaks.values()) == 1
+    assert steps["chain"][0] <= steps["sync"][0] + 2
+
+
+@pytest.mark.parametrize("quiet_s, deep", [(3600.0, False), (0.0, True)])
+def test_deep_bursts_still_wait_for_quiet(quiet_s, deep):
+    """``adaptive_decode_steps`` deepens only once no request has arrived
+    for ``adaptive_decode_quiet_s``; the chain at the configured depth
+    runs either way."""
+    eng = _overlap_engine(num_decode_steps=1, adaptive_decode_steps=4,
+                          adaptive_decode_quiet_s=quiet_s)
+    _, toks = _run_stream(eng, _reqs((17, 9), (21, 21)))
+    assert [len(t) for t in toks.values()] == [21, 21]
+    assert eng.pipelined_bursts_total > 0
+    assert (eng.adaptive_deep_bursts_total > 0) == deep
+
+
+def test_decode_counters_add_up():
+    """Chained and synchronous dispatches are all the decode dispatches,
+    every chain that started was drained, and every drain has a reason."""
+    eng = _overlap_engine(num_decode_steps=1)
+    calls = {"burst_start": 0, "burst_continue": 0, "burst_drain": 0,
+             "execute_decode_multi": 0}
+
+    def counted(name):
+        fn = getattr(eng.runner, name)
+
+        def wrapper(*a, **kw):
+            calls[name] += 1
+            return fn(*a, **kw)
+        setattr(eng.runner, name, wrapper)
+
+    for name in calls:
+        counted(name)
+    first, later = _arrival_stream()
+    # a guided row keeps its batches synchronous
+    later.insert(2, ("g", [3, 4, 5], SamplingParams(
+        max_tokens=6, temperature=0.0, guided_choice=((5, 9), (5, 12, 13)))))
+    _run_with_arrivals(eng, first, later)
+    chained = calls["burst_start"] + calls["burst_continue"]
+    assert chained == eng.pipelined_bursts_total > 0
+    assert calls["execute_decode_multi"] > 0
+    assert (chained + calls["execute_decode_multi"]
+            == eng.decode_dispatches_total)
+    assert set(eng.pipeline_breaks) == set(CHAIN_BREAK_REASONS)
+    assert (calls["burst_start"] == calls["burst_drain"]
+            == sum(eng.pipeline_breaks.values()))
+    assert eng.pipeline_breaks["prefill"] > 0
+    assert eng.pipeline_breaks["decode_set"] > 0  # the last member finished
+    stats = eng.stats()
+    assert stats["decode_dispatches_total"] == eng.decode_dispatches_total
+    assert stats["pipeline_breaks_total"] == eng.pipeline_breaks
+
+
+def test_decode_counters_are_exported():
+    from prometheus_client import generate_latest
+
+    from production_stack_tpu.engine.server import EngineMetrics
+
+    eng = _overlap_engine()
+    _run_with_arrivals(eng, *_arrival_stream())
+    metrics = EngineMetrics("m")
+    metrics.refresh(eng.stats())
+    text = generate_latest(metrics.registry).decode()
+
+    def value(series):
+        return float(re.search(
+            re.escape(series) + r" (\S+)", text).group(1))
+
+    assert (value('pst:decode_dispatches_total{model_name="m"}')
+            == eng.decode_dispatches_total)
+    assert (value('pst:pipelined_bursts_total{model_name="m"}')
+            == eng.pipelined_bursts_total)
+    for why, n in eng.pipeline_breaks.items():
+        assert value('pst:pipeline_breaks_total{model_name="m",reason="%s"}'
+                     % why) == n
+
+
+@pytest.mark.parametrize("depth, name", [
+    (1, "jit_pst_decode_step"), (4, "jit_pst_decode_burst")])
+def test_chained_program_is_named_by_its_depth(depth, name):
+    """The device trace knows a program by its module name: a chained
+    step of depth 1 is found with the synchronous decode step, a deeper
+    burst is a burst. Live traffic and the warm-up's ``b{B}xn{depth}``
+    bucket reach the same jitted object."""
+    from production_stack_tpu.engine.precompile import (
+        Bucket, table_width_buckets)
+
+    eng = _overlap_engine(num_decode_steps=depth)
+    jitted = eng.runner._burst_fn(depth)
+    lowered = []
+
+    def spy(*args):
+        shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+            if hasattr(x, "shape") else x, args)
+        lowered.append(jitted.lower(*shapes).as_text())
+        return jitted(*args)
+
+    eng.runner._burst_fn = lambda n: spy if n == depth else None
+    _run_stream(eng, _reqs((9,), (3 * depth,)))
+    assert eng.pipelined_bursts_total > 0 and lowered
+    modules = {re.search(r"module @(\w+)", text).group(1) for text in lowered}
+    assert len(modules) == 1 and modules.pop().startswith(name)
+    del eng.runner._burst_fn  # the class's own again
+    before = jitted._cache_size()
+    eng.runner.warmup_bucket(Bucket(
+        "decode_burst", rows=1, width=table_width_buckets(eng.cfg)[0],
+        n_steps=depth, greedy=True))
+    assert jitted._cache_size() == before, "the warm-up compiled another"
+
+
+def test_chain_lets_go_of_a_row_whose_deadline_passed():
+    """The scheduler sheds only rows that no burst in flight writes
+    through, so a chain must not hold a row past its deadline."""
+    eng = _overlap_engine(num_decode_steps=1, deadline_shedding=True)
+    eng.add_request("d", prompt_token_ids=list(range(5, 20)),
+                    sampling=_sp(200), deadline=time.monotonic() + 3600)
+    seq = eng._seqs["d"]
+    outs = []
+    while eng.has_work() and len(outs) < 200:
+        outs += eng.step()
+        if len(seq.output_token_ids) == 5:
+            seq.deadline = time.monotonic() - 1.0
+    assert outs[-1].finish_reason == "deadline"
+    assert len(seq.output_token_ids) <= 5 + 2  # the burst in flight, no more
+    assert eng.pipeline_breaks["not_eligible"] == 1
+    assert eng.allocator.num_free == eng.allocator.num_blocks
 
 
 def test_overlap_max_tokens_exact_with_overshoot_trimmed():
