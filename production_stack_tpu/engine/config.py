@@ -218,6 +218,57 @@ class EngineConfig:
     cost_attribution: bool = True
 
 
+def state_slot_count(cfg: EngineConfig) -> int:
+    """Recurrent-state slots a model with state-space layers is given: one a
+    running sequence and a few spare, since a burst's finished members hold
+    theirs until the drain. (The pool has one more, the scratch slot.)"""
+    return cfg.max_num_seqs + max(2, cfg.max_num_seqs // 8)
+
+
+def refuse_for_recurrent(cfg: EngineConfig) -> None:
+    """What a model with recurrent (state-space) layers cannot be served
+    with, refused at start-up by the flag's name. A sequence's state is one
+    slot, not a list of pages: a cached prefix, a swapped or tiered page and
+    a handed-off page list all lack the state that belongs to them, a
+    rejected draft would need the state rolled back, and neither adapters,
+    quantised leaves nor a mesh are built for these layers."""
+    refused = [
+        (cfg.enable_prefix_caching, "--enable-prefix-caching",
+         "a cached page list carries no state snapshot; pass "
+         "--no-enable-prefix-caching"),
+        (cfg.kv_swap, "--kv-swap",
+         "a parked sequence's state is not swapped; pass --no-kv-swap "
+         "(preemption is by recompute)"),
+        (cfg.cpu_offload_blocks > 0, "--cpu-offload-blocks",
+         "host-tier pages carry no state"),
+        (bool(cfg.remote_kv_url), "--remote-kv-url",
+         "remote-tier pages carry no state"),
+        (cfg.kv_role != "none", "--kv-role",
+         "a KV hand-off ships pages, not the state"),
+        (cfg.speculative_ngram > 0, "--speculative-ngram",
+         "a rejected draft would need the state rolled back"),
+        (cfg.enable_lora, "--enable-lora",
+         "no adapter bank exists for these layers"),
+        (cfg.tensor_parallel_size > 1, "--tensor-parallel-size",
+         "the state pools and kernels run on one device"),
+        (cfg.pipeline_parallel_size > 1, "--pipeline-parallel-size",
+         "the layer pattern is not staged"),
+        (cfg.expert_parallel_size > 1, "--expert-parallel-size",
+         "an expert-parallel share is told by the model config's "
+         "ep_share, not by a mesh"),
+        (cfg.data_parallel_size > 1, "--data-parallel-size",
+         "the state pools and kernels run on one device"),
+        (bool(cfg.quantization), "--quantization",
+         "no quantised leaves exist for these layers"),
+    ]
+    for on, flag, why in refused:
+        if on:
+            raise ValueError(
+                f"{flag} is not served for model {cfg.model!r}, which has "
+                f"recurrent (state-space) layers: {why}"
+            )
+
+
 def resolve_num_kv_blocks(
     cfg: EngineConfig, model_cfg: LlamaConfig, param_bytes_per_device: int
 ) -> int:
@@ -225,6 +276,9 @@ def resolve_num_kv_blocks(
 
     bytes/page = 2 (K+V) * L * bs * KH * hd * itemsize, divided by tp (kv
     heads sharded over the tensor axis) and pp (layers sharded over stages).
+    ``L`` counts the layers that hold pages (a hybrid model's attention
+    layers alone: ``num_kv_layers``); a model with recurrent layers has its
+    state pools taken off the budget first.
     """
     if cfg.num_kv_blocks is not None:
         return cfg.num_kv_blocks
@@ -233,7 +287,7 @@ def resolve_num_kv_blocks(
     pp = max(cfg.pipeline_parallel_size, 1)
     page_bytes = (
         2
-        * max(model_cfg.num_layers // pp, 1)
+        * max(getattr(model_cfg, "num_kv_layers", model_cfg.num_layers) // pp, 1)
         * cfg.block_size
         * max(model_cfg.num_kv_heads // tp, 1)
         * model_cfg.head_dim
@@ -254,6 +308,8 @@ def resolve_num_kv_blocks(
         if not hbm:
             hbm = require_device_spec(dev.device_kind).hbm_bytes
         budget = int(hbm * cfg.hbm_utilization) - param_bytes_per_device
+        if getattr(model_cfg, "recurrent", False):
+            budget -= model_cfg.state_bytes_per_slot() * (state_slot_count(cfg) + 1)
     n = max(budget // page_bytes, cfg.max_num_seqs * 2)
     # Never fewer pages than one full-length sequence needs.
     n = max(n, -(-cfg.max_model_len // cfg.block_size) + 1)

@@ -124,7 +124,8 @@ class LLMEngine:
             )
         else:
             self.allocator = BlockAllocator(
-                self.runner.num_blocks, cfg.block_size, cfg.enable_prefix_caching
+                self.runner.num_blocks, cfg.block_size, cfg.enable_prefix_caching,
+                state_slots=self.runner.state_slots,
             )
         # Streamed disagg KV handoff (docs/disagg.md): a producer engine
         # ships each prefill chunk's committed pages under the request's
@@ -488,6 +489,7 @@ class LLMEngine:
                 self.runner.num_blocks,
                 self.cfg.block_size,
                 self.cfg.enable_prefix_caching,
+                state_slots=self.runner.state_slots,
             )
         self.scheduler.allocator = self.allocator
         self.resident_chunk_hashes.clear()
@@ -948,8 +950,7 @@ class LLMEngine:
 
     def _release_burst_deferred(self) -> None:
         for seq in self._burst_deferred:
-            self.allocator.release_all(seq.block_ids)
-            seq.block_ids = []
+            self.allocator.release_sequence(seq)
         self._burst_deferred = []
 
     # Controller-registration hygiene: chunk claims older than the TTL (or
@@ -1190,6 +1191,15 @@ class LLMEngine:
                 self.adaptive_deep_bursts_total
             )
         out["decode_dispatches_total"] = float(self.decode_dispatches_total)
+        if self.runner.state_slots:
+            out["state_slots_in_use"] = float(self.allocator.state_slots_in_use)
+            out["state_slot_waits_total"] = float(
+                self.allocator.state_slot_waits
+            )
+        # what the model's steps reported, under the model's own names
+        for name, total in zip(self.runner.aux_names,
+                               self.runner.step_aux_totals):
+            out[name] = float(total)
         if self.cfg.overlap_decode:
             out["pipelined_bursts_total"] = float(self.pipelined_bursts_total)
             out["pipeline_breaks_total"] = dict(self.pipeline_breaks)

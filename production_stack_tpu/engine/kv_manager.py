@@ -38,7 +38,14 @@ class BlockAllocator:
         block_size: int,
         enable_prefix_caching: bool = True,
         on_evict: Optional[Callable[[int, int], None]] = None,
+        state_slots: int = 0,
     ):
+        # Recurrent-state slots (a model with state-space layers): one a
+        # sequence from its first scheduling to the release of its pages,
+        # owned here with them. 0 for a model that keeps pages alone.
+        self.state_slots = state_slots
+        self._free_slots: List[int] = list(range(state_slots - 1, -1, -1))
+        self.state_slot_waits = 0  # admissions that found no slot free
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.enable_prefix_caching = enable_prefix_caching
@@ -141,6 +148,31 @@ class BlockAllocator:
     def release_all(self, blocks: Sequence[int]) -> None:
         for b in blocks:
             self.release(b)
+
+    # -- everything a sequence holds --------------------------------------
+
+    def take_state_slot(self, seq) -> bool:
+        """Give ``seq`` a recurrent-state slot if it needs one and has none.
+        False when every slot is taken (the caller leaves it queued)."""
+        if not self.state_slots or seq.state_slot is not None:
+            return True
+        if not self._free_slots:
+            self.state_slot_waits += 1
+            return False
+        seq.state_slot = self._free_slots.pop()
+        return True
+
+    @property
+    def state_slots_in_use(self) -> int:
+        return self.state_slots - len(self._free_slots)
+
+    def release_sequence(self, seq) -> None:
+        """Give back what ``seq`` holds: its pages and its state slot."""
+        self.release_all(seq.block_ids)
+        seq.block_ids = []
+        if seq.state_slot is not None:
+            self._free_slots.append(seq.state_slot)
+            seq.state_slot = None
 
     # -- prefix lookup ----------------------------------------------------
 

@@ -35,14 +35,13 @@ from ..models.llama import (
     QUANT_LAYER_KEYS,
     QUANT_SUFFIX,
     QUANT_TOP_KEYS,
-    Llama,
     LlamaConfig,
     init_leaf,
     load_hf_params,
     quantize_leaf,
     quantize_leaf_int4,
 )
-from ..models.registry import get_model_config
+from ..models.registry import get_model_config, model_for
 from ..ops.attention import resolve_attn_impl
 from ..ops.sampling import (
     apply_allowed_mask,
@@ -52,7 +51,12 @@ from ..ops.sampling import (
     sample_tokens_packed,
 )
 from ..parallel.mesh import MeshConfig, build_mesh
-from .config import EngineConfig, resolve_num_kv_blocks
+from .config import (
+    EngineConfig,
+    refuse_for_recurrent,
+    resolve_num_kv_blocks,
+    state_slot_count,
+)
 from .scheduler import PrefillItem
 from .sequence import Sequence
 
@@ -115,7 +119,20 @@ class ModelRunner:
         # anything else stops here (device.py).
         self.platform = resolve_platform()
         self.model_cfg = model_cfg or get_model_config(cfg.model)
-        self.model = Llama(self.model_cfg)
+        self.model = model_for(self.model_cfg)
+        # A model with recurrent layers keeps, beside its pages, one state
+        # slot a sequence and a scratch slot for padding rows.
+        self._recurrent = bool(getattr(self.model_cfg, "recurrent", False))
+        self.state_slots = 0
+        if self._recurrent:
+            refuse_for_recurrent(cfg)
+            self.state_slots = state_slot_count(cfg)
+        # Rows a step appends to its packed tokens (the model's step_aux,
+        # one for each name in its AUX_NAMES), summed here as they are
+        # fetched.
+        self.aux_names = tuple(getattr(self.model, "AUX_NAMES", ()))
+        self._aux_rows = len(self.aux_names)
+        self.step_aux_totals = np.zeros(self._aux_rows, np.float64)
         tp = cfg.tensor_parallel_size
         pp = max(cfg.pipeline_parallel_size, 1)
         self._pp = pp
@@ -220,7 +237,7 @@ class ModelRunner:
             cfg, self.model_cfg, param_bytes // (max(tp, 1) * pp)
         )
         self.max_table_width = -(-cfg.max_model_len // cfg.block_size)
-        cache_sh = NamedSharding(self.mesh, Llama.cache_pspec(pipeline=pp > 1))
+        cache_sh = self._cache_sharding()
         self._dispatch_restore_kv()  # single source of truth for allocation
         self._repl = NamedSharding(self.mesh, P())
         # Decode batches shard rows over dp (independent sequences — the
@@ -252,6 +269,32 @@ class ModelRunner:
             moe_impl = "dense" if sharded else "ragged"
         self._moe_impl = moe_impl
 
+        recurrent, scratch_slot = self._recurrent, self.state_slots
+        aux_rows = self._aux_rows
+
+        def slots_of(batch, active=None):
+            """The state slot of each row, for a model that keeps any; a
+            row the caller knows to be padding goes to the scratch slot."""
+            if not recurrent:
+                return {}
+            slots = batch["state_slots"]
+            if active is not None:
+                slots = jnp.where(active, slots, scratch_slot)
+            # token_budget: no step holds more real tokens than a prefill
+            # step's budget, however far its rows are padded.
+            return {"state_slots": slots,
+                    "token_budget": max(cfg.max_prefill_tokens, cfg.max_num_seqs)}
+
+        def with_aux(packed, kv_cache):
+            """What the model's step reports beside its tokens rides the
+            same fetch: one row a number under the packed rows."""
+            if not aux_rows:
+                return packed
+            aux = model.step_aux(kv_cache)
+            return jnp.concatenate([
+                packed, jnp.broadcast_to(aux[:, None], (aux.shape[0], packed.shape[1])),
+            ])
+
         def step(
             params, kv_cache, batch: Dict[str, Any], want_lp: bool,
             greedy: bool,
@@ -271,6 +314,7 @@ class ModelRunner:
                 moe_impl=moe_impl,
                 pp_size=pp,
                 mesh=model_mesh,
+                **slots_of(batch),
             )
             if "penalty_prompt" in batch:
                 logits = apply_penalties(
@@ -302,7 +346,7 @@ class ModelRunner:
                 with_logprobs=want_lp,
                 greedy_only=greedy,
             )
-            return packed, kv_cache
+            return with_aux(packed, kv_cache), kv_cache
 
         # Sampled tokens come back replicated: on a multi-host mesh the
         # primary must be able to device_get them (only addressable shards
@@ -376,6 +420,7 @@ class ModelRunner:
                     moe_impl=moe_impl,
                     pp_size=pp,
                     mesh=model_mesh,
+                    **slots_of(batch, active),
                 )
                 if with_pen:
                     logits = apply_penalties_counts(
@@ -405,7 +450,10 @@ class ModelRunner:
                     counts = counts.at[
                         jnp.arange(counts.shape[0], dtype=jnp.int32), nxt
                     ].add(active.astype(jnp.float32))
-                return (kv_cache, nxt, positions + 1, so + 1, counts), packed
+                return (
+                    (kv_cache, nxt, positions + 1, so + 1, counts),
+                    with_aux(packed, kv_cache),
+                )
 
             carry = (kv_cache, tokens, positions, seed_off, pen_counts)
             (kv_cache, tokens, positions, seed_off, pen_counts), packed = (
@@ -764,7 +812,7 @@ class ModelRunner:
             self._dispatch_drop_kv()
 
     def _dispatch_drop_kv(self) -> None:
-        self.kv_cache.delete()
+        jax.tree.map(lambda a: a.delete(), self.kv_cache)
         self.kv_cache = None
 
     def restore_kv_cache(self) -> None:
@@ -773,8 +821,18 @@ class ModelRunner:
                 self.publisher.announce("restore_kv", None)
             self._dispatch_restore_kv()
 
+    def _cache_sharding(self):
+        """The cache's shardings, in the cache's own tree (one array of
+        pages, or a model's pages and state pools)."""
+        return jax.tree.map(
+            lambda spec: NamedSharding(self.mesh, spec),
+            self.model.cache_pspec(pipeline=self._pp > 1),
+            is_leaf=lambda x: isinstance(x, P),
+        )
+
     def _dispatch_restore_kv(self) -> None:
-        cache_sh = NamedSharding(self.mesh, Llama.cache_pspec(pipeline=self._pp > 1))
+        cache_sh = self._cache_sharding()
+        pools = {"state_slots": self.state_slots} if self._recurrent else {}
         # Allocated under jit so each device zero-fills only its own shard:
         # built eagerly the whole cache would land on device 0 first, and a
         # tp-sharded cache is sized to fill every device.
@@ -783,6 +841,7 @@ class ModelRunner:
             functools.partial(
                 self.model.make_kv_cache,
                 self.num_blocks, self.cfg.block_size, self.cfg.kv_cache_dtype,
+                **pools,
             ),
             out_shardings=cache_sh,
         )()
@@ -870,10 +929,13 @@ class ModelRunner:
         tokens (a burst writes ``kv_ahead`` more per row than its batch
         says), ``kv_pages`` the pages those rows hold."""
         n = len(seqs)
+        # state_slots: rows whose recurrent state the step reads and writes
+        slots = {"state_slots": n} if self._recurrent else {}
         ENGINE_TELEMETRY.step_info(
             kind, bucket=bucket, rows=n, new_tokens=new_tokens,
             kv_tokens=int(batch["kv_lens"][:n].sum()) + n * kv_ahead,
             kv_pages=sum(len(s.block_ids) for s in seqs),
+            **slots,
         )
 
     # -- per-request cost attribution ------------------------------------
@@ -1072,7 +1134,7 @@ class ModelRunner:
                 self.params, self.kv_cache, dev, tokens, positions, seed0,
                 cdev, n_steps, want_lp, greedy, with_pen,
             )
-        return _fetch(toks, "decode")
+        return self._take_aux(_fetch(toks, "decode"))
 
     # ------------------------------------------------------------------
     # Pipelined decode bursts: one burst always in flight; its token fetch
@@ -1244,7 +1306,7 @@ class ModelRunner:
                 tokens=tokens, positions=positions, seed=seed, counts=counts,
                 toks=toks,
             )
-        return _fetch(prev, "decode")
+        return self._take_aux(_fetch(prev, "decode"))
 
     def burst_drain(self) -> np.ndarray:
         """Fetch the in-flight burst's tokens and end the pipeline."""
@@ -1253,7 +1315,7 @@ class ModelRunner:
         # No device op, so no multihost announce: followers hold no pending
         # fetch (they never read tokens) and their next announced dispatch
         # keeps program order identical.
-        rows = _fetch(st["toks"], "decode")
+        rows = self._take_aux(_fetch(st["toks"], "decode"))
         # Drains are transitions (an arrival or shape change broke the
         # pipeline) and a prefill may already be queued behind this fetch —
         # the wall from here to the next decode dispatch is not steady-state
@@ -1405,9 +1467,7 @@ class ModelRunner:
                 # the sampled position-0 token.
                 return jnp.concatenate([ids, sampled0[:, None]], axis=1), kv_cache
 
-            cache_sh = NamedSharding(
-                self.mesh, Llama.cache_pspec(pipeline=pp > 1)
-            )
+            cache_sh = self._cache_sharding()
             # pstlint: jit-family=spec_verify
             self._spec_step = jax.jit(
                 pst_spec_verify,
@@ -1540,7 +1600,7 @@ class ModelRunner:
         return toks
 
     def prefill_fetch(self, handle, n_items: int) -> np.ndarray:
-        return _fetch(handle, "prefill")[:n_items]
+        return self._take_aux(_fetch(handle, "prefill"))[:n_items]
 
     def _run(
         self,
@@ -1568,7 +1628,17 @@ class ModelRunner:
                 self.params, self.kv_cache, self._put_batch(batch),
                 want_lp, greedy,
             )
-        return _fetch(toks, kind)
+        return self._take_aux(_fetch(toks, kind))
+
+    def _take_aux(self, rows: np.ndarray) -> np.ndarray:
+        """Split a fetched step's packed rows from what the model reported
+        under them (``with_aux``), and add that to the running totals."""
+        n = self._aux_rows
+        if not n:
+            return rows
+        self.step_aux_totals += rows[-n:].reshape(n, -1, rows.shape[-1])[
+            :, :, 0].sum(axis=1)
+        return rows[:-n]
 
     # ------------------------------------------------------------------
     # Warmup precompilation (engine/precompile.py drives this)
@@ -1588,6 +1658,7 @@ class ModelRunner:
         if self.cfg.enable_lora:
             out["lora_idx"] = np.zeros(B, np.int32)
             out["lora_scale"] = np.zeros(B, np.float32)
+        out.update(self._slot_rows([], B))
         return out
 
     def warmup_bucket(self, bucket) -> None:
@@ -1738,6 +1809,16 @@ class ModelRunner:
     # Batch construction (host side, numpy)
     # ------------------------------------------------------------------
 
+    def _slot_rows(self, seqs: List[Sequence], B: int) -> Dict[str, np.ndarray]:
+        """``state_slots`` [B] for a model with recurrent layers: each
+        row's slot, padding rows the scratch slot."""
+        if not self._recurrent:
+            return {}
+        slots = np.full(B, self.state_slots, np.int32)
+        for i, s in enumerate(seqs):
+            slots[i] = s.state_slot
+        return {"state_slots": slots}
+
     def _table_row(self, seq: Sequence, width: int) -> np.ndarray:
         row = np.zeros(width, np.int32)
         n = min(len(seq.block_ids), width)
@@ -1798,6 +1879,7 @@ class ModelRunner:
         if not multi:
             batch["write_idx"] = write_idx
             batch["last_idx"] = last_idx
+        batch.update(self._slot_rows(seqs, Bb))
         batch.update(self._sampling_arrays(seqs, Bb))
         return batch
 
@@ -1837,6 +1919,7 @@ class ModelRunner:
             "kv_lens": kv_lens,
             "last_idx": last_idx,
         }
+        batch.update(self._slot_rows([it.seq for it in items], Bb))
         batch.update(self._sampling_arrays([it.seq for it in items], Bb))
         return batch
 

@@ -207,8 +207,7 @@ class Scheduler:
     def _finish(self, seq: Sequence, reason: str) -> None:
         seq.status = SequenceStatus.FINISHED
         seq.finish_reason = reason
-        self.allocator.release_all(seq.block_ids)
-        seq.block_ids = []
+        self.allocator.release_sequence(seq)
         if self.swapper is not None:
             self.swapper.drop(seq.request_id)
 
@@ -541,7 +540,7 @@ class Scheduler:
                 # pages pinned by *waiting* sequences, so holding them here
                 # could wedge admission permanently. Re-matched next attempt.
                 if seq.block_ids:
-                    self.allocator.release_all(seq.block_ids)
+                    self.allocator.release_sequence(seq)
                     seq.reset_for_recompute()
                     seq.status = SequenceStatus.WAITING
                 # Batch-tier preemption (docs/multi-tenancy.md): before
@@ -561,6 +560,15 @@ class Scheduler:
                     self.allocator.num_free,
                     self.config.max_prefill_tokens,
                 )
+                break
+            if not self.allocator.take_state_slot(seq):
+                # Every recurrent-state slot is held (a burst's finished
+                # members keep theirs until the drain): stays queued, and
+                # the standing queue drains the burst.
+                if seq.block_ids:
+                    self.allocator.release_sequence(seq)
+                    seq.reset_for_recompute()
+                    seq.status = SequenceStatus.WAITING
                 break
             del self.waiting[idx]
             self._admit_blocked = None
@@ -645,7 +653,9 @@ class Scheduler:
             self._insert_by_stamp(self.swapped, seq)
             return
         logger.warning("preempting request %s (out of KV pages)", seq.request_id)
-        self.allocator.release_all(seq.block_ids)
+        # Pages and recurrent state both go: the recompute starts the state
+        # from zeros at position 0.
+        self.allocator.release_sequence(seq)
         seq.reset_for_recompute()
         self._insert_by_stamp(self.waiting, seq)
         out.preempted.append(seq)

@@ -150,6 +150,9 @@ class Sequence:
 
         # KV bookkeeping.
         self.block_ids: List[int] = []
+        # Recurrent-state slot (models with state-space layers; the
+        # allocator owns it with the pages): None until first scheduled.
+        self.state_slot: Optional[int] = None
         self.num_computed_tokens = 0  # tokens whose KV is resident
         self.num_cached_prompt_tokens = 0  # prefix-cache hits at admission
         self.block_hashes: List[int] = []  # hash per committed block

@@ -196,6 +196,42 @@ class EngineMetrics:
             "decode bursts dispatched, synchronous or pipelined: the "
             "denominator of pst:pipelined_bursts",
         )
+        # A model with recurrent layers and an expert share (PERF.md §3).
+        self.state_slots_in_use = gauge(
+            "pst:state_slots_in_use",
+            "recurrent-state slots held by sequences (models with "
+            "state-space layers)",
+        )
+        self.state_slot_waits = counter(
+            "pst:state_slot_waits",
+            "admissions left queued because every recurrent-state slot "
+            "was held",
+        )
+        self.moe_pairs_routed = counter(
+            "pst:moe_pairs_routed",
+            "token-expert pairs the router chose, over real tokens and "
+            "expert layers of fetched steps",
+        )
+        self.moe_pairs_held = counter(
+            "pst:moe_pairs_held",
+            "of pst:moe_pairs_routed, the pairs whose expert this engine "
+            "holds (its expert-parallel share)",
+        )
+        self.moe_busiest_expert_pairs = counter(
+            "pst:moe_busiest_expert_pairs",
+            "per step and expert layer, the pairs at the busiest held "
+            "expert, summed",
+        )
+        self.moe_experts_touched = counter(
+            "pst:moe_experts_touched",
+            "per step and expert layer, the held experts that got a pair "
+            "(whose weights the grouped products read), summed",
+        )
+        self.moe_layer_steps = counter(
+            "pst:moe_layer_steps",
+            "expert layers evaluated by fetched steps: the denominator of "
+            "a mean a layer and step over the pst:moe_* counters",
+        )
         self.pipeline_breaks = counter(
             "pst:pipeline_breaks",
             "decode pipelines drained, by why the burst in flight could "
@@ -337,6 +373,16 @@ class EngineMetrics:
             self.decode_dispatches, "decode_dispatches",
             stats.get("decode_dispatches_total", 0),
         )
+        self.state_slots_in_use.set(stats.get("state_slots_in_use", 0))
+        for metric, key in (
+            (self.state_slot_waits, "state_slot_waits_total"),
+            (self.moe_pairs_routed, "moe_pairs_routed_total"),
+            (self.moe_pairs_held, "moe_pairs_held_total"),
+            (self.moe_busiest_expert_pairs, "moe_busiest_expert_pairs_total"),
+            (self.moe_experts_touched, "moe_experts_touched_total"),
+            (self.moe_layer_steps, "moe_layer_steps_total"),
+        ):
+            self._counter_to(metric, key, stats.get(key, 0))
         for why, total in stats.get("pipeline_breaks_total", {}).items():
             self._counter_to(
                 self.pipeline_breaks(why), f"pipeline_breaks:{why}", total

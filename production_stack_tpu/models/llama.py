@@ -1438,17 +1438,23 @@ def load_hf_params(
     return params
 
 
-def config_from_hf_json(config_path: str, name: str = "") -> LlamaConfig:
-    """Build a :class:`LlamaConfig` from an HF ``config.json``."""
+def config_from_hf_json(config_path: str, name: str = ""):
+    """Build the model config of an HF ``config.json``: a
+    :class:`LlamaConfig`, or for ``model_type: nemotron_h`` the hybrid
+    class's own (``models/nemotron_h.py``)."""
     with open(config_path) as f:
         hf = json.load(f)
     mt = hf.get("model_type", "llama")
+    if mt == "nemotron_h":  # a class of its own: state-space + latent MoE
+        from .nemotron_h import config_from_hf
+
+        return config_from_hf(hf, name)
     if mt not in (
         "llama", "mistral", "qwen2", "qwen3", "mixtral", "gemma", "gemma2",
     ):
         raise ValueError(
             f"unsupported model_type {mt!r} "
-            "(llama/mistral/qwen2/qwen3/mixtral/gemma/gemma2)"
+            "(llama/mistral/qwen2/qwen3/mixtral/gemma/gemma2/nemotron_h)"
         )
     eos = hf.get("eos_token_id", 2)
     eos_ids = tuple(eos) if isinstance(eos, list) else (eos,)

@@ -11,7 +11,8 @@ from __future__ import annotations
 import os
 from typing import Dict
 
-from .llama import LlamaConfig, config_from_hf_json
+from .llama import Llama, LlamaConfig, config_from_hf_json
+from .nemotron_h import NemotronH, NemotronHConfig
 
 # Architecture presets. Shapes match the public configs of each family so
 # perf numbers are honest; weights are random-init unless an HF dir is given.
@@ -254,7 +255,45 @@ PRESETS: Dict[str, LlamaConfig] = {
         eos_token_ids=(151645, 151643),
         bos_token_id=None,
     ),
+    # Tiny hybrid (state-space + attention + latent MoE) debug model:
+    # an expert-parallel share of 4 of 16 experts from expert 4 on.
+    "tiny-nemotron-h-debug": NemotronHConfig(
+        vocab_size=128,
+        hidden_size=64,
+        pattern="MEM*E",
+        mamba_num_heads=8,
+        mamba_head_dim=16,
+        n_groups=2,
+        ssm_state_size=16,
+        conv_kernel=4,
+        chunk_size=8,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        n_routed_experts=4,
+        router_experts=16,
+        expert_first=4,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        moe_latent_size=16,
+        moe_shared_expert_intermediate_size=64,
+        routed_scaling_factor=2.5,
+        max_position_embeddings=2048,
+        name="tiny-nemotron-h-debug",
+        eos_token_ids=(0,),
+        bos_token_id=None,
+        dtype="float32",
+    ),
 }
+
+
+def model_for(model_cfg):
+    """The model class a config belongs to, bound to it: what the runner
+    and the benchmark's reference ask for ``init_params``, ``param_pspecs``,
+    the cache constructors and ``forward``."""
+    if isinstance(model_cfg, NemotronHConfig):
+        return NemotronH(model_cfg)
+    return Llama(model_cfg)
 
 
 def get_model_config(model: str) -> LlamaConfig:
