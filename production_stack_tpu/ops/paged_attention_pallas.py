@@ -14,13 +14,23 @@ efficiency, not grid geometry:
   tiling-exact, no sublane padding (a ``[..., KH, hd]`` tail would pad
   KH=8 → 16 sublanes and physically double the cache).
 - The grid is tiny — ``(B,)`` for decode, ``(B, T/Tq)`` for prefill — and
-  each cell walks its sequence's **live** pages with a double-buffered
-  ``fori_loop`` (chunks of ``C`` pages), overlapping the next chunk's DMAs
-  with the current chunk's flash accumulation. Pages past ``kv_len`` — and,
-  for prefill, pages entirely above the tile's causal horizon — are never
-  fetched at all (the round-2 kernel's ``pl.when`` skipped the *compute* but
-  the BlockSpec pipeline still paid the *DMA*; that was the round-2 TTFT
-  regression).
+  each cell walks its sequence's **live** pages in chunks of ``C`` pages
+  through a small ring of buffer slots (``_page_dma_loop``), the next
+  chunks' DMAs in flight while the oldest chunk's flash accumulation
+  runs. A page moves only if it holds a token some query of the cell may
+  see: pages past ``kv_len``, pages below a sliding window, for prefill
+  pages entirely above the tile's causal horizon, and the dead pages of a
+  row's ragged first and last chunk are never fetched at all (the round-2
+  kernel's ``pl.when`` skipped the *compute* but the BlockSpec pipeline
+  still paid the *DMA*; that was the round-2 TTFT regression). The fold
+  masks by column; ``_zero_values`` says why buffer rows no copy of the
+  chunk wrote are harmless.
+- Both grids are sequential, and within a decode call the stream does not
+  stop at a row's end: while a cell folds its row's last chunks, the
+  first chunks of the rows after it are already in flight, so per layer
+  call the DMA engine runs from the first row's first live page to the
+  last row's last with one exposed fetch at the start and one exposed
+  fold at the end.
 - Flash state (m/l/acc) is head-major in VMEM scratch so per-head slices are
   contiguous; grouped-query heads share each page read.
 
@@ -47,6 +57,7 @@ Shapes:
 from __future__ import annotations
 
 import functools
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -90,69 +101,193 @@ def _pv_dot(p, v):
     return main + fix * 0.0625
 
 
+# The decode stream's geometry, measured standalone on a v5e at the two
+# shapes the benchmark runs (``scripts/tpu_decode_attn_attrib.py``; PERF.md
+# §6, PR 32: 16 rows x 8 KV heads of fp8 at 3-11k, and 32 rows x 2 KV
+# heads of bf16 at 1-2.5k; share of the HBM roofline):
+#   chunk 1,024 tokens, 2 slots, whole-chunk fold   82 %   72 %
+#   chunk 1,024 tokens, 3 slots, fold by 512        86 %   82 %
+#   chunk 2,048 tokens, 3 slots, whole-chunk fold   85 %   83 %
+#   chunk 2,048 tokens, 3 slots, fold by 512        89 %   84 %
+# against 90.6 % for the same copies with nothing folded. Why each: a
+# chunk's fold costs 0.43 us plus 2.0 us per 1,024 tokens against 2.8 us
+# per 1,024 tokens of copies, so bigger chunks keep the fold under the
+# copies; a third slot keeps a full chunk queued behind a row's short last
+# one (with two, the DMA engine idles through half a fold at every row's
+# end); and the fold's time goes with the columns it is given, so a last
+# chunk is folded only up to its last live page, in steps of 512 tokens.
+# A fourth slot, 256-token steps and 512-token chunks gave nothing or lost.
+_DECODE_CHUNK_TOKENS = 2048
+_DECODE_FOLD_TOKENS = 512
+_DECODE_SLOTS = 3
+# The ring may take half of the call's 64 MiB of VMEM: pages of many KV
+# heads in two bytes get fewer pages a chunk (fp8 x 8 heads: 12 MiB).
+_DECODE_RING_BYTES = 32 * 1024 * 1024
+# Prefill's larger per-chunk compute amortizes a chunk's fixed cost
+# already, and its VMEM budget also carries the big q tile.
+_PREFILL_CHUNK_TOKENS = 512
+
+# Copies a chunk may hold: each is a descriptor, a semaphore and two
+# branches unrolled into the loop's body (pages far smaller than the 128
+# tokens the deployments run would otherwise unroll hundreds).
+_MAX_CHUNK_PAGES = 32
+
+
 def _chunk_pages(bs: int, target_tokens: int) -> int:
-    """Pages per DMA buffer slot (~target_tokens per chunk). Decode uses
-    bigger chunks than prefill: its per-chunk fixed cost (fori iteration,
-    semaphore waits, G-row flash updates on mostly-empty vregs) dominates
-    at long context, while prefill's larger per-chunk compute amortizes it
-    already — and prefill's VMEM budget also carries the big q tile."""
-    return max(target_tokens // bs, 1)
+    """Pages per DMA buffer slot (~target_tokens per chunk)."""
+    return min(max(target_tokens // bs, 1), _MAX_CHUNK_PAGES)
+
+
+class _LiveRange(NamedTuple):
+    """What one grid cell streams of one table row, all traced scalars:
+    pages ``[first_page, n_pages)`` in chunks ``[c_start, n_chunks)``. The
+    cell that starts a chunk's copies and the cell that waits for them
+    build their descriptors from the same range (``_decode_range``)."""
+
+    row: Any  # row of the block table
+    first_page: Any  # first page a query may see (sliding window; else 0)
+    n_pages: Any  # pages at or past this hold no live token
+    c_start: Any  # first chunk with a live page
+    n_chunks: Any  # exclusive end
+
+
+def _zero_values(buf):
+    """Zero the V half of every buffer slot (a call's first grid cell does
+    this once; scratch persists across a sequential grid). A chunk's dead
+    pages are not copied, so their buffer rows hold what an earlier chunk
+    left: finite cache bytes whose columns get ``p`` exactly 0. VMEM that
+    no copy of this call has written yet may hold a NaN pattern, and
+    ``0 x NaN`` in ``_pv_dot`` is NaN. K needs no guard: its scores are
+    replaced by a select."""
+    zero = jnp.zeros(buf.shape[3:], buf.dtype)
+    for slot in range(buf.shape[0]):
+        for j in range(buf.shape[1]):
+            buf[slot, j, 1] = zero
+
+
+# Words of the stream's state, carried from cell to cell in SMEM.
+_STREAM_STATE_WORDS = 5
 
 
 def _page_dma_loop(
     *,
-    b,  # batch index (program id)
+    live: _LiveRange,  # this cell's row
     layer,  # int32 layer index into the stacked cache
-    n_chunks,  # traced: chunks of C pages to stream (exclusive end)
     tables_ref,  # [B, W] SMEM
     kv_hbm,  # [L, nb, 2, bs, KH*hd] ANY
-    buf,  # [2, C, 2, bs, KH*hd] VMEM scratch
-    sems,  # [2, C] DMA semaphores
+    buf,  # [K, C, 2, bs, KH*hd] VMEM scratch: a ring of K chunk slots
+    sems,  # [K, C] DMA semaphores
     chunk: int,
-    table_width: int,
-    compute_chunk,  # (page [C, 2, bs, KH*hd], chunk_index) -> None
-    c_start=0,  # traced: first live chunk (sliding window skips below it)
+    compute_chunk,  # (pages [n, 2, bs, KH*hd], chunk_index) -> None
+    fold_pages: int = 0,  # fold a ragged last chunk in steps of this many
+    across_rows=None,  # (state_ref SMEM, row -> _LiveRange, rows in grid)
 ):
-    """Double-buffered page streaming shared by decode and prefill: chunk
-    ``c+1``'s DMAs are in flight while ``compute_chunk`` folds chunk ``c``.
-    Chunks below ``c_start`` (entirely outside a sliding window) are neither
-    fetched nor folded."""
-    C, W = chunk, table_width
+    """Page streaming shared by decode and prefill: a ring of K chunk
+    slots, up to K-1 chunks' copies in flight while ``compute_chunk``
+    folds the oldest (K = 2 is double buffering).
 
-    def dma(c, j, slot):
-        # Page ids past the live range clamp to the table's last entry;
-        # their columns are masked by the caller (only the ragged final
-        # chunk fetches any).
-        page = tables_ref[b, jnp.minimum(c * C + j, W - 1)]
-        return pltpu.make_async_copy(
-            kv_hbm.at[layer, page], buf.at[slot, j], sems.at[slot, j]
+    Only live pages move: a copy is issued, and later waited for, where
+    its page lies in ``[first_page, n_pages)``. Chunks below ``c_start``
+    (entirely outside a sliding window) are neither fetched nor folded;
+    the dead pages of a row's first and last chunk are not fetched, the
+    fold masks their columns, and ``_zero_values`` is why that is safe.
+
+    The stream is a queue of chunks in the order the cells fold them. An
+    *issuer* (next row and chunk to start, next slot to fill) runs ahead
+    of the *folder* (oldest slot) by as many chunks as there are free
+    slots. Without ``across_rows`` the issuer stops at this row's end:
+    each cell warms up and drains alone. With it (a sequential grid; plain
+    decode) the issuer walks on into the next row, so the copies never
+    pause at a row's end and a short last chunk is followed at once by the
+    next row's first. It stops at the grid's end and before a row with
+    nothing live (decode padding): that row's cell starts and waits for
+    nothing, and its successor, like cell 0, finds nothing in flight and
+    points the issuer at its own first chunk. Issuer and folder build a
+    chunk's descriptors from the same ``_LiveRange``, and slots are filled
+    and drained in the same order, so each wait meets the copy it names."""
+    C, K = chunk, buf.shape[0]
+
+    def copies(r: _LiveRange, c, slot, op: str):
+        # One descriptor a live page of chunk c: a loop, not C unrolled
+        # branches, so a step program traces and lowers one of them.
+        def one(j, _):
+            getattr(pltpu.make_async_copy(
+                kv_hbm.at[layer, tables_ref[r.row, c * C + j]],
+                buf.at[slot, j], sems.at[slot, j],
+            ), op)()
+            return 0
+
+        jax.lax.fori_loop(
+            jnp.maximum(r.first_page - c * C, 0),
+            jnp.minimum(r.n_pages - c * C, C), one, 0,
         )
 
-    @pl.when(n_chunks > c_start)
-    def _warmup():
-        for j in range(C):
-            dma(c_start, j, jax.lax.rem(c_start, 2)).start()
+    def issue(st, limit):
+        """Start the issuer's chunk if fewer than ``limit`` are in flight,
+        and move the issuer on."""
+        row, c, head, tail, inflight = st
+        go = (row >= 0) & (inflight < limit)
+        if across_rows is None:
+            r, after, after_c = live, -1, 0
+        else:
+            _, range_of, n_rows = across_rows
+            r = range_of(jnp.maximum(row, 0))
+            nxt = range_of(jnp.minimum(r.row + 1, n_rows - 1))
+            walk_on = (r.row + 1 < n_rows) & (nxt.n_chunks > nxt.c_start)
+            after, after_c = jnp.where(walk_on, r.row + 1, -1), nxt.c_start
 
-    def body(c, _):
-        slot = jax.lax.rem(c, 2)
-        nslot = jax.lax.rem(c + 1, 2)
+        @pl.when(go)
+        def _():
+            copies(r, c, head, "start")
 
-        @pl.when(c + 1 < n_chunks)
-        def _next():
-            for j in range(C):
-                dma(c + 1, j, nslot).start()
+        more = c + 1 < r.n_chunks
+        return (
+            jnp.where(go, jnp.where(more, row, after), row),
+            jnp.where(go, jnp.where(more, c + 1, after_c), c),
+            jnp.where(go, jax.lax.rem(head + 1, K), head),
+            tail,
+            inflight + go.astype(jnp.int32),
+        )
 
-        for j in range(C):
-            dma(c, j, slot).wait()
-        compute_chunk(buf[slot], c)
-        return 0
+    has = live.n_chunks > live.c_start
+    if across_rows is None:
+        st = (jnp.where(has, live.row, -1), live.c_start, 0, 0, 0)
+    else:
+        state = across_rows[0]
+        st = tuple(state[i] for i in range(_STREAM_STATE_WORDS))
+        # Nothing in flight for a live row: cell 0, or the cell after a
+        # padding row. The issuer starts here.
+        cold = has & (st[4] == 0)
+        st = (jnp.where(cold, live.row, st[0]),
+              jnp.where(cold, live.c_start, st[1])) + st[2:]
+    for _ in range(K - 1):
+        st = issue(st, K - 1)
 
-    jax.lax.fori_loop(c_start, n_chunks, body, 0)
+    def body(c, st):
+        row, ic, head, tail, inflight = issue(st, K)
+        copies(live, c, tail, "wait")
+        if not fold_pages:
+            compute_chunk(buf[tail], c)
+        else:
+            # The fold's time goes with the columns it is given, live or
+            # masked: hand a row's last chunk over only up to its last
+            # live page, rounded up to ``fold_pages`` (one body a size).
+            top = jnp.minimum(live.n_pages - c * C, C)
+            for n in range(fold_pages, C + 1, fold_pages):
+                @pl.when((top > n - fold_pages) & (top <= n))
+                def _(n=n):
+                    compute_chunk(buf[tail, :n], c)
+        return row, ic, head, jax.lax.rem(tail + 1, K), inflight - 1
+
+    st = jax.lax.fori_loop(live.c_start, live.n_chunks, body, st)
+    if across_rows is not None:
+        for i in range(_STREAM_STATE_WORDS):
+            state[i] = st[i]
 
 
 def _chunked_flash(
     *,
-    b, layer, n_chunks, tables_ref, kv_hbm, buf, sems,
+    live, layer, tables_ref, kv_hbm, buf, sems,
     q_heads,  # list of KH arrays [R, hd] (native dtype)
     bounds,  # [R, 1] exclusive per-row attention bound (causality + kv_len)
     m_ref,  # [KH, R, 128] fp32 scratch (col 0 live)
@@ -161,11 +296,9 @@ def _chunked_flash(
     scale: float,
     block_size: int,
     chunk: int,
-    table_width: int,
     head_dim: int,
     lows=None,  # [R, 1] inclusive per-row lower bound (sliding window)
     softcap: float = 0.0,
-    c_start=0,  # traced: first chunk any row's window reaches
 ):
     """Per-head flash accumulation over streamed KV chunks (the prefill
     shape: R = Tq*G rows per head keep the MXU busy per head). Matmuls run
@@ -205,9 +338,21 @@ def _chunked_flash(
             acc_ref[h] = acc_ref[h] * alpha + _pv_dot(p, vh)
 
     _page_dma_loop(
-        b=b, layer=layer, n_chunks=n_chunks, tables_ref=tables_ref,
-        kv_hbm=kv_hbm, buf=buf, sems=sems, chunk=chunk,
-        table_width=table_width, compute_chunk=compute, c_start=c_start,
+        live=live, layer=layer, tables_ref=tables_ref, kv_hbm=kv_hbm,
+        buf=buf, sems=sems, chunk=chunk, compute_chunk=compute,
+    )
+
+
+def _decode_range(lens_ref, win_ref, row, *, span: int, bs: int):
+    """The live range of decode row ``row``, its length and its window's
+    first position. The one query row sits at position kv_len-1 and may see
+    positions >= kv_len - window (0 = unlimited); whole chunks below that
+    are never fetched, nor the pages below it in the chunk it starts in."""
+    kv_len = lens_ref[row]
+    lo = jnp.maximum(kv_len - window_eff(win_ref[0]), 0)
+    return kv_len, lo, _LiveRange(
+        row=row, first_page=lo // bs, n_pages=(kv_len + bs - 1) // bs,
+        c_start=lo // span, n_chunks=(kv_len + span - 1) // span,
     )
 
 
@@ -216,15 +361,16 @@ def _decode_kernel(
     q_ref,  # [1, H, hd] VMEM
     kv_hbm,  # [L, nb, 2, bs, KH*hd] ANY
     o_ref,  # [1, H, hd] VMEM
-    buf, sems, m_ref, l_ref, acc_ref,  # scratch (m/l [H,128], acc [H,hd])
+    buf, sems, state, m_ref, l_ref, acc_ref,  # scratch (m/l [H,128], acc [H,hd])
     *,
     scale: float,
     block_size: int,
     chunk: int,
-    table_width: int,
+    fold_pages: int,
     group: int,
     head_dim: int,
     softcap: float = 0.0,
+    prefetch_next_row: bool = True,
 ):
     """Dense folded-q decode: per-head [G, hd] x [hd, S] mat-vecs waste the
     MXU (G of 128 rows live) and burn VPU on per-head slices, so instead q
@@ -235,18 +381,34 @@ def _decode_kernel(
     dense the same way; each row's own head block is extracted from
     [H, KH, hd] with the same mask. ~KH x more MACs, all on otherwise-idle
     MXU rows; the VPU flash update shrinks from KH G-row passes to one
-    full-vreg [H, S] pass."""
+    full-vreg [H, S] pass.
+
+    The grid is sequential, so buffers, semaphores and ``state`` carry
+    from cell to cell: while cell ``b`` folds its last chunks, the first
+    chunks of the rows after it are already in flight
+    (``_page_dma_loop``). ``prefetch_next_row`` is false where a write
+    precedes the read (``_decode_write_kernel``): row ``b+1``'s first
+    chunk may hold the page it has yet to write."""
     b = pl.program_id(0)
+    B = pl.num_programs(0)
     G, hd = group, head_dim
     H = q_ref.shape[1]
     KH = H // G
-    kv_len = lens_ref[b]
-    n_chunks = (kv_len + chunk * block_size - 1) // (chunk * block_size)
-    # Sliding window (0 = unlimited): the one query row sits at position
-    # kv_len-1 and may see positions >= kv_len - window; whole chunks below
-    # that are never fetched.
-    lo = jnp.maximum(kv_len - window_eff(win_ref[0]), 0)
-    c_start = lo // (chunk * block_size)
+    span = chunk * block_size
+    rng = functools.partial(
+        _decode_range, lens_ref, win_ref, span=span, bs=block_size
+    )
+    kv_len, lo, live = rng(b)
+    across_rows = None
+    if prefetch_next_row:
+        across_rows = (state, lambda row: rng(row)[2], B)
+
+    @pl.when(b == 0)
+    def _first_cell():
+        _zero_values(buf)
+        state[0] = -1  # the issuer points nowhere: this cell starts cold
+        for i in range(1, _STREAM_STATE_WORDS):
+            state[i] = 0
 
     q = q_ref[0]  # [H, hd] native dtype
     # Arithmetic 0/1 mask (born 3D): Mosaic cannot minor-dim-reshape or
@@ -264,10 +426,10 @@ def _decode_kernel(
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def compute(page, c):
-        S = chunk * block_size
+        S = page.shape[0] * block_size
         k = page[:, 0].reshape(S, KH * hd)
         v = page[:, 1].reshape(S, KH * hd)
-        col = c * S + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+        col = c * span + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
         s = jax.lax.dot_general(
             q_sparse, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -286,9 +448,9 @@ def _decode_kernel(
         acc_ref[...] = acc_ref[...] * alpha + own
 
     _page_dma_loop(
-        b=b, layer=layer_ref[0], n_chunks=n_chunks, tables_ref=tables_ref,
-        kv_hbm=kv_hbm, buf=buf, sems=sems, chunk=chunk,
-        table_width=table_width, compute_chunk=compute, c_start=c_start,
+        live=live, layer=layer_ref[0], tables_ref=tables_ref, kv_hbm=kv_hbm,
+        buf=buf, sems=sems, chunk=chunk, compute_chunk=compute,
+        fold_pages=fold_pages, across_rows=across_rows,
     )
     out = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-20)  # [H, hd]
     o_ref[0] = out.astype(o_ref.dtype)
@@ -302,7 +464,7 @@ def _decode_write_kernel(
     kv_hbm,  # [L, nb, 2, bs, KH*hd] ANY (aliased with kv_out)
     o_ref,  # [1, H, hd] VMEM
     kv_out,  # [L, nb, 2, bs, KH*hd] ANY — the SAME buffer (in-place)
-    buf, sems, wbuf, wsems, m_ref, l_ref, acc_ref,
+    buf, sems, state, wbuf, wsems, m_ref, l_ref, acc_ref,
     **kw,
 ):
     """Decode step with the KV write folded in: each grid cell pulls its
@@ -313,7 +475,11 @@ def _decode_write_kernel(
     and is read back in the final chunk. Folding removes the per-layer
     XLA scatter from the decode step (a fixed ~0.2 ms x layers of pure op
     overhead on a 10 GiB carried buffer); the page round trip is ~512 KB
-    per sequence per layer, noise next to the KV stream."""
+    per sequence per layer, noise next to the KV stream.
+
+    Each cell warms up its own first chunk (``prefetch_next_row`` false):
+    a chunk started by the cell before would read this row's write page
+    before the row has written it."""
     b = pl.program_id(0)
     bs = kv_hbm.shape[3]
     nb = kv_hbm.shape[1]
@@ -347,7 +513,8 @@ def _decode_write_kernel(
 
     _decode_kernel(
         tables_ref, lens_ref, layer_ref, win_ref,
-        q_ref, kv_out, o_ref, buf, sems, m_ref, l_ref, acc_ref, **kw,
+        q_ref, kv_out, o_ref, buf, sems, state, m_ref, l_ref, acc_ref,
+        prefetch_next_row=False, **kw,
     )
 
 
@@ -368,7 +535,7 @@ def pallas_paged_attention_decode_write(
     """Fused write+attend decode step. Returns (out [B, H, hd], cache).
     The cache is updated IN PLACE (input/output aliased)."""
     B, H, hd, bs, lanes, C, kw, scratch, flash = _decode_geometry(
-        q3, kv_pages, block_tables, scale=scale, softcap=softcap
+        q3, kv_pages, scale=scale, softcap=softcap
     )
     nb = kv_pages.shape[1]
     tables = block_tables.astype(jnp.int32)
@@ -434,7 +601,6 @@ def _prefill_kernel(
     scale: float,
     block_size: int,
     chunk: int,
-    table_width: int,
     group: int,
     head_dim: int,
     q_tile: int,
@@ -452,27 +618,33 @@ def _prefill_kernel(
     # full prefill, while warm tiles near the sequence end still stream every
     # live page — exactly the data they need).
     limit = jnp.minimum(kv_len, start + (tq + 1) * Tq)
-    n_chunks = (limit + chunk * block_size - 1) // (chunk * block_size)
+    span = chunk * block_size
 
     rows = jax.lax.broadcasted_iota(jnp.int32, (Tq * G, 1), 0)
     q_pos = start + tq * Tq + rows // G  # [Tq*G, 1]
     bounds = jnp.minimum(q_pos + 1, kv_len)
-    # Sliding window lower bounds; chunks below the tile's FIRST row's
+    # Sliding window lower bounds; pages below the tile's FIRST row's
     # window start are outside every row's window and are never fetched.
     win_eff = window_eff(win_ref[0])
     lows = jnp.maximum(q_pos + 1 - win_eff, 0)  # [Tq*G, 1]
-    c_start = jnp.maximum(start + tq * Tq + 1 - win_eff, 0) // (
-        chunk * block_size
+    tile_lo = jnp.maximum(start + tq * Tq + 1 - win_eff, 0)
+    live = _LiveRange(
+        row=b, first_page=tile_lo // block_size,
+        n_pages=(limit + block_size - 1) // block_size,
+        c_start=tile_lo // span, n_chunks=(limit + span - 1) // span,
     )
+
+    @pl.when((b == 0) & (tq == 0))
+    def _first_cell():
+        _zero_values(buf)
 
     qh = [
         q_ref[0, :, h * G : (h + 1) * G, :].reshape(Tq * G, head_dim)
         for h in range(KH)
     ]
     _chunked_flash(
-        b=b,
+        live=live,
         layer=layer_ref[0],
-        n_chunks=n_chunks,
         tables_ref=tables_ref,
         kv_hbm=kv_hbm,
         buf=buf,
@@ -485,11 +657,9 @@ def _prefill_kernel(
         scale=scale,
         block_size=block_size,
         chunk=chunk,
-        table_width=table_width,
         head_dim=head_dim,
         lows=lows,
         softcap=softcap,
-        c_start=c_start,
     )
     # Padding rows (kv_len == 0) accumulated nothing: l stays 0 and the
     # output is 0, matching the drop-slot contract.
@@ -510,23 +680,29 @@ def _scratch(C, bs, lanes, R, KH, hd, kv_dtype):
     ]
 
 
-def _decode_geometry(q3, kv_pages, block_tables, *, scale, softcap):
+def _decode_geometry(q3, kv_pages, *, scale, softcap):
     """Shared decode-call geometry: chunking, flash scratch, and the kernel
     kwargs — ONE source of truth for the plain and fused-write wrappers
     (a tuning change here reaches both)."""
     B, H, hd = q3.shape
     _, nb, _, bs, lanes = kv_pages.shape
     KH = lanes // hd
-    W = block_tables.shape[1]
     G = H // KH
-    C = _chunk_pages(bs, 1024)
+    page_bytes = 2 * bs * lanes * kv_pages.dtype.itemsize
+    C = min(_chunk_pages(bs, _DECODE_CHUNK_TOKENS),
+            max(_DECODE_RING_BYTES // (_DECODE_SLOTS * page_bytes), 1))
+    fold = _chunk_pages(bs, _DECODE_FOLD_TOKENS)
+    C -= C % fold if C > fold else 0  # whole fold steps
     kwargs = dict(
-        scale=scale, block_size=bs, chunk=C, table_width=W, group=G,
-        head_dim=hd, softcap=softcap,
+        scale=scale, block_size=bs, chunk=C, group=G, head_dim=hd,
+        softcap=softcap, fold_pages=fold if C > fold else 0,
     )
     scratch = [
-        pltpu.VMEM((2, C, 2, bs, lanes), kv_pages.dtype),
-        pltpu.SemaphoreType.DMA((2, C)),
+        pltpu.VMEM((_DECODE_SLOTS, C, 2, bs, lanes), kv_pages.dtype),
+        pltpu.SemaphoreType.DMA((_DECODE_SLOTS, C)),
+        # The stream's issuer and folder, carried from cell to cell
+        # (``_page_dma_loop``).
+        pltpu.SMEM((_STREAM_STATE_WORDS,), jnp.int32),
     ]
     flash_scratch = [
         pltpu.VMEM((H, 128), jnp.float32),
@@ -539,7 +715,7 @@ def _decode_geometry(q3, kv_pages, block_tables, *, scale, softcap):
 def _decode_call(q3, kv_pages, block_tables, kv_lens, layer, window,
                  *, scale, softcap):
     B, H, hd, bs, lanes, C, kw, scratch, flash = _decode_geometry(
-        q3, kv_pages, block_tables, scale=scale, softcap=softcap
+        q3, kv_pages, scale=scale, softcap=softcap
     )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -558,7 +734,12 @@ def _decode_call(q3, kv_pages, block_tables, kv_lens, layer, window,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q3.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
+            # Sequential: a cell hands its successor the ring with chunks
+            # in flight and five SMEM words. Nothing is lost by it on the
+            # one chip the program knows (v5e, one TensorCore a chip:
+            # ``device.py::DEVICE_TABLE``, ``perf/peaks.json``); a chip
+            # with two cores would want the rows split between them first.
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=pallas_interpret(),
@@ -571,9 +752,8 @@ def _prefill_call(q, kv_pages, block_tables, kv_lens, starts, layer, window,
     B, T, H, hd = q.shape
     _, nb, _, bs, lanes = kv_pages.shape
     KH = lanes // hd
-    W = block_tables.shape[1]
     G = H // KH
-    C = _chunk_pages(bs, 512)
+    C = _chunk_pages(bs, _PREFILL_CHUNK_TOKENS)
     n_tiles = T // q_tile
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -595,7 +775,6 @@ def _prefill_call(q, kv_pages, block_tables, kv_lens, starts, layer, window,
         scale=scale,
         block_size=bs,
         chunk=C,
-        table_width=W,
         group=G,
         head_dim=hd,
         q_tile=q_tile,
@@ -606,7 +785,9 @@ def _prefill_call(q, kv_pages, block_tables, kv_lens, starts, layer, window,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, T, H, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            # Sequential, so that the first cell's ``_zero_values`` covers
+            # the call (one TensorCore a chip on v5e: nothing is lost).
+            dimension_semantics=("arbitrary", "arbitrary"),
             # The 256-row q tile + 512-token KV chunks exceed the default
             # 16 MiB scoped-vmem budget; the chip has far more.
             vmem_limit_bytes=64 * 1024 * 1024,

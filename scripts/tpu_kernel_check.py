@@ -8,8 +8,18 @@ At the Llama-3-8B layer shapes the serving path uses (32 Q / 8 KV heads x
     a sliding-window + soft-cap variant of each — reference: the gather
     implementation (``ops/attention.py``), in 256-row slices for prefill so
     its score tensor fits;
-  - the fused decode-write variant behind ``PST_FUSED_KV_WRITE`` —
-    reference: XLA scatter + gather attention, and the written cache rows;
+  - decode at the two shapes the benchmark's cells run, on a stacked cache
+    at layer 2 of 3: 16 rows over 8 KV heads of fp8 pages at 3-11k of
+    context as drawn (ragged, two rows empty), and 32 rows over 2 KV heads
+    of bf16 pages at 1-2.5k. Every table entry past a row's last live page
+    points at a page of NaN, and the other layers are NaN: a dead page that
+    is fetched, a column that is not masked, or VMEM that nothing wrote
+    reaching ``p @ V`` shows here and only here (the interpreter's buffers
+    start clean);
+  - the fused decode-write variant behind ``PST_FUSED_KV_WRITE`` (each cell
+    warms up its own first chunk) — reference: XLA scatter + gather
+    attention, and the written cache rows; ``ragged`` gives its rows the
+    lengths 1 to 20k so that a row's write page lies in its first chunk;
   - ``int4_matmul`` at decode-width (8) and prefill-width (1024) rows for
     4096->14336 and 14336->4096 — reference: ``dequant_int4`` + fp32 dot;
   - ``int4_matmul_stacked`` (the call the model makes: three layers' weights
@@ -25,7 +35,7 @@ Prints one line per case with the max abs difference, then one JSON object
 backend is not ``tpu``, a kernel fails to compile, or a difference exceeds
 its bound. Run from the checkout root, on the chip:
 
-    python scripts/tpu_kernel_check.py
+    python scripts/tpu_kernel_check.py [substring of a case's name ...]
 """
 
 from __future__ import annotations
@@ -128,7 +138,52 @@ def attention_case(name, *, B, T, kv_dtype, window=0, softcap=0.0):
     }
 
 
-def fused_write_case(name, *, kv_dtype):
+def cell_shape_case(name, *, B, kv_heads, kv_dtype, lo, hi, empty=()):
+    """Decode as a benchmark cell calls it: ragged lengths, a stacked cache
+    read at a traced layer, NaN wherever the kernel must not look."""
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    lanes, layers, layer = kv_heads * HD, 3, 2
+    lens = rng.integers(lo, hi, B).astype(np.int32)
+    lens[list(empty)] = 0
+    width = -(-hi // BS) + 8
+    nb = B * width + 2
+    kv = rng.standard_normal((nb, 2, BS, lanes)).astype(np.float32)
+    kv[1] = np.nan
+    kv = jnp.asarray(kv, jnp.bfloat16).astype(kv_dtype)
+    stack = jnp.stack([jnp.full_like(kv, np.nan)] * layer + [kv])
+    tables = (rng.permutation(B * width) + 2).reshape(B, width)
+    dead = np.arange(width)[None] >= -(-lens // BS)[:, None]
+    q = jnp.asarray(rng.standard_normal((B, 1, H, HD)), jnp.bfloat16)
+    q_pos = jnp.asarray(lens - 1)[:, None]
+    kern = jax.jit(
+        lambda q, kv, t, l, p, ly: pallas_paged_attention(
+            q, kv, t, l, p, ly, scale=SCALE)
+    )
+    t0 = time.perf_counter()
+    got = np.asarray(kern(
+        q, stack, jnp.asarray(np.where(dead, 1, tables).astype(np.int32)),
+        jnp.asarray(lens), q_pos, jnp.int32(layer)), np.float32)
+    compile_s = time.perf_counter() - t0
+    # The reference multiplies every gathered V: give it page 0 for the dead.
+    ref = jax.jit(
+        lambda q, kv, t, l, p: gather_paged_attention(
+            q, kv, t, l, p, 0, scale=SCALE)
+    )
+    want = np.asarray(ref(
+        q, kv[None], jnp.asarray(np.where(dead, 0, tables).astype(np.int32)),
+        jnp.asarray(lens), q_pos), np.float32)
+    live = lens > 0
+    return {
+        "max_abs_diff": float(np.abs(got[live] - want[live]).max()),
+        "empty_rows_max_abs_diff": float(np.abs(got[~live]).max(initial=0.0)),
+        "ref_abs_max": float(np.abs(want[live]).max()),
+        "kv_tokens": int(lens.sum()),
+        "bound": ATTN_BOUND,
+        "first_call_s": round(compile_s, 2),
+    }
+
+
+def fused_write_case(name, *, kv_dtype, ragged=False):
     """Decode step with the KV write folded into the kernel vs XLA scatter
     then gather attention; also compares the rows the kernel wrote."""
     B = 8
@@ -137,11 +192,12 @@ def fused_write_case(name, *, kv_dtype):
     q = jnp.asarray(rng.standard_normal((B, H, HD)), jnp.bfloat16)
     kv = _pages(rng, nb, kv_dtype)
     tables = _tables(rng, B, nb)
-    kv_lens = jnp.full((B,), LIVE, jnp.int32)
+    lens = [1, 700, 1024, 1025, 3000, 130, LIVE, 300] if ragged else [LIVE] * B
+    kv_lens = jnp.asarray(lens, jnp.int32)
     k_new = jnp.asarray(rng.standard_normal((B, KH * HD)), jnp.bfloat16)
     v_new = jnp.asarray(rng.standard_normal((B, KH * HD)), jnp.bfloat16)
-    pos = LIVE - 1
-    blk = tables[:, pos // BS]
+    pos = kv_lens - 1
+    blk = tables[jnp.arange(B), pos // BS]
     write_flat = (blk * BS + pos % BS).astype(jnp.int32)
 
     def scatter(kv):
@@ -149,9 +205,10 @@ def fused_write_case(name, *, kv_dtype):
         return kv.at[0, blk, 1, pos % BS].set(v_new.astype(kv.dtype))
 
     kv_ref = jax.jit(scatter)(kv)
-    q_pos = jnp.full((B, 1), pos, jnp.int32)
+    q_pos = pos[:, None]
     want = _reference(q[:, None], kv_ref, tables, kv_lens, q_pos, 0, 0.0)[:, 0]
-    want_rows = np.asarray(kv_ref[0, blk][:, :, pos % BS].astype(jnp.float32))
+    want_rows = np.asarray(
+        kv_ref[0, blk, :, pos % BS].astype(jnp.float32))
 
     kern = jax.jit(
         lambda q, kv, t, l, k, v, wf: pallas_paged_attention_decode_write(
@@ -163,7 +220,7 @@ def fused_write_case(name, *, kv_dtype):
     out, kv_out = kern(q, kv, tables, kv_lens, k_new, v_new, write_flat)
     got = np.asarray(out, np.float32)
     compile_s = time.perf_counter() - t0
-    got_rows = np.asarray(kv_out[0, blk][:, :, pos % BS].astype(jnp.float32))
+    got_rows = np.asarray(kv_out[0, blk, :, pos % BS].astype(jnp.float32))
     return {
         "max_abs_diff": float(np.abs(got - want).max()),
         "written_rows_max_abs_diff": float(np.abs(got_rows - want_rows).max()),
@@ -226,6 +283,12 @@ def cases():
             B=1, T=1024, kv_dtype=dt, window=4096, softcap=50.0)
         yield f"attn_decode_fused_write_{tag}", fused_write_case, dict(
             kv_dtype=dt)
+    yield "attn_decode_fused_write_fp8_ragged", fused_write_case, dict(
+        kv_dtype=fp8, ragged=True)
+    yield "attn_decode_cell_dense_b16_kh8_fp8", cell_shape_case, dict(
+        B=16, kv_heads=8, kv_dtype=fp8, lo=3000, hi=11000, empty=(5, 15))
+    yield "attn_decode_cell_hybrid_b32_kh2_bf16", cell_shape_case, dict(
+        B=32, kv_heads=2, kv_dtype=jnp.bfloat16, lo=1000, hi=2500)
     for rows in (8, 1024):
         for din, dout in ((4096, 14336), (14336, 4096)):
             yield f"int4_matmul_n{rows}_{din}x{dout}", int4_case, dict(
@@ -252,7 +315,10 @@ def main() -> int:
     print(json.dumps({k: report[k] for k in ("device", "memory_stats")}),
           flush=True)
     failed = []
+    only = sys.argv[1:]  # substrings of case names; none = every case
     for name, fn, kw in cases():
+        if only and not any(o in name for o in only):
+            continue
         try:
             res = fn(name, **kw)
             diffs = [v for k, v in res.items() if k.endswith("max_abs_diff")]

@@ -50,6 +50,121 @@ def test_pallas_handles_empty_rows():
     assert np.allclose(np.asarray(got)[1], 0.0)
 
 
+# --- the page stream at the kernels' own geometry -------------------------
+# 128-token pages (8 a decode chunk, 4 a prefill chunk) over 64 lanes, so a
+# row has one, two or several chunks and the loop's second iteration, the
+# ragged last chunk and the hand-over between rows all run. Every table
+# entry the kernel has no business fetching (past the row's last live page,
+# below its sliding window) points at a page of NaN: a dead page that is
+# fetched, or whose columns are not masked, poisons the output. The oracle
+# gets the same table with those entries pointed at page 0 (it masks scores
+# but multiplies every gathered V).
+
+_BS, _KH, _HD, _H = 128, 2, 32, 4
+_NAN_PAGE = 1
+
+
+def _stream_case(lens, *, T=1, window=0, dtype=jnp.float32, layers=1, layer=0,
+                 seed=0):
+    rng = np.random.default_rng(seed)
+    B = len(lens)
+    lens = np.asarray(lens, np.int32)
+    W = max(-(-int(lens.max()) // _BS), 1) + 3  # dead entries in every row
+    nb = 2 + B * W
+    kv = rng.standard_normal((layers, nb, 2, _BS, _KH * _HD)).astype(np.float32)
+    kv[:, _NAN_PAGE] = np.nan
+    kv[:layer] = np.nan  # a read of the wrong layer fails
+    q = rng.standard_normal((B, T, _H, _HD), dtype=np.float32)
+    tables = (2 + rng.permutation(B * W)).reshape(B, W).astype(np.int32)
+    q_pos = (lens - T)[:, None] + np.arange(T, dtype=np.int32)[None]
+    first = np.maximum(q_pos[:, 0] + 1 - window, 0) // _BS if window else 0
+    page = np.arange(W)[None]
+    dead = (page >= -(-lens // _BS)[:, None]) | (page < np.reshape(first, (-1, 1)))
+    kv = jnp.asarray(kv).astype(dtype)
+    rest = (jnp.asarray(lens), jnp.asarray(q_pos), layer)
+    kw = dict(scale=1.0 / np.sqrt(_HD), window=window)
+    ref = gather_paged_attention(
+        jnp.asarray(q), kv, jnp.asarray(np.where(dead, 0, tables)),
+        *rest, **kw)
+    got = pallas_paged_attention(
+        jnp.asarray(q), kv, jnp.asarray(np.where(dead, _NAN_PAGE, tables)),
+        *rest, **kw)
+    return np.asarray(got), np.asarray(ref), lens
+
+
+_DECODE_STREAMS = {
+    # kv_len an exact multiple of the 1,024-token chunk
+    "whole_chunks": dict(lens=[1024, 3072, 2048]),
+    # one live page (and one live token) in the last chunk
+    "one_live_page_in_last_chunk": dict(lens=[1024 + 5, 2048 + 128, 17]),
+    # slot parity across the row boundary, both ways round
+    "one_chunk_then_many": dict(lens=[300, 3500]),
+    "many_chunks_then_one": dict(lens=[3500, 300]),
+    "odd_and_even_chunk_counts": dict(lens=[1100, 3000, 900, 2100, 2049]),
+    # decode padding: nothing issued, nothing waited for, output 0
+    "empty_row_first": dict(lens=[0, 1500, 700]),
+    "empty_row_last": dict(lens=[1500, 700, 0]),
+    "empty_row_between": dict(lens=[1500, 0, 0, 2300]),
+    "all_rows_empty": dict(lens=[0, 0, 0]),
+    # c_start 2, 0, 1: the next row's first chunk is fetched at ITS start,
+    # and its first live page lies mid-chunk
+    "window_start_differs_by_row": dict(lens=[3000, 1300, 2500], window=600),
+    "fp8_pages": dict(lens=[1100, 2100, 40], dtype=jnp.float8_e4m3fn),
+    "layer_of_a_stack": dict(lens=[1300, 200], layers=3, layer=2),
+    "single_row": dict(lens=[2500]),
+}
+
+
+@pytest.mark.parametrize("case", list(_DECODE_STREAMS))
+def test_pallas_decode_streams_live_pages_only(case):
+    kw = _DECODE_STREAMS[case]
+    got, ref, lens = _stream_case(**kw)
+    assert np.all(np.isfinite(got)), "a dead or foreign page reached the fold"
+    assert np.all(got[lens == 0] == 0.0)  # the drop-slot contract
+    # fp8 pages: the kernel's probabilities carry ~2^-8 (the split dot)
+    tol = 2e-2 if "dtype" in kw else 2e-5
+    np.testing.assert_allclose(
+        got[lens > 0], ref[lens > 0], rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("case", [
+    "odd_and_even_chunk_counts", "empty_row_between",
+    "window_start_differs_by_row",
+])
+def test_pallas_decode_every_wait_meets_its_copy(case, monkeypatch):
+    """The plain interpreter copies at ``start`` and ignores ``wait``, so a
+    wait that names another copy than the one started (what hangs the chip,
+    or lets a fold read a slot still being filled) passes there. JAX's TPU
+    interpreter moves the bytes at the ``wait``: a chunk whose wait does
+    not match its start, slot for slot and page for page, folds stale data
+    here. The issuer runs rows ahead of the folder, so this is where their
+    agreement is held."""
+    from jax.experimental.pallas import tpu as pltpu
+    from production_stack_tpu.ops import paged_attention_pallas as pap
+
+    monkeypatch.setattr(
+        pap, "pallas_interpret",
+        lambda: pltpu.InterpretParams(dma_execution_mode="on_wait"))
+    got, ref, lens = _stream_case(**_DECODE_STREAMS[case])
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(
+        got[lens > 0], ref[lens > 0], rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("case, kw", [
+    # 16 query rows ending at 716: the tile's limit leaves two of the
+    # second chunk's four pages dead
+    ("limit_ends_mid_chunk", dict(lens=[716, 1540], T=16)),
+    # window 300 over rows ending at 716: positions below 401 are outside
+    # every row's window, so the first chunk has one live page, its last
+    ("window_starts_mid_chunk", dict(lens=[716, 1540], T=16, window=300)),
+])
+def test_pallas_prefill_streams_live_pages_only(case, kw):
+    got, ref, _ = _stream_case(**kw)
+    assert np.all(np.isfinite(got)), "a dead or foreign page reached the fold"
+    np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+
+
 def _prefill_setup(B, T, start_offsets, H=8, KH=4, hd=32, nb=64, bs=8, W=8,
                    seed=1):
     """Chunked-prefill batch: row b's chunk starts at start_offsets[b] and
