@@ -225,48 +225,80 @@ def state_slot_count(cfg: EngineConfig) -> int:
     return cfg.max_num_seqs + max(2, cfg.max_num_seqs // 8)
 
 
-def refuse_for_recurrent(cfg: EngineConfig) -> None:
-    """What a model with recurrent (state-space) layers cannot be served
-    with, refused at start-up by the flag's name. A sequence's state is one
-    slot, not a list of pages: a cached prefix, a swapped or tiered page and
-    a handed-off page list all lack the state that belongs to them, a
-    rejected draft would need the state rolled back, and neither adapters,
-    quantised leaves nor a mesh are built for these layers."""
-    refused = [
-        (cfg.enable_prefix_caching, "--enable-prefix-caching",
-         "a cached page list carries no state snapshot; pass "
-         "--no-enable-prefix-caching"),
-        (cfg.kv_swap, "--kv-swap",
-         "a parked sequence's state is not swapped; pass --no-kv-swap "
-         "(preemption is by recompute)"),
-        (cfg.cpu_offload_blocks > 0, "--cpu-offload-blocks",
-         "host-tier pages carry no state"),
-        (bool(cfg.remote_kv_url), "--remote-kv-url",
-         "remote-tier pages carry no state"),
-        (cfg.kv_role != "none", "--kv-role",
-         "a KV hand-off ships pages, not the state"),
-        (cfg.speculative_ngram > 0, "--speculative-ngram",
-         "a rejected draft would need the state rolled back"),
+def _both(why: str) -> dict:
+    return {"recurrent": why, "latent_pages": why}
+
+
+# What a model class cannot be served with, refused at start-up by the
+# flag's name: one table for every class that is not a plain K+V page list.
+# A row: is the flag on, its name, and for each property of the model's
+# config (``recurrent``: per-sequence state beside the pages;
+# ``latent_pages``: a page is one latent row a token, not a K and a V half)
+# why the flag cannot be served, or no entry where it can.
+def _refusals(cfg: EngineConfig):
+    return [
+        (cfg.enable_prefix_caching, "--enable-prefix-caching", {
+            "recurrent": "a cached page list carries no state snapshot; "
+                         "pass --no-enable-prefix-caching"}),
+        (cfg.kv_swap, "--kv-swap", {
+            "recurrent": "a parked sequence's state is not swapped; pass "
+                         "--no-kv-swap (preemption is by recompute)",
+            "latent_pages": "the swap stash frames a page as a K and a V "
+                            "half (engine/swap.py); pass --no-kv-swap "
+                            "(preemption is by recompute)"}),
+        (cfg.cpu_offload_blocks > 0, "--cpu-offload-blocks", {
+            "recurrent": "host-tier pages carry no state",
+            "latent_pages": "the host tier frames a page as a K and a V "
+                            "half (engine/cache_tiering.py)"}),
+        (bool(cfg.remote_kv_url), "--remote-kv-url", {
+            "recurrent": "remote-tier pages carry no state",
+            "latent_pages": "the kvserver's framing is a K and a V half a "
+                            "page"}),
+        (cfg.kv_role != "none", "--kv-role", {
+            "recurrent": "a KV hand-off ships pages, not the state",
+            "latent_pages": "the hand-off ships K and V halves "
+                            "(engine/kv_handoff.py)"}),
+        (cfg.speculative_ngram > 0, "--speculative-ngram", {
+            "recurrent": "a rejected draft would need the state rolled back"}),
         (cfg.enable_lora, "--enable-lora",
-         "no adapter bank exists for these layers"),
+         _both("no adapter bank exists for these layers")),
         (cfg.tensor_parallel_size > 1, "--tensor-parallel-size",
-         "the state pools and kernels run on one device"),
-        (cfg.pipeline_parallel_size > 1, "--pipeline-parallel-size",
-         "the layer pattern is not staged"),
+         _both("its pools and kernels run on one device")),
+        (cfg.pipeline_parallel_size > 1, "--pipeline-parallel-size", {
+            "recurrent": "the layer pattern is not staged",
+            "latent_pages": "the dense and expert layers are not staged"}),
         (cfg.expert_parallel_size > 1, "--expert-parallel-size",
-         "an expert-parallel share is told by the model config's "
-         "ep_share, not by a mesh"),
+         _both("an expert-parallel share is told by the model config's "
+               "ep_share, not by a mesh")),
         (cfg.data_parallel_size > 1, "--data-parallel-size",
-         "the state pools and kernels run on one device"),
+         _both("its pools and kernels run on one device")),
         (bool(cfg.quantization), "--quantization",
-         "no quantised leaves exist for these layers"),
+         _both("no quantised leaves exist for these layers")),
+        (bool(cfg.kv_cache_dtype)
+         and jax.numpy.dtype(cfg.kv_cache_dtype).itemsize < 2,
+         "--kv-cache-dtype", {
+            "latent_pages": "one-byte latents are not built (the decode "
+                            "kernel folds keys and values out of one "
+                            "two-byte buffer)"}),
     ]
-    for on, flag, why in refused:
-        if on:
-            raise ValueError(
-                f"{flag} is not served for model {cfg.model!r}, which has "
-                f"recurrent (state-space) layers: {why}"
-            )
+
+
+_HAS = {"recurrent": "has recurrent (state-space) layers",
+        "latent_pages": "keeps pages of latents (MLA)"}
+
+
+def refuse_unserved(cfg: EngineConfig, model_cfg) -> None:
+    """Raise, naming the flag, for the first flag that is on and that one of
+    the model config's properties rules out."""
+    on_flags = [(flag, why) for on, flag, why in _refusals(cfg) if on]
+    for prop, has in _HAS.items():
+        if not getattr(model_cfg, prop, False):
+            continue
+        for flag, why in on_flags:
+            if prop in why:
+                raise ValueError(
+                    f"{flag} is not served for model {cfg.model!r}, which "
+                    f"{has}: {why[prop]}")
 
 
 def resolve_num_kv_blocks(
@@ -275,7 +307,9 @@ def resolve_num_kv_blocks(
     """Page count from the HBM budget (``--gpu-memory-utilization`` analogue).
 
     bytes/page = 2 (K+V) * L * bs * KH * hd * itemsize, divided by tp (kv
-    heads sharded over the tensor axis) and pp (layers sharded over stages).
+    heads sharded over the tensor axis) and pp (layers sharded over stages);
+    a model whose pages have another shape says their bytes itself
+    (``model_cfg.page_bytes``: latent rows).
     ``L`` counts the layers that hold pages (a hybrid model's attention
     layers alone: ``num_kv_layers``); a model with recurrent layers has its
     state pools taken off the budget first.
@@ -285,14 +319,17 @@ def resolve_num_kv_blocks(
     dtype_size = jax.numpy.dtype(cfg.kv_cache_dtype or model_cfg.dtype).itemsize
     tp = max(cfg.tensor_parallel_size, 1)
     pp = max(cfg.pipeline_parallel_size, 1)
-    page_bytes = (
-        2
-        * max(getattr(model_cfg, "num_kv_layers", model_cfg.num_layers) // pp, 1)
-        * cfg.block_size
-        * max(model_cfg.num_kv_heads // tp, 1)
-        * model_cfg.head_dim
-        * dtype_size
-    )
+    if hasattr(model_cfg, "page_bytes"):  # a page of the model's own shape
+        page_bytes = model_cfg.page_bytes(cfg.block_size, dtype_size)
+    else:
+        page_bytes = (
+            2
+            * max(getattr(model_cfg, "num_kv_layers", model_cfg.num_layers) // pp, 1)
+            * cfg.block_size
+            * max(model_cfg.num_kv_heads // tp, 1)
+            * model_cfg.head_dim
+            * dtype_size
+        )
     # local_devices, not devices: on a multi-host mesh devices()[0] may be
     # non-addressable here, and hosts that sized differently would diverge
     # in shape.

@@ -53,7 +53,7 @@ from ..ops.sampling import (
 from ..parallel.mesh import MeshConfig, build_mesh
 from .config import (
     EngineConfig,
-    refuse_for_recurrent,
+    refuse_unserved,
     resolve_num_kv_blocks,
     state_slot_count,
 )
@@ -124,8 +124,8 @@ class ModelRunner:
         # slot a sequence and a scratch slot for padding rows.
         self._recurrent = bool(getattr(self.model_cfg, "recurrent", False))
         self.state_slots = 0
+        refuse_unserved(cfg, self.model_cfg)
         if self._recurrent:
-            refuse_for_recurrent(cfg)
             self.state_slots = state_slot_count(cfg)
         # Rows a step appends to its packed tokens (the model's step_aux,
         # one for each name in its AUX_NAMES), summed here as they are
@@ -271,19 +271,23 @@ class ModelRunner:
 
         recurrent, scratch_slot = self._recurrent, self.state_slots
         aux_rows = self._aux_rows
+        # token_budget, for a model whose expert dispatch takes it: no step
+        # holds more real tokens than a prefill step's budget, however far
+        # its rows are padded.
+        budget = (
+            {"token_budget": max(cfg.max_prefill_tokens, cfg.max_num_seqs)}
+            if recurrent or getattr(model, "TOKEN_BUDGET", False) else {})
 
         def slots_of(batch, active=None):
-            """The state slot of each row, for a model that keeps any; a
-            row the caller knows to be padding goes to the scratch slot."""
+            """What a model class is told beside the batch: the state slot
+            of each row, for a model that keeps any (a row the caller knows
+            to be padding goes to the scratch slot), and the token budget."""
             if not recurrent:
-                return {}
+                return budget
             slots = batch["state_slots"]
             if active is not None:
                 slots = jnp.where(active, slots, scratch_slot)
-            # token_budget: no step holds more real tokens than a prefill
-            # step's budget, however far its rows are padded.
-            return {"state_slots": slots,
-                    "token_budget": max(cfg.max_prefill_tokens, cfg.max_num_seqs)}
+            return {"state_slots": slots, **budget}
 
         def with_aux(packed, kv_cache):
             """What the model's step reports beside its tokens rides the

@@ -232,6 +232,12 @@ class EngineMetrics:
             "expert layers evaluated by fetched steps: the denominator of "
             "a mean a layer and step over the pst:moe_* counters",
         )
+        self.mla_prefill_steps = counter(
+            "pst:mla_prefill_steps",
+            "fetched prefill steps of a latent-attention model, by the way "
+            "the chunk attended over its pages (expanded or absorbed)",
+            by=("path",),
+        )
         self.pipeline_breaks = counter(
             "pst:pipeline_breaks",
             "decode pipelines drained, by why the burst in flight could "
@@ -383,6 +389,10 @@ class EngineMetrics:
             (self.moe_layer_steps, "moe_layer_steps_total"),
         ):
             self._counter_to(metric, key, stats.get(key, 0))
+        for path in ("expanded", "absorbed"):
+            key = f"mla_prefill_steps_{path}_total"
+            if key in stats:
+                self._counter_to(self.mla_prefill_steps(path), key, stats[key])
         for why, total in stats.get("pipeline_breaks_total", {}).items():
             self._counter_to(
                 self.pipeline_breaks(why), f"pipeline_breaks:{why}", total

@@ -1440,8 +1440,9 @@ def load_hf_params(
 
 def config_from_hf_json(config_path: str, name: str = ""):
     """Build the model config of an HF ``config.json``: a
-    :class:`LlamaConfig`, or for ``model_type: nemotron_h`` the hybrid
-    class's own (``models/nemotron_h.py``)."""
+    :class:`LlamaConfig`, or a class's own for ``model_type: nemotron_h``
+    (``models/nemotron_h.py``) and ``glm4_moe_lite``
+    (``models/glm4_moe_lite.py``)."""
     with open(config_path) as f:
         hf = json.load(f)
     mt = hf.get("model_type", "llama")
@@ -1449,12 +1450,17 @@ def config_from_hf_json(config_path: str, name: str = ""):
         from .nemotron_h import config_from_hf
 
         return config_from_hf(hf, name)
+    if mt == "glm4_moe_lite":  # latent attention + gated experts
+        from .glm4_moe_lite import config_from_hf
+
+        return config_from_hf(hf, name)
     if mt not in (
         "llama", "mistral", "qwen2", "qwen3", "mixtral", "gemma", "gemma2",
     ):
         raise ValueError(
             f"unsupported model_type {mt!r} "
-            "(llama/mistral/qwen2/qwen3/mixtral/gemma/gemma2/nemotron_h)"
+            "(llama/mistral/qwen2/qwen3/mixtral/gemma/gemma2/nemotron_h/"
+            "glm4_moe_lite)"
         )
     eos = hf.get("eos_token_id", 2)
     eos_ids = tuple(eos) if isinstance(eos, list) else (eos,)

@@ -18,8 +18,8 @@ pattern string gives.
   down- and up-projection; one shared expert at full width. **The layer
   holds a share**: ``n_routed_experts`` of the ``router_experts`` the router
   scores, from ``expert_first`` on. Pairs routed to experts it does not hold
-  are dropped before the grouped products (``grouped_matmul``); the latent
-  up-projection is
+  are dropped before the grouped products (``moe_dispatch.py``, which the
+  latent-attention class calls too); the latent up-projection is
   applied to the partial sum (its peers' partial sums add up to the whole,
   ``tests/test_nemotron_h.py``) and the shared expert is added whole. There
   is no exchange here, and nothing stands in for the other ranks.
@@ -48,26 +48,14 @@ import jax.numpy as jnp
 import xxhash
 from jax.sharding import PartitionSpec as P
 
-from ..device import pallas_interpret
 from ..ops import ssm
 from ..ops.attention import paged_attention
-from . import llama
+from . import llama, moe_dispatch
+from .moe_dispatch import AUX_NAMES, AUX_WIDTH
 
 Params = Dict[str, Any]
 
 KINDS = {"M": "mamba", "*": "attn", "E": "moe"}
-# What a step reports beside its tokens (``step_aux``), summed over its ``E``
-# layers, under the names the engine's stats carry them by: routed pairs,
-# pairs this share holds, the pairs at its busiest expert, the held experts
-# that got a pair (whose weights the grouped products read), and the layers
-# counted (the denominator of a mean a layer and step).
-AUX_NAMES = (
-    "moe_pairs_routed_total", "moe_pairs_held_total",
-    "moe_busiest_expert_pairs_total", "moe_experts_touched_total",
-    "moe_layer_steps_total")
-AUX_WIDTH = len(AUX_NAMES)
-# Row tile of the grouped expert products: pair rows are padded to it.
-GROUP_ROWS = 128
 # Leaves kept one array a layer, not stacked over a kind's layers: a Pallas
 # call takes its operands whole, and a layer's slice of a stack would be
 # copied to a fresh buffer first (705 MB a bank at published widths).
@@ -221,26 +209,6 @@ def config_from_hf(hf: dict, name: str = "") -> NemotronHConfig:
         name=name or hf.get("_name_or_path", "nemotron_h"),
         eos_token_ids=tuple(eos) if isinstance(eos, list) else (eos,),
         bos_token_id=hf.get("bos_token_id"),
-    )
-
-
-def grouped_matmul(xs: jax.Array, bank: jax.Array, sizes: jax.Array) -> jax.Array:
-    """``xs [rows, k]`` sorted by expert, ``bank [experts, k, n]``, ``sizes
-    [experts]`` rows each -> float32 ``[rows, n]``; rows past the last group
-    are undefined. The grouped-matmul Pallas kernel that ships with JAX
-    (``megablox.gmm``) at tiles of up to 1,024: it streams each touched
-    expert's weights once (83 % of the HBM bound at 704 rows over
-    128 experts on a v5e, where ``lax.ragged_dot`` reaches 22-30 %; PERF.md
-    §6, PR 31). ``rows`` is a multiple of ``GROUP_ROWS``."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-    def tile(d: int) -> int:  # the widest multiple of 128 up to 1,024 in d
-        return next((t for t in range(1024, 0, -128) if d % t == 0), d)
-
-    return gmm(
-        xs, bank, sizes, preferred_element_type=jnp.float32,
-        tiling=(GROUP_ROWS, tile(bank.shape[1]), tile(bank.shape[2])),
-        interpret=pallas_interpret(),
     )
 
 
@@ -599,62 +567,33 @@ class NemotronH:
         """Router over all ``router_experts``: ``(ids [N, K], weights [N, K])``.
         Selection is by ``score + bias``; the weights are the scores alone."""
         cfg = self.cfg
-        s = jax.nn.sigmoid(jnp.einsum(
-            "nd,de->ne", u.astype(jnp.float32), lp["w_router"],
-            precision=jax.lax.Precision.HIGHEST))
-        _, ids = jax.lax.top_k(s + lp["router_bias"], cfg.num_experts_per_tok)
-        w = jnp.take_along_axis(s, ids, axis=-1)
-        if cfg.norm_topk_prob:
-            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
-        return ids, w * cfg.routed_scaling_factor
+        return moe_dispatch.route(
+            u, lp["w_router"], lp["router_bias"],
+            top_k=cfg.num_experts_per_tok, norm_topk_prob=cfg.norm_topk_prob,
+            scale=cfg.routed_scaling_factor)
 
     def routed_latent(self, lp, u: jax.Array, valid: jax.Array,
                       token_budget: Optional[int] = None):
         """This share's part of the routed sum, in the latent space
-        ``[N, latent]`` float32, and the step's ``[AUX_WIDTH]`` counts.
-        ``token_budget`` bounds the real tokens among the ``N`` (a prefill
-        step is padded to rows x longest chunk, several times its budget):
-        the grouped products then run over ``budget x K`` pairs at most,
-        which no held pair can fall outside."""
+        ``[N, latent]`` float32, and the step's ``[AUX_WIDTH]`` counts
+        (``moe_dispatch.routed_experts`` with this class's expert body:
+        ungated ``relu^2`` between two grouped products, in the latent)."""
         cfg = self.cfg
-        N = u.shape[0]
-        K, held = cfg.num_experts_per_tok, cfg.n_routed_experts
-        f32 = jnp.float32
-        with jax.named_scope("moe_router"):
-            ids, w = self.route(lp, u)
-            local = ids - cfg.expert_first
-            mine = (local >= 0) & (local < held) & valid[:, None]
-            # Pairs of experts held elsewhere (and of padding tokens) sort
-            # behind every group and belong to none: the grouped products
-            # do not reach them. Rows are padded to the kernel's row tile.
-            rows = min(N, token_budget or N) * K
-            rows = -(-rows // GROUP_ROWS) * GROUP_ROWS
-            key = jnp.where(mine, local, held).reshape(-1)
-            wflat = jnp.where(mine, w, 0.0).reshape(-1)
-            if rows > N * K:
-                key = jnp.pad(key, (0, rows - N * K), constant_values=held)
-                wflat = jnp.pad(wflat, (0, rows - N * K))
-            order = jnp.argsort(key)[:rows]
-            tok = jnp.minimum(order // K, N - 1)
-            sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
-            wsort = wflat[order]
         with jax.named_scope("moe_latent"):
             lat = jnp.einsum(
-                "nd,dl->nl", u, lp["w_latent_down"], preferred_element_type=f32
+                "nd,dl->nl", u, lp["w_latent_down"],
+                preferred_element_type=jnp.float32,
             ).astype(u.dtype)
-        xs = lat[tok]
-        with jax.named_scope("moe_experts"):
-            a = grouped_matmul(xs, lp["w1"], sizes)
-            a = jnp.square(jax.nn.relu(a)).astype(u.dtype)
-            y = grouped_matmul(a, lp["w2"], sizes)
-        # rows past the last group are whatever the kernel left there
-        y = jnp.where(wsort[:, None] != 0.0, y * wsort[:, None], 0.0)
-        acc = jnp.zeros((N, cfg.moe_latent_size), f32).at[tok].add(y)
-        stats = jnp.stack([  # in the order of AUX_NAMES
-            jnp.sum(valid).astype(f32) * K, jnp.sum(sizes).astype(f32),
-            jnp.max(sizes).astype(f32), jnp.sum(sizes > 0).astype(f32),
-            jnp.ones((), f32)])
-        return acc, stats
+
+        def body(xs, gmm):
+            a = gmm(xs, lp["w1"])
+            return gmm(jnp.square(jax.nn.relu(a)).astype(u.dtype), lp["w2"])
+
+        return moe_dispatch.routed_experts(
+            u, lat, valid, lp["w_router"], lp["router_bias"], body,
+            top_k=cfg.num_experts_per_tok, norm_topk_prob=cfg.norm_topk_prob,
+            scale=cfg.routed_scaling_factor, held=cfg.n_routed_experts,
+            expert_first=cfg.expert_first, token_budget=token_budget)
 
     def shared_expert(self, lp, u: jax.Array) -> jax.Array:
         f32 = jnp.float32

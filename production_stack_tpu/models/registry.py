@@ -12,6 +12,7 @@ import os
 from typing import Dict
 
 from .llama import Llama, LlamaConfig, config_from_hf_json
+from .glm4_moe_lite import Glm4MoeLite, Glm4MoeLiteConfig
 from .nemotron_h import NemotronH, NemotronHConfig
 
 # Architecture presets. Shapes match the public configs of each family so
@@ -284,6 +285,31 @@ PRESETS: Dict[str, LlamaConfig] = {
         bos_token_id=None,
         dtype="float32",
     ),
+    # Tiny latent-attention mixture of experts: one dense layer, two expert
+    # layers of 8 gated experts top 2 and a shared one, a 24 + 8 latent row.
+    "tiny-glm4-moe-lite-debug": Glm4MoeLiteConfig(
+        vocab_size=128,
+        hidden_size=64,
+        num_layers=3,
+        first_k_dense=1,
+        intermediate_size=96,
+        num_heads=4,
+        q_lora_rank=24,
+        kv_lora_rank=24,
+        qk_nope_head_dim=12,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        rope_theta=10000.0,
+        n_routed_experts=8,
+        router_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        routed_scaling_factor=1.8,
+        max_position_embeddings=2048,
+        name="tiny-glm4-moe-lite-debug",
+        eos_token_ids=(0,),
+        dtype="float32",
+    ),
 }
 
 
@@ -293,6 +319,8 @@ def model_for(model_cfg):
     the cache constructors and ``forward``."""
     if isinstance(model_cfg, NemotronHConfig):
         return NemotronH(model_cfg)
+    if isinstance(model_cfg, Glm4MoeLiteConfig):
+        return Glm4MoeLite(model_cfg)
     return Llama(model_cfg)
 
 

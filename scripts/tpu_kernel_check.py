@@ -16,6 +16,14 @@ At the Llama-3-8B layer shapes the serving path uses (32 Q / 8 KV heads x
     is fetched, a column that is not masked, or VMEM that nothing wrote
     reaching ``p @ V`` shows here and only here (the interpreter's buffers
     start clean);
+  - ``mla_decode`` (``ops/mla_attention.py``: absorbed latent attention, 20
+    heads over one ``[c_kv 512 | k_rope 64 | 0]`` row a token, bf16 pages)
+    at the latent cell's shape, 16 rows of which four are empty at 16-41k
+    of context, and at ragged lengths on every side of a page, a fold and
+    a chunk (1 to 6,145, the last chunk shorter than a fold), both on a
+    stacked cache at layer 2 of 3 with NaN in the other layers, in the
+    page every dead table entry points at and in the padding lanes of
+    that page — reference: gather the live rows, softmax in float32;
   - the fused decode-write variant behind ``PST_FUSED_KV_WRITE`` (each cell
     warms up its own first chunk) — reference: XLA scatter + gather
     attention, and the written cache rows; ``ragged`` gives its rows the
@@ -64,6 +72,7 @@ from production_stack_tpu.ops.int4_matmul import (  # noqa: E402
     int4_matmul,
     int4_matmul_stacked,
 )
+from production_stack_tpu.ops.mla_attention import mla_decode  # noqa: E402
 from production_stack_tpu.ops.paged_attention_pallas import (  # noqa: E402
     pallas_paged_attention,
     pallas_paged_attention_decode_write,
@@ -183,6 +192,59 @@ def cell_shape_case(name, *, B, kv_heads, kv_dtype, lo, hi, empty=()):
     }
 
 
+def mla_case(name, *, lens):
+    """``mla_decode`` as the latent cell calls it: 20 heads, rank 512 + 64
+    rotary lanes in rows of 640, a stacked cache read at a traced layer,
+    NaN wherever the kernel must not look."""
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    heads, rank, rope, lanes, layers, layer = 20, 512, 64, 640, 3, 2
+    lens = np.asarray(lens, np.int32)
+    B = len(lens)
+    width = -(-int(lens.max()) // BS) + 8
+    nb = B * width + 2
+    kv = np.zeros((nb, 1, BS, lanes), np.float32)
+    kv[..., :rank + rope] = rng.standard_normal((nb, 1, BS, rank + rope))
+    kv[1] = np.nan
+    kv = jnp.asarray(kv, jnp.bfloat16)
+    stack = jnp.stack([jnp.full_like(kv, np.nan)] * layer + [kv])
+    tables = (rng.permutation(B * width) + 2).reshape(B, width)
+    dead = np.arange(width)[None] >= -(-lens // BS)[:, None]
+    q = jnp.asarray(rng.standard_normal((B, heads, rank + rope)), jnp.bfloat16)
+    scale = 1.0 / 16.0
+    kern = jax.jit(lambda q, kv, t, l, ly: mla_decode(
+        q, kv, t, l, ly, rank=rank, scale=scale))
+    t0 = time.perf_counter()
+    got = np.asarray(kern(
+        q, stack, jnp.asarray(np.where(dead, 1, tables).astype(np.int32)),
+        jnp.asarray(lens), jnp.int32(layer)), np.float32)
+    compile_s = time.perf_counter() - t0
+
+    @jax.jit
+    def ref(q, rows, n):  # one sequence: gather, mask, softmax, weigh
+        s = jnp.einsum("hc,sc->hs", q.astype(jnp.float32),
+                       rows[:, :rank + rope].astype(jnp.float32),
+                       precision="highest") * scale
+        s = jnp.where(jnp.arange(rows.shape[0])[None] < n, s, -jnp.inf)
+        return jnp.einsum("hs,sc->hc", jax.nn.softmax(s, -1),
+                          rows[:, :rank].astype(jnp.float32),
+                          precision="highest")
+
+    worst, ref_max = 0.0, 0.0
+    for b in np.nonzero(lens)[0]:
+        pages = np.where(dead[b], 0, tables[b])
+        want = np.asarray(ref(q[b], kv[pages, 0].reshape(-1, lanes), lens[b]))
+        worst = max(worst, float(np.abs(got[b] - want).max()))
+        ref_max = max(ref_max, float(np.abs(want).max()))
+    return {
+        "max_abs_diff": worst,
+        "empty_rows_max_abs_diff": float(np.abs(got[lens == 0]).max(initial=0.0)),
+        "ref_abs_max": ref_max,
+        "kv_tokens": int(lens.sum()),
+        "bound": ATTN_BOUND,
+        "first_call_s": round(compile_s, 2),
+    }
+
+
 def fused_write_case(name, *, kv_dtype, ragged=False):
     """Decode step with the KV write folded into the kernel vs XLA scatter
     then gather attention; also compares the rows the kernel wrote."""
@@ -289,6 +351,12 @@ def cases():
         B=16, kv_heads=8, kv_dtype=fp8, lo=3000, hi=11000, empty=(5, 15))
     yield "attn_decode_cell_hybrid_b32_kh2_bf16", cell_shape_case, dict(
         B=32, kv_heads=2, kv_dtype=jnp.bfloat16, lo=1000, hi=2500)
+    docs = np.exp(np.linspace(np.log(16384), np.log(40960), 12)).astype(int)
+    yield "mla_decode_cell_b16_bf16", mla_case, dict(
+        lens=[0, *docs[:6], 0, 0, *(docs[6:] + 137), 0])
+    yield "mla_decode_ragged_b16_bf16", mla_case, dict(
+        lens=[1, 127, 128, 129, 0, 511, 512, 513, 2047, 2048, 2049, 2100,
+              4096, 4609, 6145, 0])
     for rows in (8, 1024):
         for din, dout in ((4096, 14336), (14336, 4096)):
             yield f"int4_matmul_n{rows}_{din}x{dout}", int4_case, dict(
