@@ -1,10 +1,23 @@
 """Pallas TPU flash kernels over paged KV: decode and chunked prefill.
 
 The hot ops of the serving loop (the role vLLM's CUDA PagedAttention +
-flash-attn kernels play behind the reference stack). Both are HBM-bandwidth
-bound at the reference's long-context protocol (20k-token histories, 32k
-max_model_len — ``BASELINE.md``), so the kernel is organized around DMA
-efficiency, not grid geometry:
+flash-attn kernels play behind the reference stack). The two are bound by
+different things and each is organized around its own bound:
+
+- **Decode is bound by its copies.** One query row a sequence against 3-11k
+  cached tokens is HBM bandwidth and nothing else, so that kernel is
+  organized around DMA efficiency, not grid geometry: it runs within 2 % of
+  its bare page copies (PERF.md §6, PR 32), and its ``p @ V`` keeps fp8
+  pages as they are (``_pv_dot``: V is the large operand there).
+- **Prefill is bound by its fold.** A chunk of 128-256 query positions x 4
+  grouped heads is 512-1,024 rows a KV head: a 1 MiB chunk is copied in
+  1.3 us and folded in tens, so what a call costs is what the fold does per
+  element of its ``[rows, 512]`` probability tile (``_chunked_flash``;
+  PERF.md §6, PR 34): pages are widened to bf16 once a chunk rather than the
+  tile rounded to e4m3 and back (the tile is the large operand here), and
+  query rows past a row's real length are not folded.
+
+Both stream pages the same way (``_page_dma_loop``):
 
 - KV lives in one combined page array ``[nb, 2, bs, KH*hd]`` (a page holds
   its K rows then V rows, each token row spanning **all** kv heads in the
@@ -50,7 +63,9 @@ Shapes:
   kv_lens     [B] int32            valid KV length per sequence (0 = padding)
   q_positions [B, T] int32         absolute position per query token; the
                                    prefill kernel uses row 0 (chunks are
-                                   consecutive positions — runner contract)
+                                   consecutive positions, of which those at
+                                   and past kv_len are padding and return
+                                   zeros — runner contract)
   layer       int32 scalar         layer to read (scalar-prefetched)
 """
 
@@ -70,6 +85,14 @@ from .attention import window_eff
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+# Two ``p @ V`` for two shapes whose needs conflict. In decode the
+# probabilities are ``[32, S]`` and V ``[S, 1024]`` is the large operand:
+# widening the streamed V costs more than the product, so ``_pv_dot`` splits
+# the probabilities in fp8 instead. In prefill the sides are reversed: the
+# probability tile has ``rows >= 2 x head_dim`` times a chunk's span and
+# the round trip over it was most of a call, so ``_chunked_flash`` hands
+# ``_pv_dot`` a V already widened to bf16 (``_fold_dtype`` chooses by the
+# shapes) and the split below is not taken.
 def _pv_dot(p, v):
     """probs @ V with fp32 accumulation, correct for quantized caches.
 
@@ -123,9 +146,26 @@ _DECODE_SLOTS = 3
 # The ring may take half of the call's 64 MiB of VMEM: pages of many KV
 # heads in two bytes get fewer pages a chunk (fp8 x 8 heads: 12 MiB).
 _DECODE_RING_BYTES = 32 * 1024 * 1024
-# Prefill's larger per-chunk compute amortizes a chunk's fixed cost
-# already, and its VMEM budget also carries the big q tile.
-_PREFILL_CHUNK_TOKENS = 512
+# Prefill is bound by its fold, not by its copies (3 % of a call), so its
+# chunk is sized for the fold: a sub-tile's row state (running maximum, sum,
+# the rescaled accumulator: 256 rows x 1 lane in 32 vregs each) is updated
+# once a chunk and costs 0.66 us, twice what a ``[256, 512]`` tile's two
+# products do, so longer chunks halve it; at 2,048 the dead columns of a
+# context's ragged last chunk, which are widened and folded like live ones,
+# cost more than that saves (standalone on a v5e, a 256-token bucket over
+# 5.4k / 7.0k cached tokens, us a call on the revision that still widened
+# with ``astype``: 512: 402 / 507; 1,024: 346 / 399; 2,048: 347 / 449;
+# ``scripts/tpu_prefill_attn_attrib.py``; PERF.md §6, PR 34). The ring
+# keeps two slots.
+_PREFILL_CHUNK_TOKENS = 1024
+# The ring's two slots and the fold's own copy of a chunk share this much
+# VMEM: pages of many KV heads in two bytes get fewer pages a chunk.
+_PREFILL_KV_BYTES = 16 * 1024 * 1024
+# Rows of one head a prefill sub-tile folds at a time (64 positions of a
+# group of four query heads): enough rows to keep the MXU full, and a
+# quarter of a 256-position bucket, so a bucket's padding is skipped in
+# quarters (128 rows: 511 us where 256 take 402; 512: 454; 1,024: 657).
+_PREFILL_SUB_ROWS = 256
 
 # Copies a chunk may hold: each is a descriptor, a semaphore and two
 # branches unrolled into the loop's body (pages far smaller than the 128
@@ -285,57 +325,172 @@ def _page_dma_loop(
             state[i] = st[i]
 
 
+def _real_positions(kv_len, first, q_tile: int):
+    """Query positions of a tile that hold a token. The runner pads a
+    chunk to a power of two and says how long the row really is:
+    ``kv_len`` is the chunk's last token + 1 (``engine/runner.py::
+    _prefill_batch``), so the positions at and past it are padding."""
+    return jnp.clip(kv_len - first, 0, q_tile)
+
+
+def _fold_dtype(kv_dtype, rows: int, head_dim: int):
+    """The dtype a chunk's K and V are folded in. e4m3 pages are widened to
+    bf16 once a chunk (exact: every e4m3 value is a bf16 value) where the
+    probability tile (``rows x span``) has at least the elements of the
+    chunk's K and V slices (``2 x span x head_dim``): then widening the
+    pages is the cheap side and ``_pv_dot``'s split over the probabilities
+    the dear one. A handful of query rows (a speculative verify step) is
+    decode's case and keeps the pages as they are."""
+    if kv_dtype == jnp.float8_e4m3fn and rows >= 2 * head_dim:
+        return jnp.dtype(jnp.bfloat16)
+    return jnp.dtype(kv_dtype)
+
+
+def _widen_e4m3(x8):
+    """``[S, n]`` e4m3 -> the two halves of ``[S, n]`` bf16, exactly, **rows
+    permuted** (``_widened_rows``). The compiler's own conversion unpacks
+    and repacks sublanes and took 12.9 us for 2 x 512 x 1,024 values, more
+    than the MXU needs to fold them; on the packed words it is 4.9 us
+    (PERF.md §6, PR 34). A 32-bit word of the input holds rows ``4r..4r+3``
+    of one lane, a byte each. Each byte is moved to where a float32 keeps
+    exponent and mantissa (``e + 120``; a subnormal as ``2^-6 (1 + m/8) -
+    2^-6``, which is why this goes through float32 at all), and the top
+    halves of two such words are one word of bf16. Which two rows share a
+    word is free, because attention sums over a chunk's tokens in any order
+    as long as K, V and the mask agree on it: bytes 0 and 1 make the first
+    half of the result, bytes 2 and 3 the second, and no value changes lane
+    or sublane. The two NaN bytes come out as 480; no live page holds one
+    (``_zero_values`` on what else a buffer may)."""
+    # ``lax`` primitives, not ``jnp`` operators: each operator is a nested
+    # ``jit`` to trace, and a step program's first use in a process pays for
+    # this body's trace whether or not its compilation is cached.
+    lax = jax.lax
+    w = pltpu.bitcast(x8, jnp.int32)  # [S/4, n]
+    # The four bytes of every word at once: [4, S/4, n], byte k on top.
+    top = jnp.stack([lax.shift_left(w, 24 - 8 * k) for k in range(3)] + [w])
+    mag = lax.shift_right_logical(lax.bitwise_and(top, 0x7F000000), 4)
+    sub = lax.lt(mag, 8 << 20)  # exponent field 0
+    f = lax.sub(
+        pltpu.bitcast(
+            lax.add(mag, lax.select(sub, lax.full_like(mag, 121 << 23),
+                                    lax.full_like(mag, 120 << 23))),
+            jnp.float32,
+        ),
+        lax.select(sub, lax.full_like(mag, 2.0**-6, jnp.float32),
+                   lax.full_like(mag, 0.0, jnp.float32)),
+    )
+    bits = lax.bitwise_or(
+        pltpu.bitcast(f, jnp.int32), lax.bitwise_and(top, -(1 << 31)))
+
+    def pair(lo, hi):  # rows (4r + k_lo, 4r + k_hi) -> rows (2r, 2r + 1)
+        return pltpu.bitcast(
+            lax.bitwise_or(
+                lax.shift_right_logical(lax.index_in_dim(bits, lo, keepdims=False), 16),
+                lax.bitwise_and(lax.index_in_dim(bits, hi, keepdims=False), -(1 << 16)),
+            ),
+            jnp.bfloat16,
+        )
+
+    return pair(0, 1), pair(2, 3)
+
+
+def _widened_rows(S: int):
+    """``[1, S]``: the chunk row that ``_widen_e4m3`` leaves at each row of
+    its result."""
+    i = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+    j = jax.lax.rem(i, S // 2)
+    return 4 * (j // 2) + 2 * (i // (S // 2)) + jax.lax.rem(j, 2)
+
+
 def _chunked_flash(
     *,
     live, layer, tables_ref, kv_hbm, buf, sems,
-    q_heads,  # list of KH arrays [R, hd] (native dtype)
-    bounds,  # [R, 1] exclusive per-row attention bound (causality + kv_len)
-    m_ref,  # [KH, R, 128] fp32 scratch (col 0 live)
-    l_ref,  # [KH, R, 128]
-    acc_ref,  # [KH, R, hd]
+    q_s,  # [KH, n_sub, Rs, hd] query rows, head-major (native dtype)
+    kv_s,  # [2, S, KH*hd] the chunk's K and V in the fold's dtype
+    m_ref,  # [KH, n_sub, Rs, 128] fp32 scratch (col 0 live)
+    l_ref,  # [KH, n_sub, Rs, 128]
+    acc_ref,  # [KH, n_sub, Rs, hd]
+    n_sub,  # sub-tiles that hold a real query row (traced)
+    first,  # the tile's first query position
+    kv_len,
+    win_eff,
     scale: float,
     block_size: int,
     chunk: int,
+    group: int,
     head_dim: int,
-    lows=None,  # [R, 1] inclusive per-row lower bound (sliding window)
     softcap: float = 0.0,
 ):
-    """Per-head flash accumulation over streamed KV chunks (the prefill
-    shape: R = Tq*G rows per head keep the MXU busy per head). Matmuls run
-    in the operands' native dtype with fp32 accumulation — MXU-native for
-    the bf16 serving path, exact for the fp32 oracle tests."""
-    hd = head_dim
-    KH = acc_ref.shape[0]
+    """Per-head flash accumulation over streamed KV chunks, the prefill
+    shape: many query rows a head, so the fold and not the stream is what
+    a call waits for (PERF.md §6, PR 34: the copies are 3 % of a call).
+
+    - The query tile is folded in sub-tiles of ``Rs`` rows
+      (``_PREFILL_SUB_ROWS``); the ``n_sub`` that hold a real row are
+      folded, the padding behind them is not.
+    - A chunk's K and V are laid out ``[S, KH*hd]`` once, e4m3 pages
+      widened to bf16 (``_fold_dtype``, ``_widen_e4m3``), so each head's
+      scores and its ``p @ V`` are one MXU product each in the operands' own
+      dtype with fp32 accumulation: bf16 probabilities over fp8 pages, what
+      decode's split product reaches in two; exact for the fp32 oracle
+      tests. With that the products set a sub-tile's pace and the masks
+      hide under them (measured: folding interior chunks without compares
+      and selects won 0.2 %), so every chunk is folded by the one masked
+      body."""
+    hd, G = head_dim, group
+    KH, _, Rs, _ = acc_ref.shape
+    S = chunk * block_size
+    widened = kv_s.dtype != buf.dtype
 
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    def fold(h, j, col):
+        lanes = pl.ds(pl.multiple_of(h * hd, hd), hd)  # head h's lanes
+        s = jax.lax.dot_general(
+            q_s[h, j], kv_s[0, :, lanes], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [Rs, S] fp32
+        if softcap:
+            s = jnp.tanh(s / softcap) * softcap
+        rows = jax.lax.broadcasted_iota(jnp.int32, (Rs, 1), 0)
+        q_pos = first + j * (Rs // G) + rows // G  # rows t*G+g: position t
+        seen = (col < jnp.minimum(q_pos + 1, kv_len)) & (
+            col >= q_pos + 1 - win_eff
+        )
+        s = jnp.where(seen, s, _NEG_INF)
+        m_prev = m_ref[h, j, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[h, j, :, :1] = alpha * l_ref[h, j, :, :1] + jnp.sum(
+            p, axis=-1, keepdims=True
+        )
+        m_ref[h, j, :, :1] = m_new
+        acc_ref[h, j] = acc_ref[h, j] * alpha + _pv_dot(p, kv_s[1, :, lanes])
+
     def compute(page, c):
-        S = chunk * block_size
-        col = c * S + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
-        for h in range(KH):
-            kh = page[:, 0, :, h * hd : (h + 1) * hd].reshape(S, hd)
-            vh = page[:, 1, :, h * hd : (h + 1) * hd].reshape(S, hd)
-            s = jax.lax.dot_general(
-                q_heads[h], kh, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [R, S] fp32
-            if softcap:
-                s = jnp.tanh(s / softcap) * softcap
-            live = col < bounds
-            if lows is not None:
-                live = live & (col >= lows)
-            s = jnp.where(live, s, _NEG_INF)
-            m_prev = m_ref[h, :, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[h, :, :1] = alpha * l_ref[h, :, :1] + jnp.sum(
-                p, axis=-1, keepdims=True
-            )
-            m_ref[h, :, :1] = m_new
-            acc_ref[h] = acc_ref[h] * alpha + _pv_dot(p, vh)
+        for i in range(2):  # K, V
+            x = page[:, i].reshape(S, KH * hd)
+            if widened:
+                kv_s[i, : S // 2], kv_s[i, S // 2 :] = _widen_e4m3(x)
+            else:
+                kv_s[i] = x
+        # The position each of the chunk's columns stands for.
+        col = c * S + (
+            _widened_rows(S) if widened
+            else jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+        )
+
+        # One body for every head and sub-tile: a step program traces and
+        # lowers it once (its first use in a process pays for that, cache
+        # or no cache).
+        def head_sub_tile(i, _):
+            fold(i // n_sub, jax.lax.rem(i, n_sub), col)
+            return 0
+
+        jax.lax.fori_loop(0, KH * n_sub, head_sub_tile, 0)
 
     _page_dma_loop(
         live=live, layer=layer, tables_ref=tables_ref, kv_hbm=kv_hbm,
@@ -596,7 +751,7 @@ def _prefill_kernel(
     q_ref,  # [1, Tq, H, hd] VMEM
     kv_hbm,  # [L, nb, 2, bs, KH*hd] ANY
     o_ref,  # [1, Tq, H, hd] VMEM
-    buf, sems, m_ref, l_ref, acc_ref,  # scratch
+    buf, sems, q_s, kv_s, m_ref, l_ref, acc_ref,  # scratch
     *,
     scale: float,
     block_size: int,
@@ -608,26 +763,23 @@ def _prefill_kernel(
 ):
     b = pl.program_id(0)
     tq = pl.program_id(1)
-    G, Tq, KH = group, q_tile, acc_ref.shape[0]
+    G, Tq = group, q_tile
+    KH, n_sub, Rs, _ = acc_ref.shape
     kv_len = lens_ref[b]
-    start = starts_ref[b]
 
-    # Rows t*G+g of each head cover absolute positions start + tq*Tq + t.
-    # The tile's causal horizon is its last row's position; pages past
-    # min(horizon+1, kv_len) are never fetched (≈ halves page traffic over a
-    # full prefill, while warm tiles near the sequence end still stream every
-    # live page — exactly the data they need).
-    limit = jnp.minimum(kv_len, start + (tq + 1) * Tq)
+    # Rows t*G+g of each head cover absolute positions first + t, of which
+    # the first ``real`` hold a token. The tile's causal horizon is its
+    # last real row's position; pages past it are never fetched (≈ halves
+    # page traffic over a full prefill, while warm tiles near the sequence
+    # end still stream every live page — exactly the data they need).
+    first = starts_ref[b] + tq * Tq
+    real = _real_positions(kv_len, first, Tq)
+    limit = first + real
     span = chunk * block_size
-
-    rows = jax.lax.broadcasted_iota(jnp.int32, (Tq * G, 1), 0)
-    q_pos = start + tq * Tq + rows // G  # [Tq*G, 1]
-    bounds = jnp.minimum(q_pos + 1, kv_len)
-    # Sliding window lower bounds; pages below the tile's FIRST row's
-    # window start are outside every row's window and are never fetched.
+    # Sliding window: pages below the tile's FIRST row's window start are
+    # outside every row's window and are never fetched.
     win_eff = window_eff(win_ref[0])
-    lows = jnp.maximum(q_pos + 1 - win_eff, 0)  # [Tq*G, 1]
-    tile_lo = jnp.maximum(start + tq * Tq + 1 - win_eff, 0)
+    tile_lo = jnp.maximum(first + 1 - win_eff, 0)
     live = _LiveRange(
         row=b, first_page=tile_lo // block_size,
         n_pages=(limit + block_size - 1) // block_size,
@@ -638,46 +790,62 @@ def _prefill_kernel(
     def _first_cell():
         _zero_values(buf)
 
-    qh = [
-        q_ref[0, :, h * G : (h + 1) * G, :].reshape(Tq * G, head_dim)
-        for h in range(KH)
-    ]
-    _chunked_flash(
-        live=live,
-        layer=layer_ref[0],
-        tables_ref=tables_ref,
-        kv_hbm=kv_hbm,
-        buf=buf,
-        sems=sems,
-        q_heads=qh,
-        bounds=bounds,
-        m_ref=m_ref,
-        l_ref=l_ref,
-        acc_ref=acc_ref,
-        scale=scale,
-        block_size=block_size,
-        chunk=chunk,
-        head_dim=head_dim,
-        lows=lows,
-        softcap=softcap,
-    )
-    # Padding rows (kv_len == 0) accumulated nothing: l stays 0 and the
-    # output is 0, matching the drop-slot contract.
-    for h in range(KH):
-        out = acc_ref[h] / jnp.maximum(l_ref[h, :, :1], 1e-20)  # [Tq*G, hd]
-        o_ref[0, :, h * G : (h + 1) * G, :] = out.reshape(
-            Tq, G, head_dim
-        ).astype(o_ref.dtype)
+    # A tile with no real row (a batch's padding row, the tiles past a
+    # chunk's real length) neither streams nor folds: zeros, the drop-slot
+    # contract.
+    @pl.when(real == 0)
+    def _padding():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(real > 0)
+    def _tile():
+        for h in range(KH):
+            q_s[h] = q_ref[0, :, h * G : (h + 1) * G, :].reshape(
+                n_sub, Rs, head_dim
+            )
+        _chunked_flash(
+            live=live,
+            layer=layer_ref[0],
+            tables_ref=tables_ref,
+            kv_hbm=kv_hbm,
+            buf=buf,
+            sems=sems,
+            q_s=q_s,
+            kv_s=kv_s,
+            m_ref=m_ref,
+            l_ref=l_ref,
+            acc_ref=acc_ref,
+            n_sub=(real * G + Rs - 1) // Rs,
+            first=first,
+            kv_len=kv_len,
+            win_eff=win_eff,
+            scale=scale,
+            block_size=block_size,
+            chunk=chunk,
+            group=group,
+            head_dim=head_dim,
+            softcap=softcap,
+        )
+        # Rows past the real length return zeros whether their sub-tile
+        # was folded beside real rows or not at all (l stays 0 there).
+        rows = jax.lax.broadcasted_iota(jnp.int32, (Tq * G, 1), 0)
+        is_real = rows // G < real
+        for h in range(KH):
+            out = acc_ref[h] / jnp.maximum(l_ref[h, :, :, :1], 1e-20)
+            out = jnp.where(is_real, out.reshape(Tq * G, head_dim), 0.0)
+            o_ref[0, :, h * G : (h + 1) * G, :] = out.reshape(
+                Tq, G, head_dim
+            ).astype(o_ref.dtype)
 
 
-def _scratch(C, bs, lanes, R, KH, hd, kv_dtype):
-    return [
-        pltpu.VMEM((2, C, 2, bs, lanes), kv_dtype),
-        pltpu.SemaphoreType.DMA((2, C)),
-        pltpu.VMEM((KH, R, 128), jnp.float32),
-        pltpu.VMEM((KH, R, 128), jnp.float32),
-        pltpu.VMEM((KH, R, hd), jnp.float32),
-    ]
+def _prefill_geometry(q_tile: int, group: int) -> "tuple[int, int]":
+    """(sub-tiles a query tile, rows a sub-tile): ``_PREFILL_SUB_ROWS``
+    rows of a head (whole positions, at least 8 of them) where that
+    divides the tile, else the tile whole."""
+    sub = max(_PREFILL_SUB_ROWS // group, 8)
+    if q_tile % sub:
+        sub = q_tile
+    return q_tile // sub, sub * group
 
 
 def _decode_geometry(q3, kv_pages, *, scale, softcap):
@@ -753,8 +921,12 @@ def _prefill_call(q, kv_pages, block_tables, kv_lens, starts, layer, window,
     _, nb, _, bs, lanes = kv_pages.shape
     KH = lanes // hd
     G = H // KH
-    C = _chunk_pages(bs, _PREFILL_CHUNK_TOKENS)
     n_tiles = T // q_tile
+    n_sub, Rs = _prefill_geometry(q_tile, G)
+    fold_dtype = _fold_dtype(kv_pages.dtype, q_tile * G, hd)
+    page_bytes = 2 * bs * lanes * (2 * kv_pages.dtype.itemsize + fold_dtype.itemsize)
+    C = min(_chunk_pages(bs, _PREFILL_CHUNK_TOKENS),
+            max(_PREFILL_KV_BYTES // page_bytes, 1))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
@@ -768,7 +940,15 @@ def _prefill_call(q, kv_pages, block_tables, kv_lens, starts, layer, window,
         out_specs=pl.BlockSpec(
             (1, q_tile, H, hd), lambda b, t, tt, l, s, ly, w: (b, t, 0, 0)
         ),
-        scratch_shapes=_scratch(C, bs, lanes, q_tile * G, KH, hd, kv_pages.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((2, C, 2, bs, lanes), kv_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, C)),
+            pltpu.VMEM((KH, n_sub, Rs, hd), q.dtype),
+            pltpu.VMEM((2, C * bs, lanes), fold_dtype),
+            pltpu.VMEM((KH, n_sub, Rs, 128), jnp.float32),
+            pltpu.VMEM((KH, n_sub, Rs, 128), jnp.float32),
+            pltpu.VMEM((KH, n_sub, Rs, hd), jnp.float32),
+        ],
     )
     kernel = functools.partial(
         _prefill_kernel,
@@ -788,8 +968,9 @@ def _prefill_call(q, kv_pages, block_tables, kv_lens, starts, layer, window,
             # Sequential, so that the first cell's ``_zero_values`` covers
             # the call (one TensorCore a chip on v5e: nothing is lost).
             dimension_semantics=("arbitrary", "arbitrary"),
-            # The 256-row q tile + 512-token KV chunks exceed the default
-            # 16 MiB scoped-vmem budget; the chip has far more.
+            # The 256-position q tile, its flash state and the KV chunks
+            # exceed the default 16 MiB scoped-vmem budget; the chip has
+            # far more.
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=pallas_interpret(),
@@ -823,8 +1004,9 @@ def pallas_paged_attention(
 
     # Chunk positions are consecutive from row 0's position (the runner
     # builds prefill batches that way), so the kernel derives causality from
-    # starts alone. Padding rows attend past their chunk; their outputs are
-    # discarded downstream (last_idx / dropped writes).
+    # starts alone, and a row's real length from kv_len: the positions at and
+    # past it are the bucket's padding, are not folded and return zeros
+    # (their outputs are discarded downstream: last_idx / dropped writes).
     # 256-row q tiles: every tile re-streams the sequence's earlier KV, so
     # at long context halving the tile count halves attention HBM traffic.
     q_tile = min(T, 256)

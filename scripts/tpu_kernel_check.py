@@ -11,7 +11,10 @@ At the Llama-3-8B layer shapes the serving path uses (32 Q / 8 KV heads x
   - decode at the two shapes the benchmark's cells run, on a stacked cache
     at layer 2 of 3: 16 rows over 8 KV heads of fp8 pages at 3-11k of
     context as drawn (ragged, two rows empty), and 32 rows over 2 KV heads
-    of bf16 pages at 1-2.5k. Every table entry past a row's last live page
+    of bf16 pages at 1-2.5k; prefill as the runner pads it: a 256-token
+    bucket holding 150 real tokens over 8k of fp8 context, and a 1,024-token
+    one holding 600 over 4k with a window and a soft cap (the padding rows
+    must come back as zeros). Every table entry past a row's last live page
     points at a page of NaN, and the other layers are NaN: a dead page that
     is fetched, a column that is not masked, or VMEM that nothing wrote
     reaching ``p @ V`` shows here and only here (the interpreter's buffers
@@ -192,6 +195,52 @@ def cell_shape_case(name, *, B, kv_heads, kv_dtype, lo, hi, empty=()):
     }
 
 
+def prefill_cell_case(name, *, T, real, start, kv_dtype, window=0,
+                      softcap=0.0):
+    """One prefill row as the runner pads it: ``real`` tokens of a
+    ``T``-token bucket after ``start`` cached ones, a stacked cache read at
+    a traced layer, NaN wherever the kernel must not look. The real rows
+    against gather; the padding rows must come back as zeros."""
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    layers, layer = 3, 2
+    kv_len = start + real
+    width = -(-(start + T) // BS) + 8
+    nb = width + 2
+    kv = rng.standard_normal((nb, 2, BS, KH * HD)).astype(np.float32)
+    kv[1] = np.nan
+    kv = jnp.asarray(kv, jnp.bfloat16).astype(kv_dtype)
+    stack = jnp.stack([jnp.full_like(kv, np.nan)] * layer + [kv])
+    tables = (rng.permutation(width) + 2).reshape(1, width)
+    first = max(start + 1 - window, 0) // BS if window else 0
+    page = np.arange(width)[None]
+    dead = (page >= -(-kv_len // BS)) | (page < first)
+    q = jnp.asarray(rng.standard_normal((1, T, H, HD)), jnp.bfloat16)
+    # The runner's padding: every position past the chunk repeats its last.
+    q_pos = jnp.asarray(
+        np.minimum(start + np.arange(T), kv_len - 1)[None].astype(np.int32))
+    lens = jnp.asarray([kv_len], jnp.int32)
+    kern = jax.jit(
+        lambda q, kv, t, l, p, ly: pallas_paged_attention(
+            q, kv, t, l, p, ly, scale=SCALE, window=window, softcap=softcap)
+    )
+    t0 = time.perf_counter()
+    got = np.asarray(kern(
+        q, stack, jnp.asarray(np.where(dead, 1, tables).astype(np.int32)),
+        lens, q_pos, jnp.int32(layer)), np.float32)
+    compile_s = time.perf_counter() - t0
+    want = _reference(
+        q, kv[None], jnp.asarray(np.where(dead, 0, tables).astype(np.int32)),
+        lens, q_pos, window, softcap)
+    return {
+        "max_abs_diff": float(np.abs(got[:, :real] - want[:, :real]).max()),
+        "padding_rows_max_abs_diff": float(
+            np.abs(got[:, real:]).max(initial=0.0)),
+        "ref_abs_max": float(np.abs(want[:, :real]).max()),
+        "bound": ATTN_BOUND,
+        "first_call_s": round(compile_s, 2),
+    }
+
+
 def mla_case(name, *, lens):
     """``mla_decode`` as the latent cell calls it: 20 heads, rank 512 + 64
     rotary lanes in rows of 640, a stacked cache read at a traced layer,
@@ -351,6 +400,11 @@ def cases():
         B=16, kv_heads=8, kv_dtype=fp8, lo=3000, hi=11000, empty=(5, 15))
     yield "attn_decode_cell_hybrid_b32_kh2_bf16", cell_shape_case, dict(
         B=32, kv_heads=2, kv_dtype=jnp.bfloat16, lo=1000, hi=2500)
+    yield "attn_prefill_cell_dense_t256_real150_fp8", prefill_cell_case, dict(
+        T=256, real=150, start=8192 - 37, kv_dtype=fp8)
+    yield "attn_prefill_t1024_real600_fp8_window_softcap", prefill_cell_case, dict(
+        T=1024, real=600, start=4096 + 71, kv_dtype=fp8, window=2048,
+        softcap=50.0)
     docs = np.exp(np.linspace(np.log(16384), np.log(40960), 12)).astype(int)
     yield "mla_decode_cell_b16_bf16", mla_case, dict(
         lens=[0, *docs[:6], 0, 0, *(docs[6:] + 137), 0])

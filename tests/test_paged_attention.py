@@ -51,7 +51,7 @@ def test_pallas_handles_empty_rows():
 
 
 # --- the page stream at the kernels' own geometry -------------------------
-# 128-token pages (8 a decode chunk, 4 a prefill chunk) over 64 lanes, so a
+# 128-token pages (8 a decode or a prefill chunk) over 64 lanes, so a
 # row has one, two or several chunks and the loop's second iteration, the
 # ragged last chunk and the hand-over between rows all run. Every table
 # entry the kernel has no business fetching (past the row's last live page,
@@ -65,10 +65,13 @@ _NAN_PAGE = 1
 
 
 def _stream_case(lens, *, T=1, window=0, dtype=jnp.float32, layers=1, layer=0,
-                 seed=0):
+                 seed=0, real=None, softcap=0.0):
+    """``real``: tokens a row's ``T``-token bucket really holds (the runner
+    pads a chunk to a power of two; ``lens`` ends at the last real one)."""
     rng = np.random.default_rng(seed)
     B = len(lens)
     lens = np.asarray(lens, np.int32)
+    real = np.full(B, T, np.int32) if real is None else np.asarray(real, np.int32)
     W = max(-(-int(lens.max()) // _BS), 1) + 3  # dead entries in every row
     nb = 2 + B * W
     kv = rng.standard_normal((layers, nb, 2, _BS, _KH * _HD)).astype(np.float32)
@@ -76,13 +79,13 @@ def _stream_case(lens, *, T=1, window=0, dtype=jnp.float32, layers=1, layer=0,
     kv[:layer] = np.nan  # a read of the wrong layer fails
     q = rng.standard_normal((B, T, _H, _HD), dtype=np.float32)
     tables = (2 + rng.permutation(B * W)).reshape(B, W).astype(np.int32)
-    q_pos = (lens - T)[:, None] + np.arange(T, dtype=np.int32)[None]
+    q_pos = (lens - real)[:, None] + np.arange(T, dtype=np.int32)[None]
     first = np.maximum(q_pos[:, 0] + 1 - window, 0) // _BS if window else 0
     page = np.arange(W)[None]
     dead = (page >= -(-lens // _BS)[:, None]) | (page < np.reshape(first, (-1, 1)))
     kv = jnp.asarray(kv).astype(dtype)
     rest = (jnp.asarray(lens), jnp.asarray(q_pos), layer)
-    kw = dict(scale=1.0 / np.sqrt(_HD), window=window)
+    kw = dict(scale=1.0 / np.sqrt(_HD), window=window, softcap=softcap)
     ref = gather_paged_attention(
         jnp.asarray(q), kv, jnp.asarray(np.where(dead, 0, tables)),
         *rest, **kw)
@@ -152,17 +155,109 @@ def test_pallas_decode_every_wait_meets_its_copy(case, monkeypatch):
 
 
 @pytest.mark.parametrize("case, kw", [
-    # 16 query rows ending at 716: the tile's limit leaves two of the
-    # second chunk's four pages dead
+    # 16 query rows ending at 716 and at 1,540: the tile's limit leaves
+    # two, and three, of the last chunk's eight pages dead
     ("limit_ends_mid_chunk", dict(lens=[716, 1540], T=16)),
     # window 300 over rows ending at 716: positions below 401 are outside
-    # every row's window, so the first chunk has one live page, its last
+    # every row's window, so the chunk's first three pages are dead too
     ("window_starts_mid_chunk", dict(lens=[716, 1540], T=16, window=300)),
 ])
 def test_pallas_prefill_streams_live_pages_only(case, kw):
     got, ref, _ = _stream_case(**kw)
     assert np.all(np.isfinite(got)), "a dead or foreign page reached the fold"
     np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
+
+
+# The prefill fold (PR 34): sub-tiles of 256 rows of a head (128 positions
+# at this group of 2), a chunk's K and V widened to bf16 once where pages
+# are e4m3 and the rows are many, nothing folded for the query positions
+# past a row's real length. A chunk spans 1,024 tokens here as on the chip.
+_PREFILL_FOLDS = {
+    # R = 512 rows a head against 2 x hd = 64: pages widened to bf16 (the
+    # chunk's rows permuted, the mask's columns with them), one p @ V
+    "fp8_pages_widened": dict(lens=[1500], T=256, dtype=jnp.float8_e4m3fn),
+    "fp8_pages_padded_bucket": dict(
+        lens=[1390], T=256, real=[150], dtype=jnp.float8_e4m3fn),
+    "fp8_pages_widened_window_softcap": dict(
+        lens=[2400], T=64, window=500, softcap=30.0, dtype=jnp.float8_e4m3fn),
+    # R = 32 < 64: the pages stay fp8 and the split product is kept
+    "fp8_pages_few_rows_keep_split": dict(
+        lens=[700, 90], T=16, dtype=jnp.float8_e4m3fn),
+    # start 1,100 is not a multiple of 1,024: chunk 0 is whole and live
+    # for every row, chunk 1 holds the causal boundary
+    "continuation_off_the_chunk_grid": dict(lens=[1164], T=64),
+    # window 700: chunk 0 is cut from below (three dead pages, then rows
+    # whose windows start at different columns), chunk 1 from above
+    "continuation_window_softcap": dict(
+        lens=[1164], T=64, window=700, softcap=30.0),
+    # window 300 over 2,900: chunks 0 and 1 are not fetched, chunk 2 is cut
+    # from below and from above at once
+    "window_inside_one_chunk": dict(lens=[2900], T=64, window=300),
+    "real_length_1": dict(lens=[901], T=256, real=[1]),
+    "real_length_sub_tile_edge": dict(lens=[1028], T=256, real=[128]),
+    "real_length_one_past_edge": dict(lens=[1029], T=256, real=[129]),
+    "real_length_full_bucket": dict(lens=[1156], T=256, real=[256]),
+    # two tiles of 256: the second holds no token, streams and folds nothing
+    "last_tile_all_padding": dict(lens=[800], T=512, real=[200]),
+    "empty_rows_beside_live": dict(
+        lens=[0, 1164, 0, 600], T=64, real=[0, 64, 0, 40]),
+    "fresh_prompt_in_four_tiles": dict(lens=[700], T=1024, real=[700]),
+}
+
+
+@pytest.mark.parametrize("case", list(_PREFILL_FOLDS))
+def test_pallas_prefill_folds_real_rows_only(case):
+    kw = _PREFILL_FOLDS[case]
+    got, ref, lens = _stream_case(**kw)
+    real = kw.get("real", [kw["T"]] * len(lens))
+    assert np.all(np.isfinite(got)), "a dead or foreign page reached the fold"
+    # float32 pages stay exact; fp8 pages carry bf16 probabilities
+    tol = 2e-2 if "dtype" in kw else 2e-5
+    for b, n in enumerate(real):
+        np.testing.assert_allclose(got[b, :n], ref[b, :n], rtol=tol, atol=tol)
+        assert np.all(got[b, n:] == 0.0), "padding rows return zeros"
+
+
+def test_widen_e4m3_is_exact_for_every_value():
+    """Every e4m3 byte but the two NaNs, subnormals and both zeros among
+    them, comes out as the bf16 of the same value, at the row
+    ``_widened_rows`` names."""
+    from production_stack_tpu.ops.paged_attention_pallas import (
+        _widen_e4m3, _widened_rows)
+
+    pats = np.array([b for b in range(256) if b & 0x7F != 0x7F], np.uint8)
+    S = 64
+    raw = np.random.default_rng(0).choice(pats, size=(S, 256))
+    raw[:, 0] = pats[:S]
+    raw[:, 1] = pats[S : 2 * S]
+    raw[:, 2] = pats[2 * S : 3 * S]
+    raw[: len(pats) - 3 * S, 3] = pats[3 * S :]
+    x = jax.lax.bitcast_convert_type(jnp.asarray(raw), jnp.float8_e4m3fn)
+    got = jnp.concatenate(jax.jit(_widen_e4m3)(x), axis=0)  # a kernel op: jit
+    assert got.dtype == jnp.bfloat16
+    rows = np.asarray(_widened_rows(S))[0]
+    assert sorted(rows) == list(range(S))
+    want = np.asarray(x.astype(jnp.float32))[rows]
+    got = np.asarray(got.astype(jnp.float32))
+    np.testing.assert_array_equal(got, want)
+    assert np.array_equal(np.signbit(got), np.signbit(want))  # -0.0 too
+
+
+def test_pallas_prefill_widens_pages_by_the_shapes_it_sees():
+    """One-byte pages are widened where the probability tile outweighs the
+    chunk's K and V slices, and only there; decode's split product stays
+    for a handful of rows, and wider pages are folded as they are."""
+    from production_stack_tpu.ops.paged_attention_pallas import _fold_dtype
+
+    fp8, bf16 = jnp.float8_e4m3fn, jnp.bfloat16
+    assert _fold_dtype(fp8, rows=256 * 4, head_dim=128) == bf16
+    assert _fold_dtype(fp8, rows=128 * 4, head_dim=128) == bf16
+    assert _fold_dtype(fp8, rows=4 * 4, head_dim=128) == fp8  # a verify step
+    assert _fold_dtype(bf16, rows=1024, head_dim=128) == bf16
+    assert _fold_dtype(jnp.float32, rows=1024, head_dim=128) == jnp.float32
+    # another one-byte format keeps the split product: the widening on the
+    # packed words reads e4m3's fields
+    assert _fold_dtype(jnp.float8_e5m2, rows=1024, head_dim=128) == jnp.float8_e5m2
 
 
 def _prefill_setup(B, T, start_offsets, H=8, KH=4, hd=32, nb=64, bs=8, W=8,
