@@ -398,6 +398,15 @@ def fleet_dashboard():
         ('sum(increase(pst_engine_flight_snapshots_persisted_total[1h]))',
          "snapshots persisted/h"),
     ], 4, 128))
+    # What held the step loop off (docs/observability.md "Flight
+    # recorder"): seconds past the bar by cause, and the collections'
+    # share of the wall beside them.
+    p.append(panel("Stalls by cause", [
+        ('sum(rate(pst_engine_stall_seconds_total[5m])) by (cause)',
+         "{{cause}} s stalled /s"),
+        ('sum(rate(pst_engine_gc_pause_seconds_total[5m]))',
+         "collections s paused /s"),
+    ], 12, 128))
 
     # Row 16 — Disagg (docs/disagg.md): the streamed P/D handoff's
     # health. Overlap p50 vs transfer p50 shows how much of the prefill

@@ -261,6 +261,7 @@ class AsyncLLMEngine:
 
     def _run(self) -> None:
         logger.info("engine step loop started")
+        ENGINE_TELEMETRY.watch_collections()
         if self._warming:
             # Precompile on the step thread: the asyncio loop keeps
             # serving /health and /ready while the lattice compiles, and

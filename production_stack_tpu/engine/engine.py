@@ -58,6 +58,9 @@ class RequestOutput:
     queue_time: Optional[float] = None
     prefill_time: Optional[float] = None
     decode_time: Optional[float] = None
+    # The stamp prefill_time ends at (time.monotonic() on the step thread):
+    # the server measures the first token's way to the socket from it.
+    first_token_time: Optional[float] = None
     # One entry per new token when SamplingParams.logprobs is set:
     # {"token_id", "logprob", "top": [(token_id, logprob), ...]}.
     logprobs: Optional[List[dict]] = None
@@ -1080,6 +1083,7 @@ class LLMEngine:
                 seq.first_token_time - scheduled
                 if scheduled is not None else None
             ),
+            first_token_time=seq.first_token_time,
             logprobs=[logprobs_entry] if logprobs_entry else None,
         )
         if finish_reason is not None:

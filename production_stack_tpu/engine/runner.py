@@ -82,14 +82,29 @@ def _fetch(arr, kind: str = "") -> np.ndarray:
     through ``jax.device_get`` (which returns the landed copy). The poll
     keeps the engine's step thread off a blocking transfer call so other
     Python threads run while the device finishes; the 0.3 ms interval was
-    chosen on an attachment that no longer exists and has not been timed
-    on a directly attached chip (ROADMAP D3). The whole of it is the
-    ``wait`` phase of the step of ``kind`` that asked."""
+    chosen on an attachment that no longer exists (ROADMAP D3). On the
+    benchmark's host, with the chip directly attached, a poll comes every
+    0.97 ms in the mean (``time.sleep(0.0003)`` alone takes 1.1 ms there:
+    a system call costs 6 us and the timer is coarse), 8 polls a decode
+    step of 19 ms, the longest gap of a step 1.2 ms in the median (one
+    run, PR 36). The whole of it is the ``wait`` phase of the step of
+    ``kind`` that asked. The polls are counted and the longest time between
+    two kept (``ENGINE_TELEMETRY.polled``): a long wait of many polls at
+    their pace is a device that stood still, one of a single long gap a
+    thread that was not let run."""
     with ENGINE_TELEMETRY.phase("wait", kind):
         arr.copy_to_host_async()
+        polls, gap_max = 0, 0.0
+        last = time.perf_counter()
         while not arr.is_ready():
             # pstlint: disable=async-blocking(0.3 ms device-readiness poll on the engine's dedicated step thread, never on an event loop)
             time.sleep(0.0003)
+            now = time.perf_counter()
+            polls += 1
+            if now - last > gap_max:
+                gap_max = now - last
+            last = now
+        ENGINE_TELEMETRY.polled(polls, gap_max)
         return np.asarray(jax.device_get(arr))
 
 

@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import argparse
 import asyncio
-import functools
 import json
 import os
 import time
@@ -666,10 +665,15 @@ def create_engine_app(
         queue_time: Optional[float],
         prefill_time: Optional[float],
         decode_time: Optional[float],
+        deliver_time: Optional[float] = None,
     ) -> None:
         """Replay the Sequence's TTFT decomposition as spans: queue wait →
-        prefill → decode, laid back-to-back ending now. Post-hoc so the
-        step thread never touches the recorder."""
+        prefill → decode, laid back-to-back ending now, and ``deliver``
+        from where prefill ends: the first token's way from the step
+        thread's stamp to the return of the socket write that carried it
+        (the hand-over to the event loop, its turn, the queue, text to
+        JSON, the write). Post-hoc so the step thread never touches the
+        recorder."""
         trace = request.get("trace")
         if trace is None:
             return
@@ -680,8 +684,23 @@ def create_engine_app(
             trace.record_span("engine_queue", queue_time, end_mono=end_queue)
         if prefill_time is not None:
             trace.record_span("prefill", prefill_time, end_mono=end_prefill)
+        if deliver_time is not None:
+            trace.record_span(
+                "deliver", deliver_time,
+                end_mono=end_prefill + max(deliver_time, 0.0),
+            )
         if decode_time is not None:
             trace.record_span("decode", decode_time, end_mono=now)
+
+    async def _send_json(
+        request: web.Request, payload: dict, headers: dict
+    ) -> web.Response:
+        """A JSON response whose whole body is written here, not after the
+        handler returns: the caller stamps the write's return."""
+        resp = web.json_response(payload, headers=headers)
+        await resp.prepare(request)
+        await resp.write_eof()
+        return resp
 
     def _attach_compile_events(request: web.Request, events) -> None:
         """Surface the XLA compiles a step absorbed as `compile` span
@@ -993,6 +1012,7 @@ def create_engine_app(
             await resp.prepare(request)
             n_out = 0
             last_out = None
+            deliver_time = None
             try:
                 if is_chat:
                     first = {
@@ -1055,6 +1075,8 @@ def create_engine_app(
                         if out.cost is not None:
                             chunk["usage"]["pst_cost"] = out.cost
                     await resp.write(f"data: {json.dumps(chunk)}\n\n".encode())
+                    if deliver_time is None and out.first_token_time is not None:
+                        deliver_time = time.monotonic() - out.first_token_time
                 await resp.write(b"data: [DONE]\n\n")
             except (ConnectionResetError, asyncio.CancelledError):
                 await engine.abort(rid)
@@ -1080,7 +1102,7 @@ def create_engine_app(
             if last_out is not None:
                 _record_engine_stages(
                     request, last_out.queue_time, last_out.prefill_time,
-                    last_out.decode_time,
+                    last_out.decode_time, deliver_time,
                 )
             metrics.e2e.observe(time.time() - start)
             metrics.success.inc()
@@ -1104,10 +1126,6 @@ def create_engine_app(
             if trace is not None:
                 trace.add_event("deadline_shed", stage="engine_scheduler")
             return _deadline_error()
-        _record_engine_stages(
-            request, result["queue_time"], result["prefill_time"],
-            result["decode_time"],
-        )
         _attach_compile_events(request, result.get("compile_events"))
         usage = {
             "prompt_tokens": len(ids),
@@ -1134,7 +1152,19 @@ def create_engine_app(
             "created": created, "model": req.model,
             "choices": [choice], "usage": usage,
         }
-        return web.json_response(payload, headers=headers)
+        resp = await _send_json(request, payload, headers)
+        _record_stages_of(request, result)
+        return resp
+
+    def _record_stages_of(request: web.Request, result: dict) -> None:
+        """The stages of a collected generation whose body has just been
+        written: unstreamed, the first token leaves with everything else."""
+        first = result["first_token_time"]
+        _record_engine_stages(
+            request, result["queue_time"], result["prefill_time"],
+            result["decode_time"],
+            time.monotonic() - first if first is not None else None,
+        )
 
     async def _collect(gen) -> dict:
         """Drain one generation stream into text/tokens/logprobs/finish
@@ -1145,7 +1175,7 @@ def create_engine_app(
         compile_events: List[dict] = []
         finish_reason = None
         cost = None
-        queue_time = prefill_time = decode_time = None
+        queue_time = prefill_time = decode_time = first_token_time = None
         async for out in gen:
             if out.num_output_tokens == 1 and out.ttft is not None:
                 metrics.ttft.observe(out.ttft)
@@ -1164,12 +1194,13 @@ def create_engine_app(
             decode_time = (
                 out.decode_time if out.decode_time is not None else decode_time
             )
+            first_token_time = first_token_time or out.first_token_time
         return {
             "text": "".join(text_parts), "token_ids": token_ids,
             "logprobs": lp_entries, "finish_reason": finish_reason,
             "queue_time": queue_time, "prefill_time": prefill_time,
-            "decode_time": decode_time, "compile_events": compile_events,
-            "cost": cost,
+            "decode_time": decode_time, "first_token_time": first_token_time,
+            "compile_events": compile_events, "cost": cost,
         }
 
     def _build_choice(req, result, index, is_chat, echo, prompt_ids) -> dict:
@@ -1243,14 +1274,8 @@ def create_engine_app(
             return _error(str(e))
         if any(r["finish_reason"] == "deadline" for r in results):
             return _deadline_error()
-        # Stage decomposition from the first candidate (all candidates
-        # share admission and the KV-shared prompt prefill; recording one
-        # keeps engine_queue/prefill/decode counts 1:1 with requests).
-        _record_engine_stages(
-            request, results[0]["queue_time"], results[0]["prefill_time"],
-            results[0]["decode_time"],
-        )
-        _attach_compile_events(request, results[0].get("compile_events"))
+        first = results[0]
+        _attach_compile_events(request, first.get("compile_events"))
         # OpenAI bills EVERY best_of candidate in completion_tokens.
         sampled_tokens = sum(len(r["token_ids"]) for r in results)
         if rank:
@@ -1282,7 +1307,12 @@ def create_engine_app(
             ],
             "usage": usage,
         }
-        return web.json_response(payload, headers={"X-Request-Id": rid})
+        resp = await _send_json(request, payload, {"X-Request-Id": rid})
+        # Stage decomposition from the first candidate (all candidates
+        # share admission and the KV-shared prompt prefill; recording one
+        # keeps engine_queue/prefill/decode counts 1:1 with requests).
+        _record_stages_of(request, first)
+        return resp
 
     # -- embeddings / rerank / score ----------------------------------
 
@@ -1557,14 +1587,15 @@ def create_engine_app(
             options.python_tracer_level = 0
             options.host_tracer_level = 2
             loop = asyncio.get_running_loop()
+
+            def start():
+                # the step loop holds no cycle to its bar meanwhile
+                with ENGINE_TELEMETRY.profiler_starting():
+                    jax.profiler.start_trace(
+                        out_dir, profiler_options=options)
+
             t0 = time.perf_counter()
-            await loop.run_in_executor(
-                None,
-                functools.partial(
-                    jax.profiler.start_trace, out_dir,
-                    profiler_options=options,
-                ),
-            )
+            await loop.run_in_executor(None, start)
             start_s = time.perf_counter() - t0
             try:
                 await asyncio.sleep(duration_ms / 1000.0)

@@ -21,6 +21,8 @@ registers it, and the fake engine can serve the same names as plain text.
 
 from __future__ import annotations
 
+import contextlib
+import gc
 import itertools
 import threading
 import time
@@ -35,6 +37,10 @@ from prometheus_client import (
     generate_latest,
 )
 
+from ..logging_utils import init_logger
+from .flight import NULL_FLIGHT_RECORDER, PHASE_AT, STALL_CAUSES
+
+logger = init_logger(__name__)
 
 ENGINE_TELEMETRY_REGISTRY = CollectorRegistry()
 
@@ -98,6 +104,45 @@ step_phase_seconds = Histogram(
     ["phase", "kind"],
     registry=ENGINE_TELEMETRY_REGISTRY,
     buckets=_PHASE_BUCKETS,
+)
+step_offcpu_seconds = Histogram(
+    "pst_engine_step_offcpu_seconds",
+    "Off-CPU time of the step thread in one cycle of the step loop (an "
+    "intake and the step after it) outside the wait phase: wall less the "
+    "thread's own CPU time over intake, schedule, batch_build, launch and "
+    "postprocess, observed once when the step ends, by step kind. There "
+    "the thread never sleeps, so this is time it waited for the "
+    "interpreter lock or for a core",
+    ["kind"],
+    registry=ENGINE_TELEMETRY_REGISTRY,
+    buckets=_PHASE_BUCKETS,
+)
+stalls_total = Counter(
+    "pst_engine_stalls",
+    "Cycles of the step loop (one intake and the step after it) past the "
+    "flight recorder's bar, by what held the step thread off: compile, gc, "
+    "device, machine, interpreter, host_work, unknown",
+    ["cause"],
+    registry=ENGINE_TELEMETRY_REGISTRY,
+)
+stall_seconds_total = Counter(
+    "pst_engine_stall_seconds",
+    "Seconds stalled cycles took beyond the rolling median of their "
+    "(kind, bucket), by cause",
+    ["cause"],
+    registry=ENGINE_TELEMETRY_REGISTRY,
+)
+# Every cause from the start: a window without a stall reads 0, not nothing.
+_stall_children = {
+    cause: (stalls_total.labels(cause=cause),
+            stall_seconds_total.labels(cause=cause))
+    for cause in STALL_CAUSES
+}
+gc_pause_seconds_total = Counter(
+    "pst_engine_gc_pause_seconds",
+    "Seconds the interpreter's collections of generations 1 and 2 paused "
+    "the engine's process (every thread of it stands still meanwhile)",
+    registry=ENGINE_TELEMETRY_REGISTRY,
 )
 batch_fill_ratio = Histogram(
     "pst_engine_batch_fill_ratio",
@@ -232,9 +277,11 @@ class _Phase:
     profiler's trace (host and device then share a clock) and, on exit,
     its wall time in ``pst_engine_step_phase_seconds``. ``kind`` may be set
     until the phase closes (a step learns its kind while it runs); the
-    trace's copy of it is fixed at entry."""
+    trace's copy of it is fixed at entry. A ``wait`` also takes the
+    thread's CPU clock at both ends: the cycle's account leaves the wait's
+    share out (see :class:`_Cycle`)."""
 
-    __slots__ = ("_tel", "name", "kind", "_ann", "_t0")
+    __slots__ = ("_tel", "name", "kind", "_ann", "_t0", "_c0")
 
     def __init__(self, tel: "EngineTelemetry", name: str, kind: str, meta: dict):
         self._tel = tel
@@ -246,15 +293,54 @@ class _Phase:
 
     def __enter__(self) -> "_Phase":
         if self.name == "step":
-            self._tel._step, self._tel._step_tid = self, threading.get_ident()
+            self._tel._step_opened(self)
+        elif self.name == "intake":
+            self._tel._cycle_opened()
         self._ann.__enter__()
+        if self.name == "wait":
+            self._c0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         dt = time.perf_counter() - self._t0
+        cpu = time.thread_time() - self._c0 if self.name == "wait" else 0.0
         self._ann.__exit__(*exc)
-        self._tel._phase_done(self, dt)
+        self._tel._phase_done(self, dt, cpu)
+
+
+class _Cycle:
+    """What one cycle of the step loop (one ``pst.intake`` and the
+    ``pst.step`` after it) has gathered so far for its flight record.
+
+    Whether the thread ran is known for the cycle, not for each phase: a
+    read of a CPU clock is a system call (6 us on the benchmark's host,
+    where the clocks also tick in steps of 10 ms), so the step thread's
+    clock is read where a cycle ends, which is where the next begins, and
+    around each wait: four reads a cycle with the process's clock. The
+    wall clock is read at the same places, so a cycle's wall and its CPU
+    time cover the same stretch: from the end of the cycle before (its
+    record's making included) to the end of this one."""
+
+    __slots__ = ("t0", "thread_cpu0", "process_cpu0", "profiler_starts0",
+                 "phases", "wait_cpu_s", "dispatches", "gc_s", "polls",
+                 "poll_gap_max_s")
+
+    def __init__(self, t0: float, thread_cpu0: float, process_cpu0: float,
+                 profiler_starts0: int):
+        self.t0 = t0
+        self.thread_cpu0 = thread_cpu0
+        self.process_cpu0 = process_cpu0
+        self.profiler_starts0 = profiler_starts0
+        # {(phase, kind): wall}: the intake, then the step's phases summed
+        # over their spans
+        self.phases: Dict[Tuple[str, str], float] = {}
+        self.wait_cpu_s = 0.0
+        # (kind, bucket, seconds, compiled, tokens) of each live dispatch
+        self.dispatches: List[tuple] = []
+        self.gc_s = 0.0
+        self.polls = 0
+        self.poll_gap_max_s = 0.0
 
 
 # Fresh runners must re-count compiles even when an earlier runner in the
@@ -292,16 +378,31 @@ class EngineTelemetry:
         # monitoring listener precompile.configure_compile_cache installs).
         self._cache_hits = 0
         self._cache_misses = 0
-        # The step phase open on the step thread, and the wall its inner
-        # phases have summed so far: {(phase, kind): seconds}.
+        # The step phase open on the step thread, and by thread the cycle
+        # its step closes (opened by the intake before it, or by the step
+        # itself where nothing runs an intake). By thread: a second engine
+        # of the process, idling, opens and drops a cycle every 50 ms.
         self._step: Optional[_Phase] = None
         self._step_tid = 0
-        self._step_acc: Dict[Tuple[str, str], float] = {}
+        self._cycles: Dict[int, _Cycle] = {}
+        # (thread, wall clock, its CPU clock, the process's) where the last
+        # cycle ended: the next cycle of that thread starts from them.
+        self._cpu_mark: Optional[Tuple[int, float, float, float]] = None
+        # What the off-CPU histogram still owes: a cycle whose CPU clock
+        # ticked past its wall reads below zero, and the next pays for it.
+        self._offcpu_carry = 0.0
         self._phase_children: Dict[Tuple[str, str], object] = {}
-        # Flight-recorder sink (obs/flight.py): every live dispatch
-        # forwards one ring record; the null recorder makes this free.
-        from .flight import NULL_FLIGHT_RECORDER
-
+        self._offcpu_children: Dict[str, object] = {}
+        # Start of the collection that is running (perf_counter; 0 when
+        # none is), its pst.gc span where it runs on the step thread, and
+        # whether a cycle that closed meanwhile has already counted it.
+        self._gc_t0 = 0.0
+        self._gc_span = None
+        self._gc_counted = False
+        # Twice the captures begun, and one more while one is starting.
+        self._profiler_starts = 0
+        # Flight-recorder sink (obs/flight.py): one ring record a cycle of
+        # the step loop; the null recorder makes this free.
         self._flight = NULL_FLIGHT_RECORDER
         # Live-traffic step-wall accumulator (host-timed) — the denominator
         # the cost attribution audit sums request costs against.
@@ -351,13 +452,7 @@ class EngineTelemetry:
         """Install the engine's flight recorder as the dispatch sink
         (obs/flight.py). One recorder per engine; re-attachment replaces
         (fresh engines in one process must not write a dead ring)."""
-        from .flight import NULL_FLIGHT_RECORDER
-
         self._flight = recorder if recorder is not None else NULL_FLIGHT_RECORDER
-
-    @property
-    def flight(self):
-        return self._flight
 
     def device_busy_seconds(self) -> float:
         """Cumulative live-traffic dispatch wall since process start (or
@@ -413,19 +508,27 @@ class EngineTelemetry:
                     "seconds": round(seconds, 3),
                 })
             if tokens > 0:
+                # Appended here, summed at the scrape (refresh_from_stats):
+                # nobody reads the gauge in between.
                 now = time.monotonic()
                 self._tok_samples.append((now, kind, tokens))
-                self._refresh_throughput_locked(now)
+                self._drop_old_samples_locked(now)
             if count_busy:
                 self._device_busy_s += seconds
         if count_busy:
             device_busy_seconds.inc(seconds)
-            # Flight ring (obs/flight.py): one bounded record per live
-            # dispatch, with the scheduler/KV state the engine's probe
-            # supplies — the post-mortem trail for any step that stalls.
-            self._flight.record_step(
-                kind, batch_bucket, seconds, compiled=compiled, tokens=tokens
-            )
+            # Flight ring (obs/flight.py): a live dispatch of the open step
+            # rides its cycle's record; any other (an embedding's encode)
+            # is a record of its own.
+            cycle = self._open_cycle()
+            if cycle is not None:
+                cycle.dispatches.append(
+                    (kind, batch_bucket, seconds, compiled, tokens))
+            else:
+                self._flight.record_step(
+                    kind, batch_bucket, seconds, compiled=compiled,
+                    tokens=tokens,
+                )
         if compiled:
             compile_total.labels(kind=kind, shape_bucket=batch_bucket).inc()
             compile_seconds.labels(kind=kind).observe(seconds)
@@ -482,9 +585,45 @@ class EngineTelemetry:
         entry, so ``pst.step`` cannot), and the step's ``kind`` label."""
         with _annotation("pst.step_info", kind=kind, **meta):
             pass
-        step = self._step
-        if step is not None and threading.get_ident() == self._step_tid:
-            step.kind = kind
+        if self._on_step_thread():
+            self._step.kind = kind
+
+    def _on_step_thread(self) -> bool:
+        """Whether the caller is the thread that has a step open."""
+        return self._step is not None and threading.get_ident() == self._step_tid
+
+    def _open_cycle(self) -> Optional[_Cycle]:
+        """The cycle of the step open on the calling thread, if one is."""
+        return self._cycles.get(self._step_tid) if self._on_step_thread() else None
+
+    def _cycle_opened(self, follows: bool = True) -> None:
+        """A cycle starts where the last one of this thread ended (all
+        three clocks from one mark), unless the loop idled in between,
+        nothing ended here yet, or no loop runs the steps (``follows``
+        false: what lies between two steps is their caller's then)."""
+        tid = threading.get_ident()
+        mark = self._cpu_mark
+        if follows and mark is not None and mark[0] == tid:
+            self._cpu_mark = None
+            self._cycles[tid] = _Cycle(*mark[1:], self._profiler_starts)
+        else:
+            self._cycles[tid] = _Cycle(
+                time.perf_counter(), time.thread_time(), time.process_time(),
+                self._profiler_starts)
+
+    def _step_opened(self, step: _Phase) -> None:
+        self._step, self._step_tid = step, threading.get_ident()
+        if self._step_tid not in self._cycles:  # no intake preceded the step
+            self._cycle_opened(follows=False)
+
+    def polled(self, polls: int, gap_max_s: float) -> None:
+        """How a fetch of the open step waited (engine/runner.py
+        ``_fetch``): the polls it made and the longest time between two."""
+        cycle = self._open_cycle()
+        if cycle is not None:
+            cycle.polls += polls
+            if gap_max_s > cycle.poll_gap_max_s:
+                cycle.poll_gap_max_s = gap_max_s
 
     def _observe_phase(self, name: str, kind: str, seconds: float) -> None:
         child = self._phase_children.get((name, kind))
@@ -494,21 +633,145 @@ class EngineTelemetry:
             )
         child.observe(seconds)
 
-    def _phase_done(self, phase: _Phase, seconds: float) -> None:
+    def _phase_done(self, phase: _Phase, seconds: float, cpu_s: float) -> None:
         if phase.name == "step":
-            acc, self._step_acc, self._step = self._step_acc, {}, None
-            for (name, kind), total in acc.items():
-                self._observe_phase(name, kind, total)
-        elif self._step is not None and threading.get_ident() == self._step_tid:
+            self._step = None
+            cycle = self._cycles.pop(threading.get_ident(), None)
+            if cycle is not None:  # None: reset_for_tests under an open step
+                for (name, kind), wall in cycle.phases.items():
+                    if name != "intake":  # observed when it closed
+                        self._observe_phase(name, kind, wall)
+                self._cycle_done(cycle, phase.kind)
+        elif (cycle := self._open_cycle()) is not None:
             key = (phase.name, phase.kind)
-            self._step_acc[key] = self._step_acc.get(key, 0.0) + seconds
+            cycle.phases[key] = cycle.phases.get(key, 0.0) + seconds
+            cycle.wait_cpu_s += cpu_s
             return
+        elif phase.name == "intake":
+            cycle = self._cycles.get(threading.get_ident())
+            if cycle is not None:
+                cycle.phases[("intake", "")] = seconds
+        elif phase.name == "no_work":
+            # the intake before it found nothing to step
+            self._cycles.pop(threading.get_ident(), None)
         self._observe_phase(phase.name, phase.kind, seconds)
 
-    def _refresh_throughput_locked(self, now: float) -> None:
+    def _cycle_done(self, cycle: _Cycle, kind: str) -> None:
+        """The cycle's account, to the off-CPU histogram and to the flight
+        recorder; a cycle past the recorder's bar is a stall, and leaves
+        its counters, one WARNING line and (under a capture) a zero-length
+        ``pst.stall`` span."""
+        now = time.perf_counter()
+        thread_cpu, process_cpu = time.thread_time(), time.process_time()
+        self._cpu_mark = (threading.get_ident(), now, thread_cpu, process_cpu)
+        cycle_s = now - cycle.t0
+        sums = [0.0] * len(PHASE_AT)
+        for (name, _), wall in cycle.phases.items():
+            sums[PHASE_AT[name]] += wall
+        # Outside its waits the thread wants the CPU throughout.
+        thread_cpu_s = thread_cpu - cycle.thread_cpu0 - cycle.wait_cpu_s
+        offcpu = cycle_s - sums[PHASE_AT["wait"]] - thread_cpu_s
+        owed = offcpu + self._offcpu_carry
+        self._offcpu_carry = min(owed, 0.0)
+        child = self._offcpu_children.get(kind)
+        if child is None:
+            child = self._offcpu_children[kind] = (
+                step_offcpu_seconds.labels(kind=kind))
+        child.observe(max(owed, 0.0))
+        gc_s = cycle.gc_s
+        if self._gc_t0:
+            # A collection on another thread that has not reported yet: it
+            # is done (this thread runs again) and its callback waits for
+            # the interpreter lock this thread took from it.
+            gc_s += now - self._gc_t0
+            self._gc_counted = True
+        starts = self._profiler_starts
+        stall = self._flight.record_cycle(
+            cycle.dispatches, cycle_s,
+            (*sums, max(offcpu, 0.0), max(thread_cpu_s, 0.0),
+             process_cpu - cycle.process_cpu0, gc_s, cycle.polls,
+             cycle.poll_gap_max_s),
+            kind=kind,
+            # a capture that started under the cycle held it, not the engine
+            held_to_bar=not (starts & 1 or starts != cycle.profiler_starts0),
+        )
+        if stall is None:
+            return
+        count, seconds = _stall_children[stall["cause"]]
+        count.inc()
+        seconds.inc(stall["excess_s"])
+        logger.warning(
+            "stall %.2f s in %s of %s: %s (%s polls, longest gap %.1f ms, "
+            "gc %.2f s, off-CPU %.2f s, thread CPU %.2f s, process CPU "
+            "%.2f s; waiting %d, running %d)",
+            stall["excess_s"], stall["phase"],
+            f"{stall['kind']} {stall['bucket']}".strip(), stall["cause"],
+            f"{stall['polls']:,}", stall["poll_gap_max_s"] * 1e3,
+            stall["gc_s"], stall["offcpu_s"], stall["thread_cpu_s"],
+            stall["process_cpu_s"], stall["waiting"], stall["running"],
+        )
+        with _annotation(
+            "pst.stall", cause=stall["cause"], phase=stall["phase"],
+            excess_ms=round(stall["excess_s"] * 1e3, 3),
+        ):
+            pass
+
+    @contextlib.contextmanager
+    def profiler_starting(self):
+        """Around ``jax.profiler.start_trace`` (``POST /debug/profile``).
+        Starting a capture keeps the interpreter lock for some 50 ms on the
+        benchmark's host: a stall in every window that is profiled and in
+        no other, of the measurement's making. A cycle it falls into is
+        recorded and held to no bar."""
+        self._profiler_starts += 1
+        try:
+            yield
+        finally:
+            self._profiler_starts += 1
+
+    # -- collections (pst_engine_gc_pause_seconds_total, pst.gc) ---------
+
+    def watch_collections(self) -> None:
+        """Time the interpreter's collections from inside the program;
+        called where the step thread starts. One ``gc.callbacks`` entry
+        however often it is asked for."""
+        if self._on_collection not in gc.callbacks:
+            gc.callbacks.append(self._on_collection)
+
+    def _on_collection(self, phase: str, info: dict) -> None:
+        """A collection holds the interpreter lock from "start" to "stop",
+        on whichever thread tripped it, and no other starts meanwhile. The
+        callbacks themselves are Python: a thread that has waited out the
+        collection takes the lock at their first instruction, so a cycle
+        may close between the collection's end and its "stop"
+        (``_cycle_done`` counts it then, and says so)."""
+        if info["generation"] == 0:
+            return
+        if phase == "start":
+            if self._on_step_thread():
+                self._gc_span = _annotation(
+                    "pst.gc", generation=info["generation"])
+                self._gc_span.__enter__()
+            self._gc_t0 = time.perf_counter()
+            return
+        t0, self._gc_t0 = self._gc_t0, 0.0
+        pause = time.perf_counter() - t0
+        span, self._gc_span = self._gc_span, None
+        if span is not None:
+            span.__exit__(None, None, None)
+        gc_pause_seconds_total.inc(pause)
+        counted, self._gc_counted = self._gc_counted, False
+        if not counted:
+            for cycle in list(self._cycles.values()):
+                cycle.gc_s += pause
+
+    def _drop_old_samples_locked(self, now: float) -> None:
         cutoff = now - self._TOKEN_WINDOW_S
         while self._tok_samples and self._tok_samples[0][0] < cutoff:
             self._tok_samples.popleft()
+
+    def _refresh_throughput_locked(self, now: float) -> None:
+        self._drop_old_samples_locked(now)
         per_kind: Dict[str, int] = {}
         for _, kind, toks in self._tok_samples:
             self._tok_kinds.add(kind)
@@ -589,11 +852,11 @@ class EngineTelemetry:
             self._cache_hits = 0
             self._cache_misses = 0
             self._step = None
-            self._step_acc = {}
+            self._cycles.clear()
+            self._cpu_mark = None
+            self._offcpu_carry = 0.0
             self._device_busy_s = 0.0
             self.startup_enabled = True
-        from .flight import NULL_FLIGHT_RECORDER
-
         self._flight = NULL_FLIGHT_RECORDER
 
 

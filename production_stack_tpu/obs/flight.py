@@ -1,31 +1,36 @@
-"""Engine flight recorder: an always-on ring of per-device-step records.
+"""Engine flight recorder: an always-on ring of step-loop records.
 
 The *write side* of deep performance introspection (docs/observability.md
 "Flight recorder"). PR 3's ``/debug/requests`` answers "what happened to
 THIS request"; the flight recorder answers the question a lone tail
 outlier leaves open — "what exactly was the engine doing when that p99
-outlier happened?" Every jitted dispatch appends one fixed-size record:
-step kind, padded batch bucket, device step wall, the host gap that
-preceded it, queue depths, KV occupancy, preemption count, tenant tier
-mix, and whether the dispatch absorbed an XLA compile.
+outlier happened, and what held it?" One fixed-size record a cycle of the
+step loop (one ``pst.intake`` and the ``pst.step`` after it), carried by
+the cycle's last dispatch: step kind, padded batch bucket, the dispatch's
+host-timed wall, the host gap that preceded it, queue depths, KV occupancy,
+preemption count, tenant tier mix, whether a dispatch absorbed an XLA
+compile — and the cycle's own account: its wall, the six phase sums, the
+step thread's off-CPU and CPU time, the process's CPU time, collection
+pauses, and how the fetch polled.
 
 Design constraints, in order:
 
 - **Always on.** The ring is a preallocated list of ``capacity`` slots
   written round-robin under a tiny lock — no allocation grows with
-  uptime, and the per-step cost is one tuple build + one list store, so
-  the PR 8 host-gap and roofline numbers are unaffected (asserted by
-  the bench acceptance bar).
-- **Post-mortem by construction.** Whenever a step exceeds the
-  ``tail_outlier`` bar (the PR 8 flag: worse than ``outlier_factor`` ×
-  the rolling per-bucket median), the recorder snapshots the ring — so
-  any p99>3×p50 event leaves a trace naming the stalled step's bucket
-  and queue state even if nobody was scraping. SIGTERM/fatal paths
-  snapshot too (``engine/server.py`` and ``engine/async_engine.py``).
+  uptime, and the per-cycle cost is one tuple build + one list store.
+- **Post-mortem by construction.** A cycle past the bar
+  (``outlier_factor`` × the rolling median ``cycle_s`` of its
+  ``(kind, bucket)`` and of whether it fetched, floored and armed as
+  below) is a *stall*: the
+  recorder names its cause (:func:`stall_cause`, a pure function of the
+  record) and snapshots the ring — so any such event leaves a trace
+  naming the stalled step's bucket, queue state and cause even if nobody
+  was scraping. SIGTERM/fatal paths snapshot too (``engine/server.py``
+  and ``engine/async_engine.py``).
 - **Feed-forward, not call-site churn.** :class:`EngineTelemetry`
-  already sees every dispatch (PR 5); the recorder registers as its
-  flight sink and the engine supplies a state probe closure
-  (scheduler depths + KV occupancy) — no new calls ride the hot loop.
+  already sees every dispatch and every phase; it hands the recorder one
+  cycle when ``pst.step`` closes, and the engine supplies a state probe
+  closure (scheduler depths + KV occupancy).
 
 Served by ``GET /debug/flight`` (last-N or time-window) on the engine.
 """
@@ -41,29 +46,86 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 # Record tuple layout (kept positional — a dict per step would allocate
 # a hash table on the hot path; rows render to dicts only at read time).
-_F_WALL = 0        # time.time() stamp (for ?window_s= and human output)
-_F_KIND = 1        # prefill | decode | spec_verify | encode
-_F_BUCKET = 2      # padded batch bucket label (b8xn4, b1xt512, ...)
-_F_DEVICE_S = 3    # device step wall (dispatch -> fetch)
-_F_HOST_GAP_S = 4  # serial host wall that preceded this dispatch
-_F_COMPILED = 5    # this dispatch absorbed an XLA compile
-_F_WAITING = 6     # scheduler waiting depth at dispatch
-_F_RUNNING = 7     # scheduler running depth at dispatch
-_F_SWAPPED = 8     # sequences parked host-side
-_F_KV_OCC = 9      # KV page occupancy fraction
-_F_PREEMPT = 10    # cumulative preemptions
-_F_BATCH_ROWS = 11 # batch-tier rows in the running set (tier mix)
-_F_TOKENS = 12     # real tokens the step moved
+_F_WALL = 0  # time.time() stamp (for ?window_s= and human output)
 
-_FIELDS = (
+# The phases whose sums a cycle's record carries, in record order.
+CYCLE_PHASES = (
+    "intake", "schedule", "batch_build", "launch", "wait", "postprocess",
+)
+_DISPATCH_FIELDS = (
     "ts", "kind", "bucket", "device_s", "host_gap_s", "compiled",
     "waiting", "running", "swapped", "kv_occupancy", "preemptions",
     "batch_tier_rows", "tokens",
 )
+# What a record says of the cycle it closes; None on a dispatch that closed
+# none (an embedding's encode, or a dispatch that was not its cycle's last).
+CYCLE_FIELDS = (
+    "cycle_s", *(p + "_s" for p in CYCLE_PHASES), "offcpu_s",
+    "thread_cpu_s", "process_cpu_s", "gc_s", "polls", "poll_gap_max_s",
+)
+FIELDS = _DISPATCH_FIELDS + CYCLE_FIELDS
+_NO_CYCLE = (None,) * len(CYCLE_FIELDS)
+# Where each phase's sum lies in a cycle's account (after cycle_s).
+PHASE_AT = {name: i for i, name in enumerate(CYCLE_PHASES)}
+
+STALL_CAUSES = (
+    "compile", "gc", "device", "machine", "interpreter", "host_work",
+    "unknown",
+)
+# Polls of a fetch further apart than this were not the step thread's doing:
+# it sleeps 0.3 ms between two.
+_POLL_GAP_HELD_S = 0.02
+# A reading of the process's CPU clock is good to one scheduler tick: the
+# benchmark's host accounts CPU time in steps of 10 ms.
+_CPU_TICK_S = 0.01
+
+
+def stall_cause(rec: dict, excess_s: float) -> str:
+    """What held a stalled cycle, from its record alone: the first that
+    holds of
+
+    - ``compile``: a dispatch of the cycle was its shape's first;
+    - ``gc``: collections paused the process for half the excess or more;
+    - ``device``: half the excess or more lies in ``wait`` and the fetch
+      polled at its pace throughout — the device, the runtime or the
+      transfer stood still, not this thread;
+    - ``machine``: the step thread was kept off the CPU (two polls far
+      apart, or off-CPU time outside its waits, where it never sleeps) and
+      the process's CPU time advanced by less than half the excess —
+      nothing of this process ran: run queue, steal, a stopped process;
+    - ``interpreter``: the same, and the process's CPU time did advance —
+      another thread of this process ran while the step thread could not;
+    - ``host_work``: the step thread's own CPU time outside its waits
+      covers half the excess — its own Python ran long;
+    - ``unknown``.
+
+    The process's clock says ``machine`` or ``interpreter`` only where it
+    lies a tick or more from the line between them (``_CPU_TICK_S``): a
+    short stall whose reading a single tick could move to the other side
+    is ``unknown``, not a coin's toss.
+    """
+    half = excess_s / 2.0
+    if rec["compiled"]:
+        return "compile"
+    if rec["gc_s"] >= half:
+        return "gc"
+    if rec["wait_s"] >= half and rec["poll_gap_max_s"] < _POLL_GAP_HELD_S:
+        return "device"
+    if rec["poll_gap_max_s"] >= half or rec["offcpu_s"] >= half:
+        if rec["process_cpu_s"] <= half - _CPU_TICK_S:
+            return "machine"
+        if rec["process_cpu_s"] >= half + _CPU_TICK_S:
+            return "interpreter"
+    if rec["thread_cpu_s"] >= half:
+        return "host_work"
+    return "unknown"
 
 
 def _row_dict(row: tuple) -> dict:
-    return dict(zip(_FIELDS, row))
+    """A record as ``GET /debug/flight`` shows it; the cycle's account is
+    rounded here, not on the step thread."""
+    return dict(zip(FIELDS, (
+        round(v, 6) if isinstance(v, float) else v for v in row)))
 
 
 def load_snapshot_dir(path: str, limit: Optional[int] = None) -> List[dict]:
@@ -97,22 +159,22 @@ def load_snapshot_dir(path: str, limit: Optional[int] = None) -> List[dict]:
 
 
 class FlightRecorder:
-    """Bounded, thread-safe per-step ring + outlier auto-snapshots.
+    """Bounded, thread-safe ring of step-loop records + stall snapshots.
 
     Written from the engine step thread (and executor threads for
     encode); read from the asyncio loop by ``GET /debug/flight``. The
     lock guards only the slot store / ring copy — never a device wait.
     """
 
-    # Rolling per-bucket median window for the outlier bar. Small on
+    # Rolling per-bucket median window for the stall bar. Small on
     # purpose: the bar should track the CURRENT steady state (post-warmup
-    # step times), not the whole process history.
+    # cycle times), not the whole process history.
     _MEDIAN_WINDOW = 64
-    # Steps below this are never outliers regardless of the median —
-    # 3x a 2 ms CPU decode step is noise, not a stall.
+    # Cycles below this never stall regardless of the median —
+    # 3x a 2 ms CPU decode cycle is noise.
     _MIN_OUTLIER_S = 0.05
     # Buckets need this many samples before the bar arms (a fresh bucket's
-    # first few steps straddle cache effects).
+    # first few cycles straddle cache effects).
     _MIN_SAMPLES = 8
 
     def __init__(
@@ -149,8 +211,9 @@ class FlightRecorder:
                 self._restored = load_snapshot_dir(
                     self.snapshot_dir, limit=self._snapshot_disk_keep
                 )
-        # (bucket -> recent device_s samples) for the rolling median.
-        self._samples: Dict[Tuple[str, str], "deque[float]"] = {}
+        # ((kind, bucket, fetched) -> recent cycle_s samples) for the
+        # rolling median.
+        self._samples: Dict[Tuple[str, str, bool], "deque[float]"] = {}
         # Engine-supplied closure: () -> dict(waiting, running, swapped,
         # batch_tier_rows, kv_occupancy, preemptions). Must be cheap and
         # safe on the step thread.
@@ -173,26 +236,16 @@ class FlightRecorder:
         this)."""
         self._pending_gap = max(float(seconds), 0.0)
 
-    def record_step(
-        self,
-        kind: str,
-        bucket: str,
-        device_s: float,
-        *,
-        compiled: bool = False,
-        tokens: int = 0,
-    ) -> None:
-        if not self.enabled:
-            return
-        probe = self._probe
+    def _row(self, dispatch: tuple, cycle: tuple) -> tuple:
+        kind, bucket, device_s, compiled, tokens = dispatch
         state: dict = {}
-        if probe is not None:
+        if self._probe is not None:
             try:
-                state = probe() or {}
+                state = self._probe() or {}
             except Exception:  # noqa: BLE001 — telemetry must not kill steps
                 state = {}
         gap, self._pending_gap = self._pending_gap, 0.0
-        row = (
+        return (
             time.time(),
             kind,
             bucket,
@@ -206,42 +259,98 @@ class FlightRecorder:
             int(state.get("preemptions", 0)),
             int(state.get("batch_tier_rows", 0)),
             int(tokens),
-        )
-        outlier_bar = None
+        ) + cycle
+
+    def _store_locked(self, row: tuple) -> None:
+        self._ring[self._idx] = row
+        self._idx = (self._idx + 1) % self.capacity
+        self._total += 1
+
+    def record_step(
+        self,
+        kind: str,
+        bucket: str,
+        device_s: float,
+        *,
+        compiled: bool = False,
+        tokens: int = 0,
+    ) -> None:
+        """One dispatch that closes no cycle of the step loop (an
+        embedding's encode on an executor thread): a record without the
+        cycle's fields, held to no bar."""
+        if not self.enabled:
+            return
+        row = self._row((kind, bucket, device_s, compiled, tokens), _NO_CYCLE)
         with self._lock:
-            self._ring[self._idx] = row
-            self._idx = (self._idx + 1) % self.capacity
-            self._total += 1
-            key = (kind, bucket)
+            self._store_locked(row)
+
+    def record_cycle(
+        self, dispatches: list, cycle_s: float, account: tuple,
+        kind: str = "", held_to_bar: bool = True,
+    ) -> Optional[dict]:
+        """One cycle of the step loop, when its ``pst.step`` closes.
+        ``dispatches``: (kind, bucket, device_s, compiled, tokens) of each
+        live dispatch of the cycle, in order; the last carries the cycle
+        (a cycle that dispatched nothing is a record of its own, of the
+        step's ``kind``). ``account``: the values of ``CYCLE_FIELDS`` after
+        ``cycle_s``. Returns the stall (its snapshot's ``detail``) when the
+        cycle passed the bar, else None. A cycle not ``held_to_bar`` (a
+        profiler started under it) is recorded, sets no baseline and is no
+        stall."""
+        if not self.enabled:
+            return None
+        compiled = any(d[3] for d in dispatches)
+        if not dispatches:
+            dispatches = [(kind or "none", "", 0.0, False, 0)]
+        *earlier, last = dispatches
+        rows = [self._row(d, _NO_CYCLE) for d in earlier]
+        last = (*last[:3], compiled, last[4])
+        rows.append(self._row(last, (cycle_s, *account)))
+        # Nothing at or under the floor is a stall: the median is looked up
+        # only for the few cycles above it.
+        slow = compiled or cycle_s > self._MIN_OUTLIER_S
+        median = None
+        with self._lock:
+            for row in rows:
+                self._store_locked(row)
+            if not held_to_bar:
+                return None
+            # A cycle that fetched is held against cycles that fetched: a
+            # prompt's inner chunks are launched and left (a few ms of
+            # host), its last waits for the device.
+            key = (last[0], last[1], account[PHASE_AT["wait"]] > 0)
             dq = self._samples.get(key)
             if dq is None:
                 dq = self._samples[key] = deque(maxlen=self._MEDIAN_WINDOW)
-            # Compile-bearing steps are architecture, not steady state:
+            # Compile-bearing cycles are architecture, not steady state:
             # they set no baseline (and ARE flagged via `compiled`).
             if not compiled:
-                if len(dq) >= self._MIN_SAMPLES:
-                    ordered = sorted(dq)
-                    p50 = ordered[len(ordered) // 2]
-                    outlier_bar = max(
-                        p50 * self.outlier_factor, self._MIN_OUTLIER_S
-                    )
-                dq.append(device_s)
-        if (
-            outlier_bar is not None and device_s > outlier_bar
-        ) or (compiled and device_s > self._MIN_OUTLIER_S):
-            self.snapshot(
-                "compile" if compiled else "tail_outlier",
-                detail={
-                    "kind": kind,
-                    "bucket": bucket,
-                    "device_s": round(device_s, 6),
-                    "bar_s": round(outlier_bar, 6) if outlier_bar else None,
-                    "waiting": row[_F_WAITING],
-                    "running": row[_F_RUNNING],
-                    "swapped": row[_F_SWAPPED],
-                    "kv_occupancy": row[_F_KV_OCC],
-                },
-            )
+                if slow and len(dq) >= self._MIN_SAMPLES:
+                    median = sorted(dq)[len(dq) // 2]
+                dq.append(cycle_s)
+        if not slow:
+            return None
+        if compiled:
+            bar = self._MIN_OUTLIER_S
+        elif median is not None:
+            bar = max(median * self.outlier_factor, self._MIN_OUTLIER_S)
+        else:
+            return None
+        if cycle_s <= bar:
+            return None
+        rec = _row_dict(rows[-1])
+        excess = cycle_s - (median or 0.0)
+        detail = dict(
+            rec,
+            cause=stall_cause(rec, excess),
+            # the phase that holds most of the cycle
+            phase=max(CYCLE_PHASES, key=lambda p: rec[p + "_s"]),
+            excess_s=round(excess, 6),
+            median_s=round(median, 6) if median is not None else None,
+            bar_s=round(bar, 6),
+        )
+        self.snapshot("compile" if compiled else "tail_outlier", detail)
+        return detail
 
     # -- read side -------------------------------------------------------
 
@@ -348,7 +457,7 @@ class FlightRecorder:
         after a restart."""
         payload = {
             **self.stats(),
-            "fields": list(_FIELDS),
+            "fields": list(FIELDS),
             "records": self.records(n=n, window_s=window_s),
             "snapshot_log": self.snapshots(),
         }

@@ -65,6 +65,12 @@ REGISTRY: Tuple[MetricSpec, ...] = (
     MetricSpec("pst_engine_step_duration_seconds", HISTOGRAM, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_host_gap_seconds", HISTOGRAM, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_step_phase_seconds", HISTOGRAM, "obs/engine_telemetry.py"),
+    # What held a step off (docs/observability.md "Flight recorder"): the
+    # step thread's off-CPU time, stalled cycles by cause, collections.
+    MetricSpec("pst_engine_step_offcpu_seconds", HISTOGRAM, "obs/engine_telemetry.py"),
+    MetricSpec("pst_engine_stalls", COUNTER, "obs/engine_telemetry.py"),
+    MetricSpec("pst_engine_stall_seconds", COUNTER, "obs/engine_telemetry.py"),
+    MetricSpec("pst_engine_gc_pause_seconds", COUNTER, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_batch_fill_ratio", HISTOGRAM, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_tokens_per_second", GAUGE, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_kv_page_occupancy", GAUGE, "obs/engine_telemetry.py"),

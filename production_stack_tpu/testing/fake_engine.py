@@ -37,6 +37,8 @@ from ..obs import (
     render_obs_metrics,
     unbind_log_context,
 )
+from ..obs.flight import FIELDS as FLIGHT_FIELDS
+from ..obs.flight import STALL_CAUSES
 
 logger = init_logger(__name__)
 
@@ -207,6 +209,10 @@ class FakeEngineState:
         # snapshot is also persisted (same file naming as
         # obs/flight.py) so post-mortem collection works after SIGKILL.
         self.flight_snapshots: List[dict] = []
+        # pst_engine_stalls_total / pst_engine_stall_seconds_total
+        # {cause="device"}: what the injected stalls have left.
+        self.stalls = 0
+        self.stall_seconds = 0.0
         self.flight_snapshot_keep = 8
         self.flight_snapshot_dir: Optional[str] = None
         self.restored_snapshots: List[dict] = []
@@ -331,6 +337,22 @@ class FakeEngineState:
             "queue_s": 0.0,
         }
 
+    @staticmethod
+    def _flight_cycle(device_s: float) -> dict:
+        """The cycle's account of a record (obs/flight.py's fields after
+        ``tokens``), a pure function of the dispatch's wall: all of it in
+        ``wait``, polled every 0.3 ms, beside 0.5 ms of host work."""
+        device_s = round(device_s, 6)
+        return {
+            "cycle_s": round(device_s + 0.0005, 6),
+            "intake_s": 0.0001, "schedule_s": 0.0001,
+            "batch_build_s": 0.0001, "launch_s": 0.0001,
+            "wait_s": device_s, "postprocess_s": 0.0001,
+            "offcpu_s": 0.0, "thread_cpu_s": 0.0005,
+            "process_cpu_s": 0.0005, "gc_s": 0.0,
+            "polls": int(device_s / 0.0003), "poll_gap_max_s": 0.0003,
+        }
+
     def record_flight(self, prompt_tokens: int, n_tokens: int) -> None:
         """Two deterministic ring records per generation (the prefill
         step and its decode burst), same field set as obs/flight.py."""
@@ -350,12 +372,14 @@ class FakeEngineState:
             "bucket": f"b1xt{max(prompt_tokens, 1)}",
             "device_s": round(prompt_tokens * 1e-4, 6),
             "tokens": prompt_tokens,
+            **self._flight_cycle(prompt_tokens * 1e-4),
         })
         self.flight_records.append({
             **base, "kind": "decode",
             "bucket": f"b{max(self.num_running, 1)}xn{max(n_tokens, 1)}",
             "device_s": round(n_tokens * 1e-3, 6),
             "tokens": n_tokens,
+            **self._flight_cycle(n_tokens * 1e-3),
         })
         self.flight_total += 2
         if len(self.flight_records) > self.flight_capacity:
@@ -363,11 +387,12 @@ class FakeEngineState:
                                     - self.flight_capacity]
 
     def record_stall(self, stall_s: float, n_tokens: int) -> None:
-        """One stalled decode step: an extra ring record whose device_s
-        is the injected stall, plus a retained tail_outlier snapshot
-        naming the stalled bucket and queue state — the same evidence
+        """One stalled decode cycle: an extra ring record whose wait is
+        the injected stall, plus a retained tail_outlier snapshot naming
+        the stalled bucket, queue state and cause (``device``: the wait
+        polled at its pace) and the two stall counters — the same evidence
         the real recorder leaves for an unexplained p99 (obs/flight.py
-        auto-snapshot contract)."""
+        stall contract)."""
         bucket = f"b{max(self.num_running, 1)}xn{max(n_tokens, 1)}"
         baseline_s = max(n_tokens, 1) * 1e-3  # the unstalled decode cost
         row = {
@@ -384,7 +409,11 @@ class FakeEngineState:
             "preemptions": 0,
             "batch_tier_rows": 0,
             "tokens": n_tokens,
+            **self._flight_cycle(stall_s),
         }
+        excess_s = round(row["cycle_s"] - baseline_s, 6)
+        self.stalls += 1
+        self.stall_seconds += excess_s
         self.flight_records.append(row)
         self.flight_total += 1
         if len(self.flight_records) > self.flight_capacity:
@@ -394,14 +423,12 @@ class FakeEngineState:
             "reason": "tail_outlier",
             "ts": time.time(),
             "detail": {
-                "kind": "decode",
-                "bucket": bucket,
-                "device_s": round(stall_s, 6),
+                **row,
+                "cause": "device",
+                "phase": "wait",
+                "excess_s": excess_s,
+                "median_s": round(baseline_s, 6),
                 "bar_s": round(baseline_s * 3.0, 6),
-                "waiting": self.num_waiting,
-                "running": self.num_running,
-                "swapped": 0,
-                "kv_occupancy": round(self.kv_occupancy, 4),
                 "injected": "stall",
             },
             "total_steps": self.flight_total,
@@ -1229,6 +1256,24 @@ def create_fake_engine_app(
                 'pst_engine_host_gap_seconds_bucket{batch_bucket="b4",le="+Inf"} 10',
                 'pst_engine_host_gap_seconds_sum{batch_bucket="b4"} 0.02',
                 'pst_engine_host_gap_seconds_count{batch_bucket="b4"} 10',
+                # What held a step off (docs/observability.md "Flight
+                # recorder"): every cause from the start, the injected
+                # stalls under `device`.
+                "# TYPE pst_engine_step_offcpu_seconds histogram",
+                'pst_engine_step_offcpu_seconds_bucket{kind="decode",le="0.001"} 9',
+                'pst_engine_step_offcpu_seconds_bucket{kind="decode",le="+Inf"} 10',
+                'pst_engine_step_offcpu_seconds_sum{kind="decode"} 0.004',
+                'pst_engine_step_offcpu_seconds_count{kind="decode"} 10',
+                "# TYPE pst_engine_stalls counter",
+                *(f'pst_engine_stalls_total{{cause="{c}"}} '
+                  f'{state.stalls if c == "device" else 0}'
+                  for c in STALL_CAUSES),
+                "# TYPE pst_engine_stall_seconds counter",
+                *(f'pst_engine_stall_seconds_total{{cause="{c}"}} '
+                  f'{state.stall_seconds if c == "device" else 0.0:.6f}'
+                  for c in STALL_CAUSES),
+                "# TYPE pst_engine_gc_pause_seconds counter",
+                "pst_engine_gc_pause_seconds_total 0.5",
                 "# TYPE pst_engine_preemptions counter",
                 "pst_engine_preemptions_total 1",
                 "# TYPE pst_engine_swap_out counter",
@@ -1386,11 +1431,7 @@ def create_fake_engine_app(
             "capacity": state.flight_capacity,
             "total_steps": state.flight_total,
             "resident": len(state.flight_records),
-            "fields": [
-                "ts", "kind", "bucket", "device_s", "host_gap_s",
-                "compiled", "waiting", "running", "swapped",
-                "kv_occupancy", "preemptions", "batch_tier_rows", "tokens",
-            ],
+            "fields": list(FLIGHT_FIELDS),
             "records": records,
             "snapshot_log": list(state.flight_snapshots),
             **(

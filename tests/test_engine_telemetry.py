@@ -1,20 +1,25 @@
 """Engine telemetry ring (docs/observability.md "Engine telemetry").
 
 Ring 1: the EngineTelemetry sink — first-call-per-bucket compile
-detection, step-duration routing, throughput, stats refresh.
+detection, step-duration routing, throughput (appended at a dispatch,
+summed at a scrape), stats refresh, collections timed in the program,
+every cause of a stall at 0 from the start.
 Ring 2: a real tiny CPU engine — a forced recompile (new prefill shape
 bucket) increments pst_engine_compile_total, records
 pst_engine_compile_seconds, and rides RequestOutput.compile_events.
 Ring 3: the engine HTTP server — the compile event lands on the
-in-flight request's trace (/debug/requests), /metrics carries the
-pst_engine_* surface, and POST /debug/profile is guarded + a graceful
-CPU no-op.
+in-flight request's trace (/debug/requests), the first token's way to
+the socket is its ``deliver`` span, streamed or not, /metrics carries
+the pst_engine_* surface, and POST /debug/profile is guarded + a
+graceful CPU no-op.
 Ring 4: the generated observability/prometheus-rules.yaml passes an
 offline schema check (promtool-equivalent) and the metric-docs lint
 passes.
 """
 
 import asyncio
+import gc
+import json
 import pathlib
 import re
 import subprocess
@@ -74,14 +79,41 @@ def test_compile_events_drain_once():
     assert tel.drain_compile_events() == []
 
 
-def test_throughput_update():
+def _gauge(text: str, series: str) -> float:
+    return float(
+        re.search("^" + re.escape(series) + r" (\S+)", text, re.M).group(1))
+
+
+def test_throughput_update(monkeypatch):
+    """A dispatch appends its sample and walks nothing; the scrape
+    (``refresh_from_stats``) sums what the last ten seconds hold."""
     tel = EngineTelemetry()
+    clock = [1000.0]
+    monkeypatch.setattr(
+        "production_stack_tpu.obs.engine_telemetry.time.monotonic",
+        lambda: clock[0])
+    series = 'pst_engine_tokens_per_second{kind="decode"}'
+    tel.refresh_from_stats({})
     tel.record_dispatch("decode", ("a",), 0.1, batch_bucket="b8", tokens=100)
+    clock[0] += 2.0
     tel.record_dispatch("decode", ("a",), 0.1, batch_bucket="b8", tokens=100)
+    clock[0] += 2.0
     # Gauges live in the shared registry; the values themselves are
     # asserted through exposition text (the public contract).
-    text = render_engine_telemetry().decode()
-    assert 'pst_engine_tokens_per_second{kind="decode"}' in text
+    tel.refresh_from_stats({})
+    assert _gauge(render_engine_telemetry().decode(), series) == 200 / 4.0
+    # nothing is read between two scrapes, so nothing is computed there
+    tel.record_dispatch("decode", ("a",), 0.1, batch_bucket="b8", tokens=400)
+    assert _gauge(render_engine_telemetry().decode(), series) == 50.0
+    # samples older than the window go at the next dispatch, scrape or none
+    clock[0] += 9.0
+    tel.record_dispatch("decode", ("a",), 0.1, batch_bucket="b8", tokens=100)
+    assert [toks for _, _, toks in tel._tok_samples] == [400, 100]
+    tel.refresh_from_stats({})
+    assert _gauge(render_engine_telemetry().decode(), series) == 500 / 9.0
+    clock[0] += 11.0  # an idle engine reads 0, not its last burst
+    tel.refresh_from_stats({})
+    assert _gauge(render_engine_telemetry().decode(), series) == 0.0
 
 
 def test_refresh_from_stats_tracks_high_watermark():
@@ -104,6 +136,75 @@ def test_startup_phase_gate():
     assert 'pst_engine_startup_seconds{phase="load"} 12.0' in (
         render_engine_telemetry().decode()
     )
+
+
+def test_every_cause_of_a_stall_reads_zero_from_the_start():
+    """In a process that has stalled nowhere yet, /metrics' telemetry part
+    carries both families with all seven causes at 0: a window without a
+    stall reads 0, not nothing."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from production_stack_tpu.obs import render_engine_telemetry;"
+         "print(render_engine_telemetry().decode())"],
+        capture_output=True, text=True, cwd=REPO, check=True).stdout
+    for cause in ("compile", "gc", "device", "machine", "interpreter",
+                  "host_work", "unknown"):
+        for family in ("pst_engine_stalls_total", "pst_engine_stall_seconds_total"):
+            assert f'{family}{{cause="{cause}"}} 0.0' in out, (family, cause)
+    assert "pst_engine_gc_pause_seconds_total 0.0" in out
+    assert len(re.findall(r'^pst_engine_stalls_total\{', out, re.M)) == 7
+
+
+def test_collections_are_timed_in_the_program():
+    tel = ENGINE_TELEMETRY  # the process's one sink: its counter is global
+    series = "pst_engine_gc_pause_seconds_total"
+    tel.watch_collections()
+    tel.watch_collections()  # one entry however often it is asked for
+    assert gc.callbacks.count(tel._on_collection) == 1
+    graph = [[i] for i in range(200_000)]
+    before = _gauge(render_engine_telemetry().decode(), series)
+    gc.collect(0)  # the young generation is not timed
+    assert _gauge(render_engine_telemetry().decode(), series) == before
+    with tel.phase("step"):  # a collection adds to the open cycle
+        gc.collect()
+        cycle = tel._open_cycle()
+    del graph
+    grown = _gauge(render_engine_telemetry().decode(), series) - before
+    assert grown > 0 and cycle.gc_s == pytest.approx(grown)
+
+
+def test_a_collection_that_reports_late_is_counted_once_and_where_it_fell():
+    """A collection's callbacks are Python: the step thread, having waited
+    it out, takes the interpreter lock at their first instruction and may
+    close its cycle before "stop" runs (seen on the chip). The cycle counts
+    the collection then, and the late "stop" adds it nowhere else."""
+    import threading
+    import time
+
+    from production_stack_tpu.obs.flight import FlightRecorder
+
+    tel = ENGINE_TELEMETRY
+    rec = FlightRecorder(capacity=8)
+    tel.attach_flight(rec)
+    series = "pst_engine_gc_pause_seconds_total"
+    before = _gauge(render_engine_telemetry().decode(), series)
+
+    def elsewhere(phase):
+        t = threading.Thread(
+            target=tel._on_collection, args=(phase, {"generation": 2}))
+        t.start()
+        t.join()
+
+    with tel.phase("step"):
+        elsewhere("start")
+        time.sleep(0.02)
+    elsewhere("stop")
+    with tel.phase("step"):
+        pass
+    stalled, after = rec.records()[-2:]
+    assert stalled["gc_s"] >= 0.02 and after["gc_s"] == 0
+    grown = _gauge(render_engine_telemetry().decode(), series) - before
+    assert grown == pytest.approx(stalled["gc_s"], abs=5e-3)
 
 
 # ---------------------------------------------------------------------------
@@ -211,6 +312,11 @@ async def test_server_metrics_and_compile_span_event():
         assert "pst_engine_step_duration_seconds" in text
         assert "pst_engine_kv_page_occupancy" in text
         assert "pst_engine_startup_seconds" in text
+        for family in ("pst_engine_step_offcpu_seconds_count",
+                       "pst_engine_stalls_total",
+                       "pst_engine_stall_seconds_total",
+                       "pst_engine_gc_pause_seconds_total"):
+            assert family in text, family
         # The vllm: surface and the stage histograms still ride along.
         assert "vllm:num_requests_running" in text
         assert "pst_stage_duration_seconds" in text
@@ -227,6 +333,36 @@ async def test_server_metrics_and_compile_span_event():
         assert compile_events[0]["attributes"]["kind"] in (
             "prefill", "decode"
         )
+
+
+@pytest.mark.parametrize("stream", [True, False],
+                         ids=["streamed", "unstreamed"])
+async def test_deliver_span_follows_prefill(stream):
+    """The first token's way from the step thread's stamp to the return of
+    the socket write that carried it, in /debug/requests: after prefill,
+    never negative, and short of the whole request."""
+    async with EngineServer() as server, aiohttp.ClientSession() as sess:
+        payload = {"model": "tiny-llama-debug", "prompt": "hello world",
+                   "max_tokens": 6, "temperature": 0.0, "stream": stream}
+        async with sess.post(f"{server.url}/v1/completions", json=payload) as r:
+            assert r.status == 200
+            body = await r.read()
+        if stream:
+            assert body.rstrip().endswith(b"data: [DONE]")
+        else:
+            assert json.loads(body)["usage"]["completion_tokens"] == 6
+        async with sess.get(f"{server.url}/debug/requests") as r:
+            (timeline,) = (await r.json())["requests"]
+        spans = {sp["name"]: sp for sp in timeline["spans"]}
+        assert {"engine_queue", "prefill", "deliver", "decode"} <= set(spans)
+        deliver, prefill = spans["deliver"], spans["prefill"]
+        assert 0 <= deliver["duration_ms"] <= timeline["duration_ms"]
+        assert deliver["start_ms"] == pytest.approx(
+            prefill["start_ms"] + prefill["duration_ms"], abs=0.01)
+        if not stream:  # the first token leaves with the last
+            assert deliver["duration_ms"] >= spans["decode"]["duration_ms"]
+        async with sess.get(f"{server.url}/metrics") as r:
+            assert 'stage="deliver"' in await r.text()
 
 
 async def test_debug_profile_guarded_and_cpu_noop():

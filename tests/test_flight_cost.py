@@ -2,9 +2,10 @@
 (docs/observability.md "Flight recorder" / "Cost attribution" /
 "Capacity signals").
 
-- Ring bounds under sustained load (no growth), outlier auto-snapshot
-  firing with the stalled step's bucket + queue state, compile
-  snapshots, disabled/null behavior; snapshot persistence (naming
+- Ring bounds under sustained load (no growth), the stall bar on a
+  cycle's wall (``cycle_s``) with the stalled step's bucket, queue state
+  and cause in the snapshot, the cause as a pure function of a record,
+  compile snapshots, disabled/null behavior; snapshot persistence (naming
   contract, bounded oldest-first disk eviction, restart load-back via
   ``?snapshots=1``) and the fake engine's deterministic stall snapshot.
 - Cost attribution parity: request device-seconds sum to the
@@ -37,9 +38,13 @@ from production_stack_tpu.obs.engine_telemetry import (
     tenant_device_seconds,
 )
 from production_stack_tpu.obs.flight import (
+    CYCLE_FIELDS,
+    FIELDS,
     NULL_FLIGHT_RECORDER,
+    STALL_CAUSES,
     FlightRecorder,
     load_snapshot_dir,
+    stall_cause,
 )
 from production_stack_tpu.obs.top import render_frame
 from production_stack_tpu.router.services import capacity as capacity_mod
@@ -79,6 +84,20 @@ def test_flight_ring_bounds_under_sustained_load():
     assert all(r["kind"] == "decode" for r in rows)
 
 
+# A cycle's account with nothing in it, by field (obs/flight.py).
+_ACCOUNT = dict.fromkeys(CYCLE_FIELDS[1:], 0.0)
+
+
+def _cycle(rec, cycle_s, kind="decode", bucket="b8xn4", compiled=False,
+           tokens=32, **account):
+    """One cycle of one dispatch, which it fetched, through the recorder;
+    the stall or None."""
+    acc = {**_ACCOUNT, "wait_s": 0.001, **account}
+    return rec.record_cycle(
+        [(kind, bucket, cycle_s * 0.9, compiled, tokens)], cycle_s,
+        tuple(acc[f] for f in CYCLE_FIELDS[1:]), kind=kind)
+
+
 def test_flight_outlier_snapshot_names_bucket_and_queue_state():
     rec = FlightRecorder(capacity=64)
     state = {"waiting": 3, "running": 7, "swapped": 1,
@@ -86,45 +105,138 @@ def test_flight_outlier_snapshot_names_bucket_and_queue_state():
     rec.set_probe(lambda: state)
     # Build the rolling baseline (p50 ~ 30ms, bar = 90ms).
     for _ in range(16):
-        rec.record_step("decode", "b8xn4", 0.03, tokens=32)
+        assert _cycle(rec, 0.03, wait_s=0.02) is None
     assert rec.snapshots() == []
-    # The 120s-style stall: one step far past 3x the bucket median.
-    rec.record_step("decode", "b8xn4", 1.5, tokens=32)
+    # The 120s-style stall: one cycle far past 3x the bucket median, all
+    # of it in a wait that polled at its pace.
+    stall = _cycle(rec, 1.5, wait_s=1.49, polls=4000, poll_gap_max_s=0.0004)
     snaps = rec.snapshots()
     assert len(snaps) == 1
     snap = snaps[0]
-    assert snap["reason"] == "tail_outlier"
+    assert snap["reason"] == "tail_outlier" and snap["detail"] == stall
     assert snap["detail"]["kind"] == "decode"
     assert snap["detail"]["bucket"] == "b8xn4"
-    assert snap["detail"]["device_s"] == pytest.approx(1.5)
+    assert snap["detail"]["cause"] == "device"
+    assert snap["detail"]["phase"] == "wait"
+    assert snap["detail"]["cycle_s"] == pytest.approx(1.5)
+    assert snap["detail"]["excess_s"] == pytest.approx(1.47)
+    assert snap["detail"]["median_s"] == pytest.approx(0.03)
+    assert snap["detail"]["bar_s"] == pytest.approx(0.09)
+    assert snap["detail"]["polls"] == 4000
     assert snap["detail"]["waiting"] == 3
     assert snap["detail"]["running"] == 7
     assert snap["detail"]["kv_occupancy"] == pytest.approx(0.83)
-    # The snapshot's record tail ends with the stalled step itself.
-    assert snap["records"][-1]["device_s"] == pytest.approx(1.5)
-    assert snap["records"][-1]["batch_tier_rows"] == 2
+    # The snapshot's record tail ends with the stalled cycle itself, under
+    # the documented fields.
+    last = snap["records"][-1]
+    assert last["cycle_s"] == pytest.approx(1.5)
+    assert last["device_s"] == pytest.approx(1.35)
+    assert last["batch_tier_rows"] == 2 and last["host_gap_s"] == 0.0
+
+
+def test_the_bar_is_on_the_cycle_and_not_on_the_dispatch():
+    """One bar: a dispatch whose host-timed wall is long inside a cycle of
+    the usual length is no stall, and a long cycle of a short dispatch is."""
+    rec = FlightRecorder(capacity=64)
+    acc = tuple(_ACCOUNT.values())
+    for _ in range(16):
+        rec.record_cycle([("decode", "b8", 0.03, False, 8)], 0.03, acc)
+    assert rec.record_cycle([("decode", "b8", 2.0, False, 8)], 0.03, acc) is None
+    stall = rec.record_cycle([("decode", "b8", 0.001, False, 8)], 2.0, acc)
+    assert stall["cause"] == "unknown" and stall["device_s"] == 0.001
+    # a cycle that fetched is not held against those that launched and left:
+    # a prompt's last chunk waits for the chunks before it
+    waited = tuple(dict(_ACCOUNT, wait_s=0.5).values())
+    for _ in range(9):
+        assert rec.record_cycle(
+            [("decode", "b8", 0.5, False, 8)], 0.5, waited) is None
+    # a dispatch outside any cycle is held to no bar
+    rec.record_step("encode", "t64", 9.0)
+    assert rec.records()[-1]["cycle_s"] is None
+    assert len(rec.snapshots()) == 1
+
+
+def test_a_cycle_of_two_dispatches_rides_its_last_and_of_none_its_own():
+    rec = FlightRecorder(capacity=64)
+    acc = tuple(dict(_ACCOUNT, polls=7).values())
+    rec.record_cycle([("decode", "b8xn1", 0.01, False, 8),
+                      ("prefill", "b1xt64", 0.02, True, 64)], 0.04, acc)
+    first, last = rec.records()[-2:]
+    assert first["kind"] == "decode" and first["cycle_s"] is None
+    assert last["kind"] == "prefill" and last["cycle_s"] == 0.04
+    assert last["compiled"] is True and last["polls"] == 7
+    rec.record_cycle([], 0.002, acc, kind="")
+    own = rec.records()[-1]
+    assert (own["kind"], own["bucket"], own["tokens"]) == ("none", "", 0)
+    assert own["cycle_s"] == 0.002 and rec.stats()["total_steps"] == 3
+
+
+# One record a cause, and the order between them: each row is the account
+# of a cycle one second past its median, and what must be read from it.
+_CAUSE_CASES = [
+    ("compile", dict(compiled=True, gc_s=1.0, wait_s=1.0)),
+    ("gc", dict(gc_s=0.5, wait_s=0.9, poll_gap_max_s=0.0004)),
+    ("device", dict(wait_s=0.5, poll_gap_max_s=0.019, thread_cpu_s=0.6)),
+    ("machine", dict(wait_s=0.9, poll_gap_max_s=0.9, process_cpu_s=0.1)),
+    ("machine", dict(postprocess_s=0.9, offcpu_s=0.5, process_cpu_s=0.48)),
+    ("interpreter", dict(wait_s=0.9, poll_gap_max_s=0.8, process_cpu_s=0.9)),
+    ("interpreter", dict(launch_s=0.9, offcpu_s=0.6, process_cpu_s=0.52,
+                         thread_cpu_s=0.9)),
+    # the process's clock within a tick of the line between the two: neither
+    ("unknown", dict(wait_s=0.9, poll_gap_max_s=0.9, process_cpu_s=0.495)),
+    ("unknown", dict(launch_s=0.9, offcpu_s=0.9, process_cpu_s=0.505)),
+    ("host_work", dict(postprocess_s=1.0, thread_cpu_s=0.5,
+                       process_cpu_s=0.6, gc_s=0.49, offcpu_s=0.49)),
+    ("host_work", dict(wait_s=0.49, thread_cpu_s=0.9)),
+    ("unknown", dict(intake_s=1.0, gc_s=0.4, wait_s=0.4, offcpu_s=0.4,
+                     thread_cpu_s=0.4, poll_gap_max_s=0.4)),
+]
+
+
+@pytest.mark.parametrize("cause,account", _CAUSE_CASES,
+                         ids=[f"{i}-{c}" for i, (c, _) in enumerate(_CAUSE_CASES)])
+def test_stall_cause_is_a_pure_function_of_the_record(cause, account):
+    rec = {"compiled": False, **_ACCOUNT, **account}
+    assert stall_cause(rec, 1.0) == cause
+    assert cause in STALL_CAUSES
+    # through the recorder: the same record, the same name
+    fr = FlightRecorder(capacity=32)
+    for _ in range(8):
+        _cycle(fr, 0.03)
+    account = dict(account)
+    compiled = account.pop("compiled", False)
+    stall = _cycle(fr, 1.03, compiled=compiled, **account)
+    assert stall["cause"] == cause
+    assert fr.snapshots()[-1]["reason"] == (
+        "compile" if compiled else "tail_outlier")
 
 
 def test_flight_outlier_bar_floors_small_steps():
-    """3x a 2ms CPU step is noise: the 50ms floor keeps it silent."""
+    """3x a 2ms CPU cycle is noise: the 50ms floor keeps it silent, and a
+    bucket of fewer than eight cycles has no bar yet."""
     rec = FlightRecorder(capacity=64)
     for _ in range(16):
-        rec.record_step("decode", "b4", 0.002)
-    rec.record_step("decode", "b4", 0.02)  # 10x the median, under the floor
+        _cycle(rec, 0.002, bucket="b4")
+    assert _cycle(rec, 0.02, bucket="b4") is None  # 10x the median
+    for _ in range(7):
+        _cycle(rec, 0.002, bucket="b2")
+    assert _cycle(rec, 5.0, bucket="b2") is None
     assert rec.snapshots() == []
 
 
 def test_flight_compile_snapshot_and_no_baseline_pollution():
     rec = FlightRecorder(capacity=64)
     # A live compile above the floor snapshots with reason "compile"...
-    rec.record_step("prefill", "b1xt512", 0.8, compiled=True)
+    stall = _cycle(rec, 0.8, "prefill", "b1xt512", compiled=True)
+    assert stall["cause"] == "compile" and stall["median_s"] is None
+    assert stall["excess_s"] == pytest.approx(0.8)
     snaps = rec.snapshots()
     assert [s["reason"] for s in snaps] == ["compile"]
-    # ...and never seeds the steady-state median (the next normal steps
+    # ...and never seeds the steady-state median (the next normal cycles
     # would otherwise need to be 3x the COMPILE wall to flag).
     for _ in range(16):
-        rec.record_step("prefill", "b1xt512", 0.01)
-    rec.record_step("prefill", "b1xt512", 0.2)
+        _cycle(rec, 0.01, "prefill", "b1xt512")
+    _cycle(rec, 0.2, "prefill", "b1xt512")
     assert [s["reason"] for s in rec.snapshots()] == [
         "compile", "tail_outlier"
     ]
@@ -144,6 +256,7 @@ def test_flight_window_and_n_filters():
 
 def test_null_recorder_is_free():
     NULL_FLIGHT_RECORDER.record_step("decode", "b8", 1e9)
+    assert _cycle(NULL_FLIGHT_RECORDER, 1e9) is None
     assert NULL_FLIGHT_RECORDER.records() == []
     assert NULL_FLIGHT_RECORDER.stats()["capacity"] == 0
 
@@ -418,20 +531,20 @@ async def test_engine_debug_flight_and_cost_header():
         assert flight["records"]
         last = flight["records"][-1]
         assert {"kind", "bucket", "device_s", "waiting", "running",
-                "kv_occupancy"} <= set(last)
+                "kv_occupancy", "cycle_s", "wait_s", "offcpu_s",
+                "process_cpu_s", "gc_s", "polls"} <= set(last)
+        assert flight["fields"] == list(last)
+        assert last["cycle_s"] > 0 and last["polls"] >= 0
 
-        # Induced 120s-style stall: the step thread records a dispatch
-        # far past its bucket's rolling median -> the ring auto-snapshots
-        # naming the stalled step's bucket and queue state, visible at
-        # GET /debug/flight without any operator action.
-        key = ("stall-test", "decode", ("shape",))
+        # Induced 120s-style stall: a cycle far past its bucket's rolling
+        # median -> the ring auto-snapshots naming the stalled step's
+        # bucket, queue state and cause, visible at GET /debug/flight
+        # without any operator action.
+        rec = server.engine.engine.flight
+        await asyncio.sleep(0.1)  # the burst still in flight is drained
         for _ in range(12):
-            ENGINE_TELEMETRY.record_dispatch(
-                "decode", key, 0.03, batch_bucket="b8", tokens=8
-            )
-        ENGINE_TELEMETRY.record_dispatch(
-            "decode", key, 2.0, batch_bucket="b8", tokens=8
-        )
+            _cycle(rec, 0.03, bucket="b8")
+        _cycle(rec, 2.0, bucket="b8", wait_s=1.9, poll_gap_max_s=0.0004)
         async with sess.get(f"{server.url}/debug/flight?n=4") as r:
             flight = await r.json()
         assert len(flight["records"]) == 4
@@ -439,6 +552,7 @@ async def test_engine_debug_flight_and_cost_header():
                  if s["reason"] == "tail_outlier"]
         assert snaps, "the induced stall left no snapshot"
         assert snaps[-1]["detail"]["bucket"] == "b8"
+        assert snaps[-1]["detail"]["cause"] == "device"
         assert "waiting" in snaps[-1]["detail"]
         # /debug/state carries the ring stats for /debug/fleet cross-check.
         async with sess.get(f"{server.url}/debug/state") as r:
@@ -504,6 +618,12 @@ async def test_fake_engine_flight_and_cost_deterministic():
             assert kinds == ["prefill", "decode"]
             assert flight["records"][0]["bucket"] == "b1xt3"
             assert flight["records"][1]["tokens"] == 5
+            # the real recorder's fields, the cycle's account among them
+            assert flight["fields"] == list(FIELDS)
+            assert all(set(r) == set(FIELDS) for r in flight["records"])
+            decode = flight["records"][1]
+            assert decode["cycle_s"] == pytest.approx(5e-3 + 5e-4)
+            assert (decode["wait_s"], decode["polls"]) == (5e-3, 16)
             # Streams carry the header too (the fake knows its output
             # upfront).
             async with sess.post(
@@ -550,6 +670,17 @@ async def test_fake_engine_stall_leaves_deterministic_snapshot(tmp_path):
             assert det["device_s"] == pytest.approx(0.05)
             for key in ("waiting", "running", "swapped", "kv_occupancy"):
                 assert key in det  # queue state rides the snapshot
+            # ... and the cause: a wait that polled at its pace
+            assert (det["cause"], det["phase"]) == ("device", "wait")
+            assert det["excess_s"] == pytest.approx(0.0505 - 0.004)
+            assert stall_cause(det, det["excess_s"]) == "device"
+            async with sess.get(f"{url}/metrics") as r:
+                text = await r.text()
+            assert 'pst_engine_stalls_total{cause="device"} 1' in text
+            assert ('pst_engine_stall_seconds_total{cause="device"} 0.0465'
+                    in text)
+            for cause in STALL_CAUSES:  # every cause, stalled or not
+                assert f'pst_engine_stalls_total{{cause="{cause}"}}' in text
             # Persisted too (same naming contract as the real recorder).
             assert flight["snapshot_dir"] == str(tmp_path / "snaps")
             on_disk = load_snapshot_dir(str(tmp_path / "snaps"))

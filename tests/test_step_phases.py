@@ -10,14 +10,23 @@
   step, however many spans a phase had in it.
 - ``POST /debug/profile`` starts and stops the profiler off the event loop,
   with the Python tracer off, and says how long both took.
+- A phase knows whether its thread ran: ``pst_engine_step_offcpu_seconds``
+  once a step; the fetch counts its polls and keeps the longest time
+  between two; a cycle past the flight recorder's bar is a stall, provoked
+  here in a real engine by a ``postprocess`` that spins (``host_work``) and
+  by a full collection over a large graph (``gc``), and each leaves its
+  counter, its snapshot and its line in the log.
 """
 
 import asyncio
+import gc
+import logging
 import threading
 import time
 
 import aiohttp
 import jax
+import numpy as np
 import pytest
 from aiohttp import web
 
@@ -25,8 +34,10 @@ from perf import host_trace
 from production_stack_tpu.engine.async_engine import AsyncLLMEngine
 from production_stack_tpu.engine.config import EngineConfig
 from production_stack_tpu.engine.sequence import SamplingParams
+from production_stack_tpu.engine.runner import _fetch
 from production_stack_tpu.engine.server import create_engine_app
 from production_stack_tpu.obs import ENGINE_TELEMETRY, ENGINE_TELEMETRY_REGISTRY
+from production_stack_tpu.obs.flight import FlightRecorder, stall_cause
 
 IN_STEP = ("schedule", "batch_build", "launch", "wait", "postprocess")
 
@@ -36,14 +47,23 @@ def _cfg():
                         block_size=16, num_kv_blocks=64, overlap_decode=False)
 
 
+def _samples(family: str, suffix: str) -> dict:
+    """{labels' values: value} of one sample name of a metric family."""
+    return {
+        tuple(smp.labels.values()): smp.value
+        for metric in ENGINE_TELEMETRY_REGISTRY.collect()
+        if metric.name == family
+        for smp in metric.samples if smp.name == family + suffix
+    }
+
+
 def _phase_counts() -> dict:
     """{(phase, kind): observations} of pst_engine_step_phase_seconds."""
-    return {
-        (smp.labels["phase"], smp.labels["kind"]): smp.value
-        for metric in ENGINE_TELEMETRY_REGISTRY.collect()
-        if metric.name == "pst_engine_step_phase_seconds"
-        for smp in metric.samples if smp.name.endswith("_count")
-    }
+    return _samples("pst_engine_step_phase_seconds", "_count")
+
+
+def _grown(before: dict, after: dict) -> dict:
+    return {k: v - before.get(k, 0.0) for k, v in after.items()}
 
 
 async def _generate(engine, prompt, n):
@@ -67,6 +87,7 @@ def traced_run(tmp_path):
         try:
             await _generate(engine, [1, 2, 3, 4, 5], 3)  # compiles, untraced
             before = _phase_counts()
+            offcpu = _samples("pst_engine_step_offcpu_seconds", "_count")
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 0
             jax.profiler.start_trace(str(tmp_path), profiler_options=options)
@@ -77,9 +98,12 @@ def traced_run(tmp_path):
             finally:
                 jax.profiler.stop_trace()
             after = _phase_counts()
+            offcpu = _grown(
+                offcpu, _samples("pst_engine_step_offcpu_seconds", "_count"))
         finally:
             engine.shutdown()
-        return {k: v - before.get(k, 0.0) for k, v in after.items()}
+        return {**_grown(before, after),
+                **{("offcpu",) + k: v for k, v in offcpu.items()}}
 
     grown = asyncio.run(run())
     trace = next(tmp_path.rglob("*.xplane.pb"))
@@ -95,7 +119,8 @@ def _inside(events, outer):
 
 def test_every_phase_of_the_table_in_every_step(traced_run):
     events, _ = traced_run
-    names = {ev[0] for ev in events}
+    # a collection or a stall may fall into any capture; nothing else may
+    names = {ev[0] for ev in events} - {"pst.gc", "pst.stall"}
     assert names == {"pst." + p for p in IN_STEP + ("step", "step_info",
                                                     "intake", "no_work")}
     steps = [ev for ev in events if ev[0] == "pst.step"]
@@ -142,9 +167,13 @@ def test_histogram_counts_one_observation_per_phase_per_step(traced_run):
         assert kinds.count(kind) <= n <= kinds.count(kind) + 2
         for phase in IN_STEP[1:]:
             assert grown[(phase, kind)] == n, (phase, kind, grown)
+        # the off-CPU time of a step's phases: once a step, as the step is
+        assert grown[("offcpu", kind)] == n
     per_step = grown[("step", "prefill")] + grown[("step", "decode")]
     assert grown[("schedule", "")] == per_step >= steps
     assert grown[("intake", "")] >= per_step + grown[("no_work", "")] - 1
+    # once an intake: the cycle's record carries it, the histogram not twice
+    assert grown[("intake", "")] <= per_step + grown[("no_work", "")] + 1
     assert grown[("no_work", "")] >= 1
 
 
@@ -158,8 +187,7 @@ def test_step_info_names_the_open_step_and_costs_little():
         ENGINE_TELEMETRY.step_info("decode", bucket="b1", rows=1)
     with ENGINE_TELEMETRY.phase("no_work"):
         pass
-    grown = {k: v - before.get(k, 0.0) for k, v in _phase_counts().items()
-             if v - before.get(k, 0.0)}
+    grown = {k: v for k, v in _grown(before, _phase_counts()).items() if v}
     assert grown == {("step", "decode"): 1, ("launch", "decode"): 1,
                      ("no_work", ""): 1}
     t0 = time.perf_counter()
@@ -168,6 +196,337 @@ def test_step_info_names_the_open_step_and_costs_little():
             pass
     # ten a step must stay far below a 30 ms step; no trace is running
     assert (time.perf_counter() - t0) / 2000 < 200e-6
+
+
+class _ReadyAfter:
+    """What ``_fetch`` asks of an array, ready at the ``n``-th poll; the
+    poll before ``slow`` comes late by ``late_s``."""
+
+    def __init__(self, n, slow=0, late_s=0.0):
+        self.n, self.slow, self.late_s, self.asked = n, slow, late_s, 0
+
+    def copy_to_host_async(self):
+        pass
+
+    def is_ready(self):
+        self.asked += 1
+        if self.asked == self.slow:
+            time.sleep(self.late_s)
+        return self.asked > self.n
+
+    def __array__(self, dtype=None, copy=None):
+        return np.arange(3)
+
+
+def test_fetch_counts_its_polls_and_keeps_the_longest_gap():
+    ENGINE_TELEMETRY.reset_for_tests()
+    rec = FlightRecorder(capacity=8)
+    ENGINE_TELEMETRY.attach_flight(rec)
+    try:
+        with ENGINE_TELEMETRY.phase("step"):
+            assert list(_fetch(_ReadyAfter(5), "decode")) == [0, 1, 2]
+            # two fetches of one step: the polls add up, the gap is the longest
+            _fetch(_ReadyAfter(7, slow=3, late_s=0.05), "decode")
+        paced = rec.records()[-1]
+        assert paced["polls"] == 12
+        assert 0.05 <= paced["poll_gap_max_s"] <= paced["wait_s"] <= paced["cycle_s"]
+        with ENGINE_TELEMETRY.phase("step"):
+            _fetch(_ReadyAfter(0), "decode")  # ready at once: no poll, no gap
+        assert (rec.records()[-1]["polls"], rec.records()[-1]["poll_gap_max_s"]) == (0, 0.0)
+        # a fetch outside any step (an embedding's, on another thread) counts nowhere
+        _fetch(_ReadyAfter(3))
+        assert rec.stats()["total_steps"] == 2
+    finally:
+        ENGINE_TELEMETRY.reset_for_tests()
+
+
+def test_a_phase_knows_whether_its_thread_ran():
+    """Off-CPU is wall less the thread's own CPU time, over the phases that
+    never sleep (the intake among them: a stall of the interpreter's often
+    lands there): a postprocess or an intake that sleeps is off the CPU,
+    one that spins is on it, and a wait counts on neither side."""
+    ENGINE_TELEMETRY.reset_for_tests()
+    rec = FlightRecorder(capacity=8)
+    ENGINE_TELEMETRY.attach_flight(rec)
+    sums = _samples("pst_engine_step_offcpu_seconds", "_sum")
+    try:
+        with ENGINE_TELEMETRY.phase("intake"):
+            time.sleep(0.01)
+        with ENGINE_TELEMETRY.phase("step"):
+            ENGINE_TELEMETRY.step_info("decode", bucket="b1")
+            with ENGINE_TELEMETRY.phase("wait", "decode"):
+                time.sleep(0.03)
+            with ENGINE_TELEMETRY.phase("postprocess", "decode"):
+                time.sleep(0.04)
+                t0 = time.thread_time()
+                while time.thread_time() - t0 < 0.02:
+                    pass
+        row = rec.records()[-1]
+        assert 0.045 <= row["offcpu_s"] <= (
+            row["intake_s"] + row["postprocess_s"] - 0.015)
+        assert 0.02 <= row["thread_cpu_s"] <= row["process_cpu_s"] + 1e-3
+        assert row["wait_s"] >= 0.03 and row["cycle_s"] >= 0.1
+        assert row["intake_s"] >= 0.01 and row["gc_s"] == 0
+        grown = _grown(sums, _samples("pst_engine_step_offcpu_seconds", "_sum"))
+        assert grown[("decode",)] == pytest.approx(row["offcpu_s"], abs=1e-5)
+        # an intake that finds nothing to step closes no cycle
+        with ENGINE_TELEMETRY.phase("intake"):
+            pass
+        with ENGINE_TELEMETRY.phase("no_work"):
+            pass
+        assert rec.stats()["total_steps"] == 1
+    finally:
+        ENGINE_TELEMETRY.reset_for_tests()
+
+
+def _spin(seconds: float) -> None:
+    t0 = time.thread_time()
+    while time.thread_time() - t0 < seconds:
+        pass
+
+
+def test_a_cycle_covers_one_stretch_on_the_wall_and_on_the_cpu_clocks():
+    """In the loop a cycle starts where the one before ended, on all three
+    clocks: what the thread burns between two cycles (the record's making)
+    is in the next one's wall as it is in its CPU time, so it is not taken
+    off the off-CPU time. A step no loop runs starts at its own opening."""
+    ENGINE_TELEMETRY.reset_for_tests()
+    rec = FlightRecorder(capacity=8)
+    ENGINE_TELEMETRY.attach_flight(rec)
+
+    def cycle(intake=True):
+        if intake:
+            with ENGINE_TELEMETRY.phase("intake"):
+                pass
+        with ENGINE_TELEMETRY.phase("step"):
+            ENGINE_TELEMETRY.step_info("decode", bucket="b1")
+            with ENGINE_TELEMETRY.phase("postprocess", "decode"):
+                time.sleep(0.03)
+
+    try:
+        cycle()
+        _spin(0.03)  # between the step's end and the next intake
+        cycle()
+        row = rec.records()[-1]
+        assert row["cycle_s"] >= 0.06 and row["thread_cpu_s"] >= 0.03
+        # the spin lies in the cycle and in none of its phases
+        assert row["cycle_s"] - row["postprocess_s"] - row["intake_s"] >= 0.03
+        # the sleep and nothing of the spin: 0.03, not 0.0
+        assert 0.025 <= row["offcpu_s"] <= row["cycle_s"] - 0.03 + 2e-3
+        # steps without a loop around them: the caller's time is the caller's
+        _spin(0.03)
+        time.sleep(0.03)
+        cycle(intake=False)
+        row = rec.records()[-1]
+        assert row["cycle_s"] - row["postprocess_s"] < 0.02
+        assert row["thread_cpu_s"] < 0.02
+    finally:
+        ENGINE_TELEMETRY.reset_for_tests()
+
+
+def test_a_profiler_starting_under_a_cycle_is_no_stall():
+    """``POST /debug/profile`` starts the capture inside
+    ``profiler_starting``: it keeps the interpreter lock for tens of ms in
+    every traced window, and the cycle it falls into is recorded and held
+    to no bar, whether the start ends inside the cycle or after it. The
+    same cycle without a capture is a stall."""
+    ENGINE_TELEMETRY.reset_for_tests()
+    rec = FlightRecorder(capacity=32)
+    ENGINE_TELEMETRY.attach_flight(rec)
+    stalls = _samples("pst_engine_stalls", "_total")
+    lines = _Lines()
+    log = logging.getLogger("production_stack_tpu.obs.engine_telemetry")
+    log.addHandler(lines)
+
+    def cycle(seconds, inside=lambda: None):
+        with ENGINE_TELEMETRY.phase("intake"):
+            pass
+        with ENGINE_TELEMETRY.phase("step"):
+            ENGINE_TELEMETRY.step_info("decode", bucket="b1")
+            with ENGINE_TELEMETRY.phase("wait", "decode"):
+                inside()
+                time.sleep(seconds)
+
+    def beside(fn):  # the capture starts on an executor's thread
+        t = threading.Thread(target=fn)
+        t.start()
+        t.join()
+
+    def start_and_finish():
+        with ENGINE_TELEMETRY.profiler_starting():
+            time.sleep(0.07)
+
+    try:
+        for _ in range(9):
+            cycle(0.002)
+        cycle(0.0, lambda: beside(start_and_finish))
+        # or it is still starting when the cycle closes, and the next
+        starting = ENGINE_TELEMETRY.profiler_starting()
+        cycle(0.07, lambda: beside(starting.__enter__))
+        cycle(0.07)
+        beside(lambda: starting.__exit__(None, None, None))
+        assert rec.snapshots() == [] and not lines.lines
+        grown = _grown(stalls, _samples("pst_engine_stalls", "_total"))
+        assert len(grown) == 7 and not any(grown.values())
+        assert all(r["cycle_s"] >= 0.07 for r in rec.records()[-3:])
+        cycle(0.07)  # the capture is up: cycles are held to the bar again
+        assert len(rec.snapshots()) == 1 and len(lines.lines) == 1
+        # and the three set no baseline
+        assert rec.snapshots()[0]["detail"]["median_s"] < 0.01
+    finally:
+        log.removeHandler(lines)
+        ENGINE_TELEMETRY.reset_for_tests()
+
+
+def test_an_idle_loop_beside_it_takes_nothing_from_a_busy_one():
+    """Two engines in one process (tests leave such threads behind): the
+    idle one opens and drops a cycle every 50 ms, and the busy one's cycle
+    is its own all the same."""
+    ENGINE_TELEMETRY.reset_for_tests()
+    rec = FlightRecorder(capacity=8)
+    ENGINE_TELEMETRY.attach_flight(rec)
+
+    def idle_tick():
+        with ENGINE_TELEMETRY.phase("intake"):
+            pass
+        with ENGINE_TELEMETRY.phase("no_work"):
+            pass
+
+    def beside(fn):
+        t = threading.Thread(target=fn)
+        t.start()
+        t.join()
+
+    try:
+        with ENGINE_TELEMETRY.phase("intake"):
+            pass
+        beside(idle_tick)
+        with ENGINE_TELEMETRY.phase("step"):
+            beside(idle_tick)
+            with ENGINE_TELEMETRY.phase("wait", "decode"):
+                time.sleep(0.01)
+            ENGINE_TELEMETRY.polled(3, 0.004)
+            beside(idle_tick)
+        (row,) = rec.records()
+        assert row["polls"] == 3 and row["wait_s"] >= 0.01 and row["intake_s"] > 0
+        assert ENGINE_TELEMETRY._cycles == {}
+    finally:
+        ENGINE_TELEMETRY.reset_for_tests()
+
+
+def _stall_counters() -> dict:
+    return {(name, cause): v
+            for name in ("pst_engine_stalls", "pst_engine_stall_seconds")
+            for (cause,), v in _samples(name, "_total").items()}
+
+
+class _Lines(logging.Handler):
+    def __init__(self):
+        super().__init__(logging.WARNING)
+        self.lines = []
+
+    def emit(self, record):
+        self.lines.append(record.getMessage())
+
+
+def _spin_once(engine, seconds):
+    """The engine's ``_append_token``, spinning for ``seconds`` of the step
+    thread's own CPU time the next time it is called: host work that runs
+    long inside ``postprocess``."""
+    real, armed = engine.engine._append_token, [True]
+
+    def slow(*args, **kwargs):
+        if armed and armed.pop():
+            t0 = time.thread_time()
+            while time.thread_time() - t0 < seconds:
+                pass
+        return real(*args, **kwargs)
+
+    engine.engine._append_token = slow
+
+
+def _collect_garbage(engine, seconds):
+    """A full collection over a large graph from this thread: every thread
+    of the process stands still meanwhile."""
+    graph = [[i] for i in range(2_000_000)]
+    t0 = time.perf_counter()
+    gc.collect()
+    assert time.perf_counter() - t0 > seconds, "the graph is too small to stall"
+    del graph
+
+
+@pytest.mark.parametrize("cause,provoke,phase", [
+    ("host_work", _spin_once, "postprocess"),
+    ("gc", _collect_garbage, None),
+])
+def test_a_stall_in_a_real_engine_names_its_cause(cause, provoke, phase):
+    """Decode steps of a tiny engine take a few ms; once their bucket's bar
+    is armed, one disturbance of 0.15 s and more is a stall of that cause,
+    in the counters, in the recorder's snapshot and in the log."""
+    ENGINE_TELEMETRY.reset_for_tests()
+    lines = _Lines()
+    log = logging.getLogger("production_stack_tpu.obs.engine_telemetry")
+    log.addHandler(lines)
+
+    async def run():
+        engine = AsyncLLMEngine(_cfg())
+        engine.start(asyncio.get_running_loop())
+        try:
+            await _generate(engine, [1, 2, 3, 4, 5], 3)  # compiles
+            before = _stall_counters()
+            stream = asyncio.create_task(_generate(engine, [5, 6, 7, 8], 200))
+            flight = engine.engine.flight
+            while flight.stats()["total_steps"] < 40:  # the bar is armed
+                await asyncio.sleep(0.01)
+            provoke(engine, 0.15)
+            await stream
+            return before, _stall_counters(), flight.snapshots()
+        finally:
+            engine.shutdown()
+
+    try:
+        before, after, snaps = asyncio.run(run())
+    finally:
+        log.removeHandler(lines)
+        ENGINE_TELEMETRY.reset_for_tests()
+    grown = {k: v for k, v in _grown(before, after).items() if v}
+    tail = [s["detail"] for s in snaps if s["reason"] == "tail_outlier"]
+    if cause == "host_work" and not any(d["cause"] == cause for d in tail):
+        # Six test workers share this machine: when the spinning thread got
+        # the CPU for less than half the stall, what held it was the
+        # machine, and the record has to say so.
+        (starved,) = [d for d in tail if d["phase"] == phase]
+        assert starved["thread_cpu_s"] >= 0.15 <= starved["offcpu_s"]
+        assert starved["offcpu_s"] >= starved["excess_s"] / 2
+        cause = starved["cause"]
+        assert cause in ("machine", "interpreter")
+    # building the graph trips collections of its own, each a stall too
+    stalls = grown[("pst_engine_stalls", cause)]
+    assert stalls == 1 or (cause == "gc" and stalls >= 1), (grown, lines.lines)
+    details = [d for d in tail if d["cause"] == cause]
+    detail = details[-1]
+    assert detail["cause"] == stall_cause(detail, detail["excess_s"])
+    assert detail["kind"] == "decode" and detail["bucket"].startswith("b1")
+    assert detail["excess_s"] >= 0.1
+    seconds = grown[("pst_engine_stall_seconds", cause)]
+    if stalls == 1:
+        assert detail["excess_s"] == pytest.approx(seconds, abs=1e-3)
+    else:  # the recorder keeps its last eight snapshots only
+        assert seconds >= detail["excess_s"]
+    assert detail["cycle_s"] > detail["bar_s"] >= 0.05 > detail["median_s"]
+    assert {"intake_s", "schedule_s", "batch_build_s", "launch_s", "wait_s",
+            "postprocess_s", "offcpu_s", "polls", "poll_gap_max_s",
+            "process_cpu_s", "waiting", "running"} <= set(detail)
+    if cause == "gc":
+        assert detail["gc_s"] >= detail["excess_s"] / 2
+    else:
+        assert detail["phase"] == phase
+        assert detail["thread_cpu_s"] >= 0.15 > detail["gc_s"]
+    line = [ln for ln in lines.lines if f": {cause} (" in ln][-1]
+    assert line.startswith(f"stall {detail['excess_s']:.2f} s in "
+                           f"{detail['phase']} of decode {detail['bucket']}")
+    assert "polls, longest gap" in line and "process CPU" in line
+    assert "waiting 0, running 1" in line
 
 
 class _Server:
