@@ -51,22 +51,33 @@ widths it runs at the speed of its weight DMAs (PERF.md §6, PR 28):
   hides either, so one rule served every row count measured (1 to 1,024
   rows, faster than the scaled-weights body at each: PERF.md §6) and there
   is no second body.
-* **The activations arrive as before, split even/odd.** The caller-side
-  split is still ``xe = x[:, 0::2]``, ``xo = x[:, 1::2]`` (two XLA strided
-  slices of the small activation). Plane ``a`` holds a group's rows ``a, a +
-  4, a + 8, ...``, so the kernel joins a group's two 64-column halves into
-  ``[tn, 128]`` and reorders its lanes to the planes' K order with one
-  128 x 128 0/1 matrix on the MXU (exact: every output is one input times
-  1.0), once per group and grid step — against the 8-112 weight tiles the
-  group's own dot pushes. (A split in the planes' order made by XLA, a
+* **The activations arrive as the contraction's two contiguous halves.**
+  The caller-side split is ``xe = x[:, :din // 2]``, ``xo = x[:, din //
+  2:]``: unit-stride, 128-lane aligned slices that XLA folds into whatever
+  produced ``x`` (the split into even and odd columns that they replace was
+  a lane-strided gather before every call: 11.9-12.9 us for each
+  ``bf16[7168, 16]`` half of ``w_down``'s input at 16 rows, and with the
+  layout copies XLA put around the gathers 1.9 ms of an 18.3 ms decode
+  step: PERF.md §6, PR 37). A weight block is original rows
+  ``[2 k tk, 2 (k + 1) tk)``, so a grid step needs ``2 tk`` adjacent columns
+  of ``x``, which lie wholly in ``xe`` for the first half of the steps and in
+  ``xo`` for the second (``_tiles`` keeps ``2 tk`` a divisor of ``din / 2``).
+  Both halves ride as ``(tn, 2 tk)`` blocks; the one a step does not read
+  keeps its block index and is not fetched again, and the step selects the
+  live block. Plane ``a`` holds a group's rows ``a, a + 4, a + 8, ...``, so
+  the kernel reorders a group's 128 adjacent lanes to the planes' K order
+  with one 128 x 128 0/1 matrix on the MXU (exact: every output is one input
+  times 1.0), once per group and grid step — against the 8-112 weight tiles
+  the group's own dot pushes. (A split in the planes' order made by XLA, a
   reshape to ``[..., 32, 4]``, cost a prefill step more than the kernel
   saved: minor dimensions of 4 and 32 pad to 128 lanes.)
 
 VMEM: the blocks are double-buffered by the pipeline — 2 x (weights ≤ 1.75
-MiB + scales 8 x tc x 4 + two x blocks) + 2 x the f32 output block (≤ 3.5
-MiB) — at most 13 MiB at the widths served. ``vmem_limit_bytes`` is 112 of
-the 128 MiB all the same, to leave XLA no room to stage a layer stack of
-scales on chip around every call (see ``VMEM_LIMIT_BYTES``).
+MiB + scales 8 x tc x 4 + two x blocks of ``tn x 2 tk``, ≤ 0.5 MiB each) +
+2 x the f32 output block (≤ 3.5 MiB) — at most 14 MiB at the widths served.
+``vmem_limit_bytes`` is 112 of the 128 MiB all the same, to leave XLA no
+room to stage a layer stack of scales on chip around every call (see
+``VMEM_LIMIT_BYTES``).
 
 Layout contract (matches models/llama.py quantize_leaf_int4):
   x       [N, din]        activations (bf16/f32)
@@ -113,14 +124,16 @@ from ..device import INTERPRET_ENV, pallas_interpret
 
 GROUP = 128
 HALF = GROUP // 2  # packed rows per group
-# din is a multiple of this, so that every packed-row tile (128/256/512)
-# divides din/2 and the groups come in whole (8, tc) scale blocks.
+# din is a multiple of this, so that a packed-row tile of 128 or 256 divides
+# din/4, the packed rows under one half of x (512 does where din is a
+# multiple of 2048: ``_tiles``), and the groups come in whole (8, tc) scale
+# blocks.
 IN_ALIGN = 1024
 WEIGHT_BLOCK_BYTES = 128 * 14336  # 1.75 MiB: the largest weight block
 WIDE_ROWS = 64  # row tiles up to this keep the whole output width resident
 WIDE_TILE = 2048  # the column tile of wider row tiles
 # The call's VMEM scope, of the v5e's 128 MiB. The double-buffered blocks need
-# at most 13 MiB (module docstring); the scope is set far above that because
+# at most 14 MiB (module docstring); the scope is set far above that because
 # what it leaves is what XLA's memory-space assignment may keep on chip
 # during the call, and with more it prefetched a whole [L, G, dout] f32 stack
 # of scales (58 MB for 14336 -> 4096) before every layer's call, which reads
@@ -168,7 +181,7 @@ def _planes(w8: jax.Array) -> jax.Array:
     return jnp.concatenate(planes, axis=0)
 
 
-def _kernel(*refs, gps: int, spb: int):
+def _kernel(*refs, gps: int, spb: int, half_steps: int):
     # A stacked call has the layer in front (scalar-prefetched); only the
     # index maps read it: p_ref / s_ref are already that layer's tile, the
     # layer axis squeezed.
@@ -185,18 +198,19 @@ def _kernel(*refs, gps: int, spb: int):
     # rejects fp32 contract precision on bf16 operands: "Bad lhs type").
     exact = dt == jnp.float32
     prec = jax.lax.Precision.HIGHEST if exact else None
-    # Lane c = 32 a + j of a group's operand is the group's column 4 j + a,
-    # which sits at lane 2 j + a // 2 of xe_g (a even) or of xo_g (a odd).
+    # The step's 2 tk adjacent columns of x lie in the lower half of the
+    # contraction for the first half of the steps and in the upper half after
+    # (the block of the other half stands still meanwhile: ``_call``).
+    x_blk = jnp.where(k < half_steps, xe_ref[...], xo_ref[...])
+    # Lane c = 32 a + j of a group's operand is the group's column 4 j + a.
     lane = jax.lax.broadcasted_iota(jnp.int32, (GROUP, GROUP), 1)
-    a, j = lane >> 5, lane & 31
-    src = ((a & 1) << 6) + (j << 1) + (a >> 1)
+    src = ((lane & 31) << 2) + (lane >> 5)
     perm = (jax.lax.broadcasted_iota(jnp.int32, (GROUP, GROUP), 0)
             == src).astype(dt)
     xs, biases = [], []
     for g in range(gps):
-        cols = slice(g * HALF, (g + 1) * HALF)
         xg = jax.lax.dot_general(
-            jnp.concatenate([xe_ref[:, cols], xo_ref[:, cols]], axis=1), perm,
+            x_blk[:, g * GROUP:(g + 1) * GROUP], perm,
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
             precision=prec,
         )
@@ -225,14 +239,18 @@ def _kernel(*refs, gps: int, spb: int):
     o_ref[...] += acc
 
 
-def _tiles(n: int, dout: int) -> tuple[int, int, int]:
+def _tiles(n: int, din: int, dout: int) -> tuple[int, int, int]:
     """(tn, tc, tk) from the call's shape alone."""
     # Row tile: pad N up to a sublane-friendly size.
     tn = 256 if n > 256 else max(8, 1 << (n - 1).bit_length())
     tc = dout
     if tn > WIDE_ROWS:
         tc = max(c for c in range(128, WIDE_TILE + 1, 128) if dout % c == 0)
-    tk = next((t for t in (512, 256) if t * tc <= WEIGHT_BLOCK_BYTES), 128)
+    # A step's 2 tk columns of x must lie in one half of the contraction:
+    # 2 tk divides din / 2 (512 packed rows only for an even multiple of 1024).
+    tk = next(
+        (t for t in (512, 256)
+         if t * tc <= WEIGHT_BLOCK_BYTES and din % (4 * t) == 0), 128)
     return tn, tc, tk
 
 
@@ -245,10 +263,6 @@ def _call(x, packed, scales, li):
     dout = packed.shape[-1]
     assert packed.shape[-2] * 2 == din, (packed.shape, din)
     assert scales.shape == packed.shape[:-2] + (din // GROUP, dout), scales.shape
-    # Split even/odd contraction columns once (cheap XLA strided slices of the
-    # small activation); the kernel reorders a group's lanes itself.
-    xe = x[:, 0::2]
-    xo = x[:, 1::2]
     # The weights stream from HBM, which is what the roofline is counted
     # against. Left to itself XLA stages the scan-sliced 2-D matrix (the one
     # leaf of models/llama.py SLICED_KERNEL_INT4) in its faster on-chip space,
@@ -258,14 +272,26 @@ def _call(x, packed, scales, li):
     interpret = pallas_interpret()
     if not interpret:
         packed = pltpu.with_memory_space_constraint(packed, pltpu.HBM)
-    tn, tc, tk = _tiles(N, dout)
+    tn, tc, tk = _tiles(N, din, dout)
     pad = -N % tn
     if pad:
-        xe = jnp.pad(xe, ((0, pad), (0, 0)))
-        xo = jnp.pad(xo, ((0, pad), (0, 0)))
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    # The contraction's lower and upper half, under the names and shapes the
+    # benchmark's cost functions read (two ``[N, din/2]`` operands). Step k's
+    # weight block is original rows [2 k tk, 2 (k + 1) tk): adjacent columns
+    # of x, all in ``xe`` for the first half of the steps and all in ``xo``
+    # after. Unit-stride slices cost next to nothing (one two-output fusion
+    # a distinct x, 0.03-0.11 us at 16 rows); the split into even and odd
+    # columns they replace was a lane-strided gather before every call,
+    # 11.9-12.9 us for each ``bf16[7168, 16]`` half of down's input, 1.9 ms
+    # of an 18.3 ms decode step with its layout copies (PERF.md §6, PR 37).
+    xe = x[:, :din // 2]
+    xo = x[:, din // 2:]
     gps = tk // HALF  # groups per grid step
     spb = 8 // gps  # grid steps per (8, tc) scales block
-    grid = ((N + pad) // tn, dout // tc, din // 2 // tk)
+    nk = din // 2 // tk
+    half_steps = nk // 2
+    grid = ((N + pad) // tn, dout // tc, nk)
 
     # Index maps get the grid position, then the prefetched scalars (the
     # layer, when stacked), which lead the weight and scale block indices.
@@ -278,15 +304,20 @@ def _call(x, packed, scales, li):
         num_scalar_prefetch=len(scalars),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tn, tk), lambda i, j, k, *_: (i, k)),
-            pl.BlockSpec((tn, tk), lambda i, j, k, *_: (i, k)),
+            # (tn, 2 tk) blocks of each half; the one the step does not read
+            # keeps its index, and a block whose index stands is not fetched
+            # again: x is read once a column tile, as before.
+            pl.BlockSpec((tn, 2 * tk), lambda i, j, k, *_: (
+                i, jnp.minimum(k, half_steps - 1))),
+            pl.BlockSpec((tn, 2 * tk), lambda i, j, k, *_: (
+                i, jnp.maximum(k - half_steps, 0))),
             pl.BlockSpec(lead + (tk, tc), w_map),
             pl.BlockSpec(lead + (8, tc), s_map),
         ],
         out_specs=pl.BlockSpec((tn, tc), lambda i, j, k, *_: (i, j)),
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, gps=gps, spb=spb),
+        functools.partial(_kernel, gps=gps, spb=spb, half_steps=half_steps),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N + pad, dout), jnp.float32),
         compiler_params=pltpu.CompilerParams(

@@ -7,6 +7,7 @@ ops/int4_matmul.py.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ from production_stack_tpu.models.llama import (
 )
 from production_stack_tpu.models.registry import get_model_config
 from production_stack_tpu.ops.int4_matmul import (
+    _tiles,
     int4_matmul,
     int4_matmul_stacked,
     kernel_supports,
@@ -52,19 +54,38 @@ def _truth(x, packed, scales):
 # (din/2 over tk = 512/256/128 packed rows, by the tile's width), scale
 # blocks shared by 2 or 4 steps, widths that are no multiple of 256 or 512,
 # the whole output width up to 64 rows and column tiles above, padded row
-# tiles.
+# tiles. The activations go in as the contraction's lower and upper half and
+# a step reads its 2 tk columns from one of them: the first half of the steps
+# from ``xe``, the rest from ``xo``, so every case walks both, from one step
+# each (1024) to 28 each (14336 at tk 128).
 TRUTH_CASES = [
     (1024, 256, 5, None),
     (2048, 512, 64, None),
     (1024, 128, 1, None),
     (2048, 384, 16, None),  # two steps of 512 packed rows; 384 columns
-    (1024, 640, 17, None),  # one step; 640 columns; 17 rows pad to 32
+    (1024, 640, 17, None),  # two steps of 256; 640 columns; 17 rows pad to 32
     (1024, 4096, 16, None),  # tk 256: a scales block serves two steps
     (1024, 7296, 1, None),  # tk 128: four steps a scales block
     (1024, 4096, 65, None),  # over 64 rows: two column tiles of 2048
     (2048, 2560, 300, None),  # two row tiles of 256, column tiles of 1280
     (2048, 512, 16, 2),  # stacked, layer 2 of 3
     (1024, 4096, 65, 1),  # stacked, column tiles, layer 1 of 3
+    # An odd multiple of 1024: 512 packed rows would straddle the halves
+    # (1536 = 3 x 512 columns each), so the rule gives 256 and six steps.
+    (3072, 1024, 16, None),
+    (3072, 4096, 1, None),
+    (3072, 1024, 200, None),  # 200 rows pad to one tile of 256
+    (3072, 4096, 256, 2),  # stacked; two column tiles re-read both halves
+    # The served contractions, 4096 and 14336, at every tk.
+    (4096, 1024, 1, None),  # tk 512: two steps a half
+    (4096, 1024, 256, None),
+    (4096, 4096, 16, 1),  # stacked; tk 256: four steps a half
+    (4096, 4096, 200, None),  # padded, column tiles of 2048 at tk 512
+    (4096, 14336, 16, None),  # tk 128: eight steps a half, gate / up
+    (2048, 14336, 200, None),  # seven column tiles over one step a half
+    (14336, 1024, 16, None),  # tk 512: seven steps a half
+    (14336, 4096, 1, None),  # tk 256: fourteen steps a half, down
+    (14336, 4096, 256, None),  # prefill: tk 512, two column tiles
 ]
 
 
@@ -114,6 +135,38 @@ def test_bf16_kernel_matches_dequant_f32_dot(N):
     assert err < 1e-4, err
 
 
+# (rows, din, dout) -> (tn, tc, tk). The first six are the calls the dense
+# cell makes (16-row decode, 128-256-row prefill) and keep the tiles PR 28
+# chose; the rest are contractions whose half is an odd number of 512-row
+# steps.
+TILE_CASES = [
+    ((16, 4096, 14336), (16, 14336, 128)),
+    ((16, 14336, 4096), (16, 4096, 256)),
+    ((16, 4096, 4096), (16, 4096, 256)),
+    ((16, 4096, 1024), (16, 1024, 512)),
+    ((256, 4096, 14336), (256, 2048, 512)),
+    ((200, 14336, 4096), (256, 2048, 512)),
+    ((16, 1024, 1024), (16, 1024, 256)),
+    ((16, 3072, 1024), (16, 1024, 256)),
+    ((300, 5120, 2048), (256, 2048, 256)),
+    ((1, 3072, 14336), (8, 14336, 128)),
+]
+
+
+@pytest.mark.parametrize(
+    "shape,tiles", TILE_CASES, ids=["x".join(map(str, c)) for c, _ in TILE_CASES]
+)
+def test_tiles_keep_a_step_inside_one_half(shape, tiles):
+    """A grid step reads ``2 tk`` adjacent columns of ``x``, which must lie
+    wholly in the lower or in the upper half of the contraction: an even
+    number of steps, half of them in each."""
+    n, din, dout = shape
+    assert _tiles(n, din, dout) == tiles
+    tk = tiles[2]
+    assert (din // 2) % (2 * tk) == 0
+    assert (din // 2 // tk) % 2 == 0
+
+
 def test_kernel_support_gate():
     assert kernel_supports(4096, 14336, 128)
     assert kernel_supports(1024, 128, 128)
@@ -125,32 +178,45 @@ def test_kernel_support_gate():
 STACK_L = 4
 
 
-@pytest.fixture(scope="module")
-def stack():
+@functools.lru_cache(maxsize=None)
+def _stack(din, dout):
     """Four layers of different weights: a wrong layer offset cannot pass."""
     rng = np.random.default_rng(7)
     w = jnp.asarray(
-        rng.normal(size=(STACK_L, 2048, 512)).astype(np.float32) * 0.02
+        rng.normal(size=(STACK_L, din, dout)).astype(np.float32) * 0.02
     )
     packed, scales = quantize_leaf_int4(w)
     assert not np.array_equal(np.asarray(packed[0]), np.asarray(packed[1]))
     return packed, scales
 
 
-@pytest.mark.parametrize("li", [0, 1, STACK_L - 1])
-@pytest.mark.parametrize("N", [16, 300], ids=["decode16", "prefill300"])
-def test_stacked_equals_2d_kernel_exactly(stack, N, li):
+# (din, dout, N, li). The first six are one step a half at tk 512; then an odd
+# multiple of 1024 (six steps of 256) and 4096 -> 4096 (eight steps of 256 up
+# to 64 rows, four of 512 under column tiles above), at one row, a decode
+# batch, a padded row tile and a whole one.
+STACK_CASES = (
+    [(2048, 512, N, li) for N in (16, 300) for li in (0, 1, STACK_L - 1)]
+    + [(3072, 1024, N, 2) for N in (1, 16, 200, 256)]
+    + [(4096, 4096, N, 1) for N in (1, 16, 200, 256)]
+)
+
+
+@pytest.mark.parametrize(
+    "din,dout,N,li", STACK_CASES,
+    ids=[f"{d}x{o}-n{n}-layer{li}" for d, o, n, li in STACK_CASES],
+)
+def test_stacked_equals_2d_kernel_exactly(din, dout, N, li):
     """The stacked call at layer ``li`` (traced, as the layer scan hands it
     over) is the 2-D call on that layer's slice, bit for bit: same tiles,
     same order, only the DMA's layer offset differs."""
-    packed, scales = stack
+    packed, scales = _stack(din, dout)
     rng = np.random.default_rng(N + li)
-    x = jnp.asarray(rng.normal(size=(N, 2048)), jnp.bfloat16)
+    x = jnp.asarray(rng.normal(size=(N, din)), jnp.bfloat16)
     want = np.asarray(int4_matmul(x, packed[li], scales[li]))
     got = np.asarray(
         jax.jit(int4_matmul_stacked)(x, packed, scales, jnp.int32(li))
     )
-    assert got.shape == (N, 512)
+    assert got.shape == (N, dout)
     assert np.array_equal(got, want)
     other = np.asarray(int4_matmul(x, packed[(li + 1) % STACK_L],
                                    scales[(li + 1) % STACK_L]))
@@ -249,18 +315,24 @@ def test_layer_scan_does_not_slice_kernel_int4_leaves(entry):
     ]
 
 
-def _pallas_calls(jaxpr):
-    """(name, operand avals) of every ``pallas_call`` in a jaxpr."""
-    found = []
+def _subjaxprs(jaxpr):
+    """A jaxpr and every jaxpr nested in its equations' parameters."""
+    yield jaxpr
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            found.append((eqn.params["name"], [v.aval for v in eqn.invars]))
         for v in eqn.params.values():
             for sub in v if isinstance(v, (list, tuple)) else [v]:
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    found += _pallas_calls(inner)
-    return found
+                    yield from _subjaxprs(inner)
+
+
+def _pallas_calls(jaxpr):
+    """(name, operand avals) of every ``pallas_call`` in a jaxpr."""
+    return [
+        (eqn.params["name"], [v.aval for v in eqn.invars])
+        for sub in _subjaxprs(jaxpr) for eqn in sub.eqns
+        if eqn.primitive.name == "pallas_call"
+    ]
 
 
 def test_kernel_call_names_and_operands_as_the_benchmark_reads_them():
@@ -292,6 +364,39 @@ def test_kernel_call_names_and_operands_as_the_benchmark_reads_them():
                 ((8, 512), "float32"), ((8, 512), "float32"),
                 ((L, 512, 1024), "int8"), ((L, 8, 1024), "float32"),
             ]
+
+
+def test_kernel_activations_are_unit_stride_halves_of_x():
+    """What XLA runs before a call is decided by how the wrapper makes the
+    two activation operands: each is a ``slice`` of the call's ``x`` with
+    unit strides, ``[N, din/2]``, the lower half then the upper. No strided
+    slice (the even/odd split that cost 1.4 ms of a decode step) and no
+    gather can come back unnoticed, in any of the model's seven calls."""
+    model, params = _eligible_model()
+    jaxpr = jax.make_jaxpr(
+        lambda p, *a: model.forward(p, *a, attn_impl="gather")
+    )(params, *_forward_args(model))
+    seen = []
+    for sub in _subjaxprs(jaxpr.jaxpr):
+        calls = [e for e in sub.eqns if e.primitive.name == "pallas_call"]
+        if not calls:
+            continue
+        made_by = {v: e for e in sub.eqns for v in e.outvars}
+        for call in calls:
+            assert call.params["name"].startswith("int4_matmul")
+            # Operands: (layer,) xe, xo, packed, scales.
+            xe, xo = (made_by[v] for v in call.invars[-4:-2])
+            src = xe.invars[0]
+            n, din = src.aval.shape
+            assert xo.invars[0] is src
+            for eqn, start in ((xe, 0), (xo, din // 2)):
+                assert eqn.primitive.name == "slice"
+                assert eqn.params["strides"] in (None, (1, 1))
+                assert eqn.params["start_indices"] == (0, start)
+                assert eqn.params["limit_indices"] == (n, start + din // 2)
+                assert eqn.outvars[0].aval.shape == (n, din // 2)
+            seen.append(call.params["name"])
+    assert sorted(seen) == ["int4_matmul"] + ["int4_matmul_stacked"] * 6
 
 
 def test_dense_stacked_leaf_and_moe_bank_of_same_rank_are_told_apart():
