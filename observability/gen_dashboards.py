@@ -408,6 +408,28 @@ def fleet_dashboard():
          "collections s paused /s"),
     ], 12, 128))
 
+    # Page groups of a model whose window layers keep their own
+    # (docs/engine.md "Model classes"): residency by group, pages released
+    # below the window, and the share of prefill positions that ran the
+    # cross-decoder.
+    p.append(panel("KV pages in use by group", [
+        ('sum({__name__="pst:kv_pages_in_use"}) by (group)', "{{group}}"),
+        ('sum({__name__="pst:state_slots_in_use"})', "state slots"),
+        ('sum(rate({__name__="pst:window_pages_released_total"}[5m]))',
+         "window pages released/s"),
+    ], 0, 129))
+    p.append(panel("Window residency and skipped cross-decoder", [
+        ('sum(rate({__name__="pst:window_page_steps_total"}[5m])) / '
+         'sum(rate({__name__="pst:window_whole_context_page_steps_total"}[5m]))',
+         "window pages held / whole context"),
+        ('sum(rate({__name__="pst:cross_decoder_positions_total"}[5m])) / '
+         'sum(rate({__name__="pst:prefill_tokens_total"}[5m]))',
+         "cross-decoder positions / prefill tokens"),
+        ('sum(rate({__name__="pst:prefill_tokens_total"}[5m])) / '
+         'sum(rate({__name__="pst:prefill_bucket_positions_total"}[5m]))',
+         "prefill tokens / bucket positions"),
+    ], 8, 129, unit="percentunit"))
+
     # Row 16 — Disagg (docs/disagg.md): the streamed P/D handoff's
     # health. Overlap p50 vs transfer p50 shows how much of the prefill
     # wall the decode leg hides; fallbacks by reason is the degradation

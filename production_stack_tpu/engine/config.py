@@ -225,6 +225,19 @@ def state_slot_count(cfg: EngineConfig) -> int:
     return cfg.max_num_seqs + max(2, cfg.max_num_seqs // 8)
 
 
+def window_block_count(cfg: EngineConfig, model_cfg) -> int:
+    """Pages of the window group a model with sliding-window layers is
+    given (0 for any other): every sequence's steady residency (the
+    window's pages, one where it straddles, one for the token being
+    written) and twice a step's prefill budget for the chunks in flight.
+    From the model's window and the engine's own limits: no flag."""
+    if not getattr(model_cfg, "window_pages", False):
+        return 0
+    pages = lambda tokens: -(-tokens // cfg.block_size)  # noqa: E731
+    return (cfg.max_num_seqs * (pages(model_cfg.sliding_window) + 2)
+            + 2 * pages(cfg.max_prefill_tokens))
+
+
 def _both(why: str) -> dict:
     return {"recurrent": why, "latent_pages": why}
 
@@ -239,13 +252,17 @@ def _refusals(cfg: EngineConfig):
     return [
         (cfg.enable_prefix_caching, "--enable-prefix-caching", {
             "recurrent": "a cached page list carries no state snapshot; "
-                         "pass --no-enable-prefix-caching"}),
+                         "pass --no-enable-prefix-caching",
+            "window_pages": "a released window page cannot be matched; "
+                            "pass --no-enable-prefix-caching"}),
         (cfg.kv_swap, "--kv-swap", {
             "recurrent": "a parked sequence's state is not swapped; pass "
                          "--no-kv-swap (preemption is by recompute)",
             "latent_pages": "the swap stash frames a page as a K and a V "
                             "half (engine/swap.py); pass --no-kv-swap "
-                            "(preemption is by recompute)"}),
+                            "(preemption is by recompute)",
+            "window_pages": "the swap stash knows one page group; pass "
+                            "--no-kv-swap (preemption is by recompute)"}),
         (cfg.cpu_offload_blocks > 0, "--cpu-offload-blocks", {
             "recurrent": "host-tier pages carry no state",
             "latent_pages": "the host tier frames a page as a K and a V "
@@ -279,12 +296,16 @@ def _refusals(cfg: EngineConfig):
          "--kv-cache-dtype", {
             "latent_pages": "one-byte latents are not built (the decode "
                             "kernel folds keys and values out of one "
-                            "two-byte buffer)"}),
+                            "two-byte buffer)",
+            "window_pages": "one-byte pages of paired [k1 | k2] heads are "
+                            "not calibrated (the differential attention "
+                            "subtracts two softmax outputs)"}),
     ]
 
 
 _HAS = {"recurrent": "has recurrent (state-space) layers",
-        "latent_pages": "keeps pages of latents (MLA)"}
+        "latent_pages": "keeps pages of latents (MLA)",
+        "window_pages": "releases its window layers' pages below the window"}
 
 
 def refuse_unserved(cfg: EngineConfig, model_cfg) -> None:
@@ -347,6 +368,11 @@ def resolve_num_kv_blocks(
         budget = int(hbm * cfg.hbm_utilization) - param_bytes_per_device
         if getattr(model_cfg, "recurrent", False):
             budget -= model_cfg.state_bytes_per_slot() * (state_slot_count(cfg) + 1)
+        if getattr(model_cfg, "window_pages", False):
+            # The window group is sized by what the sequences can hold
+            # there; the global group takes the rest.
+            budget -= (model_cfg.window_page_bytes(cfg.block_size, dtype_size)
+                       * window_block_count(cfg, model_cfg))
     n = max(budget // page_bytes, cfg.max_num_seqs * 2)
     # Never fewer pages than one full-length sequence needs.
     n = max(n, -(-cfg.max_model_len // cfg.block_size) + 1)

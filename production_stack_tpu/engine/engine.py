@@ -128,7 +128,7 @@ class LLMEngine:
         else:
             self.allocator = BlockAllocator(
                 self.runner.num_blocks, cfg.block_size, cfg.enable_prefix_caching,
-                state_slots=self.runner.state_slots,
+                **self._pool_groups(),
             )
         # Streamed disagg KV handoff (docs/disagg.md): a producer engine
         # ships each prefill chunk's committed pages under the request's
@@ -492,7 +492,7 @@ class LLMEngine:
                 self.runner.num_blocks,
                 self.cfg.block_size,
                 self.cfg.enable_prefix_caching,
-                state_slots=self.runner.state_slots,
+                **self._pool_groups(),
             )
         self.scheduler.allocator = self.allocator
         self.resident_chunk_hashes.clear()
@@ -1153,6 +1153,16 @@ class LLMEngine:
     # Metrics snapshot for the server layer
     # ------------------------------------------------------------------
 
+    def _pool_groups(self) -> dict:
+        """What the allocator owns beside the global pages: the state slots
+        of a model with recurrent layers, the page group of one with
+        sliding-window layers."""
+        out = {"state_slots": self.runner.state_slots}
+        if self.runner.window_blocks:
+            out["window_blocks"] = self.runner.window_blocks
+            out["window_tokens"] = self.runner.model_cfg.sliding_window
+        return out
+
     def stats(self) -> Dict[str, float]:
         out = {
             "num_requests_running": float(self.scheduler.num_running),
@@ -1200,6 +1210,20 @@ class LLMEngine:
             out["state_slot_waits_total"] = float(
                 self.allocator.state_slot_waits
             )
+        out["kv_pages_in_use"] = float(
+            self.allocator.num_blocks - self.allocator.num_free)
+        out["prefill_tokens_total"] = float(self.runner.prefill_tokens_total)
+        out["prefill_bucket_positions_total"] = float(
+            self.runner.prefill_bucket_positions_total)
+        if self.runner.window_blocks:
+            out["window_pages_in_use"] = float(
+                self.allocator.window_pages_in_use)
+            out["window_pages_released_total"] = float(
+                self.allocator.window_pages_released)
+            out["window_page_steps_total"] = float(
+                self.runner.window_page_steps_total)
+            out["window_whole_context_page_steps_total"] = float(
+                self.runner.window_whole_context_page_steps_total)
         # what the model's steps reported, under the model's own names
         for name, total in zip(self.runner.aux_names,
                                self.runner.step_aux_totals):

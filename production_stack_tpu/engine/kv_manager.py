@@ -39,6 +39,8 @@ class BlockAllocator:
         enable_prefix_caching: bool = True,
         on_evict: Optional[Callable[[int, int], None]] = None,
         state_slots: int = 0,
+        window_blocks: int = 0,
+        window_tokens: int = 0,
     ):
         # Recurrent-state slots (a model with state-space layers): one a
         # sequence from its first scheduling to the release of its pages,
@@ -46,6 +48,18 @@ class BlockAllocator:
         self.state_slots = state_slots
         self._free_slots: List[int] = list(range(state_slots - 1, -1, -1))
         self.state_slot_waits = 0  # admissions that found no slot free
+        # A second group of pages, for a model's sliding-window layers
+        # (``window_tokens`` the window): a sequence's pages there are
+        # released as it advances past them, so it never holds more than
+        # ``window_bound`` whatever its length. 0 pages: no such group. The
+        # group is sized for ``max_num_seqs`` such residencies
+        # (``engine/config.py::window_block_count``), so admission, which
+        # stops at that many sequences, has counted it; running out is
+        # ``NoFreeBlocksError`` all the same (preemption by recompute).
+        self.window_blocks = window_blocks
+        self.window_tokens = window_tokens
+        self._free_window: List[int] = list(range(window_blocks - 1, -1, -1))
+        self.window_pages_released = 0
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.enable_prefix_caching = enable_prefix_caching
@@ -166,10 +180,58 @@ class BlockAllocator:
     def state_slots_in_use(self) -> int:
         return self.state_slots - len(self._free_slots)
 
+    # -- the window group -------------------------------------------------
+
+    @property
+    def window_pages_in_use(self) -> int:
+        return self.window_blocks - len(self._free_window)
+
+    @property
+    def window_steady(self) -> int:
+        """Pages of the window group a sequence holds between steps: the
+        window's, one more where it straddles a page, one for the token
+        being written."""
+        return -(-self.window_tokens // self.block_size) + 2
+
+    def window_bound(self, chunk_tokens: int) -> int:
+        """The most pages of the window group one sequence holds while a
+        chunk of ``chunk_tokens`` is written."""
+        return self.window_steady + -(-chunk_tokens // self.block_size)
+
+    def trim_window(self, seq) -> None:
+        """Release ``seq``'s window-group pages that lie wholly below its
+        window: no later query sees them (a query at position ``t`` sees
+        ``t - window + 1 .. t``, and the next is at ``num_computed_tokens``
+        or later), and the kernels neither fetch nor fold them. The table
+        entry reads 0 from then on."""
+        ids = seq.window_block_ids
+        below = max(seq.num_computed_tokens - self.window_tokens, 0) // self.block_size
+        for j in range(seq.window_released, min(below, len(ids))):
+            self._free_window.append(ids[j])
+            ids[j] = 0
+            seq.window_released = j + 1
+            self.window_pages_released += 1
+
+    def advance_window(self, seq, up_to_tokens: int) -> None:
+        """Window-group pages for ``seq`` up to ``up_to_tokens``, after
+        releasing what fell below its window. All or nothing."""
+        if not self.window_blocks:
+            return
+        self.trim_window(seq)
+        need = -(-up_to_tokens // self.block_size) - len(seq.window_block_ids)
+        if need > len(self._free_window):
+            raise NoFreeBlocksError("out of window-group KV blocks")
+        for _ in range(need):
+            seq.window_block_ids.append(self._free_window.pop())
+
     def release_sequence(self, seq) -> None:
-        """Give back what ``seq`` holds: its pages and its state slot."""
+        """Give back what ``seq`` holds: its pages of both groups and its
+        state slot."""
         self.release_all(seq.block_ids)
         seq.block_ids = []
+        self._free_window.extend(seq.window_block_ids[seq.window_released:])
+        seq.window_block_ids = []
+        seq.window_released = 0
         if seq.state_slot is not None:
             self._free_slots.append(seq.state_slot)
             seq.state_slot = None

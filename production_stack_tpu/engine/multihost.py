@@ -111,8 +111,7 @@ def run_follower(runner, bridge: Optional[HostBridge] = None) -> None:
         elif kind == "burst_start":
             runner._dispatch_burst_start(*payload)
         elif kind == "burst_cont":
-            tables, kv_lens = payload
-            runner._dispatch_burst_continue(tables, kv_lens)
+            runner._dispatch_burst_continue(payload)
         elif kind == "spec_verify":
             runner._dispatch_spec_verify(payload)
         else:  # future-proof: unknown step kinds are fatal (order contract)

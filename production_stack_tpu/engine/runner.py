@@ -56,6 +56,7 @@ from .config import (
     refuse_unserved,
     resolve_num_kv_blocks,
     state_slot_count,
+    window_block_count,
 )
 from .scheduler import PrefillItem
 from .sequence import Sequence
@@ -142,6 +143,20 @@ class ModelRunner:
         refuse_unserved(cfg, self.model_cfg)
         if self._recurrent:
             self.state_slots = state_slot_count(cfg)
+        # A model whose window layers keep a page group of their own
+        # (released below the window) is handed that group's tables too.
+        self.window_blocks = window_block_count(cfg, self.model_cfg)
+        # A model whose prefill step runs its cross-decoder on sampled
+        # positions alone is told which rows those are (``sample_rows``).
+        self._skips_cross = bool(getattr(self.model, "SKIPS_CROSS_DECODER", False))
+        # Tokens prefill steps computed, beside the positions their buckets
+        # hold (rows x chunk, padding included).
+        self.prefill_tokens_total = 0
+        self.prefill_bucket_positions_total = 0
+        # Window-group pages the steps' rows held, summed over steps, beside
+        # what the same rows would hold were every page kept.
+        self.window_page_steps_total = 0
+        self.window_whole_context_page_steps_total = 0
         # Rows a step appends to its packed tokens (the model's step_aux,
         # one for each name in its AUX_NAMES), summed here as they are
         # fetched.
@@ -302,7 +317,9 @@ class ModelRunner:
             slots = batch["state_slots"]
             if active is not None:
                 slots = jnp.where(active, slots, scratch_slot)
-            return {"state_slots": slots, **budget}
+            more = {k: batch[k] for k in ("window_tables", "sample_rows")
+                    if k in batch}
+            return {"state_slots": slots, **more, **budget}
 
         def with_aux(packed, kv_cache):
             """What the model's step reports beside its tokens rides the
@@ -852,6 +869,8 @@ class ModelRunner:
     def _dispatch_restore_kv(self) -> None:
         cache_sh = self._cache_sharding()
         pools = {"state_slots": self.state_slots} if self._recurrent else {}
+        if self.window_blocks:
+            pools["window_blocks"] = self.window_blocks
         # Allocated under jit so each device zero-fills only its own shard:
         # built eagerly the whole cache would land on device 0 first, and a
         # tp-sharded cache is sized to fill every device.
@@ -950,6 +969,16 @@ class ModelRunner:
         n = len(seqs)
         # state_slots: rows whose recurrent state the step reads and writes
         slots = {"state_slots": n} if self._recurrent else {}
+        if self.window_blocks:
+            # window_tokens: what the window layers read, a row at most its
+            # window; window_pages: what the rows hold in that group
+            win = self.model_cfg.sliding_window
+            slots["window_tokens"] = int(
+                np.minimum(batch["kv_lens"][:n] + kv_ahead, win).sum())
+            whole = sum(len(s.window_block_ids) for s in seqs)
+            slots["window_pages"] = whole - sum(s.window_released for s in seqs)
+            self.window_page_steps_total += slots["window_pages"]
+            self.window_whole_context_page_steps_total += whole
         ENGINE_TELEMETRY.step_info(
             kind, bucket=bucket, rows=n, new_tokens=new_tokens,
             kv_tokens=int(batch["kv_lens"][:n].sum()) + n * kv_ahead,
@@ -1262,6 +1291,9 @@ class ModelRunner:
             for i, s in enumerate(members):
                 tables[i] = self._table_row(s, Wb)
                 kv_lens[i] = 0 if s.is_finished else max(s.num_tokens, 1)
+            refresh = {"block_tables": tables, "kv_lens": kv_lens}
+            if self.window_blocks:
+                refresh["window_tables"] = self._window_tables(members, Bb, Wb)
             alive = sum(1 for s in members if not s.is_finished)
             if tel is not None:
                 # The host's view lags the device by the burst in flight:
@@ -1276,8 +1308,8 @@ class ModelRunner:
         t0 = time.perf_counter()
         with self._device_lock:
             if self.publisher is not None:
-                self.publisher.announce("burst_cont", (tables, kv_lens))
-            rows = self._dispatch_burst_continue(tables, kv_lens)
+                self.publisher.announce("burst_cont", refresh)
+            rows = self._dispatch_burst_continue(refresh)
         if tel is not None:
             # The continuation was dispatched BEFORE the previous burst's
             # tokens were even read: the device runs the two back-to-back,
@@ -1303,14 +1335,14 @@ class ModelRunner:
         return rows
 
     def _dispatch_burst_continue(
-        self, tables: np.ndarray, kv_lens: np.ndarray
+        self, refresh: Dict[str, np.ndarray]
     ) -> np.ndarray:
+        """``refresh``: what the host renews of the burst's batch, the block
+        tables (both groups') and ``kv_lens``."""
         st = self._burst
         prev = st["toks"]
         with ENGINE_TELEMETRY.phase("launch", "decode", pipelined=1):
-            st["batch"].update(
-                self._put_batch({"block_tables": tables, "kv_lens": kv_lens})
-            )
+            st["batch"].update(self._put_batch(refresh))
             toks, tokens, positions, seed, counts, self.kv_cache = (
                 self._burst_fn(st["n"])(
                     self.params, self.kv_cache, st["batch"], st["tokens"],
@@ -1510,6 +1542,8 @@ class ModelRunner:
         real = sum(it.end - it.start for it in items)
         bucket = f"b{Bb}xt{Tb}"
         self._step_info("prefill", bucket, [it.seq for it in items], batch, real)
+        self.prefill_tokens_total += real
+        self.prefill_bucket_positions_total += Bb * Tb
         return (
             self._tel_key("prefill", batch, extras),
             bucket,
@@ -1663,7 +1697,7 @@ class ModelRunner:
     # Warmup precompilation (engine/precompile.py drives this)
     # ------------------------------------------------------------------
 
-    def _warmup_sampling_arrays(self, B: int) -> Dict[str, np.ndarray]:
+    def _warmup_sampling_arrays(self, B: int, W: int) -> Dict[str, np.ndarray]:
         """The sampling-array tree every live batch carries, all-neutral.
         Shapes and dtypes must match ``_sampling_arrays`` exactly — they
         are part of both the jit trace and the telemetry shape key."""
@@ -1677,7 +1711,7 @@ class ModelRunner:
         if self.cfg.enable_lora:
             out["lora_idx"] = np.zeros(B, np.int32)
             out["lora_scale"] = np.zeros(B, np.float32)
-        out.update(self._slot_rows([], B))
+        out.update(self._slot_rows([], B, W))
         return out
 
     def warmup_bucket(self, bucket) -> None:
@@ -1725,7 +1759,7 @@ class ModelRunner:
             "write_idx": np.full((Bb, 1), self._drop_slot, np.int32),
             "last_idx": np.zeros(Bb, np.int32),
         }
-        batch.update(self._warmup_sampling_arrays(Bb))
+        batch.update(self._warmup_sampling_arrays(Bb, Wb))
         key = self._tel_key("decode", batch, (bucket.want_lp, bucket.greedy))
         t0 = time.perf_counter()
         self._run(batch, bucket.want_lp, bucket.greedy, "decode")
@@ -1741,7 +1775,7 @@ class ModelRunner:
             "block_tables": np.zeros((Bb, Wb), np.int32),
             "kv_lens": np.zeros(Bb, np.int32),
         }
-        batch.update(self._warmup_sampling_arrays(Bb))
+        batch.update(self._warmup_sampling_arrays(Bb, Wb))
         if getattr(bucket, "penalized", False):
             # The dense penalty form _penalty_counts_for builds for live
             # penalized bursts: all-neutral state, exact same shapes.
@@ -1780,7 +1814,9 @@ class ModelRunner:
             "kv_lens": np.zeros(Bb, np.int32),
             "last_idx": np.zeros(Bb, np.int32),
         }
-        batch.update(self._warmup_sampling_arrays(Bb))
+        batch.update(self._warmup_sampling_arrays(Bb, Wb))
+        if self._skips_cross:
+            batch["sample_rows"] = np.zeros(Bb, bool)
         key = self._tel_key("prefill", batch, (bucket.want_lp, bucket.greedy))
         t0 = time.perf_counter()
         self._run(batch, bucket.want_lp, bucket.greedy, "prefill")
@@ -1799,7 +1835,7 @@ class ModelRunner:
             "kv_lens": np.zeros(Bb, np.int32),
             "last_idx": np.zeros(Bb, np.int32),
         }
-        batch.update(self._warmup_sampling_arrays(Bb))
+        batch.update(self._warmup_sampling_arrays(Bb, Wb))
         key = self._tel_key("spec_verify", batch, (K,))
         t0 = time.perf_counter()
         with self._device_lock:
@@ -1828,15 +1864,29 @@ class ModelRunner:
     # Batch construction (host side, numpy)
     # ------------------------------------------------------------------
 
-    def _slot_rows(self, seqs: List[Sequence], B: int) -> Dict[str, np.ndarray]:
+    def _slot_rows(
+        self, seqs: List[Sequence], B: int, W: int
+    ) -> Dict[str, np.ndarray]:
         """``state_slots`` [B] for a model with recurrent layers: each
-        row's slot, padding rows the scratch slot."""
+        row's slot, padding rows the scratch slot. ``window_tables`` [B, W]
+        for a model with a window page group: each row's pages there by the
+        block table's logical index (a released entry reads 0)."""
         if not self._recurrent:
             return {}
         slots = np.full(B, self.state_slots, np.int32)
         for i, s in enumerate(seqs):
             slots[i] = s.state_slot
-        return {"state_slots": slots}
+        out = {"state_slots": slots}
+        if self.window_blocks:
+            out["window_tables"] = self._window_tables(seqs, B, W)
+        return out
+
+    def _window_tables(self, seqs: List[Sequence], B: int, W: int) -> np.ndarray:
+        tables = np.zeros((B, W), np.int32)
+        for i, s in enumerate(seqs):
+            n = min(len(s.window_block_ids), W)
+            tables[i, :n] = s.window_block_ids[:n]
+        return tables
 
     def _table_row(self, seq: Sequence, width: int) -> np.ndarray:
         row = np.zeros(width, np.int32)
@@ -1898,7 +1948,7 @@ class ModelRunner:
         if not multi:
             batch["write_idx"] = write_idx
             batch["last_idx"] = last_idx
-        batch.update(self._slot_rows(seqs, Bb))
+        batch.update(self._slot_rows(seqs, Bb, Wb))
         batch.update(self._sampling_arrays(seqs, Bb))
         return batch
 
@@ -1938,7 +1988,16 @@ class ModelRunner:
             "kv_lens": kv_lens,
             "last_idx": last_idx,
         }
-        batch.update(self._slot_rows([it.seq for it in items], Bb))
+        batch.update(self._slot_rows([it.seq for it in items], Bb, Wb))
+        if self._skips_cross:
+            # rows whose chunk ends what they have to prefill: the only
+            # ones a token is sampled from
+            batch["sample_rows"] = np.zeros(Bb, bool)
+            for i, it in enumerate(items):
+                s = it.seq
+                batch["sample_rows"][i] = it.end >= (
+                    s.num_prompt_tokens if not s.output_token_ids
+                    else s.num_tokens - 1)
         batch.update(self._sampling_arrays([it.seq for it in items], Bb))
         return batch
 

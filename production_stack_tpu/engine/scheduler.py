@@ -248,6 +248,11 @@ class Scheduler:
         # device step — not a prefill chunk, not a decode slot, not even an
         # admission that pins pages.
         self._shed_expired(out)
+        if self.allocator.window_blocks:
+            # A sequence that is not in this step still gives back the
+            # window-group pages its last step moved past.
+            for seq in self.running:
+                self.allocator.trim_window(seq)
         self._admit(out)
         # Fair timeslicing: if parked/queued work remains after admission,
         # rotate out the running sequence with the most decode progress past
@@ -597,6 +602,7 @@ class Scheduler:
             try:
                 for _ in range(seq.blocks_needed(up_to_tokens, self.allocator.block_size)):
                     seq.block_ids.append(self.allocator.allocate())
+                self.allocator.advance_window(seq, up_to_tokens)
                 return True
             except NoFreeBlocksError:
                 victim = self._pick_victim(exclude=protect or seq)

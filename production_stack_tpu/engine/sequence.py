@@ -153,6 +153,11 @@ class Sequence:
         # Recurrent-state slot (models with state-space layers; the
         # allocator owns it with the pages): None until first scheduled.
         self.state_slot: Optional[int] = None
+        # Pages of the window group (a model with sliding-window layers),
+        # by the same logical index as ``block_ids``; the first
+        # ``window_released`` entries were given back and read 0.
+        self.window_block_ids: List[int] = []
+        self.window_released = 0
         self.num_computed_tokens = 0  # tokens whose KV is resident
         self.num_cached_prompt_tokens = 0  # prefix-cache hits at admission
         self.block_hashes: List[int] = []  # hash per committed block

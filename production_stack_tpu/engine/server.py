@@ -111,8 +111,12 @@ class EngineMetrics:
         self.registry = CollectorRegistry()
         label = {"model_name": model}
 
-        def gauge(name, doc):
-            g = Gauge(name, doc, ["model_name"], registry=self.registry)
+        def gauge(name, doc, by=()):
+            """The model's child; with further labels ``by``, a function
+            from their values to the child (as ``counter`` below)."""
+            g = Gauge(name, doc, ["model_name", *by], registry=self.registry)
+            if by:
+                return lambda *values: g.labels(*label.values(), *values)
             return g.labels(**label)
 
         def counter(name, doc, by=()):
@@ -205,6 +209,45 @@ class EngineMetrics:
             "pst:state_slot_waits",
             "admissions left queued because every recurrent-state slot "
             "was held",
+        )
+        # Page groups and the skipped cross-decoder (PERF.md §3): the global
+        # group for every model, the rest for one whose window layers keep
+        # a group of their own.
+        self.kv_pages_in_use = gauge(
+            "pst:kv_pages_in_use",
+            "KV pages held by sequences or the prefix cache, by page group",
+            by=("group",),
+        )
+        self.window_page_steps = counter(
+            "pst:window_page_steps",
+            "window-group pages held by the rows of each step, summed over "
+            "steps",
+        )
+        self.window_whole_context_page_steps = counter(
+            "pst:window_whole_context_page_steps",
+            "window-group pages the same rows would hold were every page "
+            "kept for the whole context, summed over steps",
+        )
+        self.window_pages_released = counter(
+            "pst:window_pages_released",
+            "window-group pages released because their sequence moved past "
+            "them (below its sliding window)",
+        )
+        self.prefill_tokens = counter(
+            "pst:prefill_tokens",
+            "tokens prefill steps computed (every layer that runs on every "
+            "token)",
+        )
+        self.prefill_bucket_positions = counter(
+            "pst:prefill_bucket_positions",
+            "positions the prefill steps' buckets hold (rows x chunk, "
+            "padding included)",
+        )
+        self.cross_decoder_positions = counter(
+            "pst:cross_decoder_positions",
+            "positions fetched prefill steps ran the cross-decoder on, "
+            "counted on the device from the batch it was handed (a model "
+            "that skips it for all but each row's last position)",
         )
         self.moe_pairs_routed = counter(
             "pst:moe_pairs_routed",
@@ -379,8 +422,18 @@ class EngineMetrics:
             stats.get("decode_dispatches_total", 0),
         )
         self.state_slots_in_use.set(stats.get("state_slots_in_use", 0))
+        self.kv_pages_in_use("global").set(stats.get("kv_pages_in_use", 0))
+        if "window_pages_in_use" in stats:
+            self.kv_pages_in_use("window").set(stats["window_pages_in_use"])
         for metric, key in (
             (self.state_slot_waits, "state_slot_waits_total"),
+            (self.window_pages_released, "window_pages_released_total"),
+            (self.window_page_steps, "window_page_steps_total"),
+            (self.window_whole_context_page_steps,
+             "window_whole_context_page_steps_total"),
+            (self.prefill_tokens, "prefill_tokens_total"),
+            (self.prefill_bucket_positions, "prefill_bucket_positions_total"),
+            (self.cross_decoder_positions, "cross_decoder_positions_total"),
             (self.moe_pairs_routed, "moe_pairs_routed_total"),
             (self.moe_pairs_held, "moe_pairs_held_total"),
             (self.moe_busiest_expert_pairs, "moe_busiest_expert_pairs_total"),

@@ -1441,8 +1441,9 @@ def load_hf_params(
 def config_from_hf_json(config_path: str, name: str = ""):
     """Build the model config of an HF ``config.json``: a
     :class:`LlamaConfig`, or a class's own for ``model_type: nemotron_h``
-    (``models/nemotron_h.py``) and ``glm4_moe_lite``
-    (``models/glm4_moe_lite.py``)."""
+    (``models/nemotron_h.py``), ``glm4_moe_lite``
+    (``models/glm4_moe_lite.py``) and ``phi4flash``
+    (``models/phi4flash.py``)."""
     with open(config_path) as f:
         hf = json.load(f)
     mt = hf.get("model_type", "llama")
@@ -1454,13 +1455,17 @@ def config_from_hf_json(config_path: str, name: str = ""):
         from .glm4_moe_lite import config_from_hf
 
         return config_from_hf(hf, name)
+    if mt == "phi4flash":  # decoder-hybrid-decoder: Mamba-1, window, GMU
+        from .phi4flash import config_from_hf
+
+        return config_from_hf(hf, name)
     if mt not in (
         "llama", "mistral", "qwen2", "qwen3", "mixtral", "gemma", "gemma2",
     ):
         raise ValueError(
             f"unsupported model_type {mt!r} "
             "(llama/mistral/qwen2/qwen3/mixtral/gemma/gemma2/nemotron_h/"
-            "glm4_moe_lite)"
+            "glm4_moe_lite/phi4flash)"
         )
     eos = hf.get("eos_token_id", 2)
     eos_ids = tuple(eos) if isinstance(eos, list) else (eos,)

@@ -14,6 +14,7 @@ from typing import Dict
 from .llama import Llama, LlamaConfig, config_from_hf_json
 from .glm4_moe_lite import Glm4MoeLite, Glm4MoeLiteConfig
 from .nemotron_h import NemotronH, NemotronHConfig
+from .phi4flash import Phi4Flash, Phi4FlashConfig
 
 # Architecture presets. Shapes match the public configs of each family so
 # perf numbers are honest; weights are random-init unless an HF dir is given.
@@ -310,6 +311,23 @@ PRESETS: Dict[str, LlamaConfig] = {
         eos_token_ids=(0,),
         dtype="float32",
     ),
+    # Tiny decoder-hybrid-decoder with the published layer map's shape:
+    # Mamba / window at 0-3, Mamba 4 handing on its scan output, full
+    # attention 5, a gated memory unit 6, cross-attention 7; window 16.
+    "tiny-phi4flash-debug": Phi4FlashConfig(
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=96,
+        num_layers=8,
+        num_heads=4,
+        num_kv_heads=2,
+        sliding_window=16,
+        max_position_embeddings=2048,
+        name="tiny-phi4flash-debug",
+        eos_token_ids=(0,),
+        bos_token_id=None,
+        dtype="float32",
+    ),
 }
 
 
@@ -321,6 +339,8 @@ def model_for(model_cfg):
         return NemotronH(model_cfg)
     if isinstance(model_cfg, Glm4MoeLiteConfig):
         return Glm4MoeLite(model_cfg)
+    if isinstance(model_cfg, Phi4FlashConfig):
+        return Phi4Flash(model_cfg)
     return Llama(model_cfg)
 
 

@@ -150,11 +150,16 @@ def attention_case(name, *, B, T, kv_dtype, window=0, softcap=0.0):
     }
 
 
-def cell_shape_case(name, *, B, kv_heads, kv_dtype, lo, hi, empty=()):
+def cell_shape_case(name, *, B, kv_heads, kv_dtype, lo, hi, empty=(),
+                    heads=H, paired=False, window=0):
     """Decode as a benchmark cell calls it: ragged lengths, a stacked cache
-    read at a traced layer, NaN wherever the kernel must not look."""
+    read at a traced layer, NaN wherever the kernel must not look (with a
+    ``window``, every page below it too: the cache manager has released
+    those). ``paired``: the differential attention's queries, ``[q1 | 0]``
+    and ``[0 | q2]`` by turns, at the scale of half a head."""
     rng = np.random.default_rng(zlib.crc32(name.encode()))
     lanes, layers, layer = kv_heads * HD, 3, 2
+    scale = 1.0 / np.sqrt(HD // 2) if paired else SCALE
     lens = rng.integers(lo, hi, B).astype(np.int32)
     lens[list(empty)] = 0
     width = -(-hi // BS) + 8
@@ -165,11 +170,17 @@ def cell_shape_case(name, *, B, kv_heads, kv_dtype, lo, hi, empty=()):
     stack = jnp.stack([jnp.full_like(kv, np.nan)] * layer + [kv])
     tables = (rng.permutation(B * width) + 2).reshape(B, width)
     dead = np.arange(width)[None] >= -(-lens // BS)[:, None]
-    q = jnp.asarray(rng.standard_normal((B, 1, H, HD)), jnp.bfloat16)
+    if window:
+        dead |= np.arange(width)[None] < (np.maximum(lens - window, 0) // BS)[:, None]
+    q = rng.standard_normal((B, 1, heads, HD)).astype(np.float32)
+    if paired:
+        q[:, :, 0::2, HD // 2:] = 0.0
+        q[:, :, 1::2, :HD // 2] = 0.0
+    q = jnp.asarray(q, jnp.bfloat16)
     q_pos = jnp.asarray(lens - 1)[:, None]
     kern = jax.jit(
         lambda q, kv, t, l, p, ly: pallas_paged_attention(
-            q, kv, t, l, p, ly, scale=SCALE)
+            q, kv, t, l, p, ly, scale=scale, window=window)
     )
     t0 = time.perf_counter()
     got = np.asarray(kern(
@@ -179,7 +190,7 @@ def cell_shape_case(name, *, B, kv_heads, kv_dtype, lo, hi, empty=()):
     # The reference multiplies every gathered V: give it page 0 for the dead.
     ref = jax.jit(
         lambda q, kv, t, l, p: gather_paged_attention(
-            q, kv, t, l, p, 0, scale=SCALE)
+            q, kv, t, l, p, 0, scale=scale, window=window)
     )
     want = np.asarray(ref(
         q, kv[None], jnp.asarray(np.where(dead, 0, tables).astype(np.int32)),
@@ -196,7 +207,7 @@ def cell_shape_case(name, *, B, kv_heads, kv_dtype, lo, hi, empty=()):
 
 
 def prefill_cell_case(name, *, T, real, start, kv_dtype, window=0,
-                      softcap=0.0):
+                      softcap=0.0, heads=H, kv_heads=KH):
     """One prefill row as the runner pads it: ``real`` tokens of a
     ``T``-token bucket after ``start`` cached ones, a stacked cache read at
     a traced layer, NaN wherever the kernel must not look. The real rows
@@ -206,7 +217,7 @@ def prefill_cell_case(name, *, T, real, start, kv_dtype, window=0,
     kv_len = start + real
     width = -(-(start + T) // BS) + 8
     nb = width + 2
-    kv = rng.standard_normal((nb, 2, BS, KH * HD)).astype(np.float32)
+    kv = rng.standard_normal((nb, 2, BS, kv_heads * HD)).astype(np.float32)
     kv[1] = np.nan
     kv = jnp.asarray(kv, jnp.bfloat16).astype(kv_dtype)
     stack = jnp.stack([jnp.full_like(kv, np.nan)] * layer + [kv])
@@ -214,7 +225,7 @@ def prefill_cell_case(name, *, T, real, start, kv_dtype, window=0,
     first = max(start + 1 - window, 0) // BS if window else 0
     page = np.arange(width)[None]
     dead = (page >= -(-kv_len // BS)) | (page < first)
-    q = jnp.asarray(rng.standard_normal((1, T, H, HD)), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((1, T, heads, HD)), jnp.bfloat16)
     # The runner's padding: every position past the chunk repeats its last.
     q_pos = jnp.asarray(
         np.minimum(start + np.arange(T), kv_len - 1)[None].astype(np.int32))
@@ -238,6 +249,63 @@ def prefill_cell_case(name, *, T, real, start, kv_dtype, window=0,
         "ref_abs_max": float(np.abs(want[:, :real]).max()),
         "bound": ATTN_BOUND,
         "first_call_s": round(compile_s, 2),
+    }
+
+
+def scan_case(name, *, B, T, lens=None):
+    """The Mamba-1 kernels (``ops/selective_scan.py``) at the published
+    widths (16 states x 5,120 channels, nine layers, 75 slots), compiled,
+    against the same recurrence in ``jax.numpy``: ``T == 1`` the decode
+    kernel, else the prefill kernel on rows of true length ``lens``. The
+    pool's other slots and layers must come back bit for bit; the second
+    call is timed."""
+    from production_stack_tpu.ops import selective_scan as ss
+
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    L, S, N, Di, li = 9, 75, 16, 5120, 4
+    f = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape).astype(np.float32))
+    pool = f(L, S, N, Di)
+    u, bm, cm = f(B, T, Di), f(B, T, N), f(B, T, N)
+    dt = 0.1 * jnp.abs(f(B, T, Di))
+    a_t = -jnp.exp(0.5 * f(N, Di))
+    d = jnp.ones((Di,), jnp.float32)
+    slots = jnp.asarray(rng.permutation(S - 1)[:B].astype(np.int32))
+    keep = jnp.asarray((np.arange(B) % 3 != 1).astype(np.int32))
+    lens = jnp.asarray(lens if lens is not None else [T] * B, jnp.int32)
+    valid = jnp.arange(T)[None, :] < lens[:, None]
+    dt = jnp.where(valid[..., None], dt, 0.0)
+    s0 = jnp.where(keep[:, None, None] != 0, pool[li, slots], 0.0)
+    y_ref, s_ref = jax.jit(ss.scan_reference)(s0, u, dt, a_t, bm, cm, d)
+    if T == 1:
+        kern = jax.jit(lambda pool: ss.selective_scan_decode(
+            pool, li, slots, keep, u[:, 0], dt[:, 0], a_t, bm[:, 0],
+            cm[:, 0], d))
+    else:
+        kern = jax.jit(lambda pool: ss.selective_scan_prefill(
+            pool, li, slots, keep, lens, u, dt, a_t, bm, cm, d))
+    t0 = time.perf_counter()
+    y, out = kern(pool)
+    y = np.asarray(y, np.float32).reshape(B, T, Di)
+    compile_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    jax.block_until_ready(kern(pool))
+    second_s = time.perf_counter() - t0
+    live = np.asarray(valid)[..., None]
+    others = np.setdiff1d(np.arange(S), np.asarray(slots))
+    rows = np.asarray(lens) > 0
+    return {
+        "max_abs_diff": float(np.abs(np.where(live, y - np.asarray(y_ref), 0)).max()),
+        "state_max_abs_diff": float(np.abs(
+            np.asarray(out[li, slots]) - np.asarray(s_ref))[rows].max()),
+        "ref_abs_max": float(np.abs(np.where(live, np.asarray(y_ref), 0)).max()),
+        "exact": bool(
+            np.array_equal(np.asarray(out[li, others]), np.asarray(pool[li, others]))
+            and np.array_equal(np.asarray(out[li - 1]), np.asarray(pool[li - 1]))
+            and np.isfinite(y).all()),
+        "bound": 2e-2,
+        "first_call_s": round(compile_s, 2),
+        "second_call_ms": round(second_s * 1e3, 3),
     }
 
 
@@ -405,6 +473,23 @@ def cases():
     yield "attn_prefill_t1024_real600_fp8_window_softcap", prefill_cell_case, dict(
         T=1024, real=600, start=4096 + 71, kv_dtype=fp8, window=2048,
         softcap=50.0)
+    # The decoder-hybrid-decoder's cell: 40 query heads of [q1 | 0] / [0 | q2]
+    # over 10 key-value heads of pairs at 1.5-7.2k of context (32 of the
+    # cell's 64 rows: the case's stacked cache of NaN layers has to fit); the
+    # window layers with every page below the 512 window released.
+    phi = dict(kv_heads=10, heads=40, kv_dtype=jnp.bfloat16)
+    yield "attn_decode_cell_phi_b32_kh10_paired", cell_shape_case, dict(
+        B=32, lo=1500, hi=7200, paired=True, **phi)
+    yield "attn_decode_cell_phi_b32_kh10_paired_window512", cell_shape_case, dict(
+        B=32, lo=1500, hi=7200, paired=True, window=512, empty=(7,), **phi)
+    yield "attn_prefill_cell_phi_t1024_real700_window512", prefill_cell_case, dict(
+        T=1024, real=700, start=2048 + 71, window=512, **phi)
+    yield "attn_prefill_cell_phi_t1024_real1024_full", prefill_cell_case, dict(
+        T=1024, real=1024, start=2048, **phi)
+    yield "scan_decode_b64", scan_case, dict(B=64, T=1)
+    yield "scan_prefill_b1_t1024", scan_case, dict(B=1, T=1024)
+    yield "scan_prefill_b4_t256_ragged", scan_case, dict(
+        B=4, T=256, lens=[256, 131, 5, 0])
     docs = np.exp(np.linspace(np.log(16384), np.log(40960), 12)).astype(int)
     yield "mla_decode_cell_b16_bf16", mla_case, dict(
         lens=[0, *docs[:6], 0, 0, *(docs[6:] + 137), 0])

@@ -1,0 +1,602 @@
+"""The decoder-hybrid-decoder class (``models/phi4flash.py``: Mamba-1, window
+and full differential attention, gated memory units, cross-attention) on the
+engine's normal path, against the benchmark's plain reference
+(``perf/reference/phi4flash.py``: float32, every layer on every token,
+nothing of the program's forward pass), at tiny widths with the published
+layer map's shape: Mamba / window at 0-3, Mamba 4 handing on ``m``, full
+attention 5, a gated memory unit 6, cross-attention 7; hidden 64, window 16,
+pages of 8.
+
+What the benchmark's ``correct`` cannot see is here: rows against each other
+(packed prefill, slots), the window group's pages released on the way with
+the block table holding freed entries, the allocator's bound, the kernels
+against their ``jax.numpy`` forms.
+"""
+
+import json
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perf.reference import phi4flash as reference
+from production_stack_tpu.engine.config import EngineConfig, window_block_count
+from production_stack_tpu.engine.engine import LLMEngine
+from production_stack_tpu.engine.kv_manager import BlockAllocator
+from production_stack_tpu.engine.sequence import SamplingParams, Sequence
+from production_stack_tpu.models.phi4flash import Phi4Flash
+from production_stack_tpu.models.registry import PRESETS
+from production_stack_tpu.ops import selective_scan as scan
+
+CFG = PRESETS["tiny-phi4flash-debug"]
+HF = {"num_hidden_layers": CFG.num_layers,
+      "num_attention_heads": CFG.num_heads,
+      "num_key_value_heads": CFG.num_kv_heads,
+      "sliding_window": CFG.sliding_window,
+      "layer_norm_eps": CFG.layer_norm_eps}
+PROMPT = [3, 17, 98, 25, 42, 7, 11, 20, 15, 31, 8, 77, 12, 5, 9, 2, 33, 44, 99,
+          100, 101, 64, 65, 1, 90, 13, 14, 6, 120, 50, 51, 52, 53, 54, 55, 56,
+          57, 58, 59, 60, 61, 62, 63, 66, 67, 68, 69, 70, 71, 72, 73, 74, 75]
+
+
+def make_engine(**over) -> LLMEngine:
+    kw = dict(
+        model="tiny-phi4flash-debug", max_model_len=256, block_size=8,
+        num_kv_blocks=96, max_num_seqs=4, max_prefill_tokens=16,
+        enable_prefix_caching=False, kv_swap=False,
+    )
+    kw.update(over)
+    return LLMEngine(EngineConfig(**kw))
+
+
+def run(eng, prompts, n_tokens, stagger=0, logprobs=5, watch=None):
+    """Drive ``eng`` by hand: request i arrives after ``stagger * i`` steps.
+    ``watch(seq)`` is called for every live sequence before every step."""
+    sp = SamplingParams(max_tokens=n_tokens, temperature=0.0, ignore_eos=True,
+                        logprobs=logprobs)
+    res = {}
+    pending = list(enumerate(prompts))
+    steps = 0
+    while pending or eng.has_work():
+        while pending and steps >= stagger * pending[0][0]:
+            i, p = pending.pop(0)
+            res[f"r{i}"] = {"tokens": [], "logprobs": [],
+                            "seq": eng.add_request(
+                                f"r{i}", prompt_token_ids=list(p), sampling=sp)}
+        if watch is not None:
+            for r in res.values():
+                watch(r["seq"])
+        for out in eng.step():
+            r = res[out.request_id]
+            r["tokens"].extend(out.new_token_ids)
+            for lp in out.logprobs or []:
+                at = dict(lp["top"])
+                at[lp["token_id"]] = lp["logprob"]
+                r["logprobs"].append(at)
+        steps += 1
+        assert steps < 4000, "the engine makes no progress"
+    return [res[f"r{i}"] for i in range(len(prompts))]
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return make_engine()
+
+
+@pytest.fixture(scope="module")
+def params(engine):
+    return engine.runner.params
+
+
+def reference_logprobs(params, ids, n_prompt, n_gen, variant="none"):
+    with jax.default_matmul_precision("highest"):
+        (lps, gap), = reference.teacher_force(
+            types.SimpleNamespace(hf=HF), params,
+            [{"tokens": list(ids), "n_prompt": n_prompt,
+              "want": [[0]] * n_gen}], variant)
+    assert gap is None
+    return lps
+
+
+def assert_matches_reference(params, prompt, got, tol=2e-3):
+    ids = list(prompt) + got["tokens"]
+    lps = reference_logprobs(params, ids, len(prompt), len(got["tokens"]))
+    assert len(got["logprobs"]) == len(got["tokens"])
+    for j, at in enumerate(got["logprobs"]):
+        for tid, lp in at.items():
+            assert abs(lps[j, tid] - lp) < tol, (j, tid, lps[j, tid], lp)
+
+
+# ----------------------------------------------------------------------------
+# The engine's normal path against the reference's full forward pass
+# ----------------------------------------------------------------------------
+
+
+def test_chunked_prefill_then_decode_with_pages_released(engine, params):
+    """53 prompt tokens in chunks of 16 (more than three windows of 16),
+    then chained decode through the caches: every reported log-probability
+    is the reference's, window-group pages were released on the way and the
+    sequence's table of that group holds freed entries."""
+    seen = {"released": 0, "held": 0}
+
+    def watch(seq):
+        seen["released"] = max(seen["released"], seq.window_released)
+        seen["held"] = max(
+            seen["held"], len(seq.window_block_ids) - seq.window_released)
+        assert all(b == 0 for b in seq.window_block_ids[:seq.window_released])
+
+    got = run(engine, [PROMPT], 8, watch=watch)[0]
+    assert len(got["tokens"]) == 8
+    assert_matches_reference(params, PROMPT, got)
+    assert engine.pipelined_bursts_total > 0, "decode must run chained"
+    assert seen["released"] >= 4, "pages below the window must be released"
+    assert seen["held"] <= engine.allocator.window_bound(16)
+    stats = engine.stats()
+    assert stats["window_pages_released_total"] >= 4
+    assert stats["window_pages_in_use"] == 0 and stats["kv_pages_in_use"] == 0
+    # three chunks of 16 and one of 5; the cross-decoder ran in the last
+    # alone, on its one position
+    assert stats["prefill_tokens_total"] == len(PROMPT)
+    assert stats["cross_decoder_positions_total"] == 1
+    assert stats["prefill_bucket_positions_total"] == 3 * 16 + 8  # 5 in a bucket of 8
+
+
+@pytest.mark.parametrize("chunk", [8, 48, 64])
+def test_chunk_size_does_not_change_the_logits(chunk, params):
+    """The same prompt in chunks of 8 (every chunk inside one window page),
+    48 and whole; the synchronous loop once."""
+    eng = make_engine(max_prefill_tokens=chunk, overlap_decode=chunk != 48)
+    got = run(eng, [PROMPT], 4)[0]
+    assert_matches_reference(params, PROMPT, got)
+
+
+def test_decode_slides_the_window_and_releases_pages(params):
+    """A short prompt and 44 decoded tokens: the window slides over its own
+    outputs, pages go back during decode, residency stays at the bound."""
+    eng = make_engine()
+    held = []
+    got = run(eng, [PROMPT[:6]], 44, watch=lambda s: held.append(
+        len(s.window_block_ids) - s.window_released))[0]
+    assert_matches_reference(params, PROMPT[:6], got)
+    assert eng.allocator.window_pages_released >= 3
+    assert max(held) <= eng.allocator.window_steady
+
+
+def test_short_prompts_and_one_token_chunks(params):
+    """Prompts shorter than the convolution's tail, and a chunk of one
+    token that is a sequence's first (the decode path from zeros)."""
+    eng = make_engine(max_prefill_tokens=8)
+    prompts = [[5], [9, 2], PROMPT[:9]]
+    for p, got in zip(prompts, run(eng, prompts, 5)):
+        assert_matches_reference(params, p, got)
+
+
+def test_packed_rows_of_unequal_length_match_their_lone_runs(params):
+    """Five sequences of different lengths arrive two steps apart into
+    three rows: packed and padded prefill steps, decode batches that grow
+    and shrink, slots and window pages taken again after a finish."""
+    prompts = [PROMPT[:n] for n in (37, 5, 53, 18, 26)]
+    n_out = 7
+    eng = make_engine(max_num_seqs=3, max_prefill_tokens=32)
+    together = run(eng, prompts, n_out, stagger=2)
+    lone_eng = make_engine(max_num_seqs=3, max_prefill_tokens=32)
+    for p, got in zip(prompts, together):
+        lone = run(lone_eng, [p], n_out)[0]
+        assert got["tokens"] == lone["tokens"]
+        for a, b in zip(got["logprobs"], lone["logprobs"]):
+            for tid in a:
+                assert abs(a[tid] - b[tid]) < 1e-3
+        assert_matches_reference(params, p, got)
+    assert eng.allocator.state_slots_in_use == 0
+    assert eng.allocator.window_pages_in_use == 0
+
+
+def test_preemption_by_recompute_returns_the_same_tokens(params):
+    """Twelve global pages: two 40-token prompts admit and one must lose
+    its pages of both groups and its slot while decoding; it starts again
+    from zeros and gives the tokens of a roomy engine."""
+    p1, p2 = PROMPT[:40], PROMPT[5:45]
+    tight = make_engine(num_kv_blocks=12, max_model_len=128, max_prefill_tokens=48)
+    got = run(tight, [p1, p2], 10)
+    assert tight.num_preempted_total > 0, "the test must exercise preemption"
+    roomy = run(make_engine(max_prefill_tokens=48), [p1, p2], 10)
+    for p, a, b in zip((p1, p2), got, roomy):
+        assert a["tokens"] == b["tokens"]
+        assert_matches_reference(params, p, a)
+    assert tight.allocator.state_slots_in_use == 0
+    assert tight.allocator.window_pages_in_use == 0
+
+
+@pytest.mark.parametrize("variant", reference.VARIANTS[1:])
+def test_every_negative_control_moves_the_reference(variant, params):
+    ids = PROMPT + PROMPT[:11]
+    sound = reference_logprobs(params, ids, len(PROMPT), 12)
+    broken = reference_logprobs(params, ids, len(PROMPT), 12, variant)
+    moved = np.abs(sound - broken).max()
+    # the precision controls move little at these widths, the equations much
+    assert moved > (1e-5 if variant in ("state_bf16", "kv_fp8") else 1e-2), moved
+
+
+# ----------------------------------------------------------------------------
+# The model's pieces
+# ----------------------------------------------------------------------------
+
+
+def _step_inputs(model, lens, T, bs=8, slots=3, nb=32):
+    B = len(lens)
+    rng = np.random.default_rng(1)
+    tokens = jnp.asarray(rng.integers(0, CFG.vocab_size, (B, T)), jnp.int32)
+    pos = np.zeros((B, T), np.int32)
+    write = np.full((B, T), nb * bs, np.int32)
+    W = -(-T // bs)
+    tables = np.zeros((B, W), np.int32)
+    wtables = np.zeros((B, W), np.int32)
+    for i, n in enumerate(lens):
+        tables[i] = 1 + i * W + np.arange(W)
+        wtables[i] = 2 + (B - i) * W + np.arange(W)
+        pos[i, :n] = np.arange(n)
+        pos[i, n:] = max(n - 1, 0)
+        write[i, :n] = tables[i][np.arange(n) // bs] * bs + np.arange(n) % bs
+    cache = model.make_kv_cache(nb, bs, None, state_slots=slots,
+                                window_blocks=nb)
+    lens = np.asarray(lens, np.int32)
+    return dict(
+        tokens=tokens, positions=jnp.asarray(pos), write_idx=jnp.asarray(write),
+        block_tables=jnp.asarray(tables), kv_lens=jnp.asarray(lens),
+        last_idx=jnp.asarray(np.maximum(lens - 1, 0)), cache=cache,
+        state_slots=jnp.arange(B, dtype=jnp.int32),
+        window_tables=jnp.asarray(wtables))
+
+
+def test_skipped_cross_decoder_equals_the_unskipped_at_sampled_positions(params):
+    """A packed prefill step of rows of 24, 9 and 0 tokens: the logits of
+    each row's last position with the cross-decoder on that position alone
+    are those of the step that runs every layer on every token."""
+    model = Phi4Flash(CFG)
+    lens = [24, 9, 0]
+    a = _step_inputs(model, lens, 24)
+    b = _step_inputs(model, lens, 24)
+    cache_in = a.pop("cache"), b.pop("cache")
+    args = ("tokens", "positions", "write_idx", "block_tables", "kv_lens",
+            "last_idx")
+    skipped, ca = model.forward(
+        params, *(a[k] for k in args), cache_in[0],
+        state_slots=a["state_slots"], window_tables=a["window_tables"])
+    whole, cb = model.forward(
+        params, *(b[k] for k in args), cache_in[1],
+        state_slots=b["state_slots"], window_tables=b["window_tables"],
+        all_logits=True)
+    for i, n in enumerate(lens[:2]):
+        np.testing.assert_allclose(skipped[i], whole[i, n - 1], atol=2e-5)
+    # what each step says it ran the cross-decoder on, counted from the batch
+    # that branch was handed: a row's one position, or every position
+    assert model.AUX_NAMES == ("cross_decoder_positions_total",)
+    assert float(model.step_aux(ca)[0]) == len(lens)
+    assert float(model.step_aux(cb)[0]) == len(lens) * 24
+    for k in set(ca) - {"aux"}:  # the caches the two steps leave are the same
+        np.testing.assert_array_equal(ca[k], cb[k])
+    # a step none of whose rows is sampled from runs it on none
+    c = _step_inputs(model, lens, 24)
+    _, cc = model.forward(
+        params, *(c[k] for k in args), c.pop("cache"),
+        state_slots=c["state_slots"], window_tables=c["window_tables"],
+        sample_rows=jnp.zeros(len(lens), bool))
+    assert float(model.step_aux(cc)[0]) == 0
+    for k in set(ca) - {"aux"}:
+        np.testing.assert_array_equal(ca[k], cc[k])
+
+
+def test_paired_heads_equal_the_four_product_form():
+    """Keys stored as pairs ``[k1 | k2]``, queries ``[q1 | 0]`` and ``[0 |
+    q2]`` through the paged attention: ``combine`` of that is the
+    reference's differential attention written as four products."""
+    model = Phi4Flash(CFG)
+    rng = np.random.default_rng(2)
+    T, bs, D = 21, 8, CFG.hidden_size
+    h = jnp.asarray(rng.normal(size=(1, T, D)), jnp.float32)
+    lp = {
+        "wq": rng.normal(size=(D, CFG.q_size)) / 8,
+        "bq": 0.1 * rng.normal(size=(CFG.q_size,)),
+        "wkv": rng.normal(size=(D, 2 * CFG.kv_size)) / 8,
+        "bkv": 0.1 * rng.normal(size=(2 * CFG.kv_size,)),
+        "wo": rng.normal(size=(CFG.q_size, D)) / 8,
+        "bo": 0.1 * rng.normal(size=(D,)),
+        "subln": 1 + 0.1 * rng.normal(size=(2 * CFG.head_dim,)),
+        "ln1_w": np.ones(D), "ln1_b": np.zeros(D),
+        **{f"lambda_{n}": 0.3 * rng.normal(size=(CFG.head_dim,))
+           for n in ("q1", "k1", "q2", "k2")},
+    }
+    lp = {k: jnp.asarray(v, jnp.float32) for k, v in lp.items()}
+    layer, window = 3, 8
+    eps = CFG.layer_norm_eps
+    x = h[0]  # the block's input; both sides normalise it first
+    hn = reference._ln(x, lp["ln1_w"], lp["ln1_b"], eps)[None]
+    tables = jnp.asarray([[4, 2, 5]], jnp.int32)
+    pos = jnp.arange(T, dtype=jnp.int32)[None]
+    flat = tables[0][pos[0] // bs] * bs + pos[0] % bs
+    with jax.default_matmul_precision("highest"):
+        pages = model._write_pages(
+            lp, hn, jnp.zeros((1, 6, 2, bs, CFG.kv_size), jnp.float32), 0, flat)
+        served = model._diff_attention(
+            lp, hn, pages, 0, tables, jnp.asarray([T]), pos, layer, "gather",
+            window=window)[0]
+        kv = reference.keys_values(
+            x, lp, pairs=CFG.num_kv_heads // 2, eps=eps, kv_fp8=False)
+        plain = reference.diff_attention(
+            x, lp, kv, reference.lambda_init(layer),
+            q_pairs=CFG.num_heads // 2, window=window, eps=eps,
+            lambda_off=False) - x
+    np.testing.assert_allclose(served, plain, atol=5e-4, rtol=1e-4)
+
+
+def test_mamba_chunks_inside_the_convolutions_reach_equal_the_whole(params, monkeypatch):
+    """One row in chunks of 5, 2, 1 and 9 positions (boundaries inside the
+    convolution's reach of 3) through the interpreted kernels, state and
+    tail carried by the slot: the outputs are the whole row's through
+    ``scan_reference``, another slot's state stays as it was."""
+    model = Phi4Flash(CFG)
+    lp = {k: v[0] for k, v in params["layers"]["self_mamba"].items()}
+    rng = np.random.default_rng(4)
+    T = 17
+    x = jnp.asarray(rng.normal(size=(1, T, CFG.hidden_size)), jnp.float32)
+
+    def rows(n, first):
+        return (jnp.asarray([1]), jnp.asarray([n]),
+                jnp.arange(n)[None] < n, jnp.asarray([not first]))
+
+    def pools():
+        c = model.make_kv_cache(1, 8, None, state_slots=2)
+        return c["ssm"] + 7.0, c["conv"] + 7.0
+
+    pool, tails = pools()
+    whole, y_whole, _, _ = model._mamba(lp, x, pool, tails, 1, rows(T, True))
+    monkeypatch.setattr(scan, "use_kernels", lambda: True)
+    pool, tails = pools()
+    outs, ys, at = [], [], 0
+    for n in (5, 2, 1, 9):
+        o, y, pool, tails = model._mamba(
+            lp, x[:, at:at + n], pool, tails, 1, rows(n, at == 0))
+        outs.append(o)
+        ys.append(y)
+        at += n
+    np.testing.assert_allclose(jnp.concatenate(outs, 1), whole, atol=2e-5)
+    np.testing.assert_allclose(jnp.concatenate(ys, 1), y_whole, atol=2e-5)
+    np.testing.assert_array_equal(pool[1, 0], np.full_like(pool[1, 0], 7.0))
+    np.testing.assert_array_equal(pool[0], np.full_like(pool[0], 7.0))
+
+
+def _scan_inputs(B, T, Di=256, N=16, L=2, S=5, seed=0):
+    rng = np.random.default_rng(seed)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    return dict(
+        pool=f(L, S, N, Di), u=f(B, T, Di), dt=0.1 * jnp.abs(f(B, T, Di)),
+        a_t=-jnp.exp(f(N, Di)), bm=f(B, T, N), cm=f(B, T, N),
+        d=jnp.ones((Di,), jnp.float32))
+
+
+@pytest.mark.parametrize("T,lens", [(21, (21, 9, 0)), (8, (8, 8, 3)),
+                                    (300, (300, 257, 1))])
+def test_prefill_kernel_equals_the_recurrence(T, lens):
+    """Packed rows each from their own slot, one from zeros, one that is
+    padding; chunks of 256 positions carry the state over (T = 300). Slots
+    no row names, and the other layer, stay bit for bit."""
+    x = _scan_inputs(3, T, seed=T)
+    slots, keep = jnp.asarray([3, 0, 4]), jnp.asarray([1, 0, 1])
+    lens = jnp.asarray(lens)
+    valid = jnp.arange(T)[None, :] < lens[:, None]
+    dt = jnp.where(valid[..., None], x["dt"], 0.0)
+    s0 = jnp.where(keep[:, None, None] != 0, x["pool"][1, slots], 0.0)
+    y_ref, s_ref = scan.scan_reference(
+        s0, x["u"], dt, x["a_t"], x["bm"], x["cm"], x["d"])
+    y, pool = scan.selective_scan_prefill(
+        x["pool"], 1, slots, keep, lens, x["u"], dt, x["a_t"], x["bm"],
+        x["cm"], x["d"])
+    np.testing.assert_allclose(
+        jnp.where(valid[..., None], y, 0.0),
+        jnp.where(valid[..., None], y_ref, 0.0), atol=2e-4, rtol=1e-4)
+    assert np.isfinite(np.asarray(y)).all()
+    np.testing.assert_allclose(pool[1, slots], s_ref, atol=2e-4, rtol=1e-4)
+    np.testing.assert_array_equal(pool[0], x["pool"][0])
+    np.testing.assert_array_equal(pool[1, jnp.asarray([1, 2])],
+                                  x["pool"][1, jnp.asarray([1, 2])])
+
+
+def test_decode_kernel_equals_the_step_and_leaves_other_slots():
+    x = _scan_inputs(3, 1, seed=7)
+    slots, keep = jnp.asarray([3, 0, 4]), jnp.asarray([1, 0, 1])
+    s0 = jnp.where(keep[:, None, None] != 0, x["pool"][1, slots], 0.0)
+    y_ref, s_ref = scan.scan_reference(
+        s0, x["u"], x["dt"], x["a_t"], x["bm"], x["cm"], x["d"])
+    y, pool = scan.selective_scan_decode(
+        x["pool"], 1, slots, keep, x["u"][:, 0], x["dt"][:, 0], x["a_t"],
+        x["bm"][:, 0], x["cm"][:, 0], x["d"])
+    np.testing.assert_allclose(y, y_ref[:, 0], atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(pool[1, slots], s_ref, atol=1e-6, rtol=1e-6)
+    np.testing.assert_array_equal(pool[0], x["pool"][0])
+    np.testing.assert_array_equal(pool[1, jnp.asarray([1, 2])],
+                                  x["pool"][1, jnp.asarray([1, 2])])
+
+
+# ----------------------------------------------------------------------------
+# The cache manager's window group
+# ----------------------------------------------------------------------------
+
+
+def _sequence(n_prompt):
+    return Sequence("s", list(range(n_prompt)), SamplingParams(max_tokens=1))
+
+
+@pytest.mark.parametrize("chunk", [1, 100, 1024])
+def test_a_sequence_of_forty_windows_stays_inside_the_bound(chunk):
+    """512-token window, 128-token pages: through 40 windows in chunks of
+    ``chunk`` a sequence never holds more than ``ceil(512 / 128) + 1`` pages
+    between steps, nor more than ``window_bound(chunk)`` while a chunk is
+    written; finished, it returns every page and its slot."""
+    alloc = BlockAllocator(256, 128, False, state_slots=2, window_blocks=32,
+                           window_tokens=512)
+    seq = _sequence(40 * 512)
+    assert alloc.take_state_slot(seq)
+    between, during = 0, 0
+    while seq.num_computed_tokens < 40 * 512:
+        end = seq.num_computed_tokens + chunk
+        alloc.advance_window(seq, end)
+        during = max(during, len(seq.window_block_ids) - seq.window_released)
+        assert alloc.window_pages_in_use == (
+            len(seq.window_block_ids) - seq.window_released)
+        # every token the next query may see still has its page
+        assert seq.window_released <= max(
+            seq.num_computed_tokens - 511, 0) // 128
+        seq.num_computed_tokens = end
+        alloc.trim_window(seq)
+        between = max(
+            between, len(seq.window_block_ids) - seq.window_released)
+    assert between <= 512 // 128 + 1 < alloc.window_steady
+    assert during <= alloc.window_bound(chunk)
+    assert alloc.window_pages_released >= 40 * 4 - 5
+    alloc.release_sequence(seq)
+    assert alloc.window_pages_in_use == 0 and alloc.state_slots_in_use == 0
+    assert sorted(alloc._free_window) == list(range(32))
+
+
+def test_window_group_exhaustion_is_the_allocators_error():
+    from production_stack_tpu.engine.kv_manager import NoFreeBlocksError
+
+    alloc = BlockAllocator(64, 8, False, window_blocks=10, window_tokens=16)
+    a, b = _sequence(64), _sequence(64)
+    alloc.advance_window(a, 64)  # 8 pages
+    with pytest.raises(NoFreeBlocksError):
+        alloc.advance_window(b, 24)
+    assert b.window_block_ids == []  # all or nothing
+    alloc.release_sequence(a)
+    alloc.advance_window(b, 24)
+    assert len(b.window_block_ids) == 3 and alloc.window_pages_in_use == 3
+    # a model without the group: nothing to advance, nothing held
+    plain = BlockAllocator(64, 8, False)
+    plain.advance_window(b, 64)
+    assert plain.window_pages_in_use == 0
+
+
+def test_the_window_group_is_sized_from_the_model_and_the_engines_limits():
+    cfg = EngineConfig(model="tiny-phi4flash-debug", block_size=8,
+                       max_num_seqs=4, max_prefill_tokens=16)
+    assert window_block_count(cfg, CFG) == 4 * (2 + 2) + 2 * 2
+    assert window_block_count(cfg, PRESETS["tiny-llama-debug"]) == 0
+    eng = make_engine()
+    assert eng.runner.window_blocks == 20
+    assert eng.runner.kv_cache["wkv"].shape[:2] == (CFG.num_window_layers, 20)
+    assert eng.runner.kv_cache["kv"].shape[:2] == (1, 96)
+    assert eng.runner.kv_cache["ssm"].shape == (
+        CFG.num_mamba_layers, eng.runner.state_slots + 1, 16, 128)
+
+
+# ----------------------------------------------------------------------------
+# Start-up: what is refused, and the configuration's door
+# ----------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("over,flag", [
+    (dict(enable_prefix_caching=True), "--enable-prefix-caching"),
+    (dict(kv_swap=True), "--kv-swap"),
+    (dict(cpu_offload_blocks=8), "--cpu-offload-blocks"),
+    (dict(remote_kv_url="http://x"), "--remote-kv-url"),
+    (dict(kv_role="producer"), "--kv-role"),
+    (dict(speculative_ngram=3), "--speculative-ngram"),
+    (dict(enable_lora=True), "--enable-lora"),
+    (dict(tensor_parallel_size=2), "--tensor-parallel-size"),
+    (dict(pipeline_parallel_size=2), "--pipeline-parallel-size"),
+    (dict(data_parallel_size=2), "--data-parallel-size"),
+    (dict(quantization="int8"), "--quantization"),
+    (dict(kv_cache_dtype="float8_e4m3fn"), "--kv-cache-dtype"),
+])
+def test_refused_at_start_up_by_the_flags_name(over, flag):
+    with pytest.raises(ValueError) as e:
+        make_engine(**over)
+    assert flag in str(e.value)
+    assert "tiny-phi4flash-debug" in str(e.value)
+
+
+def test_released_window_pages_are_a_reason_of_their_own():
+    """Without the state-space layers' reasons the new property still
+    refuses what it must, by the flag's name."""
+    from production_stack_tpu.engine.config import refuse_unserved
+
+    only = types.SimpleNamespace(window_pages=True)
+    for over, flag in ((dict(), "--enable-prefix-caching"),
+                       (dict(enable_prefix_caching=False), "--kv-swap")):
+        with pytest.raises(ValueError, match=flag):
+            refuse_unserved(EngineConfig(**over), only)
+    refuse_unserved(
+        EngineConfig(enable_prefix_caching=False, kv_swap=False), only)
+
+
+def test_config_door_knows_the_model_type(tmp_path):
+    from production_stack_tpu.models.llama import config_from_hf_json
+
+    with open("perf/configs/phi-4-mini-flash.json") as f:
+        raw = json.load(f)
+    path = tmp_path / "config.json"
+    path.write_text(json.dumps(raw))
+    cfg = config_from_hf_json(str(path), name="x")
+    assert (cfg.num_layers, cfg.hidden_size, cfg.vocab_size) == (32, 2560, 200064)
+    assert (cfg.d_inner, cfg.dt_rank, cfg.head_dim) == (5120, 160, 64)
+    assert (cfg.num_mamba_layers, cfg.num_window_layers, cfg.cross_pairs) == (9, 8, 7)
+    # a token's keys and values: 5,120 B a layer; 8 windows of 5 pages: 26 MB
+    assert cfg.page_bytes(128, 2) == 128 * 5120
+    assert cfg.window_page_bytes(128, 2) * 5 == 26_214_400
+    assert cfg.state_bytes_per_slot() == 9 * (5120 * 16 * 4 + 3 * 5120 * 2)
+    shapes = jax.eval_shape(Phi4Flash(cfg).init_params, jax.random.PRNGKey(0))
+    n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes))
+    assert 3.84e9 < n < 3.87e9  # the published 3.8 B, uncut
+    raw["num_hidden_layers"] = 30
+    path.write_text(json.dumps(raw))
+    with pytest.raises(ValueError, match="multiple of 4"):
+        config_from_hf_json(str(path))
+
+
+# ----------------------------------------------------------------------------
+# The kernels at the published widths, compiled for a described chip
+# ----------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A v5e chip that is described, not attached: the TPU's compiler is
+    installed here and refuses what the chip's would (interpret mode shows
+    neither a tiling fault nor a pool copy)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("B,T", [(64, 1), (1, 1024), (4, 256)])
+def test_scan_kernels_compile_for_the_chip_without_a_pool_copy(B, T, one_chip, monkeypatch):
+    """Nine layers, 73 slots, 16 states x 5,120 channels: Mosaic takes both
+    kernels, and the donated pool is updated in place (no temporary of the
+    pool's size)."""
+    monkeypatch.setattr(scan, "pallas_interpret", lambda: False)
+    L, S, N, Di = 9, 73, 16, 5120
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    rows = sds((B,), jnp.int32)
+    if T == 1:
+        fn = lambda pool, sl, kp, u, dt, a, bm, cm, d: scan.selective_scan_decode(  # noqa: E731
+            pool, 3, sl, kp, u, dt, a, bm, cm, d)
+        args = (sds((L, S, N, Di)), rows, rows, sds((B, Di)), sds((B, Di)),
+                sds((N, Di)), sds((B, N)), sds((B, N)), sds((Di,)))
+    else:
+        fn = lambda pool, sl, kp, ln, u, dt, a, bm, cm, d: scan.selective_scan_prefill(  # noqa: E731
+            pool, 3, sl, kp, ln, u, dt, a, bm, cm, d)
+        args = (sds((L, S, N, Di)), rows, rows, rows, sds((B, T, Di)),
+                sds((B, T, Di)), sds((N, Di)), sds((B, T, N)), sds((B, T, N)),
+                sds((Di,)))
+    with jax.disable_jit(False):
+        compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    pool_bytes = L * S * N * Di * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
