@@ -34,9 +34,13 @@ logger = init_logger(__name__)
 
 # Why a decode chain was drained (`LLMEngine._chain_break_reason`, and
 # `abort_all_requests`): the ``reason`` label of ``pst:pipeline_breaks_total``.
+# A prefill alone drains none: the rows it completes join the chain, unless
+# there is no row for them (``row_bucket``), they need another program than
+# the chain's (``sampling_variant``) or one of the older reasons holds;
+# ``prefill`` is what is left, a preempted sequence's recompute.
 CHAIN_BREAK_REASONS = (
     "prefill", "blocked_on_locked", "decode_set", "depth", "table_width",
-    "queue", "not_eligible", "abort_all",
+    "queue", "not_eligible", "abort_all", "row_bucket", "sampling_variant",
 )
 
 
@@ -191,11 +195,15 @@ class LLMEngine:
         self.spec_proposed_total = 0
         self.spec_accepted_total = 0
         # Pipelined-decode bookkeeping: membership of the in-flight burst
-        # (original order, including members that finished meanwhile) and
-        # sequences whose page release is deferred until the drain.
+        # (a row each, including members that finished meanwhile, until a
+        # new member takes the row) and sequences whose page release is
+        # deferred, each with the count of chained bursts that had been
+        # dispatched when it left: the last that can write through its
+        # pages. ``_bursts_fetched`` of them have been fetched.
         self._burst_seqs: List[Sequence] = []
         self._burst_n = 0
-        self._burst_deferred: List[Sequence] = []
+        self._burst_deferred: List[tuple] = []
+        self._bursts_fetched = 0
         if cfg.enable_lora:
             from .lora import LoraManager
 
@@ -215,6 +223,8 @@ class LLMEngine:
         self.decode_dispatches_total = 0
         self.pipelined_bursts_total = 0
         self.pipeline_breaks = {why: 0 for why in CHAIN_BREAK_REASONS}
+        # Prefill steps a chain went on behind, with no drain.
+        self.chain_kept_prefills_total = 0
         # Flight recorder (docs/observability.md "Flight recorder"):
         # always-on bounded ring of per-step records, fed through
         # ENGINE_TELEMETRY's dispatch path; this engine's scheduler/KV
@@ -437,7 +447,7 @@ class LLMEngine:
         ):
             seq = self.scheduler.detach(request_id)
             if seq is not None:
-                self._burst_deferred.append(seq)
+                self._defer_release(seq)
         else:
             seq = self.scheduler.abort(request_id)
         self._seqs.pop(request_id, None)
@@ -456,7 +466,7 @@ class LLMEngine:
             self.pipeline_breaks["abort_all"] += 1
             self._burst_seqs = []
             self._burst_n = 0
-            self._release_burst_deferred()
+            self._burst_fetched()
         rids = list(self._seqs.keys())
         for rid in rids:
             self.abort_request(rid)
@@ -580,27 +590,50 @@ class LLMEngine:
         if self.runner.burst_in_flight:
             locked = frozenset(s.request_id for s in self._burst_seqs)
             sched = self._schedule(hint, outputs, locked)
-            why = self._chain_break_reason(sched)
+            why, joins = self._chain_break_reason(sched, hint)
             if why is None:
+                # The chain goes on, behind the pass's prefill if it made
+                # one: that is dispatched first, then the next chained step
+                # with the rows the prefill completes among its members
+                # (their tokens reach it on the device), and only then is
+                # anything fetched, while the new step runs.
+                fetched = self._burst_seqs
+                self._burst_seqs = members = list(fetched)
+                for row, seq, _ in joins:
+                    members[row:row + 1] = [seq]  # a dead row, or one more
+                handle = None
+                if sched.prefills:
+                    handle = self.runner.prefill_dispatch(
+                        sched.prefills, record_at_fetch=bool(joins))
+                    self.chain_kept_prefills_total += 1
                 self._count_decode(self._burst_n, chained=True)
-                rows = self.runner.burst_continue(self._burst_seqs)
+                rows = self.runner.burst_continue(members, joins)
                 with phase("postprocess", "decode"):
-                    outputs += self._process_burst_rows(rows)
+                    outputs += self._process_burst_rows(fetched, rows)
+                    self._burst_fetched()
+                if handle is not None:
+                    # an inner chunk's sample is read by nobody
+                    prows = self.runner.prefill_fetch(
+                        handle, len(sched.prefills)) if joins else None
+                    with phase("postprocess", "prefill"):
+                        outputs += self._process_prefill_rows(
+                            sched.prefills, prows)
                 return outputs
             self.pipeline_breaks[why] += 1
-            # A new arrival's prefill can slip in BEHIND the in-flight
-            # burst: dispatch it first (the device serializes the two), then
-            # drain the burst while the prefill executes — one combined wait
-            # instead of drain-then-prefill round trips. Safe because the
-            # prefill touches only its own freshly-allocated pages (locked
-            # members could not be evicted by its allocation).
+            # The prefill of a pass that drains the chain still slips in
+            # BEHIND the in-flight burst: dispatch it first (the device
+            # serializes the two), then drain the burst while the prefill
+            # executes — one combined wait instead of drain-then-prefill
+            # round trips. Safe because the prefill touches only its own
+            # freshly-allocated pages (locked members could not be evicted
+            # by its allocation).
             prefill_handle = None
             if sched.prefills and not sched.blocked_on_locked:
                 prefill_handle = self.runner.prefill_dispatch(sched.prefills)
             rows = self.runner.burst_drain()
             with phase("postprocess", "decode"):
-                outputs += self._process_burst_rows(rows)
-                self._release_burst_deferred()
+                outputs += self._process_burst_rows(self._burst_seqs, rows)
+                self._burst_fetched()
             if prefill_handle is not None:
                 prows = self.runner.prefill_fetch(
                     prefill_handle, len(sched.prefills)
@@ -796,7 +829,10 @@ class LLMEngine:
         for i, item in enumerate(prefills):
             seq = item.seq
             seq.num_computed_tokens = item.end
-            self._commit(seq)
+            # a row that joined the chain behind this prefill is written
+            # through by the burst in flight already
+            self._commit(seq, allow_swap=not (
+                self.runner.burst_in_flight and seq in self._burst_seqs))
             # Streamed disagg handoff: this chunk's freshly committed
             # pages go out NOW, overlapped with the next chunk's compute
             # (docs/disagg.md) — not serially after the prefill response.
@@ -855,9 +891,11 @@ class LLMEngine:
         configured, every row chainable and no queue left standing
         (`_queue_stands`). It does not ask whether requests are arriving: an
         arrival waits for the one burst in flight, as it waits for the
-        running step in the synchronous loop, and its prefill is launched
-        behind that burst (`_step_impl`). Only the deepening past
-        ``num_decode_steps`` waits for quiet (`_decode_depth_hint`)."""
+        running step in the synchronous loop, its prefill is launched
+        behind that burst and the chain goes on behind the prefill with the
+        arrival among its rows (`_step_impl`, `_chain_break_reason`). Only
+        the deepening past ``num_decode_steps`` waits for quiet
+        (`_decode_depth_hint`)."""
         if not sched.decodes or not self.cfg.overlap_decode:
             return False
         # Speculation and overlap are alternative round-trip amortizers;
@@ -898,27 +936,69 @@ class LLMEngine:
         pass admitted is a prefill, not a queue."""
         return bool(self.scheduler.num_waiting or self.scheduler.num_swapped)
 
-    def _chain_break_reason(self, sched) -> Optional[str]:
-        """None while the burst in flight may chain: nothing about the step
-        shape changed and the NEXT burst's writes are provably covered.
-        Else why not, by what the pass made under the chain's locks
-        produced (the label of ``pst:pipeline_breaks_total``)."""
+    def _chain_break_reason(self, sched, hint: Optional[int] = None) -> tuple:
+        """``(None, joins)`` while the burst in flight may chain: the NEXT
+        burst's rows are the chain's live members and the sequences whose
+        prompt this pass's prefill completes (``joins``: ``(row, sequence,
+        prefill row)`` each, a row that is free or whose member finished),
+        its shape is the chain's and its writes are provably covered. Else
+        ``(why, ())``, by what the pass made under the chain's locks
+        produced (the label of ``pst:pipeline_breaks_total``).
+
+        A decode pass has scheduled and reserved pages for exactly the live
+        members. A prefill pass returns before the scheduler's decode
+        phase, so what that phase would have said is asked here: the depth
+        (``hint`` or the configured one), every row chainable, a row and
+        the chain's sampling program for each new member, and pages for the
+        next burst of all (`Scheduler.reserve_chain`). A prefill step that
+        completes no prompt (an inner chunk) changes no membership."""
         alive = [s for s in self._burst_seqs if not s.is_finished]
-        if sched.prefills:
-            return "prefill"
         if sched.blocked_on_locked:
-            return "blocked_on_locked"
-        if not alive or sched.decodes != alive:
-            return "decode_set"
-        if sched.n_decode_steps != self._burst_n:
-            return "depth"
-        if not self.runner.burst_width_stable(self._burst_seqs):
-            return "table_width"
+            return "blocked_on_locked", ()
+        fresh = []
+        for it in sched.prefills:
+            if it.seq.output_token_ids:
+                if it.end >= it.seq.num_tokens - 1:
+                    # a preempted sequence recomputed: its next token is
+                    # the host's, and the restart takes it from there
+                    return "prefill", ()
+            elif it.end == it.seq.num_prompt_tokens:
+                fresh.append(it.seq)
+        members = alive + fresh
+        if sched.prefills:
+            n = max(hint or self.cfg.num_decode_steps, 1)
+            if not members:
+                return "decode_set", ()
+        else:
+            n = sched.n_decode_steps
+            if not alive or len(sched.decodes) != len(alive) or (
+                {id(s) for s in sched.decodes} != {id(s) for s in alive}
+            ):
+                return "decode_set", ()
+        if n != self._burst_n:
+            return "depth", ()
         if self._queue_stands():
-            return "queue"
-        if not self._chainable(alive, self._burst_n):
-            return "not_eligible"
-        return None
+            return "queue", ()
+        if not self._chainable(members, self._burst_n):
+            return "not_eligible", ()
+        free = [i for i, s in enumerate(self._burst_seqs) if s.is_finished]
+        free += range(len(self._burst_seqs), self.runner.burst_rows())
+        if len(fresh) > len(free):
+            return "row_bucket", ()
+        if fresh and not self.runner.burst_variant_fits(fresh):
+            return "sampling_variant", ()
+        if sched.prefills:
+            evicted = len(sched.preempted)
+            fits = self.scheduler.reserve_chain(members, self._burst_n, sched)
+            self.num_preempted_total += len(sched.preempted) - evicted
+            if not fits:
+                return "blocked_on_locked", ()
+        if not self.runner.burst_width_stable(members):
+            return "table_width", ()
+        # after the reservation: it may have taken a chunk out of the pass
+        at = {id(it.seq): i for i, it in enumerate(sched.prefills)}
+        return None, [
+            (row, seq, at[id(seq)]) for row, seq in zip(free, fresh)]
 
     def _count_decode(self, n_steps: int, chained: bool) -> None:
         self.decode_dispatches_total += 1
@@ -927,15 +1007,15 @@ class LLMEngine:
         if n_steps > self.cfg.num_decode_steps:
             self.adaptive_deep_bursts_total += 1
 
-    def _process_burst_rows(self, rows) -> List[RequestOutput]:
-        """Apply one fetched burst's tokens. Rows align with
-        ``self._burst_seqs`` (original membership order); rows of members
+    def _process_burst_rows(self, members, rows) -> List[RequestOutput]:
+        """Apply one fetched burst's tokens. Rows align with ``members``,
+        the membership that burst was dispatched with; rows of members
         that finished earlier are speculative garbage and are skipped.
         While another burst is still in flight, page releases and dedup
         swaps are deferred — the device writes through these page ids."""
         outputs: List[RequestOutput] = []
         inflight = self.runner.burst_in_flight
-        for seq, seq_rows in zip(self._burst_seqs, rows):
+        for seq, seq_rows in zip(members, rows):
             if seq.is_finished:
                 continue
             for row in seq_rows:
@@ -951,10 +1031,28 @@ class LLMEngine:
             self._burst_n = 0
         return outputs
 
-    def _release_burst_deferred(self) -> None:
-        for seq in self._burst_deferred:
-            self.allocator.release_sequence(seq)
-        self._burst_deferred = []
+    def _defer_release(self, seq: Sequence) -> None:
+        """``seq`` left a chain whose bursts write through its pages (and
+        state slot, and window pages): it gives them back once the last
+        burst dispatched so far has been fetched. The next one is told the
+        row is dead and writes nothing."""
+        self._burst_deferred.append((self.pipelined_bursts_total, seq))
+
+    def _burst_fetched(self) -> None:
+        """One more chained burst has been fetched (or dropped): release
+        what no burst still in flight writes through. A chain that never
+        drains gives its finished members' pages back here, a burst after
+        each finished."""
+        self._bursts_fetched += 1
+        if not self._burst_deferred:
+            return
+        held = []
+        for last_writer, seq in self._burst_deferred:
+            if last_writer <= self._bursts_fetched:
+                self.allocator.release_sequence(seq)
+            else:
+                held.append((last_writer, seq))
+        self._burst_deferred = held
 
     # Controller-registration hygiene: chunk claims older than the TTL (or
     # beyond the cap) are dropped so KV-aware routing doesn't chase KV that
@@ -1099,9 +1197,9 @@ class LLMEngine:
                     )
             if self.runner.burst_in_flight and seq in self._burst_seqs:
                 # The in-flight burst still writes through this sequence's
-                # pages: detach now, release at drain.
+                # pages: detach now, release once that burst is fetched.
                 self.scheduler.detach(seq.request_id, finish_reason)
-                self._burst_deferred.append(seq)
+                self._defer_release(seq)
             else:
                 self.scheduler.finish(seq, finish_reason)
             out.finished = True
@@ -1231,6 +1329,8 @@ class LLMEngine:
         if self.cfg.overlap_decode:
             out["pipelined_bursts_total"] = float(self.pipelined_bursts_total)
             out["pipeline_breaks_total"] = dict(self.pipeline_breaks)
+            out["chain_kept_prefills_total"] = float(
+                self.chain_kept_prefills_total)
         # Tiering KPIs (present when the LMCache-analogue layer is on).
         for attr in ("host_hit_blocks", "remote_hit_blocks", "spilled_blocks"):
             if hasattr(self.allocator, attr):

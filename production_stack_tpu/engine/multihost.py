@@ -111,7 +111,9 @@ def run_follower(runner, bridge: Optional[HostBridge] = None) -> None:
         elif kind == "burst_start":
             runner._dispatch_burst_start(*payload)
         elif kind == "burst_cont":
-            runner._dispatch_burst_continue(payload)
+            runner._dispatch_burst_continue(*payload)
+        elif kind == "warm_splice":
+            runner._warm_splice(int(payload))
         elif kind == "spec_verify":
             runner._dispatch_spec_verify(payload)
         else:  # future-proof: unknown step kinds are fatal (order contract)

@@ -109,13 +109,15 @@ def _fetch(arr, kind: str = "") -> np.ndarray:
         return np.asarray(jax.device_get(arr))
 
 
-def _seed_for(seq: Sequence) -> int:
+def _seed_for(seq: Sequence, ahead: int = 0) -> int:
+    """The seed of ``seq``'s next sample; ``ahead``: of the one that many
+    tokens later."""
     base = (
         seq.sampling.seed
         if seq.sampling.seed is not None
         else xxhash.xxh32(seq.request_id.encode()).intdigest()
     )
-    return (base + len(seq.output_token_ids)) & 0x7FFF_FFFF
+    return (base + len(seq.output_token_ids) + ahead) & 0x7FFF_FFFF
 
 
 class ModelRunner:
@@ -527,6 +529,30 @@ class ModelRunner:
         self._multi_step = jax.jit(pst_decode_burst, **burst_jit)
         # pstlint: jit-family=decode_burst
         self._chained_step = jax.jit(pst_decode_step_chained, **burst_jit)
+
+        def pst_chain_splice(tokens, positions, toks, src, pos):
+            """A chain's carry between a prefill and the chained step behind
+            it: row ``i`` takes the token prefill row ``src[i]`` sampled
+            (column 0 of its packed row) and the position ``pos[i]`` where
+            ``src[i] >= 0``, token 0 at ``pos[i]`` where ``src[i] == -2`` (a
+            member that finished: its row reads no context from here on),
+            and keeps the burst in flight's own otherwise."""
+            take = src >= 0
+            new = toks[jnp.maximum(src, 0), 0].astype(jnp.int32)
+            tokens = jnp.where(take, new, jnp.where(src == -2, 0, tokens))
+            return tokens, jnp.where(take | (src == -2), pos, positions)
+
+        # Keyed by the chain's row bucket and the prefill's packed rows
+        # alone: the decode and prefill programs stay what they were.
+        # pstlint: jit-family=decode_burst
+        self._splice = jax.jit(
+            pst_chain_splice, out_shardings=(self._repl, self._repl))
+        self._splice_warm: set = set()
+        # The last prefill program's packed rows, still on the device: what
+        # a chain kept across that prefill takes its new rows' tokens from.
+        self._prefill_toks = None
+        # A dispatch `prefill_dispatch` left for `prefill_fetch` to record.
+        self._prefill_record = None
         # Pipelined-burst state: device handles of the burst in flight.
         self._burst = None
         # Per-request cost attribution (docs/observability.md "Cost
@@ -1258,12 +1284,38 @@ class ModelRunner:
             )
             # Start the host copy NOW; the eventual fetch finds it resident.
             toks.copy_to_host_async()
+        # ``rows``: the host's copy of what it owns of the batch, a row a
+        # member, renewed in place by every continuation; ``steps``: scan
+        # steps launched so far, which is the seed offset the next one
+        # starts from.
         self._burst = {
             "batch": dev, "tokens": tokens, "positions": positions,
             "seed": seed, "counts": cdev, "with_pen": with_pen,
             "toks": toks, "n": n_steps, "want_lp": want_lp,
-            "greedy": greedy,
+            "greedy": greedy, "steps": n_steps,
+            "rows": {k: v for k, v in batch.items()
+                     if k not in ("tokens", "positions")},
         }
+        self._warm_splice(batch["kv_lens"].shape[0])
+
+    def _warm_splice(self, Bb: int) -> None:
+        """Compile `_splice` for a chain of ``Bb`` rows behind every row
+        bucket a prefill step can have, the first time a chain of that size
+        starts: a chain's first arrival then loads nothing. (Packed rows
+        with log-probabilities compile when first met, as their step
+        programs do.)"""
+        if Bb in self._splice_warm:
+            return
+        self._splice_warm.add(Bb)
+        put = lambda x: jax.device_put(x, self._repl)  # noqa: E731
+        carry = put(np.zeros(Bb, np.int32))
+        src = put(np.full(Bb, -1, np.int32))
+        rows = 1
+        while rows <= _pow2(
+                min(self.cfg.max_num_seqs, self.cfg.max_prefill_tokens)):
+            toks = put(np.zeros((rows + self._aux_rows, 1), np.float32))
+            self._splice(carry, carry, toks, src, carry)
+            rows <<= 1
 
     def burst_width_stable(self, members: List[Sequence]) -> bool:
         """True while the members' block tables still fit the width bucket
@@ -1271,21 +1323,65 @@ class ModelRunner:
         if self._burst is None:
             return False
         Wb = self._burst["batch"]["block_tables"].shape[1]
-        return max(len(s.block_ids) for s in members) <= Wb
+        return max((len(s.block_ids) for s in members), default=0) <= Wb
 
-    def burst_continue(self, members: List[Sequence]) -> np.ndarray:
+    def burst_rows(self) -> int:
+        """Rows of the burst in flight's batch: members and padding."""
+        return self._burst["batch"]["kv_lens"].shape[0]
+
+    def burst_variant_fits(self, seqs: List[Sequence]) -> bool:
+        """Can ``seqs`` take rows of the chain as it was compiled? Not a
+        row that wants log-probabilities of a chain without them, a sampled
+        row of a greedy chain, or a row whose arrays are not the shape of
+        the chain's (a logit bias); and no row joins a chain that carries
+        penalty counts, nor one with penalties of its own: the counts need
+        the first token, which only the device has."""
+        st = self._burst
+        if st["with_pen"] or any(s.sampling.has_penalties for s in seqs):
+            return False
+        if self._want_lp(seqs) and not st["want_lp"]:
+            return False
+        if st["greedy"] and not self._all_greedy(seqs):
+            return False
+        own = {k: v.shape[1:] for k, v in st["rows"].items()
+               if k not in ("block_tables", "kv_lens")}
+        Wb = st["rows"]["block_tables"].shape[1]
+        return all(
+            {k: v.shape[1:] for k, v in self._member_rows([s], 1, Wb).items()}
+            == own for s in seqs)
+
+    def _member_rows(
+        self, seqs: List[Sequence], B: int, W: int
+    ) -> Dict[str, np.ndarray]:
+        """What `_decode_batch` builds a row beside its table and length."""
+        rows = self._slot_rows(seqs, B, W)
+        rows.update(self._sampling_arrays(seqs, B))
+        return rows
+
+    def burst_continue(
+        self, members: List[Sequence], joins: Seq[tuple] = ()
+    ) -> np.ndarray:
         """Dispatch the NEXT burst, then fetch and return the PREVIOUS
         burst's tokens [Bb, n] (the fetch overlaps the new burst's
-        execution). ``members`` is the pipeline's original membership, in
-        order: their block tables are refreshed (the scheduler reserves
-        lookahead pages host-side; the device table must see them) and
-        members that finished host-side get kv_len 0 so their speculative
-        rows stop writing KV."""
+        execution). ``members`` is the membership of the burst being
+        dispatched, a row each: their block tables are refreshed (the
+        scheduler reserves lookahead pages host-side; the device table must
+        see them) and members that finished host-side get kv_len 0 so their
+        speculative rows stop writing KV. The rows returned are those of
+        the membership the previous dispatch was given.
+
+        ``joins``: ``(row, sequence, prefill row)`` for every member that
+        was not in the previous burst: a sequence whose prompt the prefill
+        launched just before (`prefill_dispatch`) completes. Its row of the
+        batch is written whole, and its token and position reach the carry
+        on the device (`_splice`), so the chain goes on behind the prefill
+        with nothing fetched in between."""
         assert self._burst is not None
         tel = getattr(self, "_burst_tel", None)
         with ENGINE_TELEMETRY.phase("batch_build", "decode"):
-            Wb = self._burst["batch"]["block_tables"].shape[1]
-            Bb = self._burst["batch"]["kv_lens"].shape[0]
+            st = self._burst
+            own = st["rows"]
+            Bb, Wb = own["block_tables"].shape
             tables = np.zeros((Bb, Wb), np.int32)
             kv_lens = np.zeros(Bb, np.int32)
             for i, s in enumerate(members):
@@ -1294,11 +1390,29 @@ class ModelRunner:
             refresh = {"block_tables": tables, "kv_lens": kv_lens}
             if self.window_blocks:
                 refresh["window_tables"] = self._window_tables(members, Bb, Wb)
+            own.update(refresh)
+            splice = None
+            if joins:
+                # copies: the burst in flight may still read the old arrays
+                own = st["rows"] = {k: v.copy() for k, v in own.items()}
+                src = np.where(kv_lens > 0, -1, -2).astype(np.int32)
+                pos = np.zeros(Bb, np.int32)
+                for row, s, prefill_row in joins:
+                    for k, v in self._member_rows([s], 1, Wb).items():
+                        if k != "window_tables":
+                            own[k][row] = v[0]
+                    # the chain adds the steps it has run to every row's
+                    # seed: the row's first sample here is its second
+                    own["seeds"][row] = np.uint32(
+                        (_seed_for(s, 1) - st["steps"]) & 0xFFFF_FFFF)
+                    src[row], pos[row] = prefill_row, s.num_tokens
+                refresh, splice = own, (src, pos)
             alive = sum(1 for s in members if not s.is_finished)
             if tel is not None:
                 # The host's view lags the device by the burst in flight:
                 # a live row holds 2n - 1 more tokens after this burst
-                # than its kv_len here says.
+                # than its kv_len here says (a joining row's prefill is
+                # the program its view lags by).
                 n = tel[3]
                 self._step_info(
                     "decode", tel[1], members,
@@ -1308,8 +1422,8 @@ class ModelRunner:
         t0 = time.perf_counter()
         with self._device_lock:
             if self.publisher is not None:
-                self.publisher.announce("burst_cont", refresh)
-            rows = self._dispatch_burst_continue(refresh)
+                self.publisher.announce("burst_cont", (refresh, splice))
+            rows = self._dispatch_burst_continue(refresh, splice)
         if tel is not None:
             # The continuation was dispatched BEFORE the previous burst's
             # tokens were even read: the device runs the two back-to-back,
@@ -1335,14 +1449,21 @@ class ModelRunner:
         return rows
 
     def _dispatch_burst_continue(
-        self, refresh: Dict[str, np.ndarray]
+        self, refresh: Dict[str, np.ndarray], splice: Optional[tuple] = None
     ) -> np.ndarray:
-        """``refresh``: what the host renews of the burst's batch, the block
-        tables (both groups') and ``kv_lens``."""
+        """``refresh``: what the host renews of the burst's batch: the block
+        tables (both groups') and ``kv_lens``, or every array it owns when
+        rows join. ``splice``: ``(src, pos)`` of `_splice` then, applied to
+        the carry with the last prefill's rows."""
         st = self._burst
         prev = st["toks"]
         with ENGINE_TELEMETRY.phase("launch", "decode", pipelined=1):
             st["batch"].update(self._put_batch(refresh))
+            if splice is not None:
+                src, pos = jax.device_put(splice, self._repl)
+                st["tokens"], st["positions"] = self._splice(
+                    st["tokens"], st["positions"], self._prefill_toks,
+                    src, pos)
             toks, tokens, positions, seed, counts, self.kv_cache = (
                 self._burst_fn(st["n"])(
                     self.params, self.kv_cache, st["batch"], st["tokens"],
@@ -1355,7 +1476,7 @@ class ModelRunner:
             toks.copy_to_host_async()
             st.update(
                 tokens=tokens, positions=positions, seed=seed, counts=counts,
-                toks=toks,
+                toks=toks, steps=st["steps"] + st["n"],
             )
         return self._take_aux(_fetch(prev, "decode"))
 
@@ -1617,12 +1738,22 @@ class ModelRunner:
                 self.params, self.kv_cache, self._put_batch(batch), False, True
             )
 
-    def prefill_dispatch(self, items: List[PrefillItem]):  # noqa: D401
+    def prefill_dispatch(  # noqa: D401
+        self, items: List[PrefillItem], record_at_fetch: bool = False
+    ):
         """Async half of a prefill step: dispatch and return the device
         handle without fetching. Used to slip a new arrival's prefill in
-        BEHIND an in-flight decode burst (the device serializes them; the
-        burst drain then overlaps the prefill's execution), cutting one full
-        host<->device round trip out of TTFT."""
+        BEHIND an in-flight decode burst (the device serializes them),
+        cutting one full host<->device round trip out of TTFT. The chain's
+        next step is launched behind it in turn (`burst_continue` with
+        ``joins``, which reads the rows kept here on the device), or, where
+        the chain cannot go on, the burst's drain overlaps the prefill's
+        execution. `prefill_fetch` reads the rows; an inner chunk's are
+        never read. ``record_at_fetch``: the dispatch is recorded when its
+        rows are fetched, after the chained step launched behind it, so
+        that the cycle is the prefill's in the flight recorder (it waits
+        for the prefill program, as the cycle of a prefill behind a
+        draining burst does) and is held against cycles of its own kind."""
         with ENGINE_TELEMETRY.phase("batch_build", "prefill"):
             batch = self._prefill_batch(items)
             want_lp = self._want_lp([i.seq for i in items])
@@ -1643,17 +1774,30 @@ class ModelRunner:
                     want_lp, greedy,
                 )
                 toks.copy_to_host_async()
+                self._prefill_toks = toks
         dt = time.perf_counter() - t0
-        with ENGINE_TELEMETRY.phase("postprocess", "prefill"):
-            self._charge_prefill(items, dt)
-            ENGINE_TELEMETRY.record_dispatch(
-                "prefill", key, dt,
-                batch_bucket=bucket, tokens=real, fill_ratio=fill,
-            )
+
+        def record():
+            with ENGINE_TELEMETRY.phase("postprocess", "prefill"):
+                self._charge_prefill(items, dt)
+                # pstlint: disable=recompile-risk(key is this dispatch's own _prefill_tel key, closed over: the record is only made later, at the fetch)
+                ENGINE_TELEMETRY.record_dispatch(
+                    "prefill", key, dt,
+                    batch_bucket=bucket, tokens=real, fill_ratio=fill,
+                )
+
+        if record_at_fetch:
+            self._prefill_record = record
+        else:
+            record()
         return toks
 
     def prefill_fetch(self, handle, n_items: int) -> np.ndarray:
-        return self._take_aux(_fetch(handle, "prefill"))[:n_items]
+        rows = self._take_aux(_fetch(handle, "prefill"))[:n_items]
+        record, self._prefill_record = self._prefill_record, None
+        if record is not None:
+            record()
+        return rows
 
     def _run(
         self,
@@ -1681,6 +1825,8 @@ class ModelRunner:
                 self.params, self.kv_cache, self._put_batch(batch),
                 want_lp, greedy,
             )
+            if kind == "prefill":
+                self._prefill_toks = toks  # a follower's, for `_splice`
         return self._take_aux(_fetch(toks, kind))
 
     def _take_aux(self, rows: np.ndarray) -> np.ndarray:
@@ -1800,6 +1946,11 @@ class ModelRunner:
             self._dispatch_multi_step(
                 batch, counts, n, bucket.want_lp, bucket.greedy
             )
+            # what a chain of this many rows runs between a prefill and
+            # the step behind it
+            if self.publisher is not None:
+                self.publisher.announce("warm_splice", Bb)
+            self._warm_splice(Bb)
         self._record_warmup(
             "decode", key, time.perf_counter() - t0, bucket.label
         )

@@ -304,19 +304,36 @@ class Scheduler:
                 # the occurrence counts live in multi_step's scan carry —
                 # ops/sampling.py apply_penalties_counts.)
                 n = 1
-        look = max(self.config.decode_lookahead, 1)
         for seq in list(self.running):
             if seq not in self.running:  # lost pages to an earlier preemption
                 continue
-            reserve = min(
-                seq.num_tokens + max(look * n - 1, self.config.spec_tokens),
-                self.config.max_model_len,
-            )
-            if not self._ensure_blocks(seq, reserve, out, protect=seq):
+            if not self._reserve_decode(seq, n, out):
                 continue
             out.decodes.append(seq)
         out.n_decode_steps = n
         return out
+
+    def _reserve_decode(self, seq: Sequence, n: int, out) -> bool:
+        """Pages for ``seq``'s next ``decode_lookahead`` bursts of ``n``."""
+        look = max(self.config.decode_lookahead, 1)
+        reserve = min(
+            seq.num_tokens + max(look * n - 1, self.config.spec_tokens),
+            self.config.max_model_len,
+        )
+        return self._ensure_blocks(seq, reserve, out, protect=seq)
+
+    def reserve_chain(
+        self, seqs: List[Sequence], n: int, out: SchedulerOutput
+    ) -> bool:
+        """Phase 2's reservation for ``seqs`` alone: the members of a chain
+        the engine keeps across the prefill pass ``out`` (which returned
+        before phase 2), those among them whose prompt that pass completes.
+        All of them are locked meanwhile: only a sequence still in prefill
+        can lose its pages (and its place in ``out.prefills``) to them.
+        False, with ``out.blocked_on_locked`` set, if one cannot have its
+        pages: the engine drains and schedules again."""
+        self._locked = self._locked | {s.request_id for s in seqs}
+        return all(self._reserve_decode(s, n, out) for s in seqs)
 
     # -- internals --------------------------------------------------------
 

@@ -286,6 +286,11 @@ class EngineMetrics:
             "not chain",
             by=("reason",),
         )
+        self.chain_kept_prefills = counter(
+            "pst:chain_kept_prefills",
+            "prefill steps a decode chain went on behind without a drain: "
+            "the rows they completed joined the chain on the device",
+        )
         # Deadline shedding by stage (docs/resilience.md): admission counts
         # at the HTTP layer; queued/running refresh from scheduler stats.
         self.deadline_shed_admission = counter(
@@ -449,6 +454,10 @@ class EngineMetrics:
             self._counter_to(
                 self.pipeline_breaks(why), f"pipeline_breaks:{why}", total
             )
+        self._counter_to(
+            self.chain_kept_prefills, "chain_kept_prefills",
+            stats.get("chain_kept_prefills_total", 0),
+        )
         self._counter_to(
             self.deadline_shed_queued, "dl_queued",
             stats.get("deadline_sheds_queued_total", 0),

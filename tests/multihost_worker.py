@@ -10,6 +10,9 @@ Usage: python multihost_worker.py <coordinator_port> <process_id> [mode]
 Modes:
   pp_tp    (default) pp=2 x tp=4 — layer stages span the two hosts
   dp_pp_tp dp=2 x pp=2 x tp=2 — adds in-engine data-parallel rows
+  join     pp=2 x tp=4; the second prompt arrives while the first decodes in
+           a chain, and joins it behind its own prefill: the follower runs
+           the prefill, the splice and the chained step in the same order.
   dirty    pp=2 x tp=4, but process 0 EXITS WITHOUT announcing shutdown
            after generating (crash simulation); the follower must notice
            the lost primary and exit rather than wedge in a dead collective.
@@ -64,6 +67,8 @@ cfg = EngineConfig(
     max_num_seqs=4,
     max_prefill_tokens=32,
     attn_impl="gather",
+    # join: a chain of one member has a second row for the arrival
+    min_decode_bucket=2 if mode == "join" else 1,
     **parallel,
 )
 
@@ -78,7 +83,22 @@ if pid == 0:
     engine = LLMEngine(cfg)
     engine.runner.publisher = StepPublisher()
     prompts = [list(PROMPT)] + ([list(PROMPT2)] if mode == "dp_pp_tp" else [])
-    outs = engine.generate(prompts, SamplingParams(max_tokens=8, temperature=0.0))
+    sp = SamplingParams(max_tokens=8, temperature=0.0)
+    if mode == "join":
+        outs = [{"token_ids": []}, {"token_ids": []}]
+        engine.add_request("0", prompt_token_ids=list(PROMPT), sampling=sp)
+        steps = 0
+        while engine.has_work():
+            for out in engine.step():
+                outs[int(out.request_id)]["token_ids"] += out.new_token_ids
+            steps += 1
+            if steps == 3:
+                assert engine.runner.burst_in_flight
+                engine.add_request(
+                    "1", prompt_token_ids=list(PROMPT2), sampling=sp)
+        print(f"KEPT:{engine.chain_kept_prefills_total}")
+    else:
+        outs = engine.generate(prompts, sp)
     for i, out in enumerate(outs):
         suffix = str(i) if i else ""
         print(f"TOKENS{suffix}:" + ",".join(str(t) for t in out["token_ids"]))

@@ -201,6 +201,26 @@ def test_staggered_sequences_leave_no_trace_in_each_other(engine, params):
         assert_matches_reference(params, p, got)
 
 
+def test_arrivals_join_the_running_chain_on_latent_pages(params):
+    """Eight sequences arrive three steps apart under a chain of eight rows
+    (prompts of up to four chunks, pages found in the prefix cache): each joins
+    behind its own prefill with no drain, and a finished member's pages come
+    back a burst later while the chain runs on. Tokens and log-probabilities
+    are the synchronous loop's."""
+    prompts = [PROMPT[n:] + PROMPT[:n] for n in (37, 5, 52, 18, 26, 44, 0, 37)]
+    prompts = [p[:n] for p, n in zip(prompts, (37, 5, 53, 18, 26, 9, 30, 50))]
+    kw = dict(max_num_seqs=8, min_decode_bucket=8)
+    sync = run(make_engine(overlap_decode=False, **kw), prompts, 9, stagger=3)
+    eng = make_engine(**kw)
+    got = run(eng, prompts, 9, stagger=3)
+    for a, b in zip(got, sync):
+        assert_same_logprobs(a, b)
+    assert eng.chain_kept_prefills_total >= 7
+    assert eng.pipeline_breaks["prefill"] == 0
+    assert sum(eng.pipeline_breaks.values()) == 1, eng.pipeline_breaks
+    assert eng.allocator.num_free == eng.allocator.num_blocks
+
+
 def test_preemption_by_recompute_returns_the_same_tokens(params):
     """12 pages of 8 tokens: two 40-token prompts admit and one must lose
     its pages while decoding; it is prefilled again and still gives what the

@@ -7,6 +7,7 @@ holding 4 virtual CPU devices of one mesh. Host 0 drives the real scheduler;
 host 1 mirrors device steps through the follower loop. Coverage:
   - pp2 x tp4 topology, output oracle-exact vs single host
   - dp2 x pp2 x tp2 topology (data-parallel rows across the same hosts)
+  - an arrival joining a running chain behind its prefill, mirrored in order
   - dirty shutdown: primary crashes without announcing; the follower exits
     instead of wedging in a dead collective
 """
@@ -105,6 +106,21 @@ def test_two_process_dp_pp_tp_matches_oracle():
     for p, out in zip(procs, outs):
         assert p.returncode == 0, f"worker failed:\n{out[-3000:]}"
     assert "FOLLOWER-DONE" in outs[1], outs[1][-2000:]
+    expected = _oracle([list(PROMPT), list(PROMPT2)])
+    assert _tokens(outs[0]) == expected[0]
+    assert _tokens(outs[0], "1") == expected[1]
+
+
+def test_an_arrival_joins_the_chain_on_both_hosts():
+    """The second prompt arrives under a running chain: its prefill, the
+    splice of its token into the chain's carry and the chained step behind
+    it are announced in program order, and the follower mirrors all three
+    (a follower that skipped one would hang in the next collective)."""
+    procs, outs = _run_pair("join")
+    for p, out in zip(procs, outs):
+        assert p.returncode == 0, f"worker failed:\n{out[-3000:]}"
+    assert "FOLLOWER-DONE" in outs[1], outs[1][-2000:]
+    assert "KEPT:1" in outs[0], outs[0][-2000:]
     expected = _oracle([list(PROMPT), list(PROMPT2)])
     assert _tokens(outs[0]) == expected[0]
     assert _tokens(outs[0], "1") == expected[1]

@@ -272,6 +272,45 @@ def test_staggered_sequences_match_their_lone_runs(params):
     assert eng.allocator.state_slots_in_use == 0
 
 
+def test_arrivals_join_the_running_chain_on_state_slots(params):
+    """Eight sequences arrive three steps apart under a chain of four rows
+    (one prompt in two chunks): each joins behind its own prefill, which
+    wrote its slot before the chained step reads it, with no drain; a
+    finished member's slot and pages come back a burst later and are taken
+    again (six slots, eight sequences) while the chain runs on. Tokens and
+    log-probabilities are the synchronous loop's."""
+    prompts = [PROMPT[:n] for n in (37, 5, 53, 18, 26, 11, 44, 9)]
+    kw = dict(max_num_seqs=4, min_decode_bucket=4, max_prefill_tokens=32)
+    sync = run(make_engine(overlap_decode=False, **kw), prompts, 9, stagger=3)
+    eng = make_engine(**kw)
+    step, held = eng.step, []
+
+    def checked_step():
+        outs = step()
+        if not sum(eng.pipeline_breaks.values()):  # the chain never drained
+            # a slot is held by a running sequence or by a member that
+            # finished under the burst in flight, and by nothing else
+            assert eng.allocator.state_slots_in_use == (
+                eng.scheduler.num_running + len(eng._burst_deferred))
+            held.append(len(eng._burst_deferred))
+        return outs
+
+    eng.step = checked_step
+    got = run(eng, prompts, 9, stagger=3)
+    assert 0 < max(held) <= 2 and held.count(0) > len(held) // 2
+    for a, b in zip(got, sync):
+        assert a["tokens"] == b["tokens"]
+        for x, y in zip(a["logprobs"], b["logprobs"]):
+            assert all(abs(x[t] - y[t]) < 1e-3 for t in x)
+    slots = [min(r["slots"]) for r in got]
+    assert len(set(slots)) < len(slots), "a slot must have been reused"
+    assert eng.chain_kept_prefills_total >= 7
+    assert eng.pipeline_breaks["prefill"] == 0
+    assert sum(eng.pipeline_breaks.values()) == 1, eng.pipeline_breaks
+    assert eng.allocator.state_slots_in_use == 0
+    assert eng.allocator.num_free == eng.allocator.num_blocks
+
+
 def test_slot_wait_leaves_the_request_queued():
     """More live sequences than slots: admission waits, counts the wait,
     and every request still completes."""

@@ -9,9 +9,14 @@ or not requests are arriving. These tests pin the user-visible contract:
   stream, and its outputs (token ids, text deltas, emission order, finish
   reasons) are IDENTICAL to the unpipelined loop — at most one burst of
   overshoot, trimmed before emission, never streamed;
-- an arrival waits for the one burst in flight and no more; a standing
-  queue keeps the loop synchronous; only the deepening past
-  ``num_decode_steps`` waits for quiet;
+- an arrival waits for the one burst in flight and no more, and joins
+  the chain behind its own prefill: no drain, its token spliced into the
+  chain's carry on the device, every request's tokens what the synchronous
+  loop gives with many rows live; a finished member's pages come back a
+  burst later, with no drain; what cannot join (no row, a wider table,
+  another sampling program, a guided row, a standing queue) drains under
+  its own reason; only the deepening past ``num_decode_steps`` waits for
+  quiet;
 - every decode dispatch is counted, chained or not, and every drained
   chain by its reason; a chained step of depth 1 is a
   ``jit_pst_decode_step`` program;
@@ -165,13 +170,15 @@ def test_chain_engages_at_default_config_while_requests_arrive():
     machine's clock makes of 0.5 s): most decode dispatches are chained
     all the same, token for token what the synchronous loop gives."""
     ref, _ = _run_with_arrivals(_engine(), *_arrival_stream())
-    eng = _overlap_engine()
+    eng = _overlap_engine(min_decode_bucket=8)
     assert eng.cfg.overlap_decode and eng.cfg.adaptive_decode_quiet_s == 0.5
     eng._arrival_safe = lambda: False
     got, _ = _run_with_arrivals(eng, *_arrival_stream())
     assert got == ref
-    assert eng.pipeline_breaks["prefill"] >= 3  # arrivals landed mid-chain
-    assert eng.pipelined_bursts_total * 2 > eng.decode_dispatches_total
+    # arrivals landed mid-chain, and the chain went on behind each
+    assert eng.chain_kept_prefills_total >= 3
+    assert eng.pipeline_breaks["prefill"] == 0
+    assert eng.pipelined_bursts_total == eng.decode_dispatches_total
 
 
 def test_arrival_mid_chain_prefills_behind_the_burst_in_flight():
@@ -188,8 +195,9 @@ def test_arrival_mid_chain_prefills_behind_the_burst_in_flight():
         with a burst in flight."""
         behind = []
         dispatch = eng.runner.prefill_dispatch
-        eng.runner.prefill_dispatch = lambda items: (
-            behind.append(eng.runner.burst_in_flight), dispatch(items))[1]
+        eng.runner.prefill_dispatch = lambda items, **kw: (
+            behind.append(eng.runner.burst_in_flight),
+            dispatch(items, **kw))[1]
         eng.add_request("r0", prompt_token_ids=p0, sampling=_sp(40))
         n0, sent = 0, False
         while eng.has_work():
@@ -284,11 +292,15 @@ def test_decode_counters_add_up():
     assert set(eng.pipeline_breaks) == set(CHAIN_BREAK_REASONS)
     assert (calls["burst_start"] == calls["burst_drain"]
             == sum(eng.pipeline_breaks.values()))
-    assert eng.pipeline_breaks["prefill"] > 0
+    assert eng.pipeline_breaks["prefill"] == 0
+    assert eng.pipeline_breaks["row_bucket"] > 0  # chains of 2 and 4 rows
+    assert eng.pipeline_breaks["not_eligible"] > 0  # the guided row
     assert eng.pipeline_breaks["decode_set"] > 0  # the last member finished
+    assert eng.chain_kept_prefills_total > 0
     stats = eng.stats()
     assert stats["decode_dispatches_total"] == eng.decode_dispatches_total
     assert stats["pipeline_breaks_total"] == eng.pipeline_breaks
+    assert stats["chain_kept_prefills_total"] == eng.chain_kept_prefills_total
 
 
 def test_decode_counters_are_exported():
@@ -313,6 +325,8 @@ def test_decode_counters_are_exported():
     for why, n in eng.pipeline_breaks.items():
         assert value('pst:pipeline_breaks_total{model_name="m",reason="%s"}'
                      % why) == n
+    assert (value('pst:chain_kept_prefills_total{model_name="m"}')
+            == eng.chain_kept_prefills_total > 0)
 
 
 @pytest.mark.parametrize("depth, name", [
@@ -462,11 +476,12 @@ def test_abort_mid_overlap_cancels_cleanly():
     assert eng.allocator.num_free == eng.allocator.num_blocks
 
 
-def test_overlap_late_arrival_drains_and_joins():
-    """A request arriving mid-pipeline forces a drain (prefill pending) and
-    then joins the batch; everyone finishes with exact lengths and the
+def test_overlap_late_arrival_joins_without_a_drain():
+    """A request arriving mid-pipeline prefills behind the burst in flight
+    and takes a row of the chain behind that: nothing drains until the last
+    member has finished; everyone finishes with exact lengths and the
     allocator balances afterwards."""
-    eng = _overlap_engine(num_decode_steps=4)
+    eng = _overlap_engine(num_decode_steps=4, min_decode_bucket=2)
     rng = np.random.default_rng(4)
     eng.add_request("r0", prompt_token_ids=rng.integers(1, 500, 21).tolist(),
                     sampling=SamplingParams(max_tokens=24, temperature=0.0,
@@ -484,11 +499,237 @@ def test_overlap_late_arrival_drains_and_joins():
                 sampling=SamplingParams(max_tokens=10, temperature=0.0,
                                         ignore_eos=True),
             )
+        if steps == 5:
+            assert eng.runner.burst_in_flight
+            assert [s.request_id for s in eng._burst_seqs] == ["r0", "r1"]
+            assert sum(eng.pipeline_breaks.values()) == 0
         assert steps < 1000
     assert len(toks["r0"]) == 24
     assert len(toks["r1"]) == 10
+    assert eng.chain_kept_prefills_total == 1
+    assert eng.pipeline_breaks["decode_set"] == 1 == sum(
+        eng.pipeline_breaks.values())
     assert not eng._burst_deferred
     assert not eng.runner.burst_in_flight
+    assert eng.allocator.num_free == eng.allocator.num_blocks
+
+
+# ----------------------------------------------------------------------
+# A chain kept across prefills: rows join on the device
+# ----------------------------------------------------------------------
+
+
+def _session_stream(temperature, n_first=5, n_later=9, **sp):
+    """``n_first`` requests at once, then one more every other step while
+    they decode: different lengths, and outputs short enough that members
+    finish (and their rows are taken again) while others arrive."""
+    rng = np.random.default_rng(31)
+
+    def mk(rid, n, mt):
+        return (rid, rng.integers(1, 500, size=n).tolist(), SamplingParams(
+            max_tokens=mt, temperature=temperature, ignore_eos=True,
+            seed=None if temperature == 0.0 else 1000 + n, **sp))
+
+    first = [mk(f"a{i}", 9 + 4 * i, 30 + 3 * i) for i in range(n_first)]
+    later = [mk(f"b{i}", 7 + 3 * i, 6 + (5 * i) % 11) for i in range(n_later)]
+    return first, later
+
+
+@pytest.mark.parametrize("temperature, depth", [
+    (0.0, 1), (0.0, 2), (0.9, 1), (0.9, 3)])
+def test_rows_joining_a_live_chain_get_the_synchronous_loops_tokens(
+        temperature, depth):
+    """Many rows live, arrivals every other step, members finishing in
+    between: every request's tokens are those of the same requests with
+    ``overlap_decode`` off, greedy and seeded-sampled, at depth 1 and
+    deeper. The chain is never drained by a prefill: each arrival's first
+    token is spliced into the carry on the device, its seed counted from
+    the chain's own step offset, and a finished member's row is taken by
+    a later arrival."""
+    kw = dict(max_num_seqs=16, min_decode_bucket=16, num_decode_steps=depth,
+              num_kv_blocks=256)
+    ref, _ = _run_with_arrivals(
+        _engine(**kw), *_session_stream(temperature), every=2)
+    eng = _overlap_engine(**kw)
+    rows_used = []
+    cont = eng.runner.burst_continue
+    eng.runner.burst_continue = lambda members, joins=(): (
+        rows_used.extend(row for row, _, _ in joins),
+        cont(members, joins))[1]
+    got, _ = _run_with_arrivals(eng, *_session_stream(temperature), every=2)
+    assert got == ref
+    assert all(len(t) > 0 for t in got.values())
+    assert eng.pipeline_breaks["prefill"] == 0
+    # the first arrival comes while the five are still in prefill
+    assert eng.chain_kept_prefills_total == len(rows_used) == 8
+    assert len(set(rows_used)) < len(rows_used), "a dead row was taken again"
+    assert sum(eng.pipeline_breaks.values()) == 1  # the last member's end
+    assert eng.allocator.num_free == eng.allocator.num_blocks
+
+
+def test_inner_chunks_of_a_long_prompt_keep_the_chain():
+    """A prompt of five chunks arrives under a running chain: the chain
+    goes on behind every chunk (four that complete nothing and change no
+    membership, then the one that joins), and the tokens are the
+    synchronous loop's."""
+    rng = np.random.default_rng(8)
+    first = [("a", rng.integers(1, 500, 12).tolist(), _sp(40))]
+    later = [("long", rng.integers(1, 500, 75).tolist(), _sp(9))]
+    kw = dict(max_prefill_tokens=16, min_decode_bucket=4, num_decode_steps=1)
+    ref, _ = _run_with_arrivals(_engine(**kw), first, later)
+    eng = _overlap_engine(**kw)
+    fetched = []
+    fetch = eng.runner.prefill_fetch
+    eng.runner.prefill_fetch = lambda h, n: (fetched.append(n), fetch(h, n))[1]
+    got, _ = _run_with_arrivals(eng, first, later)
+    assert got == ref
+    assert eng.chain_kept_prefills_total == 5
+    assert fetched == [1], "only the completing chunk's token is read"
+    assert sum(eng.pipeline_breaks.values()) == 1  # the end
+
+
+def test_a_joining_cycle_is_the_prefills_in_the_flight_recorder():
+    """The cycle in which a row joins waits for the prefill program: it is
+    carried by the prefill's dispatch (recorded at its fetch, after the
+    chained step's) and held against prefill cycles, not against plain
+    decode steps a third as long, which would make every arrival a stall.
+    An inner chunk's cycle waits for a decode step and is a decode cycle."""
+    rng = np.random.default_rng(8)
+    first = [("a", rng.integers(1, 500, 12).tolist(), _sp(40))]
+    later = [("long", rng.integers(1, 500, 40).tolist(), _sp(5))]
+    eng = _overlap_engine(max_prefill_tokens=16, min_decode_bucket=4,
+                          num_decode_steps=1)
+    _run_with_arrivals(eng, first, later)
+    assert eng.chain_kept_prefills_total == 3  # two inner chunks, the last
+    rows = eng.flight.records()
+    cycles = [(rows[i - 1]["kind"], r["kind"]) for i, r in enumerate(rows)
+              if i and r["cycle_s"] is not None
+              and rows[i - 1]["cycle_s"] is None]
+    assert cycles.count(("prefill", "decode")) == 2  # the inner chunks
+    assert cycles.count(("decode", "prefill")) == 1  # the join
+
+
+def test_finished_members_give_their_pages_back_with_no_drain():
+    """One long request keeps the chain alive while sixty short ones come
+    and go through a pool that holds six of them: a member's pages are
+    free again one burst after the host saw it finish, the chain never
+    drains, and nothing is left held at the end."""
+    eng = _overlap_engine(num_decode_steps=1, max_num_seqs=4,
+                          min_decode_bucket=4, num_kv_blocks=40,
+                          max_model_len=256)
+    rng = np.random.default_rng(12)
+    eng.add_request("long", prompt_token_ids=rng.integers(1, 500, 10).tolist(),
+                    sampling=_sp(200))
+    done, sent, steps, low = 0, 0, 0, eng.allocator.num_blocks
+    while eng.has_work():
+        for out in eng.step():
+            if out.finished and out.request_id != "long":
+                done += 1
+        steps += 1
+        if sent < 60 and eng.scheduler.num_running < 3 and steps % 2 == 0:
+            eng.add_request(
+                f"s{sent}", sampling=_sp(3 + sent % 4),
+                prompt_token_ids=rng.integers(1, 500, 30).tolist())
+            sent += 1
+        if sent == 60 and done == 60 and eng.runner.burst_in_flight:
+            # two bursts after the last short one finished, with the chain
+            # still running: only the long request holds pages
+            held = len(eng._seqs["long"].block_ids) if "long" in eng._seqs else 0
+            low = min(low, eng.allocator.num_blocks - eng.allocator.num_free
+                      - held)
+        assert steps < 2000
+    assert done == 60
+    assert eng.chain_kept_prefills_total == 60
+    assert sum(eng.pipeline_breaks.values()) == 1, eng.pipeline_breaks
+    assert low == 0, "a finished member's pages were still held"
+    assert not eng._burst_deferred
+    assert eng.allocator.num_free == eng.allocator.num_blocks
+
+
+def test_a_finished_turns_pages_are_hit_by_the_sessions_next_turn():
+    """A chain that never drains commits every page while a burst is in
+    flight (``allow_swap=False``): the pages are registered all the same,
+    and a session's next turn, arriving under the same chain, finds its
+    whole history in the prefix cache as it does in the synchronous loop."""
+    rng = np.random.default_rng(3)
+    history = rng.integers(1, 500, 40).tolist()
+    cached = {}
+    for name, eng in (("sync", _engine(num_decode_steps=1)),
+                      ("chain", _overlap_engine(num_decode_steps=1,
+                                                min_decode_bucket=4))):
+        eng.add_request("keeper", prompt_token_ids=history[:11],
+                        sampling=_sp(120))
+        eng.add_request("t1", prompt_token_ids=history, sampling=_sp(17))
+        answer, turn2, steps = [], None, 0
+        while eng.has_work():
+            for out in eng.step():
+                if out.request_id == "t1":
+                    answer.extend(out.new_token_ids)
+                    if out.finished:
+                        turn2 = eng.add_request(
+                            "t2", prompt_token_ids=history + answer + [7, 8, 9],
+                            sampling=_sp(5))
+            steps += 1
+            assert steps < 1000
+        cached[name] = turn2.num_cached_prompt_tokens
+        if name == "chain":
+            assert sum(eng.pipeline_breaks.values()) == 1
+            assert eng.chain_kept_prefills_total >= 1
+    # 40 + 17 tokens of history, the last sampled token's KV never written:
+    # seven whole pages of eight
+    assert cached["chain"] == cached["sync"] == 56
+
+
+def _fallback(name):
+    """-> (engine overrides, the running chain's request, the arrival)."""
+    rng = np.random.default_rng(21)
+    p = lambda n: rng.integers(1, 500, n).tolist()  # noqa: E731
+    cases = {
+        "row_bucket": (dict(), _sp(30), (p(9), _sp(5))),
+        "table_width": (dict(max_model_len=2048, num_kv_blocks=256,
+                             max_prefill_tokens=1024, min_decode_bucket=4),
+                        _sp(30), (p(600), _sp(5))),
+        "sampling_variant:penalties": (
+            dict(min_decode_bucket=4), _sp(30),
+            (p(9), _sp(5, presence_penalty=0.5))),
+        "sampling_variant:logprobs": (
+            dict(min_decode_bucket=4), _sp(30), (p(9), _sp(5, logprobs=2))),
+        "sampling_variant:sampled": (
+            dict(min_decode_bucket=4), _sp(30),
+            (p(9), SamplingParams(max_tokens=5, temperature=0.8, seed=1,
+                                  ignore_eos=True))),
+        "not_eligible": (
+            dict(min_decode_bucket=4), _sp(30),
+            ([3, 4, 5], SamplingParams(
+                max_tokens=6, temperature=0.0,
+                guided_choice=((5, 9), (5, 12, 13))))),
+        "queue": (dict(min_decode_bucket=4, max_num_seqs=1), _sp(30),
+                  (p(9), _sp(5))),
+    }
+    over, first_sp, (prompt, sp) = cases[name]
+    return over, ("a", p(12), first_sp), ("b", prompt, sp)
+
+
+@pytest.mark.parametrize("name", [
+    "row_bucket", "table_width", "sampling_variant:penalties",
+    "sampling_variant:logprobs", "sampling_variant:sampled", "not_eligible",
+    "queue"])
+def test_what_cannot_join_drains_the_chain_under_its_reason(name):
+    """No free row in the chain's bucket, a table wider than the chain's,
+    penalties (their counts need the first token), log-probabilities or
+    sampling the chain's program was not compiled with, a guided row, a
+    queue left standing: the chain drains as it did, the reason is counted,
+    and the tokens are the synchronous loop's."""
+    over, first, later = _fallback(name)
+    kw = dict(num_decode_steps=1, **over)
+    ref, _ = _run_with_arrivals(_engine(**kw), [first], [later])
+    eng = _overlap_engine(**kw)
+    got, _ = _run_with_arrivals(eng, [first], [later])
+    assert got == ref
+    why = name.split(":")[0]
+    assert eng.pipeline_breaks[why] == 1, eng.pipeline_breaks
+    assert eng.pipeline_breaks["prefill"] == 0
+    assert eng.chain_kept_prefills_total == 0
     assert eng.allocator.num_free == eng.allocator.num_blocks
 
 

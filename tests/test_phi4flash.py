@@ -193,6 +193,47 @@ def test_packed_rows_of_unequal_length_match_their_lone_runs(params):
     assert eng.allocator.window_pages_in_use == 0
 
 
+def test_arrivals_join_the_running_chain_through_all_three_caches(params):
+    """Eight sequences arrive three steps apart under a chain of four rows
+    (one prompt in two chunks): each joins behind its own prefill with no
+    drain, its row of ``window_tables`` sent with the rest of the batch; a
+    finished member's slot, window pages and global pages come back a burst
+    later while the chain runs on. Tokens and log-probabilities are the
+    synchronous loop's."""
+    prompts = [PROMPT[:n] for n in (37, 5, 53, 18, 26, 11, 44, 9)]
+    kw = dict(max_num_seqs=4, min_decode_bucket=4, max_prefill_tokens=32)
+    sync = run(make_engine(overlap_decode=False, **kw), prompts, 9, stagger=3)
+    eng = make_engine(**kw)
+    step, held = eng.step, []
+
+    def checked_step():
+        outs = step()
+        if not sum(eng.pipeline_breaks.values()):  # the chain never drained
+            # slots and window pages are held by running sequences and by
+            # members that finished under the burst in flight, no one else
+            owners = eng.scheduler.running + [
+                s for _, s in eng._burst_deferred]
+            assert eng.allocator.state_slots_in_use == len(owners)
+            assert eng.allocator.window_pages_in_use == sum(
+                len(s.window_block_ids) - s.window_released for s in owners)
+            held.append(len(eng._burst_deferred))
+        return outs
+
+    eng.step = checked_step
+    got = run(eng, prompts, 9, stagger=3)
+    assert 0 < max(held) <= 2 and held.count(0) > len(held) // 2
+    for a, b in zip(got, sync):
+        assert a["tokens"] == b["tokens"]
+        for x, y in zip(a["logprobs"], b["logprobs"]):
+            assert all(abs(x[t] - y[t]) < 1e-3 for t in x)
+    assert eng.chain_kept_prefills_total >= 7
+    assert eng.pipeline_breaks["prefill"] == 0
+    assert sum(eng.pipeline_breaks.values()) == 1, eng.pipeline_breaks
+    assert eng.allocator.state_slots_in_use == 0
+    assert eng.allocator.window_pages_in_use == 0
+    assert eng.allocator.num_free == eng.allocator.num_blocks
+
+
 def test_preemption_by_recompute_returns_the_same_tokens(params):
     """Twelve global pages: two 40-token prompts admit and one must lose
     its pages of both groups and its slot while decoding; it starts again
