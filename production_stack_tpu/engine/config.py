@@ -246,7 +246,9 @@ def _both(why: str) -> dict:
 # flag's name: one table for every class that is not a plain K+V page list.
 # A row: is the flag on, its name, and for each property of the model's
 # config (``recurrent``: per-sequence state beside the pages;
-# ``latent_pages``: a page is one latent row a token, not a K and a V half)
+# ``latent_pages``: a page is one latent row a token, not a K and a V half;
+# ``window_pages``: a page group released below the window;
+# ``wide_head_pages``: heads wider than the paged kernels have been proven at)
 # why the flag cannot be served, or no entry where it can.
 def _refusals(cfg: EngineConfig):
     return [
@@ -299,13 +301,17 @@ def _refusals(cfg: EngineConfig):
                             "two-byte buffer)",
             "window_pages": "one-byte pages of paired [k1 | k2] heads are "
                             "not calibrated (the differential attention "
-                            "subtracts two softmax outputs)"}),
+                            "subtracts two softmax outputs)",
+            "wide_head_pages": "one-byte pages of 256-wide heads are not "
+                               "proven (the paged kernels' one-byte path "
+                               "has run at 128 lanes a head only)"}),
     ]
 
 
 _HAS = {"recurrent": "has recurrent (state-space) layers",
         "latent_pages": "keeps pages of latents (MLA)",
-        "window_pages": "releases its window layers' pages below the window"}
+        "window_pages": "releases its window layers' pages below the window",
+        "wide_head_pages": "keeps pages of 256-wide heads"}
 
 
 def refuse_unserved(cfg: EngineConfig, model_cfg) -> None:
@@ -335,8 +341,6 @@ def resolve_num_kv_blocks(
     layers alone: ``num_kv_layers``); a model with recurrent layers has its
     state pools taken off the budget first.
     """
-    if cfg.num_kv_blocks is not None:
-        return cfg.num_kv_blocks
     dtype_size = jax.numpy.dtype(cfg.kv_cache_dtype or model_cfg.dtype).itemsize
     tp = max(cfg.tensor_parallel_size, 1)
     pp = max(cfg.pipeline_parallel_size, 1)
@@ -373,6 +377,19 @@ def resolve_num_kv_blocks(
             # there; the global group takes the rest.
             budget -= (model_cfg.window_page_bytes(cfg.block_size, dtype_size)
                        * window_block_count(cfg, model_cfg))
+        if cfg.num_kv_blocks is not None:
+            # Explicit pages are taken as they are, if the device can hold
+            # them at all beside the weights and the pools above.
+            over = cfg.num_kv_blocks * page_bytes - (
+                budget + hbm - int(hbm * cfg.hbm_utilization))
+            if over > 0:
+                raise ValueError(
+                    f"--num-kv-blocks {cfg.num_kv_blocks} x {page_bytes} B "
+                    f"is {over / 2**20:.0f} MiB more than the device's "
+                    f"{hbm} B hold beside {param_bytes_per_device} B of "
+                    "weights and the model's state and window pools")
+    if cfg.num_kv_blocks is not None:
+        return cfg.num_kv_blocks
     n = max(budget // page_bytes, cfg.max_num_seqs * 2)
     # Never fewer pages than one full-length sequence needs.
     n = max(n, -(-cfg.max_model_len // cfg.block_size) + 1)

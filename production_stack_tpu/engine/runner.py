@@ -995,6 +995,11 @@ class ModelRunner:
         n = len(seqs)
         # state_slots: rows whose recurrent state the step reads and writes
         slots = {"state_slots": n} if self._recurrent else {}
+        if hasattr(self.model_cfg, "num_state_layers"):
+            # a matrix-valued state: the rows and the layers whose slots the
+            # step reads and writes (what its decode kernel's bytes follow)
+            slots.update(state_rows=n,
+                         state_layers=self.model_cfg.num_state_layers)
         if self.window_blocks:
             # window_tokens: what the window layers read, a row at most its
             # window; window_pages: what the rows hold in that group

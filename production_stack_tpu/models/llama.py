@@ -1442,8 +1442,9 @@ def config_from_hf_json(config_path: str, name: str = ""):
     """Build the model config of an HF ``config.json``: a
     :class:`LlamaConfig`, or a class's own for ``model_type: nemotron_h``
     (``models/nemotron_h.py``), ``glm4_moe_lite``
-    (``models/glm4_moe_lite.py``) and ``phi4flash``
-    (``models/phi4flash.py``)."""
+    (``models/glm4_moe_lite.py``), ``phi4flash``
+    (``models/phi4flash.py``) and ``qwen3_next``
+    (``models/qwen3_next.py``)."""
     with open(config_path) as f:
         hf = json.load(f)
     mt = hf.get("model_type", "llama")
@@ -1459,13 +1460,17 @@ def config_from_hf_json(config_path: str, name: str = ""):
         from .phi4flash import config_from_hf
 
         return config_from_hf(hf, name)
+    if mt == "qwen3_next":  # gated-delta-rule hybrid: DeltaNet, gated attention
+        from .qwen3_next import config_from_hf
+
+        return config_from_hf(hf, name)
     if mt not in (
         "llama", "mistral", "qwen2", "qwen3", "mixtral", "gemma", "gemma2",
     ):
         raise ValueError(
             f"unsupported model_type {mt!r} "
             "(llama/mistral/qwen2/qwen3/mixtral/gemma/gemma2/nemotron_h/"
-            "glm4_moe_lite/phi4flash)"
+            "glm4_moe_lite/phi4flash/qwen3_next)"
         )
     eos = hf.get("eos_token_id", 2)
     eos_ids = tuple(eos) if isinstance(eos, list) else (eos,)

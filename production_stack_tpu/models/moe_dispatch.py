@@ -1,13 +1,15 @@
-"""The expert dispatch both mixture-of-experts classes call
+"""The expert dispatch the mixture-of-experts classes call
 (``models/nemotron_h.py``: ungated ``relu^2`` experts in a latent space;
-``models/glm4_moe_lite.py``: gated SwiGLU experts at full width).
+``models/glm4_moe_lite.py``: gated SwiGLU experts at full width;
+``models/qwen3_next.py``: the same experts behind a softmax router).
 
-One router (``noaux_tc``: sigmoid scores over *all* the experts the router
-knows, the top-k of ``score + bias`` chosen, weighted by ``score``
-renormalised and scaled), one sort-by-expert dispatch over the experts **this
-engine holds** (``held`` of them from ``expert_first`` on: an expert-parallel
-share; pairs routed elsewhere are dropped before the grouped products and
-nothing stands in for the other ranks), the grouped products
+One router (scores over *all* the experts the router knows, sigmoid
+(``noaux_tc``) or softmax as the model says, the top-k of ``score + bias``
+chosen, weighted by ``score`` renormalised and scaled), one sort-by-expert
+dispatch over the experts **this engine holds** (``held`` of them from
+``expert_first`` on: an expert-parallel share; pairs routed elsewhere are
+dropped before the grouped products and nothing stands in for the other
+ranks), the grouped products
 (``megablox.gmm``), and the five counts a step reports. The expert's body is
 the caller's: a function of the sorted rows and a grouped product bound to
 this step's group sizes.
@@ -56,15 +58,24 @@ def grouped_matmul(xs: jax.Array, bank: jax.Array, sizes: jax.Array) -> jax.Arra
     )
 
 
-def route(u: jax.Array, w_router: jax.Array, router_bias: jax.Array, *,
-          top_k: int, norm_topk_prob: bool, scale: float):
+def route(u: jax.Array, w_router: jax.Array,
+          router_bias: Optional[jax.Array], *, top_k: int,
+          norm_topk_prob: bool, scale: float, scoring: str = "sigmoid"):
     """Router over all the experts ``w_router`` scores: ``(ids [N, K],
-    weights [N, K])``, in float32. Selection is by ``score + bias``; the
-    weights are the scores alone, renormalised and scaled."""
-    s = jax.nn.sigmoid(jnp.einsum(
+    weights [N, K])``, in float32. ``scoring`` is the model's: ``sigmoid``
+    (``noaux_tc``) or ``softmax`` over all the experts. Selection is by
+    ``score + bias`` (no bias: by score); the weights are the scores alone,
+    renormalised and scaled."""
+    logits = jnp.einsum(
         "nd,de->ne", u.astype(jnp.float32), w_router,
-        precision=jax.lax.Precision.HIGHEST))
-    _, ids = jax.lax.top_k(s + router_bias, top_k)
+        precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"router scoring {scoring!r}: sigmoid or softmax")
+    _, ids = jax.lax.top_k(s if router_bias is None else s + router_bias, top_k)
     w = jnp.take_along_axis(s, ids, axis=-1)
     if norm_topk_prob:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
@@ -137,12 +148,13 @@ def routed_experts(
     x: jax.Array,  # [N, k] what the experts take (u, or its latent)
     valid: jax.Array,
     w_router: jax.Array,
-    router_bias: jax.Array,
+    router_bias: Optional[jax.Array],
     body: Callable[[jax.Array, Callable], jax.Array],
     *,
     top_k: int,
     norm_topk_prob: bool,
     scale: float,
+    scoring: str = "sigmoid",
     held: int,
     expert_first: int = 0,
     token_budget: Optional[int] = None,
@@ -155,7 +167,8 @@ def routed_experts(
     the grouped product over this step's groups."""
     with jax.named_scope("moe_router"):
         ids, w = route(u, w_router, router_bias, top_k=top_k,
-                       norm_topk_prob=norm_topk_prob, scale=scale)
+                       norm_topk_prob=norm_topk_prob, scale=scale,
+                       scoring=scoring)
         tok, wsort, sizes, stats = dispatch(
             ids, w, valid, held=held, expert_first=expert_first,
             token_budget=token_budget, bank_experts=bank_experts,

@@ -15,6 +15,7 @@ from .llama import Llama, LlamaConfig, config_from_hf_json
 from .glm4_moe_lite import Glm4MoeLite, Glm4MoeLiteConfig
 from .nemotron_h import NemotronH, NemotronHConfig
 from .phi4flash import Phi4Flash, Phi4FlashConfig
+from .qwen3_next import Qwen3Next, Qwen3NextConfig
 
 # Architecture presets. Shapes match the public configs of each family so
 # perf numbers are honest; weights are random-init unless an HF dir is given.
@@ -328,6 +329,35 @@ PRESETS: Dict[str, LlamaConfig] = {
         bos_token_id=None,
         dtype="float32",
     ),
+    # Tiny gated-delta-rule hybrid: two periods of three DeltaNet layers and
+    # one gated attention layer (rotary on a quarter of the lanes), an
+    # expert-parallel share of 4 of 16 experts from expert 4 on, top 3.
+    "tiny-qwen3-next-debug": Qwen3NextConfig(
+        vocab_size=128,
+        hidden_size=64,
+        num_layers=8,
+        full_attention_interval=4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        partial_rotary_factor=0.25,
+        rope_theta=10000.0,
+        linear_num_key_heads=2,
+        linear_num_value_heads=4,
+        linear_key_head_dim=16,
+        linear_value_head_dim=16,
+        n_routed_experts=4,
+        router_experts=16,
+        expert_first=4,
+        num_experts_per_tok=3,
+        moe_intermediate_size=32,
+        shared_expert_intermediate_size=32,
+        max_position_embeddings=2048,
+        name="tiny-qwen3-next-debug",
+        eos_token_ids=(0,),
+        bos_token_id=None,
+        dtype="float32",
+    ),
 }
 
 
@@ -341,6 +371,8 @@ def model_for(model_cfg):
         return Glm4MoeLite(model_cfg)
     if isinstance(model_cfg, Phi4FlashConfig):
         return Phi4Flash(model_cfg)
+    if isinstance(model_cfg, Qwen3NextConfig):
+        return Qwen3Next(model_cfg)
     return Llama(model_cfg)
 
 

@@ -107,12 +107,12 @@ def _tables(rng, B, nb):
     )
 
 
-def _reference(q, kv, tables, kv_lens, q_pos, window, softcap):
+def _reference(q, kv, tables, kv_lens, q_pos, window, softcap, scale=SCALE):
     """Gather attention over the live table prefix, in q-row slices."""
     live_w = -(-int(kv_lens.max()) // BS)
     ref = jax.jit(
         lambda q, kv, t, l, p: gather_paged_attention(
-            q, kv, t, l, p, 0, scale=SCALE, window=window, softcap=softcap
+            q, kv, t, l, p, 0, scale=scale, window=window, softcap=softcap
         )
     )
     outs = [
@@ -151,15 +151,16 @@ def attention_case(name, *, B, T, kv_dtype, window=0, softcap=0.0):
 
 
 def cell_shape_case(name, *, B, kv_heads, kv_dtype, lo, hi, empty=(),
-                    heads=H, paired=False, window=0):
+                    heads=H, paired=False, window=0, head_dim=HD):
     """Decode as a benchmark cell calls it: ragged lengths, a stacked cache
     read at a traced layer, NaN wherever the kernel must not look (with a
     ``window``, every page below it too: the cache manager has released
     those). ``paired``: the differential attention's queries, ``[q1 | 0]``
     and ``[0 | q2]`` by turns, at the scale of half a head."""
     rng = np.random.default_rng(zlib.crc32(name.encode()))
+    HD = head_dim
     lanes, layers, layer = kv_heads * HD, 3, 2
-    scale = 1.0 / np.sqrt(HD // 2) if paired else SCALE
+    scale = 1.0 / np.sqrt(HD // 2 if paired else HD)
     lens = rng.integers(lo, hi, B).astype(np.int32)
     lens[list(empty)] = 0
     width = -(-hi // BS) + 8
@@ -207,12 +208,13 @@ def cell_shape_case(name, *, B, kv_heads, kv_dtype, lo, hi, empty=(),
 
 
 def prefill_cell_case(name, *, T, real, start, kv_dtype, window=0,
-                      softcap=0.0, heads=H, kv_heads=KH):
+                      softcap=0.0, heads=H, kv_heads=KH, head_dim=HD):
     """One prefill row as the runner pads it: ``real`` tokens of a
     ``T``-token bucket after ``start`` cached ones, a stacked cache read at
     a traced layer, NaN wherever the kernel must not look. The real rows
     against gather; the padding rows must come back as zeros."""
     rng = np.random.default_rng(zlib.crc32(name.encode()))
+    HD, scale = head_dim, 1.0 / np.sqrt(head_dim)
     layers, layer = 3, 2
     kv_len = start + real
     width = -(-(start + T) // BS) + 8
@@ -232,7 +234,7 @@ def prefill_cell_case(name, *, T, real, start, kv_dtype, window=0,
     lens = jnp.asarray([kv_len], jnp.int32)
     kern = jax.jit(
         lambda q, kv, t, l, p, ly: pallas_paged_attention(
-            q, kv, t, l, p, ly, scale=SCALE, window=window, softcap=softcap)
+            q, kv, t, l, p, ly, scale=scale, window=window, softcap=softcap)
     )
     t0 = time.perf_counter()
     got = np.asarray(kern(
@@ -241,7 +243,7 @@ def prefill_cell_case(name, *, T, real, start, kv_dtype, window=0,
     compile_s = time.perf_counter() - t0
     want = _reference(
         q, kv[None], jnp.asarray(np.where(dead, 0, tables).astype(np.int32)),
-        lens, q_pos, window, softcap)
+        lens, q_pos, window, softcap, scale)
     return {
         "max_abs_diff": float(np.abs(got[:, :real] - want[:, :real]).max()),
         "padding_rows_max_abs_diff": float(
@@ -304,6 +306,70 @@ def scan_case(name, *, B, T, lens=None):
             and np.array_equal(np.asarray(out[li - 1]), np.asarray(pool[li - 1]))
             and np.isfinite(y).all()),
         "bound": 2e-2,
+        "first_call_s": round(compile_s, 2),
+        "second_call_ms": round(second_s * 1e3, 3),
+    }
+
+
+def delta_case(name, *, B, T, lens=None, keep=None):
+    """The gated-delta-rule kernels (``ops/gated_delta.py``) at the
+    published widths (32 heads of a 128 x 128 float32 state, twelve layers,
+    74 slots), compiled, against the recurrence position by position in
+    ``jax.numpy`` at "highest": ``T == 1`` the decode kernel, else the
+    chunked prefill kernel on rows of true length ``lens`` (``keep`` 0: a
+    row starts from zeros over whatever its slot holds). A token's decay
+    spans 0.2-0.9999 as the configuration's does; the slots hold states of
+    unit scale. The pool's other slots and layers must come back bit for
+    bit; the second call is timed."""
+    from production_stack_tpu.ops import gated_delta as gd
+
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    L, S, Hd, K, V, li = 12, 74, 32, 128, 128, 5
+    f = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape).astype(np.float32))
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    pool = f(L, S, Hd, K, V)
+    q, k, v = unit(f(B, T, Hd, K)) * K ** -0.5, unit(f(B, T, Hd, K)), f(B, T, Hd, V)
+    g = -jnp.exp(jnp.asarray(rng.uniform(-9.0, 0.5, (B, T, Hd)), jnp.float32))
+    beta = jax.nn.sigmoid(f(B, T, Hd))
+    slots = jnp.asarray(rng.permutation(S - 1)[:B].astype(np.int32))
+    keep = jnp.asarray(keep if keep is not None
+                       else (np.arange(B) % 3 != 1).astype(np.int32))
+    lens = jnp.asarray(lens if lens is not None else [T] * B, jnp.int32)
+    valid = (jnp.arange(T)[None, :] < lens[:, None])[..., None]
+    g, beta = jnp.where(valid, g, 0.0), jnp.where(valid, beta, 0.0)
+    s0 = jnp.where(keep[:, None, None, None] != 0, pool[li, slots], 0.0)
+    o_ref, s_ref = jax.jit(gd.delta_reference)(s0, q, k, v, g, beta)
+    if T == 1:
+        kern = jax.jit(lambda pool: gd.gated_delta_decode(
+            pool, li, slots, keep, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+            beta[:, 0]))
+    else:
+        kern = jax.jit(lambda pool: gd.gated_delta_prefill(
+            pool, li, slots, keep, lens, q, k, v, g, beta))
+    t0 = time.perf_counter()
+    o, out = kern(pool)
+    o = np.asarray(o, np.float32).reshape(B, T, Hd, V)
+    compile_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    jax.block_until_ready(kern(pool))
+    second_s = time.perf_counter() - t0
+    live = np.asarray(valid)[..., None]
+    others = np.setdiff1d(np.arange(S), np.asarray(slots))
+    rows = np.asarray(lens) > 0
+    return {
+        "max_abs_diff": float(np.abs(np.where(live, o - np.asarray(o_ref), 0)).max()),
+        "state_max_abs_diff": float(np.abs(
+            np.asarray(out[li, slots]) - np.asarray(s_ref))[rows].max()),
+        "ref_abs_max": float(np.abs(np.where(live, np.asarray(o_ref), 0)).max()),
+        "state_abs_max": float(np.abs(np.asarray(s_ref)).max()),
+        "exact": bool(
+            np.array_equal(np.asarray(out[li, others]), np.asarray(pool[li, others]))
+            and np.array_equal(np.asarray(out[li - 1]), np.asarray(pool[li - 1]))
+            and np.isfinite(o[np.broadcast_to(live, o.shape)]).all()),
+        # float32 on both sides, the kernel's products at "highest": the
+        # chunked form orders its sums differently and nothing else
+        "bound": 2e-4,
         "first_call_s": round(compile_s, 2),
         "second_call_ms": round(second_s * 1e3, 3),
     }
@@ -490,6 +556,22 @@ def cases():
     yield "scan_prefill_b1_t1024", scan_case, dict(B=1, T=1024)
     yield "scan_prefill_b4_t256_ragged", scan_case, dict(
         B=4, T=256, lens=[256, 131, 5, 0])
+    # The gated-delta-rule hybrid's cell: 16 query heads over 2 key-value
+    # heads of 256 lanes at 0.3-5k of context (32 of the cell's 64 rows: the
+    # case's stacked cache of NaN layers has to fit), and its two kernels.
+    wide = dict(kv_heads=2, heads=16, head_dim=256, kv_dtype=jnp.bfloat16)
+    yield "attn_decode_cell_qwen3next_b32_kh2_hd256", cell_shape_case, dict(
+        B=32, lo=300, hi=5000, empty=(7,), **wide)
+    yield "attn_prefill_cell_qwen3next_t1024_real700_hd256", prefill_cell_case, dict(
+        T=1024, real=700, start=1024 + 71, **wide)
+    yield "attn_prefill_cell_qwen3next_t1024_real1024_hd256", prefill_cell_case, dict(
+        T=1024, real=1024, start=0, **wide)
+    yield "delta_decode_b64", delta_case, dict(B=64, T=1)
+    yield "delta_prefill_b1_t1024", delta_case, dict(B=1, T=1024, keep=[0])
+    yield "delta_prefill_b4_t256_ragged", delta_case, dict(
+        B=4, T=256, lens=[256, 131, 5, 0])
+    yield "delta_prefill_b1_t1024_continued", delta_case, dict(
+        B=1, T=1024, lens=[777], keep=[1])
     docs = np.exp(np.linspace(np.log(16384), np.log(40960), 12)).astype(int)
     yield "mla_decode_cell_b16_bf16", mla_case, dict(
         lens=[0, *docs[:6], 0, 0, *(docs[6:] + 137), 0])

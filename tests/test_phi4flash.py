@@ -641,3 +641,34 @@ def test_scan_kernels_compile_for_the_chip_without_a_pool_copy(B, T, one_chip, m
         compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
     pool_bytes = L * S * N * Di * 4
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
+
+
+@pytest.mark.parametrize("B,T", [(64, 1), (1, 1024), (4, 256)])
+def test_gated_delta_kernels_compile_for_the_chip_without_a_pool_copy(B, T, one_chip, monkeypatch):
+    """The gated-delta-rule hybrid's two kernels (``ops/gated_delta.py``,
+    tested in ``tests/test_qwen3_next.py``) at the published widths: twelve
+    layers, 73 slots, 32 heads of a 128 x 128 state. Kept here because the
+    described chip is this file's (one file may load the TPU's library)."""
+    from production_stack_tpu.ops import gated_delta as gdn
+
+    monkeypatch.setattr(gdn, "pallas_interpret", lambda: False)
+    L, S, H, K, V = 12, 73, 32, 128, 128
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    rows = sds((B,), jnp.int32)
+    if T == 1:
+        fn = lambda pool, sl, kp, q, k, v, g, b: gdn.gated_delta_decode(  # noqa: E731
+            pool, 5, sl, kp, q, k, v, g, b)
+        args = (sds((L, S, H, K, V)), rows, rows, sds((B, H, K)),
+                sds((B, H, K)), sds((B, H, V)), sds((B, H)), sds((B, H)))
+    else:
+        fn = lambda pool, sl, kp, ln, q, k, v, g, b: gdn.gated_delta_prefill(  # noqa: E731
+            pool, 5, sl, kp, ln, q, k, v, g, b)
+        args = (sds((L, S, H, K, V)), rows, rows, rows, sds((B, T, H, K)),
+                sds((B, T, H, K)), sds((B, T, H, V)), sds((B, T, H)),
+                sds((B, T, H)))
+    with jax.disable_jit(False):
+        compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    pool_bytes = L * S * H * K * V * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
+    assert "tpu_custom_call" in compiled.as_text()
