@@ -10,9 +10,10 @@ dispatch over the experts **this engine holds** (``held`` of them from
 ``expert_first`` on: an expert-parallel share; pairs routed elsewhere are
 dropped before the grouped products and nothing stands in for the other
 ranks), the grouped products
-(``megablox.gmm``), and the five counts a step reports. The expert's body is
-the caller's: a function of the sorted rows and a grouped product bound to
-this step's group sizes.
+(``megablox.gmm``), the way back (each token gathers its pairs' rows by the
+inverse of the sort and sums them: no scatter anywhere), and the five counts
+a step reports. The expert's body is the caller's: a function of the sorted
+rows and a grouped product bound to this step's group sizes.
 """
 
 from __future__ import annotations
@@ -89,24 +90,23 @@ def dispatch(
     *,
     held: int,
     expert_first: int = 0,
-    token_budget: Optional[int] = None,
     bank_experts: Optional[int] = None,
     bank_first=0,
-) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Sort the held pairs by expert: ``(tok [rows], wsort [rows], sizes,
-    stats [AUX_WIDTH])``. Row ``r`` of the grouped products is token
-    ``tok[r]`` with weight ``wsort[r]`` (0 on rows that belong to no group).
-
-    ``token_budget`` bounds the real tokens among the ``N`` (a prefill step
-    is padded to rows x longest chunk, several times its budget): the
-    grouped products then run over ``budget x K`` pairs at most, which no
-    held pair can fall outside.
+) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Sort the held pairs by expert: ``(tok [rows], pos [N, K], wheld
+    [N, K], sizes, stats [AUX_WIDTH])``, ``rows`` the ``N x K`` pairs padded
+    to the kernel's row tile. Row ``r`` of the grouped products is token
+    ``tok[r]``; pair ``(n, k)`` sits at row ``pos[n, k]`` (the inverse of
+    the sort) with weight ``wheld[n, k]``: 0 where the pair is held
+    elsewhere or its token is padding, and its row is then past every group.
 
     ``sizes`` has one entry an expert of the bank the products are given:
     ``held`` by default; with ``bank_experts`` the bank is a stack of layers'
     banks seen as one (``[layers x held, k, n]``, read in place) and this
     layer's groups start at ``bank_first`` (traced), every other group
-    empty: the kernel visits no empty group."""
+    empty: the kernel visits no empty group. The counts are comparisons and
+    a sum, not ``bincount``: that is a scatter-add of ones (89.8 us at
+    10,240 pairs in PR 41's trace, 14 us as a sum at 22,528 in PR 42's)."""
     N, K = ids.shape
     f32 = jnp.float32
     local = ids - expert_first
@@ -114,16 +114,15 @@ def dispatch(
     # Pairs of experts held elsewhere (and of padding tokens) sort behind
     # every group and belong to none: the grouped products do not reach
     # them. Rows are padded to the kernel's row tile.
-    rows = min(N, token_budget or N) * K
-    rows = -(-rows // GROUP_ROWS) * GROUP_ROWS
-    key = jnp.where(mine, local, held).reshape(-1)
-    wflat = jnp.where(mine, w, 0.0).reshape(-1)
-    if rows > N * K:
-        key = jnp.pad(key, (0, rows - N * K), constant_values=held)
-        wflat = jnp.pad(wflat, (0, rows - N * K))
-    order = jnp.argsort(key)[:rows]
+    rows = -(-N * K // GROUP_ROWS) * GROUP_ROWS
+    key = jnp.pad(jnp.where(mine, local, held).reshape(-1),
+                  (0, rows - N * K), constant_values=held)
+    order = jnp.argsort(key)
+    pos = jnp.argsort(order)[:N * K].reshape(N, K)
     tok = jnp.minimum(order // K, N - 1)
-    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    sizes = jnp.sum(
+        key[:, None] == jnp.arange(held, dtype=key.dtype), axis=0,
+        dtype=jnp.int32)
     stats = jnp.stack([  # in the order of AUX_NAMES
         jnp.sum(valid).astype(f32) * K, jnp.sum(sizes).astype(f32),
         jnp.max(sizes).astype(f32), jnp.sum(sizes > 0).astype(f32),
@@ -132,15 +131,26 @@ def dispatch(
         sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((bank_experts,), jnp.int32), sizes,
             (jnp.asarray(bank_first, jnp.int32),))
-    return tok, wflat[order], sizes, stats
+    return tok, pos, jnp.where(mine, w, 0.0), sizes, stats
 
 
-def combine(y: jax.Array, tok: jax.Array, wsort: jax.Array, n_tokens: int) -> jax.Array:
+def combine(y: jax.Array, pos: jax.Array, wheld: jax.Array) -> jax.Array:
     """The weighted sum of the experts' outputs ``y [rows, n]`` back at
-    their tokens: float32 ``[n_tokens, n]``. Rows past the last group are
-    whatever the kernel left there; their weight is 0 and they add 0."""
-    y = jnp.where(wsort[:, None] != 0.0, y * wsort[:, None], 0.0)
-    return jnp.zeros((n_tokens, y.shape[-1]), jnp.float32).at[tok].add(y)
+    their tokens, float32 ``[N, n]``: token ``n`` gathers the rows of its
+    ``K`` pairs and sums them in the router's order. A pair of weight 0
+    (held elsewhere, or of a padding token) adds exactly 0 whatever its row
+    holds: rows past the last group are whatever the kernel left there, NaN
+    included, hence the ``where`` and not a bare product.
+
+    No scatter-add: XLA runs one as a serial loop over rows, 71 ns a row
+    of 8 KB (115 GB/s on a v5e: 730 us for a 1,024-token step's 10,240
+    pairs, PR 41's trace), where the gather of the same rows takes 132 us
+    and their sum 114 (PERF.md §6, PR 42). The gather is choice-major,
+    ``[K, N, n]``: that is the gathered ``[K x N, n]`` seen again, where
+    ``[N, K, n]`` pads ``K`` to the tile's eight rows and XLA lays the 84
+    MB out a second time (343 us)."""
+    wk = wheld.T[..., None]
+    return jnp.sum(jnp.where(wk != 0.0, y[pos.T] * wk, 0.0), axis=0)
 
 
 def routed_experts(
@@ -164,16 +174,34 @@ def routed_experts(
     """This share's part of the routed sum, float32 ``[N, n]``, and the
     step's ``[AUX_WIDTH]`` counts. ``body(xs, gmm)`` is one expert's
     mathematics over the sorted rows ``xs [rows, k]``, with ``gmm(a, bank)``
-    the grouped product over this step's groups."""
+    the grouped product over this step's groups. Rows go out by a gather
+    along the sort (``x[tok]``) and come back by a gather along its inverse
+    (``combine``): no scatter between the router and the residual.
+
+    ``token_budget`` bounds the real tokens among the ``N`` (a prefill step
+    is padded to rows x longest chunk, up to eight times its budget): the
+    real tokens are then drawn to the front and the layer, router included,
+    runs over ``budget`` tokens, so a step costs what its budget costs
+    however far it is padded; each token reads its sum back from its place
+    among the real ones."""
+    N = u.shape[0]
+    packed = token_budget is not None and token_budget < N
+    if packed:
+        front = jnp.argsort(~valid)[:token_budget]  # stable: in their order
+        place = jnp.cumsum(valid) - 1  # of a real token among the real
+        real, u, x, valid = valid, u[front], x[front], valid[front]
     with jax.named_scope("moe_router"):
         ids, w = route(u, w_router, router_bias, top_k=top_k,
                        norm_topk_prob=norm_topk_prob, scale=scale,
                        scoring=scoring)
-        tok, wsort, sizes, stats = dispatch(
+        tok, pos, wheld, sizes, stats = dispatch(
             ids, w, valid, held=held, expert_first=expert_first,
-            token_budget=token_budget, bank_experts=bank_experts,
-            bank_first=bank_first)
+            bank_experts=bank_experts, bank_first=bank_first)
     xs = x[tok]
     with jax.named_scope("moe_experts"):
         y = body(xs, lambda a, bank: grouped_matmul(a, bank, sizes))
-    return combine(y, tok, wsort, u.shape[0]), stats
+    out = combine(y, pos, wheld)
+    if packed:
+        kept = real & (place < token_budget)
+        out = jnp.where(kept[:, None], out[jnp.maximum(place, 0)], 0.0)
+    return out, stats
