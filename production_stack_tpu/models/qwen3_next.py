@@ -34,8 +34,15 @@ when ``(i + 1) % full_attention_interval == 0``.
 Two kinds of per-request memory (``make_kv_cache``): pages of keys and
 values for the attention layers alone, and for every DeltaNet layer one
 *slot* a sequence holding the ``[value_heads, key, value]`` float32 state
-and the convolution's tail. A row that is padding points at the scratch
-slot, the pool's last.
+and the convolution's tail: the last ``linear_conv_kernel_dim - 1``
+pre-activation rows of ``[q | k | v]``, each row folded to whole ``[sublane,
+lane]`` tiles (``gated_delta.tail_shape``), so that a slot's tail is one
+contiguous piece a kernel can fetch by the slot. A row that is padding
+points at the scratch slot, the pool's last. Three kernels own the two
+pools in place: the chunked delta rule of a prefill step, and of a decode
+step the delta rule's one position and the convolution over ``[tail |
+row]`` with the tail's shift; a prefill step's convolution, and everything
+on the CPU, is ``jax.numpy`` on the same buffers.
 
 The multi-token-prediction layer of the published checkpoints is a draft
 head beside the model; it is not served (``engine/spec.py`` drafts n-grams).
@@ -372,9 +379,11 @@ class Qwen3Next:
     ) -> Dict[str, jax.Array]:
         """``kv``: pages of the attention layers alone, in ``Llama``'s page
         layout. ``ssm``: the kernels' pool, ``[layers, slots, H, K, V]``
-        float32; ``conv``: a slot's tail as one row. One slot a sequence and
-        one more, the last, that padding rows write to. ``aux``: what the
-        last step reported (:meth:`step_aux`)."""
+        float32; ``conv``: a slot's tail, ``[layers, slots, taps - 1,
+        conv_dim / 128, 128]`` (3 x 64 x 128 bf16 at the published widths:
+        whole tiles, nothing padded). One slot a sequence and one more, the
+        last, that padding rows write to. ``aux``: what the last step
+        reported (:meth:`step_aux`)."""
         c = self.cfg
         d = jnp.dtype(dtype) if dtype else c.jdtype
         n = c.num_state_layers
@@ -385,8 +394,9 @@ class Qwen3Next:
                 (n, state_slots + 1, c.linear_num_value_heads,
                  c.linear_key_head_dim, c.linear_value_head_dim), jnp.float32),
             "conv": jnp.zeros(
-                (n, state_slots + 1,
-                 (c.linear_conv_kernel_dim - 1) * c.conv_dim), c.jdtype),
+                (n, state_slots + 1)
+                + gdn.tail_shape(c.linear_conv_kernel_dim, c.conv_dim),
+                c.jdtype),
             "aux": jnp.zeros((AUX_WIDTH,), jnp.float32),
         }
 
@@ -531,7 +541,6 @@ class Qwen3Next:
         cfg = self.cfg
         slots, true_len, valid, keep = rows
         B, T, _ = x.shape
-        Kc, C = cfg.linear_conv_kernel_dim, cfg.conv_dim
         f32 = jnp.float32
         h = _norm(x, lp["norm"], cfg.rms_norm_eps)
         with jax.named_scope("gdn_proj"):
@@ -541,17 +550,15 @@ class Qwen3Next:
 
         with jax.named_scope("gdn_conv"):
             # Causal depthwise convolution over [tail | this step's rows].
-            tail = tails[li, slots].reshape(B, Kc - 1, C)
-            tail = jnp.where(keep[:, None, None], tail, jnp.zeros_like(tail))
-            window = jnp.concatenate([tail, qkv], axis=1)  # [B, Kc-1+T, C]
-            conv = sum(window[:, j:j + T].astype(f32)
-                       * lp["conv_w"][j].astype(f32) for j in range(Kc))
-            # The tail at the row's true length: rows [len, len + Kc - 1) of
-            # the window are positions len - (Kc - 1) .. len - 1.
-            new_tail = jax.vmap(
-                lambda w, n: jax.lax.dynamic_slice_in_dim(w, n, Kc - 1, axis=0)
-            )(window, true_len)
-            tails = tails.at[li, slots].set(new_tail.reshape(B, (Kc - 1) * C))
+            if T == 1 and gdn.use_kernels():
+                # a real row's true length is 1, a padding row's slot the
+                # scratch: every row's tail shifts by its row, in place
+                conv, tails = gdn.conv_tail_decode(
+                    tails, li, slots, keep, qkv[:, 0], lp["conv_w"])
+                conv = conv[:, None]
+            else:
+                conv, tails = gdn.conv_tail_reference(
+                    tails, li, slots, keep, true_len, qkv, lp["conv_w"])
             q, k, v, g, beta = self.delta_inputs(lp, jax.nn.silu(conv), ba)
             # padded positions leave the state as it is
             g = jnp.where(valid[..., None], g, 0.0)

@@ -14,9 +14,9 @@ the state (``S^T k``) before it writes it, which is why the chunked form
 needs a triangular solve a chunk.
 
 The pool is ``[layers, slots, H, K, V]`` float32: a head's keys on the
-sublanes, its values on the lanes. Both kernels read a row's state by its
-slot through scalar prefetch and write it back in place
-(``input_output_aliases``): no copy of the pool.
+sublanes, its values on the lanes. The kernels read a row's block of a pool
+by its slot through scalar prefetch and write it back in place
+(``input_output_aliases``): no copy of a pool.
 
 - :func:`gated_delta_decode` (``%gated_delta_decode``): one position a row,
   on the vector unit; every row's ``[H, K, V]`` state read and written once.
@@ -31,6 +31,11 @@ slot through scalar prefetch and write it back in place
   past a row's true length are not walked, and positions of padding inside
   a row's last chunk have ``g = 0`` and ``beta = 0``, which leaves the
   state as it is.
+- :func:`conv_tail_decode` (``%conv_tail_decode``): a decode step's causal
+  convolution over ``[tail | this step's row]`` on the second pool, the
+  convolution's tails (:func:`tail_shape`: a tap of a slot's tail is whole
+  ``[sublane, lane]`` tiles, so a slot is contiguous and nothing is
+  padded): each row's tail read, shifted by the row and written back.
 
 :func:`delta_reference` is the recurrence position by position in
 ``jax.numpy``: the CPU path, and the tests' oracle for the kernels.
@@ -51,6 +56,14 @@ CHUNK = 64
 # for the double buffer.
 DECODE_HEADS = 8
 LANES = 128
+# What ``conv_tail_decode`` claims of the 128 MiB of fast memory, though its
+# blocks hold under 1 MiB: the tails' pool (43 MB at the served widths) fits
+# there, and XLA, left the room, carries the whole pool in and out around
+# every call (two passes of the pool a layer where 6 MB move; PERF.md §6,
+# PR 43). ``ops/int4_matmul.py`` keeps its scales' stack out the same way. (A
+# memory-space constraint on the aliased operand says it outright, and aborts
+# XLA's memory-space assignment wherever the pool is not donated.)
+TAILS_VMEM_LIMIT_BYTES = 112 << 20
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -169,6 +182,118 @@ def gated_delta_decode(
       keep.astype(jnp.int32), pool, flat(q), flat(k), flat(v),
       lanes(jnp.exp(g.astype(f32)), V), lanes(beta, V))
     return o.reshape(B, H, V), pool
+
+
+# ----------------------------------------------------------------------------
+# Decode: the convolution's tail
+# ----------------------------------------------------------------------------
+
+
+def tail_shape(taps: int, channels: int) -> tuple:
+    """A slot of the tails' pool: the last ``taps - 1`` rows of ``channels``
+    numbers, each row folded to ``[channels / LANES, LANES]`` (one row where
+    the channels are no whole lanes: :func:`conv_tail_decode` refuses that)."""
+    lanes = LANES if channels % LANES == 0 else channels
+    return (taps - 1, channels // lanes, lanes)
+
+
+def conv_tail_reference(tails, li, slots, keep, true_len, x, w):
+    """``x [B, T, C]``: the causal depthwise convolution over ``[tail |
+    x]`` (``w [taps, C]``, oldest tap first) and each slot's tail as at its
+    row's ``true_len``, in ``jax.numpy``: the prefill path, the CPU path and
+    the tests' oracle for :func:`conv_tail_decode`. Returns ``(the sum [B,
+    T, C] float32, tails)``."""
+    B, T, C = x.shape
+    n = w.shape[0] - 1
+    f32 = jnp.float32
+    tail = tails[li, slots].reshape(B, n, C)
+    tail = jnp.where(keep[:, None, None] != 0, tail, jnp.zeros_like(tail))
+    window = jnp.concatenate([tail, x.astype(tail.dtype)], axis=1)
+    conv = sum(window[:, j:j + T].astype(f32) * w[j].astype(f32)
+               for j in range(n + 1))
+    # The tail at the row's true length: rows [len, len + n) of the window
+    # are positions len - n .. len - 1.
+    new_tail = jax.vmap(
+        lambda win, at: jax.lax.dynamic_slice_in_dim(win, at, n, axis=0)
+    )(window, true_len)
+    return conv, tails.at[li, slots].set(
+        new_tail.reshape((B,) + tails.shape[2:]))
+
+
+def _conv_tail_kernel(li_ref, slot_ref, keep_ref, t_ref, x_ref, w_ref, o_ref,
+                      t_out_ref):
+    """One row. ``t_ref [1, 1, taps-1, R, LANES]`` the slot's tail, oldest
+    row first; ``x_ref [1, R, LANES]`` this step's row; ``w_ref [taps, R,
+    LANES]``; ``o_ref [1, R, LANES]`` float32."""
+    from jax.experimental import pallas as pl
+
+    del li_ref, slot_ref
+    keep = keep_ref[pl.program_id(0)] != 0
+    f32 = jnp.float32
+    n = t_ref.shape[2]
+    acc = None
+    for j in range(n):
+        # through float32: exact both ways, and a select the vector unit has
+        t = jnp.where(keep, t_ref[0, 0, j].astype(f32), 0.0)
+        if j:
+            t_out_ref[0, 0, j - 1] = t.astype(t_out_ref.dtype)
+        term = t * w_ref[j].astype(f32)
+        acc = term if acc is None else acc + term
+    x = x_ref[0]
+    t_out_ref[0, 0, n - 1] = x.astype(t_out_ref.dtype)
+    o_ref[0] = acc + x.astype(f32) * w_ref[n].astype(f32)
+
+
+def conv_tail_decode(
+    tails: jax.Array,  # [L, slots, taps-1, R, LANES], updated in place
+    li,  # scalar int32: the pool's layer
+    slots: jax.Array,  # [B] int32: each row's slot
+    keep: jax.Array,  # [B] bool/int: 0 reads the row's tail as zeros
+    x: jax.Array,  # [B, C] this step's row, C = R * LANES
+    w: jax.Array,  # [taps, C] the depthwise kernel, oldest tap first
+):
+    """One position of every row: the convolution over ``[tail | x]`` and
+    the tail shifted by ``x`` on the row's own slot. Returns ``(the sum [B,
+    C] float32, tails)``; the pool is the same buffer. A row that is padding
+    shifts the scratch slot's tail like any other."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    L, S, n, R, lanes = tails.shape
+    B, C = x.shape
+    if lanes != LANES or C != R * LANES or w.shape != (n + 1, C):
+        raise ValueError(
+            f"conv_tail_decode is built for rows of whole {LANES}-lane "
+            f"tiles: tails {tails.shape}, row {x.shape}, kernel {w.shape}")
+    row = pl.BlockSpec((1, R, LANES), lambda b, li, sl, kp: (b, 0, 0))
+    tail = pl.BlockSpec(
+        (1, 1, n, R, LANES), lambda b, li, sl, kp: (li[0], sl[b], 0, 0, 0))
+    taps = pl.BlockSpec((n + 1, R, LANES), lambda b, li, sl, kp: (0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[tail, row, taps],
+        out_specs=[row, tail],
+    )
+    conv, tails = pl.pallas_call(
+        _conv_tail_kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, R, LANES), jnp.float32),
+            jax.ShapeDtypeStruct(tails.shape, tails.dtype),
+        ],
+        # operands count the three prefetched scalars: the pool is input 3
+        input_output_aliases={3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=TAILS_VMEM_LIMIT_BYTES),
+        interpret=pallas_interpret(),
+        name="conv_tail_decode",
+    )(jnp.reshape(li, (1,)).astype(jnp.int32), slots.astype(jnp.int32),
+      keep.astype(jnp.int32), tails,
+      jax.lax.optimization_barrier(x.astype(tails.dtype)).reshape(B, R, LANES),
+      w.reshape(n + 1, R, LANES))
+    return conv.reshape(B, C), tails
 
 
 # ----------------------------------------------------------------------------

@@ -27,6 +27,11 @@ At the Llama-3-8B layer shapes the serving path uses (32 Q / 8 KV heads x
     stacked cache at layer 2 of 3 with NaN in the other layers, in the
     page every dead table entry points at and in the padding lanes of
     that page — reference: gather the live rows, softmax in float32;
+  - ``conv_tail_decode`` (``ops/gated_delta.py``) at the gated-delta cell's
+    widths against the ``jax.numpy`` gather, shift and scatter, and that
+    cell's compiled step programs read as text: a decode step holds the
+    tails' pool in no instruction but the kernel's call, a prefill step in
+    no copy (``qwen3next_program_tails_*``);
   - the fused decode-write variant behind ``PST_FUSED_KV_WRITE`` (each cell
     warms up its own first chunk) — reference: XLA scatter + gather
     attention, and the written cache rows; ``ragged`` gives its rows the
@@ -53,6 +58,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import sys
 import time
 import traceback
@@ -60,7 +66,8 @@ import zlib
 
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -375,6 +382,128 @@ def delta_case(name, *, B, T, lens=None, keep=None):
     }
 
 
+def conv_tail_case(name, *, B):
+    """``conv_tail_decode`` (``ops/gated_delta.py``) at the gated-delta
+    cell's widths (twelve layers, 73 slots, a tail of 3 x 8,192 bf16, four
+    taps), compiled, against the ``jax.numpy`` gather, shift and scatter it
+    replaced in the decode step: ``B`` rows of which three are padding at
+    the scratch slot and a third start from zeros. Real rows' tails and
+    every slot and layer the step does not own must come back bit for bit,
+    the sums to float32 rounding; the second call is timed."""
+    from production_stack_tpu.ops import gated_delta as gd
+
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    L, S, taps, C, li = 12, 73, 4, 8192, 5
+    bf = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape).astype(np.float32), jnp.bfloat16)
+    pool = bf(L, S, *gd.tail_shape(taps, C))
+    x, w = bf(B, C), bf(taps, C)
+    real = np.arange(B) < B - 3
+    slots = jnp.asarray(
+        np.where(real, rng.permutation(S - 1)[:B], S - 1).astype(np.int32))
+    keep = jnp.asarray(((np.arange(B) % 3 != 1) & real).astype(np.int32))
+    lens = jnp.asarray(real.astype(np.int32))
+    want, pool_ref = jax.jit(gd.conv_tail_reference)(
+        pool, li, slots, keep, lens, x[:, None], w)
+    kern = jax.jit(lambda pool: gd.conv_tail_decode(pool, li, slots, keep, x, w))
+    t0 = time.perf_counter()
+    got, out = kern(pool)
+    got = np.asarray(got, np.float32)
+    compile_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    jax.block_until_ready(kern(pool))
+    second_s = time.perf_counter() - t0
+    others = np.setdiff1d(np.arange(S), np.asarray(slots))
+    settled = np.arange(S - 1)  # every slot but the scratch
+    return {
+        "max_abs_diff": float(np.abs(got - np.asarray(want)[:, 0])[real].max()),
+        "ref_abs_max": float(np.abs(np.asarray(want)[:, 0][real]).max()),
+        "exact": bool(
+            np.array_equal(out[li, settled], pool_ref[li, settled])
+            and np.array_equal(out[li, others], pool[li, others])
+            and np.array_equal(out[li - 1], pool[li - 1])
+            and np.array_equal(out[li + 1], pool[li + 1])
+            and np.isfinite(got).all()),
+        # four float32 products summed in the same order on both sides
+        "bound": 1e-5,
+        "first_call_s": round(compile_s, 2),
+        "second_call_ms": round(second_s * 1e3, 3),
+    }
+
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?(%[\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\(")
+# what may carry a pool: the program's plumbing, and a gather's or a
+# scatter's in-place forms in a prefill step (the pool their operand)
+_PLUMBING = {"parameter", "get-tuple-element", "tuple", "while", "call",
+             "conditional", "bitcast"}
+_IN_PLACE = {"fusion", "scatter", "dynamic-update-slice"}
+
+
+def pool_instructions(text, pool, allowed):
+    """-> (calls of ``conv_tail_decode`` with ``pool`` in their result, the
+    other instructions of ``text`` with it there whose opcode is not
+    ``allowed`` or that place it in fast memory)."""
+    found, kernels = [], 0
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if not m or pool not in m.group(2):
+            continue
+        if "conv_tail_decode" in line and m.group(3) == "custom-call":
+            kernels += 1
+        elif m.group(3) not in allowed:
+            found.append(f"{m.group(1)} {m.group(3)}")
+        if any("S(1)" in t for t in re.findall(
+                re.escape(pool) + r"\S*", m.group(2))):
+            found.append(f"{m.group(1)} in S(1)")
+    return kernels, found
+
+
+def tails_program_case(name, *, B, T):
+    """The gated-delta cell's step program (``Qwen3Next.forward`` at
+    ``perf/configs/qwen3-next-ep8-cut.json``, ``B`` rows of ``T`` positions,
+    the cache donated), compiled, read as text: which instructions have the
+    tails' pool in their result. A decode step (``T == 1``): the program's
+    plumbing and ``conv_tail_decode``'s own call, nothing else, and the pool
+    nowhere in fast memory (``S(1)``). A prefill step keeps the ``jax.numpy``
+    gather and scatter, so a scatter over the pool in place may stand there;
+    a copy of it (``copy``, ``copy-start``, ``copy-done``) or ``S(1)`` may
+    not, in either."""
+    from production_stack_tpu.models import qwen3_next as qn
+
+    with open(os.path.join(ROOT, "perf", "configs", "qwen3-next-ep8-cut.json")) as f:
+        cfg = qn.config_from_hf(json.load(f), "qwen3-next-ep8-cut")
+    model = qn.Qwen3Next(cfg)
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(
+        lambda: model.make_kv_cache(2048, 128, None, state_slots=72))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+
+    def step(params, tokens, positions, write_idx, tables, kv_lens, last_idx,
+             cache, slots):
+        return model.forward(
+            params, tokens, positions, write_idx, tables, kv_lens, last_idx,
+            cache, state_slots=slots, token_budget=1024 if T > 1 else None,
+            attn_impl="pallas")
+
+    t0 = time.perf_counter()
+    text = jax.jit(step, donate_argnums=(7,)).lower(
+        params, i32(B, T), i32(B, T), i32(B, T), i32(B, 128), i32(B), i32(B),
+        cache, i32(B)).compile().as_text()
+    compile_s = time.perf_counter() - t0
+    pool = "bf16[" + ",".join(map(str, cache["conv"].shape)) + "]"
+    kernels, found = pool_instructions(
+        text, pool, _PLUMBING | (_IN_PLACE if T > 1 else set()))
+    return {
+        "pool": pool,
+        "kernel_calls_in_text": kernels,
+        "other_instructions_of_the_pools_shape": found[:20],
+        "exact": not found and (kernels > 0) == (T == 1),
+        "bound": 0.0,
+        "compile_s": round(compile_s, 2),
+    }
+
+
 def mla_case(name, *, lens):
     """``mla_decode`` as the latent cell calls it: 20 heads, rank 512 + 64
     rotary lanes in rows of 640, a stacked cache read at a traced layer,
@@ -572,6 +701,12 @@ def cases():
         B=4, T=256, lens=[256, 131, 5, 0])
     yield "delta_prefill_b1_t1024_continued", delta_case, dict(
         B=1, T=1024, lens=[777], keep=[1])
+    yield "qwen3next_conv_tail_decode_b64", conv_tail_case, dict(B=64)
+    yield "qwen3next_program_tails_b64_t1", tails_program_case, dict(B=64, T=1)
+    yield "qwen3next_program_tails_b1_t1024", tails_program_case, dict(
+        B=1, T=1024)
+    yield "qwen3next_program_tails_b4_t256", tails_program_case, dict(
+        B=4, T=256)
     docs = np.exp(np.linspace(np.log(16384), np.log(40960), 12)).astype(int)
     yield "mla_decode_cell_b16_bf16", mla_case, dict(
         lens=[0, *docs[:6], 0, 0, *(docs[6:] + 137), 0])

@@ -14,6 +14,7 @@ against their ``jax.numpy`` forms.
 """
 
 import json
+import re
 import types
 
 import jax
@@ -672,3 +673,79 @@ def test_gated_delta_kernels_compile_for_the_chip_without_a_pool_copy(B, T, one_
     pool_bytes = L * S * H * K * V * 4
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_conv_tail_kernel_compiles_for_the_chip_without_a_pool_copy(one_chip, monkeypatch):
+    """The gated-delta-rule hybrid's third kernel at the published widths:
+    twelve layers, 73 slots, a tail of 3 x 8,192 bf16, 64 rows. Mosaic takes
+    it and the donated 43 MB pool is updated in place."""
+    from production_stack_tpu.ops import gated_delta as gdn
+
+    monkeypatch.setattr(gdn, "pallas_interpret", lambda: False)
+    L, S, taps, C, B = 12, 73, 4, 8192, 64
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    rows = sds((B,), jnp.int32)
+    fn = lambda pool, sl, kp, x, w: gdn.conv_tail_decode(  # noqa: E731
+        pool, 5, sl, kp, x, w)
+    with jax.disable_jit(False):
+        compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+            sds((L, S) + gdn.tail_shape(taps, C)), rows, rows, sds((B, C)),
+            sds((taps, C))).compile()
+    pool_bytes = L * S * (taps - 1) * C * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_gated_delta_decode_program_holds_the_tails_pool_in_the_kernel_alone(
+        one_chip, monkeypatch):
+    """The 64-row decode step of the gated-delta cell's configuration, whole,
+    compiled for the described chip. The 43 MB pool of tails fits XLA's fast
+    memory, and around a gather and a scatter (or an unconstrained kernel
+    operand) it was carried there and back in every DeltaNet layer (PERF.md
+    §6, PR 43): no copy of it, no scatter over it and no ``S(1)`` on it may
+    stand in the text, and the projection's weights stay as they are stored
+    (a reshape of the kernel's row, left to XLA, turned the whole ``w_qkv``
+    stack instead: 403 MB of temporaries)."""
+    from production_stack_tpu.models import moe_dispatch, qwen3_next
+    from production_stack_tpu.ops import gated_delta as gdn
+    from production_stack_tpu.ops import paged_attention_pallas as pap
+
+    for mod in (gdn, pap, moe_dispatch):
+        monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
+    with open("perf/configs/qwen3-next-ep8-cut.json") as f:
+        cfg = qwen3_next.config_from_hf(json.load(f), "qwen3-next-ep8-cut")
+    model = qwen3_next.Qwen3Next(cfg)
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: model.make_kv_cache(2048, 128, None, state_slots=72)))
+    B = 64
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+
+    def step(params, tokens, positions, write_idx, tables, kv_lens, last_idx,
+             cache, slots):
+        return model.forward(
+            params, tokens, positions, write_idx, tables, kv_lens, last_idx,
+            cache, state_slots=slots, attn_impl="pallas")
+
+    with jax.disable_jit(False):
+        compiled = jax.jit(step, donate_argnums=(7,)).lower(
+            params, i32(B, 1), i32(B, 1), i32(B, 1), i32(B, 128), i32(B),
+            i32(B), cache, i32(B)).compile()
+    pool = "bf16[" + ",".join(map(str, cache["conv"].shape)) + "]"
+    assert pool == "bf16[12,73,3,64,128]"  # 43 MB: no padded tile
+    text = compiled.as_text()
+    plumbing = {"parameter", "get-tuple-element", "tuple", "while", "call",
+                "bitcast"}
+    held = [
+        f"{name} {opcode}" for name, result, opcode in re.findall(
+            r"^\s*(?:ROOT )?(%\S+) = (\(.*?\)|\S+) ([\w\-]+)\(", text, re.M)
+        if pool in result and (
+            re.search(re.escape(pool) + r"\S*S\(1\)", result)
+            or opcode not in plumbing and "conv_tail_decode" not in name)]
+    assert not held
+    assert "conv_tail_decode" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

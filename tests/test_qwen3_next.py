@@ -356,9 +356,75 @@ def test_decode_kernel_equals_the_step_and_leaves_other_slots():
     np.testing.assert_array_equal(pool2[1, 0], pool[1, 0])
 
 
+def _tail_rows(case):
+    """-> (slots, keep, true_len) of a decode step's rows over a pool of 74
+    slots whose last is the scratch."""
+    scratch = 73
+    if case == "continued":
+        return [5], [1], [1]
+    if case == "fresh":  # over a slot that holds another sequence's tail
+        return [2], [0], [1]
+    if case == "padding":  # two real rows, three of padding at the scratch
+        return [7, scratch, 0, scratch, scratch], [1, 0, 0, 0, 0], [1, 0, 1, 0, 0]
+    rng = np.random.default_rng(0)  # 64 rows: 50 real in any order, 14 padding
+    slots = rng.permutation(scratch)[:64]
+    real = rng.permutation(64) < 50
+    keep = (rng.random(64) < 0.7) & real
+    return np.where(real, slots, scratch), keep, real
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("case", ["continued", "fresh", "padding", "ragged64"])
+def test_conv_tail_kernel_equals_the_gather_shift_and_scatter(case, dtype):
+    """The decode step's convolution on the tails' pool, interpreted, against
+    the ``jax.numpy`` path it replaces: each real row's tail bit for bit and
+    its sum to rounding, every slot no row of the step owns and every other
+    layer bit for bit. (The scratch slot is nobody's: the kernel shifts it
+    where the ``jax.numpy`` path leaves a padding row's tail as it read it.)"""
+    L, S, taps, C, li = 3, 74, 4, 2 * gdn.LANES, 1
+    slots, keep, lens = (jnp.asarray(a, jnp.int32) for a in _tail_rows(case))
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    pool = jax.random.normal(
+        ks[0], (L, S) + gdn.tail_shape(taps, C)).astype(dtype)
+    x = jax.random.normal(ks[1], (slots.shape[0], C)).astype(dtype)
+    w = jax.random.normal(ks[2], (taps, C)).astype(dtype)
+    want, pool_ref = gdn.conv_tail_reference(
+        pool, li, slots, keep, lens, x[:, None], w)
+    got, pool2 = jax.jit(gdn.conv_tail_decode)(
+        pool, jnp.int32(li), slots, keep, x, w)
+    real = np.asarray(lens) > 0
+    np.testing.assert_allclose(got[real], want[real, 0], atol=1e-6, rtol=1e-6)
+    settled = np.setdiff1d(np.arange(S), [] if real.all() else [S - 1])
+    np.testing.assert_array_equal(pool2[li, settled], pool_ref[li, settled])
+    untouched = np.setdiff1d(np.arange(S), np.asarray(slots))
+    np.testing.assert_array_equal(pool2[li, untouched], pool[li, untouched])
+    np.testing.assert_array_equal(pool2[0], pool[0])
+    np.testing.assert_array_equal(pool2[2], pool[2])
+    # the newest row of a real row's tail is its row of this step
+    np.testing.assert_array_equal(
+        pool2[li, slots[real], -1].reshape(-1, C), x[real])
+
+
+def test_the_tails_pool_is_whole_tiles_a_tap_and_refuses_other_widths():
+    """A slot's tap is ``[channels / 128, 128]``: 64 sublanes of bf16 at the
+    published widths, no padded tile; channels that are no whole lanes keep
+    one row (the CPU path's) and the kernel says so."""
+    assert gdn.tail_shape(4, 8192) == (3, 64, 128)
+    assert gdn.tail_shape(4, 96) == (3, 1, 96)
+    cache = Qwen3Next(CFG).make_kv_cache(4, 8, None, state_slots=3)
+    assert cache["conv"].shape == (
+        CFG.num_state_layers, 4) + gdn.tail_shape(4, CFG.conv_dim)
+    pool = jnp.zeros((1, 2) + gdn.tail_shape(4, 96))
+    with pytest.raises(ValueError, match="whole 128-lane tiles"):
+        gdn.conv_tail_decode(pool, 0, jnp.zeros(1, jnp.int32),
+                             jnp.ones(1, jnp.int32), jnp.zeros((1, 96)),
+                             jnp.zeros((4, 96)))
+
+
 def test_the_model_on_the_interpreted_kernels_equals_the_recurrence(monkeypatch):
-    """One DeltaNet layer of the class at 128-wide heads, prefill then a
-    decode step, on the kernels (interpreted) and on the recurrence."""
+    """One DeltaNet layer of the class at 128-wide heads, prefill then two
+    decode steps, on the kernels (interpreted: the chunked prefill, the
+    decode step and the convolution's tail) and on the recurrence."""
     import dataclasses
 
     cfg = dataclasses.replace(
@@ -387,9 +453,22 @@ def test_the_model_on_the_interpreted_kernels_equals_the_recurrence(monkeypatch)
     np.testing.assert_array_equal(got[2], want[2])
     step = (rows[0], jnp.array([1, 1]), jnp.ones((2, 1), bool),
             jnp.array([True, True]))
+    calls = []
+    kernel = gdn.conv_tail_decode
+    monkeypatch.setattr(gdn, "conv_tail_decode",
+                        lambda *a: calls.append(1) or kernel(*a))
     want2, got2 = both(x[:, :1], want[1], want[2], step)
+    assert calls == [1]  # the decode step's convolution went through it
     np.testing.assert_allclose(got2[0], want2[0], atol=1e-4)
     np.testing.assert_allclose(got2[1], want2[1], atol=1e-4)
+    np.testing.assert_array_equal(got2[2], want2[2])
+    # and a step with a row of padding at the scratch slot (the pool's last)
+    step = (jnp.array([2, 3]), jnp.array([1, 0]),
+            jnp.array([[True], [False]]), jnp.array([True, False]))
+    want3, got3 = both(x[:, 1:2], want2[1], want2[2], step)
+    np.testing.assert_allclose(got3[0][0], want3[0][0], atol=1e-4)
+    np.testing.assert_allclose(got3[1][:, :3], want3[1][:, :3], atol=1e-4)
+    np.testing.assert_array_equal(got3[2][:, :3], want3[2][:, :3])
 
 
 # ----------------------------------------------------------------------------
