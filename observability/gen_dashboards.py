@@ -133,10 +133,6 @@ def fleet_dashboard():
          'clamp_min(sum(rate(vllm:spec_decode_num_draft_tokens_total[2m])),'
          ' 1e-9)', "accept rate"),
     ], 8, 25, unit="percentunit"))
-    p.append(panel("Adaptive deep decode bursts /s", [
-        ('sum(rate(pst:adaptive_deep_bursts_total[2m])) by (model_name)',
-         "{{model_name}}"),
-    ], 16, 25))
     # Row 6 — fleet hit rate (the ≥0.6 north star) + live-KV swap.
     p.append(panel("Fleet KV hit rate (all engines)", [
         ('sum(vllm:gpu_prefix_cache_hits_total) / '

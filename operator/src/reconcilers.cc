@@ -135,10 +135,6 @@ Json build_engine_deployment(const Json& cr, const std::string& ns) {
     push_arg(args, "--quantization", ec.at("quantization").as_string_or(""));
   if (ec.has("numDecodeSteps") && ec.at("numDecodeSteps").as_int(0) > 0)
     push_arg_num(args, "--num-decode-steps", ec.at("numDecodeSteps").as_int());
-  if (ec.has("adaptiveDecodeSteps") &&
-      ec.at("adaptiveDecodeSteps").as_int(0) > 0)
-    push_arg_num(args, "--adaptive-decode-steps",
-                 ec.at("adaptiveDecodeSteps").as_int());
   if (ec.has("hbmUtilization")) {
     char buf[16];
     snprintf(buf, sizeof(buf), "%.3f", ec.at("hbmUtilization").as_number(0.9));

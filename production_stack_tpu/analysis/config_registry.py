@@ -343,13 +343,6 @@ ENGINE_FIELDS: Tuple[EngineFieldSpec, ...] = (
     EngineFieldSpec("num_decode_steps", "--num-decode-steps",
                     _ms("engineConfig.numDecodeSteps"),
                     default_differs=_SIZED),
-    EngineFieldSpec("adaptive_decode_steps", "--adaptive-decode-steps",
-                    _ms("engineConfig.adaptiveDecodeSteps")),
-    EngineFieldSpec("adaptive_decode_quiet_s", "--adaptive-decode-quiet-s",
-                    note="adaptive-burst tuning; extraArgs"),
-    EngineFieldSpec("adaptive_decode_min_running",
-                    "--adaptive-decode-min-running",
-                    note="adaptive-burst tuning; extraArgs"),
     EngineFieldSpec("min_decode_bucket", "--min-decode-bucket",
                     note="lattice floor tuning; extraArgs"),
     EngineFieldSpec("speculative_ngram", "--speculative-ngram",

@@ -18,7 +18,7 @@ import jax
 
 from ..device import require_device_spec
 from ..logging_utils import init_logger
-from ..models.llama import LlamaConfig
+from ..models.base import ModelConfig
 
 logger = init_logger(__name__)
 
@@ -70,22 +70,6 @@ class EngineConfig:
     # burst; at most n-1 speculatively-decoded tokens are discarded per
     # finished request. 1 = classic per-token stepping.
     num_decode_steps: int = 1
-    # Adaptive burst depth: when the arrival stream has been quiet for
-    # ``adaptive_decode_quiet_s`` and nothing is waiting, decode bursts
-    # deepen to this many steps (amortizing the fixed per-dispatch
-    # host<->device latency over more tokens; never timed on a directly
-    # attached chip — ROADMAP D3). Gated on PAST arrivals only, so a live
-    # Poisson stream keeps bursts at num_decode_steps and tail latency is
-    # unaffected; saturated decode (batch/offline phases) runs at the deep
-    # setting. 0 = off. The deepening is all these three fields govern:
-    # an arrival waits for the burst in flight, and a deep one is n steps.
-    adaptive_decode_steps: int = 0
-    adaptive_decode_quiet_s: float = 0.5
-    # Additional deepening gate: require at least this many running
-    # sequences. In closed-loop/multi-round traffic a full running set
-    # means no client has a request left to send — exactly when a deep
-    # burst cannot delay anyone's TTFT. 0 = no constraint.
-    adaptive_decode_min_running: int = 0
     # Floor for the decode-batch row bucket. Serving workloads whose active
     # set fluctuates otherwise walk through every power-of-two width,
     # compiling each one the first time it appears (an XLA compile mid-burst
@@ -231,7 +215,7 @@ def window_block_count(cfg: EngineConfig, model_cfg) -> int:
     window's pages, one where it straddles, one for the token being
     written) and twice a step's prefill budget for the chunks in flight.
     From the model's window and the engine's own limits: no flag."""
-    if not getattr(model_cfg, "window_pages", False):
+    if not model_cfg.window_pages:
         return 0
     pages = lambda tokens: -(-tokens // cfg.block_size)  # noqa: E731
     return (cfg.max_num_seqs * (pages(model_cfg.sliding_window) + 2)
@@ -314,12 +298,16 @@ _HAS = {"recurrent": "has recurrent (state-space) layers",
         "wide_head_pages": "keeps pages of 256-wide heads"}
 
 
-def refuse_unserved(cfg: EngineConfig, model_cfg) -> None:
+def refuse_unserved(cfg: EngineConfig, model_cfg: ModelConfig) -> None:
     """Raise, naming the flag, for the first flag that is on and that one of
     the model config's properties rules out."""
     on_flags = [(flag, why) for on, flag, why in _refusals(cfg) if on]
+    kinds = {"recurrent": model_cfg.recurrent,
+             "latent_pages": model_cfg.latent_pages,
+             "window_pages": model_cfg.window_pages,
+             "wide_head_pages": model_cfg.wide_head_pages}
     for prop, has in _HAS.items():
-        if not getattr(model_cfg, prop, False):
+        if not kinds[prop]:
             continue
         for flag, why in on_flags:
             if prop in why:
@@ -329,32 +317,22 @@ def refuse_unserved(cfg: EngineConfig, model_cfg) -> None:
 
 
 def resolve_num_kv_blocks(
-    cfg: EngineConfig, model_cfg: LlamaConfig, param_bytes_per_device: int
+    cfg: EngineConfig, model_cfg: ModelConfig, param_bytes_per_device: int
 ) -> int:
     """Page count from the HBM budget (``--gpu-memory-utilization`` analogue).
 
-    bytes/page = 2 (K+V) * L * bs * KH * hd * itemsize, divided by tp (kv
-    heads sharded over the tensor axis) and pp (layers sharded over stages);
-    a model whose pages have another shape says their bytes itself
-    (``model_cfg.page_bytes``: latent rows).
-    ``L`` counts the layers that hold pages (a hybrid model's attention
-    layers alone: ``num_kv_layers``); a model with recurrent layers has its
-    state pools taken off the budget first.
+    bytes/page is the model config's to say (``ModelConfig.page_bytes``:
+    2 (K+V) * L * bs * KH * hd * itemsize, divided by tp (kv heads sharded
+    over the tensor axis) and pp (layers sharded over stages), unless its
+    pages have another shape: latent rows). ``L`` counts the layers that
+    hold pages (a hybrid model's attention layers alone: ``num_kv_layers``);
+    a model with recurrent layers has its state pools taken off the budget
+    first.
     """
     dtype_size = jax.numpy.dtype(cfg.kv_cache_dtype or model_cfg.dtype).itemsize
     tp = max(cfg.tensor_parallel_size, 1)
     pp = max(cfg.pipeline_parallel_size, 1)
-    if hasattr(model_cfg, "page_bytes"):  # a page of the model's own shape
-        page_bytes = model_cfg.page_bytes(cfg.block_size, dtype_size)
-    else:
-        page_bytes = (
-            2
-            * max(getattr(model_cfg, "num_kv_layers", model_cfg.num_layers) // pp, 1)
-            * cfg.block_size
-            * max(model_cfg.num_kv_heads // tp, 1)
-            * model_cfg.head_dim
-            * dtype_size
-        )
+    page_bytes = model_cfg.page_bytes(cfg.block_size, dtype_size, tp, pp)
     # local_devices, not devices: on a multi-host mesh devices()[0] may be
     # non-addressable here, and hosts that sized differently would diverge
     # in shape.
@@ -370,13 +348,12 @@ def resolve_num_kv_blocks(
         if not hbm:
             hbm = require_device_spec(dev.device_kind).hbm_bytes
         budget = int(hbm * cfg.hbm_utilization) - param_bytes_per_device
-        if getattr(model_cfg, "recurrent", False):
-            budget -= model_cfg.state_bytes_per_slot() * (state_slot_count(cfg) + 1)
-        if getattr(model_cfg, "window_pages", False):
-            # The window group is sized by what the sequences can hold
-            # there; the global group takes the rest.
-            budget -= (model_cfg.window_page_bytes(cfg.block_size, dtype_size)
-                       * window_block_count(cfg, model_cfg))
+        # The state pool and the window group (nothing, for a model that
+        # has neither) are sized by what the sequences can hold there; the
+        # global group takes the rest.
+        budget -= model_cfg.state_bytes_per_slot() * (state_slot_count(cfg) + 1)
+        budget -= (model_cfg.window_page_bytes(cfg.block_size, dtype_size)
+                   * window_block_count(cfg, model_cfg))
         if cfg.num_kv_blocks is not None:
             # Explicit pages are taken as they are, if the device can hold
             # them at all beside the weights and the pools above.

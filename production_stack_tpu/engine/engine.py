@@ -214,12 +214,9 @@ class LLMEngine:
             self.lora_manager = None
         # Unloaded-adapter slots awaiting their last in-flight sequence.
         self._retiring_slots: set = set()
-        # Last request arrival (the adaptive burst-depth gate), and what the
-        # decode loop did: every decode dispatch, those of them that were
-        # chained (a chain's start and its continuations) or deep, and each
-        # drained chain by the reason it could not go on.
-        self._last_arrival = 0.0
-        self.adaptive_deep_bursts_total = 0
+        # What the decode loop did: every decode dispatch, those of them
+        # that were chained (a chain's start and its continuations), and
+        # each drained chain by the reason it could not go on.
         self.decode_dispatches_total = 0
         self.pipelined_bursts_total = 0
         self.pipeline_breaks = {why: 0 for why in CHAIN_BREAK_REASONS}
@@ -394,7 +391,6 @@ class LLMEngine:
             tenant_class=tenant_class or "interactive",
             kv_transfer=kv_transfer,
         )
-        self._last_arrival = time.time()
         self.scheduler.add(seq)
         self._seqs[request_id] = seq
         self._detok[request_id] = {"emitted": "", "prefix": 0, "read": 0}
@@ -511,38 +507,6 @@ class LLMEngine:
     # Stepping
     # ------------------------------------------------------------------
 
-    def _arrival_safe(self) -> bool:
-        """The three arrival-safety rules of adaptive deepening
-        (proposals/adaptive-decode-bursts.md): PAST observations only —
-        (1) the waiting queue is empty, (2) at least
-        ``adaptive_decode_min_running`` sequences run (closed-loop traffic:
-        a full running set means no client has a request left to send),
-        (3) no arrival for ``adaptive_decode_quiet_s``. While arrivals
-        flow, bursts stay at the configured depth: an arrival waits for
-        the one burst in flight, and a deep one would make it wait
-        ``adaptive_decode_steps`` steps. (The chained pipeline at the
-        configured depth does not ask: `_pipeline_ok`.)"""
-        if self.scheduler.num_waiting:
-            return False
-        if self.scheduler.num_running < self.cfg.adaptive_decode_min_running:
-            return False
-        return (
-            time.time() - self._last_arrival
-            >= self.cfg.adaptive_decode_quiet_s
-        )
-
-    def _decode_depth_hint(self) -> Optional[int]:
-        """Adaptive burst depth: deepen only when the arrival stream has
-        been quiet (PAST arrivals only — a live request stream keeps bursts
-        at the configured depth, so the deepening never costs tail latency
-        it didn't already have)."""
-        cap = self.cfg.adaptive_decode_steps
-        if not cap or cap <= self.cfg.num_decode_steps:
-            return None
-        if not self._arrival_safe():
-            return None
-        return cap
-
     def step(self) -> List[RequestOutput]:
         # The whole step is one phase of the loop (obs/engine_telemetry
         # ``phase``); schedule, batch_build, launch, wait and postprocess
@@ -572,13 +536,13 @@ class LLMEngine:
             return outputs
 
     def _schedule(
-        self, hint: Optional[int], outputs: List[RequestOutput],
+        self, outputs: List[RequestOutput],
         locked: frozenset = frozenset(),
     ):
         """One scheduling pass and its bookkeeping, as the ``schedule``
         phase; deadline sheds are appended to ``outputs``."""
         with ENGINE_TELEMETRY.phase("schedule"):
-            sched = self.scheduler.schedule(locked=locked, n_decode=hint)
+            sched = self.scheduler.schedule(locked=locked)
             self.num_preempted_total += len(sched.preempted)
             outputs += self._finish_expired(sched.expired)
         return sched
@@ -586,11 +550,10 @@ class LLMEngine:
     def _step_impl(self) -> List[RequestOutput]:
         phase = ENGINE_TELEMETRY.phase
         outputs: List[RequestOutput] = []
-        hint = self._decode_depth_hint()
         if self.runner.burst_in_flight:
             locked = frozenset(s.request_id for s in self._burst_seqs)
-            sched = self._schedule(hint, outputs, locked)
-            why, joins = self._chain_break_reason(sched, hint)
+            sched = self._schedule(outputs, locked)
+            why, joins = self._chain_break_reason(sched)
             if why is None:
                 # The chain goes on, behind the pass's prefill if it made
                 # one: that is dispatched first, then the next chained step
@@ -606,7 +569,7 @@ class LLMEngine:
                     handle = self.runner.prefill_dispatch(
                         sched.prefills, record_at_fetch=bool(joins))
                     self.chain_kept_prefills_total += 1
-                self._count_decode(self._burst_n, chained=True)
+                self._count_decode(chained=True)
                 rows = self.runner.burst_continue(members, joins)
                 with phase("postprocess", "decode"):
                     outputs += self._process_burst_rows(fetched, rows)
@@ -641,7 +604,7 @@ class LLMEngine:
                 with phase("postprocess", "prefill"):
                     outputs += self._process_prefill_rows(sched.prefills, prows)
                 return outputs
-        sched = self._schedule(hint, outputs)
+        sched = self._schedule(outputs)
         if sched.is_empty:
             return outputs
         if sched.prefills:
@@ -673,10 +636,10 @@ class LLMEngine:
             # on the NEXT step, overlapped with the following burst.
             self._burst_seqs = list(sched.decodes)
             self._burst_n = sched.n_decode_steps
-            self._count_decode(sched.n_decode_steps, chained=True)
+            self._count_decode(chained=True)
             self.runner.burst_start(sched.decodes, sched.n_decode_steps)
         else:
-            self._count_decode(sched.n_decode_steps, chained=False)
+            self._count_decode(chained=False)
             bursts = self.runner.execute_decode_multi(
                 sched.decodes, sched.n_decode_steps
             )
@@ -893,9 +856,7 @@ class LLMEngine:
         arrival waits for the one burst in flight, as it waits for the
         running step in the synchronous loop, its prefill is launched
         behind that burst and the chain goes on behind the prefill with the
-        arrival among its rows (`_step_impl`, `_chain_break_reason`). Only
-        the deepening past ``num_decode_steps`` waits for quiet
-        (`_decode_depth_hint`)."""
+        arrival among its rows (`_step_impl`, `_chain_break_reason`)."""
         if not sched.decodes or not self.cfg.overlap_decode:
             return False
         # Speculation and overlap are alternative round-trip amortizers;
@@ -936,7 +897,7 @@ class LLMEngine:
         pass admitted is a prefill, not a queue."""
         return bool(self.scheduler.num_waiting or self.scheduler.num_swapped)
 
-    def _chain_break_reason(self, sched, hint: Optional[int] = None) -> tuple:
+    def _chain_break_reason(self, sched) -> tuple:
         """``(None, joins)`` while the burst in flight may chain: the NEXT
         burst's rows are the chain's live members and the sequences whose
         prompt this pass's prefill completes (``joins``: ``(row, sequence,
@@ -947,8 +908,8 @@ class LLMEngine:
 
         A decode pass has scheduled and reserved pages for exactly the live
         members. A prefill pass returns before the scheduler's decode
-        phase, so what that phase would have said is asked here: the depth
-        (``hint`` or the configured one), every row chainable, a row and
+        phase, so what that phase would have said is asked here: the
+        configured depth, every row chainable, a row and
         the chain's sampling program for each new member, and pages for the
         next burst of all (`Scheduler.reserve_chain`). A prefill step that
         completes no prompt (an inner chunk) changes no membership."""
@@ -966,7 +927,7 @@ class LLMEngine:
                 fresh.append(it.seq)
         members = alive + fresh
         if sched.prefills:
-            n = max(hint or self.cfg.num_decode_steps, 1)
+            n = max(self.cfg.num_decode_steps, 1)
             if not members:
                 return "decode_set", ()
         else:
@@ -1000,12 +961,10 @@ class LLMEngine:
         return None, [
             (row, seq, at[id(seq)]) for row, seq in zip(free, fresh)]
 
-    def _count_decode(self, n_steps: int, chained: bool) -> None:
+    def _count_decode(self, chained: bool) -> None:
         self.decode_dispatches_total += 1
         if chained:
             self.pipelined_bursts_total += 1
-        if n_steps > self.cfg.num_decode_steps:
-            self.adaptive_deep_bursts_total += 1
 
     def _process_burst_rows(self, members, rows) -> List[RequestOutput]:
         """Apply one fetched burst's tokens. Rows align with ``members``,
@@ -1297,10 +1256,6 @@ class LLMEngine:
             )
             out["spec_decode_num_accepted_tokens_total"] = float(
                 self.spec_accepted_total
-            )
-        if self.cfg.adaptive_decode_steps:
-            out["adaptive_deep_bursts_total"] = float(
-                self.adaptive_deep_bursts_total
             )
         out["decode_dispatches_total"] = float(self.decode_dispatches_total)
         if self.runner.state_slots:

@@ -152,9 +152,9 @@ def encode_buckets(cfg: EngineConfig) -> List[int]:
 
 def burst_depths(cfg: EngineConfig) -> List[int]:
     """Burst depths the engine dispatches at steady state: the configured
-    depth and the adaptive deep depth — plus, when the pipeline is on
-    (``overlap_decode``, the default: every chainable decode batch), the
-    configured depth even at 1: the pipeline runs the multi-step
+    depth above 1 — and, when the pipeline is on (``overlap_decode``, the
+    default: every chainable decode batch), the configured depth even at 1:
+    the pipeline runs the multi-step
     executable (``b{B}xn{n}``) at whatever depth the scheduler emits, so
     a depth-1 engine decodes through ``b{B}xn1`` shapes, whose program
     is ``jit_pst_decode_step_chained`` (`ModelRunner._burst_fn`). (The
@@ -162,16 +162,12 @@ def burst_depths(cfg: EngineConfig) -> List[int]:
     values on the last few tokens of a context-limit sequence — that long
     tail is deliberately NOT enumerated; it is one compile per engine
     lifetime at worst.)"""
-    depths = {
-        n
-        for n in (cfg.num_decode_steps, cfg.adaptive_decode_steps)
-        if n and n > 1
-    }
+    n = max(cfg.num_decode_steps, 1)
     # Mirrors LLMEngine._pipeline_ok: overlap defers to configured n-gram
     # speculation, so spec engines never dispatch the depth-1 variant.
-    if cfg.overlap_decode and not cfg.speculative_ngram:
-        depths.add(max(cfg.num_decode_steps, 1))
-    return sorted(depths)
+    if n > 1 or (cfg.overlap_decode and not cfg.speculative_ngram):
+        return [n]
+    return []
 
 
 # The (want_lp, greedy) static-flag sets warmed by default. Logprob
@@ -339,9 +335,12 @@ def configure_compile_cache(cfg: EngineConfig, model_cfg) -> Optional[str]:
     executables live; else ``cfg.compile_cache_dir/<key>`` — the
     deployment flag (helm mounts a PVC there), keyed so engines of
     different shape sharing one volume stay apart; else, on the chip, the
-    fixed in-checkout default. On the CPU test platform an unplaced cache
-    stays off (returns None): XLA:CPU logs two multi-KB machine-feature
-    errors per cache hit, and a test engine's compiles are sub-second.
+    fixed in-checkout default. On the CPU platform an unplaced cache stays
+    off (returns None): XLA:CPU logs two multi-KB machine-feature errors
+    per cache hit. (A test engine's compiles are not cheap, a tiny hybrid's
+    step programs take seconds each and most of a model file's CPU: the
+    tests place a cache themselves, through the variable, in
+    ``tests/conftest.py``.)
 
     Must run before the runner wires its jits (compiles that happen
     earlier are never written back)."""

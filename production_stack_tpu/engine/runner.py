@@ -30,12 +30,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..device import describe_devices, pallas_interpret, resolve_platform
 from ..logging_utils import init_logger
 from ..obs.engine_telemetry import ENGINE_TELEMETRY, next_runner_scope
+from ..models.base import ModelConfig
 from ..models.llama import (
     QUANT4_SUFFIX,
     QUANT_LAYER_KEYS,
     QUANT_SUFFIX,
     QUANT_TOP_KEYS,
-    LlamaConfig,
     init_leaf,
     load_hf_params,
     quantize_leaf,
@@ -124,7 +124,7 @@ class ModelRunner:
     def __init__(
         self,
         cfg: EngineConfig,
-        model_cfg: Optional[LlamaConfig] = None,
+        model_cfg: Optional[ModelConfig] = None,
         mesh=None,
     ):
         t_init = time.perf_counter()
@@ -140,7 +140,7 @@ class ModelRunner:
         self.model = model_for(self.model_cfg)
         # A model with recurrent layers keeps, beside its pages, one state
         # slot a sequence and a scratch slot for padding rows.
-        self._recurrent = bool(getattr(self.model_cfg, "recurrent", False))
+        self._recurrent = bool(self.model_cfg.recurrent)
         self.state_slots = 0
         refuse_unserved(cfg, self.model_cfg)
         if self._recurrent:
@@ -150,7 +150,7 @@ class ModelRunner:
         self.window_blocks = window_block_count(cfg, self.model_cfg)
         # A model whose prefill step runs its cross-decoder on sampled
         # positions alone is told which rows those are (``sample_rows``).
-        self._skips_cross = bool(getattr(self.model, "SKIPS_CROSS_DECODER", False))
+        self._skips_cross = bool(self.model.SKIPS_CROSS_DECODER)
         # Tokens prefill steps computed, beside the positions their buckets
         # hold (rows x chunk, padding included).
         self.prefill_tokens_total = 0
@@ -162,7 +162,7 @@ class ModelRunner:
         # Rows a step appends to its packed tokens (the model's step_aux,
         # one for each name in its AUX_NAMES), summed here as they are
         # fetched.
-        self.aux_names = tuple(getattr(self.model, "AUX_NAMES", ()))
+        self.aux_names = tuple(self.model.AUX_NAMES)
         self._aux_rows = len(self.aux_names)
         self.step_aux_totals = np.zeros(self._aux_rows, np.float64)
         tp = cfg.tensor_parallel_size
@@ -308,7 +308,7 @@ class ModelRunner:
         # its rows are padded.
         budget = (
             {"token_budget": max(cfg.max_prefill_tokens, cfg.max_num_seqs)}
-            if recurrent or getattr(model, "TOKEN_BUDGET", False) else {})
+            if recurrent or model.TOKEN_BUDGET else {})
 
         def slots_of(batch, active=None):
             """What a model class is told beside the batch: the state slot
@@ -995,7 +995,7 @@ class ModelRunner:
         n = len(seqs)
         # state_slots: rows whose recurrent state the step reads and writes
         slots = {"state_slots": n} if self._recurrent else {}
-        if hasattr(self.model_cfg, "num_state_layers"):
+        if self.model_cfg.num_state_layers:
             # a matrix-valued state: the rows and the layers whose slots the
             # step reads and writes (what its decode kernel's bytes follow)
             slots.update(state_rows=n,

@@ -107,7 +107,6 @@ class Scheduler:
         # Involuntary preemption/swap keeps the original stamp (front of
         # line); voluntary rotation takes a fresh one (back of line).
         self._stamp = 0
-        self._n_decode_hint: Optional[int] = None
         # (request_id, num_free) of the last head-of-line admission failure:
         # until the free-page count changes there is no point re-running the
         # prefix match every step (it is O(prompt) hashing and would skew the
@@ -229,20 +228,12 @@ class Scheduler:
     # -- the step ---------------------------------------------------------
 
     def schedule(
-        self,
-        locked: frozenset = frozenset(),
-        n_decode: Optional[int] = None,
+        self, locked: frozenset = frozenset(),
     ) -> SchedulerOutput:
         """``locked``: request ids whose pages an in-flight burst references;
         they must not be preempted this pass (the engine drains the burst
-        and re-schedules when that constraint binds).
-
-        ``n_decode``: burst-depth override for this pass (the engine's
-        adaptive-depth hint — deeper bursts amortize the fixed per-step
-        dispatch+fetch latency when the arrival stream is quiet); clamped
-        by the same per-sequence limits as the configured depth."""
+        and re-schedules when that constraint binds)."""
         self._locked = locked
-        self._n_decode_hint = n_decode
         out = SchedulerOutput()
         # Deadline sweep FIRST: an expired sequence must never consume a
         # device step — not a prefill chunk, not a decode slot, not even an
@@ -295,7 +286,7 @@ class Scheduler:
         # Phase 2: a decode burst for every running sequence. Burst length is
         # bounded so no sequence writes KV past max_model_len; early stops
         # are trimmed host-side (≤ n-1 wasted tokens per finishing request).
-        n = max(self._n_decode_hint or self.config.num_decode_steps, 1)
+        n = max(self.config.num_decode_steps, 1)
         for seq in self.running:
             n = min(n, max(self.config.max_model_len - seq.num_tokens, 1))
             if seq.sampling.guided_choice:

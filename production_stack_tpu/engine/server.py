@@ -185,10 +185,6 @@ class EngineMetrics:
             "vllm:spec_decode_num_accepted_tokens",
             "speculative draft tokens accepted",
         )
-        self.adaptive_deep = counter(
-            "pst:adaptive_deep_bursts",
-            "decode bursts executed at the adaptive deep depth",
-        )
         self.pipelined_bursts = counter(
             "pst:pipelined_bursts",
             "decode bursts dispatched as part of an overlapped pipeline "
@@ -413,10 +409,6 @@ class EngineMetrics:
         self._counter_to(
             self.spec_accepted, "accepted",
             stats.get("spec_decode_num_accepted_tokens_total", 0),
-        )
-        self._counter_to(
-            self.adaptive_deep, "deep",
-            stats.get("adaptive_deep_bursts_total", 0),
         )
         self._counter_to(
             self.pipelined_bursts, "pipelined",
@@ -1884,10 +1876,6 @@ def parse_engine_args(argv=None) -> argparse.Namespace:
     p.add_argument("--lora-dir", default="/adapters")
     # Decode burst + batch-shape floors.
     p.add_argument("--num-decode-steps", type=int, default=1)
-    p.add_argument("--adaptive-decode-steps", type=int, default=0,
-                   help="deep burst cap when the arrival stream is quiet")
-    p.add_argument("--adaptive-decode-quiet-s", type=float, default=0.5)
-    p.add_argument("--adaptive-decode-min-running", type=int, default=0)
     p.add_argument("--min-decode-bucket", type=int, default=1)
     # Overlapped decode pipeline (docs/engine.md "Overlapped decode
     # pipeline"): burst N+1 dispatches as soon as burst N's tokens are
@@ -2050,9 +2038,6 @@ def engine_config_from_args(args: argparse.Namespace) -> EngineConfig:
         max_lora_rank=args.max_lora_rank,
         lora_dir=args.lora_dir,
         num_decode_steps=args.num_decode_steps,
-        adaptive_decode_steps=args.adaptive_decode_steps,
-        adaptive_decode_quiet_s=args.adaptive_decode_quiet_s,
-        adaptive_decode_min_running=args.adaptive_decode_min_running,
         overlap_decode=args.overlap_decode,
         min_decode_bucket=args.min_decode_bucket,
         speculative_ngram=args.speculative_ngram,

@@ -43,10 +43,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import xxhash
-from jax.sharding import PartitionSpec as P
 
 from ..ops import mla_attention as mla
-from . import llama, moe_dispatch
+from . import base, moe_dispatch
 
 Params = Dict[str, Any]
 
@@ -58,7 +57,7 @@ AUX_WIDTH = len(AUX_NAMES)
 
 
 @dataclasses.dataclass(frozen=True)
-class Glm4MoeLiteConfig:
+class Glm4MoeLiteConfig(base.ModelConfig):
     vocab_size: int = 154880
     hidden_size: int = 2048
     num_layers: int = 47
@@ -94,10 +93,6 @@ class Glm4MoeLiteConfig:
     num_kv_heads = 1  # one shared row a token: nothing to shard by head
 
     @property
-    def jdtype(self):
-        return jnp.dtype(self.dtype)
-
-    @property
     def head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
@@ -113,7 +108,8 @@ class Glm4MoeLiteConfig:
     def cache_lanes(self) -> int:
         return mla.latent_lanes(self.kv_lora_rank, self.qk_rope_head_dim)
 
-    def page_bytes(self, block_size: int, itemsize: int) -> int:
+    def page_bytes(self, block_size: int, itemsize: int,
+                   tp: int = 1, pp: int = 1) -> int:
         """Bytes of one page over every layer, as the pool stores it."""
         return self.num_layers * block_size * self.cache_lanes * itemsize
 
@@ -193,15 +189,12 @@ def init_leaf(name: str, shape, dtype, key: jax.Array, fan_in: int) -> jax.Array
             / math.sqrt(fan_in)).astype(dtype)
 
 
-class Glm4MoeLite:
+class Glm4MoeLite(base.Model):
     """Stateless model functions bound to a config (the runner's model
     object, as :class:`production_stack_tpu.models.llama.Llama` is)."""
 
     AUX_NAMES = AUX_NAMES  # rows the runner appends to a step's packed tokens
     TOKEN_BUDGET = True  # forward takes the step's bound on real tokens
-
-    def __init__(self, cfg: Glm4MoeLiteConfig):
-        self.cfg = cfg
 
     # ------------------------------------------------------------------
     # Parameters
@@ -276,30 +269,14 @@ class Glm4MoeLite:
             }
         V, D, d = c.vocab_size, c.hidden_size, c.jdtype
         params: Params = {
-            "embed": llama.init_leaf("embed", (V, D), d, key_of("embed")),
+            "embed": base.init_leaf("embed", (V, D), d, key_of("embed")),
             "layers": layers,
             "final_norm": jnp.ones((D,), d),
         }
         if not c.tie_word_embeddings:
-            params["lm_head"] = llama.init_leaf(
+            params["lm_head"] = base.init_leaf(
                 "lm_head", (V, D), d, key_of("lm_head"))
         return params
-
-    def param_pspecs(self, pipeline: bool = False, quantize=False) -> Params:
-        """Every leaf replicated: this class is served on one device (the
-        engine refuses a mesh and quantisation for it at start-up)."""
-        specs: Params = {
-            "embed": P(),
-            "layers": {
-                kind: {leaf: P() for leaf in leaves}
-                for kind, leaves in self.leaf_shapes().items()
-                if self.layer_counts()[kind]
-            },
-            "final_norm": P(),
-        }
-        if not self.cfg.tie_word_embeddings:
-            specs["lm_head"] = P()
-        return specs
 
     # ------------------------------------------------------------------
     # Per-request state: pages of latents
@@ -318,16 +295,6 @@ class Glm4MoeLite:
                 (c.num_layers, num_blocks, 1, block_size, c.cache_lanes), d),
             "aux": jnp.zeros((AUX_WIDTH,), jnp.float32),
         }
-
-    @staticmethod
-    def cache_pspec(pipeline: bool = False) -> Dict[str, P]:
-        return {"kv": P(), "aux": P()}
-
-    @staticmethod
-    def step_aux(cache) -> jax.Array:
-        """``[AUX_WIDTH]`` float32 the step left in its cache, one number
-        for each of ``AUX_NAMES``."""
-        return cache["aux"]
 
     # ------------------------------------------------------------------
     # Forward
@@ -385,7 +352,7 @@ class Glm4MoeLite:
             kv_lens=kv_lens, positions=positions, rope=rope, path=path,
             attn_impl=attn_impl)
 
-        x = llama._embed_lookup(params, tokens, cfg)
+        x = base._embed_lookup(params, tokens, cfg)
         layers = params["layers"]
         kv = cache["kv"]
         for i in range(cfg.first_k_dense):
@@ -393,7 +360,7 @@ class Glm4MoeLite:
             x, kv = self._attention(ap, x, kv, i, step)
             with jax.named_scope("dense_mlp"):
                 x = x + self._swiglu(
-                    llama._rms_norm(x, ap["mlp_norm"], cfg.rms_norm_eps),
+                    base._rms_norm(x, ap["mlp_norm"], cfg.rms_norm_eps),
                     *(layers["dense"][w][i]
                       for w in ("w_gate", "w_up", "w_down"))).astype(x.dtype)
 
@@ -418,7 +385,7 @@ class Glm4MoeLite:
             ap = {k: jax.lax.dynamic_index_in_dim(v, li, keepdims=False)
                   for k, v in layers["attn"].items()}
             x, kv = self._attention(ap, x, kv, li, step)
-            u = llama._rms_norm(x, ap["mlp_norm"], cfg.rms_norm_eps)
+            u = base._rms_norm(x, ap["mlp_norm"], cfg.rms_norm_eps)
             out, stats = self._moe(
                 mp, banks, j * held, u.reshape(B * T, -1), valid, token_budget)
             x = x + out.reshape(B, T, -1).astype(x.dtype)
@@ -430,7 +397,7 @@ class Glm4MoeLite:
         aux = jnp.concatenate([moe_aux, jnp.asarray(
             [path == "expanded", path == "absorbed"], jnp.float32)])
 
-        x = llama._rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        x = base._rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         head = params["lm_head" if "lm_head" in params else "embed"]
         if all_logits:
             logits = jnp.einsum(
@@ -452,21 +419,21 @@ class Glm4MoeLite:
         f32, eps = jnp.float32, cfg.rms_norm_eps
         scale = 1.0 / math.sqrt(cfg.head_dim)
         cos, sin = step["rope"]
-        h = llama._rms_norm(x, ap["attn_norm"], eps)
+        h = base._rms_norm(x, ap["attn_norm"], eps)
         with jax.named_scope("mla_q"):
             c_q = jnp.einsum("btd,dr->btr", h, ap["w_dq"],
                              preferred_element_type=f32).astype(h.dtype)
-            c_q = llama._rms_norm(c_q, ap["q_norm"], eps)
+            c_q = base._rms_norm(c_q, ap["q_norm"], eps)
             q = jnp.einsum("btr,re->bte", c_q, ap["w_uq"],
                            preferred_element_type=f32
                            ).astype(h.dtype).reshape(B, T, H, nope + rd)
             q_nope = q[..., :nope]
-            q_rope = llama._apply_rope(q[..., nope:], cos, sin)
+            q_rope = base._apply_rope(q[..., nope:], cos, sin)
         with jax.named_scope("mla_kv_down"):
             ckr = jnp.einsum("btd,de->bte", h, ap["w_dkv"],
                              preferred_element_type=f32).astype(h.dtype)
-            c_kv = llama._rms_norm(ckr[..., :r], ap["kv_norm"], eps)
-            k_rope = llama._apply_rope(ckr[..., None, r:], cos, sin)[:, :, 0]
+            c_kv = base._rms_norm(ckr[..., :r], ap["kv_norm"], eps)
+            k_rope = base._apply_rope(ckr[..., None, r:], cos, sin)[:, :, 0]
             rows = jnp.concatenate([c_kv, k_rope], axis=-1).reshape(B * T, r + rd)
             rows = jnp.pad(rows, ((0, 0), (0, kv.shape[-1] - r - rd)))
             kv = mla.write_rows(kv, li, step["flat_write"], rows)

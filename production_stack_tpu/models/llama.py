@@ -34,10 +34,9 @@ Design notes (TPU-first):
 from __future__ import annotations
 
 import dataclasses
-import json
 import math
 import os
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,10 +46,20 @@ from jax.sharding import PartitionSpec as P
 from ..logging_utils import init_logger
 from ..ops.attention import paged_attention, window_eff
 from ..parallel.mesh import AXIS_EXPERT, AXIS_PIPELINE, AXIS_TENSOR
+from .base import (  # noqa: F401  (re-exported: the benchmark reads them here)
+    QUANT_SUFFIX,
+    QUANT_TOP_KEYS,
+    Model,
+    ModelConfig,
+    Params,
+    _apply_rope,
+    _embed_lookup,
+    _rms_norm,
+    init_leaf,
+)
 
 logger = init_logger(__name__)
 
-Params = Dict[str, Any]
 
 # ----------------------------------------------------------------------------
 # Weight-only int8 quantization (per-output-channel symmetric).
@@ -69,11 +78,9 @@ Params = Dict[str, Any]
 # per-row scale serves both the lookup and the tied unembed.
 # ----------------------------------------------------------------------------
 
-QUANT_SUFFIX = "_qs"
 QUANT4_SUFFIX = "_q4s"
 QUANT4_GROUP = 128
 QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
-QUANT_TOP_KEYS = ("embed", "lm_head")
 
 
 def quantize_leaf(w: jax.Array, axis: int = -2) -> Tuple[jax.Array, jax.Array]:
@@ -277,21 +284,6 @@ def _qdot(
     return out, s
 
 
-def init_leaf(name: str, shape, dtype, key: jax.Array) -> jax.Array:
-    """One param leaf's random init, matching :meth:`Llama.init_params`
-    distributions by name. Used by the runner's streamed materialization
-    (leaf-by-leaf, jitted straight into its device sharding) so big-model
-    init never holds the full bf16 tree anywhere."""
-    if "norm" in name:
-        return jnp.ones(shape, dtype)
-    if name.startswith(("b", "lora_")):
-        return jnp.zeros(shape, dtype)
-    fan_in = shape[-1] if name in QUANT_TOP_KEYS else shape[-2]
-    return (
-        jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
-    ).astype(dtype)
-
-
 def pp_compose(run_stage, x, replicated, scanned, pp_size: int, mesh):
     """Compose layer-stages across the ``pp`` mesh axis by rotating
     activations (TPU-native pipeline parallel; replaces the reference's
@@ -343,7 +335,7 @@ def pp_compose(run_stage, x, replicated, scanned, pp_size: int, mesh):
 
 
 @dataclasses.dataclass(frozen=True)
-class LlamaConfig:
+class LlamaConfig(ModelConfig):
     vocab_size: int = 32000
     hidden_size: int = 4096
     intermediate_size: int = 11008
@@ -392,10 +384,6 @@ class LlamaConfig:
     bos_token_id: Optional[int] = 1
 
     @property
-    def jdtype(self):
-        return jnp.dtype(self.dtype)
-
-    @property
     def attn_scale(self) -> float:
         base = self.query_pre_attn_scalar or self.head_dim
         return 1.0 / math.sqrt(base)
@@ -409,11 +397,8 @@ class LlamaConfig:
         return self.num_kv_heads * self.head_dim
 
 
-class Llama:
+class Llama(Model):
     """Stateless model functions bound to a config."""
-
-    def __init__(self, cfg: LlamaConfig):
-        self.cfg = cfg
 
     # ------------------------------------------------------------------
     # Parameters
@@ -1041,17 +1026,6 @@ def _decode_write_fused(attn_impl: str) -> bool:
     return resolve_attn_impl(attn_impl) == "pallas"
 
 
-def _rms_norm(
-    x: jax.Array, w: jax.Array, eps: float, unit_offset: bool = False
-) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    normed = xf * jax.lax.rsqrt(var + eps)
-    if unit_offset:  # Gemma stores w with effective weight (1 + w), fp32 math
-        return (normed * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
-    return normed.astype(x.dtype) * w
-
-
 def _act(cfg: "LlamaConfig"):
     if cfg.hidden_act == "gelu_tanh":  # Gemma GeGLU
         return lambda v: jax.nn.gelu(v, approximate=True)
@@ -1076,16 +1050,6 @@ def _layer_window(cfg: "LlamaConfig", li) -> jax.Array:
 
 def _softcap(logits: jax.Array, cap: float) -> jax.Array:
     return jnp.tanh(logits / cap) * cap if cap else logits
-
-
-def _embed_lookup(params: Params, tokens: jax.Array, cfg: "LlamaConfig") -> jax.Array:
-    """Token embedding gather; int8 tables dequantize with their per-row
-    scale (the same rows the tied unembed scales by)."""
-    x = params["embed"][tokens]
-    s = params.get("embed" + QUANT_SUFFIX)
-    if s is not None:
-        x = (x.astype(jnp.float32) * s[tokens][..., None]).astype(cfg.jdtype)
-    return x
 
 
 def _mlp(cfg: "LlamaConfig", lp: Params, h: jax.Array, moe_impl: str = "auto") -> jax.Array:
@@ -1239,18 +1203,6 @@ def _rope_tables(
         )
     angles = positions.astype(jnp.float32)[..., None] * freqs  # [B, T, half]
     return jnp.cos(angles), jnp.sin(angles)
-
-
-def _apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """HF-Llama rotate-half convention; x: [B, T, H, hd]."""
-    half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
-    c = cos[:, :, None, :]
-    s = sin[:, :, None, :]
-    xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
-    return jnp.concatenate(
-        [xf1 * c - xf2 * s, xf2 * c + xf1 * s], axis=-1
-    ).astype(x.dtype)
 
 
 # ----------------------------------------------------------------------------
@@ -1439,39 +1391,18 @@ def load_hf_params(
 
 
 def config_from_hf_json(config_path: str, name: str = ""):
-    """Build the model config of an HF ``config.json``: a
-    :class:`LlamaConfig`, or a class's own for ``model_type: nemotron_h``
-    (``models/nemotron_h.py``), ``glm4_moe_lite``
-    (``models/glm4_moe_lite.py``), ``phi4flash``
-    (``models/phi4flash.py``) and ``qwen3_next``
-    (``models/qwen3_next.py``)."""
-    with open(config_path) as f:
-        hf = json.load(f)
+    """Kept for ``perf/config.py``, which imports it from here: the reader
+    of every ``model_type`` is ``models/registry.py``'s."""
+    from .registry import config_from_hf_json as read
+
+    return read(config_path, name)
+
+
+def config_from_hf(hf: dict, name: str = "") -> LlamaConfig:
+    """The Llama-family keys of an HF ``config.json`` (``model_type`` one
+    of llama, mistral, qwen2, qwen3, mixtral, gemma, gemma2: the rows of
+    ``models/registry.py::MODEL_TYPES`` that name this reader)."""
     mt = hf.get("model_type", "llama")
-    if mt == "nemotron_h":  # a class of its own: state-space + latent MoE
-        from .nemotron_h import config_from_hf
-
-        return config_from_hf(hf, name)
-    if mt == "glm4_moe_lite":  # latent attention + gated experts
-        from .glm4_moe_lite import config_from_hf
-
-        return config_from_hf(hf, name)
-    if mt == "phi4flash":  # decoder-hybrid-decoder: Mamba-1, window, GMU
-        from .phi4flash import config_from_hf
-
-        return config_from_hf(hf, name)
-    if mt == "qwen3_next":  # gated-delta-rule hybrid: DeltaNet, gated attention
-        from .qwen3_next import config_from_hf
-
-        return config_from_hf(hf, name)
-    if mt not in (
-        "llama", "mistral", "qwen2", "qwen3", "mixtral", "gemma", "gemma2",
-    ):
-        raise ValueError(
-            f"unsupported model_type {mt!r} "
-            "(llama/mistral/qwen2/qwen3/mixtral/gemma/gemma2/nemotron_h/"
-            "glm4_moe_lite/phi4flash/qwen3_next)"
-        )
     eos = hf.get("eos_token_id", 2)
     eos_ids = tuple(eos) if isinstance(eos, list) else (eos,)
     heads = hf["num_attention_heads"]

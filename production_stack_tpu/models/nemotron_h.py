@@ -46,11 +46,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import xxhash
-from jax.sharding import PartitionSpec as P
 
 from ..ops import ssm
 from ..ops.attention import paged_attention
-from . import llama, moe_dispatch
+from . import base, moe_dispatch
 from .moe_dispatch import AUX_NAMES, AUX_WIDTH
 
 Params = Dict[str, Any]
@@ -63,7 +62,7 @@ UNSTACKED = ("w1", "w2")
 
 
 @dataclasses.dataclass(frozen=True)
-class NemotronHConfig:
+class NemotronHConfig(base.ModelConfig):
     vocab_size: int = 131072
     hidden_size: int = 4096
     pattern: str = "MEM*E"
@@ -101,10 +100,6 @@ class NemotronHConfig:
 
     # What the engine asks of any model config.
     recurrent = True  # has per-sequence state beside the paged KV
-
-    @property
-    def jdtype(self):
-        return jnp.dtype(self.dtype)
 
     @property
     def num_layers(self) -> int:
@@ -217,11 +212,11 @@ def init_leaf(name: str, shape, dtype, key: jax.Array,
     """One leaf's random init by its name (``<kind>.<leaf>`` or a top-level
     name). The state-space leaves follow the published initialisation
     (``time_step``: ``time_step_min``, ``_max``, ``_floor``); the rest is
-    ``models/llama.py::init_leaf``'s."""
-    base = name.rsplit(".", 1)[-1]
-    if base == "A_log":  # A = -exp(A_log) in -[1, 16]
+    ``models/base.py::init_leaf``'s."""
+    leaf = name.rsplit(".", 1)[-1]
+    if leaf == "A_log":  # A = -exp(A_log) in -[1, 16]
         return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
-    if base == "dt_bias":  # softplus(dt_bias) log-uniform in [min, max]
+    if leaf == "dt_bias":  # softplus(dt_bias) log-uniform in [min, max]
         lo, hi, floor = time_step
         dt = jnp.exp(
             jax.random.uniform(key, shape, jnp.float32)
@@ -229,21 +224,21 @@ def init_leaf(name: str, shape, dtype, key: jax.Array,
         )
         dt = jnp.maximum(dt, floor)
         return dt + jnp.log(-jnp.expm1(-dt))
-    if base == "D":
+    if leaf == "D":
         return jnp.ones(shape, jnp.float32)
-    if base == "router_bias":  # small and non-zero: selects, never weighs
+    if leaf == "router_bias":  # small and non-zero: selects, never weighs
         return 0.02 * jax.random.normal(key, shape, jnp.float32)
-    if base == "conv_b":
+    if leaf == "conv_b":
         return jnp.zeros(shape, dtype)
-    if base == "conv_w":  # [K, C]: fan-in is the kernel's length
+    if leaf == "conv_w":  # [K, C]: fan-in is the kernel's length
         return (
             jax.random.normal(key, shape, jnp.float32) / math.sqrt(shape[-2])
         ).astype(dtype)
-    return llama.init_leaf(base, shape, dtype, key)
+    return base.init_leaf(leaf, shape, dtype, key)
 
 
 
-class NemotronH:
+class NemotronH(base.Model):
     """Stateless model functions bound to a config (the runner's model
     object, as :class:`production_stack_tpu.models.llama.Llama` is)."""
 
@@ -341,26 +336,6 @@ class NemotronH:
             params["lm_head"] = init_leaf("lm_head", (V, D), d, key_of("lm_head"))
         return params
 
-    def param_pspecs(self, pipeline: bool = False, quantize=False) -> Params:
-        """Every leaf replicated: this class is served on one device (the
-        runner refuses tp, pp and quantisation for it at start-up)."""
-        c = self.cfg
-        specs: Params = {
-            "embed": P(),
-            "layers": {
-                kind: {
-                    leaf: (P(),) * c.count(kind) if leaf in UNSTACKED else P()
-                    for leaf in leaves
-                }
-                for kind, leaves in self.leaf_shapes().items()
-                if c.count(kind)
-            },
-            "final_norm": P(),
-        }
-        if not c.tie_word_embeddings:
-            specs["lm_head"] = P()
-        return specs
-
     # ------------------------------------------------------------------
     # Per-request state: pages for attention, slots for the state space
     # ------------------------------------------------------------------
@@ -388,16 +363,6 @@ class NemotronH:
                 (n_m, state_slots + 1, c.conv_kernel - 1, c.conv_dim), c.jdtype),
             "aux": jnp.zeros((AUX_WIDTH,), jnp.float32),
         }
-
-    @staticmethod
-    def cache_pspec(pipeline: bool = False) -> Dict[str, P]:
-        return {"kv": P(), "ssm": P(), "conv": P(), "aux": P()}
-
-    @staticmethod
-    def step_aux(cache) -> jax.Array:
-        """``[AUX_WIDTH]`` float32 the step left in its cache, one number
-        for each of ``AUX_NAMES``: over its real tokens and ``E`` layers."""
-        return cache["aux"]
 
     # ------------------------------------------------------------------
     # Forward
@@ -433,13 +398,13 @@ class NemotronH:
         valid = jnp.arange(T, dtype=jnp.int32)[None, :] < true_len[:, None]
         fresh = positions[:, 0] == 0  # a sequence's first chunk: from zeros
 
-        x = llama._embed_lookup(params, tokens, cfg)
+        x = base._embed_lookup(params, tokens, cfg)
         kv, pool, tails = cache["kv"], cache["ssm"], cache["conv"]
         aux = jnp.zeros((AUX_WIDTH,), jnp.float32)
         layers = params["layers"]
         for kind, i in self.blocks:
             lp = {k: v[i] for k, v in layers[kind].items()}
-            h = llama._rms_norm(x, lp["norm"], cfg.rms_norm_eps)
+            h = base._rms_norm(x, lp["norm"], cfg.rms_norm_eps)
             if kind == "mamba":
                 with jax.named_scope("ssm_mixer"):
                     out, pool, tails = self._mamba(
@@ -455,7 +420,7 @@ class NemotronH:
                 aux = aux + stats
             x = x + out.astype(x.dtype)
 
-        x = llama._rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        x = base._rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         head = params["lm_head" if "lm_head" in params else "embed"]
         if all_logits:
             logits = jnp.einsum(

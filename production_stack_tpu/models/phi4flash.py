@@ -56,11 +56,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import xxhash
-from jax.sharding import PartitionSpec as P
 
 from ..ops import selective_scan as scan
 from ..ops.attention import paged_attention
-from . import llama
+from . import base
 
 Params = Dict[str, Any]
 
@@ -74,7 +73,7 @@ _F32 = ("dt_bias", "A_log", "D", "lambda_q1", "lambda_k1", "lambda_q2",
 
 
 @dataclasses.dataclass(frozen=True)
-class Phi4FlashConfig:
+class Phi4FlashConfig(base.ModelConfig):
     vocab_size: int = 200064
     hidden_size: int = 2560
     intermediate_size: int = 10240
@@ -98,7 +97,6 @@ class Phi4FlashConfig:
     # What the engine asks of any model config.
     recurrent = True  # has per-sequence state beside the paged KV
     window_pages = True  # a group of pages released below the window
-    num_experts = 0
 
     def __post_init__(self):
         if self.num_layers % 4 or self.num_layers < 8:
@@ -110,10 +108,6 @@ class Phi4FlashConfig:
             raise ValueError(
                 "differential attention pairs heads: num_attention_heads and "
                 "num_key_value_heads even, the pairs a multiple of each other")
-
-    @property
-    def jdtype(self):
-        return jnp.dtype(self.dtype)
 
     @property
     def head_dim(self) -> int:
@@ -161,7 +155,8 @@ class Phi4FlashConfig:
 
     # -- what the cache manager sizes its groups from ---------------------
 
-    def page_bytes(self, block_size: int, itemsize: int) -> int:
+    def page_bytes(self, block_size: int, itemsize: int,
+                   tp: int = 1, pp: int = 1) -> int:
         """A page of the global group: the full-attention layer's keys and
         values of ``block_size`` tokens."""
         return 2 * block_size * self.kv_size * itemsize
@@ -253,7 +248,7 @@ def _mm(x, w):
     return jnp.einsum("btd,de->bte", x, w, preferred_element_type=jnp.float32)
 
 
-class Phi4Flash:
+class Phi4Flash(base.Model):
     """Stateless model functions bound to a config (the runner's model
     object, as :class:`production_stack_tpu.models.llama.Llama` is)."""
 
@@ -261,9 +256,6 @@ class Phi4Flash:
     # the runner says which rows a token is sampled from (``sample_rows``).
     SKIPS_CROSS_DECODER = True
     AUX_NAMES = AUX_NAMES  # rows the runner appends to a step's packed tokens
-
-    def __init__(self, cfg: Phi4FlashConfig):
-        self.cfg = cfg
 
     # ------------------------------------------------------------------
     # Parameters
@@ -343,20 +335,6 @@ class Phi4Flash:
             params["lm_head"] = init_leaf("embed", (V, D), d, key_of("lm_head"))
         return params
 
-    def param_pspecs(self, pipeline: bool = False, quantize=False) -> Params:
-        """Every leaf replicated: this class is served on one device (the
-        runner refuses any mesh and quantisation for it at start-up)."""
-        specs: Params = {
-            "embed": P(),
-            "layers": {g: {leaf: P() for leaf in leaves}
-                       for g, leaves in self.leaf_shapes().items()},
-            "final_norm": P(),
-            "final_norm_b": P(),
-        }
-        if not self.cfg.tie_word_embeddings:
-            specs["lm_head"] = P()
-        return specs
-
     # ------------------------------------------------------------------
     # Per-request memory: two page groups and the state slots
     # ------------------------------------------------------------------
@@ -387,15 +365,6 @@ class Phi4Flash:
                 (n_m, state_slots + 1, (c.d_conv - 1) * c.d_inner), c.jdtype),
             "aux": jnp.zeros((len(AUX_NAMES),), jnp.float32),
         }
-
-    @staticmethod
-    def cache_pspec(pipeline: bool = False) -> Dict[str, P]:
-        return {"kv": P(), "wkv": P(), "ssm": P(), "conv": P(), "aux": P()}
-
-    @staticmethod
-    def step_aux(cache) -> jax.Array:
-        """``[len(AUX_NAMES)]`` float32 the step left in its cache."""
-        return cache["aux"]
 
     # ------------------------------------------------------------------
     # Forward
@@ -444,7 +413,7 @@ class Phi4Flash:
             flat >= nb * bs, nbw * bs,
             wblk.reshape(-1) * bs + positions.reshape(-1) % bs)
 
-        x = llama._embed_lookup(params, tokens, cfg)
+        x = base._embed_lookup(params, tokens, cfg)
         layers = params["layers"]
         kv, wkv, pool, tails = (cache["kv"], cache["wkv"], cache["ssm"],
                                 cache["conv"])

@@ -57,11 +57,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import xxhash
-from jax.sharding import PartitionSpec as P
 
 from ..ops import gated_delta as gdn
 from ..ops.attention import paged_attention
-from . import llama, moe_dispatch
+from . import base, moe_dispatch
 from .moe_dispatch import AUX_NAMES, AUX_WIDTH
 
 Params = Dict[str, Any]
@@ -71,7 +70,7 @@ _BANKS = ("w1", "w2")
 
 
 @dataclasses.dataclass(frozen=True)
-class Qwen3NextConfig:
+class Qwen3NextConfig(base.ModelConfig):
     vocab_size: int = 151936
     hidden_size: int = 2048
     num_layers: int = 48
@@ -118,10 +117,6 @@ class Qwen3NextConfig:
         if self.linear_num_value_heads % self.linear_num_key_heads:
             raise ValueError(
                 "linear_num_value_heads is no multiple of linear_num_key_heads")
-
-    @property
-    def jdtype(self):
-        return jnp.dtype(self.dtype)
 
     @property
     def periods(self) -> int:
@@ -256,21 +251,18 @@ def init_leaf(name: str, shape, dtype, key: jax.Array) -> jax.Array:
 
 def _norm(x, w, eps):
     """The zero-centred RMS norm, in float32; the result in ``x``'s dtype."""
-    return llama._rms_norm(x, w, eps, unit_offset=True)
+    return base._rms_norm(x, w, eps, unit_offset=True)
 
 
 def _mm(x, w):
     return jnp.einsum("...d,de->...e", x, w, preferred_element_type=jnp.float32)
 
 
-class Qwen3Next:
+class Qwen3Next(base.Model):
     """Stateless model functions bound to a config (the runner's model
     object, as :class:`production_stack_tpu.models.llama.Llama` is)."""
 
     AUX_NAMES = AUX_NAMES  # rows the runner appends to a step's packed tokens
-
-    def __init__(self, cfg: Qwen3NextConfig):
-        self.cfg = cfg
 
     # ------------------------------------------------------------------
     # Parameters
@@ -356,19 +348,6 @@ class Qwen3Next:
             params["lm_head"] = init_leaf("lm_head", (V, D), d, key_of("lm_head"))
         return params
 
-    def param_pspecs(self, pipeline: bool = False, quantize=False) -> Params:
-        """Every leaf replicated: this class is served on one device (the
-        engine refuses a mesh and quantisation for it at start-up)."""
-        specs: Params = {
-            "embed": P(),
-            "layers": {kind: {leaf: P() for leaf in leaves}
-                       for kind, leaves in self.leaf_shapes().items()},
-            "final_norm": P(),
-        }
-        if not self.cfg.tie_word_embeddings:
-            specs["lm_head"] = P()
-        return specs
-
     # ------------------------------------------------------------------
     # Per-request memory: pages for attention, slots for the delta rule
     # ------------------------------------------------------------------
@@ -399,16 +378,6 @@ class Qwen3Next:
                 c.jdtype),
             "aux": jnp.zeros((AUX_WIDTH,), jnp.float32),
         }
-
-    @staticmethod
-    def cache_pspec(pipeline: bool = False) -> Dict[str, P]:
-        return {"kv": P(), "ssm": P(), "conv": P(), "aux": P()}
-
-    @staticmethod
-    def step_aux(cache) -> jax.Array:
-        """``[AUX_WIDTH]`` float32 the step left in its cache, one number
-        for each of ``AUX_NAMES``: over its real tokens and layers."""
-        return cache["aux"]
 
     # ------------------------------------------------------------------
     # Forward
@@ -496,7 +465,7 @@ class Qwen3Next:
                 p * (n_delta + 1) + n_delta, x + out.astype(x.dtype), aux)
             return (x, kv, pool, tails, aux), None
 
-        x = llama._embed_lookup(params, tokens, cfg)
+        x = base._embed_lookup(params, tokens, cfg)
         (x, kv, pool, tails, aux), _ = jax.lax.scan(
             period,
             (x, cache["kv"], cache["ssm"], cache["conv"],
@@ -593,7 +562,7 @@ class Qwen3Next:
         H, head_dim]`` (half-split pairing), the rest untouched."""
         r = self.cfg.rotary_dim
         return jnp.concatenate(
-            [llama._apply_rope(x[..., :r], cos, sin), x[..., r:]], axis=-1)
+            [base._apply_rope(x[..., :r], cos, sin), x[..., r:]], axis=-1)
 
     def _attention(self, lp, x, kv_all, li, step):
         """-> (the mixer's output [B, T, D] float32, the cache with this

@@ -8,18 +8,39 @@ directory (config.json + safetensors, loaded zero-egress) or a named preset
 
 from __future__ import annotations
 
+import json
 import os
-from typing import Dict
+from typing import Callable, Dict, Tuple
 
-from .llama import Llama, LlamaConfig, config_from_hf_json
-from .glm4_moe_lite import Glm4MoeLite, Glm4MoeLiteConfig
-from .nemotron_h import NemotronH, NemotronHConfig
-from .phi4flash import Phi4Flash, Phi4FlashConfig
-from .qwen3_next import Qwen3Next, Qwen3NextConfig
+from . import glm4_moe_lite, llama, nemotron_h, phi4flash, qwen3_next
+from .base import Model, ModelConfig
+from .glm4_moe_lite import Glm4MoeLiteConfig
+from .llama import LlamaConfig
+from .nemotron_h import NemotronHConfig
+from .phi4flash import Phi4FlashConfig
+from .qwen3_next import Qwen3NextConfig
+
+# HF ``model_type`` -> (reader of its ``config.json`` keys, the config class
+# it returns, the model class bound to such a config). What adding a class
+# costs: its module, a row here, a preset below (and rows in
+# ``engine/config.py::_refusals`` only for a new kind of page).
+MODEL_TYPES: Dict[str, Tuple[Callable[[dict, str], ModelConfig], type, type]] = {
+    **{mt: (llama.config_from_hf, LlamaConfig, llama.Llama) for mt in (
+        "llama", "mistral", "qwen2", "qwen3", "mixtral", "gemma", "gemma2")},
+    "nemotron_h": (
+        nemotron_h.config_from_hf, NemotronHConfig, nemotron_h.NemotronH),
+    "glm4_moe_lite": (
+        glm4_moe_lite.config_from_hf, Glm4MoeLiteConfig,
+        glm4_moe_lite.Glm4MoeLite),
+    "phi4flash": (phi4flash.config_from_hf, Phi4FlashConfig, phi4flash.Phi4Flash),
+    "qwen3_next": (
+        qwen3_next.config_from_hf, Qwen3NextConfig, qwen3_next.Qwen3Next),
+}
+_MODEL_OF = {config: model for _, config, model in MODEL_TYPES.values()}
 
 # Architecture presets. Shapes match the public configs of each family so
 # perf numbers are honest; weights are random-init unless an HF dir is given.
-PRESETS: Dict[str, LlamaConfig] = {
+PRESETS: Dict[str, ModelConfig] = {
     # Tiny debug model for unit tests / CPU-mesh e2e (heads divisible by 8
     # so every tp degree the test mesh uses divides cleanly).
     "tiny-llama-debug": LlamaConfig(
@@ -361,22 +382,26 @@ PRESETS: Dict[str, LlamaConfig] = {
 }
 
 
-def model_for(model_cfg):
+def config_from_hf_json(config_path: str, name: str = "") -> ModelConfig:
+    """The model config of an HF ``config.json``, read by its
+    ``model_type``'s row of :data:`MODEL_TYPES`."""
+    with open(config_path) as f:
+        hf = json.load(f)
+    mt = hf.get("model_type", "llama")
+    if mt not in MODEL_TYPES:
+        raise ValueError(
+            f"unsupported model_type {mt!r} ({'/'.join(MODEL_TYPES)})")
+    return MODEL_TYPES[mt][0](hf, name)
+
+
+def model_for(model_cfg) -> Model:
     """The model class a config belongs to, bound to it: what the runner
     and the benchmark's reference ask for ``init_params``, ``param_pspecs``,
     the cache constructors and ``forward``."""
-    if isinstance(model_cfg, NemotronHConfig):
-        return NemotronH(model_cfg)
-    if isinstance(model_cfg, Glm4MoeLiteConfig):
-        return Glm4MoeLite(model_cfg)
-    if isinstance(model_cfg, Phi4FlashConfig):
-        return Phi4Flash(model_cfg)
-    if isinstance(model_cfg, Qwen3NextConfig):
-        return Qwen3Next(model_cfg)
-    return Llama(model_cfg)
+    return _MODEL_OF[type(model_cfg)](model_cfg)
 
 
-def get_model_config(model: str) -> LlamaConfig:
+def get_model_config(model: str) -> ModelConfig:
     """Resolve ``model`` to a config: preset name or local HF directory."""
     if model in PRESETS:
         return PRESETS[model]

@@ -17,7 +17,8 @@ engine log, the harness's log and its ``window.json``, a traced run's
 ``breakdown`` and, with ``--ops <regex>`` ahead of the tag, the whole text
 (operands and shapes) of the device instructions of its capture whose
 names match. One JSON row a run is printed as it ends, and the table is
-kept. Both sides use one compile cache directory; where it keeps one
+kept. Both sides use one compile cache directory (the one the machine
+comes with, else ``.chip_scratch/cache``); where it keeps one
 tree's programs only (the larger configurations), put a side's runs next
 to each other. Never imports jax in the parent: a process that touched it
 would hold the chip."""
@@ -75,7 +76,9 @@ def main(argv: list) -> int:
     out_root = os.path.join(ROOT, "chiprun_out", tag)
     os.makedirs(out_root, exist_ok=True)
     env = dict(os.environ)
-    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(SCRATCH, "cache")
+    # the machine's own cache where it comes with one: the tool keeps it
+    # for this repository's next call
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(SCRATCH, "cache"))
     os.makedirs(env["JAX_COMPILATION_CACHE_DIR"], exist_ok=True)
     table = []
     for n, spec in enumerate(runs):

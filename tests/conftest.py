@@ -20,7 +20,23 @@ if "xla_force_host_platform_device_count" not in _flags:
     ).strip()
 os.environ.setdefault("PST_FORCE_PALLAS_INTERPRET", "1")
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _ROOT)
+
+# A program is compiled once: jax's persistent compilation cache, at one
+# fixed path in the checkout (git-ignored) that the xdist workers and the
+# engine processes the tests start share through the environment, which
+# ``engine/precompile.py::configure_compile_cache`` honours. An entry is
+# keyed by the program's text, jax's version and the compile options, so an
+# old entry is never a wrong one and a second run on the same copy starts
+# warm. Most of what the model files' engines compile, another engine of
+# the same settings compiled before. The thresholds keep every program,
+# however quickly it compiled. Tests that place a cache of their own take
+# the variable out first (``tests/test_precompile.py``).
+os.environ.setdefault(
+    "JAX_COMPILATION_CACHE_DIR", os.path.join(_ROOT, ".jax_cache", "tests"))
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
 
 import asyncio  # noqa: E402
 import inspect  # noqa: E402

@@ -274,7 +274,8 @@ def test_pallas_attention_runs_per_shard(T, dp):
 # ---------------------------------------------------------------------------
 
 
-def test_engine_states_its_device_path():
+def test_engine_states_its_device_path(monkeypatch, cache_config_restored):
+    monkeypatch.delenv(precompile.CACHE_DIR_ENV, raising=False)  # the tests' own
     eng = LLMEngine(_tiny())
     info = eng.runner.device_info
     assert info["platform"] == "cpu" and info["device_kind"] == "cpu"

@@ -161,16 +161,25 @@ def test_collections_are_timed_in_the_program():
     tel.watch_collections()
     tel.watch_collections()  # one entry however often it is asked for
     assert gc.callbacks.count(tel._on_collection) == 1
-    graph = [[i] for i in range(200_000)]
-    before = _gauge(render_engine_telemetry().decode(), series)
-    gc.collect(0)  # the young generation is not timed
-    assert _gauge(render_engine_telemetry().decode(), series) == before
-    with tel.phase("step"):  # a collection adds to the open cycle
-        gc.collect()
-        cycle = tel._open_cycle()
-    del graph
-    grown = _gauge(render_engine_telemetry().decode(), series) - before
-    assert grown > 0 and cycle.gc_s == pytest.approx(grown)
+    # Automatic collection is held off: between two readings of the counter
+    # only the collections asked for here run (a full one that an allocation
+    # trips in between is timed too, and moved it under the driver's load).
+    was_on = gc.isenabled()
+    gc.disable()
+    try:
+        graph = [[i] for i in range(200_000)]
+        before = _gauge(render_engine_telemetry().decode(), series)
+        gc.collect(0)  # the young generation is not timed
+        assert _gauge(render_engine_telemetry().decode(), series) == before
+        with tel.phase("step"):  # a collection adds to the open cycle
+            gc.collect()
+            cycle = tel._open_cycle()
+        del graph
+        grown = _gauge(render_engine_telemetry().decode(), series) - before
+        assert grown > 0 and cycle.gc_s == pytest.approx(grown)
+    finally:
+        if was_on:
+            gc.enable()
 
 
 def test_a_collection_that_reports_late_is_counted_once_and_where_it_fell():

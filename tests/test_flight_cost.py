@@ -384,7 +384,6 @@ def test_cost_attribution_parity_vs_device_busy(overlap):
     eng = LLMEngine(_tiny_cfg(
         overlap_decode=overlap,
         num_decode_steps=4 if overlap else 1,
-        adaptive_decode_quiet_s=0.0,
     ))
     _drive_mixed(eng, "warm")  # absorb compiles
     busy0 = ENGINE_TELEMETRY.device_busy_seconds()

@@ -23,12 +23,13 @@ import pytest
 from perf import config as configs
 from perf.reference import glm4_moe_lite as ref
 from production_stack_tpu.engine.config import EngineConfig, resolve_num_kv_blocks
-from production_stack_tpu.engine.engine import LLMEngine
-from production_stack_tpu.engine.sequence import SamplingParams
 from production_stack_tpu.models import moe_dispatch
 from production_stack_tpu.models.glm4_moe_lite import Glm4MoeLite
 from production_stack_tpu.models.registry import PRESETS
 from production_stack_tpu.ops import mla_attention as mla
+
+from . import model_contract as contract
+from .model_contract import assert_same, run
 
 NAME = "tiny-glm4-moe-lite-debug"
 CFG = PRESETS[NAME]
@@ -57,36 +58,7 @@ REF_CFG = configs.Config(
     reference="glm4_moe_lite", raw={})
 
 
-def make_engine(**over) -> LLMEngine:
-    kw = dict(
-        model=NAME, max_model_len=256, block_size=8, num_kv_blocks=96,
-        max_num_seqs=4, max_prefill_tokens=16, kv_swap=False,
-    )
-    kw.update(over)
-    return LLMEngine(EngineConfig(**kw))
-
-
-def run(eng, prompts, n_tokens, stagger=0, logprobs=5):
-    """Drive ``eng`` by hand: request i arrives after ``stagger * i`` steps.
-    -> per request ``{"tokens", "logprobs": [{id: lp}]}``."""
-    sp = SamplingParams(max_tokens=n_tokens, temperature=0.0, ignore_eos=True,
-                        logprobs=logprobs)
-    res, pending, steps = {}, list(enumerate(prompts)), 0
-    while pending or eng.has_work():
-        while pending and steps >= stagger * pending[0][0]:
-            i, p = pending.pop(0)
-            res[f"r{i}"] = {"tokens": [], "logprobs": []}
-            eng.add_request(f"r{i}", prompt_token_ids=list(p), sampling=sp)
-        for out in eng.step():
-            r = res[out.request_id]
-            r["tokens"].extend(out.new_token_ids)
-            for lp in out.logprobs or []:
-                at = dict(lp["top"])
-                at[lp["token_id"]] = lp["logprob"]
-                r["logprobs"].append(at)
-        steps += 1
-        assert steps < 2000, "the engine makes no progress"
-    return [res[f"r{i}"] for i in range(len(prompts))]
+make_engine = functools.partial(contract.make_engine, NAME)
 
 
 @pytest.fixture(scope="module")
@@ -105,18 +77,8 @@ def reference_logprobs(params, prompt, tokens):
     return ref.teacher_force(REF_CFG, params, [seq], "none")[0][0]
 
 
-def assert_matches_reference(params, prompt, got, tol=TOL):
-    lps = reference_logprobs(params, prompt, got["tokens"])
-    assert len(got["logprobs"]) == len(got["tokens"]) == len(lps)
-    for j, at in enumerate(got["logprobs"]):
-        for tid, lp in at.items():
-            assert abs(lps[j][tid] - lp) < tol, (j, tid, lps[j][tid], lp)
-
-
-def assert_same_logprobs(a, b, tol=1e-3):
-    assert a["tokens"] == b["tokens"]
-    for x, y in zip(a["logprobs"], b["logprobs"]):
-        assert all(abs(x[t] - y[t]) < tol for t in x)
+assert_matches_reference = functools.partial(
+    contract.assert_matches_reference, reference_logprobs, tol=TOL)
 
 
 # ----------------------------------------------------------------------------
@@ -181,7 +143,7 @@ def test_a_prefix_cache_hit_on_latent_pages_gives_the_same_logits(engine, params
     assert engine.stats()["prefix_cache_hits_total"] == hits
     again = run(engine, [doc], 6)[0]
     assert engine.stats()["prefix_cache_hits_total"] - hits == 48
-    assert_same_logprobs(first, again)
+    assert_same(first, again)
     assert_matches_reference(params, doc, again)
     turn = doc + first["tokens"] + [4, 19, 88]
     hits = engine.stats()["prefix_cache_hits_total"]
@@ -214,7 +176,7 @@ def test_arrivals_join_the_running_chain_on_latent_pages(params):
     eng = make_engine(**kw)
     got = run(eng, prompts, 9, stagger=3)
     for a, b in zip(got, sync):
-        assert_same_logprobs(a, b)
+        assert_same(a, b)
     assert eng.chain_kept_prefills_total >= 7
     assert eng.pipeline_breaks["prefill"] == 0
     assert sum(eng.pipeline_breaks.values()) == 1, eng.pipeline_breaks

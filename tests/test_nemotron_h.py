@@ -11,18 +11,19 @@ slot reused after a finish — is here.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from production_stack_tpu.engine.config import EngineConfig
-from production_stack_tpu.engine.engine import LLMEngine
-from production_stack_tpu.engine.sequence import SamplingParams
 from production_stack_tpu.models.nemotron_h import NemotronH
 from production_stack_tpu.models.registry import PRESETS
 from production_stack_tpu.ops import ssm
+
+from . import model_contract as contract
+from .model_contract import assert_same, run
 
 CFG = PRESETS["tiny-nemotron-h-debug"]
 KIND = {"M": "mamba", "*": "attn", "E": "moe"}
@@ -153,43 +154,16 @@ def _log_softmax(x):
 # ----------------------------------------------------------------------------
 
 
-def make_engine(**over) -> LLMEngine:
-    kw = dict(
-        model="tiny-nemotron-h-debug", max_model_len=256, block_size=8,
-        num_kv_blocks=96, max_num_seqs=4, max_prefill_tokens=16,
-        enable_prefix_caching=False, kv_swap=False,
-    )
-    kw.update(over)
-    return LLMEngine(EngineConfig(**kw))
+make_engine = functools.partial(
+    contract.make_engine, "tiny-nemotron-h-debug", enable_prefix_caching=False)
 
 
-def run(eng, prompts, n_tokens, stagger=0, logprobs=5):
-    """Drive ``eng`` by hand: request i arrives after ``stagger * i`` steps.
-    -> per request ``{"tokens", "logprobs": [{id: lp}], "slots"}``."""
-    sp = SamplingParams(max_tokens=n_tokens, temperature=0.0, ignore_eos=True,
-                        logprobs=logprobs)
-    res = {}
-    pending = list(enumerate(prompts))
-    steps = 0
-    while pending or eng.has_work():
-        while pending and steps >= stagger * pending[0][0]:
-            i, p = pending.pop(0)
-            res[f"r{i}"] = {"tokens": [], "logprobs": [], "slots": set(),
-                            "seq": eng.add_request(
-                                f"r{i}", prompt_token_ids=list(p), sampling=sp)}
-        for r in res.values():
-            if r["seq"].state_slot is not None:
-                r["slots"].add(r["seq"].state_slot)
-        for out in eng.step():
-            r = res[out.request_id]
-            r["tokens"].extend(out.new_token_ids)
-            for lp in out.logprobs or []:
-                at = dict(lp["top"])
-                at[lp["token_id"]] = lp["logprob"]
-                r["logprobs"].append(at)
-        steps += 1
-        assert steps < 2000, "the engine makes no progress"
-    return [res[f"r{i}"] for i in range(len(prompts))]
+def slots_into(seen):
+    """A ``watch`` for ``run``: the state slots each request held, by id."""
+    def watch(seq):
+        if seq.state_slot is not None:
+            seen.setdefault(seq.request_id, set()).add(seq.state_slot)
+    return watch
 
 
 @pytest.fixture(scope="module")
@@ -203,14 +177,13 @@ def params(engine):
                         jax.device_get(engine.runner.params))
 
 
-def assert_matches_oracle(params, prompt, got, tol=2e-3):
-    ids = list(prompt) + got["tokens"]
-    lps = _log_softmax(oracle_logits(CFG, params, ids))
-    assert len(got["logprobs"]) == len(got["tokens"])
-    for j, at in enumerate(got["logprobs"]):
-        row = lps[len(prompt) - 1 + j]
-        for tid, lp in at.items():
-            assert abs(row[tid] - lp) < tol, (j, tid, row[tid], lp)
+def oracle_rows(params, prompt, tokens):
+    lps = _log_softmax(oracle_logits(CFG, params, prompt + tokens))
+    return lps[len(prompt) - 1:len(prompt) - 1 + len(tokens)]
+
+
+assert_matches_oracle = functools.partial(
+    contract.assert_matches_reference, oracle_rows)
 
 
 # ----------------------------------------------------------------------------
@@ -257,17 +230,15 @@ def test_staggered_sequences_match_their_lone_runs(params):
     prompts = [PROMPT[:n] for n in (37, 5, 53, 18, 26)]
     n_out = 7
     eng = make_engine(max_num_seqs=3, max_prefill_tokens=32)
-    together = run(eng, prompts, n_out, stagger=2)
-    slots = [min(r["slots"]) for r in together]
-    assert all(len(r["slots"]) == 1 for r in together)
+    seen = {}
+    together = run(eng, prompts, n_out, stagger=2, watch=slots_into(seen))
+    slots = [min(seen[f"r{i}"]) for i in range(len(prompts))]
+    assert all(len(s) == 1 for s in seen.values())
     assert len(set(slots)) < len(slots), "a slot must have been reused"
     lone_eng = make_engine(max_num_seqs=3, max_prefill_tokens=32)
     for p, got in zip(prompts, together):
         lone = run(lone_eng, [p], n_out)[0]
-        assert got["tokens"] == lone["tokens"]
-        for a, b in zip(got["logprobs"], lone["logprobs"]):
-            for tid in a:
-                assert abs(a[tid] - b[tid]) < 1e-3
+        assert_same(got, lone)
         assert_matches_oracle(params, p, got)
     assert eng.allocator.state_slots_in_use == 0
 
@@ -296,13 +267,12 @@ def test_arrivals_join_the_running_chain_on_state_slots(params):
         return outs
 
     eng.step = checked_step
-    got = run(eng, prompts, 9, stagger=3)
+    seen = {}
+    got = run(eng, prompts, 9, stagger=3, watch=slots_into(seen))
     assert 0 < max(held) <= 2 and held.count(0) > len(held) // 2
     for a, b in zip(got, sync):
-        assert a["tokens"] == b["tokens"]
-        for x, y in zip(a["logprobs"], b["logprobs"]):
-            assert all(abs(x[t] - y[t]) < 1e-3 for t in x)
-    slots = [min(r["slots"]) for r in got]
+        assert_same(a, b)
+    slots = [min(seen[f"r{i}"]) for i in range(len(prompts))]
     assert len(set(slots)) < len(slots), "a slot must have been reused"
     assert eng.chain_kept_prefills_total >= 7
     assert eng.pipeline_breaks["prefill"] == 0

@@ -61,8 +61,8 @@ def _engine(**over):
 
 
 def _overlap_engine(**over):
-    """The pipeline as it is configured by default: ``overlap_decode`` and
-    the three ``adaptive_decode_*`` fields are left to ``EngineConfig``."""
+    """The pipeline as it is configured by default: ``overlap_decode`` is
+    left to ``EngineConfig``."""
     return _engine(**over, overlap_decode=EngineConfig.overlap_decode)
 
 
@@ -165,14 +165,12 @@ def _arrival_stream():
 
 
 def test_chain_engages_at_default_config_while_requests_arrive():
-    """No ``adaptive_decode_quiet_s`` override, and the quiet gate held
-    shut as a stream of arrivals holds it on a busy server (whatever this
-    machine's clock makes of 0.5 s): most decode dispatches are chained
-    all the same, token for token what the synchronous loop gives."""
+    """Under a stream of arrivals, as a busy server has one, most decode
+    dispatches are chained, token for token what the synchronous loop
+    gives: a chain asks for no quiet."""
     ref, _ = _run_with_arrivals(_engine(), *_arrival_stream())
     eng = _overlap_engine(min_decode_bucket=8)
-    assert eng.cfg.overlap_decode and eng.cfg.adaptive_decode_quiet_s == 0.5
-    eng._arrival_safe = lambda: False
+    assert eng.cfg.overlap_decode
     got, _ = _run_with_arrivals(eng, *_arrival_stream())
     assert got == ref
     # arrivals landed mid-chain, and the chain went on behind each
@@ -247,19 +245,6 @@ def test_standing_queue_costs_no_more_steps_than_the_synchronous_loop(swap):
     # drain of the burst launched before its last member was seen to finish
     assert sum(eng.pipeline_breaks.values()) == 1
     assert steps["chain"][0] <= steps["sync"][0] + 2
-
-
-@pytest.mark.parametrize("quiet_s, deep", [(3600.0, False), (0.0, True)])
-def test_deep_bursts_still_wait_for_quiet(quiet_s, deep):
-    """``adaptive_decode_steps`` deepens only once no request has arrived
-    for ``adaptive_decode_quiet_s``; the chain at the configured depth
-    runs either way."""
-    eng = _overlap_engine(num_decode_steps=1, adaptive_decode_steps=4,
-                          adaptive_decode_quiet_s=quiet_s)
-    _, toks = _run_stream(eng, _reqs((17, 9), (21, 21)))
-    assert [len(t) for t in toks.values()] == [21, 21]
-    assert eng.pipelined_bursts_total > 0
-    assert (eng.adaptive_deep_bursts_total > 0) == deep
 
 
 def test_decode_counters_add_up():

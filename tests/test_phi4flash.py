@@ -13,6 +13,7 @@ the block table holding freed entries, the allocator's bound, the kernels
 against their ``jax.numpy`` forms.
 """
 
+import functools
 import json
 import re
 import types
@@ -24,12 +25,15 @@ import pytest
 
 from perf.reference import phi4flash as reference
 from production_stack_tpu.engine.config import EngineConfig, window_block_count
-from production_stack_tpu.engine.engine import LLMEngine
 from production_stack_tpu.engine.kv_manager import BlockAllocator
 from production_stack_tpu.engine.sequence import SamplingParams, Sequence
+from production_stack_tpu.models.base import ModelConfig
 from production_stack_tpu.models.phi4flash import Phi4Flash
 from production_stack_tpu.models.registry import PRESETS
 from production_stack_tpu.ops import selective_scan as scan
+
+from . import model_contract as contract
+from .model_contract import assert_same, run
 
 CFG = PRESETS["tiny-phi4flash-debug"]
 HF = {"num_hidden_layers": CFG.num_layers,
@@ -42,43 +46,8 @@ PROMPT = [3, 17, 98, 25, 42, 7, 11, 20, 15, 31, 8, 77, 12, 5, 9, 2, 33, 44, 99,
           57, 58, 59, 60, 61, 62, 63, 66, 67, 68, 69, 70, 71, 72, 73, 74, 75]
 
 
-def make_engine(**over) -> LLMEngine:
-    kw = dict(
-        model="tiny-phi4flash-debug", max_model_len=256, block_size=8,
-        num_kv_blocks=96, max_num_seqs=4, max_prefill_tokens=16,
-        enable_prefix_caching=False, kv_swap=False,
-    )
-    kw.update(over)
-    return LLMEngine(EngineConfig(**kw))
-
-
-def run(eng, prompts, n_tokens, stagger=0, logprobs=5, watch=None):
-    """Drive ``eng`` by hand: request i arrives after ``stagger * i`` steps.
-    ``watch(seq)`` is called for every live sequence before every step."""
-    sp = SamplingParams(max_tokens=n_tokens, temperature=0.0, ignore_eos=True,
-                        logprobs=logprobs)
-    res = {}
-    pending = list(enumerate(prompts))
-    steps = 0
-    while pending or eng.has_work():
-        while pending and steps >= stagger * pending[0][0]:
-            i, p = pending.pop(0)
-            res[f"r{i}"] = {"tokens": [], "logprobs": [],
-                            "seq": eng.add_request(
-                                f"r{i}", prompt_token_ids=list(p), sampling=sp)}
-        if watch is not None:
-            for r in res.values():
-                watch(r["seq"])
-        for out in eng.step():
-            r = res[out.request_id]
-            r["tokens"].extend(out.new_token_ids)
-            for lp in out.logprobs or []:
-                at = dict(lp["top"])
-                at[lp["token_id"]] = lp["logprob"]
-                r["logprobs"].append(at)
-        steps += 1
-        assert steps < 4000, "the engine makes no progress"
-    return [res[f"r{i}"] for i in range(len(prompts))]
+make_engine = functools.partial(
+    contract.make_engine, "tiny-phi4flash-debug", enable_prefix_caching=False)
 
 
 @pytest.fixture(scope="module")
@@ -101,13 +70,10 @@ def reference_logprobs(params, ids, n_prompt, n_gen, variant="none"):
     return lps
 
 
-def assert_matches_reference(params, prompt, got, tol=2e-3):
-    ids = list(prompt) + got["tokens"]
-    lps = reference_logprobs(params, ids, len(prompt), len(got["tokens"]))
-    assert len(got["logprobs"]) == len(got["tokens"])
-    for j, at in enumerate(got["logprobs"]):
-        for tid, lp in at.items():
-            assert abs(lps[j, tid] - lp) < tol, (j, tid, lps[j, tid], lp)
+assert_matches_reference = functools.partial(
+    contract.assert_matches_reference,
+    lambda params, prompt, tokens: reference_logprobs(
+        params, prompt + tokens, len(prompt), len(tokens)))
 
 
 # ----------------------------------------------------------------------------
@@ -185,10 +151,7 @@ def test_packed_rows_of_unequal_length_match_their_lone_runs(params):
     lone_eng = make_engine(max_num_seqs=3, max_prefill_tokens=32)
     for p, got in zip(prompts, together):
         lone = run(lone_eng, [p], n_out)[0]
-        assert got["tokens"] == lone["tokens"]
-        for a, b in zip(got["logprobs"], lone["logprobs"]):
-            for tid in a:
-                assert abs(a[tid] - b[tid]) < 1e-3
+        assert_same(got, lone)
         assert_matches_reference(params, p, got)
     assert eng.allocator.state_slots_in_use == 0
     assert eng.allocator.window_pages_in_use == 0
@@ -224,9 +187,7 @@ def test_arrivals_join_the_running_chain_through_all_three_caches(params):
     got = run(eng, prompts, 9, stagger=3)
     assert 0 < max(held) <= 2 and held.count(0) > len(held) // 2
     for a, b in zip(got, sync):
-        assert a["tokens"] == b["tokens"]
-        for x, y in zip(a["logprobs"], b["logprobs"]):
-            assert all(abs(x[t] - y[t]) < 1e-3 for t in x)
+        assert_same(a, b)
     assert eng.chain_kept_prefills_total >= 7
     assert eng.pipeline_breaks["prefill"] == 0
     assert sum(eng.pipeline_breaks.values()) == 1, eng.pipeline_breaks
@@ -520,12 +481,12 @@ def test_window_group_exhaustion_is_the_allocators_error():
     assert plain.window_pages_in_use == 0
 
 
-def test_the_window_group_is_sized_from_the_model_and_the_engines_limits():
+def test_the_window_group_is_sized_from_the_model_and_the_engines_limits(engine):
     cfg = EngineConfig(model="tiny-phi4flash-debug", block_size=8,
                        max_num_seqs=4, max_prefill_tokens=16)
     assert window_block_count(cfg, CFG) == 4 * (2 + 2) + 2 * 2
     assert window_block_count(cfg, PRESETS["tiny-llama-debug"]) == 0
-    eng = make_engine()
+    eng = engine
     assert eng.runner.window_blocks == 20
     assert eng.runner.kv_cache["wkv"].shape[:2] == (CFG.num_window_layers, 20)
     assert eng.runner.kv_cache["kv"].shape[:2] == (1, 96)
@@ -564,7 +525,7 @@ def test_released_window_pages_are_a_reason_of_their_own():
     refuses what it must, by the flag's name."""
     from production_stack_tpu.engine.config import refuse_unserved
 
-    only = types.SimpleNamespace(window_pages=True)
+    only = type("Only", (ModelConfig,), {"window_pages": True})()
     for over, flag in ((dict(), "--enable-prefix-caching"),
                        (dict(enable_prefix_caching=False), "--kv-swap")):
         with pytest.raises(ValueError, match=flag):
@@ -608,13 +569,21 @@ def one_chip():
     installed here and refuses what the chip's would (interpret mode shows
     neither a tiling fault nor a pool copy)."""
     from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
     from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no compiler here: nothing to test
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    # The session's compile cache (tests/conftest.py) stays out of these: a
+    # program compiled for a described chip is written there but cannot be
+    # read back without one (a warning and a second compile each time).
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
 
 
 @pytest.mark.parametrize("B,T", [(64, 1), (1, 1024), (4, 256)])

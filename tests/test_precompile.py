@@ -13,6 +13,7 @@ The acceptance spine of the subsystem, on the CPU test model:
 """
 
 import asyncio
+import os
 import threading
 import time
 
@@ -40,6 +41,10 @@ from production_stack_tpu.obs import ENGINE_TELEMETRY, ENGINE_TELEMETRY_REGISTRY
 # Tiny but complete: two decode row buckets, one table bucket, four
 # prefill chunk buckets, a 2-step burst — small enough that a full
 # precompile stays in CI budget, rich enough to exercise every kind.
+# The session's compile cache (tests/conftest.py). The tests below that place
+# a cache of their own take the variable out first: it wins over the flag.
+SESSION_CACHE_DIR = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+
 TINY = dict(
     model="tiny-llama-debug",
     max_model_len=64,
@@ -310,9 +315,10 @@ def test_full_warmup_covers_spec_verify():
 
 def _disable_persistent_cache(jax) -> None:
     """Undo configure_compile_cache for the rest of the pytest process:
-    clear the config AND jax's latched cache object (which would
-    otherwise keep serving the test's tmp directory)."""
-    jax.config.update("jax_compilation_cache_dir", None)
+    put back the session's directory (``tests/conftest.py``) AND clear
+    jax's latched cache object (which would otherwise keep serving the
+    test's tmp directory)."""
+    jax.config.update("jax_compilation_cache_dir", SESSION_CACHE_DIR)
     try:
         from jax._src import compilation_cache
 
@@ -321,13 +327,14 @@ def _disable_persistent_cache(jax) -> None:
         pass
 
 
-def test_warm_restart_reuses_persistent_cache(tmp_path):
+def test_warm_restart_reuses_persistent_cache(tmp_path, monkeypatch):
     import gc
 
     import jax
 
     from production_stack_tpu.engine.engine import LLMEngine
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     cfg_kw = dict(TINY, compile_cache_dir=str(tmp_path), warmup="full",
                   warmup_bucket_budget=8)
     try:
@@ -353,13 +360,14 @@ def test_warm_restart_reuses_persistent_cache(tmp_path):
         _disable_persistent_cache(jax)
 
 
-def test_cache_key_partitions_cache_dir(tmp_path):
+def test_cache_key_partitions_cache_dir(tmp_path, monkeypatch):
     """Different configs must never share executables: the keyed
     subdirectory isolates them."""
     from production_stack_tpu.engine.precompile import configure_compile_cache
 
     import jax
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     try:
         cfg_a = EngineConfig(**dict(TINY, compile_cache_dir=str(tmp_path)))
         cfg_b = EngineConfig(**dict(

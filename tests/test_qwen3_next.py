@@ -12,6 +12,7 @@ What the benchmark's ``correct`` cannot see is here: rows against each other
 next, the kernels against the recurrence, the shares against the whole.
 """
 
+import functools
 import json
 import types
 
@@ -23,12 +24,13 @@ import pytest
 from perf.reference import qwen3_next as reference
 from production_stack_tpu.engine import config as engine_config
 from production_stack_tpu.engine.config import EngineConfig
-from production_stack_tpu.engine.engine import LLMEngine
-from production_stack_tpu.engine.sequence import SamplingParams
 from production_stack_tpu.models import moe_dispatch
 from production_stack_tpu.models.qwen3_next import Qwen3Next
 from production_stack_tpu.models.registry import PRESETS
 from production_stack_tpu.ops import gated_delta as gdn
+
+from . import model_contract as contract
+from .model_contract import assert_same, run
 
 NAME = "tiny-qwen3-next-debug"
 CFG = PRESETS[NAME]
@@ -55,38 +57,8 @@ PROMPT = [3, 17, 98, 25, 42, 7, 11, 20, 15, 31, 8, 77, 12, 5, 9, 2, 33, 44, 99,
           57, 58, 59, 60, 61, 62, 63, 66, 67, 68, 69, 70, 71, 72, 73, 74, 75]
 
 
-def make_engine(**over) -> LLMEngine:
-    kw = dict(
-        model=NAME, max_model_len=256, block_size=8, num_kv_blocks=96,
-        max_num_seqs=4, max_prefill_tokens=16, enable_prefix_caching=False,
-        kv_swap=False,
-    )
-    kw.update(over)
-    return LLMEngine(EngineConfig(**kw))
-
-
-def run(eng, prompts, n_tokens, stagger=0, logprobs=5):
-    """Drive ``eng`` by hand: request i arrives after ``stagger * i`` steps."""
-    sp = SamplingParams(max_tokens=n_tokens, temperature=0.0, ignore_eos=True,
-                        logprobs=logprobs)
-    res = {}
-    pending = list(enumerate(prompts))
-    steps = 0
-    while pending or eng.has_work():
-        while pending and steps >= stagger * pending[0][0]:
-            i, p = pending.pop(0)
-            res[f"r{i}"] = {"tokens": [], "logprobs": []}
-            eng.add_request(f"r{i}", prompt_token_ids=list(p), sampling=sp)
-        for out in eng.step():
-            r = res[out.request_id]
-            r["tokens"].extend(out.new_token_ids)
-            for lp in out.logprobs or []:
-                at = dict(lp["top"])
-                at[lp["token_id"]] = lp["logprob"]
-                r["logprobs"].append(at)
-        steps += 1
-        assert steps < 4000, "the engine makes no progress"
-    return [res[f"r{i}"] for i in range(len(prompts))]
+make_engine = functools.partial(
+    contract.make_engine, NAME, enable_prefix_caching=False)
 
 
 @pytest.fixture(scope="module")
@@ -110,19 +82,10 @@ def reference_logprobs(params, ids, n_prompt, n_gen, variant="none"):
     return lps
 
 
-def assert_matches_reference(params, prompt, got, tol=2e-3):
-    ids = list(prompt) + got["tokens"]
-    lps = reference_logprobs(params, ids, len(prompt), len(got["tokens"]))
-    assert len(got["logprobs"]) == len(got["tokens"])
-    for j, at in enumerate(got["logprobs"]):
-        for tid, lp in at.items():
-            assert abs(lps[j, tid] - lp) < tol, (j, tid, lps[j, tid], lp)
-
-
-def assert_same(a, b, tol=1e-3):
-    assert a["tokens"] == b["tokens"]
-    for x, y in zip(a["logprobs"], b["logprobs"]):
-        assert all(abs(x[t] - y[t]) < tol for t in x)
+assert_matches_reference = functools.partial(
+    contract.assert_matches_reference,
+    lambda params, prompt, tokens: reference_logprobs(
+        params, prompt + tokens, len(prompt), len(tokens)))
 
 
 # ----------------------------------------------------------------------------
@@ -149,9 +112,9 @@ def test_chunked_prefill_then_decode_through_slots_and_pages(engine, params):
     assert 0 < stats["moe_pairs_held_total"] < stats["moe_pairs_routed_total"]
 
 
-def test_a_prompt_cut_into_three_chunks_equals_one_chunk(params):
+def test_a_prompt_cut_into_three_chunks_equals_one_chunk(engine, params):
     prompt = PROMPT[:48]
-    three = run(make_engine(max_prefill_tokens=16), [prompt], 4)[0]
+    three = run(engine, [prompt], 4)[0]  # the defaults: chunks of 16
     one = run(make_engine(max_prefill_tokens=64, overlap_decode=False),
               [prompt], 4)[0]
     assert_same(three, one)

@@ -4,6 +4,8 @@ Ring-1 strategy (SURVEY.md §4): stub-free unit tests over the admission and
 preemption state machine alone.
 """
 
+import dataclasses
+
 import pytest
 
 from production_stack_tpu.engine.kv_manager import BlockAllocator
@@ -97,11 +99,11 @@ def test_infeasible_prompt_rejected_at_add():
 
 
 def test_decode_depth_hint_overrides_and_clamps():
-    """Adaptive burst depth (engine hint): schedule(n_decode=) deepens the
-    burst; per-sequence clamps (max_model_len margin, guided rows) still
-    apply over the hint. Penalty rows ride at full depth — their state
-    lives in multi_step's scan carry now."""
-    sched, alloc = _sched(num_blocks=32, bs=4, num_decode_steps=2)
+    """The burst depth is the configured ``num_decode_steps``, under the
+    per-sequence clamps: a row near ``max_model_len`` shortens the burst to
+    what its context has left, a guided row forces 1. Penalty rows ride at
+    full depth — their state lives in multi_step's scan carry now."""
+    sched, alloc = _sched(num_blocks=32, bs=4, num_decode_steps=16)
     a = Sequence("a", [1, 2, 3, 4, 5], SamplingParams(max_tokens=64))
     sched.add(a)
     out = sched.schedule()  # prefill pass
@@ -110,80 +112,24 @@ def test_decode_depth_hint_overrides_and_clamps():
     a.output_token_ids.append(7)
 
     out = sched.schedule()
-    assert out.n_decode_steps == 2  # configured depth
-    out = sched.schedule(n_decode=16)
-    assert out.n_decode_steps == 16  # hint deepens
-    # The hint does not stick: the next pass reverts to the config depth.
-    out = sched.schedule()
-    assert out.n_decode_steps == 2
+    assert out.n_decode_steps == 16  # configured depth
 
     # Penalty rows keep the full depth (counts ride the scan carry).
     a.sampling = SamplingParams(max_tokens=64, repetition_penalty=1.2,
                                 presence_penalty=0.5)
-    out = sched.schedule(n_decode=16)
+    out = sched.schedule()
     assert out.n_decode_steps == 16
 
-    # Guided rows force n=1 regardless of hint.
+    # The max_model_len margin: a burst never writes past the context.
+    roomy = sched.config
+    sched.config = dataclasses.replace(roomy, max_model_len=a.num_tokens + 5)
+    out = sched.schedule()
+    assert out.n_decode_steps == 5
+    sched.config = roomy
+
+    # Guided rows force n=1 regardless of the configured depth.
     a.sampling = SamplingParams(max_tokens=64, guided_choice=(("x", (9,)),))
-    out = sched.schedule(n_decode=16)
+    out = sched.schedule()
     assert out.n_decode_steps == 1
 
 
-def test_engine_decode_depth_gate(monkeypatch):
-    """LLMEngine._decode_depth_hint: deepens only when adaptive is on, the
-    waiting queue is empty, and the arrival stream has been quiet."""
-    import time as _time
-
-    from production_stack_tpu.engine.config import EngineConfig
-    from production_stack_tpu.engine.engine import LLMEngine
-
-    eng = LLMEngine(EngineConfig(
-        model="tiny-llama-debug", max_model_len=128, block_size=8,
-        num_kv_blocks=64, max_num_seqs=4, max_prefill_tokens=32,
-        attn_impl="gather", num_decode_steps=2,
-        adaptive_decode_steps=8, adaptive_decode_quiet_s=0.2,
-    ))
-    assert eng._decode_depth_hint() == 8  # no arrivals ever: quiet
-    eng.add_request("r1", prompt_token_ids=[1, 2, 3])
-    assert eng._decode_depth_hint() is None  # waiting + recent arrival
-    while eng.has_work():
-        eng.step()
-    eng._last_arrival = _time.time()
-    assert eng._decode_depth_hint() is None  # within the quiet window
-    eng._last_arrival -= 1.0
-    assert eng._decode_depth_hint() == 8  # quiet again
-
-
-def test_adaptive_deep_bursts_execute_and_count():
-    """End-to-end deep path: with the gate open, decode runs at the deep
-    depth, the counter advances, and output length is exact (the burst's
-    speculative tail past max_tokens is trimmed host-side)."""
-    from production_stack_tpu.engine.config import EngineConfig
-    from production_stack_tpu.engine.engine import LLMEngine
-
-    eng = LLMEngine(EngineConfig(
-        model="tiny-llama-debug", max_model_len=256, block_size=8,
-        num_kv_blocks=128, max_num_seqs=4, max_prefill_tokens=32,
-        attn_impl="gather", num_decode_steps=2,
-        adaptive_decode_steps=8, adaptive_decode_quiet_s=0.0,
-        adaptive_decode_min_running=2,
-    ))
-    out = eng.generate(
-        [[1, 2, 3], [4, 5, 6]],
-        SamplingParams(max_tokens=21, temperature=0.0, ignore_eos=True),
-    )
-    assert all(len(o["token_ids"]) == 21 for o in out)
-    assert eng.adaptive_deep_bursts_total >= 2
-    assert eng.stats()["adaptive_deep_bursts_total"] >= 2
-
-    # Deep output must equal shallow output token-for-token (greedy).
-    eng2 = LLMEngine(EngineConfig(
-        model="tiny-llama-debug", max_model_len=256, block_size=8,
-        num_kv_blocks=128, max_num_seqs=4, max_prefill_tokens=32,
-        attn_impl="gather", num_decode_steps=1,
-    ))
-    out2 = eng2.generate(
-        [[1, 2, 3], [4, 5, 6]],
-        SamplingParams(max_tokens=21, temperature=0.0, ignore_eos=True),
-    )
-    assert [o["token_ids"] for o in out] == [o["token_ids"] for o in out2]
