@@ -214,16 +214,23 @@ def window_block_count(cfg: EngineConfig, model_cfg) -> int:
     given (0 for any other): every sequence's steady residency (the
     window's pages, one where it straddles, one for the token being
     written) and twice a step's prefill budget for the chunks in flight.
-    From the model's window and the engine's own limits: no flag."""
+    Where the group serves the prefix cache (a class that is not also
+    recurrent), the window's pages once more a row: the last window of one
+    conversation that waits for its next turn, which is all of it a later
+    match needs, while another holds the row. From the model's window and
+    the engine's own limits: no flag."""
     if not model_cfg.window_pages:
         return 0
     pages = lambda tokens: -(-tokens // cfg.block_size)  # noqa: E731
-    return (cfg.max_num_seqs * (pages(model_cfg.sliding_window) + 2)
+    window = pages(model_cfg.sliding_window)
+    waiting = (window if cfg.enable_prefix_caching and not model_cfg.recurrent
+               else 0)
+    return (cfg.max_num_seqs * (window + 2 + waiting)
             + 2 * pages(cfg.max_prefill_tokens))
 
 
 def _both(why: str) -> dict:
-    return {"recurrent": why, "latent_pages": why}
+    return {"recurrent": why, "latent_pages": why, "window_pages": why}
 
 
 # What a model class cannot be served with, refused at start-up by the
@@ -231,16 +238,14 @@ def _both(why: str) -> dict:
 # A row: is the flag on, its name, and for each property of the model's
 # config (``recurrent``: per-sequence state beside the pages;
 # ``latent_pages``: a page is one latent row a token, not a K and a V half;
-# ``window_pages``: a page group released below the window;
+# ``window_pages``: a second page group, released below the window;
 # ``wide_head_pages``: heads wider than the paged kernels have been proven at)
 # why the flag cannot be served, or no entry where it can.
 def _refusals(cfg: EngineConfig):
     return [
         (cfg.enable_prefix_caching, "--enable-prefix-caching", {
             "recurrent": "a cached page list carries no state snapshot; "
-                         "pass --no-enable-prefix-caching",
-            "window_pages": "a released window page cannot be matched; "
-                            "pass --no-enable-prefix-caching"}),
+                         "pass --no-enable-prefix-caching"}),
         (cfg.kv_swap, "--kv-swap", {
             "recurrent": "a parked sequence's state is not swapped; pass "
                          "--no-kv-swap (preemption is by recompute)",
@@ -252,24 +257,30 @@ def _refusals(cfg: EngineConfig):
         (cfg.cpu_offload_blocks > 0, "--cpu-offload-blocks", {
             "recurrent": "host-tier pages carry no state",
             "latent_pages": "the host tier frames a page as a K and a V "
-                            "half (engine/cache_tiering.py)"}),
+                            "half (engine/cache_tiering.py)",
+            "window_pages": "the host tier knows one page group"}),
         (bool(cfg.remote_kv_url), "--remote-kv-url", {
             "recurrent": "remote-tier pages carry no state",
             "latent_pages": "the kvserver's framing is a K and a V half a "
-                            "page"}),
+                            "page",
+            "window_pages": "the remote tier knows one page group"}),
         (cfg.kv_role != "none", "--kv-role", {
             "recurrent": "a KV hand-off ships pages, not the state",
             "latent_pages": "the hand-off ships K and V halves "
-                            "(engine/kv_handoff.py)"}),
+                            "(engine/kv_handoff.py)",
+            "window_pages": "the hand-off ships one page group"}),
         (cfg.speculative_ngram > 0, "--speculative-ngram", {
-            "recurrent": "a rejected draft would need the state rolled back"}),
+            "recurrent": "a rejected draft would need the state rolled back",
+            "window_pages": "a draft's rows have not been verified through "
+                            "the window group"}),
         (cfg.enable_lora, "--enable-lora",
          _both("no adapter bank exists for these layers")),
         (cfg.tensor_parallel_size > 1, "--tensor-parallel-size",
          _both("its pools and kernels run on one device")),
         (cfg.pipeline_parallel_size > 1, "--pipeline-parallel-size", {
             "recurrent": "the layer pattern is not staged",
-            "latent_pages": "the dense and expert layers are not staged"}),
+            "latent_pages": "the dense and expert layers are not staged",
+            "window_pages": "the two page groups are not staged"}),
         (cfg.expert_parallel_size > 1, "--expert-parallel-size",
          _both("an expert-parallel share is told by the model config's "
                "ep_share, not by a mesh")),
@@ -283,9 +294,8 @@ def _refusals(cfg: EngineConfig):
             "latent_pages": "one-byte latents are not built (the decode "
                             "kernel folds keys and values out of one "
                             "two-byte buffer)",
-            "window_pages": "one-byte pages of paired [k1 | k2] heads are "
-                            "not calibrated (the differential attention "
-                            "subtracts two softmax outputs)",
+            "window_pages": "one-byte pages of a window group are not "
+                            "calibrated",
             "wide_head_pages": "one-byte pages of 256-wide heads are not "
                                "proven (the paged kernels' one-byte path "
                                "has run at 128 lanes a head only)"}),

@@ -1273,6 +1273,12 @@ class LLMEngine:
                 self.allocator.window_pages_in_use)
             out["window_pages_released_total"] = float(
                 self.allocator.window_pages_released)
+            out["window_pages_cached"] = float(
+                self.allocator.window_pages_cached)
+            out["window_prefix_tokens_lost_total"] = float(
+                self.allocator.window_prefix_tokens_lost)
+            out["window_pages_evicted_total"] = float(
+                self.allocator.window_pages_evicted)
             out["window_page_steps_total"] = float(
                 self.runner.window_page_steps_total)
             out["window_whole_context_page_steps_total"] = float(

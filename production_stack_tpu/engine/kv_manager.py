@@ -49,17 +49,30 @@ class BlockAllocator:
         self._free_slots: List[int] = list(range(state_slots - 1, -1, -1))
         self.state_slot_waits = 0  # admissions that found no slot free
         # A second group of pages, for a model's sliding-window layers
-        # (``window_tokens`` the window): a sequence's pages there are
-        # released as it advances past them, so it never holds more than
+        # (``window_tokens`` the window): a sequence drops its reference to
+        # a page there as it advances past it, so it never holds more than
         # ``window_bound`` whatever its length. 0 pages: no such group. The
-        # group is sized for ``max_num_seqs`` such residencies
+        # group is an allocator of its own: with prefix caching on its full
+        # pages are committed under the same block hashes as the global
+        # group's, an unreferenced one waits in its LRU, and ``match_prefix``
+        # ends a match where either group ends it; with it off no hash is
+        # kept and a dropped page is free at once. The group is sized for
+        # ``max_num_seqs`` such residencies
         # (``engine/config.py::window_block_count``), so admission, which
         # stops at that many sequences, has counted it; running out is
         # ``NoFreeBlocksError`` all the same (preemption by recompute).
         self.window_blocks = window_blocks
         self.window_tokens = window_tokens
-        self._free_window: List[int] = list(range(window_blocks - 1, -1, -1))
+        self.window: Optional[BlockAllocator] = None
+        if window_blocks:
+            self.window = BlockAllocator(
+                window_blocks, block_size, enable_prefix_caching,
+                on_evict=self._count_window_eviction)
         self.window_pages_released = 0
+        self.window_pages_evicted = 0
+        # Tokens a match was cut back by because the window group had lost
+        # the pages under the last window before the match point.
+        self.window_prefix_tokens_lost = 0
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.enable_prefix_caching = enable_prefix_caching
@@ -149,13 +162,18 @@ class BlockAllocator:
         self._block_of_hash[h] = blk
         return blk
 
-    def release(self, blk: int) -> None:
+    def release(self, blk: int, cold: bool = False) -> None:
+        """Drop a reference. An unreferenced page with a hash waits in the
+        LRU as its youngest, or with ``cold`` as its oldest: the next to be
+        evicted."""
         self._refcount[blk] -= 1
         assert self._refcount[blk] >= 0, f"double free of block {blk}"
         if self._refcount[blk] == 0:
             h = self._hash_of_block.get(blk)
             if h is not None:
                 self._reusable[blk] = h  # keep content for future hits
+                if cold:
+                    self._reusable.move_to_end(blk, last=False)
             else:
                 self._free.append(blk)
 
@@ -182,9 +200,18 @@ class BlockAllocator:
 
     # -- the window group -------------------------------------------------
 
+    def _count_window_eviction(self, blk: int, h: int) -> None:
+        self.window_pages_evicted += 1
+
     @property
     def window_pages_in_use(self) -> int:
-        return self.window_blocks - len(self._free_window)
+        """Pages of the window group some sequence references."""
+        return self.window.num_blocks - self.window.num_free if self.window else 0
+
+    @property
+    def window_pages_cached(self) -> int:
+        """Unreferenced pages of the window group kept for their hashes."""
+        return len(self.window._reusable) if self.window else 0
 
     @property
     def window_steady(self) -> int:
@@ -198,40 +225,98 @@ class BlockAllocator:
         chunk of ``chunk_tokens`` is written."""
         return self.window_steady + -(-chunk_tokens // self.block_size)
 
+    def _window_first(self, computed_tokens: int) -> int:
+        """The first page of the window group a query at
+        ``computed_tokens`` or later can see (a query at position ``t`` sees
+        ``t - window + 1 .. t``)."""
+        return max(computed_tokens - self.window_tokens, 0) // self.block_size
+
     def trim_window(self, seq) -> None:
-        """Release ``seq``'s window-group pages that lie wholly below its
-        window: no later query sees them (a query at position ``t`` sees
-        ``t - window + 1 .. t``, and the next is at ``num_computed_tokens``
-        or later), and the kernels neither fetch nor fold them. The table
-        entry reads 0 from then on."""
+        """Drop ``seq``'s references to the window-group pages that lie
+        wholly below its window: no later query of its own sees them (the
+        next is at ``num_computed_tokens`` or later), and the kernels
+        neither fetch nor fold them. The table entry reads 0 from then on.
+        A page that was committed under its hash stays matchable in the
+        group's LRU until its room is needed. The pages under the last window
+        before the point where ``seq`` left the cached chain it was matched
+        on (``window_parted`` pages in: sequences part there, and the next
+        may) keep an LRU page's place; any other page it passes is worth a
+        match only to a sequence that parts within a window of it, and goes
+        first, or one long prompt's pages would push every waiting
+        conversation's last window out of the group."""
         ids = seq.window_block_ids
-        below = max(seq.num_computed_tokens - self.window_tokens, 0) // self.block_size
+        below = self._window_first(seq.num_computed_tokens)
+        kept = self._window_first(seq.window_parted * self.block_size)
         for j in range(seq.window_released, min(below, len(ids))):
-            self._free_window.append(ids[j])
+            self.window.release(
+                ids[j], cold=not (kept <= j < seq.window_parted))
             ids[j] = 0
             seq.window_released = j + 1
             self.window_pages_released += 1
 
     def advance_window(self, seq, up_to_tokens: int) -> None:
         """Window-group pages for ``seq`` up to ``up_to_tokens``, after
-        releasing what fell below its window. All or nothing."""
+        dropping what fell below its window: from the free list first, then
+        the LRU's oldest. All or nothing."""
         if not self.window_blocks:
             return
         self.trim_window(seq)
         need = -(-up_to_tokens // self.block_size) - len(seq.window_block_ids)
-        if need > len(self._free_window):
+        if need > self.window.num_free:
             raise NoFreeBlocksError("out of window-group KV blocks")
         for _ in range(need):
-            seq.window_block_ids.append(self._free_window.pop())
+            seq.window_block_ids.append(self.window.allocate())
+
+    def commit_window(self, seq, i: int, h: int, allow_swap: bool = True) -> None:
+        """Content-address ``seq``'s window-group page ``i`` by the hash of
+        its global page (the same tokens under the same prefix): a no-op
+        where it was trimmed already, or with prefix caching off."""
+        if (self.window is None or i < seq.window_released
+                or i >= len(seq.window_block_ids)):
+            return
+        seq.window_block_ids[i] = self.window.commit(
+            seq.window_block_ids[i], h, allow_swap=allow_swap)
+
+    def match_window(
+        self, seq, blocks: List[int], hashes: List[int]
+    ) -> Tuple[List[int], List[int]]:
+        """Cut the global group's match ``(blocks, hashes)`` back to the
+        longest point ``m`` the window group covers too: it has to hold the
+        pages under the last window before ``m`` (what ``trim_window``
+        leaves a sequence that has computed ``m`` pages), since the window
+        layers' keys cannot be recomputed for a tail alone. Takes the
+        references in the window group for ``seq``, gives back the global
+        pages past ``m``, and counts the tokens so lost."""
+        if self.window is None or not blocks:
+            return blocks, hashes
+        seq.window_parted = len(blocks)
+        held = self.window._block_of_hash
+
+        def covered(n: int) -> bool:  # the last window before page n
+            first = self._window_first(n * self.block_size)
+            return all(h in held for h in hashes[first:n])
+
+        m = next((n for n in range(len(blocks), 0, -1) if covered(n)), 0)
+        lost = len(blocks) - m
+        if lost:
+            self.release_all(blocks[m:])
+            self.hit_tokens -= lost * self.block_size
+            self.window_prefix_tokens_lost += lost * self.block_size
+        first = self._window_first(m * self.block_size)
+        seq.window_block_ids = [0] * first + [
+            self.window.acquire_cached(h) for h in hashes[first:m]]
+        seq.window_released = first
+        return blocks[:m], hashes[:m]
 
     def release_sequence(self, seq) -> None:
-        """Give back what ``seq`` holds: its pages of both groups and its
-        state slot."""
+        """Give back what ``seq`` holds: its references in both groups and
+        its state slot."""
         self.release_all(seq.block_ids)
         seq.block_ids = []
-        self._free_window.extend(seq.window_block_ids[seq.window_released:])
+        if self.window is not None:
+            self.window.release_all(seq.window_block_ids[seq.window_released:])
         seq.window_block_ids = []
-        seq.window_released = 0
+        seq.window_released = seq.window_parted = 0
         if seq.state_slot is not None:
             self._free_slots.append(seq.state_slot)
             seq.state_slot = None

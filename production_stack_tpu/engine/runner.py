@@ -314,13 +314,13 @@ class ModelRunner:
             """What a model class is told beside the batch: the state slot
             of each row, for a model that keeps any (a row the caller knows
             to be padding goes to the scratch slot), and the token budget."""
+            more = {k: batch[k] for k in ("window_tables", "sample_rows")
+                    if k in batch}
             if not recurrent:
-                return budget
+                return {**more, **budget}
             slots = batch["state_slots"]
             if active is not None:
                 slots = jnp.where(active, slots, scratch_slot)
-            more = {k: batch[k] for k in ("window_tables", "sample_rows")
-                    if k in batch}
             return {"state_slots": slots, **more, **budget}
 
         def with_aux(packed, kv_cache):
@@ -2027,12 +2027,12 @@ class ModelRunner:
         row's slot, padding rows the scratch slot. ``window_tables`` [B, W]
         for a model with a window page group: each row's pages there by the
         block table's logical index (a released entry reads 0)."""
-        if not self._recurrent:
-            return {}
-        slots = np.full(B, self.state_slots, np.int32)
-        for i, s in enumerate(seqs):
-            slots[i] = s.state_slot
-        out = {"state_slots": slots}
+        out = {}
+        if self._recurrent:
+            slots = np.full(B, self.state_slots, np.int32)
+            for i, s in enumerate(seqs):
+                slots[i] = s.state_slot
+            out["state_slots"] = slots
         if self.window_blocks:
             out["window_tables"] = self._window_tables(seqs, B, W)
         return out

@@ -534,6 +534,9 @@ class Scheduler:
                     matchable, salt=getattr(seq, "cache_salt", 0),
                     deadline=seq.deadline,
                 )
+                # a model with a window page group: the match ends where
+                # either group ends it
+                blocks, hashes = self.allocator.match_window(seq, blocks, hashes)
                 if blocks:
                     seq.adopt_cached_prefix(blocks, hashes)
                     seq.num_computed_tokens = len(blocks) * self.allocator.block_size

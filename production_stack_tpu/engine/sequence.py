@@ -155,9 +155,11 @@ class Sequence:
         self.state_slot: Optional[int] = None
         # Pages of the window group (a model with sliding-window layers),
         # by the same logical index as ``block_ids``; the first
-        # ``window_released`` entries were given back and read 0.
+        # ``window_released`` entries were given back and read 0;
+        # ``window_parted``: the pages of the cached chain it was matched on.
         self.window_block_ids: List[int] = []
         self.window_released = 0
+        self.window_parted = 0
         self.num_computed_tokens = 0  # tokens whose KV is resident
         self.num_cached_prompt_tokens = 0  # prefix-cache hits at admission
         self.block_hashes: List[int] = []  # hash per committed block
@@ -277,6 +279,7 @@ class Sequence:
             self.block_ids[i] = allocator.commit(
                 self.block_ids[i], h, allow_swap=allow_swap
             )
+            allocator.commit_window(self, i, h, allow_swap=allow_swap)
             self.block_hashes.append(h)
             self._last_hash = h
             self._committed_blocks += 1

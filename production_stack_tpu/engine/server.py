@@ -24,6 +24,8 @@ import argparse
 import asyncio
 import json
 import os
+import signal
+import threading
 import time
 from typing import List, Optional
 
@@ -229,6 +231,24 @@ class EngineMetrics:
             "window-group pages released because their sequence moved past "
             "them (below its sliding window)",
         )
+        # The window group's prefix cache (a model whose window layers'
+        # pages are matched again by their hashes).
+        self.window_pages_cached = gauge(
+            "pst:window_pages_cached",
+            "unreferenced window-group pages kept under their hashes for a "
+            "later prefix match",
+        )
+        self.window_prefix_tokens_lost = counter(
+            "pst:window_prefix_tokens_lost",
+            "prompt tokens a prefix match was cut back by because the window "
+            "group no longer held the pages under the last window before the "
+            "match point",
+        )
+        self.window_pages_evicted = counter(
+            "pst:window_pages_evicted",
+            "hashed window-group pages evicted from the group's LRU to make "
+            "room",
+        )
         self.prefill_tokens = counter(
             "pst:prefill_tokens",
             "tokens prefill steps computed (every layer that runs on every "
@@ -422,9 +442,13 @@ class EngineMetrics:
         self.kv_pages_in_use("global").set(stats.get("kv_pages_in_use", 0))
         if "window_pages_in_use" in stats:
             self.kv_pages_in_use("window").set(stats["window_pages_in_use"])
+            self.window_pages_cached.set(stats["window_pages_cached"])
         for metric, key in (
             (self.state_slot_waits, "state_slot_waits_total"),
             (self.window_pages_released, "window_pages_released_total"),
+            (self.window_prefix_tokens_lost,
+             "window_prefix_tokens_lost_total"),
+            (self.window_pages_evicted, "window_pages_evicted_total"),
             (self.window_page_steps, "window_page_steps_total"),
             (self.window_whole_context_page_steps,
              "window_whole_context_page_steps_total"),
@@ -635,6 +659,7 @@ def create_engine_app(
     debug_requests_buffer: int = 256,
     profiling: bool = False,
     profile_dir: str = "/tmp/pst_profiles",
+    profile_max_ms: float = 60_000.0,
 ) -> web.Application:
     # Everything except unauthenticated probe/scrape endpoints is guarded
     # when --api-key is set (/sleep in particular is destructive). Enforced
@@ -1615,7 +1640,7 @@ def create_engine_app(
             )
         except (TypeError, ValueError):
             return _error("duration_ms must be a number")
-        duration_ms = min(max(duration_ms, 10.0), 60_000.0)
+        duration_ms = min(max(duration_ms, 10.0), profile_max_ms)
         out_dir = str(body.get("dir") or profile_dir)
 
         import jax
@@ -1638,8 +1663,8 @@ def create_engine_app(
             # profiler seconds, so both run in the executor and the loop
             # keeps serving streams meanwhile.
             options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0
-            options.host_tracer_level = 2
+            for key, val in profile_options_attrs().items():
+                setattr(options, key, val)
             loop = asyncio.get_running_loop()
 
             def start():
@@ -1867,6 +1892,10 @@ def parse_engine_args(argv=None) -> argparse.Namespace:
         action="store_false",
     )
     p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--exit-with-parent", action="store_true",
+                   help="shut down when the process that started this server "
+                        "is gone (a supervisor or harness that was killed "
+                        "must not leave a server holding the chip)")
     p.add_argument("--api-key", default=None)
     p.add_argument("--sentry-dsn", default=None)
     # LoRA serving (vLLM --enable-lora analogue).
@@ -1966,7 +1995,15 @@ def parse_engine_args(argv=None) -> argparse.Namespace:
                         "trace capture; no-op on CPU backends)")
     p.add_argument("--profile-dir", default="/tmp/pst_profiles",
                    help="directory POST /debug/profile writes traces to")
-    # Startup-phase decomposition (pst_engine_startup_seconds{phase}).
+    p.add_argument("--profile-max-ms", type=float, default=60_000.0,
+                   help="the longest capture POST /debug/profile takes, "
+                        "whatever duration_ms asks for (the answer gives "
+                        "the length taken). The answer comes once the "
+                        "capture is converted and written, which takes a "
+                        "process's first capture about 130 us a device "
+                        "operation's event: 130 s for 3 s of a decode step "
+                        "of 4,700 operations at 60 steps a second "
+                        "(docs/observability.md \"Profiling\")")
     p.add_argument("--startup-phases", dest="startup_phases",
                    action="store_true", default=True)
     p.add_argument("--no-startup-phases", dest="startup_phases",
@@ -2097,8 +2134,37 @@ async def controller_report_loop(
         await asyncio.sleep(interval)
 
 
+def profile_options_attrs() -> dict:
+    """What ``POST /debug/profile`` sets on ``jax.profiler.ProfileOptions``
+    (``scripts/tpu_profile_stop_probe.py`` times a capture under them)."""
+    return {"python_tracer_level": 0, "host_tracer_level": 2}
+
+
+def exit_with_parent(poll_s: float = 1.0, grace_s: float = 20.0) -> None:
+    """``--exit-with-parent``: watch the process that started this one and,
+    once it is gone (this process was handed to another parent), take the
+    ordinary way out: SIGTERM to itself, which before the app runs ends the
+    process and afterwards is aiohttp's graceful shutdown; ``grace_s`` later
+    whatever is left is ended."""
+    parent = os.getppid()
+
+    def watch() -> None:
+        while os.getppid() == parent:
+            # pstlint: disable=async-blocking(the poll of the watcher's own daemon thread, started before the app exists; never on the event loop)
+            time.sleep(poll_s)
+        logger.warning("parent process %d is gone: shutting down", parent)
+        os.kill(os.getpid(), signal.SIGTERM)
+        # pstlint: disable=async-blocking(the same daemon thread, waiting out the graceful shutdown it asked for)
+        time.sleep(grace_s)
+        os._exit(1)
+
+    threading.Thread(target=watch, name="parent-watch", daemon=True).start()
+
+
 def main(argv=None) -> None:
     args = parse_engine_args(argv)
+    if args.exit_with_parent:
+        exit_with_parent()
     configure_logging(
         getattr(args, "log_format", "text") or "text",
         component="engine",
@@ -2147,6 +2213,7 @@ def main(argv=None) -> None:
         debug_requests_buffer=args.debug_requests_buffer,
         profiling=args.profiling,
         profile_dir=args.profile_dir,
+        profile_max_ms=args.profile_max_ms,
     )
 
     async def on_startup(app):

@@ -20,6 +20,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 Params = Dict[str, Any]
@@ -152,6 +153,31 @@ def _embed_lookup(params: Params, tokens: jax.Array, cfg: "ModelConfig") -> jax.
     if s is not None:
         x = (x.astype(jnp.float32) * s[tokens][..., None]).astype(cfg.jdtype)
     return x
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max_position: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN's inverse frequencies ``[head_dim / 2]`` (float64, on the host):
+    each a blend of the default ``f = theta^(-2i / head_dim)`` and the
+    interpolated ``f / factor`` by a linear ramp between the (whole)
+    dimensions that turn ``beta_fast`` and ``beta_slow`` times in
+    ``original_max_position`` positions: fast lanes keep ``f``, slow lanes
+    take ``f / factor``. The caller scales ``cos`` and ``sin`` by the
+    config's ``attention_factor``."""
+    half = head_dim // 2
+    f = theta ** (-np.arange(half, dtype=np.float64) / half)
+
+    def turning(rotations: float) -> float:  # the dimension that turns so often
+        return (head_dim * math.log(original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turning(beta_fast)), 0)
+    high = min(math.ceil(turning(beta_slow)), head_dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return f / factor * ramp + f * (1.0 - ramp)
 
 
 def _apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

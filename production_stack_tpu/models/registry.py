@@ -12,10 +12,11 @@ import json
 import os
 from typing import Callable, Dict, Tuple
 
-from . import glm4_moe_lite, llama, nemotron_h, phi4flash, qwen3_next
+from . import glm4_moe_lite, llama, mellum, nemotron_h, phi4flash, qwen3_next
 from .base import Model, ModelConfig
 from .glm4_moe_lite import Glm4MoeLiteConfig
 from .llama import LlamaConfig
+from .mellum import MellumConfig
 from .nemotron_h import NemotronHConfig
 from .phi4flash import Phi4FlashConfig
 from .qwen3_next import Qwen3NextConfig
@@ -35,6 +36,7 @@ MODEL_TYPES: Dict[str, Tuple[Callable[[dict, str], ModelConfig], type, type]] = 
     "phi4flash": (phi4flash.config_from_hf, Phi4FlashConfig, phi4flash.Phi4Flash),
     "qwen3_next": (
         qwen3_next.config_from_hf, Qwen3NextConfig, qwen3_next.Qwen3Next),
+    "mellum": (mellum.config_from_hf, MellumConfig, mellum.Mellum),
 }
 _MODEL_OF = {config: model for _, config, model in MODEL_TYPES.values()}
 
@@ -375,6 +377,34 @@ PRESETS: Dict[str, ModelConfig] = {
         shared_expert_intermediate_size=32,
         max_position_embeddings=2048,
         name="tiny-qwen3-next-debug",
+        eos_token_ids=(0,),
+        bos_token_id=None,
+        dtype="float32",
+    ),
+    # Tiny window / full attention mix: two periods of three window layers
+    # (16 tokens) and one full layer under YaRN (factor 4 over 64 original
+    # positions), every layer 8 softmax-routed experts top 2, all held.
+    "tiny-mellum-debug": MellumConfig(
+        vocab_size=128,
+        hidden_size=64,
+        num_layers=8,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",)
+        + ("sliding_attention",) * 3 + ("full_attention",),
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        sliding_window=16,
+        rope_theta=10000.0,
+        full_rope_theta=10000.0,
+        yarn_factor=4.0,
+        yarn_original_max_position=64,
+        yarn_attention_factor=1.1386294361119891,
+        n_routed_experts=8,
+        router_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        max_position_embeddings=2048,
+        name="tiny-mellum-debug",
         eos_token_ids=(0,),
         bos_token_id=None,
         dtype="float32",

@@ -277,3 +277,88 @@ async def test_infeasible_prompt_400_not_hang():
         }
         async with sess.post(f"{server.url}/v1/completions", json=ok) as r:
             assert r.status == 200
+
+
+def test_exit_with_parent_ends_a_server_whose_starter_is_gone(tmp_path):
+    """``--exit-with-parent``: a process starts the watcher's process in a
+    session of its own (as a harness starts a server) and dies; the orphan
+    takes SIGTERM from itself within a poll or two. Without the watcher it
+    would sleep on."""
+    import os
+    import subprocess
+    import sys
+    import time
+
+    orphan = (
+        "import os, sys, time\n"
+        "from production_stack_tpu.engine.server import exit_with_parent\n"
+        "if sys.argv[2] == 'watch':\n"
+        "    exit_with_parent(poll_s=0.05)\n"
+        "open(sys.argv[1], 'w').write(str(os.getpid()))\n"
+        "time.sleep(120)\n")
+    starter = (
+        "import subprocess, sys, time\n"
+        "subprocess.Popen([sys.executable, '-c', sys.argv[1], sys.argv[2], "
+        "sys.argv[3]], start_new_session=True)\n"
+        "while not __import__('os').path.exists(sys.argv[2]):\n"
+        "    time.sleep(0.05)\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=root, JAX_PLATFORMS="cpu")
+
+    def alive(pid):
+        try:
+            os.kill(pid, 0)
+        except ProcessLookupError:
+            return False
+        with open(f"/proc/{pid}/stat") as f:  # a zombie is not alive
+            return f.read().rsplit(")", 1)[1].split()[0] != "Z"
+
+    pids = {}
+    for mode in ("watch", "plain"):
+        pid_file = tmp_path / f"{mode}.pid"
+        subprocess.run([sys.executable, "-c", starter, orphan, str(pid_file),
+                        mode], env=env, check=True, timeout=120)
+        pids[mode] = int(pid_file.read_text())
+    try:
+        deadline = time.monotonic() + 10
+        while alive(pids["watch"]) and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert not alive(pids["watch"])
+        assert alive(pids["plain"])
+    finally:
+        for pid in pids.values():
+            if alive(pid):
+                os.kill(pid, 9)
+
+
+async def test_profile_capture_is_no_longer_than_profile_max_ms(monkeypatch):
+    """``--profile-max-ms``: ``POST /debug/profile`` takes the shorter of
+    what it is asked for and the server's limit, and its answer says which
+    (the backend's name is patched: on the CPU the endpoint skips)."""
+    import jax
+
+    from production_stack_tpu.engine import server
+
+    engine = AsyncLLMEngine(EngineServer().cfg)
+    app = create_engine_app(engine, profiling=True, profile_max_ms=40.0)
+    took = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    runner = web.AppRunner(app)
+    await runner.setup()
+    site = web.TCPSite(runner, "127.0.0.1", 0)
+    await site.start()
+    port = site._server.sockets[0].getsockname()[1]
+    try:
+        async with aiohttp.ClientSession() as sess:
+            for asked in (3000, 20):
+                async with sess.post(
+                        f"http://127.0.0.1:{port}/debug/profile",
+                        json={"duration_ms": asked}) as r:
+                    took.append((await r.json())["duration_ms"])
+    finally:
+        await runner.cleanup()
+    assert took == [40.0, 20.0]
+    assert server.parse_engine_args(
+        ["--model", "tiny-llama-debug"]).profile_max_ms == 60_000.0

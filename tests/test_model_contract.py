@@ -35,6 +35,7 @@ CONFIG_JSON = {
     "glm4_moe_lite": "perf/configs/glm-4.7-flash-pp6-cut.json",
     "phi4flash": "perf/configs/phi-4-mini-flash.json",
     "qwen3_next": "perf/configs/qwen3-next-ep8-cut.json",
+    "mellum": "perf/configs/mellum2-ep4-cut.json",
 }
 CONFIG_CLASSES = sorted({row[1] for row in MODEL_TYPES.values()},
                         key=lambda c: c.__name__)
@@ -91,6 +92,9 @@ WEIGHT_DIGESTS = {
         "44b3f7877a6155745ab2b15a093137796f2b54e4e138b54d76735616f617f98f",
     "tiny-qwen3-next-debug":
         "1d19d138c633bf0c869c15fd9abfb82de7c020203b9f7cbc895eb3caf78d2bf4",
+    # recorded when the class was added (PR 46)
+    "tiny-mellum-debug":
+        "68272915cccf68df88fd38bc5ccce1e14c732244c56bf70144f54fd7e610492b",
 }
 
 

@@ -460,7 +460,8 @@ def test_a_sequence_of_forty_windows_stays_inside_the_bound(chunk):
     assert alloc.window_pages_released >= 40 * 4 - 5
     alloc.release_sequence(seq)
     assert alloc.window_pages_in_use == 0 and alloc.state_slots_in_use == 0
-    assert sorted(alloc._free_window) == list(range(32))
+    assert sorted(alloc.window._free) == list(range(32))
+    assert alloc.window_pages_cached == 0  # no hash is kept with the cache off
 
 
 def test_window_group_exhaustion_is_the_allocators_error():
@@ -521,17 +522,18 @@ def test_refused_at_start_up_by_the_flags_name(over, flag):
 
 
 def test_released_window_pages_are_a_reason_of_their_own():
-    """Without the state-space layers' reasons the new property still
-    refuses what it must, by the flag's name."""
+    """Without the state-space layers' reasons the property still refuses
+    what it must, by the flag's name; its prefix cache is served (the window
+    group matches by hash: ``tests/test_mellum.py``)."""
     from production_stack_tpu.engine.config import refuse_unserved
 
     only = type("Only", (ModelConfig,), {"window_pages": True})()
-    for over, flag in ((dict(), "--enable-prefix-caching"),
-                       (dict(enable_prefix_caching=False), "--kv-swap")):
+    for over, flag in ((dict(), "--kv-swap"),
+                       (dict(kv_swap=False, cpu_offload_blocks=8),
+                        "--cpu-offload-blocks")):
         with pytest.raises(ValueError, match=flag):
             refuse_unserved(EngineConfig(**over), only)
-    refuse_unserved(
-        EngineConfig(enable_prefix_caching=False, kv_swap=False), only)
+    refuse_unserved(EngineConfig(kv_swap=False), only)
 
 
 def test_config_door_knows_the_model_type(tmp_path):
@@ -718,3 +720,51 @@ def test_the_gated_delta_decode_program_holds_the_tails_pool_in_the_kernel_alone
     assert not held
     assert "conv_tail_decode" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_the_window_mix_decode_program_copies_no_weight_stack(one_chip, monkeypatch):
+    """The 32-row decode step of the window / full attention cell's
+    configuration, whole, compiled for the described chip (PR 46). Left to
+    XLA, the reshape of the query and key projections to heads turned the
+    whole ``wq`` and ``wk`` stacks instead of the rows: a copy of 528 + 66 MB
+    in every step (1.4 ms of a 18 ms step on the chip, and as much
+    temporary memory); ``Mellum._attention`` holds the projections behind an
+    ``optimization_barrier``. Neither page group is copied either, and both
+    paged kernels and the grouped products are in the text."""
+    from production_stack_tpu.models import mellum, moe_dispatch
+    from production_stack_tpu.ops import paged_attention_pallas as pap
+
+    for mod in (pap, moe_dispatch):
+        monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
+    with open("perf/configs/mellum2-ep4-cut.json") as f:
+        cfg = mellum.config_from_hf(json.load(f), "mellum2-ep4-cut")
+    model = mellum.Mellum(cfg)
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: model.make_kv_cache(2560, 128, None, window_blocks=592)))
+    B = 32
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+
+    def step(params, tokens, positions, write_idx, tables, kv_lens, last_idx,
+             cache, window_tables):
+        return model.forward(
+            params, tokens, positions, write_idx, tables, kv_lens, last_idx,
+            cache, window_tables=window_tables, attn_impl="pallas")
+
+    with jax.disable_jit(False):
+        compiled = jax.jit(step, donate_argnums=(7,)).lower(
+            params, i32(B, 1), i32(B, 1), i32(B, 1), i32(B, 256), i32(B),
+            i32(B), cache, i32(B, 256)).compile()
+    text = compiled.as_text()
+    big = ("bf16[28,", "bf16[448,", "bf16[7,2560,", "bf16[21,592,")
+    copies = [
+        name for name, result, opcode in re.findall(
+            r"^\s*(?:ROOT )?(%\S+) = (\(.*?\)|\S+) ([\w\-]+)\(", text, re.M)
+        if opcode in ("copy", "transpose") and result.startswith(big)]
+    assert not copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
+    for kernel in ("paged_attn_decode", "gmm"):
+        assert kernel in text
