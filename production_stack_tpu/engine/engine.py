@@ -103,6 +103,10 @@ class LLMEngine:
         self.runner = ModelRunner(cfg, self.model_cfg, mesh)
         t_runner_s = time.perf_counter() - t_runner
         self.runner.device_info["compile_cache_dir"] = compile_cache_path
+        # Step programs are kept beside the executables (a `programs/`
+        # directory there): a shape this tree built before is loaded at
+        # its first use, not traced again.
+        self.runner.place_program_store(compile_cache_path)
         if cfg.cpu_offload_blocks > 0 or cfg.remote_kv_url:
             from .cache_tiering import TieredAllocator, create_remote_client
 

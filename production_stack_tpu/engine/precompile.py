@@ -310,7 +310,9 @@ CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 
 def _install_cache_listener() -> None:
     """Feed jax's compilation-cache monitoring events into the telemetry
-    hit/miss counters. Process-global and idempotent."""
+    hit/miss counters, and its duration events (tracing, lowering,
+    compiling, the cache's read) into the split of a step shape's first
+    use. Process-global and idempotent."""
     global _cache_listener_installed
     if _cache_listener_installed:
         return
@@ -322,7 +324,11 @@ def _install_cache_listener() -> None:
         elif name.endswith("/compilation_cache/cache_misses"):
             ENGINE_TELEMETRY.record_cache_event(False)
 
+    def _on_duration(name: str, seconds: float, **kwargs) -> None:
+        ENGINE_TELEMETRY.first_use_event(name, seconds)
+
     monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
     _cache_listener_installed = True
 
 
@@ -347,6 +353,7 @@ def configure_compile_cache(cfg: EngineConfig, model_cfg) -> Optional[str]:
     import jax
     from jax._src import compilation_cache
 
+    _install_cache_listener()
     path = os.environ.get(CACHE_DIR_ENV)
     if not path:
         if cfg.compile_cache_dir:
@@ -369,7 +376,6 @@ def configure_compile_cache(cfg: EngineConfig, model_cfg) -> Optional[str]:
     # previous engine in this process, or an import-time jit). Reset to
     # pristine so the next compile initializes against the directory.
     compilation_cache.reset_cache()
-    _install_cache_listener()
     logger.info("persistent compilation cache: %s", path)
     return path
 

@@ -51,6 +51,7 @@ from ..ops.sampling import (
     sample_tokens_packed,
 )
 from ..parallel.mesh import MeshConfig, build_mesh
+from .program_store import StepPrograms, open_store
 from .config import (
     EngineConfig,
     refuse_unserved,
@@ -408,6 +409,9 @@ class ModelRunner:
         # pstlint: jit-family=prefill
         prefill_step = jax.jit(pst_prefill_step, **step_jit)
         self._step = {"decode": decode_step, "prefill": prefill_step}
+        # Every jitted step dispatch below is called through this holder,
+        # by its shape key; `place_program_store` gives it the store.
+        self.programs = StepPrograms()
 
         bs = cfg.block_size
         drop_slot = self.num_blocks * bs
@@ -602,6 +606,26 @@ class ModelRunner:
             ],
         }
         logger.info("engine device path: %s", self.device_info)
+
+    def place_program_store(self, cache_path: Optional[str]) -> None:
+        """Keep step programs beside the compile cache at ``cache_path``
+        (what ``configure_compile_cache`` returned), where one is kept at
+        all (`program_store.open_store`). What the runner resolved itself
+        names the entries with the configuration it resolved it from."""
+        self.programs.store = open_store(
+            cache_path, self.cfg, self.model_cfg, self.mesh,
+            resolved={
+                "attention_impl": self._attn_impl,
+                "moe_impl": self._moe_impl,
+                "int4_impl": self._int4_impl(),
+                "pallas_interpret": pallas_interpret(),
+                "kv_pages": self.num_blocks,
+                "state_slots": self.state_slots,
+                "window_blocks": self.window_blocks,
+            },
+        )
+        self.device_info["program_store"] = (
+            self.programs.store.path if self.programs.store else None)
 
     def _int4_impl(self) -> Optional[str]:
         """Which implementation the int4 layer matmuls trace to: ``pallas``
@@ -929,7 +953,7 @@ class ModelRunner:
         with self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce("encode", (toks, length))
-            out = self._dispatch_encode(toks, length)
+            out = self._dispatch_encode(toks, length, key)
         ENGINE_TELEMETRY.record_dispatch(
             "encode", key, time.perf_counter() - t0,
             batch_bucket=f"t{T}", tokens=len(token_ids),
@@ -937,7 +961,10 @@ class ModelRunner:
         )
         return out
 
-    def _dispatch_encode(self, toks: np.ndarray, length: np.ndarray) -> np.ndarray:
+    def _dispatch_encode(
+        self, toks: np.ndarray, length: np.ndarray,
+        key: Optional[tuple] = None,
+    ) -> np.ndarray:
         if not hasattr(self, "_encode_fn"):
             model = self.model
             pp = self._pp
@@ -954,10 +981,10 @@ class ModelRunner:
 
             # pstlint: jit-family=encode
             self._encode_fn = jax.jit(enc, out_shardings=self._repl)
-        out = self._encode_fn(
-            self.params,
-            jax.device_put(toks, self._repl),
-            jax.device_put(length, self._repl),
+        out = self.programs.call(
+            key, self._encode_fn,
+            (self.params, jax.device_put(toks, self._repl),
+             jax.device_put(length, self._repl)),
         )
         return _fetch(out)[0]
 
@@ -1089,7 +1116,7 @@ class ModelRunner:
             self._step_info("decode", f"b{Bb}", seqs, batch, len(seqs))
         t0 = time.perf_counter()
         self._host_gap_mark(f"b{Bb}", t0, seqs)
-        rows = self._run(batch, want_lp, greedy, "decode")
+        rows = self._run(batch, want_lp, greedy, "decode", key)
         self._host_gap_arm()
         dt = time.perf_counter() - t0
         with ENGINE_TELEMETRY.phase("postprocess", "decode"):
@@ -1135,7 +1162,7 @@ class ModelRunner:
                     "multi_step", (batch, counts, n_steps, want_lp, greedy)
                 )
             rows = self._dispatch_multi_step(
-                batch, counts, n_steps, want_lp, greedy
+                batch, counts, n_steps, want_lp, greedy, key
             )
         self._host_gap_arm()
         dt = time.perf_counter() - t0
@@ -1201,6 +1228,7 @@ class ModelRunner:
         n_steps: int,
         want_lp: bool = False,
         greedy: bool = False,
+        key: Optional[tuple] = None,
     ) -> np.ndarray:
         with ENGINE_TELEMETRY.phase("launch", "decode"):
             dev = self._put_batch(batch)
@@ -1209,9 +1237,11 @@ class ModelRunner:
             tokens = dev.pop("tokens")
             positions = dev.pop("positions")
             with_pen = "penalty_seen" in batch
-            toks, _, _, _, _, self.kv_cache = self._burst_fn(n_steps)(
-                self.params, self.kv_cache, dev, tokens, positions, seed0,
-                cdev, n_steps, want_lp, greedy, with_pen,
+            toks, _, _, _, _, self.kv_cache = self.programs.call(
+                key, self._burst_fn(n_steps),
+                (self.params, self.kv_cache, dev, tokens, positions, seed0,
+                 cdev),
+                (n_steps, want_lp, greedy, with_pen),
             )
         return self._take_aux(_fetch(toks, "decode"))
 
@@ -1251,7 +1281,8 @@ class ModelRunner:
                 self.publisher.announce(
                     "burst_start", (batch, counts, n_steps, want_lp, greedy)
                 )
-            self._dispatch_burst_start(batch, counts, n_steps, want_lp, greedy)
+            self._dispatch_burst_start(
+                batch, counts, n_steps, want_lp, greedy, key)
         dt = time.perf_counter() - t0
         with ENGINE_TELEMETRY.phase("postprocess", "decode"):
             self._charge_decode(seqs, dt)
@@ -1272,6 +1303,7 @@ class ModelRunner:
         n_steps: int,
         want_lp: bool = False,
         greedy: bool = False,
+        key: Optional[tuple] = None,
     ) -> None:
         # pipelined: a later step fetches what this launch computes
         with ENGINE_TELEMETRY.phase("launch", "decode", pipelined=1):
@@ -1282,9 +1314,11 @@ class ModelRunner:
             positions = dev.pop("positions")
             with_pen = "penalty_seen" in batch
             toks, tokens, positions, seed, cdev, self.kv_cache = (
-                self._burst_fn(n_steps)(
-                    self.params, self.kv_cache, dev, tokens, positions, seed,
-                    cdev, n_steps, want_lp, greedy, with_pen,
+                self.programs.call(
+                    key, self._burst_fn(n_steps),
+                    (self.params, self.kv_cache, dev, tokens, positions,
+                     seed, cdev),
+                    (n_steps, want_lp, greedy, with_pen),
                 )
             )
             # Start the host copy NOW; the eventual fetch finds it resident.
@@ -1298,6 +1332,10 @@ class ModelRunner:
             "seed": seed, "counts": cdev, "with_pen": with_pen,
             "toks": toks, "n": n_steps, "want_lp": want_lp,
             "greedy": greedy, "steps": n_steps,
+            # A continuation takes `tokens` and `positions` as the step
+            # before left them, replicated; with dp > 1 the start took them
+            # by rows: another program, under a key of its own.
+            "key": key + ("continued",) if key and self._dp > 1 else key,
             "rows": {k: v for k, v in batch.items()
                      if k not in ("tokens", "positions")},
         }
@@ -1319,8 +1357,15 @@ class ModelRunner:
         while rows <= _pow2(
                 min(self.cfg.max_num_seqs, self.cfg.max_prefill_tokens)):
             toks = put(np.zeros((rows + self._aux_rows, 1), np.float32))
-            self._splice(carry, carry, toks, src, carry)
+            self._call_splice(carry, carry, toks, src, carry)
             rows <<= 1
+
+    def _call_splice(self, tokens, positions, toks, src, pos):
+        """`_splice` through its program: keyed by the chain's rows and
+        the prefill's packed rows."""
+        return self.programs.call(
+            (self._tel_scope, "splice", tokens.shape, toks.shape),
+            self._splice, (tokens, positions, toks, src, pos))
 
     def burst_width_stable(self, members: List[Sequence]) -> bool:
         """True while the members' block tables still fit the width bucket
@@ -1466,15 +1511,15 @@ class ModelRunner:
             st["batch"].update(self._put_batch(refresh))
             if splice is not None:
                 src, pos = jax.device_put(splice, self._repl)
-                st["tokens"], st["positions"] = self._splice(
+                st["tokens"], st["positions"] = self._call_splice(
                     st["tokens"], st["positions"], self._prefill_toks,
                     src, pos)
             toks, tokens, positions, seed, counts, self.kv_cache = (
-                self._burst_fn(st["n"])(
-                    self.params, self.kv_cache, st["batch"], st["tokens"],
-                    st["positions"], st["seed"], st["counts"], st["n"],
-                    st["want_lp"], st.get("greedy", False),
-                    st.get("with_pen", False),
+                self.programs.call(
+                    st["key"], self._burst_fn(st["n"]),
+                    (self.params, self.kv_cache, st["batch"], st["tokens"],
+                     st["positions"], st["seed"], st["counts"]),
+                    (st["n"], st["want_lp"], st["greedy"], st["with_pen"]),
                 )
             )
             # Start the host copy NOW; the eventual fetch finds it resident.
@@ -1529,7 +1574,7 @@ class ModelRunner:
         with self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce("spec_verify", batch)
-            ids, sampled0 = self._dispatch_spec_verify(batch)
+            ids, sampled0 = self._dispatch_spec_verify(batch, key)
         dt = time.perf_counter() - t0
         with ENGINE_TELEMETRY.phase("postprocess", "spec_verify"):
             self._charge_decode(seqs, dt)
@@ -1591,7 +1636,9 @@ class ModelRunner:
         batch.pop("repetition", None)
         return batch
 
-    def _dispatch_spec_verify(self, batch: Dict[str, np.ndarray]) -> np.ndarray:
+    def _dispatch_spec_verify(
+        self, batch: Dict[str, np.ndarray], key: Optional[tuple] = None
+    ) -> np.ndarray:
         if not hasattr(self, "_spec_step"):
             model = self.model
             attn_impl = self._attn_impl
@@ -1652,8 +1699,9 @@ class ModelRunner:
                 out_shardings=(self._repl, cache_sh),
             )
         with ENGINE_TELEMETRY.phase("launch", "spec_verify"):
-            packed, self.kv_cache = self._spec_step(
-                self.params, self.kv_cache, self._put_batch(batch)
+            packed, self.kv_cache = self.programs.call(
+                key, self._spec_step,
+                (self.params, self.kv_cache, self._put_batch(batch)),
             )
         packed = _fetch(packed, "spec_verify")
         return packed[:, :-1], packed[:, -1]
@@ -1695,7 +1743,7 @@ class ModelRunner:
             )
         t0 = time.perf_counter()
         self._host_gap_cancel()
-        rows = self._run(batch, want_lp, greedy, "prefill")
+        rows = self._run(batch, want_lp, greedy, "prefill", key)
         dt = time.perf_counter() - t0
         with ENGINE_TELEMETRY.phase("postprocess", "prefill"):
             self._charge_prefill(items, dt)
@@ -1726,7 +1774,7 @@ class ModelRunner:
         with self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce("step_nofetch", batch)
-            self._dispatch_step_nofetch(batch)
+            self._dispatch_step_nofetch(batch, key)
         dt = time.perf_counter() - t0
         with ENGINE_TELEMETRY.phase("postprocess", "prefill"):
             self._charge_prefill(items, dt)
@@ -1735,12 +1783,16 @@ class ModelRunner:
                 batch_bucket=bucket, tokens=real, fill_ratio=fill,
             )
 
-    def _dispatch_step_nofetch(self, batch: Dict[str, np.ndarray]) -> None:
+    def _dispatch_step_nofetch(
+        self, batch: Dict[str, np.ndarray], key: Optional[tuple] = None
+    ) -> None:
         # greedy=True: nobody reads an intermediate chunk's sample, so the
         # cheapest sampling variant (plain argmax) is always correct here.
         with ENGINE_TELEMETRY.phase("launch", "prefill"):
-            _, self.kv_cache = self._step["prefill"](
-                self.params, self.kv_cache, self._put_batch(batch), False, True
+            _, self.kv_cache = self.programs.call(
+                key, self._step["prefill"],
+                (self.params, self.kv_cache, self._put_batch(batch)),
+                (False, True),
             )
 
     def prefill_dispatch(  # noqa: D401
@@ -1774,9 +1826,10 @@ class ModelRunner:
                     "step", (batch, want_lp, greedy, "prefill")
                 )
             with ENGINE_TELEMETRY.phase("launch", "prefill"):
-                toks, self.kv_cache = self._step["prefill"](
-                    self.params, self.kv_cache, self._put_batch(batch),
-                    want_lp, greedy,
+                toks, self.kv_cache = self.programs.call(
+                    key, self._step["prefill"],
+                    (self.params, self.kv_cache, self._put_batch(batch)),
+                    (want_lp, greedy),
                 )
                 toks.copy_to_host_async()
                 self._prefill_toks = toks
@@ -1810,11 +1863,12 @@ class ModelRunner:
         want_lp: bool,
         greedy: bool,
         kind: str,
+        key: Optional[tuple] = None,
     ) -> np.ndarray:
         with self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce("step", (batch, want_lp, greedy, kind))
-            return self._dispatch_step(batch, want_lp, greedy, kind)
+            return self._dispatch_step(batch, want_lp, greedy, kind, key)
 
     def _dispatch_step(
         self,
@@ -1822,13 +1876,16 @@ class ModelRunner:
         want_lp: bool,
         greedy: bool,
         kind: str,
+        key: Optional[tuple] = None,
     ) -> np.ndarray:
         """Launch the ``kind`` ("decode" | "prefill") program on ``batch``
-        and fetch its packed rows."""
+        and fetch its packed rows. ``key``: the step's shape key, which
+        finds its program (a follower's replay has none: the jit)."""
         with ENGINE_TELEMETRY.phase("launch", kind):
-            toks, self.kv_cache = self._step[kind](
-                self.params, self.kv_cache, self._put_batch(batch),
-                want_lp, greedy,
+            toks, self.kv_cache = self.programs.call(
+                key, self._step[kind],
+                (self.params, self.kv_cache, self._put_batch(batch)),
+                (want_lp, greedy),
             )
             if kind == "prefill":
                 self._prefill_toks = toks  # a follower's, for `_splice`
@@ -1913,7 +1970,7 @@ class ModelRunner:
         batch.update(self._warmup_sampling_arrays(Bb, Wb))
         key = self._tel_key("decode", batch, (bucket.want_lp, bucket.greedy))
         t0 = time.perf_counter()
-        self._run(batch, bucket.want_lp, bucket.greedy, "decode")
+        self._run(batch, bucket.want_lp, bucket.greedy, "decode", key)
         self._record_warmup(
             "decode", key, time.perf_counter() - t0, bucket.label
         )
@@ -1949,7 +2006,7 @@ class ModelRunner:
                     (batch, counts, n, bucket.want_lp, bucket.greedy),
                 )
             self._dispatch_multi_step(
-                batch, counts, n, bucket.want_lp, bucket.greedy
+                batch, counts, n, bucket.want_lp, bucket.greedy, key
             )
             # what a chain of this many rows runs between a prefill and
             # the step behind it
@@ -1975,7 +2032,7 @@ class ModelRunner:
             batch["sample_rows"] = np.zeros(Bb, bool)
         key = self._tel_key("prefill", batch, (bucket.want_lp, bucket.greedy))
         t0 = time.perf_counter()
-        self._run(batch, bucket.want_lp, bucket.greedy, "prefill")
+        self._run(batch, bucket.want_lp, bucket.greedy, "prefill", key)
         self._record_warmup(
             "prefill", key, time.perf_counter() - t0, bucket.label
         )
@@ -1997,7 +2054,7 @@ class ModelRunner:
         with self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce("spec_verify", batch)
-            self._dispatch_spec_verify(batch)
+            self._dispatch_spec_verify(batch, key)
         self._record_warmup(
             "spec_verify", key, time.perf_counter() - t0, bucket.label
         )
@@ -2011,7 +2068,7 @@ class ModelRunner:
         with self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce("encode", (toks, length))
-            self._dispatch_encode(toks, length)
+            self._dispatch_encode(toks, length, key)
         self._record_warmup(
             "encode", key, time.perf_counter() - t0, bucket.label
         )

@@ -196,7 +196,9 @@ startup_seconds = Gauge(
     "tokenizer, load (param materialization), shard (device placement + "
     "KV alloc + jit wiring), warmup (compile cache, allocator, scheduler), "
     "precompile (ahead-of-time shape-bucket lattice compilation), serve "
-    "(constructor done to first ready, precompile excluded)",
+    "(constructor done to first ready, precompile excluded); beside "
+    "them, not one of their sum, program_first_use: what step shapes' "
+    "first uses in the process took so far, in precompile or after it",
     ["phase"],
     registry=ENGINE_TELEMETRY_REGISTRY,
 )
@@ -217,15 +219,56 @@ warmup_buckets = Gauge(
 compile_cache_hits = Counter(
     "pst_engine_compile_cache_hits",
     "Persistent JAX compilation-cache hits (executable deserialized "
-    "instead of rebuilt by XLA)",
+    "instead of rebuilt by XLA), and step programs loaded from the program "
+    "store, which keeps those in its place",
     registry=ENGINE_TELEMETRY_REGISTRY,
 )
 compile_cache_misses = Counter(
     "pst_engine_compile_cache_misses",
     "Persistent JAX compilation-cache misses (fresh XLA build, entry "
-    "written for the next restart)",
+    "written for the next restart), and step programs built for the "
+    "program store",
     registry=ENGINE_TELEMETRY_REGISTRY,
 )
+# The program store (engine/program_store.py): what became of a step
+# shape's first use in the process, and what that first use spent its time
+# on. Every label from the start: a window without a first use reads 0.
+PROGRAM_STORE_OUTCOMES = ("loaded", "built", "rejected")
+FIRST_USE_PHASES = ("trace", "lower", "backend_compile", "cache_read",
+                    "load", "write", "other")
+program_store_total = Counter(
+    "pst_engine_program_store",
+    "Step programs met for the first time in the process where a program "
+    "store is placed: loaded (the stored executable, nothing traced), "
+    "built (traced, lowered and compiled, then stored), rejected (an entry "
+    "that did not read, load or take its arguments: dropped and rebuilt)",
+    ["outcome"],
+    registry=ENGINE_TELEMETRY_REGISTRY,
+)
+program_first_use_seconds = Counter(
+    "pst_engine_program_first_use_seconds",
+    "Seconds step shapes' first uses in the process took, by what they "
+    "were spent on: trace (the program's Python to a jaxpr), lower (the "
+    "jaxpr to its module, kernels' bodies included), backend_compile (XLA "
+    "and Mosaic, or the cache key where the cache had it), cache_read "
+    "(XLA's persistent cache: read and load), load (the program store: "
+    "read, decompress and load), write (a built program serialised into "
+    "the store), other (argument handling and the first call)",
+    ["phase"],
+    registry=ENGINE_TELEMETRY_REGISTRY,
+)
+_store_children = {
+    o: program_store_total.labels(outcome=o) for o in PROGRAM_STORE_OUTCOMES}
+_first_use_children = {
+    p: program_first_use_seconds.labels(phase=p) for p in FIRST_USE_PHASES}
+# jax.monitoring's duration events, by the last part of their names, to
+# the phase each feeds (engine/precompile.py installs the listener).
+_FIRST_USE_EVENTS = {
+    "jaxpr_trace_duration": "trace",
+    "jaxpr_to_mlir_module_duration": "lower",
+    "backend_compile_duration": "backend_compile",
+    "cache_retrieval_time_sec": "cache_read",
+}
 # Per-request cost attribution (docs/observability.md "Cost attribution"):
 # each finished request's accumulated device-seconds, split by phase, and
 # the per-tenant chip-time meter that extends PR 12's token metering into
@@ -324,7 +367,7 @@ class _Cycle:
 
     __slots__ = ("t0", "thread_cpu0", "process_cpu0", "profiler_starts0",
                  "phases", "wait_cpu_s", "dispatches", "gc_s", "polls",
-                 "poll_gap_max_s")
+                 "poll_gap_max_s", "store_outcome")
 
     def __init__(self, t0: float, thread_cpu0: float, process_cpu0: float,
                  profiler_starts0: int):
@@ -341,6 +384,19 @@ class _Cycle:
         self.gc_s = 0.0
         self.polls = 0
         self.poll_gap_max_s = 0.0
+        # what the program store made of a shape this cycle met first
+        self.store_outcome = ""
+
+
+class _FirstUse:
+    """One step shape's first use in the process, while it is open: the
+    seconds of each phase and what the program store made of it."""
+
+    __slots__ = ("seconds", "outcome")
+
+    def __init__(self):
+        self.seconds = dict.fromkeys(FIRST_USE_PHASES, 0.0)
+        self.outcome = ""
 
 
 # Fresh runners must re-count compiles even when an earlier runner in the
@@ -378,6 +434,10 @@ class EngineTelemetry:
         # monitoring listener precompile.configure_compile_cache installs).
         self._cache_hits = 0
         self._cache_misses = 0
+        # By thread, the first use of a step shape that is open there
+        # (`program_first_use`); and the seconds all of them took so far.
+        self._first_use = threading.local()
+        self._first_use_s = 0.0
         # The step phase open on the step thread, and by thread the cycle
         # its step closes (opened by the intake before it, or by the step
         # itself where nothing runs an intake). By thread: a second engine
@@ -432,13 +492,68 @@ class EngineTelemetry:
 
     def record_cache_event(self, hit: bool) -> None:
         """One persistent-compilation-cache lookup outcome (from the jax
-        monitoring listener)."""
+        monitoring listener), or a program store's: a step program it
+        builds goes past XLA's cache, which reports nothing of it."""
         with self._lock:
             if hit:
                 self._cache_hits += 1
             else:
                 self._cache_misses += 1
         (compile_cache_hits if hit else compile_cache_misses).inc()
+
+    @contextlib.contextmanager
+    def program_first_use(self):
+        """Around a step shape's first use in the process (the runner's
+        holder of step programs opens it): what jax reports of tracing,
+        lowering, compiling and its cache on this thread meanwhile is the
+        first use's, and so is what the program store adds to the record it
+        is handed (``seconds["load"]``, ``seconds["write"]``, ``outcome``).
+        At the end the split goes to
+        ``pst_engine_program_first_use_seconds_total{phase}``, the outcome
+        to ``pst_engine_program_store_total`` and the sum of all first uses
+        so far to ``pst_engine_startup_seconds{phase="program_first_use"}``."""
+        use = self._first_use.open = _FirstUse()
+        t0 = time.perf_counter()
+        try:
+            yield use
+        finally:
+            self._first_use.open = None
+            wall = time.perf_counter() - t0
+            sec = use.seconds
+            # XLA times its cache inside its compile
+            sec["backend_compile"] = max(
+                sec["backend_compile"] - sec["cache_read"], 0.0)
+            sec["other"] = max(wall - sum(sec.values()), 0.0)
+            for phase, child in _first_use_children.items():
+                child.inc(sec[phase])
+            with self._lock:
+                self._first_use_s += wall
+                total = self._first_use_s
+            self.record_startup_phase("program_first_use", total)
+            if use.outcome:
+                _store_children[use.outcome].inc()
+                self.record_cache_event(use.outcome == "loaded")
+                cycle = self._open_cycle()
+                if cycle is not None:
+                    cycle.store_outcome = use.outcome
+
+    def first_use_event(self, event: str, seconds: float) -> None:
+        """One of jax.monitoring's duration events, on the thread that
+        compiles: counted where a first use is open there. A program's
+        trace reports the traces of the jitted functions inside it too,
+        each inside the outer one's time: the longest is the program's."""
+        use = getattr(self._first_use, "open", None)
+        if use is None:
+            return
+        phase = _FIRST_USE_EVENTS.get(event.rsplit("/", 1)[-1])
+        if phase == "trace":
+            use.seconds[phase] = max(use.seconds[phase], seconds)
+        elif phase is not None:
+            use.seconds[phase] += seconds
+
+    def program_rejected(self) -> None:
+        """A stored program that did not read, load or take its arguments."""
+        _store_children["rejected"].inc()
 
     def cache_stats(self) -> "Tuple[int, int]":
         """(hits, misses) observed since process start — bench and the
@@ -700,13 +815,16 @@ class EngineTelemetry:
         count, seconds = _stall_children[stall["cause"]]
         count.inc()
         seconds.inc(stall["excess_s"])
+        # a first use through the program store says what became of it
+        made = (f"{cycle.store_outcome}; "
+                if stall["cause"] == "compile" and cycle.store_outcome else "")
         logger.warning(
-            "stall %.2f s in %s of %s: %s (%s polls, longest gap %.1f ms, "
+            "stall %.2f s in %s of %s: %s (%s%s polls, longest gap %.1f ms, "
             "gc %.2f s, off-CPU %.2f s, thread CPU %.2f s, process CPU "
             "%.2f s; waiting %d, running %d)",
             stall["excess_s"], stall["phase"],
             f"{stall['kind']} {stall['bucket']}".strip(), stall["cause"],
-            f"{stall['polls']:,}", stall["poll_gap_max_s"] * 1e3,
+            made, f"{stall['polls']:,}", stall["poll_gap_max_s"] * 1e3,
             stall["gc_s"], stall["offcpu_s"], stall["thread_cpu_s"],
             stall["process_cpu_s"], stall["waiting"], stall["running"],
         )
@@ -851,6 +969,7 @@ class EngineTelemetry:
             self._kv_hwm = 0.0
             self._cache_hits = 0
             self._cache_misses = 0
+            self._first_use_s = 0.0
             self._step = None
             self._cycles.clear()
             self._cpu_mark = None

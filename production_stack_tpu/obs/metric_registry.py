@@ -84,6 +84,10 @@ REGISTRY: Tuple[MetricSpec, ...] = (
     MetricSpec("pst_engine_warmup_buckets", GAUGE, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_compile_cache_hits", COUNTER, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_compile_cache_misses", COUNTER, "obs/engine_telemetry.py"),
+    # The program store (engine/program_store.py): what became of a step
+    # shape's first use, and what first uses spent their time on.
+    MetricSpec("pst_engine_program_store", COUNTER, "obs/engine_telemetry.py"),
+    MetricSpec("pst_engine_program_first_use_seconds", COUNTER, "obs/engine_telemetry.py"),
     # Per-request cost attribution (docs/observability.md "Cost
     # attribution"): device-seconds per finished request + the per-tenant
     # chip-time billing meter and its audit denominator.
