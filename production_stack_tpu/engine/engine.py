@@ -100,9 +100,14 @@ class LLMEngine:
         self.tokenizer = get_tokenizer(tok_spec, self.model_cfg.vocab_size)
         t_runner = time.perf_counter()
         ENGINE_TELEMETRY.record_startup_phase("tokenizer", t_runner - t_tok)
+        logger.info(
+            "tokenizer: loader %s, path %s, %.2f s",
+            self.tokenizer.loader, tok_spec, t_runner - t_tok,
+        )
         self.runner = ModelRunner(cfg, self.model_cfg, mesh)
         t_runner_s = time.perf_counter() - t_runner
         self.runner.device_info["compile_cache_dir"] = compile_cache_path
+        self.runner.device_info["tokenizer_loader"] = self.tokenizer.loader
         # Step programs are kept beside the executables (a `programs/`
         # directory there): a shape this tree built before is loaded at
         # its first use, not traced again.
