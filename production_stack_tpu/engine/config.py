@@ -233,14 +233,23 @@ def _both(why: str) -> dict:
     return {"recurrent": why, "latent_pages": why, "window_pages": why}
 
 
+# A looped stack's step has been held to the reference with these on
+# (``tests/test_ouro.py``): the prefix cache, swap, n-gram drafts,
+# quantised leaves, tensor parallelism. Everything else of the table it is
+# refused with.
+_UNPROVEN_LOOPED = ("it has not been held to the reference through "
+                    "passes x layers cache slots")
+
+
 # What a model class cannot be served with, refused at start-up by the
 # flag's name: one table for every class that is not a plain K+V page list.
 # A row: is the flag on, its name, and for each property of the model's
 # config (``recurrent``: per-sequence state beside the pages;
 # ``latent_pages``: a page is one latent row a token, not a K and a V half;
 # ``window_pages``: a second page group, released below the window;
-# ``wide_head_pages``: heads wider than the paged kernels have been proven at)
-# why the flag cannot be served, or no entry where it can.
+# ``wide_head_pages``: heads wider than the paged kernels have been proven at;
+# ``looped``: more layers of pages than of weights, the stack run several
+# times a step) why the flag cannot be served, or no entry where it can.
 def _refusals(cfg: EngineConfig):
     return [
         (cfg.enable_prefix_caching, "--enable-prefix-caching", {
@@ -258,34 +267,43 @@ def _refusals(cfg: EngineConfig):
             "recurrent": "host-tier pages carry no state",
             "latent_pages": "the host tier frames a page as a K and a V "
                             "half (engine/cache_tiering.py)",
-            "window_pages": "the host tier knows one page group"}),
+            "window_pages": "the host tier knows one page group",
+            "looped": _UNPROVEN_LOOPED}),
         (bool(cfg.remote_kv_url), "--remote-kv-url", {
             "recurrent": "remote-tier pages carry no state",
             "latent_pages": "the kvserver's framing is a K and a V half a "
                             "page",
-            "window_pages": "the remote tier knows one page group"}),
+            "window_pages": "the remote tier knows one page group",
+            "looped": _UNPROVEN_LOOPED}),
         (cfg.kv_role != "none", "--kv-role", {
             "recurrent": "a KV hand-off ships pages, not the state",
             "latent_pages": "the hand-off ships K and V halves "
                             "(engine/kv_handoff.py)",
-            "window_pages": "the hand-off ships one page group"}),
+            "window_pages": "the hand-off ships one page group",
+            "looped": _UNPROVEN_LOOPED}),
         (cfg.speculative_ngram > 0, "--speculative-ngram", {
             "recurrent": "a rejected draft would need the state rolled back",
             "window_pages": "a draft's rows have not been verified through "
                             "the window group"}),
-        (cfg.enable_lora, "--enable-lora",
-         _both("no adapter bank exists for these layers")),
+        (cfg.enable_lora, "--enable-lora", {
+            **_both("no adapter bank exists for these layers"),
+            "looped": _UNPROVEN_LOOPED}),
         (cfg.tensor_parallel_size > 1, "--tensor-parallel-size",
          _both("its pools and kernels run on one device")),
         (cfg.pipeline_parallel_size > 1, "--pipeline-parallel-size", {
             "recurrent": "the layer pattern is not staged",
             "latent_pages": "the dense and expert layers are not staged",
-            "window_pages": "the two page groups are not staged"}),
+            "window_pages": "the two page groups are not staged",
+            "looped": "a stage would be visited once a pass, several times "
+                      "a step"}),
         (cfg.expert_parallel_size > 1, "--expert-parallel-size",
          _both("an expert-parallel share is told by the model config's "
                "ep_share, not by a mesh")),
-        (cfg.data_parallel_size > 1, "--data-parallel-size",
-         _both("its pools and kernels run on one device")),
+        (cfg.data_parallel_size > 1, "--data-parallel-size", {
+            **_both("its pools and kernels run on one device"),
+            "looped": _UNPROVEN_LOOPED}),
+        (cfg.sequence_parallel_size > 1, "--sequence-parallel-size", {
+            "looped": "the embeddings path is not built for a looped stack"}),
         (bool(cfg.quantization), "--quantization",
          _both("no quantised leaves exist for these layers")),
         (bool(cfg.kv_cache_dtype)
@@ -298,14 +316,19 @@ def _refusals(cfg: EngineConfig):
                             "calibrated",
             "wide_head_pages": "one-byte pages of 256-wide heads are not "
                                "proven (the paged kernels' one-byte path "
-                               "has run at 128 lanes a head only)"}),
+                               "has run at 128 lanes a head only)",
+            "looped": "one-byte pages under a looped stack are not "
+                      "calibrated (a pass reads what the pass before "
+                      "rounded)"}),
     ]
 
 
 _HAS = {"recurrent": "has recurrent (state-space) layers",
         "latent_pages": "keeps pages of latents (MLA)",
         "window_pages": "releases its window layers' pages below the window",
-        "wide_head_pages": "keeps pages of 256-wide heads"}
+        "wide_head_pages": "keeps pages of 256-wide heads",
+        "looped": "runs its layer stack several times a step, each pass on "
+                  "cache slots of its own"}
 
 
 def refuse_unserved(cfg: EngineConfig, model_cfg: ModelConfig) -> None:
@@ -315,7 +338,8 @@ def refuse_unserved(cfg: EngineConfig, model_cfg: ModelConfig) -> None:
     kinds = {"recurrent": model_cfg.recurrent,
              "latent_pages": model_cfg.latent_pages,
              "window_pages": model_cfg.window_pages,
-             "wide_head_pages": model_cfg.wide_head_pages}
+             "wide_head_pages": model_cfg.wide_head_pages,
+             "looped": model_cfg.looped}
     for prop, has in _HAS.items():
         if not kinds[prop]:
             continue
@@ -376,12 +400,15 @@ def resolve_num_kv_blocks(
                     f"{hbm} B hold beside {param_bytes_per_device} B of "
                     "weights and the model's state and window pools")
     if cfg.num_kv_blocks is not None:
-        return cfg.num_kv_blocks
-    n = max(budget // page_bytes, cfg.max_num_seqs * 2)
-    # Never fewer pages than one full-length sequence needs.
-    n = max(n, -(-cfg.max_model_len // cfg.block_size) + 1)
+        n = cfg.num_kv_blocks
+    else:
+        n = max(budget // page_bytes, cfg.max_num_seqs * 2)
+        # Never fewer pages than one full-length sequence needs.
+        n = max(n, -(-cfg.max_model_len // cfg.block_size) + 1)
     logger.info(
-        "KV cache: %d pages x %d tokens (%.1f MiB/device)",
-        n, cfg.block_size, n * page_bytes / 2**20,
+        "KV cache: %d pages x %d tokens over %d layers of pages, %d B a "
+        "token (%.1f MiB/device)",
+        n, cfg.block_size, model_cfg.num_kv_layers,
+        page_bytes // cfg.block_size, n * page_bytes / 2**20,
     )
     return int(n)

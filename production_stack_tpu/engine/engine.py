@@ -228,6 +228,10 @@ class LLMEngine:
         # each drained chain by the reason it could not go on.
         self.decode_dispatches_total = 0
         self.pipelined_bursts_total = 0
+        # Layers a decode dispatch ran, by the host's count: the layer
+        # stack's passes (1 but for a looped stack) x its layers x the
+        # burst's depth (a later per-token exit would run fewer).
+        self.decode_layer_passes_total = 0
         self.pipeline_breaks = {why: 0 for why in CHAIN_BREAK_REASONS}
         # Prefill steps a chain went on behind, with no drain.
         self.chain_kept_prefills_total = 0
@@ -578,7 +582,7 @@ class LLMEngine:
                     handle = self.runner.prefill_dispatch(
                         sched.prefills, record_at_fetch=bool(joins))
                     self.chain_kept_prefills_total += 1
-                self._count_decode(chained=True)
+                self._count_decode(chained=True, depth=self._burst_n)
                 rows = self.runner.burst_continue(members, joins)
                 with phase("postprocess", "decode"):
                     outputs += self._process_burst_rows(fetched, rows)
@@ -645,10 +649,10 @@ class LLMEngine:
             # on the NEXT step, overlapped with the following burst.
             self._burst_seqs = list(sched.decodes)
             self._burst_n = sched.n_decode_steps
-            self._count_decode(chained=True)
+            self._count_decode(chained=True, depth=sched.n_decode_steps)
             self.runner.burst_start(sched.decodes, sched.n_decode_steps)
         else:
-            self._count_decode(chained=False)
+            self._count_decode(chained=False, depth=sched.n_decode_steps)
             bursts = self.runner.execute_decode_multi(
                 sched.decodes, sched.n_decode_steps
             )
@@ -970,8 +974,9 @@ class LLMEngine:
         return None, [
             (row, seq, at[id(seq)]) for row, seq in zip(free, fresh)]
 
-    def _count_decode(self, chained: bool) -> None:
+    def _count_decode(self, chained: bool, depth: int) -> None:
         self.decode_dispatches_total += 1
+        self.decode_layer_passes_total += self.runner.layers_a_token * depth
         if chained:
             self.pipelined_bursts_total += 1
 
@@ -1267,6 +1272,12 @@ class LLMEngine:
                 self.spec_accepted_total
             )
         out["decode_dispatches_total"] = float(self.decode_dispatches_total)
+        out["decode_layer_passes_total"] = float(
+            self.decode_layer_passes_total)
+        out["prefill_layer_passes_total"] = float(
+            self.runner.prefill_layer_passes_total)
+        out["kv_slot_layers"] = float(self.model_cfg.num_kv_layers)
+        out["prefix_waits_total"] = float(self.scheduler.prefix_waits)
         if self.runner.state_slots:
             out["state_slots_in_use"] = float(self.allocator.state_slots_in_use)
             out["state_slot_waits_total"] = float(

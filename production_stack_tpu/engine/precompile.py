@@ -275,6 +275,9 @@ def compile_cache_key(cfg: EngineConfig, model_cfg) -> str:
     parts = (
         f"model={model_cfg.name}",
         f"layers={model_cfg.num_layers}",
+        # a looped stack: the same layers, another program and cache
+        *((f"kv_layers={model_cfg.num_kv_layers}",) if model_cfg.looped
+          else ()),
         f"kv_heads={model_cfg.num_kv_heads}",
         f"head_dim={model_cfg.head_dim}",
         f"vocab={model_cfg.vocab_size}",

@@ -156,6 +156,13 @@ class ModelRunner:
         # hold (rows x chunk, padding included).
         self.prefill_tokens_total = 0
         self.prefill_bucket_positions_total = 0
+        # A looped stack runs its layers ``passes`` times a step over one
+        # set of weights (1 for every other model): what a step's trace
+        # record says beside its rows, and the layers a token costs.
+        self.passes = (self.model_cfg.num_kv_layers // self.model_cfg.num_layers
+                       if self.model_cfg.looped else 1)
+        self.layers_a_token = self.passes * self.model_cfg.num_layers
+        self.prefill_layer_passes_total = 0
         # Window-group pages the steps' rows held, summed over steps, beside
         # what the same rows would hold were every page kept.
         self.window_page_steps_total = 0
@@ -1037,6 +1044,10 @@ class ModelRunner:
             slots["window_pages"] = whole - sum(s.window_released for s in seqs)
             self.window_page_steps_total += slots["window_pages"]
             self.window_whole_context_page_steps_total += whole
+        if self.passes > 1:
+            # a looped stack: the step reads its weights and writes a layer
+            # of pages once a pass
+            slots["passes"] = self.passes
         ENGINE_TELEMETRY.step_info(
             kind, bucket=bucket, rows=n, new_tokens=new_tokens,
             kv_tokens=int(batch["kv_lens"][:n].sum()) + n * kv_ahead,
@@ -1718,6 +1729,7 @@ class ModelRunner:
         self._step_info("prefill", bucket, [it.seq for it in items], batch, real)
         self.prefill_tokens_total += real
         self.prefill_bucket_positions_total += Bb * Tb
+        self.prefill_layer_passes_total += self.layers_a_token
         return (
             self._tel_key("prefill", batch, extras),
             bucket,

@@ -122,6 +122,9 @@ class Scheduler:
 
         self._tenant_drr = DeficitScheduler()
         self.batch_preemptions = 0  # batch seqs preempted for interactive
+        # admissions held back a step because a running row was computing
+        # the page they need next (``_running_prefill_computes_next_page``)
+        self.prefix_waits = 0
 
     # -- queue ops --------------------------------------------------------
 
@@ -464,6 +467,41 @@ class Scheduler:
             s.blocks_needed(s.num_prompt_tokens, bs) for s in self.running
         )
 
+    def _running_prefill_computes_next_page(
+        self, seq: Sequence, toks: List[int], matched: int
+    ) -> bool:
+        """Whether ``seq`` should wait a step: some running row still in its
+        prefill shares ``seq``'s tokens past the ``matched`` pages the cache
+        gave it and has not computed those pages yet, so they will be
+        committed under the hashes ``seq`` asks for within a step or a few,
+        and computing them twice is waste. Worth a step's wait only where
+        the pages in the making are a fair share of a step (an eighth of
+        the prefill budget): two short prompts that happen to agree are
+        computed side by side, as they always were. Same tokens and salt is
+        the hash chain's own test, without hashing anything."""
+        if not self.allocator.enable_prefix_caching:
+            return False
+        bs = self.allocator.block_size
+        start = matched * bs
+        worth = max(bs, self.config.max_prefill_tokens // 8)
+        if start + worth > len(toks) - 1:
+            return False
+        salt = getattr(seq, "cache_salt", 0)
+        for r in self.running:
+            if (r.num_computed_tokens >= r.num_prompt_tokens
+                    or getattr(r, "cache_salt", 0) != salt):
+                continue
+            theirs = r.all_token_ids[:r.num_prompt_tokens]
+            if theirs[:start + bs] != toks[:start + bs]:
+                continue
+            common = next((n for n, (a, b) in enumerate(
+                zip(theirs[start:], toks[start:len(toks) - 1])) if a != b),
+                min(len(theirs), len(toks) - 1) - start)
+            pages = common // bs  # whole pages in the making, past the match
+            if pages * bs >= worth and r.num_computed_tokens < start + pages * bs:
+                return True
+        return False
+
     def _admit(self, out: SchedulerOutput) -> None:
         # ``swapped`` and ``waiting`` admit as one stamp-ordered FIFO.
         # Swap-in is gated by a worst-case page check so a blocked resume
@@ -541,6 +579,24 @@ class Scheduler:
                     seq.adopt_cached_prefix(blocks, hashes)
                     seq.num_computed_tokens = len(blocks) * self.allocator.block_size
                     seq.num_cached_prompt_tokens = seq.num_computed_tokens
+                if self._running_prefill_computes_next_page(
+                        seq, toks, len(blocks)):
+                    # A running row is about to compute the page this
+                    # sequence needs next (arrivals behind one uncached
+                    # prefix): admitted now it would compute the same
+                    # tokens again and hold a second copy of their pages.
+                    # It waits for that row's commit and takes the pages
+                    # from the cache, and nobody jumps the line meanwhile.
+                    # The attempt is taken back whole, its counts too.
+                    if seq.block_ids:
+                        self.allocator.release_sequence(seq)
+                        seq.reset_for_recompute()
+                        seq.status = SequenceStatus.WAITING
+                    self.allocator.query_tokens -= len(matchable)
+                    self.allocator.hit_tokens -= (
+                        len(blocks) * self.allocator.block_size)
+                    self.prefix_waits += 1
+                    break
             # Admission requires pages for the FULL prompt (vLLM-style), not
             # just the first chunk: chunk-level admission of a long prompt
             # overcommits the pool, and its later chunks then preempt

@@ -197,6 +197,29 @@ class EngineMetrics:
             "decode bursts dispatched, synchronous or pipelined: the "
             "denominator of pst:pipelined_bursts",
         )
+        # A looped stack (PERF.md §3): layers run by the host's count, and
+        # the layers of pages under them.
+        self.decode_layer_passes = counter(
+            "pst:decode_layer_passes",
+            "layers run by decode dispatches: the stack's passes x its "
+            "layers x the burst's depth, summed over dispatches",
+        )
+        self.prefill_layer_passes = counter(
+            "pst:prefill_layer_passes",
+            "layers run by prefill steps: the stack's passes x its layers, "
+            "summed over steps",
+        )
+        self.kv_slot_layers = gauge(
+            "pst:kv_slot_layers",
+            "layers of pages a token holds: the layers with a cache, times "
+            "the passes of a looped stack",
+        )
+        self.prefix_waits = counter(
+            "pst:prefix_waits",
+            "admission attempts held back a step because a running row was "
+            "computing the pages the sequence needs next (arrivals behind "
+            "one uncached prefix compute it once)",
+        )
         # A model with recurrent layers and an expert share (PERF.md §3).
         self.state_slots_in_use = gauge(
             "pst:state_slots_in_use",
@@ -438,6 +461,7 @@ class EngineMetrics:
             self.decode_dispatches, "decode_dispatches",
             stats.get("decode_dispatches_total", 0),
         )
+        self.kv_slot_layers.set(stats.get("kv_slot_layers", 0))
         self.state_slots_in_use.set(stats.get("state_slots_in_use", 0))
         self.kv_pages_in_use("global").set(stats.get("kv_pages_in_use", 0))
         if "window_pages_in_use" in stats:
@@ -445,6 +469,9 @@ class EngineMetrics:
             self.window_pages_cached.set(stats["window_pages_cached"])
         for metric, key in (
             (self.state_slot_waits, "state_slot_waits_total"),
+            (self.decode_layer_passes, "decode_layer_passes_total"),
+            (self.prefix_waits, "prefix_waits_total"),
+            (self.prefill_layer_passes, "prefill_layer_passes_total"),
             (self.window_pages_released, "window_pages_released_total"),
             (self.window_prefix_tokens_lost,
              "window_prefix_tokens_lost_total"),

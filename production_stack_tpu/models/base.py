@@ -42,6 +42,9 @@ class ModelConfig:
     latent_pages = False  # a page is one latent row a token, not K and V
     window_pages = False  # a page group released below ``sliding_window``
     wide_head_pages = False  # heads wider than the paged kernels' one-byte path
+    # More layers of pages than of weights: a step runs the layer stack
+    # ``num_kv_layers // num_layers`` times, each pass on slots of its own.
+    looped = False
     # Layers of matrix-valued state whose slots a decode step reads and
     # writes whole: what a step's trace record says its kernel's bytes follow.
     num_state_layers = 0
@@ -126,9 +129,10 @@ def init_leaf(name: str, shape, dtype, key: jax.Array) -> jax.Array:
     init never holds the full bf16 tree anywhere."""
     if "norm" in name:
         return jnp.ones(shape, dtype)
-    if name.startswith(("b", "lora_")):
+    if name.startswith(("b", "lora_")) or not shape:  # a scalar is a bias
         return jnp.zeros(shape, dtype)
-    fan_in = shape[-1] if name in QUANT_TOP_KEYS else shape[-2]
+    fan_in = (shape[-1] if name in QUANT_TOP_KEYS or len(shape) == 1
+              else shape[-2])
     return (
         jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)
     ).astype(dtype)

@@ -377,11 +377,28 @@ class LlamaConfig(ModelConfig):
     # global and the rest are local (Gemma-2: N=2); 1 = all layers local.
     sliding_window: int = 0
     sliding_window_pattern: int = 1
+    # A looped stack (HF ``total_ut_steps``): a step runs the ``num_layers``
+    # layers this many times over one set of weights, pass ``t`` writing and
+    # reading cache slot ``t * num_layers + layer``, with the final norm at
+    # the end of every pass. 1 = a plain stack, and no loop is traced.
+    ut_steps: int = 1
+    # The looped model's exit gate (``Linear(D, 1)`` on each pass's output)
+    # is among the parameters; nothing served reads it (see ``init_params``).
+    exit_gate: bool = False
     dtype: str = "bfloat16"
     # Serving identity / tokenizer hints (not part of the math).
     name: str = "llama"
     eos_token_ids: Tuple[int, ...] = (2,)
     bos_token_id: Optional[int] = 1
+
+    @property
+    def num_kv_layers(self) -> int:
+        """Cache slots: a looped stack keeps one layer of pages a pass."""
+        return self.num_layers * self.ut_steps
+
+    @property
+    def looped(self) -> bool:
+        return self.ut_steps > 1
 
     @property
     def attn_scale(self) -> float:
@@ -459,6 +476,14 @@ class Llama(Model):
             params["layers"]["post_mlp_norm"] = jnp.ones((L, D), d)
         if not cfg.tie_word_embeddings:
             params["lm_head"] = dense(k[0], (cfg.vocab_size, D), D)
+        if cfg.exit_gate:
+            # The exit gate, ``lambda_t = sigmoid(w . h_t + b)``: held so that
+            # the parameter count is the model's. ``forward`` does not read
+            # it: at ``early_exit_threshold`` 1, the only one
+            # ``config_from_hf`` lets in, every token leaves at the last pass.
+            params["exit_gate_w"] = dense(
+                jax.random.fold_in(rng, 9), (D,), D)
+            params["exit_gate_b"] = jnp.zeros((), d)
         return params
 
     def param_pspecs(
@@ -520,6 +545,9 @@ class Llama(Model):
             specs["layers"]["post_mlp_norm"] = P(pp, None)
         if not self.cfg.tie_word_embeddings:
             specs["lm_head"] = P(None, AXIS_TENSOR)
+        if self.cfg.exit_gate:
+            specs["exit_gate_w"] = P(None)
+            specs["exit_gate_b"] = P()
         if mode:
             # int8 scale spec = weight spec minus the reduced (input) axis:
             # the scale shards exactly like its weight's output channels.
@@ -594,7 +622,8 @@ class Llama(Model):
     def make_kv_cache(
         self, num_blocks: int, block_size: int, dtype: Optional[str] = None
     ) -> jax.Array:
-        # One combined array [L, nb, 2, bs, KH*hd]: a page holds its K rows
+        # One combined array [L, nb, 2, bs, KH*hd] (L the cache slots: the
+        # layers, times the passes of a looped stack): a page holds its K rows
         # (index 0 of dim 2) then V rows (index 1), each token row spanning
         # all kv heads in the lane dimension. One DMA moves a whole page in
         # the pallas kernel, the write path is a single scatter, and the
@@ -603,7 +632,7 @@ class Llama(Model):
         # physically double the cache.
         cfg = self.cfg
         shape = (
-            cfg.num_layers, num_blocks, 2, block_size,
+            cfg.num_kv_layers, num_blocks, 2, block_size,
             cfg.num_kv_heads * cfg.head_dim,
         )
         d = jnp.dtype(dtype) if dtype else cfg.jdtype
@@ -688,7 +717,9 @@ class Llama(Model):
             # ctx: traced arrays shared by every layer. Threaded explicitly
             # (not closed over) so the pp shard_map can pass them through.
             # kv_all: the FULL stacked cache [L, nb, 2, bs, KH*hd]; li is
-            # this layer's index into it. The cache is never sliced — the
+            # this layer's index into it (its cache slot: under a looped
+            # stack the pass's offset is in it, while ``lp`` and
+            # ``li_global`` are the layer's). The cache is never sliced — the
             # attention kernel takes (cache, layer) and reads only the live
             # pages, and the write is a scatter at layer-offset rows, so the
             # carried buffer updates in place (a per-layer slice/update pair
@@ -702,6 +733,14 @@ class Llama(Model):
                 q = q + lora_delta(lp, "wq", h).astype(q.dtype)
                 k = k + lora_delta(lp, "wk", h).astype(k.dtype)
                 v = v + lora_delta(lp, "wv", h).astype(v.dtype)
+            if cfg.ut_steps > 1:
+                # The barrier keeps the projections' rows as the products
+                # leave them: left to XLA, the reshape to heads below turns
+                # the whole bf16 ``wq`` and ``wk`` stacks instead, once a step
+                # (2 x 384 MiB copied and held at the looped cell's widths:
+                # PERF.md §6, PR 49). Under the loop alone, so that a plain
+                # stack's programs stay what they were.
+                q, k, v = jax.lax.optimization_barrier((q, k, v))
             q = q.reshape(B, T, cfg.num_heads, cfg.head_dim)
             k = k.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
             v = v.reshape(B, T, cfg.num_kv_heads, cfg.head_dim)
@@ -794,7 +833,8 @@ class Llama(Model):
             x = x + ff
             return x, kv_all
 
-        def scan_layers(ctx, x, kv_all, layers, n_layers, li_base=0):
+        def scan_layers(ctx, x, kv_all, layers, n_layers, li_base=0,
+                        slot_base=None):
             # The cache rides the scan CARRY — carried while-loop buffers
             # alias across iterations, so peak HBM holds ONE cache. (As scan
             # xs/ys the stacked outputs would be a second full-size
@@ -803,14 +843,17 @@ class Llama(Model):
             # layer_fn), nor the int4 weights the stacked kernel serves:
             # only ``sliced`` is scan xs; ``whole`` is loop-invariant and
             # indexed by ``i``. ``li_base`` is the stage's global layer offset
-            # (nonzero under pp, where the scan index is stage-local).
+            # (nonzero under pp, where the scan index is stage-local);
+            # ``slot_base`` the first cache slot of a looped stack's pass
+            # (None for a plain stack: the slot is the layer).
             sliced, whole = split_kernel_int4(layers)
 
             def body(carry, sl):
                 x, kv_all = carry
                 lp, i = sl
                 lp = _layer_params(lp, whole, i)
-                x, kv_all = layer_fn(ctx, x, kv_all, lp, i, li_base + i)
+                slot = i if slot_base is None else slot_base + i
+                x, kv_all = layer_fn(ctx, x, kv_all, lp, slot, li_base + i)
                 return (x, kv_all), None
 
             (x, kv_all), _ = jax.lax.scan(
@@ -821,7 +864,26 @@ class Llama(Model):
 
         ctx = (flat_write_real, rope_cos, rope_sin, block_tables, kv_lens,
                positions)
-        if pp_size > 1:
+        if cfg.ut_steps > 1:
+            if pp_size > 1:
+                raise ValueError(
+                    "a looped stack (ut_steps > 1) is not staged: every "
+                    "stage would be visited ut_steps times a step")
+
+            # One rolled loop around one layer scan (the program holds one
+            # layer body, not ut_steps scans end to end), the cache in the
+            # carry; the final norm closes every pass, so the last pass's
+            # output is already normalised for the head.
+            def one_pass(t, carry):
+                x, kv_all = scan_layers(
+                    ctx, *carry, params["layers"], cfg.num_layers,
+                    slot_base=t * cfg.num_layers)
+                return _rms_norm(x, params["final_norm"], cfg.rms_norm_eps,
+                                 offset), kv_all
+
+            x, kv_cache = jax.lax.fori_loop(
+                0, cfg.ut_steps, one_pass, (x, kv_cache))
+        elif pp_size > 1:
             def run_stage(x, repl, scanned_local, gate):
                 fw, *rest = repl
                 # Suppress cache writes on garbage (rotated) lanes: only the
@@ -845,7 +907,8 @@ class Llama(Model):
                 ctx, x, kv_cache, params["layers"], cfg.num_layers
             )
 
-        x = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps, offset)
+        if cfg.ut_steps == 1:
+            x = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps, offset)
         head = "lm_head" if "lm_head" in params else "embed"
         unembed = _wcast(params[head], x.dtype)  # [V, D]
         uqs = params.get(head + QUANT_SUFFIX)
@@ -885,6 +948,10 @@ class Llama(Model):
         encode across the sp group.
         """
         cfg = self.cfg
+        if cfg.ut_steps > 1:
+            raise ValueError(
+                "the embeddings path is not built for a looped stack "
+                "(ut_steps > 1): it would pool one pass's hidden states")
         B, T = tokens.shape
         use_ring = sp_size > 1 and mesh is not None
         if use_ring and pp_size > 1:
@@ -1321,12 +1388,23 @@ def load_hf_params(
     params["final_norm"] = cast(raw.pop("model.norm.weight"))
     if "lm_head.weight" in raw:
         put_top("lm_head", raw.pop("lm_head.weight"))
+    if cfg.exit_gate:
+        params["exit_gate_w"] = cast(
+            raw.pop("model.early_exit_gate.weight").reshape(-1))
+        params["exit_gate_b"] = cast(
+            raw.pop("model.early_exit_gate.bias").reshape(()))
 
     layer_map = dict(_HF_LAYER_MAP)
     if cfg.qk_norm:
         layer_map["self_attn.q_norm"] = "q_norm"
         layer_map["self_attn.k_norm"] = "k_norm"
-    if cfg.post_block_norms:
+    if cfg.post_block_norms and "model.layers.0.input_layernorm_2.weight" in raw:
+        # The looped model's names for the four-norm block: each sub-block's
+        # second norm is on its output (``post_attention_layernorm`` stays
+        # the MLP's pre-norm, as in Llama).
+        layer_map["input_layernorm_2"] = "post_attn_norm"
+        layer_map["post_attention_layernorm_2"] = "post_mlp_norm"
+    elif cfg.post_block_norms:
         # Gemma-2 norm layout: post_attention_layernorm is the POST-attn
         # norm (not the MLP pre-norm as in Llama), and the MLP has its own
         # pre/post pair.
@@ -1400,9 +1478,24 @@ def config_from_hf_json(config_path: str, name: str = ""):
 
 def config_from_hf(hf: dict, name: str = "") -> LlamaConfig:
     """The Llama-family keys of an HF ``config.json`` (``model_type`` one
-    of llama, mistral, qwen2, qwen3, mixtral, gemma, gemma2: the rows of
-    ``models/registry.py::MODEL_TYPES`` that name this reader)."""
+    of llama, mistral, qwen2, qwen3, mixtral, gemma, gemma2, ouro: the rows
+    of ``models/registry.py::MODEL_TYPES`` that name this reader)."""
     mt = hf.get("model_type", "llama")
+    looped = mt == "ouro"
+    if looped:
+        # Served: every token takes every pass (the published threshold).
+        # Below 1 a token leaves at the first pass whose cumulative exit
+        # probability reaches the threshold, per token: not built.
+        if float(hf.get("early_exit_threshold", 1)) != 1:
+            raise ValueError(
+                f"early_exit_threshold {hf['early_exit_threshold']!r} is not "
+                "served: per-token early exit is not built (only 1, every "
+                "token through all total_ut_steps passes)")
+        if hf.get("use_sliding_window") or hf.get("attention_bias"):
+            raise ValueError(
+                "model_type 'ouro' is served without a sliding window and "
+                "without projection biases (use_sliding_window, "
+                "attention_bias)")
     eos = hf.get("eos_token_id", 2)
     eos_ids = tuple(eos) if isinstance(eos, list) else (eos,)
     heads = hf["num_attention_heads"]
@@ -1455,7 +1548,12 @@ def config_from_hf(hf: dict, name: str = "") -> LlamaConfig:
         if mt == "gemma2" else 0.0,
         final_logit_softcap=float(hf.get("final_logit_softcapping") or 0.0)
         if mt == "gemma2" else 0.0,
-        post_block_norms=mt == "gemma2",
+        post_block_norms=mt == "gemma2" or looped,
+        ut_steps=int(hf.get("total_ut_steps", 1)) if looped else 1,
+        exit_gate=looped,
+        # read for this type alone: every other type keeps the default
+        # whatever its file says, as it always has
+        dtype=(hf.get("torch_dtype") if looped else None) or "bfloat16",
         sliding_window=sliding,
         sliding_window_pattern=2 if mt == "gemma2" else 1,
         name=name or hf.get("_name_or_path", mt),

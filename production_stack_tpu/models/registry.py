@@ -27,7 +27,8 @@ from .qwen3_next import Qwen3NextConfig
 # ``engine/config.py::_refusals`` only for a new kind of page).
 MODEL_TYPES: Dict[str, Tuple[Callable[[dict, str], ModelConfig], type, type]] = {
     **{mt: (llama.config_from_hf, LlamaConfig, llama.Llama) for mt in (
-        "llama", "mistral", "qwen2", "qwen3", "mixtral", "gemma", "gemma2")},
+        "llama", "mistral", "qwen2", "qwen3", "mixtral", "gemma", "gemma2",
+        "ouro")},
     "nemotron_h": (
         nemotron_h.config_from_hf, NemotronHConfig, nemotron_h.NemotronH),
     "glm4_moe_lite": (
@@ -280,6 +281,27 @@ PRESETS: Dict[str, ModelConfig] = {
         name="qwen2-7b",
         eos_token_ids=(151645, 151643),
         bos_token_id=None,
+    ),
+    # Tiny looped stack: three layers run twice over one set of weights (six
+    # cache slots), the four-norm block, one key-value head a query head.
+    "tiny-ouro-debug": LlamaConfig(
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=96,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=16,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-6,
+        max_position_embeddings=2048,
+        post_block_norms=True,
+        ut_steps=2,
+        exit_gate=True,
+        name="tiny-ouro-debug",
+        eos_token_ids=(0,),
+        bos_token_id=None,
+        dtype="float32",
     ),
     # Tiny hybrid (state-space + attention + latent MoE) debug model:
     # an expert-parallel share of 4 of 16 experts from expert 4 on.

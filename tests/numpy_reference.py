@@ -177,47 +177,50 @@ def ref_decoder_forward(cfg, params, token_ids, kv_quant=None):
     G = cfg.num_heads // cfg.num_kv_heads
     scale = 1.0 / math.sqrt(cfg.query_pre_attn_scalar or cfg.head_dim)
 
-    for li in range(cfg.num_layers):
-        h = _rms(x, lp["attn_norm"][li], cfg.rms_norm_eps, offset)
-        q = h @ lp["wq"][li]
-        k = h @ lp["wk"][li]
-        v = h @ lp["wv"][li]
-        if "bq" in lp:
-            q, k, v = q + lp["bq"][li], k + lp["bk"][li], v + lp["bv"][li]
-        q = q.reshape(T, cfg.num_heads, cfg.head_dim)
-        k = k.reshape(T, cfg.num_kv_heads, cfg.head_dim)
-        v = v.reshape(T, cfg.num_kv_heads, cfg.head_dim)
-        if "q_norm" in lp:  # Qwen3: per-head RMS over hd, pre-rope
-            q = _rms(q, lp["q_norm"][li], cfg.rms_norm_eps)
-            k = _rms(k, lp["k_norm"][li], cfg.rms_norm_eps)
-        q = _apply_rope(q, cos, sin)
-        k = _apply_rope(k, cos, sin)
-        if kv_quant is not None:
-            k, v = kv_quant(k), kv_quant(v)
-        # GQA: query head hq reads kv head hq // G.
-        kq = np.repeat(k, G, axis=1)  # [T, H, hd]
-        vq = np.repeat(v, G, axis=1)
-        scores = np.einsum("thd,shd->hts", q, kq) * scale
-        scores = _softcap(scores, cfg.attn_logit_softcap)
-        mask = positions[None, :] <= positions[:, None]  # causal [T, S]
-        win = _layer_window(cfg, li)
-        if win:
-            mask = mask & (positions[None, :] > positions[:, None] - win)
-        scores = np.where(mask[None], scores, -1e30)
-        z = scores - scores.max(-1, keepdims=True)
-        probs = np.exp(z) / np.exp(z).sum(-1, keepdims=True)
-        attn = np.einsum("hts,shd->thd", probs, vq).reshape(T, -1)
-        o = attn @ lp["wo"][li]
-        if cfg.post_block_norms:
-            o = _rms(o, lp["post_attn_norm"][li], cfg.rms_norm_eps, offset)
-        x = x + o
-        h = _rms(x, lp["mlp_norm"][li], cfg.rms_norm_eps, offset)
-        ff = _mlp(cfg, lp, li, h)
-        if cfg.post_block_norms:
-            ff = _rms(ff, lp["post_mlp_norm"][li], cfg.rms_norm_eps, offset)
-        x = x + ff
+    # A looped stack (``ut_steps`` > 1) runs the same layers that many times,
+    # the final norm closing every pass; a plain stack is one pass.
+    for _ in range(cfg.ut_steps):
+        for li in range(cfg.num_layers):
+            h = _rms(x, lp["attn_norm"][li], cfg.rms_norm_eps, offset)
+            q = h @ lp["wq"][li]
+            k = h @ lp["wk"][li]
+            v = h @ lp["wv"][li]
+            if "bq" in lp:
+                q, k, v = q + lp["bq"][li], k + lp["bk"][li], v + lp["bv"][li]
+            q = q.reshape(T, cfg.num_heads, cfg.head_dim)
+            k = k.reshape(T, cfg.num_kv_heads, cfg.head_dim)
+            v = v.reshape(T, cfg.num_kv_heads, cfg.head_dim)
+            if "q_norm" in lp:  # Qwen3: per-head RMS over hd, pre-rope
+                q = _rms(q, lp["q_norm"][li], cfg.rms_norm_eps)
+                k = _rms(k, lp["k_norm"][li], cfg.rms_norm_eps)
+            q = _apply_rope(q, cos, sin)
+            k = _apply_rope(k, cos, sin)
+            if kv_quant is not None:
+                k, v = kv_quant(k), kv_quant(v)
+            # GQA: query head hq reads kv head hq // G.
+            kq = np.repeat(k, G, axis=1)  # [T, H, hd]
+            vq = np.repeat(v, G, axis=1)
+            scores = np.einsum("thd,shd->hts", q, kq) * scale
+            scores = _softcap(scores, cfg.attn_logit_softcap)
+            mask = positions[None, :] <= positions[:, None]  # causal [T, S]
+            win = _layer_window(cfg, li)
+            if win:
+                mask = mask & (positions[None, :] > positions[:, None] - win)
+            scores = np.where(mask[None], scores, -1e30)
+            z = scores - scores.max(-1, keepdims=True)
+            probs = np.exp(z) / np.exp(z).sum(-1, keepdims=True)
+            attn = np.einsum("hts,shd->thd", probs, vq).reshape(T, -1)
+            o = attn @ lp["wo"][li]
+            if cfg.post_block_norms:
+                o = _rms(o, lp["post_attn_norm"][li], cfg.rms_norm_eps, offset)
+            x = x + o
+            h = _rms(x, lp["mlp_norm"][li], cfg.rms_norm_eps, offset)
+            ff = _mlp(cfg, lp, li, h)
+            if cfg.post_block_norms:
+                ff = _rms(ff, lp["post_mlp_norm"][li], cfg.rms_norm_eps, offset)
+            x = x + ff
 
-    x = _rms(x, params["final_norm"], cfg.rms_norm_eps, offset)
+        x = _rms(x, params["final_norm"], cfg.rms_norm_eps, offset)
     head = params.get("lm_head", params["embed"])
     logits = x @ head.T
     return _softcap(logits, cfg.final_logit_softcap)

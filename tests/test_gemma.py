@@ -1,5 +1,8 @@
 """Gemma-family correctness (Gemma-1 GeGLU/norm/embedding conventions,
-Gemma-2 softcaps, post-block norms, alternating sliding-window layers).
+Gemma-2 softcaps, post-block norms, alternating sliding-window layers), and
+the four-norm block's second user: a looped stack (``tiny-ouro-debug``: the
+same block without the unit offset, run ``ut_steps`` = 2 times over one set
+of weights), which the naive reference here follows by ``cfg.ut_steps``.
 
 Same ring-1 strategy as ``test_engine_core``: an independent naive
 full-attention reference reimplements the Gemma math directly (no shared
@@ -61,34 +64,35 @@ def naive_forward(cfg, params, token_ids):
         return jnp.tanh(s / c) * c if c else s
 
     lp = params["layers"]
-    for i in range(cfg.num_layers):
-        h = rms(x, lp["attn_norm"][i])
-        q = (h @ lp["wq"][i]).reshape(T, cfg.num_heads, cfg.head_dim)
-        k = (h @ lp["wk"][i]).reshape(T, cfg.num_kv_heads, cfg.head_dim)
-        v = (h @ lp["wv"][i]).reshape(T, cfg.num_kv_heads, cfg.head_dim)
-        q, k = rope(q), rope(k)
-        G = cfg.num_heads // cfg.num_kv_heads
-        k = jnp.repeat(k, G, axis=1)
-        v = jnp.repeat(v, G, axis=1)
-        scores = jnp.einsum("thd,shd->hts", q, k) * cfg.attn_scale
-        scores = cap(scores, cfg.attn_logit_softcap)
-        mask = pos[None, :] <= pos[:, None]
-        win = int(_layer_window(cfg, i))
-        if win:
-            mask = mask & (pos[None, :] > pos[:, None] - win)
-        scores = jnp.where(mask[None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        attn = jnp.einsum("hts,shd->thd", probs, v).reshape(T, -1)
-        o = attn @ lp["wo"][i]
-        if cfg.post_block_norms:
-            o = rms(o, lp["post_attn_norm"][i])
-        x = x + o
-        h = rms(x, lp["mlp_norm"][i])
-        ff = (act(h @ lp["w_gate"][i]) * (h @ lp["w_up"][i])) @ lp["w_down"][i]
-        if cfg.post_block_norms:
-            ff = rms(ff, lp["post_mlp_norm"][i])
-        x = x + ff
-    x = rms(x, params["final_norm"])
+    for _ in range(cfg.ut_steps):  # a looped stack: the final norm every pass
+        for i in range(cfg.num_layers):
+            h = rms(x, lp["attn_norm"][i])
+            q = (h @ lp["wq"][i]).reshape(T, cfg.num_heads, cfg.head_dim)
+            k = (h @ lp["wk"][i]).reshape(T, cfg.num_kv_heads, cfg.head_dim)
+            v = (h @ lp["wv"][i]).reshape(T, cfg.num_kv_heads, cfg.head_dim)
+            q, k = rope(q), rope(k)
+            G = cfg.num_heads // cfg.num_kv_heads
+            k = jnp.repeat(k, G, axis=1)
+            v = jnp.repeat(v, G, axis=1)
+            scores = jnp.einsum("thd,shd->hts", q, k) * cfg.attn_scale
+            scores = cap(scores, cfg.attn_logit_softcap)
+            mask = pos[None, :] <= pos[:, None]
+            win = int(_layer_window(cfg, i))
+            if win:
+                mask = mask & (pos[None, :] > pos[:, None] - win)
+            scores = jnp.where(mask[None], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1)
+            attn = jnp.einsum("hts,shd->thd", probs, v).reshape(T, -1)
+            o = attn @ lp["wo"][i]
+            if cfg.post_block_norms:
+                o = rms(o, lp["post_attn_norm"][i])
+            x = x + o
+            h = rms(x, lp["mlp_norm"][i])
+            ff = (act(h @ lp["w_gate"][i]) * (h @ lp["w_up"][i])) @ lp["w_down"][i]
+            if cfg.post_block_norms:
+                ff = rms(ff, lp["post_mlp_norm"][i])
+            x = x + ff
+        x = rms(x, params["final_norm"])
     unembed = params.get("lm_head", params["embed"])
     return cap(x @ unembed.T, cfg.final_logit_softcap)
 
@@ -143,13 +147,15 @@ def test_layer_window_pattern():
     assert int(_layer_window(cfg1, 0)) == 0  # no sliding window configured
 
 
-@pytest.mark.parametrize("model", ["tiny-gemma-debug", "tiny-gemma2-debug"])
+@pytest.mark.parametrize(
+    "model", ["tiny-gemma-debug", "tiny-gemma2-debug", "tiny-ouro-debug"])
 def test_engine_greedy_matches_naive(model):
     eng = make_engine(model)
     cfg = PRESETS[model]
     params = jax.device_get(eng.runner.params)
-    expected = naive_greedy(cfg, params, PROMPT, 12)
-    got = run_greedy(eng, "g0", PROMPT, 12)
+    prompt = [t % cfg.vocab_size for t in PROMPT]
+    expected = naive_greedy(cfg, params, prompt, 12)
+    got = run_greedy(eng, "g0", prompt, 12)
     assert got == expected
 
 
@@ -162,6 +168,19 @@ def test_gemma2_chunked_prefill_matches():
     expected = naive_greedy(cfg, params, PROMPT, 6)
     got = run_greedy(eng, "g1", PROMPT, 6)
     assert got == expected
+
+
+def test_the_looped_four_norm_block_in_chunks_matches_naive():
+    """The loop at ``ut_steps`` 2 through 8-token prefill chunks: a later
+    chunk reads the earlier ones' pages of both passes."""
+    eng = make_engine("tiny-ouro-debug", max_prefill_tokens=8)
+    cfg = PRESETS["tiny-ouro-debug"]
+    assert cfg.ut_steps == 2 and cfg.post_block_norms
+    assert not cfg.norm_unit_offset
+    params = jax.device_get(eng.runner.params)
+    tokens = [t % cfg.vocab_size for t in PROMPT]
+    assert run_greedy(eng, "o1", tokens, 6) == naive_greedy(
+        cfg, params, tokens, 6)
 
 
 def test_gemma2_tensor_parallel_matches():

@@ -36,6 +36,7 @@ CONFIG_JSON = {
     "phi4flash": "perf/configs/phi-4-mini-flash.json",
     "qwen3_next": "perf/configs/qwen3-next-ep8-cut.json",
     "mellum": "perf/configs/mellum2-ep4-cut.json",
+    "ouro": "perf/configs/ouro-2.6b.json",
 }
 CONFIG_CLASSES = sorted({row[1] for row in MODEL_TYPES.values()},
                         key=lambda c: c.__name__)
@@ -95,6 +96,10 @@ WEIGHT_DIGESTS = {
     # recorded when the class was added (PR 46)
     "tiny-mellum-debug":
         "68272915cccf68df88fd38bc5ccce1e14c732244c56bf70144f54fd7e610492b",
+    # the dense class under the loop, the exit gate's two leaves among them:
+    # recorded when the model type was added (PR 49)
+    "tiny-ouro-debug":
+        "2f5101ef1166a85ab4cf09f9451578f0a6ccfeb337080be3e4c59f775c87edc1",
 }
 
 
@@ -164,7 +169,7 @@ def test_every_class_has_what_the_runner_reads_off_a_model(model_cls):
 
 
 @pytest.mark.parametrize("preset", sorted(set(WEIGHT_DIGESTS) - {
-    "tiny-llama-debug"}))
+    "tiny-llama-debug", "tiny-ouro-debug"}))
 def test_the_default_shardings_follow_the_trees_the_class_makes(preset):
     model = registry.model_for(PRESETS[preset])
     is_spec = lambda x: isinstance(x, P)  # noqa: E731

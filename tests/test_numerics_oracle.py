@@ -73,6 +73,10 @@ FAMILIES = {
     # Gemma 2: softcaps, post-block norms, alternating sliding windows,
     # query_pre_attn_scalar.
     "gemma2": _variant("tiny-gemma2-debug"),
+    # A looped stack: three layers run twice over one set of weights (cache
+    # slots pass x layers + layer), the four-norm block without the unit
+    # offset, the final norm closing every pass.
+    "ouro": _variant("tiny-ouro-debug"),
 }
 
 

@@ -768,3 +768,53 @@ def test_the_window_mix_decode_program_copies_no_weight_stack(one_chip, monkeypa
     assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
     for kernel in ("paged_attn_decode", "gmm"):
         assert kernel in text
+
+
+@pytest.mark.parametrize("B,T", [(16, 1), (1, 1024)])
+def test_the_looped_step_program_copies_no_weight_stack(B, T, one_chip, monkeypatch):
+    """A 16-row decode step and a 1,024-token prefill step of the looped
+    dense cell's configuration, whole, compiled for the described chip
+    (PR 49): Mosaic takes 32-token pages of 2,048 lanes with one key-value
+    head a query head; the program holds one loop of passes around one loop
+    of layers; and, left to XLA, the reshape of the query and key
+    projections to heads turned the whole bf16 ``wq`` and ``wk`` stacks
+    instead of the rows, once a step: 2 x 384 MiB copied and held beside
+    15.0 GB of arguments (``Llama.forward`` holds the projections behind an
+    ``optimization_barrier`` under the loop)."""
+    from production_stack_tpu.models import llama
+    from production_stack_tpu.ops import paged_attention_pallas as pap
+
+    monkeypatch.setattr(pap, "pallas_interpret", lambda: False)
+    with open("perf/configs/ouro-2.6b.json") as f:
+        cfg = llama.config_from_hf(json.load(f), "ouro-2.6b")
+    model = llama.Llama(cfg)
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.make_kv_cache(192, 32)))
+    assert cache.shape == (192, 192, 2, 32, 2048)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+
+    def step(params, tokens, positions, write_idx, tables, kv_lens, last_idx,
+             cache):
+        return model.forward(
+            params, tokens, positions, write_idx, tables, kv_lens, last_idx,
+            cache, attn_impl="pallas")
+
+    with jax.disable_jit(False):
+        compiled = jax.jit(step, donate_argnums=(7,)).lower(
+            params, i32(B, T), i32(B, T), i32(B, T), i32(B, 64), i32(B),
+            i32(B), cache).compile()
+    text = compiled.as_text()
+    results = re.findall(
+        r"^\s*(?:ROOT )?(%\S+) = (\(.*?\)|\S+) ([\w\-]+)\(", text, re.M)
+    big = ("bf16[48,2048,", "bf16[48,5632,", "bf16[192,192,")
+    copies = [name for name, result, opcode in results
+              if opcode in ("copy", "transpose") and result.startswith(big)]
+    assert not copies
+    assert sum(1 for _, _, opcode in results if opcode == "while") == 2
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 << 20
+    assert 14.9e9 < memory.argument_size_in_bytes < 15.1e9  # 5.34 + 9.66 GB
+    assert "paged_attn_decode" in text or T > 1
