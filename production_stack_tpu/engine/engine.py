@@ -1276,6 +1276,10 @@ class LLMEngine:
             self.decode_layer_passes_total)
         out["prefill_layer_passes_total"] = float(
             self.runner.prefill_layer_passes_total)
+        out["decode_context_tokens_total"] = float(
+            self.runner.decode_context_tokens_total)
+        out["decode_shared_tokens_spared_total"] = float(
+            self.runner.decode_shared_tokens_spared_total)
         out["kv_slot_layers"] = float(self.model_cfg.num_kv_layers)
         out["prefix_waits_total"] = float(self.scheduler.prefix_waits)
         if self.runner.state_slots:

@@ -42,7 +42,7 @@ from ..models.llama import (
     quantize_leaf_int4,
 )
 from ..models.registry import get_model_config, model_for
-from ..ops.attention import resolve_attn_impl
+from ..ops.attention import decode_sharing_calls, resolve_attn_impl
 from ..ops.sampling import (
     apply_allowed_mask,
     apply_logit_bias,
@@ -110,6 +110,36 @@ def _fetch(arr, kind: str = "") -> np.ndarray:
         return np.asarray(jax.device_get(arr))
 
 
+def shared_prefix_run(
+    tables: np.ndarray, kv_lens: np.ndarray, block_size: int
+) -> "tuple[int, int]":
+    """(pages, rows): the leading pages every live row of a decode batch
+    holds in common, and the rows that share them (0 and 0 where nothing
+    is shared). A prefix-cache hit gives rows the same page ids, so this is
+    a comparison of the tables; rows with ``kv_len`` 0 (padding, a
+    finished member of a chain) are left out, and a row's last page, the
+    one it writes, is never counted. The decode kernel finds the same run
+    itself, once a call (``ops/paged_attention_pallas.py::_find_shared_run``),
+    and reads those pages once; here it is only counted."""
+    # Plain lists, no array operation: on the step thread, beside 16-64
+    # streaming responses, each array operation is a chance to lose the
+    # interpreter lock (0.2-0.4 ms a step measured so: PERF.md §7).
+    lens = kv_lens.tolist()
+    live = [i for i, n in enumerate(lens) if n > 0]
+    if len(live) < 2:
+        return 0, 0
+    cap = (min(lens[i] for i in live) - 1) // block_size
+    first = tables[:, 0].tolist()
+    if cap <= 0 or any(first[i] != first[live[0]] for i in live):
+        return 0, 0  # most batches: nothing shared
+    held = tables[:, :cap].tolist()
+    named = held[live[0]]
+    for at in range(1, cap):
+        if any(held[i][at] != named[at] for i in live):
+            return at, len(live)
+    return cap, len(live)
+
+
 def _seed_for(seq: Sequence, ahead: int = 0) -> int:
     """The seed of ``seq``'s next sample; ``ahead``: of the one that many
     tokens later."""
@@ -167,6 +197,12 @@ class ModelRunner:
         # what the same rows would hold were every page kept.
         self.window_page_steps_total = 0
         self.window_whole_context_page_steps_total = 0
+        # Context tokens the rows of decode dispatches held, a step of a
+        # burst each, beside those a dispatch's rows held in common pages
+        # and the kernel read once for all of them (`_step_info`).
+        self.decode_context_tokens_total = 0
+        self.decode_shared_tokens_spared_total = 0
+        self._sharing_calls: Dict[int, int] = {}  # by the bucket's rows
         # Rows a step appends to its packed tokens (the model's step_aux,
         # one for each name in its AUX_NAMES), summed here as they are
         # fetched.
@@ -1024,9 +1060,16 @@ class ModelRunner:
     ) -> None:
         """Tell the trace and the open step phase what this step is:
         ``kv_tokens`` sums ``kv_lens`` over the real rows after this step's
-        tokens (a burst writes ``kv_ahead`` more per row than its batch
-        says), ``kv_pages`` the pages those rows hold."""
+        tokens (``batch`` has a burst's lengths at its first step; a live
+        row holds ``kv_ahead`` more after its last), ``kv_pages`` the pages
+        those rows hold; of a decode dispatch, ``shared_kv_tokens`` the
+        tokens in the leading pages its ``shared_rows`` live rows hold in
+        common and the decode kernel reads once a call, which are in
+        ``kv_tokens`` once a row all the same."""
         n = len(seqs)
+        # (plain lists: see `shared_prefix_run`)
+        first = [x for x in batch["kv_lens"][:n].tolist() if x > 0]
+        kv_tokens = sum(first) + len(first) * kv_ahead
         # state_slots: rows whose recurrent state the step reads and writes
         slots = {"state_slots": n} if self._recurrent else {}
         if self.model_cfg.num_state_layers:
@@ -1038,8 +1081,7 @@ class ModelRunner:
             # window_tokens: what the window layers read, a row at most its
             # window; window_pages: what the rows hold in that group
             win = self.model_cfg.sliding_window
-            slots["window_tokens"] = int(
-                np.minimum(batch["kv_lens"][:n] + kv_ahead, win).sum())
+            slots["window_tokens"] = sum(min(x + kv_ahead, win) for x in first)
             whole = sum(len(s.window_block_ids) for s in seqs)
             slots["window_pages"] = whole - sum(s.window_released for s in seqs)
             self.window_page_steps_total += slots["window_pages"]
@@ -1048,9 +1090,33 @@ class ModelRunner:
             # a looped stack: the step reads its weights and writes a layer
             # of pages once a pass
             slots["passes"] = self.passes
+        if kind == "decode":
+            # What a layer that reads a row's whole context would read a
+            # walk a row, and what the kernel's shared phase spares of it:
+            # the run by the lengths of the dispatch's first step (a later
+            # step's may be a page longer), and only where the calls take
+            # it, by the rule they trace by (``decode_sharing_calls``).
+            depth, Bb = kv_ahead + 1, len(batch["kv_lens"])
+            calls = self._sharing_calls.get(Bb)
+            if calls is None:
+                cfg = self.model_cfg
+                calls = self._sharing_calls[Bb] = (
+                    0 if cfg.latent_pages else decode_sharing_calls(
+                        self._attn_impl, self.mesh, Bb,
+                        *cfg.paged_query_shape, cfg.global_window))
+            pages, rows = shared_prefix_run(
+                batch["block_tables"][:n], batch["kv_lens"][:n],
+                self.cfg.block_size,
+            ) if calls else (0, 0)
+            slots.update(shared_kv_tokens=pages * self.cfg.block_size,
+                         shared_rows=rows)
+            self.decode_context_tokens_total += (
+                sum(first) * depth + len(first) * depth * (depth - 1) // 2)
+            self.decode_shared_tokens_spared_total += (
+                max(rows - calls, 0) * slots["shared_kv_tokens"] * depth)
         ENGINE_TELEMETRY.step_info(
             kind, bucket=bucket, rows=n, new_tokens=new_tokens,
-            kv_tokens=int(batch["kv_lens"][:n].sum()) + n * kv_ahead,
+            kv_tokens=kv_tokens,
             kv_pages=sum(len(s.block_ids) for s in seqs),
             **slots,
         )
@@ -1471,14 +1537,16 @@ class ModelRunner:
             alive = sum(1 for s in members if not s.is_finished)
             if tel is not None:
                 # The host's view lags the device by the burst in flight:
-                # a live row holds 2n - 1 more tokens after this burst
-                # than its kv_len here says (a joining row's prefill is
-                # the program its view lags by).
+                # a live row holds n more tokens after this burst's first
+                # step than its kv_len here says, and 2n - 1 more after
+                # its last (a joining row's prefill is the program its
+                # view lags by).
                 n = tel[3]
                 self._step_info(
                     "decode", tel[1], members,
-                    {"kv_lens": np.where(kv_lens > 0, kv_lens + 2 * n - 1, 0)},
-                    alive * n,
+                    {"kv_lens": np.where(kv_lens > 0, kv_lens + n, 0),
+                     "block_tables": tables},
+                    alive * n, n - 1,
                 )
         t0 = time.perf_counter()
         with self._device_lock:

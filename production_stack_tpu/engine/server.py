@@ -204,6 +204,24 @@ class EngineMetrics:
             "layers run by decode dispatches: the stack's passes x its "
             "layers x the burst's depth, summed over dispatches",
         )
+        # Rows behind one prompt hold the same leading pages, and the decode
+        # kernel reads those once a call (PERF.md §3): spared / context is
+        # the share of a full-attention layer's context reads that did not
+        # happen.
+        self.decode_context_tokens = counter(
+            "pst:decode_context_tokens",
+            "context tokens the rows of decode dispatches held, a step of "
+            "a burst each: what a layer that attends to the whole context "
+            "reads on a walk a row",
+        )
+        self.decode_shared_tokens_spared = counter(
+            "pst:decode_shared_tokens_spared",
+            "of those, the tokens such a layer's decode calls did not read "
+            "because their rows held them in common pages, read once a "
+            "call: (sharing rows - calls) x shared tokens x the burst's "
+            "depth; 0 where the calls walk a row (the gather reference, a "
+            "shape or a window the kernel's shared phase does not take)",
+        )
         self.prefill_layer_passes = counter(
             "pst:prefill_layer_passes",
             "layers run by prefill steps: the stack's passes x its layers, "
@@ -470,6 +488,9 @@ class EngineMetrics:
         for metric, key in (
             (self.state_slot_waits, "state_slot_waits_total"),
             (self.decode_layer_passes, "decode_layer_passes_total"),
+            (self.decode_context_tokens, "decode_context_tokens_total"),
+            (self.decode_shared_tokens_spared,
+             "decode_shared_tokens_spared_total"),
             (self.prefix_waits, "prefix_waits_total"),
             (self.prefill_layer_passes, "prefill_layer_passes_total"),
             (self.window_pages_released, "window_pages_released_total"),

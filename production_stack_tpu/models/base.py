@@ -32,8 +32,8 @@ class ModelConfig:
 
     A config class is a frozen dataclass that inherits this and carries, as
     fields or properties of its own: ``vocab_size``, ``num_layers``,
-    ``num_kv_heads``, ``head_dim``, ``dtype``, ``name``, ``eos_token_ids``,
-    ``max_position_embeddings``.
+    ``num_heads``, ``num_kv_heads``, ``head_dim``, ``dtype``, ``name``,
+    ``eos_token_ids``, ``max_position_embeddings``.
     """
 
     # The kinds of per-request memory (``engine/config.py::_refusals`` has
@@ -59,6 +59,19 @@ class ModelConfig:
     def num_kv_layers(self) -> int:
         """Layers that hold pages: the KV pool is sized from these."""
         return self.num_layers
+
+    @property
+    def paged_query_shape(self) -> "tuple[int, int]":
+        """(heads, lanes a head) of the queries a layer hands the paged
+        attention kernels: what their choice of path goes by."""
+        return self.num_heads, self.head_dim
+
+    @property
+    def global_window(self) -> int:
+        """The widest view a layer over the global page group has: 0 where
+        some layer there reads a row's whole context (window layers with a
+        page group of their own are not among them)."""
+        return 0 if self.window_pages else self.sliding_window
 
     def page_bytes(self, block_size: int, itemsize: int,
                    tp: int = 1, pp: int = 1) -> int:

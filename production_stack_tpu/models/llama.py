@@ -44,7 +44,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..logging_utils import init_logger
-from ..ops.attention import paged_attention, window_eff
+from ..ops.attention import decode_write_fused, paged_attention, window_eff
 from ..parallel.mesh import AXIS_EXPERT, AXIS_PIPELINE, AXIS_TENSOR
 from .base import (  # noqa: F401  (re-exported: the benchmark reads them here)
     QUANT_SUFFIX,
@@ -401,6 +401,11 @@ class LlamaConfig(ModelConfig):
         return self.ut_steps > 1
 
     @property
+    def global_window(self) -> int:
+        """0 unless every layer is local (``_layer_window``)."""
+        return self.sliding_window if self.sliding_window_pattern <= 1 else 0
+
+    @property
     def attn_scale(self) -> float:
         base = self.query_pre_attn_scalar or self.head_dim
         return 1.0 / math.sqrt(base)
@@ -750,7 +755,7 @@ class Llama(Model):
             q = _apply_rope(q, rope_cos, rope_sin)
             k = _apply_rope(k, rope_cos, rope_sin)
 
-            if _decode_write_fused(attn_impl) and T == 1:
+            if decode_write_fused(attn_impl) and T == 1:
                 # Decode on the Pallas path: the KV write rides INSIDE the
                 # attention kernel (one DMA per sequence before the read
                 # loop) — the per-layer XLA scatter below is pure op
@@ -1074,23 +1079,6 @@ class Llama(Model):
 # ----------------------------------------------------------------------------
 # Layer primitives
 # ----------------------------------------------------------------------------
-
-
-def _decode_write_fused(attn_impl: str) -> bool:
-    """Whether single-token decode should fold the KV write into the
-    Pallas attention kernel (skips the per-layer XLA scatter).
-
-    OFF by default: measured on v5e at the 8B bench shape, the fold's
-    page round-trip (sub-row DMA into a tiled fp8 page is not
-    expressible, so the kernel pulls/splices/pushes the whole page) costs
-    MORE than the XLA scatter it removes (36.2 vs 32.5 ms/step at batch
-    8 x 20k). Kept behind PST_FUSED_KV_WRITE=1 with its exact-parity test
-    for revisiting on hardware where row-granular HBM writes are legal."""
-    if os.environ.get("PST_FUSED_KV_WRITE") != "1":
-        return False
-    from ..ops.attention import resolve_attn_impl
-
-    return resolve_attn_impl(attn_impl) == "pallas"
 
 
 def _act(cfg: "LlamaConfig"):

@@ -145,6 +145,12 @@ class Phi4FlashConfig(base.ModelConfig):
         return self.self_pairs + 1
 
     @property
+    def paged_query_shape(self) -> "tuple[int, int]":
+        """A query head spans its key-value pair's two heads
+        (``paired_queries``)."""
+        return self.num_heads, 2 * self.head_dim
+
+    @property
     def num_window_layers(self) -> int:
         return self.self_pairs
 

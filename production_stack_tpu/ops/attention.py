@@ -33,6 +33,8 @@ Shapes:
 
 from __future__ import annotations
 
+import os
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -61,6 +63,50 @@ def resolve_attn_impl(impl: str) -> str:
     if impl not in ("pallas", "gather"):
         raise ValueError(f"unknown attn_impl {impl!r} (auto|gather|pallas)")
     return impl
+
+
+def decode_write_fused(impl: str) -> bool:
+    """Whether single-token decode should fold the KV write into the
+    Pallas attention kernel (skips the per-layer XLA scatter).
+
+    OFF by default: measured on v5e at the 8B bench shape, the fold's
+    page round-trip (sub-row DMA into a tiled fp8 page is not
+    expressible, so the kernel pulls/splices/pushes the whole page) costs
+    MORE than the XLA scatter it removes (36.2 vs 32.5 ms/step at batch
+    8 x 20k). Kept behind PST_FUSED_KV_WRITE=1 with its exact-parity test
+    for revisiting on hardware where row-granular HBM writes are legal."""
+    if os.environ.get("PST_FUSED_KV_WRITE") != "1":
+        return False
+    return resolve_attn_impl(impl) == "pallas"
+
+
+def _row_shards(mesh, rows: int) -> int:
+    """Shards a decode batch's rows fall into on ``mesh``: the data axis
+    where the rows divide by it (``_pallas_per_shard``), else one."""
+    dp = mesh.shape.get(AXIS_DATA, 1) if mesh is not None else 1
+    return dp if dp > 1 and rows % dp == 0 else 1
+
+
+def decode_sharing_calls(
+    impl: str, mesh, rows: int, heads: int, head_dim: int, window: int = 0
+) -> int:
+    """Into how many kernel calls that each read their rows' common
+    leading pages once (``paged_attention_pallas.py``'s shared phase) a
+    layer's decode attention over a bucket of ``rows`` rows falls: 0 where
+    every call walks a row (the gather reference, the fused write, a shape
+    or a window the phase does not take: ``decode_shares`` is the rule),
+    else a call a shard of rows. What the engine's count of spared reads
+    asks (``engine/runner.py::_step_info``): it holds no rule of its own.
+    ``window``: that of the layers over the pages in question with the
+    widest view (0: some layer reads a row's whole context)."""
+    if resolve_attn_impl(impl) != "pallas" or decode_write_fused(impl):
+        return 0
+    from .paged_attention_pallas import decode_shares
+
+    shards = _row_shards(mesh, rows)
+    tp = mesh.shape.get(AXIS_TENSOR, 1) if mesh is not None else 1
+    return shards if decode_shares(
+        rows // shards, heads // tp, head_dim, window) else 0
 
 
 def paged_attention(
@@ -123,10 +169,9 @@ def _pallas_per_shard(
 
     ctx_mesh = jax.sharding.get_abstract_mesh()  # empty under plain jit
     axes = set(mesh.axis_names) - set(ctx_mesh.manual_axes)
-    dp = mesh.shape.get(AXIS_DATA, 1)
     rows = (
         AXIS_DATA
-        if AXIS_DATA in axes and dp > 1 and q.shape[0] % dp == 0
+        if AXIS_DATA in axes and _row_shards(mesh, q.shape[0]) > 1
         else None
     )
     heads = P(rows, None, AXIS_TENSOR, None)

@@ -228,7 +228,7 @@ def _mla_decode_kernel(
     def rng(row):
         n = lens_ref[row]
         return _LiveRange(
-            row=row, first_page=0, n_pages=(n + block_size - 1) // block_size,
+            row=row, table=row, first_page=0, n_pages=(n + block_size - 1) // block_size,
             c_start=0, n_chunks=(n + span - 1) // span)
 
     kv_len = lens_ref[b]
@@ -253,14 +253,15 @@ def _mla_decode_kernel(
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def compute(page, c):
+    def compute(page, col0):
+        page = page[...]
         S = page.shape[0] * block_size
         k = page[:, 0].reshape(S, lanes)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale  # [Hp, S]
-        col = c * span + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+        col = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
         s = jnp.where(col < kv_len, s, _NEG_INF)
         m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))

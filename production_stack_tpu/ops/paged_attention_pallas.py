@@ -46,6 +46,11 @@ Both stream pages the same way (``_page_dma_loop``):
   fold at the end.
 - Flash state (m/l/acc) is head-major in VMEM scratch so per-head slices are
   contiguous; grouped-query heads share each page read.
+- Rows of a decode call behind one prompt hold the same leading pages (a
+  prefix-cache hit). Those are streamed once a call, ahead of the rows' own
+  pages in the same queue, and folded against every row's query at once
+  (``_find_shared_run``, ``_decode_kernel``'s shared phase; PERF.md §6,
+  PR 50).
 
 Scalar-prefetched block tables address the pages (``PrefetchScalarGridSpec``)
 so page ids are in SMEM before the body runs.
@@ -184,7 +189,8 @@ class _LiveRange(NamedTuple):
     cell that starts a chunk's copies and the cell that waits for them
     build their descriptors from the same range (``_decode_range``)."""
 
-    row: Any  # row of the block table
+    row: Any  # place in the stream (``across_rows`` walks these in order)
+    table: Any  # row of the block table its page ids stand in
     first_page: Any  # first page a query may see (sliding window; else 0)
     n_pages: Any  # pages at or past this hold no live token
     c_start: Any  # first chunk with a live page
@@ -218,7 +224,7 @@ def _page_dma_loop(
     buf,  # [K, C, 2, bs, KH*hd] VMEM scratch: a ring of K chunk slots
     sems,  # [K, C] DMA semaphores
     chunk: int,
-    compute_chunk,  # (pages [n, 2, bs, KH*hd], chunk_index) -> None
+    compute_chunk,  # (view of pages [n, 2, bs, KH*hd], first position) -> None
     fold_pages: int = 0,  # fold a ragged last chunk in steps of this many
     across_rows=None,  # (state_ref SMEM, row -> _LiveRange, rows in grid)
 ):
@@ -245,14 +251,14 @@ def _page_dma_loop(
     points the issuer at its own first chunk. Issuer and folder build a
     chunk's descriptors from the same ``_LiveRange``, and slots are filled
     and drained in the same order, so each wait meets the copy it names."""
-    C, K = chunk, buf.shape[0]
+    C, K, bs = chunk, buf.shape[0], buf.shape[3]
 
     def copies(r: _LiveRange, c, slot, op: str):
         # One descriptor a live page of chunk c: a loop, not C unrolled
         # branches, so a step program traces and lowers one of them.
         def one(j, _):
             getattr(pltpu.make_async_copy(
-                kv_hbm.at[layer, tables_ref[r.row, c * C + j]],
+                kv_hbm.at[layer, tables_ref[r.table, c * C + j]],
                 buf.at[slot, j], sems.at[slot, j],
             ), op)()
             return 0
@@ -307,16 +313,24 @@ def _page_dma_loop(
         row, ic, head, tail, inflight = issue(st, K)
         copies(live, c, tail, "wait")
         if not fold_pages:
-            compute_chunk(buf[tail], c)
+            compute_chunk(buf.at[tail], c * C * bs)
         else:
             # The fold's time goes with the columns it is given, live or
-            # masked: hand a row's last chunk over only up to its last
-            # live page, rounded up to ``fold_pages`` (one body a size).
+            # masked: hand a chunk over only from its first live page to
+            # its last, in whole steps of ``fold_pages`` (one body a size).
+            # Its first page is 0 unless a window or a shared run
+            # (``_decode_range``) starts the row inside the chunk.
             top = jnp.minimum(live.n_pages - c * C, C)
+            if isinstance(live.first_page, int) and live.first_page == 0:
+                low = 0
+            else:
+                low = jnp.maximum(live.first_page - c * C, 0)
+                low = low - jax.lax.rem(low, fold_pages)
             for n in range(fold_pages, C + 1, fold_pages):
-                @pl.when((top > n - fold_pages) & (top <= n))
+                @pl.when((top - low > n - fold_pages) & (top - low <= n))
                 def _(n=n):
-                    compute_chunk(buf[tail, :n], c)
+                    compute_chunk(
+                        buf.at[tail, pl.ds(low, n)], (c * C + low) * bs)
         return row, ic, head, jax.lax.rem(tail + 1, K), inflight - 1
 
     st = jax.lax.fori_loop(live.c_start, live.n_chunks, body, st)
@@ -470,7 +484,8 @@ def _chunked_flash(
         m_ref[h, j, :, :1] = m_new
         acc_ref[h, j] = acc_ref[h, j] * alpha + _pv_dot(p, kv_s[1, :, lanes])
 
-    def compute(page, c):
+    def compute(page, col0):
+        page = page[...]
         for i in range(2):  # K, V
             x = page[:, i].reshape(S, KH * hd)
             if widened:
@@ -478,7 +493,7 @@ def _chunked_flash(
             else:
                 kv_s[i] = x
         # The position each of the chunk's columns stands for.
-        col = c * S + (
+        col = col0 + (
             _widened_rows(S) if widened
             else jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
         )
@@ -498,17 +513,96 @@ def _chunked_flash(
     )
 
 
-def _decode_range(lens_ref, win_ref, row, *, span: int, bs: int):
-    """The live range of decode row ``row``, its length and its window's
-    first position. The one query row sits at position kv_len-1 and may see
-    positions >= kv_len - window (0 = unlimited); whole chunks below that
-    are never fetched, nor the pages below it in the chunk it starts in."""
+def _decode_range(lens_ref, win_ref, row, *, span: int, bs: int, skip=0):
+    """The live range of decode row ``row``, its length and the first
+    position its walk folds. The one query row sits at position kv_len-1
+    and may see positions >= kv_len - window (0 = unlimited); whole chunks
+    below that are never fetched, nor the pages below it in the chunk it
+    starts in. ``skip``: leading pages the call's shared phase has folded
+    for this row already (``_find_shared_run``; 0 under a window)."""
     kv_len = lens_ref[row]
-    lo = jnp.maximum(kv_len - window_eff(win_ref[0]), 0)
+    lo = jnp.maximum(kv_len - window_eff(win_ref[0]), skip * bs)
     return kv_len, lo, _LiveRange(
-        row=row, first_page=lo // bs, n_pages=(kv_len + bs - 1) // bs,
+        row=row, table=row, first_page=lo // bs,
+        n_pages=(kv_len + bs - 1) // bs,
         c_start=lo // span, n_chunks=(kv_len + span - 1) // span,
     )
+
+
+def _find_shared_run(tables_ref, lens_ref, rows: int, bs: int):
+    """(pages, first live row), traced scalars: the leading pages every live
+    row of a decode call holds in common, and the row whose table names them.
+
+    A prefix-cache hit hands rows the same physical pages, so the run is a
+    comparison of page ids, a column of the table at a time until one
+    differs: nothing is hashed. Rows with ``kv_len`` 0 (padding, a finished
+    member of a chain) are left out; every live row keeps at least its last
+    page, the one it writes, to itself; a call with one live row shares
+    nothing. Found by the call's first cell on the scalar core, from the
+    tables and lengths it has in SMEM anyway (a few hundred scalar
+    operations, under a microsecond): as XLA operations of the step program
+    the search's tail was sunk into the layer scan and cost six small
+    fusions a layer (PERF.md §6, PR 50). ``engine/runner.py::
+    shared_prefix_run`` is its twin on the host, which only counts."""
+    i32 = jnp.int32
+
+    def look(i, found):
+        first, live, cap = found
+        kv_len = lens_ref[i]
+        here = kv_len > 0
+        return (jnp.where(here & (first < 0), i, first),
+                live + here.astype(i32),
+                jnp.where(here, jnp.minimum(cap, (kv_len - 1) // bs), cap))
+
+    first, live, cap = jax.lax.fori_loop(
+        0, rows, look, (i32(-1), i32(0), i32(1 << 30)))
+    first = jnp.maximum(first, 0)
+    cap = jnp.where(live > 1, cap, 0)
+
+    def column_shared(j):
+        page = tables_ref[first, j]
+        return jax.lax.fori_loop(
+            0, rows,
+            lambda i, same: same & (
+                (lens_ref[i] == 0) | (tables_ref[i, j] == page)).astype(i32),
+            i32(1))
+
+    def step(at):
+        same = column_shared(at[0])
+        return at[0] + same, same
+
+    pages, _ = jax.lax.while_loop(
+        lambda at: (at[1] > 0) & (at[0] < cap), step, (i32(0), i32(1)))
+    return pages, first
+
+
+def decode_shares(rows: int, heads: int, head_dim: int, window=0):
+    """Does a decode call of ``rows`` rows run the shared phase? THE rule:
+    the call's trace, its first cell and the engine's count of what the
+    phase spares (``ops/attention.py::decode_sharing_calls``) all ask here.
+    More than one row; on the chip rows and heads in whole sublane tiles
+    of float32 and heads in whole lines of 128 lanes (the phase keeps its
+    state head-major in slabs of ``rows`` rows and each row's cell takes
+    its own with one strided load); and no window, which bounds a row's
+    reads already and may lie above its shared pages. ``window`` may be
+    the layer's traced scalar: then so is the answer."""
+    if rows < 2 or not (bool(pallas_interpret()) or (
+            rows % 8 == 0 and heads % 8 == 0 and head_dim % 128 == 0)):
+        return False
+    return window <= 0
+
+
+class _SharedPhase(NamedTuple):
+    """What ``_decode_call`` hands a kernel that traces the shared phase."""
+
+    q_all: Any  # [B, H, hd] VMEM: every row's query whole
+    run: Any  # SMEM (pages of the run, first live row)
+    # VMEM float32, a line a (head, row), heads in blocks of 128 lanes:
+    q32: Any  # the queries row-major
+    q_sh: Any  # the queries head-major
+    m_sh: Any  # [B*H, 128]
+    l_sh: Any  # [B*H, 128]
+    acc_sh: Any
 
 
 def _decode_kernel(
@@ -526,6 +620,7 @@ def _decode_kernel(
     head_dim: int,
     softcap: float = 0.0,
     prefetch_next_row: bool = True,
+    phase: "_SharedPhase | None" = None,
 ):
     """Dense folded-q decode: per-head [G, hd] x [hd, S] mat-vecs waste the
     MXU (G of 128 rows live) and burn VPU on per-head slices, so instead q
@@ -543,20 +638,74 @@ def _decode_kernel(
     chunks of the rows after it are already in flight
     (``_page_dma_loop``). ``prefetch_next_row`` is false where a write
     precedes the read (``_decode_write_kernel``): row ``b+1``'s first
-    chunk may hold the page it has yet to write."""
+    chunk may hold the page it has yet to write.
+
+    **The shared phase** (``phase``). Rows behind one system
+    prompt hold the same leading pages (``_find_shared_run``), and a walk a row
+    would read them once a row. Instead the first cell streams them through
+    the ring once, ahead of row 0's own pages and in the same queue, and
+    folds each chunk against every row's query at once: a KV head at a
+    time, its ``B x G`` query rows against that head's lanes of the chunk
+    (the block-diagonal trick would multiply the work by ``KH`` here, where
+    the rows are many enough to fill the MXU without it). The partial flash
+    state stays head-major, ``[(head, row), ...]``; each row's cell takes
+    its ``H`` lines of it with one strided load, starts its walk at the end
+    of the run (``_decode_range``'s ``skip``) and finishes as ever: the
+    shared pages are folded first either way, with the same products and
+    the same rounding of ``p`` (``_pv_dot``). With a run of 0, under a
+    window, the phase is one scalar comparison."""
+    share = phase is not None
+    if share:
+        q_all_ref, run, q32, q_sh, m_sh, l_sh, acc_sh = phase
     b = pl.program_id(0)
     B = pl.num_programs(0)
     G, hd = group, head_dim
     H = q_ref.shape[1]
     KH = H // G
     span = chunk * block_size
+    skip = 0
+    if share:
+        n_b = q_all_ref.shape[0]  # rows: static, as ``B`` is not
+
+        @pl.when(b == 0)
+        def _find_run():
+            run[0] = 0
+            run[1] = 0
+
+            @pl.when(decode_shares(n_b, H, hd, win_ref[0]))
+            def _():
+                run[0], run[1] = _find_shared_run(
+                    tables_ref, lens_ref, n_b, block_size)
+
+        skip = run[0]  # pages of the shared run, for every cell of the call
     rng = functools.partial(
-        _decode_range, lens_ref, win_ref, span=span, bs=block_size
+        _decode_range, lens_ref, win_ref, span=span, bs=block_size, skip=skip
     )
     kv_len, lo, live = rng(b)
+
+    def place(v):
+        """The stream's places: the shared run, then the rows."""
+        row = rng(jnp.maximum(v - 1, 0))[2]
+        shared = v == 0
+        return _LiveRange(
+            row=v, table=jnp.where(shared, run[1], row.table),
+            first_page=jnp.where(shared, 0, row.first_page),
+            n_pages=jnp.where(shared, skip, row.n_pages),
+            c_start=jnp.where(shared, 0, row.c_start),
+            n_chunks=jnp.where(
+                shared, (skip + chunk - 1) // chunk, row.n_chunks),
+        )
+
     across_rows = None
-    if prefetch_next_row:
+    if share:
+        live = place(b + 1)
+        across_rows = (state, place, B + 1)
+    elif prefetch_next_row:
         across_rows = (state, lambda row: rng(row)[2], B)
+    stream = dict(
+        layer=layer_ref[0], tables_ref=tables_ref, kv_hbm=kv_hbm, buf=buf,
+        sems=sems, chunk=chunk, fold_pages=fold_pages, across_rows=across_rows,
+    )
 
     @pl.when(b == 0)
     def _first_cell():
@@ -564,6 +713,74 @@ def _decode_kernel(
         state[0] = -1  # the issuer points nowhere: this cell starts cold
         for i in range(1, _STREAM_STATE_WORDS):
             state[i] = 0
+
+    if share:
+        GB = G * n_b  # query rows a KV head
+        # A strided load reads lines of 128 lanes: wider heads lie in blocks.
+        n_l, lb = q_sh.shape[0], q_sh.shape[2]
+        blocks = [(j, slice(j * lb, (j + 1) * lb)) for j in range(n_l)]
+
+        @pl.when((b == 0) & (skip > 0))
+        def _shared_phase():
+            # Queries head-major: line ``r * B + row`` holds row ``row``'s
+            # head ``r``. Through float32: a strided load wants whole words.
+            def widen(i, _):
+                for j, lanes in blocks:
+                    q32[j, pl.ds(pl.multiple_of(i * H, H), H), :] = (
+                        q_all_ref[i][:, lanes].astype(jnp.float32))
+                return 0
+
+            jax.lax.fori_loop(0, n_b, widen, 0)
+
+            def turn(r, _):
+                for j, _ in blocks:
+                    q_sh[j, pl.ds(pl.multiple_of(r * n_b, n_b), n_b), :] = (
+                        q32[j, pl.ds(r, n_b, stride=H), :])
+                return 0
+
+            jax.lax.fori_loop(0, H, turn, 0)
+            m_sh[...] = jnp.full_like(m_sh, _NEG_INF)
+            l_sh[...] = jnp.zeros_like(l_sh)
+            acc_sh[...] = jnp.zeros_like(acc_sh)
+
+            def fold_shared(page, col0):
+                S = page.shape[0] * block_size
+                col = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+                seen = col < skip * block_size
+
+                def head(h, _):
+                    lanes = pl.ds(pl.multiple_of(h * hd, hd), hd)
+                    rows = pl.ds(pl.multiple_of(h * GB, GB), GB)
+                    k = page[:, 0, :, lanes].reshape(S, hd)
+                    v = page[:, 1, :, lanes].reshape(S, hd)
+                    q_h = jnp.concatenate(
+                        [q_sh[j, rows, :] for j, _ in blocks], axis=-1)
+                    s = jax.lax.dot_general(
+                        q_h.astype(q_ref.dtype), k,
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    ) * scale  # [B*G, S] fp32
+                    if softcap:
+                        s = jnp.tanh(s / softcap) * softcap
+                    s = jnp.where(seen, s, _NEG_INF)
+                    m_prev = m_sh[rows, :1]
+                    m_new = jnp.maximum(
+                        m_prev, jnp.max(s, axis=-1, keepdims=True))
+                    p = jnp.exp(s - m_new)
+                    alpha = jnp.exp(m_prev - m_new)
+                    l_sh[rows, :1] = alpha * l_sh[rows, :1] + jnp.sum(
+                        p, axis=-1, keepdims=True)
+                    m_sh[rows, :1] = m_new
+                    pv = _pv_dot(p, v)
+                    for j, lanes in blocks:
+                        acc_sh[j, rows, :] = (
+                            acc_sh[j, rows, :] * alpha + pv[:, lanes])
+                    return 0
+
+                jax.lax.fori_loop(0, KH, head, 0)
+
+            _page_dma_loop(
+                live=place(0), compute_chunk=fold_shared, **stream)
 
     q = q_ref[0]  # [H, hd] native dtype
     # Arithmetic 0/1 mask (born 3D): Mosaic cannot minor-dim-reshape or
@@ -576,15 +793,32 @@ def _decode_kernel(
         q[:, None, :] * blockdiag.astype(q.dtype)
     ).reshape(H, KH * hd)
 
-    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+    def fresh():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def compute(page, c):
+    if share:
+        # A live row goes on from what the shared phase left for it.
+        took = (skip > 0) & (kv_len > 0)
+        pl.when(~took)(fresh)
+
+        @pl.when(took)
+        def _take():
+            mine = pl.ds(b, H, stride=n_b)  # head r's line of row b
+            m_ref[...] = m_sh[mine, :]
+            l_ref[...] = l_sh[mine, :]
+            for j, lanes in blocks:
+                acc_ref[:, lanes] = acc_sh[j, mine, :]
+    else:
+        fresh()
+
+    def compute(page, col0):
+        page = page[...]
         S = page.shape[0] * block_size
         k = page[:, 0].reshape(S, KH * hd)
         v = page[:, 1].reshape(S, KH * hd)
-        col = c * span + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+        col = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
         s = jax.lax.dot_general(
             q_sparse, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -602,11 +836,7 @@ def _decode_kernel(
         own = (pv * blockdiag).sum(axis=1)  # each row's own head block
         acc_ref[...] = acc_ref[...] * alpha + own
 
-    _page_dma_loop(
-        live=live, layer=layer_ref[0], tables_ref=tables_ref, kv_hbm=kv_hbm,
-        buf=buf, sems=sems, chunk=chunk, compute_chunk=compute,
-        fold_pages=fold_pages, across_rows=across_rows,
-    )
+    _page_dma_loop(live=live, compute_chunk=compute, **stream)
     out = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-20)  # [H, hd]
     o_ref[0] = out.astype(o_ref.dtype)
 
@@ -666,6 +896,8 @@ def _decode_write_kernel(
         push.start()
         push.wait()
 
+    # The per-row walk, no shared phase: a row's shared pages may hold the
+    # page a row before it has yet to write.
     _decode_kernel(
         tables_ref, lens_ref, layer_ref, win_ref,
         q_ref, kv_out, o_ref, buf, sems, state, m_ref, l_ref, acc_ref,
@@ -781,7 +1013,7 @@ def _prefill_kernel(
     win_eff = window_eff(win_ref[0])
     tile_lo = jnp.maximum(first + 1 - win_eff, 0)
     live = _LiveRange(
-        row=b, first_page=tile_lo // block_size,
+        row=b, table=b, first_page=tile_lo // block_size,
         n_pages=(limit + block_size - 1) // block_size,
         c_start=tile_lo // span, n_chunks=(limit + span - 1) // span,
     )
@@ -885,18 +1117,39 @@ def _decode_call(q3, kv_pages, block_tables, kv_lens, layer, window,
     B, H, hd, bs, lanes, C, kw, scratch, flash = _decode_geometry(
         q3, kv_pages, scale=scale, softcap=softcap
     )
+    share = decode_shares(B, H, hd)
+    q_all, q_all_spec, phase = [], [], []
+    if share:
+        # Every row's query whole beside the cell's own block (the same
+        # array: its block never changes, so it is fetched once), and the
+        # phase's state (``_SharedPhase``; ``wide``: in blocks of 128 lanes).
+        q_all = [q3]
+        q_all_spec = [pl.BlockSpec((B, H, hd), lambda b, *_: (0, 0, 0))]
+        lb = 128 if hd % 128 == 0 else hd  # ``decode_shares``: on the chip, 128
+        wide = pltpu.VMEM((hd // lb, B * H, lb), jnp.float32)
+        narrow = pltpu.VMEM((B * H, 128), jnp.float32)
+        phase = [pltpu.SMEM((2,), jnp.int32), wide, wide, narrow, narrow, wide]
+
+    def kernel(tables_ref, lens_ref, layer_ref, win_ref, q_ref, *refs):
+        given = None
+        if share:  # inputs, the output, scratch: in the order given below
+            q_all_ref, *refs = refs
+            given = _SharedPhase(q_all_ref, *refs[-len(phase):])
+            refs = refs[:-len(phase)]
+        _decode_kernel(tables_ref, lens_ref, layer_ref, win_ref, q_ref, *refs,
+                       phase=given, **kw)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H, hd), lambda b, t, l, ly, w: (b, 0, 0)),
+            pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
+            *q_all_spec,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, hd), lambda b, t, l, ly, w: (b, 0, 0)),
-        scratch_shapes=scratch + flash,
+        out_specs=pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=scratch + flash + phase,
     )
-    kernel = functools.partial(_decode_kernel, **kw)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -912,7 +1165,7 @@ def _decode_call(q3, kv_pages, block_tables, kv_lens, layer, window,
         ),
         interpret=pallas_interpret(),
         name="paged_attn_decode",
-    )(block_tables, kv_lens, layer, window, q3, kv_pages)
+    )(block_tables, kv_lens, layer, window, q3, *q_all, kv_pages)
 
 
 def _prefill_call(q, kv_pages, block_tables, kv_lens, starts, layer, window,

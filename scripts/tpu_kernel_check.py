@@ -19,6 +19,11 @@ At the Llama-3-8B layer shapes the serving path uses (32 Q / 8 KV heads x
     is fetched, a column that is not masked, or VMEM that nothing wrote
     reaching ``p @ V`` shows here and only here (the interpreter's buffers
     start clean);
+  - decode behind one shared prompt (``shared_run_case``: 16 rows behind
+    512 shared tokens at the looped cell's geometry, 16 behind 1,024 at the
+    dense cell's), checked the same way and timed a call against what the
+    chip's memory allows for the rows' contexts and for the distinct tokens
+    among them;
   - ``mla_decode`` (``ops/mla_attention.py``: absorbed latent attention, 20
     heads over one ``[c_kv 512 | k_rope 64 | 0]`` row a token, bf16 pages)
     at the latent cell's shape, 16 rows of which four are empty at 16-41k
@@ -99,6 +104,7 @@ ATTN_BOUND = 2e-2
 # nibbles and scales the fp32 partial by the fp32 scale, the reference
 # multiplies by the fp32 dequant — outputs are O(1) sums of din terms.
 INT4_BOUND = 5e-2
+HBM_BYTES_S = 819e9  # one v5e chip (``perf/peaks.json``)
 
 _Q_SLICE = 256
 
@@ -209,6 +215,80 @@ def cell_shape_case(name, *, B, kv_heads, kv_dtype, lo, hi, empty=(),
         "empty_rows_max_abs_diff": float(np.abs(got[~live]).max(initial=0.0)),
         "ref_abs_max": float(np.abs(want[live]).max()),
         "kv_tokens": int(lens.sum()),
+        "bound": ATTN_BOUND,
+        "first_call_s": round(compile_s, 2),
+    }
+
+
+def shared_run_case(name, *, B, kv_heads, heads, kv_dtype, bs, shared, lo, hi,
+                    empty=(), calls=64):
+    """Decode behind one shared prompt, as the prefix cache leaves it:
+    every live row's first ``shared`` tokens are the same physical pages,
+    then ``lo`` to ``hi`` tokens of its own (``ops/paged_attention_pallas.py
+    ::_find_shared_run``: the kernel reads the shared pages once a call). Checked
+    like ``cell_shape_case`` (NaN wherever the kernel must not look), then
+    timed: ``calls`` calls chained in one program over the layers of a
+    stacked cache, against the least time the chip's memory allows for the
+    rows' contexts (what a walk a row has to read) and for the distinct
+    tokens among them (what this call has to). It runs on a tree without the
+    phase too: copy it into that tree's ``scripts/``."""
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    lanes, layers = kv_heads * HD, 3
+    own = rng.integers(lo, hi, B).astype(np.int32)
+    lens = shared + own
+    lens[list(empty)] = 0
+    n_shared = shared // bs
+    width = -(-(shared + hi) // bs) + 8
+    nb = B * width + n_shared + 2
+    kv = rng.standard_normal((layers, nb, 2, bs, lanes)).astype(np.float32)
+    kv[:, 1] = np.nan
+    kv = jnp.asarray(kv, jnp.bfloat16).astype(kv_dtype)
+    tables = (rng.permutation(B * width) + n_shared + 2).reshape(B, width)
+    tables[:, :n_shared] = 2 + np.arange(n_shared)
+    dead = np.arange(width)[None] >= -(-lens // bs)[:, None]
+    q = jnp.asarray(rng.standard_normal((B, 1, heads, HD)), jnp.bfloat16)
+    q_pos = jnp.asarray(np.maximum(lens - 1, 0))[:, None]
+    t_kern = jnp.asarray(np.where(dead, 1, tables).astype(np.int32))
+    lens_j = jnp.asarray(lens)
+    kern = jax.jit(
+        lambda q, kv, t, l, p, ly: pallas_paged_attention(
+            q, kv, t, l, p, ly, scale=SCALE))
+    t0 = time.perf_counter()
+    got = np.asarray(kern(q, kv, t_kern, lens_j, q_pos, jnp.int32(2)), np.float32)
+    compile_s = time.perf_counter() - t0
+    ref = jax.jit(
+        lambda q, kv, t, l, p: gather_paged_attention(
+            q, kv, t, l, p, 2, scale=SCALE))
+    want = np.asarray(ref(
+        q, kv, jnp.asarray(np.where(dead, 0, tables).astype(np.int32)),
+        lens_j, q_pos), np.float32)
+
+    def chain(q, kv, t, l, p):
+        def body(i, q):
+            out = pallas_paged_attention(
+                q, kv, t, l, p, jax.lax.rem(i, layers), scale=SCALE)
+            return q + out * 1e-3
+        return jax.lax.fori_loop(0, calls, body, q)
+
+    timed = jax.jit(chain)
+    jax.block_until_ready(timed(q, kv, t_kern, lens_j, q_pos))
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        jax.block_until_ready(timed(q, kv, t_kern, lens_j, q_pos))
+        best = min(best, (time.perf_counter() - t0) / calls)
+    live = lens > 0
+    token_bytes = 2 * lanes * jnp.dtype(kv_dtype).itemsize
+    distinct = int(lens.sum()) - (int(live.sum()) - 1) * shared
+    return {
+        "max_abs_diff": float(np.abs(got[live] - want[live]).max()),
+        "empty_rows_max_abs_diff": float(np.abs(got[~live]).max(initial=0.0)),
+        "ref_abs_max": float(np.abs(want[live]).max()),
+        "kv_tokens": int(lens.sum()),
+        "distinct_tokens": distinct,
+        "us_a_call": round(best * 1e6, 2),
+        "roofline_us_rows": round(int(lens.sum()) * token_bytes / HBM_BYTES_S * 1e6, 2),
+        "roofline_us_distinct": round(distinct * token_bytes / HBM_BYTES_S * 1e6, 2),
         "bound": ATTN_BOUND,
         "first_call_s": round(compile_s, 2),
     }
@@ -663,6 +743,16 @@ def cases():
         B=16, kv_heads=8, kv_dtype=fp8, lo=3000, hi=11000, empty=(5, 15))
     yield "attn_decode_cell_hybrid_b32_kh2_bf16", cell_shape_case, dict(
         B=32, kv_heads=2, kv_dtype=jnp.bfloat16, lo=1000, hi=2500)
+    # Rows behind one prompt (PR 50): 16 graders behind 512 few-shot tokens
+    # on the looped cell's 32-token bf16 pages of 16 KV heads, and 16
+    # sessions behind a 1,024-token system prompt on the dense cell's fp8
+    # pages, one row finished.
+    yield "attn_decode_shared_ouro_b16_kh16_bf16", shared_run_case, dict(
+        B=16, kv_heads=16, heads=16, kv_dtype=jnp.bfloat16, bs=32,
+        shared=512, lo=64, hi=256)
+    yield "attn_decode_shared_dense_b16_kh8_fp8", shared_run_case, dict(
+        B=16, kv_heads=8, heads=32, kv_dtype=fp8, bs=128, shared=1024,
+        lo=2000, hi=8000, empty=(11,))
     yield "attn_prefill_cell_dense_t256_real150_fp8", prefill_cell_case, dict(
         T=256, real=150, start=8192 - 37, kv_dtype=fp8)
     yield "attn_prefill_t1024_real600_fp8_window_softcap", prefill_cell_case, dict(

@@ -106,7 +106,8 @@ def _loop_without(what: str, orig):
         buf, fold, live = kw["buf"], kw["compute_chunk"], kw["live"]
 
         def body(c, _):
-            fold(buf[jax.lax.rem(c, 2)], c)
+            # (a view of the pages, the position of their first column)
+            fold(buf.at[jax.lax.rem(c, 2)], c * kw["chunk"] * buf.shape[3])
             return 0
 
         jax.lax.fori_loop(live.c_start, live.n_chunks, body, 0)

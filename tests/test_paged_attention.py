@@ -41,7 +41,22 @@ def test_pallas_decode_matches_gather():
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
 
 
-def test_pallas_handles_empty_rows():
+@pytest.fixture(params=["shared_phase_traced", "per_row_walk_traced"])
+def decode_trace(request, monkeypatch):
+    """The two traces of a decode call at more than one row. Under the
+    interpreter every such call traces the shared phase
+    (``decode_shares``: the stream's places are the run, then the rows); on
+    the chip a call whose rows or heads are not in eights, or whose heads
+    are not of 128 lanes, traces the walk a row with the next row's first
+    chunk fetched ahead. Both are held against the oracle."""
+    from production_stack_tpu.ops import paged_attention_pallas as pap
+
+    if request.param == "per_row_walk_traced":
+        monkeypatch.setattr(pap, "decode_shares", lambda *shape, **kw: False)
+    return request.param
+
+
+def test_pallas_handles_empty_rows(decode_trace):
     q, kv, tables, kv_lens, q_pos = _setup()
     kv_lens = kv_lens.at[1].set(0)  # padding row
     scale = 1.0 / np.sqrt(q.shape[-1])
@@ -113,13 +128,14 @@ _DECODE_STREAMS = {
     # and its first live page lies mid-chunk
     "window_start_differs_by_row": dict(lens=[3000, 1300, 2500], window=600),
     "fp8_pages": dict(lens=[1100, 2100, 40], dtype=jnp.float8_e4m3fn),
+    "soft_cap": dict(lens=[1100, 40, 2100], softcap=30.0),
     "layer_of_a_stack": dict(lens=[1300, 200], layers=3, layer=2),
     "single_row": dict(lens=[2500]),
 }
 
 
 @pytest.mark.parametrize("case", list(_DECODE_STREAMS))
-def test_pallas_decode_streams_live_pages_only(case):
+def test_pallas_decode_streams_live_pages_only(case, decode_trace):
     kw = _DECODE_STREAMS[case]
     got, ref, lens = _stream_case(**kw)
     assert np.all(np.isfinite(got)), "a dead or foreign page reached the fold"
@@ -134,7 +150,8 @@ def test_pallas_decode_streams_live_pages_only(case):
     "odd_and_even_chunk_counts", "empty_row_between",
     "window_start_differs_by_row",
 ])
-def test_pallas_decode_every_wait_meets_its_copy(case, monkeypatch):
+def test_pallas_decode_every_wait_meets_its_copy(
+        case, decode_trace, monkeypatch):
     """The plain interpreter copies at ``start`` and ignores ``wait``, so a
     wait that names another copy than the one started (what hangs the chip,
     or lets a fold read a slot still being filled) passes there. JAX's TPU
@@ -387,3 +404,218 @@ def test_decode_write_fused_matches_scatter_then_read(dtype):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref[:, 0]), atol=1e-5
     )
+
+
+# --- the shared phase of decode (PR 50) -------------------------------------
+# Rows behind one prompt hold the same leading pages; the kernel streams
+# those once, folds them against every row's query at once, and each row's
+# walk goes on from there (``_decode_kernel``). Held here: that the result is
+# the per-row walk's (the same call with the run forced to 0) to the
+# rounding of the pages' dtype, and the gather oracle's; that the shared
+# pages really are read through ONE row's table (every other row's entries
+# for them point at NaN); and where the run ends.
+
+_SHARED_GEOMETRIES = {
+    # the looped cell's: every head its own keys and values, 32-token pages
+    "bf16_mha_pages_of_32": dict(
+        H=4, KH=4, hd=32, bs=32, dtype=jnp.bfloat16, tol=2e-2),
+    # the dense cell's: four query heads a KV head, 128-token e4m3 pages
+    "fp8_grouped_4_to_1_pages_of_128": dict(
+        H=8, KH=2, hd=32, bs=128, dtype=jnp.float8_e4m3fn, tol=2e-2),
+    "float32_exact": dict(H=4, KH=2, hd=32, bs=32, dtype=jnp.float32, tol=2e-5),
+}
+# (pages the rows' tables hold in common, own tokens a row; the shortest
+# row's own tokens decide how far the run may reach)
+_SHARED_RUNS = {
+    "run_0": dict(common=0, own=[40, 70, 5, 33]),
+    "partial_run": dict(common=3, own=[40, 70, 5, 33]),
+    # the tables agree on five pages, but row 2 ends inside the fifth: the
+    # run stops before the page it writes
+    "whole_of_the_shortest_row_but_its_last_page": dict(
+        common=5, own=[40, 70, -3, 33]),
+    "padding_rows_inside_the_batch": dict(
+        common=3, own=[40, None, 70, None, 5, 33, None, 9]),
+    "run_longer_than_a_chunk": dict(common=70, own=[40, 70, 5, 33]),
+}
+
+
+def _shared_case(*, H, KH, hd, bs, dtype, common, own, softcap=0.0, seed=0,
+                 tol=None, poison_other_rows=False, ahead=0):
+    """Rows whose tables agree on ``common`` pages, then ``own[i]`` tokens
+    each (negative: the row ends that many tokens before the common pages
+    do; None: a padding row, ``kv_len`` 0, its table zeros; ``ahead``: tokens
+    a burst will add, whose pages are live too). -> (inputs of
+    ``pallas_paged_attention``, tables for the oracle, lens, pages shared)."""
+    rng = np.random.default_rng(seed)
+    B = len(own)
+    lens = np.array(
+        [0 if o is None else common * bs + o for o in own], np.int32)
+    W = -(-int(lens.max()) // bs) + 2
+    nb = 2 + common + B * W
+    kv = rng.standard_normal((2, nb, 2, bs, KH * hd)).astype(np.float32)
+    kv[:, 1] = np.nan
+    kv[0] = np.nan  # layer 1 is read
+    tables = (2 + common + rng.permutation(B * W)).reshape(B, W).astype(np.int32)
+    tables[:, :common] = 2 + np.arange(common)
+    tables[lens == 0] = 0
+    live = lens > 0
+    dead = np.arange(W)[None] >= -(-(lens + ahead * live) // bs)[:, None]
+    pages = min(common, int((lens[live] - 1).min()) // bs) if live.sum() > 1 else 0
+    q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
+    if dtype != jnp.float32:
+        q = q.astype(jnp.bfloat16)
+    seen = np.where(dead, 1, tables)
+    if poison_other_rows:
+        first = int(np.argmax(live))
+        others = np.arange(B) != first
+        seen[np.ix_(others, np.arange(pages))] = 1
+    args = (q, jnp.asarray(kv).astype(dtype), jnp.asarray(seen),
+            jnp.asarray(lens), jnp.asarray(np.maximum(lens - 1, 0))[:, None], 1)
+    kw = dict(scale=1.0 / np.sqrt(hd), softcap=softcap)
+    return args, jnp.asarray(np.where(dead, 0, tables)), lens, pages, kw
+
+
+def program_run(tables, lens, bs):
+    """(pages, first live row) as the decode kernel's first cell finds them:
+    ``_find_shared_run`` on the tables and lengths in SMEM, in a kernel of
+    its own."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from production_stack_tpu.ops import paged_attention_pallas as pap
+
+    def kernel(tables_ref, lens_ref, out_ref):
+        out_ref[0], out_ref[1] = pap._find_shared_run(
+            tables_ref, lens_ref, tables.shape[0], bs)
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,), in_specs=[],
+            out_specs=pl.BlockSpec(memory_space=pltpu.SMEM)),
+        out_shape=jax.ShapeDtypeStruct((2,), jnp.int32),
+        interpret=True,
+    )(jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32))
+    return tuple(int(x) for x in np.asarray(out))
+
+
+def _per_row_walk(monkeypatch):
+    """The same call with the run forced to 0: today's walk, a row a cell."""
+    from production_stack_tpu.ops import paged_attention_pallas as pap
+
+    monkeypatch.setattr(
+        pap, "_find_shared_run", lambda *refs: (jnp.int32(0), jnp.int32(0)))
+
+
+def _three_ways(args, oracle_tables, kw, monkeypatch):
+    """(the kernel with its shared phase, the gather oracle, the per-row
+    walk) on one set of inputs, float32."""
+    got = np.asarray(pallas_paged_attention(*args, **kw), np.float32)
+    ref = np.asarray(gather_paged_attention(
+        args[0], args[1], oracle_tables, *args[3:], **kw), np.float32)
+    _per_row_walk(monkeypatch)
+    walk = np.asarray(pallas_paged_attention(*args, **kw), np.float32)
+    return got, ref, walk
+
+
+@pytest.mark.parametrize("run", list(_SHARED_RUNS))
+@pytest.mark.parametrize("geometry", list(_SHARED_GEOMETRIES))
+def test_pallas_decode_shared_phase_equals_the_per_row_walk(
+        geometry, run, monkeypatch):
+    from production_stack_tpu.ops import paged_attention_pallas as pap
+
+    geo = dict(_SHARED_GEOMETRIES[geometry])
+    tol = geo.pop("tol")
+    args, oracle_tables, lens, pages, kw = _shared_case(
+        **geo, **_SHARED_RUNS[run])
+    found = program_run(args[2], args[3], geo["bs"])
+    assert found[0] == pages and (pages == 0 or lens[found[1]] > 0)
+    got, ref, walk = _three_ways(args, oracle_tables, kw, monkeypatch)
+    live = lens > 0
+    assert np.all(np.isfinite(got)), "a dead or foreign page reached the fold"
+    assert np.all(got[~live] == 0.0)  # the drop-slot contract
+    np.testing.assert_allclose(got[live], walk[live], rtol=tol, atol=tol)
+    np.testing.assert_allclose(got[live], ref[live], rtol=tol, atol=tol)
+    if pages == 0:  # a run of 0 is today's call, bit for bit
+        assert np.array_equal(got, walk)
+
+
+@pytest.mark.parametrize("geometry", list(_SHARED_GEOMETRIES))
+def test_pallas_decode_shared_phase_with_a_soft_cap(geometry, monkeypatch):
+    geo = dict(_SHARED_GEOMETRIES[geometry])
+    tol = geo.pop("tol")
+    args, oracle_tables, lens, pages, kw = _shared_case(
+        **geo, common=3, own=[40, 70, None, 33], softcap=20.0, seed=3)
+    got, ref, walk = _three_ways(args, oracle_tables, kw, monkeypatch)
+    live = lens > 0
+    np.testing.assert_allclose(got[live], walk[live], rtol=tol, atol=tol)
+    np.testing.assert_allclose(got[live], ref[live], rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("interpreter", ["plain", "copies_at_the_wait"])
+def test_pallas_decode_reads_shared_pages_through_one_row(
+        interpreter, monkeypatch):
+    """Every row but the first live one has NaN pages where the run stands
+    in its table: a shared page fetched a row would poison that row. And
+    under the interpreter that moves the bytes at the wait, the shared
+    chunks ride the same ring ahead of row 0's: every wait meets its copy."""
+    from jax.experimental.pallas import tpu as pltpu
+    from production_stack_tpu.ops import paged_attention_pallas as pap
+
+    geo = dict(_SHARED_GEOMETRIES["float32_exact"])
+    tol = geo.pop("tol")
+    args, oracle_tables, lens, pages, kw = _shared_case(
+        **geo, common=70, own=[None, 40, 70, 5, 2100], poison_other_rows=True)
+    assert pages == 70
+    # the run is what the real tables say; the kernel is handed the poisoned
+    monkeypatch.setattr(
+        pap, "_find_shared_run", lambda *refs: (jnp.int32(70), jnp.int32(1)))
+    if interpreter != "plain":
+        monkeypatch.setattr(
+            pap, "pallas_interpret",
+            lambda: pltpu.InterpretParams(dma_execution_mode="on_wait"))
+    got = np.asarray(pallas_paged_attention(*args, **kw), np.float32)
+    ref = np.asarray(gather_paged_attention(
+        args[0], args[1], oracle_tables, *args[3:], **kw), np.float32)
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got[1:], ref[1:], rtol=tol, atol=tol)
+
+
+def test_pallas_decode_shared_phase_is_not_taken_under_a_window(monkeypatch):
+    """A window bounds a row's reads already and its shared pages may lie
+    below it: with ``window`` > 0 the rows walk alone, whatever the run."""
+    geo = dict(_SHARED_GEOMETRIES["float32_exact"])
+    geo.pop("tol")
+    args, _, lens, pages, kw = _shared_case(
+        **geo, common=6, own=[40, 70, 5, 33])
+    assert pages == 6
+    got = np.asarray(pallas_paged_attention(*args, window=100, **kw))
+    _per_row_walk(monkeypatch)
+    walk = np.asarray(pallas_paged_attention(*args, window=100, **kw))
+    assert np.array_equal(got, walk)
+
+
+def test_pallas_decode_shared_phase_through_a_burst(monkeypatch):
+    """A burst of depth 3 inside one program: the lengths advance on the
+    device and the kernel finds the run again at every step (row 2 crosses onto a
+    new page at the third step, which lets the run grow by one)."""
+    geo = dict(_SHARED_GEOMETRIES["bf16_mha_pages_of_32"])
+    tol, bs = geo.pop("tol"), geo["bs"]
+    args, oracle_tables, lens, pages, kw = _shared_case(
+        **geo, common=5, own=[40, 70, -1, 33], ahead=2)
+    assert pages == 4
+    q, kv, tables, lens_j, _, layer = args
+
+    def burst(q, kv, tables, lens):
+        def step(lens, _):
+            out = pallas_paged_attention(
+                q, kv, tables, lens, (lens - 1)[:, None], layer, **kw)
+            return lens + 1, out
+        return jax.lax.scan(step, lens, None, length=3)[1]
+
+    got = np.asarray(jax.jit(burst)(q, kv, tables, lens_j), np.float32)
+    runs = [program_run(tables, lens_j + i, bs)[0] for i in range(3)]
+    assert runs == [4, 4, 5]
+    _per_row_walk(monkeypatch)
+    walk = np.asarray(jax.jit(burst)(q, kv, tables, lens_j), np.float32)
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, walk, rtol=tol, atol=tol)

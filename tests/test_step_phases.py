@@ -130,8 +130,11 @@ def test_every_phase_of_the_table_in_every_step(traced_run):
         inner = _inside(events, step)
         info = [ev[3] for ev in inner if ev[0] == "pst.step_info"]
         assert len(info) == 1, inner
+        # a decode step says what its rows hold in common pages as well
+        shared = ({"shared_kv_tokens", "shared_rows"}
+                  if info[0]["kind"] == "decode" else set())
         assert set(info[0]) == {"kind", "bucket", "rows", "new_tokens",
-                                "kv_tokens", "kv_pages"}
+                                "kv_tokens", "kv_pages"} | shared
         assert info[0]["kind"] in ("prefill", "decode")
         assert 1 <= info[0]["rows"] <= 2 <= info[0]["kv_tokens"]
         assert info[0]["new_tokens"] >= info[0]["rows"] <= info[0]["kv_pages"]
