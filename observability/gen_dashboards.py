@@ -243,10 +243,16 @@ def fleet_dashboard():
         ('histogram_quantile(0.9, sum(rate(pst_engine_compile_seconds_bucket'
          '[10m])) by (le, kind))', "{{kind}}"),
     ], 8, 68, unit="s"))
-    p.append(panel("Device step duration p90 by kind", [
+    # The device's own time of a launched program, by the engine's
+    # completion clock (pst_engine_step_duration_seconds is the host's wall
+    # around the dispatch call: under chained decode not the device's time).
+    p.append(panel("Device step time p50 / p90 by kind (engine's clock)", [
+        ('histogram_quantile(0.5, sum(rate('
+         'pst_engine_device_step_seconds_bucket[2m])) by (le, kind))',
+         "{{kind}} p50"),
         ('histogram_quantile(0.9, sum(rate('
-         'pst_engine_step_duration_seconds_bucket[2m])) by (le, kind))',
-         "{{kind}}"),
+         'pst_engine_device_step_seconds_bucket[2m])) by (le, kind))',
+         "{{kind}} p90"),
     ], 16, 68, unit="s"))
     p.append(panel("Engine tokens/s (by step kind)", [
         ('sum(pst_engine_tokens_per_second) by (kind)', "{{kind}} tok/s"),
@@ -378,12 +384,20 @@ def fleet_dashboard():
         ('pst_capacity_queue_depth_slope', "queue slope /s"),
         ('pst_capacity_kv_headroom', "kv headroom"),
     ], 8, 121))
-    p.append(panel("Cost: tenant chip-seconds + request device time", [
+    p.append(panel("Cost: tenant chip-seconds + the device's busy, idle "
+                   "and seen-late share", [
         ('sum(rate(pst_tenant_device_seconds_total[5m])) by (tenant)',
          "{{tenant}} chip-s/s"),
         ('histogram_quantile(0.9, sum(rate('
          'pst_request_device_seconds_bucket[5m])) by (le, phase))',
          "{{phase}} p90 device-s"),
+        ('sum(rate(pst_engine_device_busy_seconds_total[5m]))',
+         "device busy s/s"),
+        ('sum(rate(pst_engine_device_idle_seconds_total[5m])) by (state)',
+         "device idle s/s: {{state}}"),
+        ('sum(rate(pst_engine_device_service_seconds_total{seen="late"}[5m]))'
+         ' / clamp_min(sum(rate(pst_engine_device_busy_seconds_total[5m])), '
+         '1e-9)', "seen-late share of busy"),
     ], 16, 121))
     p.append(stat("Attribution coverage (5m)",
                   'clamp_max(sum(rate(pst_request_device_seconds_sum[5m])) / '
@@ -402,6 +416,10 @@ def fleet_dashboard():
          "{{cause}} s stalled /s"),
         ('sum(rate(pst_engine_gc_pause_seconds_total[5m]))',
          "collections s paused /s"),
+        # the window account: the step thread's wall by what the loop was
+        # doing (the states sum to 1 s/s an engine)
+        ('sum(rate(pst_engine_loop_seconds_total[5m])) by (state)',
+         "loop s/s: {{state}}"),
     ], 12, 128))
 
     # Page groups of a model whose window layers keep their own

@@ -17,7 +17,8 @@ Rules (scope: ``engine/``):
    construct ``Bucket("<kind>", ...)`` literals; the set of kinds is the
    registered family set.
 2. **Dispatch families.** Every ``ENGINE_TELEMETRY.record_dispatch`` /
-   ``_record_warmup`` call site's bucket family — derived from the
+   ``_record_warmup`` / ``_dispatching`` (the runner's block around a live
+   dispatch: kind, key, who pays, label) call site's bucket family — derived from the
    ``batch_bucket`` label grammar (``b{N}`` decode, ``b{N}xn{S}``
    decode_burst, ``b{N}xt{C}`` prefill, ``b{N}xk{K}`` spec_verify,
    ``t{T}`` encode) — must be a registered family.
@@ -62,7 +63,7 @@ DESCRIPTION = (
 
 _KEY_HELPERS = {"_tel_key", "_prefill_tel"}
 _KEY_FORWARDERS = {"_record_warmup"}
-_DISPATCH_FUNCS = {"record_dispatch", "_record_warmup"}
+_DISPATCH_FUNCS = {"record_dispatch", "_record_warmup", "_dispatching"}
 _SCOPE_ATTR = "_tel_scope"
 
 # The shape_bucket label grammar (mirrors Bucket.label in precompile.py).

@@ -18,6 +18,7 @@ import functools
 import os
 import threading
 import time
+from collections import deque
 from typing import Any, Dict, List, Optional, Sequence as Seq
 
 import jax
@@ -79,7 +80,127 @@ def _pow2(n: int, cap: Optional[int] = None) -> int:
 _MIN_TABLE_BUCKET = 64
 
 
-def _fetch(arr, kind: str = "") -> np.ndarray:
+class _ReadyClock:
+    """The engine's own clock for the device: when each program the runner
+    launched was seen ready, and from that its service time.
+
+    The device runs a process's programs in launch order, so a program's
+    time there is ``ready - max(ready of the one before, launched_at)``, the
+    completion of a job in a one-server queue; what lies between an earlier
+    ready and a later launch is device idle. Both stamps are readings the
+    step thread makes anyway: ``launched_at`` is the close of the
+    dispatch's ``pst.launch`` phase (where nothing was queued the device
+    may have begun up to that phase's length earlier: idle so filed errs
+    high by at most that), and ``ready`` is the reading of the poll of
+    `_fetch` that first found the program's array ready. Errors are one
+    poll (about 1 ms on the benchmark's host) a stamp and telescope: over a
+    busy stretch the service times sum to last ready less first start.
+
+    A short FIFO of programs launched and not yet seen ready. `_fetch`
+    hands every poll here with its own array's answer; while that array is
+    not ready the head of the FIFO, where it is another program (a prefill
+    launched ahead of the chained step, an inner chunk nobody fetches), is
+    asked ``is_ready()`` too: one extra call a poll at most. A program
+    found ready with no ask in the `_FRESH_S` before that found it still
+    running (the first poll that asked, or the first of a fetch after the
+    host's own phases) was *seen late*: it ended at some moment since, the
+    host and not the device set the pace, and its interval, which holds
+    whatever the device then idled, is counted and marked so. Each opening
+    ``pst.launch`` asks the head once as well, so that what ended while the
+    host was busy is stamped before the next launch and the time between
+    is idle, not service. What the clock cannot see is an idle stretch
+    shorter than a stamp's lag (the runtime reports an array ready 0.4-1.4
+    ms after its program's end on the benchmark's host, and a poll comes
+    every millisecond): it lies in the service time of the program after
+    it, which then reads the period between two programs' ends.
+
+    ``sink(entry, start, ready, seen, idle_s, idle_state)`` is called for
+    each program seen ready, under the clock's lock (an embedding's encode
+    polls from an executor thread). ``no_work_count()``: how many waits for
+    work the loop has made; idle across one is ``no_work``, else ``host``."""
+
+    __slots__ = ("_sink", "_count", "_fifo", "_lock", "_ready_at",
+                 "_ready_count")
+
+    def __init__(self, sink, no_work_count=lambda: 0):
+        self._sink = sink
+        self._count = no_work_count
+        self._fifo: deque = deque()
+        self._lock = threading.Lock()
+        self._ready_at: Optional[float] = None  # the last ready seen
+        self._ready_count = 0  # no_work_count() then
+
+    def launched(self, kind: str, handle, at: float, who=None) -> None:
+        """``handle`` (an array the program writes) was launched at ``at``;
+        ``who``: whose it is (`_Dispatch`), None for a program of no live
+        traffic (a warm-up's, a follower's)."""
+        with self._lock:
+            # [kind, handle, launched_at, who, no_work_count, when last
+            #  asked and found running]
+            self._fifo.append([kind, handle, at, who, self._count(), None])
+
+    def poll(self, now: float, own=None, own_ready: bool = False) -> None:
+        """A poll at ``now``: ``own`` is the array the caller waits for and
+        ``own_ready`` what it just answered (everything launched before a
+        ready array is done too, and is not asked)."""
+        fifo = self._fifo
+        try:
+            head = fifo[0]
+        except IndexError:
+            return
+        if head[1] is own and not own_ready:
+            head[5] = now  # most polls: the fetched program, still running
+            return
+        with self._lock:
+            mine = None if own is None else next(
+                (e for e in fifo if e[1] is own), None)
+            through = own_ready and mine is not None
+            if mine is not None and not own_ready:
+                mine[5] = now
+            while fifo:
+                head = fifo[0]
+                if head is mine:
+                    if not own_ready:
+                        return
+                elif not through and not _is_ready(head[1]):
+                    head[5] = now
+                    return
+                fifo.popleft()
+                self._seen(head, now)
+                if head is mine:
+                    return
+
+    def _seen(self, entry: list, now: float) -> None:
+        launched_at = entry[2]
+        before = self._ready_at
+        if before is None or before < launched_at:
+            start = launched_at
+            idle_s = 0.0 if before is None else launched_at - before
+        else:
+            start, idle_s = before, 0.0
+        state = "no_work" if entry[4] != self._ready_count else "host"
+        # (a stamp from another thread may lie before this launch's close)
+        ready = max(now, start)
+        self._ready_at, self._ready_count = ready, self._count()
+        asked = entry[5]
+        self._sink(entry, start, ready,
+                   "poll" if asked is not None and now - asked <= _FRESH_S
+                   else "late", idle_s, state)
+
+
+# An ask this long before the one that found a program ready still vouches
+# for the stamp: two polls of `_fetch` at the benchmark's host's pace.
+_FRESH_S = 0.0025
+
+
+def _is_ready(handle) -> bool:
+    try:
+        return handle.is_ready()
+    except RuntimeError:  # a deleted array: whatever wrote it is done
+        return True
+
+
+def _fetch(arr, kind: str = "", clock: Optional[_ReadyClock] = None) -> np.ndarray:
     """Device→host fetch: start the async copy, poll readiness, then read
     through ``jax.device_get`` (which returns the landed copy). The poll
     keeps the engine's step thread off a blocking transfer call so other
@@ -93,12 +214,19 @@ def _fetch(arr, kind: str = "") -> np.ndarray:
     ``kind`` that asked. The polls are counted and the longest time between
     two kept (``ENGINE_TELEMETRY.polled``): a long wait of many polls at
     their pace is a device that stood still, one of a single long gap a
-    thread that was not let run."""
+    thread that was not let run. ``clock``: the runner's `_ReadyClock`,
+    where ``arr`` was registered with it at its launch; every poll's
+    reading is handed to it."""
     with ENGINE_TELEMETRY.phase("wait", kind):
         arr.copy_to_host_async()
         polls, gap_max = 0, 0.0
         last = time.perf_counter()
-        while not arr.is_ready():
+        while True:
+            ready = arr.is_ready()
+            if clock is not None:
+                clock.poll(last, arr, ready)
+            if ready:
+                break
             # pstlint: disable=async-blocking(0.3 ms device-readiness poll on the engine's dedicated step thread, never on an event loop)
             time.sleep(0.0003)
             now = time.perf_counter()
@@ -108,6 +236,54 @@ def _fetch(arr, kind: str = "") -> np.ndarray:
             last = now
         ENGINE_TELEMETRY.polled(polls, gap_max)
         return np.asarray(jax.device_get(arr))
+
+
+class _Dispatch:
+    """The host's side of one dispatch, around the call that makes it:
+    the wall around the call goes to ``record_dispatch`` when the block
+    closes (the step histogram's number, a compile's cost, the tokens), or
+    later where ``deferred`` (`ModelRunner.prefill_dispatch`). The device's
+    side comes with the clock: the program launched inside the block is
+    registered as this dispatch's (``who``), and when it is seen ready its
+    service time is charged to ``charge`` (``(function, rows)``:
+    `_charge_decode` and the sequences, `_charge_prefill` and the items;
+    None for an embedding) and kept in ``service_s``."""
+
+    __slots__ = ("kind", "key", "charge", "bucket", "tokens", "fill",
+                 "deferred", "in_step", "service_s", "dt", "t0")
+
+    def __init__(self, kind: str, key: tuple, charge, bucket: str, *,
+                 tokens: int, fill: float, deferred: bool = False,
+                 in_step: bool = True):
+        self.kind, self.key, self.charge, self.bucket = kind, key, charge, bucket
+        self.tokens, self.fill = tokens, fill
+        self.deferred, self.in_step = deferred, in_step
+        self.service_s = 0.0
+
+    def __enter__(self) -> "_Dispatch":
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if exc_type is None:
+            self.dt = time.perf_counter() - self.t0
+            if not self.deferred:
+                self.record()
+
+    def record(self) -> None:
+        if self.in_step:
+            with ENGINE_TELEMETRY.phase("postprocess", self.kind):
+                self._record()
+        else:
+            self._record()
+
+    def _record(self) -> None:
+        # pstlint: disable=recompile-risk(key and bucket are this dispatch's own, checked where `_dispatching` was called with them: the record is only made here, when the block closes or the rows are fetched)
+        ENGINE_TELEMETRY.record_dispatch(
+            self.kind, self.key, self.dt, batch_bucket=self.bucket,
+            tokens=self.tokens, fill_ratio=self.fill,
+            device_s=self.service_s,
+        )
 
 
 def shared_prefix_run(
@@ -149,6 +325,30 @@ def _seed_for(seq: Sequence, ahead: int = 0) -> int:
         else xxhash.xxh32(seq.request_id.encode()).intdigest()
     )
     return (base + len(seq.output_token_ids) + ahead) & 0x7FFF_FFFF
+
+
+class _Launch:
+    """A ``pst.launch`` phase whose program goes to the clock: the head of
+    the clock's FIFO is asked once as the phase opens, and the array the
+    block leaves in ``handle`` is registered at the phase's close."""
+
+    __slots__ = ("_clock", "_phase", "_kind", "_who", "handle")
+
+    def __init__(self, clock: _ReadyClock, kind: str, who, meta: dict):
+        self._clock, self._kind, self._who = clock, kind, who
+        self._phase = ENGINE_TELEMETRY.phase("launch", kind, **meta)
+        self.handle = None
+
+    def __enter__(self) -> "_Launch":
+        self._phase.__enter__()
+        self._clock.poll(self._phase.t0)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._phase.__exit__(exc_type, exc, tb)
+        if exc_type is None and self.handle is not None:
+            self._clock.launched(
+                self._kind, self.handle, self._phase.t1, self._who)
 
 
 class ModelRunner:
@@ -600,6 +800,11 @@ class ModelRunner:
         self._prefill_toks = None
         # A dispatch `prefill_dispatch` left for `prefill_fetch` to record.
         self._prefill_record = None
+        # When each launched program was seen ready (`_ReadyClock`).
+        self._clock = _ReadyClock(
+            self._program_ready, lambda: ENGINE_TELEMETRY.no_work_phases)
+        # Service time no live row was left to pay (`_program_ready`).
+        self._owed_s = 0.0
         # Pipelined-burst state: device handles of the burst in flight.
         self._burst = None
         # Per-request cost attribution (docs/observability.md "Cost
@@ -991,22 +1196,18 @@ class ModelRunner:
         toks[0, : len(token_ids)] = token_ids
         length = np.array([len(token_ids)], np.int32)
         key = (self._tel_scope, "encode", T)
-        t0 = time.perf_counter()
         self._host_gap_cancel()
-        with self._device_lock:
+        with self._dispatching(
+            "encode", key, None, f"t{T}", tokens=len(token_ids),
+            fill=len(token_ids) / max(T, 1), in_step=False,
+        ) as who, self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce("encode", (toks, length))
-            out = self._dispatch_encode(toks, length, key)
-        ENGINE_TELEMETRY.record_dispatch(
-            "encode", key, time.perf_counter() - t0,
-            batch_bucket=f"t{T}", tokens=len(token_ids),
-            fill_ratio=len(token_ids) / max(T, 1),
-        )
-        return out
+            return self._dispatch_encode(toks, length, key, who)
 
     def _dispatch_encode(
         self, toks: np.ndarray, length: np.ndarray,
-        key: Optional[tuple] = None,
+        key: Optional[tuple] = None, who: Optional[_Dispatch] = None,
     ) -> np.ndarray:
         if not hasattr(self, "_encode_fn"):
             model = self.model
@@ -1024,12 +1225,13 @@ class ModelRunner:
 
             # pstlint: jit-family=encode
             self._encode_fn = jax.jit(enc, out_shardings=self._repl)
-        out = self.programs.call(
-            key, self._encode_fn,
-            (self.params, jax.device_put(toks, self._repl),
-             jax.device_put(length, self._repl)),
-        )
-        return _fetch(out)[0]
+        with self._launch("encode", who) as launch:
+            launch.handle = out = self.programs.call(
+                key, self._encode_fn,
+                (self.params, jax.device_put(toks, self._repl),
+                 jax.device_put(length, self._repl)),
+            )
+        return _fetch(out, "encode", self._clock)[0]
 
     # ------------------------------------------------------------------
     # Public entry points
@@ -1121,38 +1323,80 @@ class ModelRunner:
             **slots,
         )
 
+    # -- the device's side of a dispatch (`_ReadyClock`) ------------------
+
+    def _dispatching(self, kind: str, key: tuple, charge, bucket: str,
+                     **how) -> _Dispatch:
+        """Around one dispatch call: see `_Dispatch`. ``charge``: the
+        sequences of a decode or verify step, the items of a prefill step,
+        None where no request pays (an embedding)."""
+        if charge is not None:
+            charge = (self._charge_prefill if kind == "prefill"
+                      else self._charge_decode, charge)
+        return _Dispatch(kind, key, charge, bucket, **how)
+
+    def _launch(self, kind: str, who: Optional[_Dispatch] = None,
+                **meta) -> _Launch:
+        """The ``pst.launch`` phase of a program of ``who``'s (None: a
+        warm-up's or a follower's, which moves the clock and no counter)."""
+        return _Launch(self._clock, kind, who, meta)
+
+    def _program_ready(self, entry: list, start: float, ready: float,
+                       seen: str, idle_s: float, idle_state: str) -> None:
+        """The clock's sink: a program was seen ready. Its service time
+        goes to the telemetry and to whoever its dispatch charges, here,
+        when it is known, which for a chained step or a prefill launched
+        ahead of one is a cycle after the dispatch."""
+        kind, _, launched_at, who = entry[:4]
+        ENGINE_TELEMETRY.record_ready(
+            kind, who.bucket if who is not None else "", launched_at, start,
+            ready, seen, idle_s, idle_state, live=who is not None)
+        if who is not None:
+            service = ready - start
+            who.service_s += service
+            if who.charge is not None:
+                # A program none of whose rows is alive any more (the step
+                # a chain had launched before its last member's end was
+                # read) is paid by the rows of the next program charged:
+                # the shares sum to the busy counter.
+                owed = service + self._owed_s
+                charged = who.charge[0](who.charge[1], owed)
+                self._owed_s = 0.0 if charged else owed
+
     # -- per-request cost attribution ------------------------------------
 
-    def _charge_decode(self, seqs: List[Sequence], seconds: float) -> None:
-        """Split one decode/verify dispatch's wall equally across its
-        ACTIVE rows (padding rows and already-finished pipeline members
-        cost nothing; shares sum to the step wall, so pipelined
-        continuations never double-count — each wall segment is charged
-        exactly once)."""
+    def _charge_decode(self, seqs: List[Sequence], seconds: float) -> bool:
+        """Split one decode/verify program's service time equally across
+        its ACTIVE rows when it is seen ready (padding rows and members
+        that finished meanwhile cost nothing; each program's time is
+        charged exactly once, so shares sum to the busy counter). False
+        where no row was left to pay."""
         if not self._cost_enabled or seconds <= 0:
-            return
+            return True
         alive = [s for s in seqs if not s.is_finished]
         if not alive:
-            return
+            return False
         share = seconds / len(alive)
         now = time.monotonic()
         for s in alive:
             s.cost_decode_s += share
             s.charge_kv_pages(now)
+        return True
 
-    def _charge_prefill(self, items: List[PrefillItem], seconds: float) -> None:
-        """Split one prefill step's wall across its chunks by real-token
-        weight (a 2k-token chunk sharing a step with a 64-token one pays
-        accordingly)."""
-        if not self._cost_enabled or seconds <= 0 or not items:
-            return
+    def _charge_prefill(self, items: List[PrefillItem], seconds: float) -> bool:
+        """Split one prefill program's service time across its chunks by
+        real-token weight (a 2k-token chunk sharing a step with a 64-token
+        one pays accordingly)."""
+        if not self._cost_enabled or seconds <= 0:
+            return True
         total = sum(it.end - it.start for it in items)
         if total <= 0:
-            return
+            return False
         now = time.monotonic()
         for it in items:
             it.seq.cost_prefill_s += seconds * (it.end - it.start) / total
             it.seq.charge_kv_pages(now)
+        return True
 
     # -- host-gap accounting (pst_engine_host_gap_seconds) ---------------
 
@@ -1191,18 +1435,13 @@ class ModelRunner:
             key = self._tel_key("decode", batch, (want_lp, greedy))
             Bb = batch["kv_lens"].shape[0]
             self._step_info("decode", f"b{Bb}", seqs, batch, len(seqs))
-        t0 = time.perf_counter()
-        self._host_gap_mark(f"b{Bb}", t0, seqs)
-        rows = self._run(batch, want_lp, greedy, "decode", key)
-        self._host_gap_arm()
-        dt = time.perf_counter() - t0
-        with ENGINE_TELEMETRY.phase("postprocess", "decode"):
-            self._charge_decode(seqs, dt)
-            ENGINE_TELEMETRY.record_dispatch(
-                "decode", key, dt,
-                batch_bucket=f"b{Bb}", tokens=len(seqs),
-                fill_ratio=len(seqs) / Bb,
-            )
+        with self._dispatching(
+            "decode", key, seqs, f"b{Bb}", tokens=len(seqs),
+            fill=len(seqs) / Bb,
+        ) as who:
+            self._host_gap_mark(f"b{Bb}", who.t0, seqs)
+            rows = self._run(batch, want_lp, greedy, "decode", key, who)
+            self._host_gap_arm()
         return rows[: len(seqs)]
 
     def execute_decode_multi(self, seqs: List[Sequence], n_steps: int) -> np.ndarray:
@@ -1231,25 +1470,20 @@ class ModelRunner:
                 "decode", f"b{Bb}xn{n_steps}", seqs, batch,
                 len(seqs) * n_steps, n_steps - 1,
             )
-        t0 = time.perf_counter()
-        self._host_gap_mark(f"b{Bb}xn{n_steps}", t0, seqs)
-        with self._device_lock:
-            if self.publisher is not None:
-                self.publisher.announce(
-                    "multi_step", (batch, counts, n_steps, want_lp, greedy)
+        with self._dispatching(
+            "decode", key, seqs, f"b{Bb}xn{n_steps}",
+            tokens=len(seqs) * n_steps, fill=len(seqs) / Bb,
+        ) as who:
+            self._host_gap_mark(f"b{Bb}xn{n_steps}", who.t0, seqs)
+            with self._device_lock:
+                if self.publisher is not None:
+                    self.publisher.announce(
+                        "multi_step", (batch, counts, n_steps, want_lp, greedy)
+                    )
+                rows = self._dispatch_multi_step(
+                    batch, counts, n_steps, want_lp, greedy, key, who
                 )
-            rows = self._dispatch_multi_step(
-                batch, counts, n_steps, want_lp, greedy, key
-            )
-        self._host_gap_arm()
-        dt = time.perf_counter() - t0
-        with ENGINE_TELEMETRY.phase("postprocess", "decode"):
-            self._charge_decode(seqs, dt)
-            ENGINE_TELEMETRY.record_dispatch(
-                "decode", key, dt,
-                batch_bucket=f"b{Bb}xn{n_steps}", tokens=len(seqs) * n_steps,
-                fill_ratio=len(seqs) / Bb,
-            )
+            self._host_gap_arm()
         return rows[: len(seqs)]
 
     def _penalty_counts_for(
@@ -1306,8 +1540,9 @@ class ModelRunner:
         want_lp: bool = False,
         greedy: bool = False,
         key: Optional[tuple] = None,
+        who: Optional[_Dispatch] = None,
     ) -> np.ndarray:
-        with ENGINE_TELEMETRY.phase("launch", "decode"):
+        with self._launch("decode", who) as launch:
             dev = self._put_batch(batch)
             seed0 = jax.device_put(np.zeros((), np.uint32), self._repl)
             cdev = jax.device_put(counts, self._repl)
@@ -1320,7 +1555,8 @@ class ModelRunner:
                  cdev),
                 (n_steps, want_lp, greedy, with_pen),
             )
-        return self._take_aux(_fetch(toks, "decode"))
+            launch.handle = toks
+        return self._take_aux(_fetch(toks, "decode", self._clock))
 
     # ------------------------------------------------------------------
     # Pipelined decode bursts: one burst always in flight; its token fetch
@@ -1351,23 +1587,19 @@ class ModelRunner:
             self._step_info(
                 "decode", bucket, seqs, batch, len(seqs) * n_steps, n_steps - 1
             )
-        t0 = time.perf_counter()
-        self._host_gap_mark(bucket, t0, seqs)
-        with self._device_lock:
-            if self.publisher is not None:
-                self.publisher.announce(
-                    "burst_start", (batch, counts, n_steps, want_lp, greedy)
-                )
-            self._dispatch_burst_start(
-                batch, counts, n_steps, want_lp, greedy, key)
-        dt = time.perf_counter() - t0
-        with ENGINE_TELEMETRY.phase("postprocess", "decode"):
-            self._charge_decode(seqs, dt)
-            ENGINE_TELEMETRY.record_dispatch(
-                "decode", key, dt,
-                batch_bucket=bucket, tokens=len(seqs) * n_steps,
-                fill_ratio=len(seqs) / Bb,
-            )
+        with self._dispatching(
+            "decode", key, seqs, bucket, tokens=len(seqs) * n_steps,
+            fill=len(seqs) / Bb,
+        ) as who:
+            self._host_gap_mark(bucket, who.t0, seqs)
+            with self._device_lock:
+                if self.publisher is not None:
+                    self.publisher.announce(
+                        "burst_start",
+                        (batch, counts, n_steps, want_lp, greedy)
+                    )
+                self._dispatch_burst_start(
+                    batch, counts, n_steps, want_lp, greedy, key, who)
         # Continuations re-dispatch the same executable: keep the signature
         # so their step timings land in the same bucket without re-counting
         # a compile.
@@ -1381,9 +1613,10 @@ class ModelRunner:
         want_lp: bool = False,
         greedy: bool = False,
         key: Optional[tuple] = None,
+        who: Optional[_Dispatch] = None,
     ) -> None:
         # pipelined: a later step fetches what this launch computes
-        with ENGINE_TELEMETRY.phase("launch", "decode", pipelined=1):
+        with self._launch("decode", who, pipelined=1) as launch:
             dev = self._put_batch(batch)
             seed = jax.device_put(np.zeros((), np.uint32), self._repl)
             cdev = jax.device_put(counts, self._repl)
@@ -1400,6 +1633,7 @@ class ModelRunner:
             )
             # Start the host copy NOW; the eventual fetch finds it resident.
             toks.copy_to_host_async()
+            launch.handle = toks
         # ``rows``: the host's copy of what it owns of the batch, a row a
         # member, renewed in place by every continuation; ``steps``: scan
         # steps launched so far, which is the seed offset the next one
@@ -1504,7 +1738,7 @@ class ModelRunner:
         on the device (`_splice`), so the chain goes on behind the prefill
         with nothing fetched in between."""
         assert self._burst is not None
-        tel = getattr(self, "_burst_tel", None)
+        tel = self._burst_tel  # `burst_start` left it
         with ENGINE_TELEMETRY.phase("batch_build", "decode"):
             st = self._burst
             own = st["rows"]
@@ -1535,50 +1769,43 @@ class ModelRunner:
                     src[row], pos[row] = prefill_row, s.num_tokens
                 refresh, splice = own, (src, pos)
             alive = sum(1 for s in members if not s.is_finished)
-            if tel is not None:
-                # The host's view lags the device by the burst in flight:
-                # a live row holds n more tokens after this burst's first
-                # step than its kv_len here says, and 2n - 1 more after
-                # its last (a joining row's prefill is the program its
-                # view lags by).
-                n = tel[3]
-                self._step_info(
-                    "decode", tel[1], members,
-                    {"kv_lens": np.where(kv_lens > 0, kv_lens + n, 0),
-                     "block_tables": tables},
-                    alive * n, n - 1,
-                )
-        t0 = time.perf_counter()
-        with self._device_lock:
-            if self.publisher is not None:
-                self.publisher.announce("burst_cont", (refresh, splice))
-            rows = self._dispatch_burst_continue(refresh, splice)
-        if tel is not None:
+            # The host's view lags the device by the burst in flight: a
+            # live row holds n more tokens after this burst's first step
+            # than its kv_len here says, and 2n - 1 more after its last (a
+            # joining row's prefill is the program its view lags by).
+            n = tel[3]
+            self._step_info(
+                "decode", tel[1], members,
+                {"kv_lens": np.where(kv_lens > 0, kv_lens + n, 0),
+                 "block_tables": tables},
+                alive * n, n - 1,
+            )
+        key, bucket, rows_b, n = tel
+        # The program launched here is charged, when it is seen ready a
+        # cycle on, to the members still alive then; the host's wall around
+        # this call (launch the next, fetch the one before) is the step
+        # histogram's.
+        # pstlint: disable=recompile-risk(key and bucket are carried verbatim from burst_start's registered _tel_key via _burst_tel — a continuation re-dispatches the same executable, so the shape identity cannot drift)
+        with self._dispatching(
+            "decode", key, members, bucket, tokens=alive * n,
+            fill=alive / max(rows_b, 1),
+        ) as who:
+            with self._device_lock:
+                if self.publisher is not None:
+                    self.publisher.announce("burst_cont", (refresh, splice))
+                rows = self._dispatch_burst_continue(refresh, splice, who)
             # The continuation was dispatched BEFORE the previous burst's
             # tokens were even read: the device runs the two back-to-back,
             # so the host gap on this step is — by construction — zero.
             # Recording it keeps the histogram's percentiles honest about
             # what the pipeline removed (not silently absent at steady
             # state).
-            ENGINE_TELEMETRY.record_host_gap(tel[1], 0.0)
-            key, bucket, rows_b, n = tel
-            dt = time.perf_counter() - t0
-            with ENGINE_TELEMETRY.phase("postprocess", "decode"):
-                # The continuation wall (dispatch next + overlapped fetch
-                # of the previous burst) is charged ONCE across the members
-                # still alive — the share of the just-fetched burst's
-                # device time.
-                self._charge_decode(members, dt)
-                # pstlint: disable=recompile-risk(key and bucket are carried verbatim from burst_start's registered _tel_key via _burst_tel — a continuation re-dispatches the same executable, so the shape identity cannot drift)
-                ENGINE_TELEMETRY.record_dispatch(
-                    "decode", key, dt,
-                    batch_bucket=bucket, tokens=alive * n,
-                    fill_ratio=alive / max(rows_b, 1),
-                )
+            ENGINE_TELEMETRY.record_host_gap(bucket, 0.0)
         return rows
 
     def _dispatch_burst_continue(
-        self, refresh: Dict[str, np.ndarray], splice: Optional[tuple] = None
+        self, refresh: Dict[str, np.ndarray], splice: Optional[tuple] = None,
+        who: Optional[_Dispatch] = None,
     ) -> np.ndarray:
         """``refresh``: what the host renews of the burst's batch: the block
         tables (both groups') and ``kv_lens``, or every array it owns when
@@ -1586,7 +1813,7 @@ class ModelRunner:
         the carry with the last prefill's rows."""
         st = self._burst
         prev = st["toks"]
-        with ENGINE_TELEMETRY.phase("launch", "decode", pipelined=1):
+        with self._launch("decode", who, pipelined=1) as launch:
             st["batch"].update(self._put_batch(refresh))
             if splice is not None:
                 src, pos = jax.device_put(splice, self._repl)
@@ -1603,11 +1830,12 @@ class ModelRunner:
             )
             # Start the host copy NOW; the eventual fetch finds it resident.
             toks.copy_to_host_async()
+            launch.handle = toks
             st.update(
                 tokens=tokens, positions=positions, seed=seed, counts=counts,
                 toks=toks, steps=st["steps"] + st["n"],
             )
-        return self._take_aux(_fetch(prev, "decode"))
+        return self._take_aux(_fetch(prev, "decode", self._clock))
 
     def burst_drain(self) -> np.ndarray:
         """Fetch the in-flight burst's tokens and end the pipeline."""
@@ -1616,7 +1844,7 @@ class ModelRunner:
         # No device op, so no multihost announce: followers hold no pending
         # fetch (they never read tokens) and their next announced dispatch
         # keeps program order identical.
-        rows = self._take_aux(_fetch(st["toks"], "decode"))
+        rows = self._take_aux(_fetch(st["toks"], "decode", self._clock))
         # Drains are transitions (an arrival or shape change broke the
         # pipeline) and a prefill may already be queued behind this fetch —
         # the wall from here to the next decode dispatch is not steady-state
@@ -1648,20 +1876,14 @@ class ModelRunner:
             self._step_info(
                 "spec_verify", f"b{Bb}xk{K}", seqs, batch, len(seqs) * (K + 1)
             )
-        t0 = time.perf_counter()
         self._host_gap_cancel()
-        with self._device_lock:
+        with self._dispatching(
+            "spec_verify", key, seqs, f"b{Bb}xk{K}",
+            tokens=len(seqs) * (K + 1), fill=len(seqs) / Bb,
+        ) as who, self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce("spec_verify", batch)
-            ids, sampled0 = self._dispatch_spec_verify(batch, key)
-        dt = time.perf_counter() - t0
-        with ENGINE_TELEMETRY.phase("postprocess", "spec_verify"):
-            self._charge_decode(seqs, dt)
-            ENGINE_TELEMETRY.record_dispatch(
-                "spec_verify", key, dt,
-                batch_bucket=f"b{Bb}xk{K}", tokens=len(seqs) * (K + 1),
-                fill_ratio=len(seqs) / Bb,
-            )
+            ids, sampled0 = self._dispatch_spec_verify(batch, key, who)
         return ids[: len(seqs)], sampled0[: len(seqs)]
 
     def _spec_batch(
@@ -1716,7 +1938,8 @@ class ModelRunner:
         return batch
 
     def _dispatch_spec_verify(
-        self, batch: Dict[str, np.ndarray], key: Optional[tuple] = None
+        self, batch: Dict[str, np.ndarray], key: Optional[tuple] = None,
+        who: Optional[_Dispatch] = None,
     ) -> np.ndarray:
         if not hasattr(self, "_spec_step"):
             model = self.model
@@ -1777,12 +2000,13 @@ class ModelRunner:
                 donate_argnums=(1,),
                 out_shardings=(self._repl, cache_sh),
             )
-        with ENGINE_TELEMETRY.phase("launch", "spec_verify"):
+        with self._launch("spec_verify", who) as launch:
             packed, self.kv_cache = self.programs.call(
                 key, self._spec_step,
                 (self.params, self.kv_cache, self._put_batch(batch)),
             )
-        packed = _fetch(packed, "spec_verify")
+            launch.handle = packed
+        packed = _fetch(packed, "spec_verify", self._clock)
         return packed[:, :-1], packed[:, -1]
 
     def _prefill_tel(
@@ -1821,16 +2045,11 @@ class ModelRunner:
             key, bucket, real, fill = self._prefill_tel(
                 items, batch, (want_lp, greedy)
             )
-        t0 = time.perf_counter()
         self._host_gap_cancel()
-        rows = self._run(batch, want_lp, greedy, "prefill", key)
-        dt = time.perf_counter() - t0
-        with ENGINE_TELEMETRY.phase("postprocess", "prefill"):
-            self._charge_prefill(items, dt)
-            ENGINE_TELEMETRY.record_dispatch(
-                "prefill", key, dt,
-                batch_bucket=bucket, tokens=real, fill_ratio=fill,
-            )
+        with self._dispatching(
+            "prefill", key, items, bucket, tokens=real, fill=fill,
+        ) as who:
+            rows = self._run(batch, want_lp, greedy, "prefill", key, who)
         return rows[: len(items)]
 
     def execute_prefill_batch_nofetch(self, items: List[PrefillItem]) -> None:
@@ -1849,27 +2068,24 @@ class ModelRunner:
             key, bucket, real, fill = self._prefill_tel(
                 items, batch, (False, True)
             )
-        t0 = time.perf_counter()
         self._host_gap_cancel()
-        with self._device_lock:
+        with self._dispatching(
+            "prefill", key, items, bucket, tokens=real, fill=fill,
+        ) as who, self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce("step_nofetch", batch)
-            self._dispatch_step_nofetch(batch, key)
-        dt = time.perf_counter() - t0
-        with ENGINE_TELEMETRY.phase("postprocess", "prefill"):
-            self._charge_prefill(items, dt)
-            ENGINE_TELEMETRY.record_dispatch(
-                "prefill", key, dt,
-                batch_bucket=bucket, tokens=real, fill_ratio=fill,
-            )
+            self._dispatch_step_nofetch(batch, key, who)
 
     def _dispatch_step_nofetch(
-        self, batch: Dict[str, np.ndarray], key: Optional[tuple] = None
+        self, batch: Dict[str, np.ndarray], key: Optional[tuple] = None,
+        who: Optional[_Dispatch] = None,
     ) -> None:
         # greedy=True: nobody reads an intermediate chunk's sample, so the
         # cheapest sampling variant (plain argmax) is always correct here.
-        with ENGINE_TELEMETRY.phase("launch", "prefill"):
-            _, self.kv_cache = self.programs.call(
+        # The sample is kept all the same, as the clock's handle on the
+        # program: a later fetch's poll sees it ready.
+        with self._launch("prefill", who) as launch:
+            launch.handle, self.kv_cache = self.programs.call(
                 key, self._step["prefill"],
                 (self.params, self.kv_cache, self._put_batch(batch)),
                 (False, True),
@@ -1898,40 +2114,30 @@ class ModelRunner:
             key, bucket, real, fill = self._prefill_tel(
                 items, batch, (want_lp, greedy)
             )
-        t0 = time.perf_counter()
         self._host_gap_cancel()
-        with self._device_lock:
+        with self._dispatching(
+            "prefill", key, items, bucket, tokens=real, fill=fill,
+            deferred=record_at_fetch,
+        ) as who, self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce(
                     "step", (batch, want_lp, greedy, "prefill")
                 )
-            with ENGINE_TELEMETRY.phase("launch", "prefill"):
+            with self._launch("prefill", who) as launch:
                 toks, self.kv_cache = self.programs.call(
                     key, self._step["prefill"],
                     (self.params, self.kv_cache, self._put_batch(batch)),
                     (want_lp, greedy),
                 )
                 toks.copy_to_host_async()
-                self._prefill_toks = toks
-        dt = time.perf_counter() - t0
-
-        def record():
-            with ENGINE_TELEMETRY.phase("postprocess", "prefill"):
-                self._charge_prefill(items, dt)
-                # pstlint: disable=recompile-risk(key is this dispatch's own _prefill_tel key, closed over: the record is only made later, at the fetch)
-                ENGINE_TELEMETRY.record_dispatch(
-                    "prefill", key, dt,
-                    batch_bucket=bucket, tokens=real, fill_ratio=fill,
-                )
-
+                launch.handle = self._prefill_toks = toks
         if record_at_fetch:
-            self._prefill_record = record
-        else:
-            record()
+            self._prefill_record = who.record
         return toks
 
     def prefill_fetch(self, handle, n_items: int) -> np.ndarray:
-        rows = self._take_aux(_fetch(handle, "prefill"))[:n_items]
+        rows = self._take_aux(
+            _fetch(handle, "prefill", self._clock))[:n_items]
         record, self._prefill_record = self._prefill_record, None
         if record is not None:
             record()
@@ -1944,11 +2150,12 @@ class ModelRunner:
         greedy: bool,
         kind: str,
         key: Optional[tuple] = None,
+        who: Optional[_Dispatch] = None,
     ) -> np.ndarray:
         with self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce("step", (batch, want_lp, greedy, kind))
-            return self._dispatch_step(batch, want_lp, greedy, kind, key)
+            return self._dispatch_step(batch, want_lp, greedy, kind, key, who)
 
     def _dispatch_step(
         self,
@@ -1957,19 +2164,23 @@ class ModelRunner:
         greedy: bool,
         kind: str,
         key: Optional[tuple] = None,
+        who: Optional[_Dispatch] = None,
     ) -> np.ndarray:
         """Launch the ``kind`` ("decode" | "prefill") program on ``batch``
         and fetch its packed rows. ``key``: the step's shape key, which
-        finds its program (a follower's replay has none: the jit)."""
-        with ENGINE_TELEMETRY.phase("launch", kind):
+        finds its program (a follower's replay has none: the jit);
+        ``who``: the dispatch whose program this is (a warm-up's and a
+        follower's have none)."""
+        with self._launch(kind, who) as launch:
             toks, self.kv_cache = self.programs.call(
                 key, self._step[kind],
                 (self.params, self.kv_cache, self._put_batch(batch)),
                 (want_lp, greedy),
             )
+            launch.handle = toks
             if kind == "prefill":
                 self._prefill_toks = toks  # a follower's, for `_splice`
-        return self._take_aux(_fetch(toks, kind))
+        return self._take_aux(_fetch(toks, kind, self._clock))
 
     def _take_aux(self, rows: np.ndarray) -> np.ndarray:
         """Split a fetched step's packed rows from what the model reported

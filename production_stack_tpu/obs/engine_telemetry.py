@@ -69,11 +69,32 @@ compile_seconds = Histogram(
 )
 step_duration = Histogram(
     "pst_engine_step_duration_seconds",
-    "Device step wall time (dispatch to fetch), by step kind and padded "
-    "batch bucket; compile-bearing first calls excluded",
+    "The host's wall around one dispatch call, by step kind and padded "
+    "batch bucket; compile-bearing first calls excluded. Of a chained decode "
+    "step that is the launch of the next program and the fetch of the one "
+    "before, of a prefill launched behind a chain the launch alone: not "
+    "the device's time, which is pst_engine_device_step_seconds",
     ["kind", "batch_bucket"],
     registry=ENGINE_TELEMETRY_REGISTRY,
     buckets=_STEP_BUCKETS,
+)
+# Service times between 5 and 100 ms in steps of 8 %: a median is good to
+# one step there; coarse outside.
+_SERVICE_BUCKETS = (
+    0.0005, 0.001, 0.0025,
+    *(round(0.005 * 1.08 ** i, 6) for i in range(39)),
+    0.1, 0.125, 0.16, 0.2, 0.25, 0.5, 1.0, 2.5, 10.0, 60.0)
+device_step_seconds = Histogram(
+    "pst_engine_device_step_seconds",
+    "Service time of one launched program on the device, by step kind: "
+    "from the later of its launch and the program before it being seen "
+    "ready to its own being seen ready by the fetch's poll (one poll, about "
+    "1 ms, a stamp). Programs seen late (found ready with no ask just "
+    "before that found them running: the host set the pace) are in the "
+    "totals and not here",
+    ["kind"],
+    registry=ENGINE_TELEMETRY_REGISTRY,
+    buckets=_SERVICE_BUCKETS,
 )
 # Host gaps span "pipelined, zero by construction" to ~100 ms of serial
 # bookkeeping between bursts on a busy host.
@@ -293,13 +314,60 @@ tenant_device_seconds = Counter(
 )
 device_busy_seconds = Counter(
     "pst_engine_device_busy_seconds",
-    "Cumulative host-timed wall of live-traffic step dispatches (warmup "
-    "precompilation excluded): the host's clock around each step, not a "
-    "device counter — the denominator per-request cost attribution is "
+    "Cumulative service time of the programs live traffic launched (warmup "
+    "precompilation excluded), each counted when the step thread's poll "
+    "sees it ready: ready less the later of its launch and the ready of "
+    "the program before it, so over a busy stretch the sum is last ready "
+    "less first start. The denominator per-request cost attribution is "
     "audited against (sum of request device-seconds must cover >= 90% of "
     "this)",
     registry=ENGINE_TELEMETRY_REGISTRY,
 )
+device_service_seconds = Counter(
+    "pst_engine_device_service_seconds",
+    "The busy counter's seconds by step kind and by how the program's end "
+    "was seen: poll (a poll at most 2.5 ms earlier had found it running: "
+    "the stamp is good to that) or late (none had: it ended at some moment "
+    "before, and its interval holds what the host was late by and the "
+    "device meanwhile idled)",
+    ["kind", "seen"],
+    registry=ENGINE_TELEMETRY_REGISTRY,
+)
+IDLE_STATES = ("host", "no_work")
+device_idle_seconds = Counter(
+    "pst_engine_device_idle_seconds",
+    "Seconds between one program being seen ready and the launch of the "
+    "next where nothing was queued behind it, by state: no_work (the step "
+    "loop waited for work in between) or host (it did not: the device "
+    "waited for the host). High by at most the launch phase's length a "
+    "stretch",
+    ["state"],
+    registry=ENGINE_TELEMETRY_REGISTRY,
+)
+loop_seconds = Counter(
+    "pst_engine_loop_seconds",
+    "The step thread's wall by what the loop was doing: each cycle (an "
+    "intake and the step after it) whole under the step's kind (decode, "
+    "prefill, spec_verify; none for a step that dispatched nothing), each "
+    "wait for work with the intake before it under no_work. Cycles begin "
+    "where the last stretch ended, so the states' changes between two "
+    "scrapes sum to the wall between them (to the stretch in progress)",
+    ["state"],
+    registry=ENGINE_TELEMETRY_REGISTRY,
+)
+loop_cycles = Counter(
+    "pst_engine_loop_cycles",
+    "Stretches counted into pst_engine_loop_seconds_total, by state",
+    ["state"],
+    registry=ENGINE_TELEMETRY_REGISTRY,
+)
+LOOP_STATES = ("decode", "prefill", "spec_verify", "none", "no_work")
+# Every label from the start: a window without the state reads 0.
+_idle_children = {
+    st: device_idle_seconds.labels(state=st) for st in IDLE_STATES}
+_loop_children = {
+    st: (loop_seconds.labels(state=st), loop_cycles.labels(state=st))
+    for st in LOOP_STATES}
 
 _trace_annotation = None
 
@@ -320,11 +388,13 @@ class _Phase:
     profiler's trace (host and device then share a clock) and, on exit,
     its wall time in ``pst_engine_step_phase_seconds``. ``kind`` may be set
     until the phase closes (a step learns its kind while it runs); the
-    trace's copy of it is fixed at entry. A ``wait`` also takes the
-    thread's CPU clock at both ends: the cycle's account leaves the wait's
-    share out (see :class:`_Cycle`)."""
+    trace's copy of it is fixed at entry. ``t0`` and ``t1`` are the wall
+    clock's readings at its ends (the runner's completion clock stamps a
+    launch with its ``pst.launch``'s ``t1`` and reads no clock of its
+    own). A ``wait`` also takes the thread's CPU clock at both ends: the
+    cycle's account leaves the wait's share out (see :class:`_Cycle`)."""
 
-    __slots__ = ("_tel", "name", "kind", "_ann", "_t0", "_c0")
+    __slots__ = ("_tel", "name", "kind", "_ann", "t0", "t1", "_c0")
 
     def __init__(self, tel: "EngineTelemetry", name: str, kind: str, meta: dict):
         self._tel = tel
@@ -342,14 +412,14 @@ class _Phase:
         self._ann.__enter__()
         if self.name == "wait":
             self._c0 = time.thread_time()
-        self._t0 = time.perf_counter()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        dt = time.perf_counter() - self._t0
+        self.t1 = time.perf_counter()
         cpu = time.thread_time() - self._c0 if self.name == "wait" else 0.0
         self._ann.__exit__(*exc)
-        self._tel._phase_done(self, dt, cpu)
+        self._tel._phase_done(self, self.t1 - self.t0, cpu)
 
 
 class _Cycle:
@@ -367,7 +437,8 @@ class _Cycle:
 
     __slots__ = ("t0", "thread_cpu0", "process_cpu0", "profiler_starts0",
                  "phases", "wait_cpu_s", "dispatches", "gc_s", "polls",
-                 "poll_gap_max_s", "store_outcome")
+                 "poll_gap_max_s", "store_outcome", "device_s", "service_s",
+                 "queued_s")
 
     def __init__(self, t0: float, thread_cpu0: float, process_cpu0: float,
                  profiler_starts0: int):
@@ -386,6 +457,13 @@ class _Cycle:
         self.poll_gap_max_s = 0.0
         # what the program store made of a shape this cycle met first
         self.store_outcome = ""
+        # The programs seen ready under the cycle (`record_ready`): their
+        # service times summed, and of the last of them (the one the cycle
+        # fetched) its own and how long, inside the cycle, it stood behind
+        # the others.
+        self.device_s = 0.0
+        self.service_s = 0.0
+        self.queued_s = 0.0
 
 
 class _FirstUse:
@@ -464,9 +542,14 @@ class EngineTelemetry:
         # Flight-recorder sink (obs/flight.py): one ring record a cycle of
         # the step loop; the null recorder makes this free.
         self._flight = NULL_FLIGHT_RECORDER
-        # Live-traffic step-wall accumulator (host-timed) — the denominator
-        # the cost attribution audit sums request costs against.
+        # Live traffic's service time on the device (`record_ready`): the
+        # denominator the cost attribution audit sums request costs against.
         self._device_busy_s = 0.0
+        # Waits for work the loop has made: the runner's clock reads it at
+        # a launch and at a ready, and files the idle between the two under
+        # no_work where it moved.
+        self.no_work_phases = 0
+        self._service_children: Dict[Tuple[str, str], tuple] = {}
         # --no-startup-phases: the gauges stay at 0 (helm
         # servingEngineSpec.observability.startupPhases).
         self.startup_enabled = True
@@ -570,8 +653,8 @@ class EngineTelemetry:
         self._flight = recorder if recorder is not None else NULL_FLIGHT_RECORDER
 
     def device_busy_seconds(self) -> float:
-        """Cumulative live-traffic dispatch wall since process start (or
-        the last reset) — warmup precompilation excluded."""
+        """Cumulative service time of live traffic's programs since process
+        start (or the last reset) — warmup precompilation excluded."""
         with self._lock:
             return self._device_busy_s
 
@@ -604,13 +687,19 @@ class EngineTelemetry:
         tokens: int = 0,
         fill_ratio: Optional[float] = None,
         count_busy: bool = True,
+        device_s: float = 0.0,
     ) -> bool:
         """Record one device dispatch; returns True when this was the
         first call for its shape bucket (i.e. it paid a compile).
+        ``seconds`` is the host's wall around the dispatch call (the step
+        histogram's, and a compile's cost); the device's time is not known
+        here and comes with `record_ready`. ``device_s``: the service time
+        of a dispatch outside the step loop, which fetched what it launched
+        (an embedding's encode), for its flight record.
 
         ``count_busy=False`` marks warmup-precompile dispatches: they
         compile real executables but serve no request, so they stay out
-        of the device-busy denominator and the flight ring."""
+        of the flight ring."""
         seconds = max(seconds, 0.0)
         with self._lock:
             compiled = shape_key not in self._seen_shapes
@@ -628,20 +717,18 @@ class EngineTelemetry:
                 now = time.monotonic()
                 self._tok_samples.append((now, kind, tokens))
                 self._drop_old_samples_locked(now)
-            if count_busy:
-                self._device_busy_s += seconds
         if count_busy:
-            device_busy_seconds.inc(seconds)
             # Flight ring (obs/flight.py): a live dispatch of the open step
-            # rides its cycle's record; any other (an embedding's encode)
-            # is a record of its own.
+            # rides its cycle's record, whose last row takes the device
+            # time the cycle saw (`_cycle_done`); any other (an embedding's
+            # encode) is a record of its own.
             cycle = self._open_cycle()
             if cycle is not None:
                 cycle.dispatches.append(
-                    (kind, batch_bucket, seconds, compiled, tokens))
+                    (kind, batch_bucket, 0.0, compiled, tokens))
             else:
                 self._flight.record_step(
-                    kind, batch_bucket, seconds, compiled=compiled,
+                    kind, batch_bucket, device_s, compiled=compiled,
                     tokens=tokens,
                 )
         if compiled:
@@ -658,6 +745,53 @@ class EngineTelemetry:
                 min(max(fill_ratio, 0.0), 1.0)
             )
         return compiled
+
+    def record_ready(
+        self, kind: str, bucket: str, launched_at: float, start: float,
+        ready: float, seen: str, idle_s: float = 0.0,
+        idle_state: str = "host", live: bool = True,
+    ) -> None:
+        """One launched program seen ready (engine/runner.py ``_ReadyClock``;
+        the three stamps are `perf_counter` readings the step thread had
+        made anyway): ``start`` is the later of its launch and the ready of
+        the program before it, so ``ready - start`` is its service time on
+        a device that runs programs in launch order, and ``idle_s`` what
+        lay between that earlier ready and this launch. To the busy
+        counter, the service histogram (not a program ``seen`` late), the
+        idle counter, the open cycle's record, and a zero-length
+        ``pst.ready`` span beside the program's own end in a capture.
+        ``live`` false: a warm-up's program, which moves the clock and no
+        counter."""
+        service = max(ready - start, 0.0)
+        queued = max(start - launched_at, 0.0)
+        with _annotation(
+            "pst.ready", kind=kind, bucket=bucket,
+            service_us=int(service * 1e6), queued_us=int(queued * 1e6),
+            seen=seen,
+        ):
+            pass
+        if not live:
+            return
+        with self._lock:
+            self._device_busy_s += service
+        device_busy_seconds.inc(service)
+        children = self._service_children.get((kind, seen))
+        if children is None:
+            children = self._service_children[(kind, seen)] = (
+                device_service_seconds.labels(kind=kind, seen=seen),
+                device_step_seconds.labels(kind=kind))
+        children[0].inc(service)
+        if seen != "late":
+            children[1].observe(service)
+        if idle_s > 0:
+            _idle_children[idle_state].inc(idle_s)
+        cycle = self._open_cycle()
+        if cycle is not None:
+            cycle.device_s += service
+            cycle.service_s = service
+            # behind others inside this cycle: what it stood before the
+            # cycle began was the cycle before's to wait out
+            cycle.queued_s = max(start - max(launched_at, cycle.t0), 0.0)
 
     def record_host_gap(
         self, batch_bucket: str, seconds: float,
@@ -712,9 +846,9 @@ class EngineTelemetry:
         return self._cycles.get(self._step_tid) if self._on_step_thread() else None
 
     def _cycle_opened(self, follows: bool = True) -> None:
-        """A cycle starts where the last one of this thread ended (all
-        three clocks from one mark), unless the loop idled in between,
-        nothing ended here yet, or no loop runs the steps (``follows``
+        """A cycle starts where the last stretch of this thread ended, a
+        cycle or a wait for work (all three clocks from one mark), unless
+        nothing ended here yet or no loop runs the steps (``follows``
         false: what lies between two steps is their caller's then)."""
         tid = threading.get_ident()
         mark = self._cpu_mark
@@ -767,9 +901,27 @@ class EngineTelemetry:
             if cycle is not None:
                 cycle.phases[("intake", "")] = seconds
         elif phase.name == "no_work":
-            # the intake before it found nothing to step
-            self._cycles.pop(threading.get_ident(), None)
+            # The intake before it found nothing to step: its cycle is
+            # dropped, and the stretch from where that began is the loop
+            # account's `no_work`. The next cycle begins here.
+            tid = threading.get_ident()
+            dropped = self._cycles.pop(tid, None)
+            self.no_work_phases += 1
+            self._loop_account(
+                "no_work", phase.t1 - dropped.t0 if dropped else seconds)
+            self._cpu_mark = (
+                tid, phase.t1, time.thread_time(), time.process_time())
         self._observe_phase(phase.name, phase.kind, seconds)
+
+    def _loop_account(self, state: str, seconds: float) -> None:
+        """One stretch of the step thread's wall, to the window account."""
+        children = _loop_children.get(state)
+        if children is None:
+            children = _loop_children[state] = (
+                loop_seconds.labels(state=state),
+                loop_cycles.labels(state=state))
+        children[0].inc(max(seconds, 0.0))
+        children[1].inc()
 
     def _cycle_done(self, cycle: _Cycle, kind: str) -> None:
         """The cycle's account, to the off-CPU histogram and to the flight
@@ -780,6 +932,7 @@ class EngineTelemetry:
         thread_cpu, process_cpu = time.thread_time(), time.process_time()
         self._cpu_mark = (threading.get_ident(), now, thread_cpu, process_cpu)
         cycle_s = now - cycle.t0
+        self._loop_account(kind or "none", cycle_s)
         sums = [0.0] * len(PHASE_AT)
         for (name, _), wall in cycle.phases.items():
             sums[PHASE_AT[name]] += wall
@@ -801,11 +954,19 @@ class EngineTelemetry:
             gc_s += now - self._gc_t0
             self._gc_counted = True
         starts = self._profiler_starts
+        # The record's last row takes the device time the cycle saw (a
+        # cycle that only fetched, a chain's drain, has no row but its own).
+        dispatches = cycle.dispatches
+        if dispatches:
+            dispatches[-1] = (*dispatches[-1][:2], cycle.device_s,
+                              *dispatches[-1][3:])
+        elif cycle.device_s:
+            dispatches = [(kind or "none", "", cycle.device_s, False, 0)]
         stall = self._flight.record_cycle(
-            cycle.dispatches, cycle_s,
+            dispatches, cycle_s,
             (*sums, max(offcpu, 0.0), max(thread_cpu_s, 0.0),
              process_cpu - cycle.process_cpu0, gc_s, cycle.polls,
-             cycle.poll_gap_max_s),
+             cycle.poll_gap_max_s, cycle.service_s, cycle.queued_s),
             kind=kind,
             # a capture that started under the cycle held it, not the engine
             held_to_bar=not (starts & 1 or starts != cycle.profiler_starts0),
@@ -975,6 +1136,7 @@ class EngineTelemetry:
             self._cpu_mark = None
             self._offcpu_carry = 0.0
             self._device_busy_s = 0.0
+            self.no_work_phases = 0
             self.startup_enabled = True
         self._flight = NULL_FLIGHT_RECORDER
 

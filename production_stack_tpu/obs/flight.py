@@ -6,22 +6,25 @@ THIS request"; the flight recorder answers the question a lone tail
 outlier leaves open — "what exactly was the engine doing when that p99
 outlier happened, and what held it?" One fixed-size record a cycle of the
 step loop (one ``pst.intake`` and the ``pst.step`` after it), carried by
-the cycle's last dispatch: step kind, padded batch bucket, the dispatch's
-host-timed wall, the host gap that preceded it, queue depths, KV occupancy,
-preemption count, tenant tier mix, whether a dispatch absorbed an XLA
-compile — and the cycle's own account: its wall, the six phase sums, the
-step thread's off-CPU and CPU time, the process's CPU time, collection
-pauses, and how the fetch polled.
+the cycle's last dispatch: step kind, padded batch bucket, the service
+time of the programs the cycle saw ready (``device_s``), the host gap that
+preceded it, queue depths, KV occupancy, preemption count, tenant tier mix,
+whether a dispatch absorbed an XLA compile — and the cycle's own account:
+its wall, the six phase sums, the step thread's off-CPU and CPU time, the
+process's CPU time, collection pauses, how the fetch polled, and of the
+program it fetched the service time and how long it stood behind others.
 
 Design constraints, in order:
 
 - **Always on.** The ring is a preallocated list of ``capacity`` slots
   written round-robin under a tiny lock — no allocation grows with
   uptime, and the per-cycle cost is one tuple build + one list store.
-- **Post-mortem by construction.** A cycle past the bar
-  (``outlier_factor`` × the rolling median ``cycle_s`` of its
-  ``(kind, bucket)`` and of whether it fetched, floored and armed as
-  below) is a *stall*: the
+- **Post-mortem by construction.** A cycle whose wall, less what the
+  program it fetched stood behind others inside it (``queued_s``: a decode
+  step launched behind a long prefill program waits that program out, and
+  nothing stalled), is past the bar (``outlier_factor`` × the rolling
+  median of the same of its ``(kind, bucket)`` and of whether it fetched,
+  floored and armed as below) is a *stall*: the
   recorder names its cause (:func:`stall_cause`, a pure function of the
   record) and snapshots the ring — so any such event leaves a trace
   naming the stalled step's bucket, queue state and cause even if nobody
@@ -62,11 +65,13 @@ _DISPATCH_FIELDS = (
 CYCLE_FIELDS = (
     "cycle_s", *(p + "_s" for p in CYCLE_PHASES), "offcpu_s",
     "thread_cpu_s", "process_cpu_s", "gc_s", "polls", "poll_gap_max_s",
+    "service_s", "queued_s",
 )
 FIELDS = _DISPATCH_FIELDS + CYCLE_FIELDS
 _NO_CYCLE = (None,) * len(CYCLE_FIELDS)
 # Where each phase's sum lies in a cycle's account (after cycle_s).
 PHASE_AT = {name: i for i, name in enumerate(CYCLE_PHASES)}
+_QUEUED_AT = CYCLE_FIELDS.index("queued_s") - 1
 
 STALL_CAUSES = (
     "compile", "gc", "device", "machine", "interpreter", "host_work",
@@ -86,9 +91,12 @@ def stall_cause(rec: dict, excess_s: float) -> str:
 
     - ``compile``: a dispatch of the cycle was its shape's first;
     - ``gc``: collections paused the process for half the excess or more;
-    - ``device``: half the excess or more lies in ``wait`` and the fetch
-      polled at its pace throughout — the device, the runtime or the
-      transfer stood still, not this thread;
+    - ``device``: half the excess or more lies in ``wait``, the fetch
+      polled at its pace throughout, and the service time of the program
+      it fetched carries half the excess itself — the device, the runtime
+      or the transfer stood still, not this thread, and not behind another
+      program (a record from before ``service_s`` was kept is read as it
+      was: by its wait);
     - ``machine``: the step thread was kept off the CPU (two polls far
       apart, or off-CPU time outside its waits, where it never sleeps) and
       the process's CPU time advanced by less than half the excess —
@@ -109,7 +117,8 @@ def stall_cause(rec: dict, excess_s: float) -> str:
         return "compile"
     if rec["gc_s"] >= half:
         return "gc"
-    if rec["wait_s"] >= half and rec["poll_gap_max_s"] < _POLL_GAP_HELD_S:
+    if (min(rec["wait_s"], rec.get("service_s", rec["wait_s"])) >= half
+            and rec["poll_gap_max_s"] < _POLL_GAP_HELD_S):
         return "device"
     if rec["poll_gap_max_s"] >= half or rec["offcpu_s"] >= half:
         if rec["process_cpu_s"] <= half - _CPU_TICK_S:
@@ -211,8 +220,8 @@ class FlightRecorder:
                 self._restored = load_snapshot_dir(
                     self.snapshot_dir, limit=self._snapshot_disk_keep
                 )
-        # ((kind, bucket, fetched) -> recent cycle_s samples) for the
-        # rolling median.
+        # ((kind, bucket, fetched) -> recent samples of cycle_s less
+        # queued_s) for the rolling median.
         self._samples: Dict[Tuple[str, str, bool], "deque[float]"] = {}
         # Engine-supplied closure: () -> dict(waiting, running, swapped,
         # batch_tier_rows, kv_occupancy, preemptions). Must be cheap and
@@ -293,10 +302,11 @@ class FlightRecorder:
         live dispatch of the cycle, in order; the last carries the cycle
         (a cycle that dispatched nothing is a record of its own, of the
         step's ``kind``). ``account``: the values of ``CYCLE_FIELDS`` after
-        ``cycle_s``. Returns the stall (its snapshot's ``detail``) when the
-        cycle passed the bar, else None. A cycle not ``held_to_bar`` (a
-        profiler started under it) is recorded, sets no baseline and is no
-        stall."""
+        ``cycle_s``. Held to the bar, and kept for the median, is the
+        cycle's wall less its ``queued_s``. Returns the stall (its
+        snapshot's ``detail``) when the cycle passed the bar, else None. A
+        cycle not ``held_to_bar`` (a profiler started under it) is
+        recorded, sets no baseline and is no stall."""
         if not self.enabled:
             return None
         compiled = any(d[3] for d in dispatches)
@@ -308,7 +318,8 @@ class FlightRecorder:
         rows.append(self._row(last, (cycle_s, *account)))
         # Nothing at or under the floor is a stall: the median is looked up
         # only for the few cycles above it.
-        slow = compiled or cycle_s > self._MIN_OUTLIER_S
+        held = cycle_s - account[_QUEUED_AT]
+        slow = compiled or held > self._MIN_OUTLIER_S
         median = None
         with self._lock:
             for row in rows:
@@ -327,7 +338,7 @@ class FlightRecorder:
             if not compiled:
                 if slow and len(dq) >= self._MIN_SAMPLES:
                     median = sorted(dq)[len(dq) // 2]
-                dq.append(cycle_s)
+                dq.append(held)
         if not slow:
             return None
         if compiled:
@@ -336,10 +347,10 @@ class FlightRecorder:
             bar = max(median * self.outlier_factor, self._MIN_OUTLIER_S)
         else:
             return None
-        if cycle_s <= bar:
+        if held <= bar:
             return None
         rec = _row_dict(rows[-1])
-        excess = cycle_s - (median or 0.0)
+        excess = held - (median or 0.0)
         detail = dict(
             rec,
             cause=stall_cause(rec, excess),

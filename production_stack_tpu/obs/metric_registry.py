@@ -94,6 +94,16 @@ REGISTRY: Tuple[MetricSpec, ...] = (
     MetricSpec("pst_request_device_seconds", HISTOGRAM, "obs/engine_telemetry.py"),
     MetricSpec("pst_tenant_device_seconds", COUNTER, "obs/engine_telemetry.py"),
     MetricSpec("pst_engine_device_busy_seconds", COUNTER, "obs/engine_telemetry.py"),
+    # The engine's own clock for the device (engine/runner.py
+    # ``_ReadyClock``): each launched program's service time from
+    # ready-to-ready stamps, the idle between, and the step thread's wall
+    # by what the loop was doing (docs/observability.md "The device's
+    # time, from the engine").
+    MetricSpec("pst_engine_device_step_seconds", HISTOGRAM, "obs/engine_telemetry.py"),
+    MetricSpec("pst_engine_device_service_seconds", COUNTER, "obs/engine_telemetry.py"),
+    MetricSpec("pst_engine_device_idle_seconds", COUNTER, "obs/engine_telemetry.py"),
+    MetricSpec("pst_engine_loop_seconds", COUNTER, "obs/engine_telemetry.py"),
+    MetricSpec("pst_engine_loop_cycles", COUNTER, "obs/engine_telemetry.py"),
     # --- resilience/metrics.py: breakers, deadlines, hedges, resume -----
     MetricSpec("pst_resilience_breaker_state", GAUGE, "resilience/metrics.py"),
     MetricSpec("pst_resilience_breaker_transitions_total", COUNTER, "resilience/metrics.py"),

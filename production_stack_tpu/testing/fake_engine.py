@@ -351,6 +351,8 @@ class FakeEngineState:
             "offcpu_s": 0.0, "thread_cpu_s": 0.0005,
             "process_cpu_s": 0.0005, "gc_s": 0.0,
             "polls": int(device_s / 0.0003), "poll_gap_max_s": 0.0003,
+            # the program the cycle fetched ran all of it, behind nothing
+            "service_s": device_s, "queued_s": 0.0,
         }
 
     def record_flight(self, prompt_tokens: int, n_tokens: int) -> None:
@@ -1240,6 +1242,25 @@ def create_fake_engine_app(
                 'pst_engine_step_duration_seconds_bucket{kind="decode",batch_bucket="b4",le="+Inf"} 10',
                 'pst_engine_step_duration_seconds_sum{kind="decode",batch_bucket="b4"} 0.5',
                 'pst_engine_step_duration_seconds_count{kind="decode",batch_bucket="b4"} 10',
+                # The device's own time of the same ten steps (the engine's
+                # completion clock), beside the host's wall above.
+                "# TYPE pst_engine_device_step_seconds histogram",
+                'pst_engine_device_step_seconds_bucket{kind="decode",le="0.05"} 9',
+                'pst_engine_device_step_seconds_bucket{kind="decode",le="+Inf"} 9',
+                'pst_engine_device_step_seconds_sum{kind="decode"} 0.45',
+                'pst_engine_device_step_seconds_count{kind="decode"} 9',
+                "# TYPE pst_engine_device_service_seconds counter",
+                'pst_engine_device_service_seconds_total{kind="decode",seen="poll"} 0.45',
+                'pst_engine_device_service_seconds_total{kind="decode",seen="late"} 0.05',
+                "# TYPE pst_engine_device_idle_seconds counter",
+                'pst_engine_device_idle_seconds_total{state="host"} 0.02',
+                'pst_engine_device_idle_seconds_total{state="no_work"} 1.5',
+                "# TYPE pst_engine_loop_seconds counter",
+                'pst_engine_loop_seconds_total{state="decode"} 0.52',
+                'pst_engine_loop_seconds_total{state="no_work"} 1.5',
+                "# TYPE pst_engine_loop_cycles counter",
+                'pst_engine_loop_cycles_total{state="decode"} 10',
+                'pst_engine_loop_cycles_total{state="no_work"} 30',
                 "# TYPE pst_engine_batch_fill_ratio histogram",
                 'pst_engine_batch_fill_ratio_bucket{kind="decode",le="+Inf"} 10',
                 'pst_engine_batch_fill_ratio_sum{kind="decode"} 7.5',

@@ -114,6 +114,16 @@ def cost(cycles: int = CYCLES) -> dict:
     value = np.zeros(3, np.int32)
     key = ("cost", "decode", ("b16xn1",))
     clock = [1000.0]
+    # The runner's clock for the device (PR 51; a tree before it has none,
+    # and this script runs there too): each cycle asks the head as its
+    # launch opens, registers the program at the launch's close, and the
+    # fetch's last poll sees the one before ready: a stamp, the counters,
+    # the histogram, a pst.ready span and the cycle's record.
+    ready = getattr(runner, "_ReadyClock", None)
+    if ready is not None:
+        ready = ready(lambda e, start, at, seen, idle_s, state: tel.record_ready(
+            e[0], "b16xn1", e[2], start, at, seen, idle_s, state))
+    in_flight = []
 
     def tick():
         clock[0] += 0.012
@@ -128,10 +138,15 @@ def cost(cycles: int = CYCLES) -> dict:
             with tel.phase("batch_build", "decode"):
                 tel.step_info("decode", bucket="b16xn1", rows=16,
                               new_tokens=16, kv_tokens=100_000, kv_pages=800)
-            with tel.phase("launch", "decode", pipelined=1):
-                pass
-            with tel.phase("wait", "decode"):
-                pass
+            with tel.phase("launch", "decode", pipelined=1) as launch:
+                if ready is not None:
+                    ready.poll(launch.t0)
+            if ready is not None:
+                in_flight.append(Ready())
+                ready.launched("decode", in_flight[-1], launch.t1, "who")
+            with tel.phase("wait", "decode") as wait:
+                if len(in_flight) > 1:
+                    ready.poll(wait.t0, in_flight.pop(0), True)
             tel.record_host_gap("b16xn1", 0.0)
             with tel.phase("postprocess", "decode"):
                 tel.record_dispatch("decode", key, 0.012, batch_bucket="b16xn1",
@@ -144,7 +159,14 @@ def cost(cycles: int = CYCLES) -> dict:
     real_sleep, real_monotonic = time.sleep, time.monotonic
     try:
         time.sleep = lambda s: None  # the poll's own work, not its 0.3 ms
-        fetch_ns = _ns_each(lambda: runner._fetch(Ready(), "decode"), cycles // 10)
+        def fetch():
+            if ready is None:
+                return runner._fetch(Ready(), "decode")
+            own = Ready()
+            ready.launched("decode", own, time.perf_counter())
+            return runner._fetch(own, "decode", ready)
+
+        fetch_ns = _ns_each(fetch, cycles // 10)
         out["fetch_of_40_polls_ns"] = fetch_ns
         time.monotonic = tick
         for _ in range(1000):  # the throughput window fills: 833 samples

@@ -108,8 +108,9 @@ def test_flight_outlier_snapshot_names_bucket_and_queue_state():
         assert _cycle(rec, 0.03, wait_s=0.02) is None
     assert rec.snapshots() == []
     # The 120s-style stall: one cycle far past 3x the bucket median, all
-    # of it in a wait that polled at its pace.
-    stall = _cycle(rec, 1.5, wait_s=1.49, polls=4000, poll_gap_max_s=0.0004)
+    # of it in a wait that polled at its pace for a program that took it.
+    stall = _cycle(rec, 1.5, wait_s=1.49, service_s=1.49, polls=4000,
+                   poll_gap_max_s=0.0004)
     snaps = rec.snapshots()
     assert len(snaps) == 1
     snap = snaps[0]
@@ -176,7 +177,13 @@ def test_a_cycle_of_two_dispatches_rides_its_last_and_of_none_its_own():
 _CAUSE_CASES = [
     ("compile", dict(compiled=True, gc_s=1.0, wait_s=1.0)),
     ("gc", dict(gc_s=0.5, wait_s=0.9, poll_gap_max_s=0.0004)),
-    ("device", dict(wait_s=0.5, poll_gap_max_s=0.019, thread_cpu_s=0.6)),
+    ("device", dict(wait_s=0.5, service_s=0.5, poll_gap_max_s=0.019,
+                    thread_cpu_s=0.6)),
+    # service time long: the program the cycle fetched took the excess itself
+    ("device", dict(wait_s=1.0, service_s=0.98, poll_gap_max_s=0.0011)),
+    # a wait that polled at its pace for a program that ran its usual time:
+    # whatever the thread waited for, the device did not stand still
+    ("unknown", dict(wait_s=0.9, service_s=0.017, poll_gap_max_s=0.0011)),
     ("machine", dict(wait_s=0.9, poll_gap_max_s=0.9, process_cpu_s=0.1)),
     ("machine", dict(postprocess_s=0.9, offcpu_s=0.5, process_cpu_s=0.48)),
     ("interpreter", dict(wait_s=0.9, poll_gap_max_s=0.8, process_cpu_s=0.9)),
@@ -209,6 +216,29 @@ def test_stall_cause_is_a_pure_function_of_the_record(cause, account):
     assert stall["cause"] == cause
     assert fr.snapshots()[-1]["reason"] == (
         "compile" if compiled else "tail_outlier")
+
+
+def test_queued_behind_a_long_program_is_no_stall():
+    """A chained 17 ms decode step launched behind a 45 ms prefill program
+    waits that program out: 62 ms a cycle at a median of 17, and nothing
+    stalled. The bar is held to the cycle's wall less what the fetched
+    program stood behind others inside it; the same wall with nothing
+    queued is a stall of the device, and a record from before the clock
+    (no ``service_s``) is read by its wait as it was."""
+    rec = FlightRecorder(capacity=64)
+    for _ in range(16):
+        assert _cycle(rec, 0.017, bucket="b64xn1", wait_s=0.015,
+                      service_s=0.017) is None
+    assert _cycle(rec, 0.062, bucket="b64xn1", wait_s=0.060, service_s=0.017,
+                  queued_s=0.045) is None
+    assert rec.snapshots() == []
+    # the median the next cycle is held to did not move either
+    stall = _cycle(rec, 0.062, bucket="b64xn1", wait_s=0.060, service_s=0.062)
+    assert stall["cause"] == "device" and stall["median_s"] == 0.017
+    assert stall["excess_s"] == pytest.approx(0.045)
+    assert (stall["service_s"], stall["queued_s"]) == (0.062, 0.0)
+    old = {k: v for k, v in stall.items() if k != "service_s"}
+    assert stall_cause(old, stall["excess_s"]) == "device"
 
 
 def test_flight_outlier_bar_floors_small_steps():
@@ -543,7 +573,8 @@ async def test_engine_debug_flight_and_cost_header():
         await asyncio.sleep(0.1)  # the burst still in flight is drained
         for _ in range(12):
             _cycle(rec, 0.03, bucket="b8")
-        _cycle(rec, 2.0, bucket="b8", wait_s=1.9, poll_gap_max_s=0.0004)
+        _cycle(rec, 2.0, bucket="b8", wait_s=1.9, service_s=1.9,
+               poll_gap_max_s=0.0004)
         async with sess.get(f"{server.url}/debug/flight?n=4") as r:
             flight = await r.json()
         assert len(flight["records"]) == 4

@@ -122,7 +122,19 @@ def test_every_phase_of_the_table_in_every_step(traced_run):
     # a collection or a stall may fall into any capture; nothing else may
     names = {ev[0] for ev in events} - {"pst.gc", "pst.stall"}
     assert names == {"pst." + p for p in IN_STEP + ("step", "step_info",
-                                                    "intake", "no_work")}
+                                                    "intake", "no_work",
+                                                    "ready")}
+    # each program launched under the capture is stamped once when a poll
+    # sees it ready (a zero-length pst.ready: the engine's clock for the
+    # device), inside the wait that saw it or the launch that asked
+    ready = [ev for ev in events if ev[0] == "pst.ready"]
+    launched = [ev for ev in events if ev[0] == "pst.launch"]
+    assert 0 < len(ready) <= len(launched) + 1
+    for ev in ready:
+        assert ev[2] < 1e5 and set(ev[3]) == {  # ns: a span of no length
+            "kind", "bucket", "service_us", "queued_us", "seen"}
+        assert ev[3]["kind"] in ("prefill", "decode")
+        assert ev[3]["seen"] in ("poll", "late") and ev[3]["service_us"] >= 0
     steps = [ev for ev in events if ev[0] == "pst.step"]
     assert len(steps) >= 4  # a prefill step and three decode steps at least
     covered = total = 0.0
