@@ -331,6 +331,12 @@ class EngineMetrics:
             "expert layers evaluated by fetched steps: the denominator of "
             "a mean a layer and step over the pst:moe_* counters",
         )
+        self.moe_dispatch_overflow = counter(
+            "pst:moe_dispatch_overflow",
+            "of pst:moe_layer_steps, the expert layers whose held pairs "
+            "passed the dispatch's row capacity, so that further rounds "
+            "ran (models/moe_dispatch.py::capacity)",
+        )
         self.mla_prefill_steps = counter(
             "pst:mla_prefill_steps",
             "fetched prefill steps of a latent-attention model, by the way "
@@ -508,6 +514,7 @@ class EngineMetrics:
             (self.moe_busiest_expert_pairs, "moe_busiest_expert_pairs_total"),
             (self.moe_experts_touched, "moe_experts_touched_total"),
             (self.moe_layer_steps, "moe_layer_steps_total"),
+            (self.moe_dispatch_overflow, "moe_dispatch_overflow_total"),
         ):
             self._counter_to(metric, key, stats.get(key, 0))
         for path in ("expanded", "absorbed"):

@@ -3,7 +3,7 @@ grouped products.
 
     chiprun -- python scripts/tpu_moe_combine_attrib.py [--tag x] [--shapes qwen_t1024 ...]
 
-One expert layer as the three expert cells' models call it
+One expert layer as the four expert cells' models call it
 (``moe_dispatch.routed_experts``: router, sort by expert, ``x[tok]``, the
 body's two ``megablox.gmm`` products, the weighted sum back at the tokens),
 at each cell's published widths and at the step shapes its programs have: a
@@ -14,9 +14,16 @@ the JAX profiler and the device's ``XLA Ops`` are summed by name: the
 which is the dispatch's bookkeeping (PERF.md §6, PR 42). Times are per layer,
 in microseconds, the mean over every layer of every traced repeat.
 
+Since PR 52 the bookkeeping is also summed by **item**, under names that
+do not depend on how a tree computes them (``ITEMS``): each traced
+operation is looked up in the compiled program's text and put by the scope
+it was traced under (``moe_router``, ``moe_experts``, this script's own
+``attrib_glue``), by what its root does and by its result's shape. An
+operation XLA fused across two items counts under its root's.
+
 The script imports nothing of the program but ``routed_experts`` (and the
 benchmark's ``perf/trace.py`` to read the capture), so a copy of it runs on a
-tree whose dispatch goes back another way: copy it into that tree's
+tree whose dispatch goes another way: copy it into that tree's
 ``scripts/`` and compare the two reports
 (``chiprun_out/moe_combine_attrib/<tag>.json``).
 """
@@ -25,6 +32,7 @@ import argparse
 import glob
 import json
 import os
+import re
 import shutil
 import sys
 import time
@@ -53,7 +61,10 @@ MODELS = {
                      bank=None),
     "glm": dict(d=2048, k_in=2048, inner=1536, gated=True, scored=64,
                 held=64, top_k=4, scoring="sigmoid", scale=1.8, bank=2),
+    "mellum": dict(d=2304, k_in=2304, inner=896, gated=True, scored=64,
+                   held=16, top_k=8, scoring="softmax", scale=1.0, bank=2),
 }
+MODELS["mellum_drawn"] = dict(MODELS["mellum"], drawn=True)
 # name -> model, tokens of the step as padded, its token budget, real tokens
 SHAPES = {
     "qwen_t1024": ("qwen", 1024, 1024, 700),
@@ -66,7 +77,18 @@ SHAPES = {
     "glm_t1024": ("glm", 1024, 1024, 700),
     "glm_t4096": ("glm", 4096, 1024, 700),
     "glm_b16": ("glm", 16, None, 16),
+    "mellum_t1024": ("mellum", 1024, 1024, 700),
+    "mellum_t2048": ("mellum", 2048, 1024, 700),
+    "mellum_b32": ("mellum", 32, None, 24),
+    # a short chunk, and the same with every pair drawn to this share (a
+    # bias on its experts): 256 held pairs at a capacity of 128, two rounds
+    "mellum_t32": ("mellum", 32, None, 32),
+    "mellum_t32_drawn": ("mellum_drawn", 32, None, 32),
 }
+# What one expert layer's time is made of, beside the ``%gmm`` products.
+ITEMS = ("xs gather", "body elementwise", "way back", "router pick",
+         "router top-k", "router product and scores", "sorts", "counts",
+         "gmm metadata", "packing", "script", "other")
 
 
 def _weights(m):
@@ -77,7 +99,8 @@ def _weights(m):
                        ).astype(jnp.bfloat16)
     return dict(
         w_router=jax.random.normal(keys[0], (m["d"], m["scored"]), jnp.float32),
-        bias=0.01 * jax.random.normal(keys[1], (m["scored"],), jnp.float32),
+        bias=0.01 * jax.random.normal(keys[1], (m["scored"],), jnp.float32)
+        + (jnp.arange(m["scored"]) < m["held"]) * (2.0 if m.get("drawn") else 0.0),
         w1=bf(keys[2], (groups, m["k_in"], up)),
         w2=bf(keys[3], (groups, m["inner"], m["k_in"])),
     )
@@ -103,16 +126,76 @@ def layers_fn(m, budget, u, valid, w):
                       bank_first=(li % m["bank"]) * m["held"])
         y, stats = routed_experts(
             u, x, valid, w["w_router"],
-            w["bias"] if m["scoring"] == "sigmoid" else None, body_of,
+            w["bias"] if m["scoring"] == "sigmoid" or m.get("drawn") else None,
+            body_of,
             top_k=m["top_k"], norm_topk_prob=True, scale=m["scale"],
             scoring=m["scoring"], held=m["held"], expert_first=0,
             token_budget=budget, **kw)
-        y = jnp.pad(y, ((0, 0), (0, m["d"] - m["k_in"])))
-        h = u.astype(jnp.float32) + y
-        h = h * jax.lax.rsqrt(jnp.mean(h * h, -1, keepdims=True) + 1e-5)
-        return h.astype(jnp.bfloat16), stats
+        with jax.named_scope("attrib_glue"):
+            y = jnp.pad(y, ((0, 0), (0, m["d"] - m["k_in"])))
+            h = u.astype(jnp.float32) + y
+            h = h * jax.lax.rsqrt(jnp.mean(h * h, -1, keepdims=True) + 1e-5)
+            return h.astype(jnp.bfloat16), stats
 
     return jax.lax.scan(layer, u, jnp.arange(LAYERS, dtype=jnp.int32))
+
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?(%\S+) = (\(.*?\)|\S+) ([\w\-]+)\((.*)$", re.M)
+_SHAPE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
+
+
+def item_of(name, result, opcode, rest, m, tokens, packed):
+    """The item one instruction of the compiled layer belongs to."""
+    op = re.search(r'op_name="([^"]*)"', rest)
+    op = op.group(1) if op else ""
+    tail = op.rsplit("/", 1)[-1]
+    shape = _SHAPE.search(result)
+    dtype, dims = shape.groups() if shape else ("", "")
+    dims = [int(d) for d in dims.split(",") if d]
+    if "moe_sum_rows" in name or "moe_sum_rows" in op:
+        return "way back"
+    if "attrib_glue" in op:
+        return "script"
+    if "moe_experts" in op:
+        if len(dims) == 2 and dims[0] >= 128 and dtype in ("bf16", "f32"):
+            return "body elementwise"
+        return "gmm metadata"
+    if "moe_router" in op:
+        if opcode == "sort" or tail in ("sort", "top_k", "argsort"):
+            by_expert = len(dims) == 2 and dims[-1] == m["scored"]
+            return "router top-k" if by_expert or tail == "top_k" else "sorts"
+        if dtype == "f32" and dims and dims[-1] != m["scored"] and (
+                len(dims) == 1 and dims[0] > tokens
+                or len(dims) == 2 and dims[-1] == m["top_k"]):
+            return "router pick"  # the chosen scores, and their norm
+        if dtype in ("s32", "pred", "u32"):
+            return "counts"
+        return "router product and scores"
+    if packed and (opcode == "sort" or dims[:1] == [tokens] and (
+            tail in ("gather", "cumsum", "select_n"))):
+        return "packing"
+    if dtype == "bf16" and len(dims) == 2 and dims[1] == m["k_in"] and (
+            dims[0] != tokens):
+        return "xs gather"
+    if dtype == "f32" and dims and dims[-1] == m["k_in"]:
+        return "way back"
+    return "other"
+
+
+def items_of(compiled_text, per_layer, m, tokens, packed):
+    """``per_layer`` {``%name shape``: us} summed by item. ``tokens``: the
+    layer's tokens as routed (the budget where the step is packed)."""
+    where = {}
+    for name, result, opcode, rest in _INSTRUCTION.findall(compiled_text):
+        where.setdefault(name, (result, opcode, rest))
+    items = dict.fromkeys(ITEMS, 0.0)
+    for op, us in per_layer.items():
+        name = op.split(" ")[0]
+        item = "other" if name not in where else item_of(
+            name, *where[name], m, tokens, packed)
+        items[item] += us
+    return {k: round(v, 2) for k, v in items.items()}
 
 
 def device_ops(trace_dir):
@@ -151,6 +234,7 @@ def main():
         valid = (jnp.arange(n) * real) % n < real  # real of n, spread
         fn = jax.jit(lambda u, valid, w, m=m, budget=budget:
                      layers_fn(m, budget, u, valid, w))
+        text = fn.lower(u, valid, w).compile().as_text()
         out, stats = jax.block_until_ready(fn(u, valid, w))
         t0 = time.perf_counter()
         for _ in range(REPEATS):
@@ -167,14 +251,20 @@ def main():
         shutil.rmtree(trace_dir)
         kernels = {o: t for o, t in per_layer.items() if o.startswith("%gmm")}
         others = {o: t for o, t in per_layer.items() if o not in kernels}
+        packed = budget is not None and budget < n
         report["shapes"][name] = {
             "wall_us_a_layer": round(wall_us, 2),
             "gmm_us_a_layer": round(sum(kernels.values()), 2),
             "other_ops_us_a_layer": round(sum(others.values()), 2),
             "held_pairs_a_layer": float(stats[0, 1]),
+            "items_us_a_layer": items_of(
+                text, others, m, budget if packed else n, packed),
             "finite": bool(jnp.isfinite(out[0].astype(jnp.float32)).all()),
+            # the layers' output, to hold two trees' reports together
+            "out_mean_abs": float(jnp.mean(jnp.abs(out[0].astype(jnp.float32)))),
+            "out_head": [float(v) for v in out[0][0, :4].astype(jnp.float32)],
             "other_ops": {o: round(t, 2) for o, t in sorted(
-                others.items(), key=lambda kv: -kv[1])[:24]},
+                others.items(), key=lambda kv: -kv[1]) if t >= 0.3},
         }
         print(json.dumps({name: report["shapes"][name]}), flush=True)
     with open(os.path.join(OUT_DIR, f"{args.tag}.json"), "w") as f:

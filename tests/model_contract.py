@@ -71,3 +71,27 @@ def assert_matches_reference(reference, params, prompt, got, tol=2e-3):
     for j, at in enumerate(got["logprobs"]):
         for tid, lp in at.items():
             assert abs(rows[j][tid] - lp) < tol, (j, tid, rows[j][tid], lp)
+
+
+def assert_dispatch_counts_exported(eng):
+    """An engine whose model goes through ``models/moe_dispatch.py`` has run:
+    all six of the dispatch's counts come out of ``step_aux`` into the
+    engine's stats and from there onto ``/metrics``, and the sixth, the
+    expert layers whose held pairs passed the row capacity, reads 0 where
+    a step's pairs are one row tile (every tiny test configuration)."""
+    from prometheus_client import generate_latest
+
+    from production_stack_tpu.engine.server import EngineMetrics
+    from production_stack_tpu.models import moe_dispatch
+
+    assert eng.runner.aux_names[:moe_dispatch.AUX_WIDTH] == moe_dispatch.AUX_NAMES
+    metrics, stats = EngineMetrics("m"), eng.stats()
+    metrics.refresh(stats)
+    text = generate_latest(metrics.registry).decode()
+    for key in moe_dispatch.AUX_NAMES:
+        line = next(ln for ln in text.splitlines()
+                    if ln.startswith(f'pst:{key}{{model_name="m"}}'))
+        assert float(line.split()[-1]) == stats[key]
+    assert stats["moe_layer_steps_total"] > 0
+    assert stats["moe_dispatch_overflow_total"] == 0
+

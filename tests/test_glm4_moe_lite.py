@@ -101,6 +101,12 @@ def test_chunked_prefill_then_decode_through_the_latent_pages(engine, params):
     assert stats["moe_pairs_held_total"] == stats["moe_pairs_routed_total"] > 0
 
 
+def test_the_server_exports_the_dispatch_counts(engine):
+    """``pst:moe_dispatch_overflow_total`` beside the accepted five."""
+    run(engine, [PROMPT[:20]], 2)
+    contract.assert_dispatch_counts_exported(engine)
+
+
 def test_prefill_in_one_chunk_takes_the_expanded_path(params):
     """The whole prompt as one chunk of 64 positions: long enough at these
     widths (the rule's threshold is 47) to pay for expanding the context."""
@@ -303,16 +309,20 @@ def test_mla_decode_kernel_reads_live_pages_alone():
 # ----------------------------------------------------------------------------
 
 
-def test_eight_shares_add_up_to_the_uncut_references_layer():
+@pytest.mark.parametrize("tokens", [11, 300])
+def test_eight_shares_add_up_to_the_uncut_references_layer(tokens):
     """At 64 experts top 4: eight shares of 8 experts each, through the
-    dispatch both mixture-of-experts classes call, with the shared expert
-    counted once, add up to what the reference gives for the whole layer."""
+    dispatch the mixture-of-experts classes call, with the shared expert
+    counted once, add up to what the reference gives for the whole layer:
+    at 11 tokens (one row tile: the plain program) and at 300, where a
+    share works on one row capacity of 256 of its 1,280 rows a round."""
+    assert (moe_dispatch.capacity(tokens * 4, 8, 64) == 256) == (tokens == 300)
     whole = dataclasses.replace(
         CFG, n_routed_experts=64, router_experts=64, num_experts_per_tok=4)
     p = Glm4MoeLite(whole).init_params(jax.random.PRNGKey(3))
     mp = {k: v[0] for k, v in p["layers"]["moe"].items()}
     norm = jnp.ones((CFG.hidden_size,), jnp.float32)
-    x = jax.random.normal(jax.random.PRNGKey(4), (11, CFG.hidden_size), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(4), (tokens, CFG.hidden_size), jnp.float32)
     want, gap = ref.moe(
         x, norm, mp, top_k=4, first=0, scale=CFG.routed_scaling_factor,
         renorm=True, eps=CFG.rms_norm_eps, softmax=False)
@@ -336,6 +346,7 @@ def test_eight_shares_add_up_to_the_uncut_references_layer():
         assert counts["moe_pairs_routed_total"] == x.shape[0] * 4
         assert 0 < counts["moe_experts_touched_total"] <= 8
         assert counts["moe_layer_steps_total"] == 1
+        assert counts["moe_dispatch_overflow_total"] == 0
         held_pairs += counts["moe_pairs_held_total"]
     assert held_pairs == x.shape[0] * 4
     # the last rank's own part is the reference's over its eight experts

@@ -133,6 +133,12 @@ def test_chunked_prefill_then_decode_through_both_groups(engine, params):
     assert stats["moe_pairs_held_total"] == stats["moe_pairs_routed_total"] > 0
 
 
+def test_the_server_exports_the_dispatch_counts(engine):
+    """``pst:moe_dispatch_overflow_total`` beside the accepted five."""
+    run(engine, [PROMPT[:20]], 2)
+    contract.assert_dispatch_counts_exported(engine)
+
+
 def test_a_prompt_cut_into_three_chunks_equals_one_chunk(uncached, params):
     prompt = PROMPT[:48]
     three = run(uncached, [prompt], 4)[0]  # the defaults: chunks of 16
@@ -463,27 +469,30 @@ def test_yarn_frequencies_are_the_modelling_librarys():
     assert ours_scale == scale
 
 
-def test_the_four_shares_routed_parts_are_the_whole(params):
+# 23 tokens: one row tile, the plain program; 200: a capacity, rounds traced
+@pytest.mark.parametrize("tokens", [23, 200])
+def test_the_four_shares_routed_parts_are_the_whole(tokens, params):
     """Four ranks of 4 of 16 experts, one router: the routed parts add up
     to the uncut reference's expert block."""
     E, held = 16, 4
     cfg = dataclasses.replace(CFG, router_experts=E, n_routed_experts=held)
     D, Fe = cfg.hidden_size, cfg.moe_intermediate_size
-    x = jax.random.normal(jax.random.PRNGKey(3), (23, D))
+    x = jax.random.normal(jax.random.PRNGKey(3), (tokens, D))
     ks = jax.random.split(jax.random.PRNGKey(4), 3)
     whole = {"w1": jax.random.normal(ks[0], (E, D, 2 * Fe)) / np.sqrt(D),
              "w2": jax.random.normal(ks[1], (E, Fe, D)) / np.sqrt(Fe)}
     mp = {"norm": params["layers"]["moe"]["norm"][0],
           "w_router": jax.random.normal(ks[2], (D, E)) / np.sqrt(D)}
     u = reference._rms(x, mp["norm"], cfg.rms_norm_eps)
-    valid = jnp.ones((23,), bool)
+    valid = jnp.ones((tokens,), bool)
     total = 0.0
     for first in range(0, E, held):
         model = Mellum(dataclasses.replace(cfg, expert_first=first))
         banks = {k: v[first:first + held] for k, v in whole.items()}
         part, stats = model.routed(mp, banks, 0, u, valid)
-        assert stats[0] == 23 * cfg.num_experts_per_tok
+        assert stats[0] == tokens * cfg.num_experts_per_tok
         assert 0 < stats[1] < stats[0]
+        assert stats[5] == 0  # no share passed its capacity
         total = total + part
     with jax.default_matmul_precision("highest"):
         want, _ = reference.moe(

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from production_stack_tpu.models import moe_dispatch
 from production_stack_tpu.models.nemotron_h import NemotronH
 from production_stack_tpu.models.registry import PRESETS
 from production_stack_tpu.ops import ssm
@@ -200,6 +201,12 @@ def test_chunked_prefill_then_decode_matches_full_forward(engine, params):
     assert engine.pipelined_bursts_total > 0, "decode must run chained"
 
 
+def test_the_server_exports_the_dispatch_counts(engine):
+    """``pst:moe_dispatch_overflow_total`` beside the accepted five."""
+    run(engine, [PROMPT[:20]], 2)
+    contract.assert_dispatch_counts_exported(engine)
+
+
 @pytest.mark.parametrize("chunk", [16, 48, 64])
 def test_chunk_size_does_not_change_the_logits(chunk, params):
     """(b) the same prompt in chunks of 16, 48 and whole."""
@@ -380,11 +387,16 @@ def _moe_layer(seed=3, n_tokens=9):
     return lw, u
 
 
-def test_the_four_shares_add_up_to_the_whole_layer():
+@pytest.mark.parametrize("tokens", [9, 150])
+def test_the_four_shares_add_up_to_the_whole_layer(tokens):
     """(f) each of the four ranks' routed parts, passed through the latent
     up-projection, summed, with the shared expert counted once, is what the
-    oracle gives for the uncut 16-expert layer."""
-    lw, u = _moe_layer()
+    oracle gives for the uncut 16-expert layer: at 9 tokens, whose pairs
+    are one row tile (the plain program), and at 150, where a share works
+    on one row capacity a round."""
+    lw, u = _moe_layer(n_tokens=tokens)
+    pairs = tokens * CFG.num_experts_per_tok
+    assert (moe_dispatch.capacity(pairs, 4, 16) < pairs) == (tokens == 150)
     want_routed, want_shared = oracle_moe(CFG, lw, u, 0, lw["w1"], lw["w2"])
     total = np.zeros_like(want_routed)
     valid = jnp.ones(u.shape[0], bool)
@@ -407,6 +419,7 @@ def test_the_four_shares_add_up_to_the_whole_layer():
         # a pair is held, at most the busiest times their number; one layer
         assert float(stats[1]) <= float(stats[2]) * float(stats[3])
         assert 0 < float(stats[3]) <= 4 and float(stats[4]) == 1.0
+        assert float(stats[5]) == 0.0  # no share passed its capacity
         out, _ = model._moe(share, jnp.asarray(u), valid)
         np.testing.assert_allclose(
             np.asarray(out), own + want_shared, atol=2e-4, rtol=2e-4)

@@ -766,8 +766,36 @@ def test_the_window_mix_decode_program_copies_no_weight_stack(one_chip, monkeypa
         if opcode in ("copy", "transpose") and result.startswith(big)]
     assert not copies
     assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
-    for kernel in ("paged_attn_decode", "gmm"):
+    # 256 pair rows at a quarter share: a capacity of 128, so the held rows'
+    # way back and the loop of further rounds are in the program
+    for kernel in ("paged_attn_decode", "gmm", "moe_sum_rows"):
         assert kernel in text
+
+
+@pytest.mark.parametrize("rows,width,tokens", [
+    (1920, 2048, 1024), (8448, 1024, 1024), (3072, 2304, 1024),
+    (128, 2048, 64), (7680, 2048, 4096)])
+def test_the_held_rows_way_back_compiles_for_the_chip(rows, width, tokens,
+                                                      one_chip, monkeypatch):
+    """``moe_dispatch.sum_rows`` (PR 52) at the three share cells' prefill
+    capacities, a decode step's, and a step of 4,096 tokens whose sums take
+    two blocks of fast memory: Mosaic takes the float32 weights as a
+    prefetched scalar operand and the one-row read-add-write at a traced
+    row, and the kernel asks for no more fast memory than it is given."""
+    from production_stack_tpu.models import moe_dispatch
+
+    monkeypatch.setattr(moe_dispatch, "pallas_interpret", lambda: False)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    blocks, block = moe_dispatch.sums_blocks(tokens, width)
+    assert blocks == (2 if tokens == 4096 else 1)
+    with jax.disable_jit(False):
+        compiled = jax.jit(moe_dispatch.sum_rows, donate_argnums=(4,)).lower(
+            sds((rows, width), jnp.float32), sds((rows,), jnp.int32),
+            sds((rows,), jnp.float32), sds((), jnp.int32),
+            sds((blocks * block, width), jnp.float32),
+            sds((), jnp.int32)).compile()
+    assert "moe_sum_rows" in compiled.as_text()
 
 
 @pytest.mark.parametrize("B,T", [(16, 1), (1, 1024)])

@@ -112,6 +112,12 @@ def test_chunked_prefill_then_decode_through_slots_and_pages(engine, params):
     assert 0 < stats["moe_pairs_held_total"] < stats["moe_pairs_routed_total"]
 
 
+def test_the_server_exports_the_dispatch_counts(engine):
+    """``pst:moe_dispatch_overflow_total`` beside the accepted five."""
+    run(engine, [PROMPT[:20]], 2)
+    contract.assert_dispatch_counts_exported(engine)
+
+
 def test_a_prompt_cut_into_three_chunks_equals_one_chunk(engine, params):
     prompt = PROMPT[:48]
     three = run(engine, [prompt], 4)[0]  # the defaults: chunks of 16
@@ -234,27 +240,30 @@ def test_sigmoid_route_is_what_it_was():
         w, 2.5 * chosen / chosen.sum(-1, keepdims=True), rtol=1e-6)
 
 
-def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_whole(params):
+# 23 tokens: one row tile, the plain program; 200: a capacity, rounds traced
+@pytest.mark.parametrize("tokens", [23, 200])
+def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_whole(tokens, params):
     """Four ranks of 4 of the 16 experts, one router: the routed parts add
     up (with the shared expert once) to the uncut reference's expert block."""
     import dataclasses
 
     D, Fe, E = CFG.hidden_size, CFG.moe_intermediate_size, CFG.router_experts
     held = CFG.n_routed_experts
-    x = jax.random.normal(jax.random.PRNGKey(3), (23, D))
+    x = jax.random.normal(jax.random.PRNGKey(3), (tokens, D))
     ks = jax.random.split(jax.random.PRNGKey(4), 2)
     whole = {"w1": jax.random.normal(ks[0], (E, D, 2 * Fe)) / np.sqrt(D),
              "w2": jax.random.normal(ks[1], (E, Fe, D)) / np.sqrt(Fe)}
     mp = {k: v[0] for k, v in params["layers"]["moe"].items()
           if k not in whole}
     u = reference._norm(x, mp["norm"], CFG.rms_norm_eps)
-    valid = jnp.ones((23,), bool)
+    valid = jnp.ones((tokens,), bool)
     total = Qwen3Next(CFG).shared_expert(mp, u)
     for first in range(0, E, held):
         model = Qwen3Next(dataclasses.replace(CFG, expert_first=first))
         banks = {k: v[first:first + held] for k, v in whole.items()}
         part, stats = model.routed(mp, banks, 0, u, valid)
-        assert stats[0] == 23 * CFG.num_experts_per_tok
+        assert stats[0] == tokens * CFG.num_experts_per_tok
+        assert stats[5] == 0  # no share passed its capacity
         total = total + part
     with jax.default_matmul_precision("highest"):
         want, _ = reference.moe(
