@@ -122,7 +122,8 @@ def fleet_dashboard():
     p.append(panel("Router request stats (QPS per backend)", [
         ('vllm:current_qps', "{{server}}"),
     ], 16, 18))
-    # Row 5 — speculative decoding (engines started with --speculative-ngram).
+    # Row 5 — speculative decoding (engines started with --speculative-ngram
+    # or, for a model that drafts its own next token, --speculative-mtp).
     p.append(panel("Speculative decode: draft vs accepted tok/s", [
         ('sum(rate(vllm:spec_decode_num_draft_tokens_total[2m]))', "drafted"),
         ('sum(rate(vllm:spec_decode_num_accepted_tokens_total[2m]))',
@@ -133,6 +134,15 @@ def fleet_dashboard():
          'clamp_min(sum(rate(vllm:spec_decode_num_draft_tokens_total[2m])),'
          ' 1e-9)', "accept rate"),
     ], 8, 25, unit="percentunit"))
+    # A verify-and-draft step yields one token a live row and one more where
+    # the device accepted its draft: 1.0 the floor, 2.0 the ceiling. Read it
+    # beside the decode step histogram below (the step costs two positions).
+    p.append(panel("Tokens a row and verify-and-draft step (--speculative-mtp)", [
+        ('sum(rate(pst:mtp_tokens_emitted_total[2m])) / '
+         'clamp_min(sum(rate(pst:mtp_row_steps_total[2m])), 1e-9)',
+         "tokens / row-step"),
+        ('sum(rate(pst:mtp_steps_total[2m]))', "steps /s"),
+    ], 16, 25))
     # Row 6 — fleet hit rate (the ≥0.6 north star) + live-KV swap.
     p.append(panel("Fleet KV hit rate (all engines)", [
         ('sum(vllm:gpu_prefix_cache_hits_total) / '

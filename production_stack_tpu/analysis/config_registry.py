@@ -347,6 +347,8 @@ ENGINE_FIELDS: Tuple[EngineFieldSpec, ...] = (
                     note="lattice floor tuning; extraArgs"),
     EngineFieldSpec("speculative_ngram", "--speculative-ngram",
                     note="speculation is opt-in via extraArgs"),
+    EngineFieldSpec("speculative_mtp", "--speculative-mtp",
+                    note="a model's own draft is opt-in via extraArgs"),
     EngineFieldSpec("ngram_min", "--ngram-min",
                     note="companion of --speculative-ngram"),
     EngineFieldSpec("ngram_max", "--ngram-max",

@@ -86,6 +86,12 @@ class EngineConfig:
     # Cap the prompt-lookup scan to the last N tokens (0 = whole history).
     # Bounds the per-step host-side draft cost at long context.
     ngram_lookback: int = 8192
+    # The model's own draft (multi-token prediction; docs/engine.md "Verify
+    # and draft"): 1 = every decode step of a model that has the module
+    # verifies one draft a row and makes the next, in one program on the
+    # device, and emits 1 or 2 tokens a row; 0 = off. The depth is the
+    # published module's (1). Token for token the draft-off output.
+    speculative_mtp: int = 0
     # Overlapped decode pipeline (docs/engine.md "Overlapped decode
     # pipeline"): keep one burst in flight. As soon as burst N's token ids
     # are fetched, burst N+1 is dispatched and burst N's host bookkeeping
@@ -283,8 +289,13 @@ def _refusals(cfg: EngineConfig):
             "looped": _UNPROVEN_LOOPED}),
         (cfg.speculative_ngram > 0, "--speculative-ngram", {
             "recurrent": "a rejected draft would need the state rolled back",
-            "window_pages": "a draft's rows have not been verified through "
-                            "the window group"}),
+            "window_pages": "an n-gram draft's rows have not been verified "
+                            "through the window group (the model's own "
+                            "draft, --speculative-mtp, has)"}),
+        (cfg.speculative_mtp > 0, "--speculative-mtp", {
+            "recurrent": "a rejected draft would need the state rolled back",
+            "latent_pages": "no draft module is built over latent pages",
+            "looped": _UNPROVEN_LOOPED}),
         (cfg.enable_lora, "--enable-lora", {
             **_both("no adapter bank exists for these layers"),
             "looped": _UNPROVEN_LOOPED}),
@@ -348,6 +359,31 @@ def refuse_unserved(cfg: EngineConfig, model_cfg: ModelConfig) -> None:
                 raise ValueError(
                     f"{flag} is not served for model {cfg.model!r}, which "
                     f"{has}: {why[prop]}")
+    refuse_mtp(cfg, model_cfg)
+
+
+def refuse_mtp(cfg: EngineConfig, model_cfg: ModelConfig) -> None:
+    """``--speculative-mtp`` needs a class with the module, at its published
+    depth, and is the step's only draft source; the chained pipeline is off
+    with it (its step is synchronous: one launch and one fetch)."""
+    n = cfg.speculative_mtp
+    if not n:
+        return
+    if not model_cfg.mtp_layers:
+        raise ValueError(
+            f"--speculative-mtp is not served for model {cfg.model!r}, which "
+            "has no multi-token-prediction module (num_nextn_predict_layers)")
+    if n != model_cfg.mtp_layers:
+        raise ValueError(
+            f"--speculative-mtp {n}: the module's depth is "
+            f"{model_cfg.mtp_layers}, and a deeper draft is not built")
+    for on, flag, why in (
+            (cfg.speculative_ngram > 0, "--speculative-ngram",
+             "a step verifies one draft source"),
+            (cfg.num_decode_steps > 1, "--num-decode-steps",
+             "a verify-and-draft step is one step a launch")):
+        if on:
+            raise ValueError(f"{flag} with --speculative-mtp: {why}")
 
 
 def resolve_num_kv_blocks(

@@ -192,7 +192,12 @@ class LLMEngine:
                     2 if cfg.overlap_decode and not cfg.speculative_ngram
                     else 1
                 ),
-                spec_tokens=cfg.speculative_ngram,
+                # (a verify-and-draft step writes its draft's position and,
+                # one slot ahead, the draft layer's entry for it; a chained
+                # one starts up to two tokens past the host's view)
+                spec_tokens=cfg.speculative_ngram or cfg.speculative_mtp * (
+                    4 if cfg.overlap_decode else 2),
+                mtp=bool(cfg.speculative_mtp),
                 swap_quantum=cfg.swap_quantum_tokens,
                 deadline_shedding=cfg.deadline_shedding,
                 tenant_fairness=cfg.tenant_fairness,
@@ -651,6 +656,10 @@ class LLMEngine:
             self._burst_n = sched.n_decode_steps
             self._count_decode(chained=True, depth=sched.n_decode_steps)
             self.runner.burst_start(sched.decodes, sched.n_decode_steps)
+        elif self.cfg.speculative_mtp:
+            # (a row the chain cannot take: near max_model_len, penalties,
+            # a guided choice, a standing queue)
+            outputs += self._mtp_step(sched.decodes)
         else:
             self._count_decode(chained=False, depth=sched.n_decode_steps)
             bursts = self.runner.execute_decode_multi(
@@ -679,10 +688,11 @@ class LLMEngine:
         Gating is PER ROW where possible: only greedy rows get drafts;
         sampled (temperature>0) rows ride the same verify step and have
         position 0 put through the full sampling pipeline — identical to a
-        plain decode step for them. Batch-level bail-outs remain for
-        penalties (accepted tokens would change the counts mid-step) and
-        logprobs (verify returns no packed logprob rows), plus too few
-        draft-carrying rows to beat a plain burst."""
+        plain decode step for them, and so does a row that asks for
+        log-probabilities (the verify step packs position 0's for it). A
+        batch-level bail-out remains for penalties (accepted tokens would
+        change the counts mid-step), plus too few draft-carrying rows to
+        beat a plain burst."""
         K = self.cfg.speculative_ngram
         if not K or not decodes:
             return None
@@ -690,12 +700,13 @@ class LLMEngine:
             from .spec import propose_ngram
 
             for s in decodes:
-                if s.sampling.has_penalties or s.sampling.logprobs is not None:
+                if s.sampling.has_penalties:
                     return None
             drafts = np.zeros((len(decodes), K), np.int32)
             lens = np.zeros(len(decodes), np.int32)
             for i, s in enumerate(decodes):
-                if not s.sampling.greedy or s.sampling.guided_choice:
+                if (not s.sampling.greedy or s.sampling.guided_choice
+                        or s.sampling.logprobs is not None):
                     continue  # rides along; sampled/masked at position 0 only
                 if s.num_tokens + K > self.cfg.max_model_len:
                     continue  # verify writes would run past the last page
@@ -747,14 +758,16 @@ class LLMEngine:
         from .spec import count_accepted
 
         drafts, lens = spec
-        rows, sampled0 = self.runner.execute_spec_verify(decodes, drafts)
+        rows, packed0 = self.runner.execute_spec_verify(decodes, drafts)
         with ENGINE_TELEMETRY.phase("postprocess", "spec_verify"):
             outputs: List[RequestOutput] = []
             for i, seq in enumerate(decodes):
+                lp_row = None
                 if lens[i] == 0:
                     # Draftless (or sampled) row: position 0 went through the
-                    # full sampling pipeline — exactly one plain decode step.
-                    emitted = [int(sampled0[i])]
+                    # full sampling pipeline — exactly one plain decode step,
+                    # its log-probabilities packed where a row asks for them.
+                    emitted, lp_row = [int(packed0[i][0])], packed0[i]
                 else:
                     draft = [int(t) for t in drafts[i][: lens[i]]]
                     a = count_accepted(draft, rows[i])
@@ -766,12 +779,23 @@ class LLMEngine:
                 for tok in emitted:
                     seq.num_computed_tokens += 1
                     self._commit(seq)
-                    out = self._append_token(seq, tok)
+                    out = self._append_token(seq, tok, lp_row=lp_row)
                     if out is not None:
                         outputs.append(out)
                     if seq.is_finished:
                         break
             return outputs
+
+    def _mtp_step(self, decodes) -> List[RequestOutput]:
+        """One verify-and-draft step outside a chain (``--speculative-mtp``
+        with a row the chain cannot take, or ``--no-overlap-decode``): each
+        row commits the model's own next token and, where the device accepted
+        its draft, the one after. The accept test ran on the device; what is
+        left here is what every fetched decode step's rows get."""
+        self._count_decode(chained=False, depth=1)
+        rows = self.runner.execute_mtp_verify(decodes)
+        with ENGINE_TELEMETRY.phase("postprocess", "decode"):
+            return self._process_burst_rows(decodes, rows)
 
     def _finish_expired(self, expired) -> List[RequestOutput]:
         """Surface scheduler deadline sheds to their waiting clients: the
@@ -892,10 +916,16 @@ class LLMEngine:
         its pages would not exist), not one whose deadline has passed (the
         scheduler sheds only what no burst in flight writes through)."""
         now = time.monotonic()
+        # A chained verify-and-draft step advances a row by up to two and
+        # writes one slot past its draft's: twice the room; its program
+        # carries no penalty counts.
+        mtp = bool(self.cfg.speculative_mtp)
+        room = 2 * n * (2 if mtp else 1)
         return not any(
             s.sampling.guided_choice
-            or s.num_tokens + 2 * n > self.cfg.max_model_len
+            or s.num_tokens + room > self.cfg.max_model_len
             or s.deadline_expired(now)
+            or (mtp and s.sampling.has_penalties)
             for s in decodes
         )
 

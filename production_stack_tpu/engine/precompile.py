@@ -45,6 +45,7 @@ _KIND_RANK = {
     "decode_burst": 1,
     "prefill": 2,
     "spec_verify": 3,
+    "mtp_verify": 0,  # the decode step of an engine that drafts for itself
     "encode": 4,
 }
 
@@ -75,7 +76,7 @@ class Bucket:
             return f"b{self.rows}xn{self.n_steps}"
         if self.kind == "prefill":
             return f"b{self.rows}xt{self.tokens}"
-        if self.kind == "spec_verify":
+        if self.kind in ("spec_verify", "mtp_verify"):
             return f"b{self.rows}xk{self.tokens}"
         return f"t{self.tokens}"
 
@@ -164,7 +165,10 @@ def burst_depths(cfg: EngineConfig) -> List[int]:
     lifetime at worst.)"""
     n = max(cfg.num_decode_steps, 1)
     # Mirrors LLMEngine._pipeline_ok: overlap defers to configured n-gram
-    # speculation, so spec engines never dispatch the depth-1 variant.
+    # speculation, so spec engines never dispatch the depth-1 variant; nor
+    # does an engine whose decode step is the verify-and-draft step.
+    if cfg.speculative_mtp:
+        return []
     if n > 1 or (cfg.overlap_decode and not cfg.speculative_ngram):
         return [n]
     return []
@@ -186,7 +190,12 @@ def enumerate_lattice(cfg: EngineConfig) -> List[Bucket]:
     for lp, greedy in _FLAG_SETS:
         for r in rows:
             for w in widths:
+                # every decode step of an engine that serves its model's own
+                # draft is a verify-and-draft step of one draft a row
                 buckets.append(
+                    Bucket("mtp_verify", rows=r, tokens=1, width=w,
+                           want_lp=lp, greedy=greedy)
+                    if cfg.speculative_mtp else
                     Bucket("decode", rows=r, width=w, want_lp=lp, greedy=greedy)
                 )
         for n in burst_depths(cfg):

@@ -382,6 +382,11 @@ class ModelRunner:
         # A model whose prefill step runs its cross-decoder on sampled
         # positions alone is told which rows those are (``sample_rows``).
         self._skips_cross = bool(self.model.SKIPS_CROSS_DECODER)
+        # The model's own draft is served (``--speculative-mtp``): every
+        # prefill step runs the draft module a token ahead, every decode
+        # step is a verify-and-draft step of two positions a row.
+        self._mtp = bool(cfg.speculative_mtp)
+        self._mtp_accepted_last = 0  # drafts the last verify step accepted
         # Tokens prefill steps computed, beside the positions their buckets
         # hold (rows x chunk, padding included).
         self.prefill_tokens_total = 0
@@ -550,8 +555,10 @@ class ModelRunner:
         # token_budget, for a model whose expert dispatch takes it: no step
         # holds more real tokens than a prefill step's budget, however far
         # its rows are padded.
+        mtp = self._mtp
         budget = (
-            {"token_budget": max(cfg.max_prefill_tokens, cfg.max_num_seqs)}
+            {"token_budget": max(cfg.max_prefill_tokens,
+                                 cfg.max_num_seqs * (2 if mtp else 1))}
             if recurrent or model.TOKEN_BUDGET else {})
 
         def slots_of(batch, active=None):
@@ -581,7 +588,7 @@ class ModelRunner:
             params, kv_cache, batch: Dict[str, Any], want_lp: bool,
             greedy: bool,
         ):
-            logits, kv_cache = model.forward(
+            logits, *hidden, kv_cache = model.forward(
                 params,
                 batch["tokens"],
                 batch["positions"],
@@ -596,6 +603,7 @@ class ModelRunner:
                 moe_impl=moe_impl,
                 pp_size=pp,
                 mesh=model_mesh,
+                **({"return_hidden": True} if "mtp_next" in batch else {}),
                 **slots_of(batch),
             )
             if "penalty_prompt" in batch:
@@ -628,7 +636,137 @@ class ModelRunner:
                 with_logprobs=want_lp,
                 greedy_only=greedy,
             )
+            if hidden:
+                # The draft module over the same positions, a token ahead:
+                # the token after each is the batch's, or, after a row's
+                # last (-1), the one just sampled. The row's first draft
+                # rides the packed row as one more column.
+                nxt = jnp.where(batch["mtp_next"] >= 0, batch["mtp_next"],
+                                packed[:, :1].astype(jnp.int32))
+                draft_logits, kv_cache = model.mtp_forward(
+                    params, hidden[0], nxt, batch["positions"],
+                    batch["mtp_write_idx"], batch["block_tables"],
+                    batch["mtp_kv_lens"], batch["last_idx"], kv_cache,
+                    attn_impl=attn_impl, **budget)
+                draft = jnp.argmax(draft_logits, axis=-1)
+                packed = jnp.concatenate(
+                    [packed, draft[:, None].astype(packed.dtype)], axis=1)
             return with_aux(packed, kv_cache), kv_cache
+
+        def pst_decode_step_mtp(params, kv_cache, batch, want_lp, greedy):
+            """Verify then draft, one program (docs/engine.md "Verify and
+            draft"): the main stack on ``[t_n, d]`` at positions ``n, n +
+            1``; ``t_{n+1}`` is position 0's sample, the draft ``d`` is
+            accepted where it equals it (greedy rows the host marked
+            ``draft_ok``) and position 1's sample is then ``t_{n+2}``; the
+            draft module on the same two positions with those tokens after
+            them; the next draft is its argmax at the last committed
+            position. A row of the result: position 0's packed sample,
+            position 1's, the accept count, the next draft."""
+            tokens = batch["tokens"]
+            logits, hidden, kv_cache = model.forward(
+                params, tokens, batch["positions"], batch["write_idx"],
+                batch["block_tables"], batch["kv_lens"], batch["last_idx"],
+                kv_cache, attn_impl=attn_impl, moe_impl=moe_impl,
+                pp_size=pp, mesh=model_mesh, all_logits=True,
+                return_hidden=True, **slots_of(batch))
+            l0, l1 = logits[:, 0], logits[:, 1]
+            if "penalty_prompt" in batch:  # such a row is not drafted for
+                l0 = apply_penalties(
+                    l0, batch["penalty_prompt"], batch["penalty_output"],
+                    batch["presence"], batch["frequency"],
+                    batch["repetition"])
+            if "bias_ids" in batch:
+                l0, l1 = (apply_logit_bias(
+                    x, batch["bias_ids"], batch["bias_vals"])
+                    for x in (l0, l1))
+            if "allowed_ids" in batch:  # nor is a guided row
+                l0 = apply_allowed_mask(
+                    l0, batch["allowed_ids"], batch["allow_free"])
+            sample = lambda x, ahead: sample_tokens_packed(  # noqa: E731
+                x, batch["temps"], batch["top_ps"], batch["top_ks"],
+                batch["min_ps"], batch["seeds"] + jnp.uint32(ahead),
+                with_logprobs=want_lp, greedy_only=greedy)
+            p0, p1 = sample(l0, 0), sample(l1, 1)
+            t1, t2 = (p[:, 0].astype(jnp.int32) for p in (p0, p1))
+            live = batch["kv_lens"] > 0
+            drafted = batch["draft_ok"] & live
+            accept = drafted & (tokens[:, 1] == t1)
+            draft_logits, kv_cache = model.mtp_forward(
+                params, hidden, jnp.stack([t1, t2], axis=1),
+                batch["positions"], batch["mtp_write_idx"],
+                batch["block_tables"], batch["mtp_kv_lens"],
+                batch["last_idx"], kv_cache, attn_impl=attn_impl,
+                all_logits=True, **budget)
+            drafts = jnp.argmax(draft_logits, axis=-1)  # [B, 2]
+            nxt = jnp.where(accept, drafts[:, 1], drafts[:, 0])
+            f32 = jnp.float32
+            packed = jnp.concatenate(
+                [p0, p1, accept[:, None].astype(f32),
+                 nxt[:, None].astype(f32)], axis=1)
+            rows = jnp.sum(live).astype(f32)
+            took = jnp.sum(accept).astype(f32)
+            counts = jnp.stack([  # in the order of the model's MTP_AUX_NAMES
+                jnp.sum(drafted).astype(f32), took, jnp.ones((), f32), rows,
+                rows + took])
+            aux = kv_cache["aux"]
+            kv_cache = dict(kv_cache, aux=jnp.concatenate(
+                [aux[:aux.shape[0] - counts.shape[0]], counts]))
+            return with_aux(packed, kv_cache), kv_cache
+
+        def pst_decode_step_mtp_chained(params, kv_cache, batch, tokens,
+                                        drafts, positions, seed_off,
+                                        want_lp, greedy):
+            """The verify-and-draft step as a chained step: the row's last
+            committed token, its draft (-1: none) and its position are the
+            device's own from the step before, so the next step's positions,
+            write slots and lengths follow the device's accept counts and
+            nothing is fetched in between. ``batch``: what the host renews
+            (both groups' tables, which rows live, the sampling arrays,
+            ``draft_ok``)."""
+            tables = batch["block_tables"]
+            width = tables.shape[1]
+            active = batch["kv_lens"] > 0  # padding and finished rows
+
+            def slots(p):  # [B, 2] positions -> flat slots of the global group
+                blk = jnp.take_along_axis(
+                    tables, jnp.minimum(p // bs, width - 1), axis=1)
+                return jnp.where(
+                    active[:, None] & (p // bs < width), blk * bs + p % bs,
+                    drop_slot).astype(jnp.int32)
+
+            pos2 = jnp.stack([positions, positions + 1], axis=1)
+            packed, kv_cache = pst_decode_step_mtp(params, kv_cache, dict(
+                batch,
+                tokens=jnp.stack([tokens, jnp.maximum(drafts, 0)], axis=1),
+                positions=pos2, write_idx=slots(pos2),
+                mtp_write_idx=slots(pos2 + 1),
+                kv_lens=jnp.where(active, positions + 2, 0),
+                mtp_kv_lens=jnp.where(active, positions + 3, 0),
+                last_idx=jnp.ones_like(positions),
+                draft_ok=batch["draft_ok"] & (drafts >= 0),
+                seeds=batch["seeds"] + seed_off), want_lp, greedy)
+            rows = packed[: positions.shape[0]]  # (the aux rows lie below)
+            took = rows[:, -2].astype(jnp.int32)
+            second = (rows.shape[1] - 2) // 2  # position 1's packed sample
+            nxt = jnp.where(took > 0, rows[:, second], rows[:, 0])
+            return (packed, nxt.astype(jnp.int32),
+                    rows[:, -1].astype(jnp.int32), positions + 1 + took,
+                    seed_off + 1, kv_cache)
+
+        def pst_chain_splice_mtp(tokens, drafts, positions, toks, src, pos):
+            """`pst_chain_splice` for a chain of verify-and-draft steps: a
+            joining row takes its token and its first draft (the last column
+            of its prefill row); a member that finished takes no draft."""
+            take = src >= 0
+            row = toks[jnp.maximum(src, 0)]
+            gone = src == -2
+            return (
+                jnp.where(take, row[:, 0].astype(jnp.int32),
+                          jnp.where(gone, 0, tokens)),
+                jnp.where(take, row[:, -1].astype(jnp.int32),
+                          jnp.where(gone, -1, drafts)),
+                jnp.where(take | gone, pos, positions))
 
         # Sampled tokens come back replicated: on a multi-host mesh the
         # primary must be able to device_get them (only addressable shards
@@ -652,6 +790,18 @@ class ModelRunner:
         # pstlint: jit-family=prefill
         prefill_step = jax.jit(pst_prefill_step, **step_jit)
         self._step = {"decode": decode_step, "prefill": prefill_step}
+        if mtp:
+            # found with the decode programs under ``jit_pst_decode_step*``
+            # pstlint: jit-family=decode
+            self._step["mtp_verify"] = jax.jit(pst_decode_step_mtp, **step_jit)
+            # pstlint: jit-family=decode_burst
+            self._mtp_chained = jax.jit(
+                pst_decode_step_mtp_chained, static_argnums=(7, 8),
+                donate_argnums=(1,),
+                out_shardings=(self._repl,) * 5 + (cache_sh,))
+            # pstlint: jit-family=decode_burst
+            self._mtp_splice = jax.jit(
+                pst_chain_splice_mtp, out_shardings=(self._repl,) * 3)
         # Every jitted step dispatch below is called through this holder,
         # by its shape key; `place_program_store` gives it the store.
         self.programs = StepPrograms()
@@ -798,8 +948,10 @@ class ModelRunner:
         # The last prefill program's packed rows, still on the device: what
         # a chain kept across that prefill takes its new rows' tokens from.
         self._prefill_toks = None
-        # A dispatch `prefill_dispatch` left for `prefill_fetch` to record.
+        # A dispatch `prefill_dispatch` left for `prefill_fetch` to record,
+        # and its items (whose first drafts the rows carry, `_take_drafts`).
         self._prefill_record = None
+        self._prefill_items: List[PrefillItem] = []
         # When each launched program was seen ready (`_ReadyClock`).
         self._clock = _ReadyClock(
             self._program_ready, lambda: ENGINE_TELEMETRY.no_work_phases)
@@ -1259,6 +1411,7 @@ class ModelRunner:
     def _step_info(
         self, kind: str, bucket: str, seqs: List[Sequence],
         batch: Dict[str, np.ndarray], new_tokens: int, kv_ahead: int = 0,
+        verify: Optional[dict] = None,
     ) -> None:
         """Tell the trace and the open step phase what this step is:
         ``kv_tokens`` sums ``kv_lens`` over the real rows after this step's
@@ -1299,7 +1452,9 @@ class ModelRunner:
             # step's may be a page longer), and only where the calls take
             # it, by the rule they trace by (``decode_sharing_calls``).
             depth, Bb = kv_ahead + 1, len(batch["kv_lens"])
-            calls = self._sharing_calls.get(Bb)
+            # (a verify-and-draft step's two positions a row go through the
+            # chunk kernel, which has no shared phase)
+            calls = 0 if verify else self._sharing_calls.get(Bb)
             if calls is None:
                 cfg = self.model_cfg
                 calls = self._sharing_calls[Bb] = (
@@ -1320,7 +1475,7 @@ class ModelRunner:
             kind, bucket=bucket, rows=n, new_tokens=new_tokens,
             kv_tokens=kv_tokens,
             kv_pages=sum(len(s.block_ids) for s in seqs),
-            **slots,
+            **slots, **(verify or {}),
         )
 
     # -- the device's side of a dispatch (`_ReadyClock`) ------------------
@@ -1572,6 +1727,8 @@ class ModelRunner:
         """Dispatch the first burst of a pipeline (async; nothing fetched)."""
         if self._burst is not None:
             raise RuntimeError("burst already in flight (drain first)")
+        if self._mtp:
+            return self._mtp_burst_start(seqs)
         with ENGINE_TELEMETRY.phase("batch_build", "decode"):
             batch = self._decode_batch(seqs, multi=True)
             if "allowed_ids" in batch:
@@ -1652,6 +1809,72 @@ class ModelRunner:
         }
         self._warm_splice(batch["kv_lens"].shape[0])
 
+    def _mtp_burst_start(self, seqs: List[Sequence]) -> None:
+        """`burst_start` for a chain of verify-and-draft steps: the carry is
+        each row's last committed token, its draft (-1: none yet) and its
+        position."""
+        with ENGINE_TELEMETRY.phase("batch_build", "decode"):
+            batch = self._decode_batch(seqs, multi=True)
+            Bb = batch["kv_lens"].shape[0]
+            drafts = np.full(Bb, -1, np.int32)
+            for i, s in enumerate(seqs):
+                if s.mtp_draft is not None:
+                    drafts[i] = s.mtp_draft
+            want_lp, greedy = self._want_lp(seqs), self._all_greedy(seqs)
+            key = self._tel_key("mtp_chain", batch, (want_lp, greedy))
+            bucket = f"b{Bb}xk1"
+            self._step_info(
+                "decode", bucket, seqs,
+                dict(batch, kv_lens=np.where(
+                    batch["kv_lens"] > 0, batch["kv_lens"] + 1, 0)),
+                2 * len(seqs), verify=self._verify_info(
+                    int(sum(self._draft_ok(s) and s.mtp_draft is not None
+                            for s in seqs))))
+        with self._dispatching(
+            "decode", key, seqs, bucket, tokens=2 * len(seqs),
+            fill=len(seqs) / Bb,
+        ) as who, self._device_lock:
+            self._host_gap_mark(bucket, who.t0, seqs)
+            with self._launch("decode", who, pipelined=1) as launch:
+                dev = self._put_batch(batch)
+                carry = jax.device_put(
+                    (dev.pop("tokens"), drafts, dev.pop("positions"),
+                     np.zeros((), np.uint32)), self._repl)
+                toks, *carry, self.kv_cache = self.programs.call(
+                    key, self._mtp_chained,
+                    (self.params, self.kv_cache, dev, *carry),
+                    (want_lp, greedy))
+                toks.copy_to_host_async()
+                launch.handle = toks
+        self._burst = {
+            "mtp": True, "batch": dev, "carry": carry, "toks": toks, "n": 1,
+            "want_lp": want_lp, "greedy": greedy, "with_pen": False,
+            "steps": 1, "key": key, "members": list(seqs),
+            "rows": {k: v for k, v in batch.items()
+                     if k not in ("tokens", "positions")},
+        }
+        self._burst_tel = (key, bucket, Bb, 1)
+        self._warm_splice(Bb)
+
+    def _verify_info(self, drafted: int) -> dict:
+        """What a verify-and-draft step's ``pst.step_info`` carries beside a
+        decode step's (``accepted``: the last fetched step's)."""
+        return {"step": "mtp_verify", "draft_positions": drafted,
+                "accepted": self._mtp_accepted_last}
+
+    def _mtp_rows(self, rows: np.ndarray, members: List[Sequence]) -> list:
+        """A fetched verify-and-draft step's packed rows as the engine reads
+        a burst's: for each row its one or two packed samples. Each member's
+        next draft is kept on it (what a synchronous step after a drain
+        verifies)."""
+        out, took_all = [], rows[:, -2].astype(np.int32)
+        for i, took in enumerate(took_all.tolist()):
+            out.append(rows[i, :-2].reshape(2, -1)[: 1 + took])
+        for s, draft in zip(members, rows[:, -1].tolist()):
+            s.mtp_draft = int(draft)
+        self._mtp_accepted_last = int(took_all[: len(members)].sum())
+        return out
+
     def _warm_splice(self, Bb: int) -> None:
         """Compile `_splice` for a chain of ``Bb`` rows behind every row
         bucket a prefill step can have, the first time a chain of that size
@@ -1667,8 +1890,13 @@ class ModelRunner:
         rows = 1
         while rows <= _pow2(
                 min(self.cfg.max_num_seqs, self.cfg.max_prefill_tokens)):
-            toks = put(np.zeros((rows + self._aux_rows, 1), np.float32))
-            self._call_splice(carry, carry, toks, src, carry)
+            # (a prefill row of an engine that drafts carries its first draft)
+            toks = put(np.zeros(
+                (rows + self._aux_rows, 2 if self._mtp else 1), np.float32))
+            if self._mtp:
+                self._call_splice_mtp(carry, carry, carry, toks, src, carry)
+            else:
+                self._call_splice(carry, carry, toks, src, carry)
             rows <<= 1
 
     def _call_splice(self, tokens, positions, toks, src, pos):
@@ -1677,6 +1905,11 @@ class ModelRunner:
         return self.programs.call(
             (self._tel_scope, "splice", tokens.shape, toks.shape),
             self._splice, (tokens, positions, toks, src, pos))
+
+    def _call_splice_mtp(self, tokens, drafts, positions, toks, src, pos):
+        return self.programs.call(
+            (self._tel_scope, "splice_mtp", tokens.shape, toks.shape),
+            self._mtp_splice, (tokens, drafts, positions, toks, src, pos))
 
     def burst_width_stable(self, members: List[Sequence]) -> bool:
         """True while the members' block tables still fit the width bucket
@@ -1717,6 +1950,9 @@ class ModelRunner:
         """What `_decode_batch` builds a row beside its table and length."""
         rows = self._slot_rows(seqs, B, W)
         rows.update(self._sampling_arrays(seqs, B))
+        if self._mtp:
+            rows["draft_ok"] = np.zeros(B, bool)
+            rows["draft_ok"][: len(seqs)] = [self._draft_ok(s) for s in seqs]
         return rows
 
     def burst_continue(
@@ -1768,18 +2004,31 @@ class ModelRunner:
                         (_seed_for(s, 1) - st["steps"]) & 0xFFFF_FFFF)
                     src[row], pos[row] = prefill_row, s.num_tokens
                 refresh, splice = own, (src, pos)
+            st["fetch_members"], st["members"] = (
+                st.get("members", ()), list(members))
             alive = sum(1 for s in members if not s.is_finished)
             # The host's view lags the device by the burst in flight: a
             # live row holds n more tokens after this burst's first step
             # than its kv_len here says, and 2n - 1 more after its last (a
             # joining row's prefill is the program its view lags by).
             n = tel[3]
-            self._step_info(
-                "decode", tel[1], members,
-                {"kv_lens": np.where(kv_lens > 0, kv_lens + n, 0),
-                 "block_tables": tables},
-                alive * n, n - 1,
-            )
+            if st.get("mtp"):
+                # (two positions a row; the step in flight may have
+                # committed two tokens more than the host has seen)
+                self._step_info(
+                    "decode", tel[1], members,
+                    {"kv_lens": np.where(kv_lens > 0, kv_lens + 2, 0),
+                     "block_tables": tables}, 2 * alive,
+                    verify=self._verify_info(int(
+                        own["draft_ok"][: len(members)][
+                            kv_lens[: len(members)] > 0].sum())))
+            else:
+                self._step_info(
+                    "decode", tel[1], members,
+                    {"kv_lens": np.where(kv_lens > 0, kv_lens + n, 0),
+                     "block_tables": tables},
+                    alive * n, n - 1,
+                )
         key, bucket, rows_b, n = tel
         # The program launched here is charged, when it is seen ready a
         # cycle on, to the members still alive then; the host's wall around
@@ -1813,6 +2062,25 @@ class ModelRunner:
         the carry with the last prefill's rows."""
         st = self._burst
         prev = st["toks"]
+        if st.get("mtp"):
+            with self._launch("decode", who, pipelined=1) as launch:
+                st["batch"].update(self._put_batch(refresh))
+                tokens, drafts, positions, seed = st["carry"]
+                if splice is not None:
+                    src, pos = jax.device_put(splice, self._repl)
+                    tokens, drafts, positions = self._call_splice_mtp(
+                        tokens, drafts, positions, self._prefill_toks,
+                        src, pos)
+                toks, *st["carry"], self.kv_cache = self.programs.call(
+                    st["key"], self._mtp_chained,
+                    (self.params, self.kv_cache, st["batch"], tokens, drafts,
+                     positions, seed), (st["want_lp"], st["greedy"]))
+                toks.copy_to_host_async()
+                launch.handle = toks
+                st.update(toks=toks, steps=st["steps"] + 1)
+            return self._mtp_rows(
+                self._take_aux(_fetch(prev, "decode", self._clock)),
+                st["fetch_members"])
         with self._launch("decode", who, pipelined=1) as launch:
             st["batch"].update(self._put_batch(refresh))
             if splice is not None:
@@ -1845,6 +2113,8 @@ class ModelRunner:
         # fetch (they never read tokens) and their next announced dispatch
         # keeps program order identical.
         rows = self._take_aux(_fetch(st["toks"], "decode", self._clock))
+        if st.get("mtp"):
+            rows = self._mtp_rows(rows, st["members"])
         # Drains are transitions (an arrival or shape change broke the
         # pipeline) and a prefill may already be queued behind this fetch —
         # the wall from here to the next decode dispatch is not steady-state
@@ -1859,12 +2129,14 @@ class ModelRunner:
         committed token plus its K draft tokens in ONE forward pass.
 
         ``drafts`` is [B, K] int32. Returns ``(argmax_ids [B, K+1],
-        sampled0 [B])`` — row j's argmax is the token the model itself would
-        emit after consuming positions ≤ p0+j (the engine compares it
-        against the drafts to count acceptances), and ``sampled0`` is
-        position 0 put through the full sampling pipeline (temperature /
-        top-p / seeds / logit_bias), so draftless rows in a mixed batch get
-        exactly the token a plain decode step would have produced. KV for
+        packed0 [B, 1 or PACKED_WIDTH])`` — row j's argmax is the token the
+        model itself would emit after consuming positions ≤ p0+j (the engine
+        compares it against the drafts to count acceptances), and
+        ``packed0`` is position 0 put through the full sampling pipeline
+        (temperature / top-p / seeds / logit_bias) and packed as a decode
+        step packs it, with log-probabilities where some row asks for them,
+        so draftless rows in a mixed batch get exactly what a plain decode
+        step would have produced. KV for
         all K+1 positions is written during the pass; rejected positions
         sit past the committed kv_len and are overwritten on real decode.
         """
@@ -1883,8 +2155,8 @@ class ModelRunner:
         ) as who, self._device_lock:
             if self.publisher is not None:
                 self.publisher.announce("spec_verify", batch)
-            ids, sampled0 = self._dispatch_spec_verify(batch, key, who)
-        return ids[: len(seqs)], sampled0[: len(seqs)]
+            ids, packed0 = self._dispatch_spec_verify(batch, key, who)
+        return ids[: len(seqs)], packed0[: len(seqs)]
 
     def _spec_batch(
         self, seqs: List[Sequence], drafts: np.ndarray
@@ -1930,6 +2202,10 @@ class ModelRunner:
         # decode step (draftless rows in a mixed batch rely on this), and
         # LoRA rows verify WITH their adapter.
         batch.update(self._sampling_arrays(seqs, Bb))
+        if self._want_lp(seqs):
+            # its presence compiles position 0's log-probabilities in (a
+            # follower replays the batch: the same program there)
+            batch["lp_rows"] = np.zeros(Bb, bool)
         batch.pop("penalty_prompt", None)  # penalized rows never reach spec
         batch.pop("penalty_output", None)
         batch.pop("presence", None)
@@ -1986,12 +2262,12 @@ class ModelRunner:
                     batch["top_ks"],
                     batch["min_ps"],
                     batch["seeds"],
-                    with_logprobs=False,
+                    with_logprobs="lp_rows" in batch,
                 )
-                sampled0 = packed0[:, 0].astype(jnp.int32)  # [B]
-                # ONE output array = ONE host fetch: column K+1 carries
-                # the sampled position-0 token.
-                return jnp.concatenate([ids, sampled0[:, None]], axis=1), kv_cache
+                # ONE output array = ONE host fetch: the columns past K+1
+                # carry position 0's packed sample.
+                return jnp.concatenate(
+                    [ids.astype(packed0.dtype), packed0], axis=1), kv_cache
 
             cache_sh = self._cache_sharding()
             # pstlint: jit-family=spec_verify
@@ -2007,7 +2283,8 @@ class ModelRunner:
             )
             launch.handle = packed
         packed = _fetch(packed, "spec_verify", self._clock)
-        return packed[:, :-1], packed[:, -1]
+        T = batch["tokens"].shape[1]
+        return packed[:, :T].astype(np.int32), packed[:, T:]
 
     def _prefill_tel(
         self, items: List[PrefillItem], batch: Dict[str, np.ndarray],
@@ -2050,7 +2327,7 @@ class ModelRunner:
             "prefill", key, items, bucket, tokens=real, fill=fill,
         ) as who:
             rows = self._run(batch, want_lp, greedy, "prefill", key, who)
-        return rows[: len(items)]
+        return self._take_drafts(items, rows[: len(items)])
 
     def execute_prefill_batch_nofetch(self, items: List[PrefillItem]) -> None:
         """Dispatch a prefill step WITHOUT fetching its sampled tokens.
@@ -2131,13 +2408,14 @@ class ModelRunner:
                 )
                 toks.copy_to_host_async()
                 launch.handle = self._prefill_toks = toks
+        self._prefill_items = items
         if record_at_fetch:
             self._prefill_record = who.record
         return toks
 
     def prefill_fetch(self, handle, n_items: int) -> np.ndarray:
-        rows = self._take_aux(
-            _fetch(handle, "prefill", self._clock))[:n_items]
+        rows = self._take_drafts(self._prefill_items, self._take_aux(
+            _fetch(handle, "prefill", self._clock))[:n_items])
         record, self._prefill_record = self._prefill_record, None
         if record is not None:
             record()
@@ -2231,6 +2509,8 @@ class ModelRunner:
             self._warmup_prefill(bucket)
         elif kind == "spec_verify":
             self._warmup_spec_verify(bucket)
+        elif kind == "mtp_verify":
+            self._warmup_mtp_verify(bucket)
         elif kind == "encode":
             self._warmup_encode(bucket)
         else:
@@ -2321,6 +2601,11 @@ class ModelRunner:
         batch.update(self._warmup_sampling_arrays(Bb, Wb))
         if self._skips_cross:
             batch["sample_rows"] = np.zeros(Bb, bool)
+        if self._mtp:
+            batch.update(
+                mtp_next=np.zeros((Bb, Tb), np.int32),
+                mtp_write_idx=np.full((Bb, Tb), self._drop_slot, np.int32),
+                mtp_kv_lens=np.zeros(Bb, np.int32))
         key = self._tel_key("prefill", batch, (bucket.want_lp, bucket.greedy))
         t0 = time.perf_counter()
         self._run(batch, bucket.want_lp, bucket.greedy, "prefill", key)
@@ -2349,6 +2634,49 @@ class ModelRunner:
         self._record_warmup(
             "spec_verify", key, time.perf_counter() - t0, bucket.label
         )
+
+    def _warmup_mtp_verify(self, bucket) -> None:
+        Bb, Wb = bucket.rows, bucket.width
+        drop = np.full((Bb, 2), self._drop_slot, np.int32)
+        batch = {
+            "tokens": np.zeros((Bb, 2), np.int32),
+            "positions": np.zeros((Bb, 2), np.int32),
+            "write_idx": drop, "mtp_write_idx": drop.copy(),
+            "block_tables": np.zeros((Bb, Wb), np.int32),
+            "kv_lens": np.zeros(Bb, np.int32),
+            "mtp_kv_lens": np.zeros(Bb, np.int32),
+            "last_idx": np.ones(Bb, np.int32),
+            "draft_ok": np.zeros(Bb, bool),
+        }
+        batch.update(self._warmup_sampling_arrays(Bb, Wb))
+        flags = (bucket.want_lp, bucket.greedy)
+        key = self._tel_key("mtp_verify", batch, flags)
+        t0 = time.perf_counter()
+        with self._device_lock:
+            self._dispatch_mtp_verify(batch, *flags, key)
+        self._record_warmup(
+            "decode", key, time.perf_counter() - t0, bucket.label)
+        if not self.cfg.overlap_decode:
+            return
+        # the chained form: what every step of such an engine is but for a
+        # row the chain cannot take
+        chain = {k: v for k, v in batch.items() if k not in (
+            "tokens", "positions", "write_idx", "mtp_write_idx",
+            "mtp_kv_lens", "last_idx")}
+        key = self._tel_key("mtp_chain", dict(
+            chain, tokens=np.zeros(Bb, np.int32),
+            positions=np.zeros(Bb, np.int32)), flags)
+        t0 = time.perf_counter()
+        with self._device_lock:
+            dev = self._put_batch(chain)
+            carry = jax.device_put(
+                (np.zeros(Bb, np.int32), np.full(Bb, -1, np.int32),
+                 np.zeros(Bb, np.int32), np.zeros((), np.uint32)), self._repl)
+            *_, self.kv_cache = self.programs.call(
+                key, self._mtp_chained,
+                (self.params, self.kv_cache, dev, *carry), flags)
+        self._record_warmup(
+            "decode", key, time.perf_counter() - t0, bucket.label)
 
     def _warmup_encode(self, bucket) -> None:
         T = bucket.tokens
@@ -2452,8 +2780,9 @@ class ModelRunner:
         if not multi:
             batch["write_idx"] = write_idx
             batch["last_idx"] = last_idx
-        batch.update(self._slot_rows(seqs, Bb, Wb))
-        batch.update(self._sampling_arrays(seqs, Bb))
+        batch.update(self._member_rows(seqs, Bb, Wb) if multi else {
+            **self._slot_rows(seqs, Bb, Wb),
+            **self._sampling_arrays(seqs, Bb)})
         return batch
 
     def _prefill_batch(self, items: List[PrefillItem]) -> Dict[str, np.ndarray]:
@@ -2492,6 +2821,8 @@ class ModelRunner:
             "kv_lens": kv_lens,
             "last_idx": last_idx,
         }
+        if self._mtp:
+            batch.update(self._mtp_prefill_arrays(items, batch))
         batch.update(self._slot_rows([it.seq for it in items], Bb, Wb))
         if self._skips_cross:
             # rows whose chunk ends what they have to prefill: the only
@@ -2504,6 +2835,164 @@ class ModelRunner:
                     else s.num_tokens - 1)
         batch.update(self._sampling_arrays([it.seq for it in items], Bb))
         return batch
+
+    def _row_slots(self, seq: Sequence, first: int, n: int) -> np.ndarray:
+        """Flat slots of the global group for ``seq``'s positions ``first ..
+        first + n - 1``: the drop slot where it holds no page for one."""
+        bs = self.cfg.block_size
+        pos = first + np.arange(n)
+        held = pos < len(seq.block_ids) * bs
+        pages = np.asarray(seq.block_ids, np.int64)[pos[held] // bs]
+        idx = np.full(n, self._drop_slot, np.int32)
+        idx[held] = pages * bs + pos[held] % bs
+        return idx
+
+    def _mtp_slots(self, seq: Sequence, first: int, n: int) -> tuple:
+        """Where the draft layer's entries for positions ``first .. first +
+        n - 1`` go (the slot rule: position ``i``'s at slot ``i + 1``) and
+        the length its attention reads up to: the last of those slots that
+        has a page."""
+        covered = len(seq.block_ids) * self.cfg.block_size
+        return (self._row_slots(seq, first + 1, n),
+                min(first + n + 1, covered))
+
+    def _mtp_prefill_arrays(
+        self, items: List[PrefillItem], batch: Dict[str, np.ndarray]
+    ) -> Dict[str, np.ndarray]:
+        """What a prefill step hands the draft module beside the batch:
+        ``mtp_next`` the token after each position (-1 after a sequence's
+        last: the step's own sample), ``mtp_write_idx`` / ``mtp_kv_lens``
+        the slots one ahead. And the main stack's writes into pages this
+        sequence took from the prefix cache are dropped (``batch`` in
+        place): the one position a hit computes again, for the state that
+        makes the first slot past the hit, is in a page others read."""
+        bs = self.cfg.block_size
+        Bb, Tb = batch["tokens"].shape
+        nxt = np.zeros((Bb, Tb), np.int32)
+        idx = np.full((Bb, Tb), self._drop_slot, np.int32)
+        lens = np.zeros(Bb, np.int32)
+        for i, it in enumerate(items):
+            s, start, chunk = it.seq, it.start, it.end - it.start
+            ids = s.all_token_ids
+            for j in range(chunk):
+                nxt[i, j] = ids[start + j + 1] if start + j + 1 < len(ids) else -1
+            idx[i, :chunk], lens[i] = self._mtp_slots(s, start, chunk)
+            shared = min(s._committed_blocks * bs - start, chunk)
+            if shared > 0:
+                batch["write_idx"][i, :shared] = self._drop_slot
+        return {"mtp_next": nxt, "mtp_write_idx": idx, "mtp_kv_lens": lens}
+
+    def _take_drafts(self, items: List[PrefillItem], rows: np.ndarray) -> np.ndarray:
+        """Split a fetched prefill step's rows from the first drafts under
+        their last column, and keep each for the sequence whose prompt the
+        step completed (its first decode step verifies it)."""
+        if not self._mtp:
+            return rows
+        for it, draft in zip(items, rows[:, -1]):
+            it.seq.mtp_draft = (
+                int(draft) if it.end == it.seq.num_prompt_tokens
+                and not it.seq.output_token_ids else None)
+        return rows[:, :-1]
+
+    # -- the verify-and-draft step (``--speculative-mtp``) -----------------
+
+    def _draftable(self, s: Sequence) -> bool:
+        """Whether ``s``'s draft may be verified this step: greedy rows
+        only (a sampled row's second token would need the first's seed
+        history), no penalties (the first token would change the counts),
+        no guided choice (the mask is rebuilt a token on the host), and
+        room for two tokens under ``max_model_len``."""
+        return (s.mtp_draft is not None and self._draft_ok(s)
+                and s.num_tokens + 2 <= self.cfg.max_model_len)
+
+    @staticmethod
+    def _draft_ok(s: Sequence) -> bool:
+        """What of `_draftable` a row keeps for its whole life (a chain's
+        batch carries it a row; the draft and the room are the device's
+        carry's and `LLMEngine._chainable`'s to say there)."""
+        sp = s.sampling
+        return sp.greedy and not sp.has_penalties and not sp.guided_choice
+
+    def _mtp_batch(self, seqs: List[Sequence]) -> Dict[str, np.ndarray]:
+        B = len(seqs)
+        Bb = self._row_bucket(B)
+        Wb = self._table_bucket(seqs)
+        bs = self.cfg.block_size
+        tokens = np.zeros((Bb, 2), np.int32)
+        positions = np.zeros((Bb, 2), np.int32)
+        write_idx = np.full((Bb, 2), self._drop_slot, np.int32)
+        mtp_idx = np.full((Bb, 2), self._drop_slot, np.int32)
+        tables = np.zeros((Bb, Wb), np.int32)
+        kv_lens = np.zeros(Bb, np.int32)
+        mtp_lens = np.zeros(Bb, np.int32)
+        draft_ok = np.zeros(Bb, bool)
+        for i, s in enumerate(seqs):
+            p0 = s.num_tokens - 1  # the not-yet-computed last token
+            tokens[i, 0] = (s.output_token_ids[-1] if s.output_token_ids
+                            else s.prompt_token_ids[-1])
+            draft_ok[i] = self._draftable(s)
+            if draft_ok[i]:
+                tokens[i, 1] = s.mtp_draft
+            positions[i] = (p0, p0 + 1)
+            write_idx[i] = self._row_slots(s, p0, 2)
+            mtp_idx[i], mtp_lens[i] = self._mtp_slots(s, p0, 2)
+            tables[i] = self._table_row(s, Wb)
+            kv_lens[i] = min(p0 + 2, len(s.block_ids) * bs)
+        batch = {
+            "tokens": tokens, "positions": positions, "write_idx": write_idx,
+            "block_tables": tables, "kv_lens": kv_lens,
+            # every live row holds two positions: what the model's ``valid``
+            # mask and the expert dispatch count as real
+            "last_idx": np.ones(Bb, np.int32),
+            "mtp_write_idx": mtp_idx, "mtp_kv_lens": mtp_lens,
+            "draft_ok": draft_ok,
+        }
+        batch.update(self._slot_rows(seqs, Bb, Wb))
+        batch.update(self._sampling_arrays(seqs, Bb))
+        return batch
+
+    def execute_mtp_verify(self, seqs: List[Sequence]) -> list:
+        """One verify-and-draft step, synchronous: for each sequence its
+        one or two packed samples (two where the device accepted its draft)
+        as a decode step packs them, log-probabilities included where a row
+        asks; what a chained step's fetch gives (`_mtp_rows`). Each
+        sequence's next draft is kept on it. One launch, one fetch."""
+        with ENGINE_TELEMETRY.phase("batch_build", "decode"):
+            batch = self._mtp_batch(seqs)
+            want_lp, greedy = self._want_lp(seqs), self._all_greedy(seqs)
+            key = self._tel_key("mtp_verify", batch, (want_lp, greedy))
+            Bb = batch["kv_lens"].shape[0]
+            bucket = f"b{Bb}xk1"
+            # The step is a decode step to the telemetry and the trace (its
+            # program is found under ``jit_pst_decode_step*``), told apart
+            # by its bucket and these fields; ``accepted`` is the last
+            # step's, known only after its fetch.
+            self._step_info(
+                "decode", bucket, seqs, batch, 2 * len(seqs),
+                verify={"step": "mtp_verify",
+                        "draft_positions": int(batch["draft_ok"].sum()),
+                        "accepted": self._mtp_accepted_last})
+        with self._dispatching(
+            "decode", key, seqs, bucket, tokens=2 * len(seqs),
+            fill=len(seqs) / Bb,
+        ) as who, self._device_lock:
+            self._host_gap_mark(bucket, who.t0, seqs)
+            rows = self._dispatch_mtp_verify(batch, want_lp, greedy, key, who)
+            self._host_gap_arm()
+        return self._mtp_rows(rows[: len(seqs)], seqs)
+
+    def _dispatch_mtp_verify(
+        self, batch: Dict[str, np.ndarray], want_lp: bool, greedy: bool,
+        key: Optional[tuple] = None, who: Optional[_Dispatch] = None,
+    ) -> np.ndarray:
+        with self._launch("decode", who) as launch:
+            packed, self.kv_cache = self.programs.call(
+                key, self._step["mtp_verify"],
+                (self.params, self.kv_cache, self._put_batch(batch)),
+                (want_lp, greedy),
+            )
+            launch.handle = packed
+        return self._take_aux(_fetch(packed, "decode", self._clock))
 
     def _sampling_arrays(
         self, seqs: List[Sequence], B: int

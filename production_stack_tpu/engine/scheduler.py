@@ -40,6 +40,12 @@ class SchedulerConfig:
     # step writes KV at up to spec_tokens positions past the committed
     # length, so those pages must exist before dispatch.
     spec_tokens: int = 0
+    # The model drafts its own next token (``--speculative-mtp``): its draft
+    # layer's entry for a position is stored one slot ahead, so a prefill
+    # chunk needs the page of the token after its last, and a prefix hit
+    # recomputes its last cached token (whose state makes the first slot
+    # past the hit).
+    mtp: bool = False
     # Fair timeslicing when more live users than HBM holds (needs a
     # swapper): after a running sequence has decoded this many tokens since
     # its last (re)admission, it may rotate out in favor of a parked or
@@ -279,7 +285,7 @@ class Scheduler:
             chunk = min(remaining, budget)
             start = seq.num_computed_tokens
             end = start + chunk
-            if not self._ensure_blocks(seq, end, out):
+            if not self._ensure_blocks(seq, end + self.config.mtp, out):
                 continue
             out.prefills.append(PrefillItem(seq=seq, start=start, end=end))
             budget -= chunk
@@ -577,7 +583,9 @@ class Scheduler:
                 blocks, hashes = self.allocator.match_window(seq, blocks, hashes)
                 if blocks:
                     seq.adopt_cached_prefix(blocks, hashes)
-                    seq.num_computed_tokens = len(blocks) * self.allocator.block_size
+                    seq.num_computed_tokens = (
+                        len(blocks) * self.allocator.block_size
+                        - self.config.mtp)
                     seq.num_cached_prompt_tokens = seq.num_computed_tokens
                 if self._running_prefill_computes_next_page(
                         seq, toks, len(blocks)):

@@ -116,6 +116,11 @@ class Sequence:
         kv_transfer: Optional[dict] = None,
     ):
         self.request_id = request_id
+        # The model's own draft of the token after the last one committed
+        # (``--speculative-mtp``): made on the device by the step that
+        # committed it; None where that step was none of those (a sequence
+        # recomputed after preemption), and its next step then verifies none.
+        self.mtp_draft: Optional[int] = None
         self.prompt_token_ids: List[int] = list(prompt_token_ids)
         self.output_token_ids: List[int] = []
         self.sampling = sampling
@@ -318,6 +323,7 @@ class Sequence:
         # mark set would bill the post-recompute page count over the
         # whole wait (systematic overcharge of preempted tenants).
         self._kv_cost_mark = None
+        self.mtp_draft = None
         self.block_ids = []
         self.num_computed_tokens = 0
         self.num_cached_prompt_tokens = 0

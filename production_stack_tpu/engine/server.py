@@ -337,6 +337,21 @@ class EngineMetrics:
             "passed the dispatch's row capacity, so that further rounds "
             "ran (models/moe_dispatch.py::capacity)",
         )
+        self.mtp_steps = counter(
+            "pst:mtp_steps",
+            "verify-and-draft steps fetched (--speculative-mtp): one launch "
+            "and one fetch each, counted on the device",
+        )
+        self.mtp_row_steps = counter(
+            "pst:mtp_row_steps",
+            "live rows of the verify-and-draft steps fetched, summed: the "
+            "denominator of tokens a row and step",
+        )
+        self.mtp_tokens_emitted = counter(
+            "pst:mtp_tokens_emitted",
+            "tokens the verify-and-draft steps yielded by the device's "
+            "accept test: one a live row, two where its draft was accepted",
+        )
         self.mla_prefill_steps = counter(
             "pst:mla_prefill_steps",
             "fetched prefill steps of a latent-attention model, by the way "
@@ -515,6 +530,9 @@ class EngineMetrics:
             (self.moe_experts_touched, "moe_experts_touched_total"),
             (self.moe_layer_steps, "moe_layer_steps_total"),
             (self.moe_dispatch_overflow, "moe_dispatch_overflow_total"),
+            (self.mtp_steps, "mtp_steps_total"),
+            (self.mtp_row_steps, "mtp_row_steps_total"),
+            (self.mtp_tokens_emitted, "mtp_tokens_emitted_total"),
         ):
             self._counter_to(metric, key, stats.get(key, 0))
         for path in ("expanded", "absorbed"):
@@ -1975,6 +1993,11 @@ def parse_engine_args(argv=None) -> argparse.Namespace:
     # Speculative decoding (n-gram prompt lookup; 0 = off).
     p.add_argument("--speculative-ngram", type=int, default=0,
                    help="max draft tokens per step via n-gram prompt lookup")
+    p.add_argument("--speculative-mtp", type=int, default=0,
+                   help="1: verify one draft a row from the model's own "
+                        "multi-token-prediction module every decode step and "
+                        "make the next, on the device (a model with the "
+                        "module only; 0 = off)")
     p.add_argument("--ngram-min", type=int, default=1)
     p.add_argument("--ngram-max", type=int, default=3)
     p.add_argument("--ngram-lookback", type=int, default=8192,
@@ -2133,6 +2156,7 @@ def engine_config_from_args(args: argparse.Namespace) -> EngineConfig:
         overlap_decode=args.overlap_decode,
         min_decode_bucket=args.min_decode_bucket,
         speculative_ngram=args.speculative_ngram,
+        speculative_mtp=args.speculative_mtp,
         ngram_min=args.ngram_min,
         ngram_max=args.ngram_max,
         ngram_lookback=args.ngram_lookback,

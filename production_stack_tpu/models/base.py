@@ -50,6 +50,10 @@ class ModelConfig:
     num_state_layers = 0
     num_experts = 0  # experts an expert-parallel mesh could divide
     sliding_window = 0  # tokens a window layer reads (0: no window layers)
+    # Draft layers of the model's own (multi-token prediction) that a decode
+    # step may run and verify on the device (``--speculative-mtp``); their
+    # pages are further layers of the global group, stored one slot ahead.
+    mtp_layers = 0
 
     @property
     def jdtype(self):
@@ -119,6 +123,14 @@ class Model:
     def cache_pspec(self, pipeline: bool = False) -> Dict[str, P]:
         """Every array of ``make_kv_cache``'s dict replicated."""
         return {k: P() for k in jax.eval_shape(lambda: self.make_kv_cache(1, 1))}
+
+    def mtp_forward(self, *args, **kwargs):
+        """The draft module over positions ``forward`` has just run (with
+        ``return_hidden``): a class whose config has ``mtp_layers`` brings it
+        (``models/exaone_moe.py``); the engine asks no other class
+        (``engine/config.py::refuse_mtp``)."""
+        raise NotImplementedError(
+            f"{type(self).__name__} has no multi-token-prediction module")
 
     @staticmethod
     def step_aux(cache) -> jax.Array:

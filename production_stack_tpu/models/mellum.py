@@ -33,7 +33,9 @@ The seven periods are one scanned body (the window layers an inner scan), so
 a step's program holds one period's layers, not 28.
 
 The draft head of the published checkpoint (``described_as``: "MTP head") is
-beside the model; it is not served (``engine/spec.py`` drafts n-grams).
+beside the model and not built for this class. A class that brings its
+module serves it (``--speculative-mtp``: ``models/exaone_moe.py``, a
+verify-and-draft step on the device); ``engine/spec.py`` drafts n-grams.
 """
 
 from __future__ import annotations

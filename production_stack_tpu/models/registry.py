@@ -12,10 +12,12 @@ import json
 import os
 from typing import Callable, Dict, Tuple
 
-from . import glm4_moe_lite, llama, mellum, nemotron_h, phi4flash, qwen3_next
+from . import (exaone_moe, glm4_moe_lite, llama, mellum, nemotron_h, phi4flash,
+               qwen3_next)
 from .base import Model, ModelConfig
 from .glm4_moe_lite import Glm4MoeLiteConfig
 from .llama import LlamaConfig
+from .exaone_moe import ExaoneMoeConfig
 from .mellum import MellumConfig
 from .nemotron_h import NemotronHConfig
 from .phi4flash import Phi4FlashConfig
@@ -38,6 +40,8 @@ MODEL_TYPES: Dict[str, Tuple[Callable[[dict, str], ModelConfig], type, type]] = 
     "qwen3_next": (
         qwen3_next.config_from_hf, Qwen3NextConfig, qwen3_next.Qwen3Next),
     "mellum": (mellum.config_from_hf, MellumConfig, mellum.Mellum),
+    "exaone_moe": (
+        exaone_moe.config_from_hf, ExaoneMoeConfig, exaone_moe.ExaoneMoe),
 }
 _MODEL_OF = {config: model for _, config, model in MODEL_TYPES.values()}
 
@@ -427,6 +431,33 @@ PRESETS: Dict[str, ModelConfig] = {
         moe_intermediate_size=32,
         max_position_embeddings=2048,
         name="tiny-mellum-debug",
+        eos_token_ids=(0,),
+        bos_token_id=None,
+        dtype="float32",
+    ),
+    # Tiny window / full mix with a draft module: a dense layer, then expert
+    # layers (8 sigmoid-routed experts top 2 with a selection bias, one shared
+    # expert), `sliding, sliding, sliding, full, sliding` at a 16-token
+    # window, and one multi-token-prediction layer (--speculative-mtp 1).
+    "tiny-exaone-moe-debug": ExaoneMoeConfig(
+        vocab_size=128,
+        hidden_size=64,
+        intermediate_size=128,
+        num_layers=5,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",)
+        + ("sliding_attention",),
+        mlp_layer_types=("dense",) + ("sparse",) * 4,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        sliding_window=16,
+        rope_theta=10000.0,
+        n_routed_experts=8,
+        router_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=32,
+        max_position_embeddings=2048,
+        name="tiny-exaone-moe-debug",
         eos_token_ids=(0,),
         bos_token_id=None,
         dtype="float32",

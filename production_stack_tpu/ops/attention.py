@@ -122,6 +122,7 @@ def paged_attention(
     window=0,
     softcap: float = 0.0,
     mesh=None,
+    key_floor: int = 0,
 ) -> jax.Array:
     """Causal attention of ``q`` against paged KV. Returns [B, T, H, hd].
 
@@ -130,9 +131,22 @@ def paged_attention(
     to the last ``window`` positions; 0 = unlimited. ``softcap`` applies
     Gemma-style attention-logit soft-capping ``tanh(s/c)*c`` (static; 0 =
     off). ``mesh``: the engine mesh when it spans more than one device —
-    the kernel then runs once per shard (see :func:`_pallas_per_shard`)."""
+    the kernel then runs once per shard (see :func:`_pallas_per_shard`).
+    ``key_floor`` (static): keys at positions below it are masked for every
+    query (a layer whose entries are stored one slot ahead leaves slot 0
+    empty: ``models/exaone_moe.py``'s draft layer); one device only."""
     impl = resolve_attn_impl(impl)
     if impl == "pallas":
+        if key_floor:
+            from .paged_attention_pallas import pallas_paged_attention
+
+            if mesh is not None and mesh.size > 1:
+                raise ValueError("key_floor is served on one device")
+            return pallas_paged_attention(
+                q, kv_pages, block_tables, kv_lens, q_positions, layer,
+                scale=scale, window=window, softcap=softcap,
+                key_floor=key_floor,
+            )
         if mesh is not None and mesh.size > 1:
             return _pallas_per_shard(
                 q, kv_pages, block_tables, kv_lens, q_positions, layer,
@@ -146,7 +160,7 @@ def paged_attention(
         )
     return gather_paged_attention(
         q, kv_pages, block_tables, kv_lens, q_positions, layer, scale=scale,
-        window=window, softcap=softcap,
+        window=window, softcap=softcap, key_floor=key_floor,
     )
 
 
@@ -211,6 +225,7 @@ def gather_paged_attention(
     scale: float,
     window=0,
     softcap: float = 0.0,
+    key_floor: int = 0,
 ) -> jax.Array:
     B, T, H, hd = q.shape
     _, nb, _, bs, lanes = kv_pages.shape
@@ -242,6 +257,8 @@ def gather_paged_attention(
     # Sliding window: each query sees at most the last `window` positions
     # (0 = unlimited; `window` may be a traced scalar for per-layer windows).
     in_window = kv_pos[:, None, :] > q_positions[..., None] - window_eff(window)
+    if key_floor:
+        in_window &= kv_pos[:, None, :] >= key_floor
     mask = (valid[:, None, :] & causal & in_window)[:, None, None]
     scores = jnp.where(mask, scores, _NEG_INF)
 

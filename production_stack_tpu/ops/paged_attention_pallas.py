@@ -434,6 +434,7 @@ def _chunked_flash(
     group: int,
     head_dim: int,
     softcap: float = 0.0,
+    key_floor: int = 0,
 ):
     """Per-head flash accumulation over streamed KV chunks, the prefill
     shape: many query rows a head, so the fold and not the stream is what
@@ -473,6 +474,8 @@ def _chunked_flash(
         seen = (col < jnp.minimum(q_pos + 1, kv_len)) & (
             col >= q_pos + 1 - win_eff
         )
+        if key_floor:  # static: a layer stored one slot ahead, slot 0 empty
+            seen &= col >= key_floor
         s = jnp.where(seen, s, _NEG_INF)
         m_prev = m_ref[h, j, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -992,6 +995,7 @@ def _prefill_kernel(
     head_dim: int,
     q_tile: int,
     softcap: float = 0.0,
+    key_floor: int = 0,
 ):
     b = pl.program_id(0)
     tq = pl.program_id(1)
@@ -1057,6 +1061,7 @@ def _prefill_kernel(
             group=group,
             head_dim=head_dim,
             softcap=softcap,
+            key_floor=key_floor,
         )
         # Rows past the real length return zeros whether their sub-tile
         # was folded beside real rows or not at all (l stays 0 there).
@@ -1169,7 +1174,7 @@ def _decode_call(q3, kv_pages, block_tables, kv_lens, layer, window,
 
 
 def _prefill_call(q, kv_pages, block_tables, kv_lens, starts, layer, window,
-                  *, scale, q_tile, softcap):
+                  *, scale, q_tile, softcap, key_floor=0):
     B, T, H, hd = q.shape
     _, nb, _, bs, lanes = kv_pages.shape
     KH = lanes // hd
@@ -1212,6 +1217,7 @@ def _prefill_call(q, kv_pages, block_tables, kv_lens, starts, layer, window,
         head_dim=hd,
         q_tile=q_tile,
         softcap=softcap,
+        **({"key_floor": key_floor} if key_floor else {}),
     )
     return pl.pallas_call(
         kernel,
@@ -1242,12 +1248,21 @@ def pallas_paged_attention(
     scale: float,
     window=0,  # int32 scalar sliding window (may be traced; 0 = unlimited)
     softcap: float = 0.0,  # attention-logit soft cap (static; 0 = off)
+    key_floor: int = 0,  # static: keys below it are masked for every query
 ) -> jax.Array:
     B, T, H, hd = q.shape
     tables = block_tables.astype(jnp.int32)
     lens = kv_lens.astype(jnp.int32)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     win_arr = jnp.asarray(window, jnp.int32).reshape(1)
+    if key_floor and T == 1:
+        # the floor is the prefill kernel's: one query row rides it as a
+        # chunk of two whose second row is padding (at or past ``kv_len``)
+        return pallas_paged_attention(
+            jnp.pad(q, ((0, 0), (0, 1), (0, 0), (0, 0))), kv_pages, tables,
+            lens, jnp.concatenate([q_positions, q_positions + 1], axis=1),
+            layer, scale=scale, window=window, softcap=softcap,
+            key_floor=key_floor)[:, :1]
     if T == 1:
         out = _decode_call(
             q[:, 0], kv_pages, tables, lens, layer_arr, win_arr,
@@ -1272,5 +1287,5 @@ def pallas_paged_attention(
     starts = q_positions[:, 0].astype(jnp.int32)
     return _prefill_call(
         q, kv_pages, tables, lens, starts, layer_arr, win_arr, scale=scale,
-        q_tile=q_tile, softcap=softcap,
+        q_tile=q_tile, softcap=softcap, key_floor=key_floor,
     )

@@ -73,6 +73,21 @@ def test_engine_template_wires_warmup_flags_and_cache_volume():
     assert 'fail "servingEngineSpec.warmup.cacheDir is set but neither' in text
 
 
+@pytest.mark.parametrize("flag,value", [
+    ("--speculative-ngram", "4"), ("--speculative-mtp", "1")])
+def test_engine_template_passes_a_draft_flag_through_extra_args(flag, value):
+    """Neither draft source has a value of its own in the chart: both ride
+    ``engineConfig.extraArgs``, which the template hands the engine as they
+    are; the values file names both, and the engine's parser takes them."""
+    from production_stack_tpu.engine.server import parse_engine_args
+
+    text = (HELM_DIR / "templates" / "deployment-engine.yaml").read_text()
+    assert re.search(r"range \.extraArgs \}\}\s*- \{\{ \. \| quote \}\}", text)
+    assert f'"{flag}"' in (HELM_DIR / "values.yaml").read_text()
+    args = parse_engine_args(["--model", "m", flag, value])
+    assert getattr(args, flag[2:].replace("-", "_")) == int(value)
+
+
 def test_values_schema_covers_warmup():
     with open(HELM_DIR / "values.schema.json") as f:
         schema = json.load(f)

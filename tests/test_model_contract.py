@@ -37,6 +37,7 @@ CONFIG_JSON = {
     "qwen3_next": "perf/configs/qwen3-next-ep8-cut.json",
     "mellum": "perf/configs/mellum2-ep4-cut.json",
     "ouro": "perf/configs/ouro-2.6b.json",
+    "exaone_moe": "perf/configs/k-exaone-ep8-cut.json",
 }
 CONFIG_CLASSES = sorted({row[1] for row in MODEL_TYPES.values()},
                         key=lambda c: c.__name__)
@@ -100,6 +101,10 @@ WEIGHT_DIGESTS = {
     # recorded when the model type was added (PR 49)
     "tiny-ouro-debug":
         "2f5101ef1166a85ab4cf09f9451578f0a6ccfeb337080be3e4c59f775c87edc1",
+    # every layer leaves of its own, the draft module's among them: recorded
+    # when the class was added (PR 53)
+    "tiny-exaone-moe-debug":
+        "8a230879dcae8e48e33b78adaa43205729e076c146d13ce454cc692c700af176",
 }
 
 

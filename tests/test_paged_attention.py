@@ -619,3 +619,40 @@ def test_pallas_decode_shared_phase_through_a_burst(monkeypatch):
     walk = np.asarray(jax.jit(burst)(q, kv, tables, lens_j), np.float32)
     assert np.all(np.isfinite(got))
     np.testing.assert_allclose(got, walk, rtol=tol, atol=tol)
+
+
+# ---------------------------------------------------------------------------
+# Two query positions a row (a verify-and-draft step) and a key floor (a
+# layer whose entries are stored one slot ahead: slot 0 empty and masked)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("T,window,key_floor", [
+    (2, 0, 0),  # a verify step through a full-attention layer
+    (2, 16, 0),  # through a window layer
+    (2, 0, 1),  # through the draft layer: slot 0 masked
+    (1, 0, 1),  # one position under the floor: rides the chunk kernel
+    (16, 0, 1),  # a prefill chunk under the floor
+])
+def test_pallas_verify_shapes_and_the_key_floor_match_gather(T, window, key_floor):
+    """Rows whose chunk starts at a page boundary, inside a page, at the
+    sequence's start (where the floor leaves the first query one key) and
+    deep into it; the kernel equals the gather reference, and under the
+    floor slot 0 is not read as a key whatever it holds."""
+    # (eight pages of 8 a row: a chunk ends by token 64)
+    starts = [1 if key_floor else 0, 13, 40, 48]
+    q, kv, tables, kv_lens, q_pos = _prefill_setup(
+        B=4, T=T, start_offsets=starts)
+    if key_floor:  # a wild slot 0: masked, so it moves nothing
+        kv = kv.at[:, tables[:, 0], :, 0].set(1e4)
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    kw = dict(scale=scale, window=window)
+    ref = gather_paged_attention(q, kv, tables, kv_lens, q_pos,
+                                 key_floor=key_floor, **kw)
+    got = pallas_paged_attention(q, kv, tables, kv_lens, q_pos,
+                                 key_floor=key_floor, **kw)
+    assert np.isfinite(np.asarray(got)).all() and np.abs(np.asarray(ref)).max() < 50
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)
+    if key_floor:  # and without the floor the wild slot is read
+        wild = gather_paged_attention(q, kv, tables, kv_lens, q_pos, **kw)
+        assert np.abs(np.asarray(wild) - np.asarray(ref)).max() > 1

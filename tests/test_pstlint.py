@@ -592,7 +592,9 @@ class TestLiveTree:
         assert active == [], [f.message for f in active]
 
     def test_real_lattice_families_complete(self):
-        """The real enumeration registers exactly the five families."""
+        """The real enumeration registers exactly the six families (the
+        sixth since PR 53: the verify-and-draft step of an engine that
+        serves its model's own draft)."""
         from production_stack_tpu.analysis.checks.recompile_risk import (
             lattice_families,
         )
@@ -604,7 +606,8 @@ class TestLiveTree:
         pre = project.find("engine/precompile.py")[0]
         families, _ = lattice_families(pre)
         assert families == {
-            "decode", "decode_burst", "prefill", "spec_verify", "encode"
+            "decode", "decode_burst", "prefill", "spec_verify", "encode",
+            "mtp_verify",
         }
 
 

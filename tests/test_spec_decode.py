@@ -263,3 +263,37 @@ def test_spec_with_prefix_cache_and_preemption_pressure():
         outs[i] = run_greedy(eng, f"p{i}", REPEAT, 16)
         wants[i] = run_greedy(base, f"q{i}", REPEAT, 16)
     assert outs == wants
+
+
+@pytest.mark.parametrize("top", [1, 5])
+def test_a_row_with_logprobs_rides_the_verify_step_draftless(top):
+    """One row of the batch asks for log-probabilities: the batch speculates
+    all the same (before PR 53 it did not). That row rides the verify step
+    without a draft, position 0's log-probabilities packed for it, and
+    reports what the non-speculative engine reports; the greedy row beside
+    it is drafted for."""
+    def run_pair(spec: bool):
+        eng = make_engine(**({"speculative_ngram": 4} if spec else {}))
+        for rid, lp in (("g", None), ("l", top), ("h", None)):
+            eng.add_request(
+                rid, prompt_token_ids=list(REPEAT),
+                sampling=SamplingParams(
+                    max_tokens=16, temperature=0.0, ignore_eos=True,
+                    logprobs=lp),
+            )
+        outs = {"g": [], "l": [], "h": []}
+        while eng.has_work():
+            for out in eng.step():
+                lps = out.logprobs or [None] * len(out.new_token_ids)
+                outs[out.request_id].extend(
+                    (t, lp and (round(lp["logprob"], 3),
+                                tuple(i for i, _ in lp["top"])))
+                    for t, lp in zip(out.new_token_ids, lps))
+        return outs, eng
+
+    base, _ = run_pair(spec=False)
+    spec, eng = run_pair(spec=True)
+    assert spec == base
+    assert all(lp is not None and len(lp[1]) == top for _, lp in spec["l"])
+    assert all(lp is None for _, lp in spec["g"])
+    assert eng.spec_proposed_total > 0 and eng.spec_accepted_total > 0
