@@ -287,14 +287,16 @@ class _Dispatch:
 
 
 def shared_prefix_run(
-    tables: np.ndarray, kv_lens: np.ndarray, block_size: int
+    tables: np.ndarray, kv_lens: np.ndarray, block_size: int,
+    positions: int = 1,
 ) -> "tuple[int, int]":
     """(pages, rows): the leading pages every live row of a decode batch
     holds in common, and the rows that share them (0 and 0 where nothing
     is shared). A prefix-cache hit gives rows the same page ids, so this is
     a comparison of the tables; rows with ``kv_len`` 0 (padding, a
-    finished member of a chain) are left out, and a row's last page, the
-    one it writes, is never counted. The decode kernel finds the same run
+    finished member of a chain) are left out, and the pages from a row's
+    first query position on (its last ``positions`` tokens: a verify step's
+    two), those it writes, are never counted. The decode kernel finds the same run
     itself, once a call (``ops/paged_attention_pallas.py::_find_shared_run``),
     and reads those pages once; here it is only counted."""
     # Plain lists, no array operation: on the step thread, beside 16-64
@@ -304,7 +306,7 @@ def shared_prefix_run(
     live = [i for i, n in enumerate(lens) if n > 0]
     if len(live) < 2:
         return 0, 0
-    cap = (min(lens[i] for i in live) - 1) // block_size
+    cap = max(min(lens[i] for i in live) - positions, 0) // block_size
     first = tables[:, 0].tolist()
     if cap <= 0 or any(first[i] != first[live[0]] for i in live):
         return 0, 0  # most batches: nothing shared
@@ -407,7 +409,8 @@ class ModelRunner:
         # and the kernel read once for all of them (`_step_info`).
         self.decode_context_tokens_total = 0
         self.decode_shared_tokens_spared_total = 0
-        self._sharing_calls: Dict[int, int] = {}  # by the bucket's rows
+        # by the bucket's rows and a row's query positions
+        self._sharing_calls: Dict[tuple, int] = {}
         # Rows a step appends to its packed tokens (the model's step_aux,
         # one for each name in its AUX_NAMES), summed here as they are
         # fetched.
@@ -1452,18 +1455,19 @@ class ModelRunner:
             # step's may be a page longer), and only where the calls take
             # it, by the rule they trace by (``decode_sharing_calls``).
             depth, Bb = kv_ahead + 1, len(batch["kv_lens"])
-            # (a verify-and-draft step's two positions a row go through the
-            # chunk kernel, which has no shared phase)
-            calls = 0 if verify else self._sharing_calls.get(Bb)
+            # (a verify-and-draft step's two positions a row ride the same
+            # stream: a short run, its shared pages below the first of them)
+            positions = 2 if verify else 1
+            calls = self._sharing_calls.get((Bb, positions))
             if calls is None:
                 cfg = self.model_cfg
-                calls = self._sharing_calls[Bb] = (
+                calls = self._sharing_calls[Bb, positions] = (
                     0 if cfg.latent_pages else decode_sharing_calls(
                         self._attn_impl, self.mesh, Bb,
-                        *cfg.paged_query_shape, cfg.global_window))
+                        *cfg.paged_query_shape, cfg.global_window, positions))
             pages, rows = shared_prefix_run(
                 batch["block_tables"][:n], batch["kv_lens"][:n],
-                self.cfg.block_size,
+                self.cfg.block_size, positions,
             ) if calls else (0, 0)
             slots.update(shared_kv_tokens=pages * self.cfg.block_size,
                          shared_rows=rows)

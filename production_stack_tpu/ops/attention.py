@@ -15,6 +15,8 @@ is TPU-native with two interchangeable implementations:
 
 Shapes:
   q            [B, T, H, hd]       T=1 for decode rows, T=chunk for prefill
+                                   (a verify step's few positions a row are
+                                   a chunk of consecutive positions too)
   kv_pages     [L, nb, 2, bs, KH*hd] combined pages: row 0 = K, row 1 = V;
                                    each token row spans all kv heads in the
                                    lane dim (one DMA per page in the kernel;
@@ -88,7 +90,8 @@ def _row_shards(mesh, rows: int) -> int:
 
 
 def decode_sharing_calls(
-    impl: str, mesh, rows: int, heads: int, head_dim: int, window: int = 0
+    impl: str, mesh, rows: int, heads: int, head_dim: int, window: int = 0,
+    positions: int = 1,
 ) -> int:
     """Into how many kernel calls that each read their rows' common
     leading pages once (``paged_attention_pallas.py``'s shared phase) a
@@ -98,15 +101,20 @@ def decode_sharing_calls(
     else a call a shard of rows. What the engine's count of spared reads
     asks (``engine/runner.py::_step_info``): it holds no rule of its own.
     ``window``: that of the layers over the pages in question with the
-    widest view (0: some layer reads a row's whole context)."""
+    widest view (0: some layer reads a row's whole context). ``positions``:
+    the query positions a row (a verify step's two or few); a run too long
+    for the decode stream (``rides_stream``) is the chunk kernel's, which
+    walks a row."""
     if resolve_attn_impl(impl) != "pallas" or decode_write_fused(impl):
         return 0
-    from .paged_attention_pallas import decode_shares
+    from .paged_attention_pallas import decode_shares, rides_stream
 
     shards = _row_shards(mesh, rows)
     tp = mesh.shape.get(AXIS_TENSOR, 1) if mesh is not None else 1
+    if not rides_stream(positions, heads // tp):
+        return 0
     return shards if decode_shares(
-        rows // shards, heads // tp, head_dim, window) else 0
+        rows // shards, positions * heads // tp, head_dim, window) else 0
 
 
 def paged_attention(
@@ -134,7 +142,8 @@ def paged_attention(
     the kernel then runs once per shard (see :func:`_pallas_per_shard`).
     ``key_floor`` (static): keys at positions below it are masked for every
     query (a layer whose entries are stored one slot ahead leaves slot 0
-    empty: ``models/exaone_moe.py``'s draft layer); one device only."""
+    empty: ``models/exaone_moe.py``'s draft layer): a column mask in the
+    reference and in every kernel; one device only."""
     impl = resolve_attn_impl(impl)
     if impl == "pallas":
         if key_floor:

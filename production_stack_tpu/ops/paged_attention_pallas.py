@@ -8,7 +8,11 @@ different things and each is organized around its own bound:
   cached tokens is HBM bandwidth and nothing else, so that kernel is
   organized around DMA efficiency, not grid geometry: it runs within 2 % of
   its bare page copies (PERF.md §6, PR 32), and its ``p @ V`` keeps fp8
-  pages as they are (``_pv_dot``: V is the large operand there).
+  pages as they are (``_pv_dot``: V is the large operand there). So is a
+  **short run** of query positions a row (a verify step's two, a prefill
+  bucket of 2 or 4: ``rides_stream``): up to 128 query lines fold under
+  the same copies, so it takes the same stream with a bound a line
+  (``paged_attn_short``; PERF.md §6, PR 54).
 - **Prefill is bound by its fold.** A chunk of 128-256 query positions x 4
   grouped heads is 512-1,024 rows a KV head: a 1 MiB chunk is copied in
   1.3 us and folded in tens, so what a call costs is what the fold does per
@@ -62,15 +66,19 @@ cache as a copy per layer per step, while the ANY-space operand costs
 nothing — the DMA engine reads only the pages the sequence actually needs.
 
 Shapes:
-  q           [B, T, H, hd]        T=1 decode, T=chunk prefill
+  q           [B, T, H, hd]        T=1 decode, T=chunk prefill; a short
+                                   chunk (``rides_stream``) takes decode's
+                                   stream
   kv_pages    [L, nb, 2, bs, KH*hd] combined K(row 0)/V(row 1) pages
   tables      [B, W] int32         page ids (W*bs >= kv_len)
   kv_lens     [B] int32            valid KV length per sequence (0 = padding)
-  q_positions [B, T] int32         absolute position per query token; the
-                                   prefill kernel uses row 0 (chunks are
-                                   consecutive positions, of which those at
-                                   and past kv_len are padding and return
-                                   zeros — runner contract)
+  q_positions [B, T] int32         absolute position per query token; a
+                                   chunk's kernel (prefill, a short run)
+                                   uses row 0 (chunks are consecutive
+                                   positions, of which those at and past
+                                   kv_len are padding and return zeros —
+                                   runner contract); the one position of
+                                   decode is kv_len - 1
   layer       int32 scalar         layer to read (scalar-prefetched)
 """
 
@@ -171,6 +179,17 @@ _PREFILL_KV_BYTES = 16 * 1024 * 1024
 # quarter of a 256-position bucket, so a bucket's padding is skipped in
 # quarters (128 rows: 511 us where 256 take 402; 512: 454; 1,024: 657).
 _PREFILL_SUB_ROWS = 256
+
+# A short run's shared phase folds a KV head's ``[rows x group, columns]``
+# scores (float32) in tiles of at most this much: 64 rows x 16 lines x 512
+# columns. Mosaic unrolls a body over its tile's registers, and a kernel's
+# short cells run slower the more code it holds, **executed or not**: in the
+# K-EXAONE step a window call, which never enters the phase, took 358 us with
+# the phase's tile at 4 MiB, 251 at 2 MiB and 229 with no phase traced
+# (PERF.md §6, PR 54; alone in a loop all three read 230). One position
+# keeps PR 50's whole-view fold: every cell's ``paged_attn_decode`` is the
+# program it was.
+_SHARED_TILE_BYTES = 2 * 1024 * 1024
 
 # Copies a chunk may hold: each is a descriptor, a semaphore and two
 # branches unrolled into the loop's body (pages far smaller than the 128
@@ -516,32 +535,41 @@ def _chunked_flash(
     )
 
 
-def _decode_range(lens_ref, win_ref, row, *, span: int, bs: int, skip=0):
+def _decode_range(lens_ref, win_ref, row, *, span: int, bs: int, skip=0,
+                  starts_ref=None, positions: int = 1):
     """The live range of decode row ``row``, its length and the first
     position its walk folds. The one query row sits at position kv_len-1
     and may see positions >= kv_len - window (0 = unlimited); whole chunks
     below that are never fetched, nor the pages below it in the chunk it
     starts in. ``skip``: leading pages the call's shared phase has folded
-    for this row already (``_find_shared_run``; 0 under a window)."""
-    kv_len = lens_ref[row]
-    lo = jnp.maximum(kv_len - window_eff(win_ref[0]), skip * bs)
+    for this row already (``_find_shared_run``; 0 under a window). A short
+    run of ``positions`` queries from ``starts_ref[row]`` on
+    (``rides_stream``) walks the union of their views: from the first
+    one's window start to the last one's horizon."""
+    kv_len = end = above = lens_ref[row]  # above: the first query's horizon
+    if starts_ref is not None:
+        above = starts_ref[row] + 1
+        end = jnp.minimum(starts_ref[row] + positions, kv_len)
+    lo = jnp.maximum(above - window_eff(win_ref[0]), skip * bs)
     return kv_len, lo, _LiveRange(
         row=row, table=row, first_page=lo // bs,
-        n_pages=(kv_len + bs - 1) // bs,
-        c_start=lo // span, n_chunks=(kv_len + span - 1) // span,
+        n_pages=(end + bs - 1) // bs,
+        c_start=lo // span, n_chunks=(end + span - 1) // span,
     )
 
 
-def _find_shared_run(tables_ref, lens_ref, rows: int, bs: int):
+def _find_shared_run(tables_ref, lens_ref, rows: int, bs: int,
+                     starts_ref=None):
     """(pages, first live row), traced scalars: the leading pages every live
     row of a decode call holds in common, and the row whose table names them.
 
     A prefix-cache hit hands rows the same physical pages, so the run is a
     comparison of page ids, a column of the table at a time until one
     differs: nothing is hashed. Rows with ``kv_len`` 0 (padding, a finished
-    member of a chain) are left out; every live row keeps at least its last
-    page, the one it writes, to itself; a call with one live row shares
-    nothing. Found by the call's first cell on the scalar core, from the
+    member of a chain) are left out; every live row keeps the pages from
+    its first query position on (``starts_ref``; None: the one query at
+    ``kv_len - 1``), those it writes, to itself; a call with one live row
+    shares nothing. Found by the call's first cell on the scalar core, from the
     tables and lengths it has in SMEM anyway (a few hundred scalar
     operations, under a microsecond): as XLA operations of the step program
     the search's tail was sunk into the layer scan and cost six small
@@ -553,9 +581,16 @@ def _find_shared_run(tables_ref, lens_ref, rows: int, bs: int):
         first, live, cap = found
         kv_len = lens_ref[i]
         here = kv_len > 0
+
+        def below():  # the last position under the row's first query
+            # (a closure: traced where the one query's ``kv_len - 1`` was)
+            if starts_ref is None:
+                return kv_len - 1
+            return jnp.minimum(starts_ref[i], kv_len - 1)
+
         return (jnp.where(here & (first < 0), i, first),
                 live + here.astype(i32),
-                jnp.where(here, jnp.minimum(cap, (kv_len - 1) // bs), cap))
+                jnp.where(here, jnp.minimum(cap, below() // bs), cap))
 
     first, live, cap = jax.lax.fori_loop(
         0, rows, look, (i32(-1), i32(0), i32(1 << 30)))
@@ -583,6 +618,7 @@ def decode_shares(rows: int, heads: int, head_dim: int, window=0):
     """Does a decode call of ``rows`` rows run the shared phase? THE rule:
     the call's trace, its first cell and the engine's count of what the
     phase spares (``ops/attention.py::decode_sharing_calls``) all ask here.
+    ``heads``: the query lines a row (a short run's ``positions x heads``).
     More than one row; on the chip rows and heads in whole sublane tiles
     of float32 and heads in whole lines of 128 lanes (the phase keeps its
     state head-major in slabs of ``rows`` rows and each row's cell takes
@@ -595,8 +631,25 @@ def decode_shares(rows: int, heads: int, head_dim: int, window=0):
     return window <= 0
 
 
+# Query lines one block-diagonal fold takes: the MXU's rows on the one chip
+# the program knows (``device.py::DEVICE_TABLE``).
+_STREAM_LINES = 128
+
+
+def rides_stream(positions: int, heads: int) -> bool:
+    """Does a call of ``positions`` query positions a row go through the
+    decode stream (``_decode_kernel``)? THE rule, by shape alone: one
+    position always (``paged_attn_decode``); a short run whose ``positions x
+    heads`` query lines one block-diagonal fold takes (``paged_attn_short``:
+    a verify step's two or few positions a row, a prefill bucket of 2 or 4)
+    too, which is bound by its copies as decode is; anything longer is bound
+    by its fold and is the chunk kernel's (``paged_attn_prefill``)."""
+    return positions == 1 or positions * heads <= _STREAM_LINES
+
+
 class _SharedPhase(NamedTuple):
-    """What ``_decode_call`` hands a kernel that traces the shared phase."""
+    """What ``_decode_call`` hands a kernel that traces the shared phase
+    (``H``: the query lines a row, ``positions x heads``)."""
 
     q_all: Any  # [B, H, hd] VMEM: every row's query whole
     run: Any  # SMEM (pages of the run, first live row)
@@ -624,6 +677,9 @@ def _decode_kernel(
     softcap: float = 0.0,
     prefetch_next_row: bool = True,
     phase: "_SharedPhase | None" = None,
+    starts_ref=None,  # [B] SMEM: a short run's first query position a row
+    positions: int = 1,
+    key_floor: int = 0,
 ):
     """Dense folded-q decode: per-head [G, hd] x [hd, S] mat-vecs waste the
     MXU (G of 128 rows live) and burn VPU on per-head slices, so instead q
@@ -656,7 +712,20 @@ def _decode_kernel(
     of the run (``_decode_range``'s ``skip``) and finishes as ever: the
     shared pages are folded first either way, with the same products and
     the same rounding of ``p`` (``_pv_dot``). With a run of 0, under a
-    window, the phase is one scalar comparison."""
+    window, the phase is one scalar comparison.
+
+    **A short run** (``positions`` > 1: ``rides_stream``). A row's few
+    consecutive query positions from ``starts_ref[b]`` on arrive as
+    ``positions x heads`` lines laid out ``[KH, positions, G]``, that is as a
+    row of that many heads in groups of ``positions x G`` (``group`` here),
+    and everything above holds for them as it stands: the ring, the
+    block-diagonal products, the hand-over, the shared phase (whose run ends
+    below every row's *first* position). What differs is the mask, a bound a
+    line where one ``kv_len`` served the row: a line of position ``t`` sees
+    the columns below ``min(start + t + 1, kv_len)`` and from its own window
+    bound on, and a position at or past ``kv_len`` is padding and returns
+    zeros: the chunk kernel's contract (``pallas_paged_attention``).
+    ``key_floor`` (static) masks the columns below it in both phases."""
     share = phase is not None
     if share:
         q_all_ref, run, q32, q_sh, m_sh, l_sh, acc_sh = phase
@@ -678,11 +747,12 @@ def _decode_kernel(
             @pl.when(decode_shares(n_b, H, hd, win_ref[0]))
             def _():
                 run[0], run[1] = _find_shared_run(
-                    tables_ref, lens_ref, n_b, block_size)
+                    tables_ref, lens_ref, n_b, block_size, starts_ref)
 
         skip = run[0]  # pages of the shared run, for every cell of the call
     rng = functools.partial(
-        _decode_range, lens_ref, win_ref, span=span, bs=block_size, skip=skip
+        _decode_range, lens_ref, win_ref, span=span, bs=block_size, skip=skip,
+        starts_ref=starts_ref, positions=positions,
     )
     kv_len, lo, live = rng(b)
 
@@ -747,9 +817,30 @@ def _decode_kernel(
             acc_sh[...] = jnp.zeros_like(acc_sh)
 
             def fold_shared(page, col0):
-                S = page.shape[0] * block_size
+                # A short run's ``[B*G, S]`` scores in tiles of at most
+                # ``_SHARED_TILE_BYTES``: whole pages, a divisor of the view.
+                n = page.shape[0]
+                sub = n if positions == 1 else max(
+                    d for d in range(1, n + 1) if n % d == 0 and (
+                        d == 1
+                        or d * block_size * GB * 4 <= _SHARED_TILE_BYTES))
+                S = sub * block_size
+                if sub == n:
+                    return fold_columns(page, col0, S)
+
+                def tile(i, _):
+                    at = i * sub
+                    fold_columns(page.at[pl.ds(at, sub)],
+                                 col0 + at * block_size, S)
+                    return 0
+
+                jax.lax.fori_loop(0, n // sub, tile, 0)
+
+            def fold_columns(page, col0, S):
                 col = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
                 seen = col < skip * block_size
+                if key_floor:
+                    seen &= col >= key_floor
 
                 def head(h, _):
                     lanes = pl.ds(pl.multiple_of(h * hd, hd), hd)
@@ -816,6 +907,20 @@ def _decode_kernel(
     else:
         fresh()
 
+    # The columns a line sees, ``[low, high)``: scalars for the one query of
+    # a row, ``[H, 1]`` for a short run, whose line ``r`` holds position
+    # ``start + (r // G') % positions`` (``G'``: the heads a KV head).
+    low, high, real = lo, kv_len, None
+    if starts_ref is not None:
+        line = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0)
+        q_pos = starts_ref[b] + jax.lax.rem(line // (G // positions), positions)
+        high = jnp.minimum(q_pos + 1, kv_len)
+        low = jnp.maximum(
+            q_pos + 1 - window_eff(win_ref[0]), skip * block_size)
+        real = q_pos < kv_len
+    if key_floor:
+        low = jnp.maximum(low, key_floor)
+
     def compute(page, col0):
         page = page[...]
         S = page.shape[0] * block_size
@@ -828,7 +933,7 @@ def _decode_kernel(
         ) * scale  # [H, S] fp32
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
-        s = jnp.where((col >= lo) & (col < kv_len), s, _NEG_INF)
+        s = jnp.where((col >= low) & (col < high), s, _NEG_INF)
         m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -841,6 +946,8 @@ def _decode_kernel(
 
     _page_dma_loop(live=live, compute_chunk=compute, **stream)
     out = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-20)  # [H, hd]
+    if real is not None:
+        out = jnp.where(real, out, 0.0)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -1118,11 +1225,20 @@ def _decode_geometry(q3, kv_pages, *, scale, softcap):
 
 
 def _decode_call(q3, kv_pages, block_tables, kv_lens, layer, window,
-                 *, scale, softcap):
+                 *, scale, softcap, key_floor=0, starts=None, positions=1,
+                 static_window=0):
+    """``q3``: a row's query lines, ``[B, positions x heads, hd]`` laid out
+    ``[KH, positions, G]`` (``_decode_kernel``: a short run), with ``starts``
+    the rows' first query positions; one position needs neither.
+    ``static_window``: the window where the caller knows it at trace time: a
+    call that cannot share traces no phase (``_SHARED_TILE_BYTES``: code a
+    call holds costs its cells whether it runs or not)."""
     B, H, hd, bs, lanes, C, kw, scratch, flash = _decode_geometry(
         q3, kv_pages, scale=scale, softcap=softcap
     )
-    share = decode_shares(B, H, hd)
+    kw.update(key_floor=key_floor, positions=positions)
+    short = [] if starts is None else [starts]
+    share = decode_shares(B, H, hd, static_window)
     q_all, q_all_spec, phase = [], [], []
     if share:
         # Every row's query whole beside the cell's own block (the same
@@ -1135,17 +1251,20 @@ def _decode_call(q3, kv_pages, block_tables, kv_lens, layer, window,
         narrow = pltpu.VMEM((B * H, 128), jnp.float32)
         phase = [pltpu.SMEM((2,), jnp.int32), wide, wide, narrow, narrow, wide]
 
-    def kernel(tables_ref, lens_ref, layer_ref, win_ref, q_ref, *refs):
-        given = None
+    def kernel(tables_ref, lens_ref, layer_ref, win_ref, *refs):
+        given, how = None, {}
+        if short:
+            how["starts_ref"], *refs = refs
+        q_ref, *refs = refs
         if share:  # inputs, the output, scratch: in the order given below
             q_all_ref, *refs = refs
             given = _SharedPhase(q_all_ref, *refs[-len(phase):])
             refs = refs[:-len(phase)]
         _decode_kernel(tables_ref, lens_ref, layer_ref, win_ref, q_ref, *refs,
-                       phase=given, **kw)
+                       phase=given, **how, **kw)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=4 + len(short),
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, H, hd), lambda b, *_: (b, 0, 0)),
@@ -1169,8 +1288,10 @@ def _decode_call(q3, kv_pages, block_tables, kv_lens, layer, window,
             vmem_limit_bytes=64 * 1024 * 1024,
         ),
         interpret=pallas_interpret(),
-        name="paged_attn_decode",
-    )(block_tables, kv_lens, layer, window, q3, *q_all, kv_pages)
+        # A name of its own for the short run: a trace and the benchmark's
+        # ``kernel.paged_attn_decode_*`` metrics tell the two apart.
+        name="paged_attn_short" if short else "paged_attn_decode",
+    )(block_tables, kv_lens, layer, window, *short, q3, *q_all, kv_pages)
 
 
 def _prefill_call(q, kv_pages, block_tables, kv_lens, starts, layer, window,
@@ -1255,20 +1376,27 @@ def pallas_paged_attention(
     lens = kv_lens.astype(jnp.int32)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape(1)
     win_arr = jnp.asarray(window, jnp.int32).reshape(1)
-    if key_floor and T == 1:
-        # the floor is the prefill kernel's: one query row rides it as a
-        # chunk of two whose second row is padding (at or past ``kv_len``)
-        return pallas_paged_attention(
-            jnp.pad(q, ((0, 0), (0, 1), (0, 0), (0, 0))), kv_pages, tables,
-            lens, jnp.concatenate([q_positions, q_positions + 1], axis=1),
-            layer, scale=scale, window=window, softcap=softcap,
-            key_floor=key_floor)[:, :1]
     if T == 1:
         out = _decode_call(
             q[:, 0], kv_pages, tables, lens, layer_arr, win_arr,
-            scale=scale, softcap=softcap,
+            scale=scale, softcap=softcap, key_floor=key_floor,
         )
         return out[:, None]
+    starts = q_positions[:, 0].astype(jnp.int32)
+    if rides_stream(T, H):
+        # A short run rides the decode stream as ``T x H`` query lines
+        # ``[KH, T, G]``: the turn there and back is XLA's, a few MiB a step
+        # beside the pages the call reads.
+        KH = kv_pages.shape[-1] // hd
+        lines = q.reshape(B, T, KH, H // KH, hd).transpose(0, 2, 1, 3, 4)
+        out = _decode_call(
+            lines.reshape(B, T * H, hd), kv_pages, tables, lens, layer_arr,
+            win_arr, scale=scale, softcap=softcap, key_floor=key_floor,
+            starts=starts, positions=T,
+            static_window=window if isinstance(window, int) else 0,
+        )
+        return out.reshape(B, KH, T, H // KH, hd).transpose(
+            0, 2, 1, 3, 4).reshape(B, T, H, hd)
 
     # Chunk positions are consecutive from row 0's position (the runner
     # builds prefill batches that way), so the kernel derives causality from
@@ -1284,7 +1412,6 @@ def pallas_paged_attention(
             f"({q_tile}), got T={T}; the runner only emits power-of-two "
             "chunk buckets"
         )
-    starts = q_positions[:, 0].astype(jnp.int32)
     return _prefill_call(
         q, kv_pages, tables, lens, starts, layer_arr, win_arr, scale=scale,
         q_tile=q_tile, softcap=softcap, key_floor=key_floor,

@@ -14,11 +14,19 @@ splits a call's time four ways (PR 28's method for the int4 kernel):
 as ``perf/traffic/sessions-closed.json`` draws them (1,024 shared + a
 log-uniform 2,048-8,192 history + up to 2,000 of turns: 3-11k).
 ``hybrid``: B = 32, 32 Q / 2 KV heads x 128, bf16 pages, 1-2.5k.
+``verify_global`` / ``verify_window`` (PR 54): the verify-and-draft step of
+``k-exaone-ep8-cut.thinking-closed``, B = 64 rows x 2 query positions, 64 Q
+/ 8 KV heads x 128, bf16 pages, 2.3-7.2k of context behind 1,024 shared
+tokens (the rows' tables agree on those eight pages); the window call reads
+129 tokens a row. Since PR 54 such a call is ``%paged_attn_short`` (the
+decode stream); on a tree before it, copied into its ``scripts/``, the same
+lines time ``%paged_attn_prefill`` at a tile of two.
 
 Each line gives the time of one call, of one grid cell, and the share of
 the HBM roofline (``perf/cost/paged_attn.py``'s bytes: every row's live
 keys and values once, the queries in and the result out, at the peak of
-``perf/peaks.json``). ``--chunk-tokens``, ``--fold-tokens`` and ``--slots``
+``perf/peaks.json``; ``distinct_pct``: the same with the shared tokens
+counted once a call, what the shared phase reads). ``--chunk-tokens``, ``--fold-tokens`` and ``--slots``
 time other geometries (module constants the script overrides; the program
 has no such option). Writes ``chiprun_out/decode_attn_attrib[_<tag>].json``.
 
@@ -28,6 +36,7 @@ has no such option). Writes ``chiprun_out/decode_attn_attrib[_<tag>].json``.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -49,6 +58,11 @@ SHAPES = {
     "dense": dict(B=16, KH=8, dtype="float8_e4m3fn", widths=(128, 64),
                   L=8, nb=1340),
     "hybrid": dict(B=32, KH=2, dtype="bfloat16", widths=(32,), L=1, nb=2048),
+    # (H: query heads, T: query positions a row, shared: tokens of one prompt)
+    "verify_global": dict(B=64, KH=8, dtype="bfloat16", widths=(64,), L=2,
+                          nb=1800, H=64, T=2, shared=1024),
+    "verify_window": dict(B=64, KH=8, dtype="bfloat16", widths=(64,), L=2,
+                          nb=1800, H=64, T=2, shared=1024, window=128),
 }
 
 
@@ -57,16 +71,21 @@ def draw_lengths(shape: str, B: int, W: int, seed: int) -> np.ndarray:
     if shape == "dense":
         hist = np.exp(rng.uniform(np.log(2048), np.log(8192), B))
         lens = 1024 + hist + rng.uniform(0, 2000, B)
+    elif shape.startswith("verify"):
+        # perf/traffic/thinking-closed.json, a row caught mid-answer
+        prompt = np.exp(rng.uniform(np.log(256), np.log(3072), B))
+        lens = 1024 + prompt + rng.uniform(0, 1, B) * rng.uniform(1024, 3072, B)
     else:
         lens = np.exp(rng.uniform(np.log(128), np.log(1024), B)) + rng.uniform(
             512, 1536, B)
     return np.minimum(lens.astype(np.int64), W * BS - 1).astype(np.int32)
 
 
-def roofline_s(lens: np.ndarray, KH: int, kv_bytes: int, peak: float) -> float:
-    """Least time of one layer's call (``perf/cost/paged_attn.py``)."""
-    rows = len(lens)
-    nbytes = int(lens.sum()) * 2 * KH * HD * kv_bytes + rows * H * HD * 2 * 2
+def roofline_s(tokens: int, rows: int, KH: int, kv_bytes: int, peak: float,
+               lines: int = H) -> float:
+    """Least time of one layer's call that reads ``tokens`` keys and values
+    (``perf/cost/paged_attn.py``)."""
+    nbytes = tokens * 2 * KH * HD * kv_bytes + rows * lines * HD * 2 * 2
     return nbytes / peak
 
 
@@ -89,8 +108,8 @@ def _loop_without(what: str, orig):
     return loop
 
 
-def time_variant(variant, q, kv, tables, lens, calls, iters):
-    L = kv.shape[0]
+def time_variant(variant, q, kv, tables, lens, calls, iters, window=0):
+    L, T = kv.shape[0], q.shape[1]
     scale = 1.0 / np.sqrt(HD)
     orig = pap._page_dma_loop
     if variant in ("copies", "fold"):
@@ -99,8 +118,9 @@ def time_variant(variant, q, kv, tables, lens, calls, iters):
         def run(q, kv, tables, lens):
             def body(i, q):
                 out = pap.pallas_paged_attention(
-                    q, kv, tables, lens, (lens - 1)[:, None],
-                    jax.lax.rem(i, L), scale=scale)
+                    q, kv, tables, lens,
+                    (lens - T)[:, None] + jnp.arange(T, dtype=jnp.int32),
+                    jax.lax.rem(i, L), scale=scale, window=window)
                 # Chain the calls; ``fold`` reads a buffer nothing wrote,
                 # so keep its (possibly non-finite) result out of q.
                 return q + jnp.where(jnp.isfinite(out), out, 0) * 1e-3
@@ -159,10 +179,14 @@ def main() -> int:
             for seed in args.seeds:
                 rng = np.random.default_rng(seed)
                 lens0 = draw_lengths(shape, s["B"], W, seed)
-                tables = jnp.asarray(rng.integers(
-                    0, s["nb"], (s["B"], W)).astype(np.int32))
+                tables = rng.integers(0, s["nb"], (s["B"], W)).astype(np.int32)
+                shared = s.get("shared", 0)
+                tables[:, : shared // BS] = tables[0, : shared // BS]
+                tables = jnp.asarray(tables)
+                heads, T = s.get("H", H), s.get("T", 1)
+                window = s.get("window", 0)
                 q = jnp.asarray(rng.standard_normal(
-                    (s["B"], 1, H, HD)), jnp.bfloat16)
+                    (s["B"], T, heads, HD)), jnp.bfloat16)
                 for ct, ft, ns in [(c, f, n) for c in args.chunk_tokens
                                    for f in args.fold_tokens
                                    for n in args.slots]:
@@ -182,8 +206,16 @@ def main() -> int:
                             lens = np.minimum(lens, W * BS // span * span)
                         t, first = time_variant(
                             variant, q, kv, tables, jnp.asarray(lens),
-                            args.calls, args.iters)
-                        least = roofline_s(lens, s["KH"], dtype.itemsize, peak)
+                            args.calls, args.iters, window)
+                        read = (np.minimum(lens, window + T - 1) if window
+                                else lens)
+                        cost = functools.partial(
+                            roofline_s, rows=s["B"], KH=s["KH"],
+                            kv_bytes=dtype.itemsize, peak=peak,
+                            lines=T * heads)
+                        least = cost(int(read.sum()))
+                        distinct = int(read.sum()) - (
+                            0 if window else (s["B"] - 1) * shared)
                         line = {
                             "shape": shape, "W": W, "seed": seed,
                             "chunk_tokens": pap._DECODE_CHUNK_TOKENS,
@@ -191,6 +223,8 @@ def main() -> int:
                             "slots": pap._DECODE_SLOTS,
                             "variant": variant,
                             "kv_tokens": int(lens.sum()),
+                            "distinct_pct": round(
+                                100 * cost(distinct) / t, 2),
                             "call_us": round(t * 1e6, 2),
                             "cell_us": round(t * 1e6 / s["B"], 3),
                             "least_us": round(least * 1e6, 2),

@@ -221,7 +221,7 @@ def cell_shape_case(name, *, B, kv_heads, kv_dtype, lo, hi, empty=(),
 
 
 def shared_run_case(name, *, B, kv_heads, heads, kv_dtype, bs, shared, lo, hi,
-                    empty=(), calls=64):
+                    empty=(), calls=64, T=1, window=0, key_floor=0, layers=3):
     """Decode behind one shared prompt, as the prefix cache leaves it:
     every live row's first ``shared`` tokens are the same physical pages,
     then ``lo`` to ``hi`` tokens of its own (``ops/paged_attention_pallas.py
@@ -231,9 +231,13 @@ def shared_run_case(name, *, B, kv_heads, heads, kv_dtype, bs, shared, lo, hi,
     stacked cache, against the least time the chip's memory allows for the
     rows' contexts (what a walk a row has to read) and for the distinct
     tokens among them (what this call has to). It runs on a tree without the
-    phase too: copy it into that tree's ``scripts/``."""
+    phase too: copy it into that tree's ``scripts/``. ``T`` > 1 (PR 54): a
+    short run of ``T`` query positions a row, the last ``T`` of its tokens
+    (a verify-and-draft step; ``window`` / ``key_floor``: through a window
+    layer, where every page below the window is NaN too, and through the
+    draft layer), one row's last position padding."""
     rng = np.random.default_rng(zlib.crc32(name.encode()))
-    lanes, layers = kv_heads * HD, 3
+    lanes = kv_heads * HD
     own = rng.integers(lo, hi, B).astype(np.int32)
     lens = shared + own
     lens[list(empty)] = 0
@@ -246,19 +250,27 @@ def shared_run_case(name, *, B, kv_heads, heads, kv_dtype, bs, shared, lo, hi,
     tables = (rng.permutation(B * width) + n_shared + 2).reshape(B, width)
     tables[:, :n_shared] = 2 + np.arange(n_shared)
     dead = np.arange(width)[None] >= -(-lens // bs)[:, None]
-    q = jnp.asarray(rng.standard_normal((B, 1, heads, HD)), jnp.bfloat16)
-    q_pos = jnp.asarray(np.maximum(lens - 1, 0))[:, None]
+    q = jnp.asarray(rng.standard_normal((B, T, heads, HD)), jnp.bfloat16)
+    first = np.maximum(lens - T, 0)
+    if T > 1:
+        first[0] += 1  # row 0's last position lies at ``kv_len``: padding
+    q_pos = jnp.asarray(first[:, None] + np.arange(T)[None]).astype(jnp.int32)
+    if window:
+        dead |= np.arange(width)[None] < (
+            np.maximum(first + 1 - window, 0) // bs)[:, None]
     t_kern = jnp.asarray(np.where(dead, 1, tables).astype(np.int32))
     lens_j = jnp.asarray(lens)
+    how = dict(scale=SCALE, window=window, key_floor=key_floor)
     kern = jax.jit(
         lambda q, kv, t, l, p, ly: pallas_paged_attention(
-            q, kv, t, l, p, ly, scale=SCALE))
+            q, kv, t, l, p, ly, **how))
     t0 = time.perf_counter()
-    got = np.asarray(kern(q, kv, t_kern, lens_j, q_pos, jnp.int32(2)), np.float32)
+    got = np.asarray(kern(
+        q, kv, t_kern, lens_j, q_pos, jnp.int32(layers - 1)), np.float32)
     compile_s = time.perf_counter() - t0
     ref = jax.jit(
         lambda q, kv, t, l, p: gather_paged_attention(
-            q, kv, t, l, p, 2, scale=SCALE))
+            q, kv, t, l, p, layers - 1, **how))
     want = np.asarray(ref(
         q, kv, jnp.asarray(np.where(dead, 0, tables).astype(np.int32)),
         lens_j, q_pos), np.float32)
@@ -266,7 +278,7 @@ def shared_run_case(name, *, B, kv_heads, heads, kv_dtype, bs, shared, lo, hi,
     def chain(q, kv, t, l, p):
         def body(i, q):
             out = pallas_paged_attention(
-                q, kv, t, l, p, jax.lax.rem(i, layers), scale=SCALE)
+                q, kv, t, l, p, jax.lax.rem(i, layers), **how)
             return q + out * 1e-3
         return jax.lax.fori_loop(0, calls, body, q)
 
@@ -277,17 +289,20 @@ def shared_run_case(name, *, B, kv_heads, heads, kv_dtype, bs, shared, lo, hi,
         t0 = time.perf_counter()
         jax.block_until_ready(timed(q, kv, t_kern, lens_j, q_pos))
         best = min(best, (time.perf_counter() - t0) / calls)
-    live = lens > 0
+    rows_live = lens > 0
+    live = (np.asarray(q_pos) < lens[:, None]) & rows_live[:, None]
     token_bytes = 2 * lanes * jnp.dtype(kv_dtype).itemsize
-    distinct = int(lens.sum()) - (int(live.sum()) - 1) * shared
+    read = np.minimum(lens, window + T - 1) if window else lens
+    distinct = int(read.sum()) - (
+        0 if window else (int(rows_live.sum()) - 1) * shared)
     return {
         "max_abs_diff": float(np.abs(got[live] - want[live]).max()),
         "empty_rows_max_abs_diff": float(np.abs(got[~live]).max(initial=0.0)),
         "ref_abs_max": float(np.abs(want[live]).max()),
-        "kv_tokens": int(lens.sum()),
+        "kv_tokens": int(read.sum()),
         "distinct_tokens": distinct,
         "us_a_call": round(best * 1e6, 2),
-        "roofline_us_rows": round(int(lens.sum()) * token_bytes / HBM_BYTES_S * 1e6, 2),
+        "roofline_us_rows": round(int(read.sum()) * token_bytes / HBM_BYTES_S * 1e6, 2),
         "roofline_us_distinct": round(distinct * token_bytes / HBM_BYTES_S * 1e6, 2),
         "bound": ATTN_BOUND,
         "first_call_s": round(compile_s, 2),
@@ -753,6 +768,21 @@ def cases():
     yield "attn_decode_shared_dense_b16_kh8_fp8", shared_run_case, dict(
         B=16, kv_heads=8, heads=32, kv_dtype=fp8, bs=128, shared=1024,
         lo=2000, hi=8000, empty=(11,))
+    # A verify-and-draft step (PR 54): 64 thinkers behind 1,024 shared tokens
+    # on the draft cell's bf16 pages of 8 KV heads, two query positions a row
+    # through the decode stream (``paged_attn_short``): the full layer, a
+    # window layer, the draft layer under its key floor; and a dense prefill
+    # bucket of four positions on fp8 pages.
+    short = dict(B=64, kv_heads=8, heads=64, kv_dtype=jnp.bfloat16, bs=128,
+                 shared=1024, lo=1300, hi=4000, empty=(11,), T=2, layers=1)
+    yield "attn_short_exaone_b64_t2_kh8_bf16", shared_run_case, short
+    yield "attn_short_exaone_b64_t2_window128", shared_run_case, dict(
+        short, window=128)
+    yield "attn_short_exaone_b64_t2_key_floor", shared_run_case, dict(
+        short, key_floor=1)
+    yield "attn_short_dense_b8_t4_kh8_fp8", shared_run_case, dict(
+        B=8, kv_heads=8, heads=32, kv_dtype=fp8, bs=128, shared=1024,
+        lo=2000, hi=8000, T=4, layers=1)
     yield "attn_prefill_cell_dense_t256_real150_fp8", prefill_cell_case, dict(
         T=256, real=150, start=8192 - 37, kv_dtype=fp8)
     yield "attn_prefill_t1024_real600_fp8_window_softcap", prefill_cell_case, dict(

@@ -270,3 +270,65 @@ def test_the_server_takes_the_flag_and_exports_the_counters(drafted):
         line = next(ln for ln in text.splitlines()
                     if ln.startswith(f'{name}{{model_name="m"}}'))
         assert float(line.split()[-1]) == stats[key] > 0
+
+
+@pytest.mark.parametrize("overlap", [False, True],
+                         ids=["synchronous", "chained"])
+def test_a_verify_step_reads_the_pages_its_rows_share_once(overlap, monkeypatch):
+    """(PR 54) A verify step's two positions a row ride the decode stream
+    (``paged_attn_short``), whose shared phase reads the rows' common
+    leading pages once a call: the step's ``pst.step_info`` carries
+    ``shared_kv_tokens`` / ``shared_rows`` and the spared-reads counter
+    moves by what `shared_prefix_run` counts below each row's *first* query
+    position, which is the run the kernel's first cell finds; and the
+    tokens are the gather reference's."""
+    from production_stack_tpu.engine.runner import shared_prefix_run
+    from production_stack_tpu.obs.engine_telemetry import ENGINE_TELEMETRY
+
+    from .test_paged_attention import program_run
+
+    eng = make(speculative_mtp=1, attn_impl="pallas", overlap_decode=overlap)
+    bs = eng.cfg.block_size
+    prefix = prompts(1, lo=3 * bs, seed=21)[0]  # three whole pages
+    tails = [[1, 2, 3], [4, 5, 6, 7, 8], [9], [10, 11, 3, 4, 5, 6, 7]]
+    ps = [prefix + t for t in tails]
+    run(eng, [prefix + [11]], 1)  # leaves the prefix's pages in the cache
+    seen, batches = [], []
+    monkeypatch.setattr(
+        ENGINE_TELEMETRY, "step_info",
+        lambda kind, **meta: seen.append((kind, meta)))
+    build = eng.runner._mtp_batch
+    monkeypatch.setattr(
+        eng.runner, "_mtp_batch",
+        lambda seqs: batches.append(build(seqs)) or batches[-1])
+    before = eng.stats()
+    got = run(eng, ps, 6)
+    after = eng.stats()
+    steps = [m for k, m in seen if k == "decode"]
+    assert steps and all(m["step"] == "mtp_verify" for m in steps)
+    full = [m for m in steps if m["rows"] == 4 and m["shared_rows"] == 4]
+    assert full, "four rows behind the prefix were verified together"
+    assert all(m["shared_kv_tokens"] >= 2 * bs for m in full)
+    spared = (after["decode_shared_tokens_spared_total"]
+              - before["decode_shared_tokens_spared_total"])
+    assert spared == sum(
+        max(m["shared_rows"] - 1, 0) * m["shared_kv_tokens"] for m in steps)
+    context = (after["decode_context_tokens_total"]
+               - before["decode_context_tokens_total"])
+    assert 0.2 < spared / context < 0.75
+    if not overlap:
+        # each synchronous step's record is its batch's run, and the host's
+        # twin agrees with the kernel's first cell on where the run ends
+        assert len(batches) == len(steps)
+        for b, m in zip(batches, steps):
+            n = m["rows"]
+            tables, lens = b["block_tables"][:n], b["kv_lens"][:n]
+            pages, rows = shared_prefix_run(tables, lens, bs, 2)
+            assert (pages * bs, rows) == (
+                m["shared_kv_tokens"], m["shared_rows"])
+            found = program_run(tables, lens, bs, b["positions"][:n, 0])
+            assert found[0] == pages
+            assert pages <= int(b["positions"][:n, 0].min()) // bs
+    want = run(make(speculative_mtp=1, overlap_decode=overlap), ps, 6)
+    for a, b in zip(got, want):
+        assert_same(a, b, tol=2e-4)

@@ -131,24 +131,42 @@ _DECODE_STREAMS = {
     "soft_cap": dict(lens=[1100, 40, 2100], softcap=30.0),
     "layer_of_a_stack": dict(lens=[1300, 200], layers=3, layer=2),
     "single_row": dict(lens=[2500]),
+    # A short run (PR 54): two query positions a row ride the same stream,
+    # a bound a line. Row 2's pair straddles a chunk's (and a page's) edge.
+    "short_run_of_two": dict(lens=[1100, 0, 2049, 3000, 17], T=2),
+    # row 0's second position is padding (at ``kv_len``): zeros, its page dead
+    "short_run_with_a_padding_position": dict(
+        lens=[1025, 700, 2], T=2, real=[1, 2, 2]),
+    # c_start 2, 0, 1 again; position 0's window starts a column lower
+    "short_run_under_a_window": dict(lens=[3000, 1300, 2500], T=2, window=600),
+    "short_run_of_four_fp8_pages": dict(
+        lens=[1100, 2100, 40], T=4, dtype=jnp.float8_e4m3fn),
 }
+
+
+def _real_positions(kw, lens):
+    """[B, T] bool: the query positions of a stream case that hold a token."""
+    T = kw.get("T", 1)
+    real = np.asarray(kw.get("real", [T] * len(lens)))
+    return (np.arange(T)[None] < real[:, None]) & (lens > 0)[:, None]
 
 
 @pytest.mark.parametrize("case", list(_DECODE_STREAMS))
 def test_pallas_decode_streams_live_pages_only(case, decode_trace):
     kw = _DECODE_STREAMS[case]
     got, ref, lens = _stream_case(**kw)
+    real = _real_positions(kw, lens)
     assert np.all(np.isfinite(got)), "a dead or foreign page reached the fold"
-    assert np.all(got[lens == 0] == 0.0)  # the drop-slot contract
+    assert np.all(got[~real] == 0.0)  # the drop-slot contract
     # fp8 pages: the kernel's probabilities carry ~2^-8 (the split dot)
     tol = 2e-2 if "dtype" in kw else 2e-5
-    np.testing.assert_allclose(
-        got[lens > 0], ref[lens > 0], rtol=tol, atol=tol)
+    np.testing.assert_allclose(got[real], ref[real], rtol=tol, atol=tol)
 
 
 @pytest.mark.parametrize("case", [
     "odd_and_even_chunk_counts", "empty_row_between",
-    "window_start_differs_by_row",
+    "window_start_differs_by_row", "short_run_of_two",
+    "short_run_with_a_padding_position",
 ])
 def test_pallas_decode_every_wait_meets_its_copy(
         case, decode_trace, monkeypatch):
@@ -166,9 +184,9 @@ def test_pallas_decode_every_wait_meets_its_copy(
         pap, "pallas_interpret",
         lambda: pltpu.InterpretParams(dma_execution_mode="on_wait"))
     got, ref, lens = _stream_case(**_DECODE_STREAMS[case])
+    real = _real_positions(_DECODE_STREAMS[case], lens)
     assert np.all(np.isfinite(got))
-    np.testing.assert_allclose(
-        got[lens > 0], ref[lens > 0], rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got[real], ref[real], rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("case, kw", [
@@ -436,16 +454,26 @@ _SHARED_RUNS = {
     "padding_rows_inside_the_batch": dict(
         common=3, own=[40, None, 70, None, 5, 33, None, 9]),
     "run_longer_than_a_chunk": dict(common=70, own=[40, 70, 5, 33]),
+    # row 0's last two tokens are the fourth page's last and the fifth's
+    # first: as a short run of two its positions straddle the boundary and
+    # the run ends a page lower than for its one query
+    "last_two_tokens_straddle_a_page_boundary": dict(
+        common=4, own=[1, 40, 70, 33]),
+    # row 0's second-last token opens its fifth page: the run ends exactly
+    # where a short run of two begins
+    "run_ends_at_the_first_of_two_positions": dict(
+        common=4, own=[2, 40, 70, 33]),
 }
 
 
 def _shared_case(*, H, KH, hd, bs, dtype, common, own, softcap=0.0, seed=0,
-                 tol=None, poison_other_rows=False, ahead=0):
+                 tol=None, poison_other_rows=False, ahead=0, T=1):
     """Rows whose tables agree on ``common`` pages, then ``own[i]`` tokens
     each (negative: the row ends that many tokens before the common pages
     do; None: a padding row, ``kv_len`` 0, its table zeros; ``ahead``: tokens
-    a burst will add, whose pages are live too). -> (inputs of
-    ``pallas_paged_attention``, tables for the oracle, lens, pages shared)."""
+    a burst will add, whose pages are live too; ``T``: query positions a row,
+    the last ``T`` of its tokens). -> (inputs of ``pallas_paged_attention``,
+    tables for the oracle, lens, pages shared)."""
     rng = np.random.default_rng(seed)
     B = len(own)
     lens = np.array(
@@ -460,8 +488,8 @@ def _shared_case(*, H, KH, hd, bs, dtype, common, own, softcap=0.0, seed=0,
     tables[lens == 0] = 0
     live = lens > 0
     dead = np.arange(W)[None] >= -(-(lens + ahead * live) // bs)[:, None]
-    pages = min(common, int((lens[live] - 1).min()) // bs) if live.sum() > 1 else 0
-    q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
+    pages = min(common, int((lens[live] - T).min()) // bs) if live.sum() > 1 else 0
+    q = jnp.asarray(rng.standard_normal((B, T, H, hd)), jnp.float32)
     if dtype != jnp.float32:
         q = q.astype(jnp.bfloat16)
     seen = np.where(dead, 1, tables)
@@ -469,32 +497,36 @@ def _shared_case(*, H, KH, hd, bs, dtype, common, own, softcap=0.0, seed=0,
         first = int(np.argmax(live))
         others = np.arange(B) != first
         seen[np.ix_(others, np.arange(pages))] = 1
+    q_pos = np.maximum(lens - T, 0)[:, None] + np.arange(T, dtype=np.int32)
     args = (q, jnp.asarray(kv).astype(dtype), jnp.asarray(seen),
-            jnp.asarray(lens), jnp.asarray(np.maximum(lens - 1, 0))[:, None], 1)
+            jnp.asarray(lens), jnp.asarray(q_pos), 1)
     kw = dict(scale=1.0 / np.sqrt(hd), softcap=softcap)
     return args, jnp.asarray(np.where(dead, 0, tables)), lens, pages, kw
 
 
-def program_run(tables, lens, bs):
+def program_run(tables, lens, bs, starts=None):
     """(pages, first live row) as the decode kernel's first cell finds them:
-    ``_find_shared_run`` on the tables and lengths in SMEM, in a kernel of
-    its own."""
+    ``_find_shared_run`` on the tables and lengths in SMEM (and a short
+    run's first query positions, ``starts``), in a kernel of its own."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from production_stack_tpu.ops import paged_attention_pallas as pap
 
-    def kernel(tables_ref, lens_ref, out_ref):
+    scalars = [tables, lens] + ([] if starts is None else [starts])
+
+    def kernel(tables_ref, lens_ref, *refs):
+        *starts_ref, out_ref = refs
         out_ref[0], out_ref[1] = pap._find_shared_run(
-            tables_ref, lens_ref, tables.shape[0], bs)
+            tables_ref, lens_ref, tables.shape[0], bs, *starts_ref)
 
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(1,), in_specs=[],
+            num_scalar_prefetch=len(scalars), grid=(1,), in_specs=[],
             out_specs=pl.BlockSpec(memory_space=pltpu.SMEM)),
         out_shape=jax.ShapeDtypeStruct((2,), jnp.int32),
         interpret=True,
-    )(jnp.asarray(tables, jnp.int32), jnp.asarray(lens, jnp.int32))
+    )(*(jnp.asarray(x, jnp.int32) for x in scalars))
     return tuple(int(x) for x in np.asarray(out))
 
 
@@ -519,15 +551,17 @@ def _three_ways(args, oracle_tables, kw, monkeypatch):
 
 @pytest.mark.parametrize("run", list(_SHARED_RUNS))
 @pytest.mark.parametrize("geometry", list(_SHARED_GEOMETRIES))
+@pytest.mark.parametrize("T", [1, 2], ids=["one_position", "short_run_of_two"])
 def test_pallas_decode_shared_phase_equals_the_per_row_walk(
-        geometry, run, monkeypatch):
-    from production_stack_tpu.ops import paged_attention_pallas as pap
-
+        T, geometry, run, monkeypatch):
+    """``T`` 2 (PR 54): the phase folds every row's two positions' lines a
+    KV head, and the run ends below each row's first position."""
     geo = dict(_SHARED_GEOMETRIES[geometry])
     tol = geo.pop("tol")
     args, oracle_tables, lens, pages, kw = _shared_case(
-        **geo, **_SHARED_RUNS[run])
-    found = program_run(args[2], args[3], geo["bs"])
+        **geo, **_SHARED_RUNS[run], T=T)
+    found = program_run(args[2], args[3], geo["bs"],
+                        None if T == 1 else args[4][:, 0])
     assert found[0] == pages and (pages == 0 or lens[found[1]] > 0)
     got, ref, walk = _three_ways(args, oracle_tables, kw, monkeypatch)
     live = lens > 0
@@ -631,8 +665,11 @@ def test_pallas_decode_shared_phase_through_a_burst(monkeypatch):
     (2, 0, 0),  # a verify step through a full-attention layer
     (2, 16, 0),  # through a window layer
     (2, 0, 1),  # through the draft layer: slot 0 masked
-    (1, 0, 1),  # one position under the floor: rides the chunk kernel
+    (1, 0, 1),  # one position under the floor: the decode stream's mask
     (16, 0, 1),  # a prefill chunk under the floor
+    # (PR 54) 4 x 8 heads: still a short run of the decode stream
+    (4, 0, 0), (4, 16, 0), (4, 0, 1), (4, 5, 1),
+    (32, 0, 1),  # 256 lines: the chunk kernel
 ])
 def test_pallas_verify_shapes_and_the_key_floor_match_gather(T, window, key_floor):
     """Rows whose chunk starts at a page boundary, inside a page, at the
@@ -640,7 +677,7 @@ def test_pallas_verify_shapes_and_the_key_floor_match_gather(T, window, key_floo
     deep into it; the kernel equals the gather reference, and under the
     floor slot 0 is not read as a key whatever it holds."""
     # (eight pages of 8 a row: a chunk ends by token 64)
-    starts = [1 if key_floor else 0, 13, 40, 48]
+    starts = [1 if key_floor else 0, 13, 40, 48] if T <= 16 else [1, 5, 20, 32]
     q, kv, tables, kv_lens, q_pos = _prefill_setup(
         B=4, T=T, start_offsets=starts)
     if key_floor:  # a wild slot 0: masked, so it moves nothing
@@ -656,3 +693,70 @@ def test_pallas_verify_shapes_and_the_key_floor_match_gather(T, window, key_floo
     if key_floor:  # and without the floor the wild slot is read
         wild = gather_paged_attention(q, kv, tables, kv_lens, q_pos, **kw)
         assert np.abs(np.asarray(wild) - np.asarray(ref)).max() > 1
+
+
+def _traced_calls(monkeypatch):
+    """Every ``pallas_call`` the kernels' module makes from here on: (name,
+    scratch shapes)."""
+    from production_stack_tpu.ops import paged_attention_pallas as pap
+
+    calls, real = [], pap.pl.pallas_call
+
+    def spy(kernel, *, name, grid_spec, **kw):
+        calls.append((name, [
+            getattr(x, "shape", None) for x in grid_spec.scratch_shapes]))
+        return real(kernel, name=name, grid_spec=grid_spec, **kw)
+
+    monkeypatch.setattr(pap.pl, "pallas_call", spy)
+    return calls
+
+
+def test_the_kernel_a_call_traces_is_chosen_by_its_shape_alone(monkeypatch):
+    """``rides_stream``: one position is ``paged_attn_decode``; a short run
+    whose ``T x H`` lines one block-diagonal fold takes is the same stream
+    under a name of its own; past that the chunk kernel. And one position
+    traces what it traced before the short run came: the ring, the flash
+    state and the phase's six buffers, sized by ``H`` lines."""
+    from production_stack_tpu.ops import paged_attention_pallas as pap
+
+    assert pap.rides_stream(1, 512) and pap.rides_stream(2, 64)
+    assert pap.rides_stream(4, 32) and not pap.rides_stream(4, 64)
+    assert not pap.rides_stream(256, 4)
+    calls = _traced_calls(monkeypatch)
+    for T, want in ((1, "paged_attn_decode"), (2, "paged_attn_short"),
+                    (16, "paged_attn_short"), (32, "paged_attn_prefill")):
+        q, kv, tables, kv_lens, q_pos = _prefill_setup(
+            B=2, T=T, start_offsets=[3, 20])
+        jax.eval_shape(
+            lambda *a: pallas_paged_attention(*a, scale=1.0),
+            q, kv, tables, kv_lens, q_pos)
+        assert calls[-1][0] == want, (T, calls[-1][0])
+    H, hd, B, bs, lanes = 8, 32, 2, 8, 4 * 32
+    one, two = calls[0][1], calls[1][1]
+    C = one[0][1]
+    assert one == [
+        (pap._DECODE_SLOTS, C, 2, bs, lanes), (pap._DECODE_SLOTS, C), (5,),
+        (H, 128), (H, 128), (H, hd),  # a row's flash state
+        (2,), (1, B * H, hd), (1, B * H, hd), (B * H, 128), (B * H, 128),
+        (1, B * H, hd)]  # the shared phase's
+    # the short run's is the same list at twice the lines
+    assert two[:3] == one[:3] and two[3:] == [
+        tuple(2 * n if n in (H, B * H) else n for n in shape)
+        for shape in one[3:]]
+
+
+def test_a_short_run_on_the_chip_takes_the_phase_by_its_line_count(monkeypatch):
+    """``decode_sharing_calls`` asked with the positions a row: the verify
+    step of the draft cell (64 rows x 2 x 64 heads) is one sharing call; a
+    run too long for the stream is the chunk kernel's and shares nothing."""
+    from production_stack_tpu.ops import paged_attention_pallas as pap
+    from production_stack_tpu.ops.attention import decode_sharing_calls
+
+    monkeypatch.setattr(pap, "pallas_interpret", lambda: False)
+    monkeypatch.delenv("PST_FUSED_KV_WRITE", raising=False)
+    assert decode_sharing_calls("pallas", None, 64, 64, 128, 0, 2) == 1
+    assert decode_sharing_calls("pallas", None, 64, 64, 128, 128, 2) == 0
+    assert decode_sharing_calls("pallas", None, 64, 64, 128, 0, 4) == 0
+    assert decode_sharing_calls("pallas", None, 16, 32, 128, 0, 4) == 1
+    assert decode_sharing_calls("pallas", None, 4, 32, 128, 0, 2) == 0
+    assert decode_sharing_calls("gather", None, 64, 64, 128, 0, 2) == 0
